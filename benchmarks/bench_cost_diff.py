@@ -66,4 +66,7 @@ def show(tag, ca):
 
 
 if __name__ == "__main__":
+    from mxnet_tpu.cache_dirs import arm_compile_cache
+
+    arm_compile_cache()
     show("framework", framework_cost())

@@ -67,9 +67,6 @@ COLD = "--cold-start" in sys.argv
 
 if SMOKE:
     os.environ["JAX_PLATFORMS"] = "cpu"
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 
@@ -87,8 +84,20 @@ def model_setup():
     """Dims, symbol, params and the predictor factory — shared by the
     fleet drive and the ``--cold-start`` program-readiness phase (same
     env knobs, same model, so the two headlines describe one fleet)."""
+    import jax
+
+    import mxnet_tpu as mx
     from mxnet_tpu.decode import DecodePredictor
     from mxnet_tpu.models import attention_lm
+
+    # outside --smoke the fleet's predictors live on the TPU or the run
+    # stops here (DecodePredictor's own default context is the host CPU,
+    # which a TPU VM also has)
+    ctx = mx.cpu() if SMOKE else mx.tpu()
+    emit({"phase": "placement", "params_on": str(ctx.jax_device),
+          "platform": ctx.jax_device.platform,
+          "device_kind": ctx.jax_device.device_kind,
+          "device_count": len(jax.devices())})
 
     n_hosts = int(os.environ.get("BENCH_FLEET_HOSTS",
                                  "2" if SMOKE else "3"))
@@ -140,7 +149,7 @@ def model_setup():
         params["aux:" + name] = np.zeros(shape, np.float32)
 
     def mk_pred(pool=pool_pages):
-        return DecodePredictor(sym, params, cache_len=cache_len,
+        return DecodePredictor(sym, params, cache_len=cache_len, ctx=ctx,
                                temperature=0.0, kv_dtype="",
                                paged=True, page_tokens=page_tokens,
                                pool_pages=pool, prefill_chunk=chunk)
@@ -515,4 +524,8 @@ def cold_start_main():
 
 
 if __name__ == "__main__":
+    if not SMOKE:
+        from mxnet_tpu.cache_dirs import arm_compile_cache
+
+        arm_compile_cache()
     cold_start_main() if COLD else main()

@@ -69,6 +69,9 @@ def run(tag, wd=1e-4, skip_bn_data=False, batch=256, iters=12):
 
 
 if __name__ == "__main__":
+    from mxnet_tpu.cache_dirs import arm_compile_cache
+
+    arm_compile_cache()
     which = sys.argv[1] if len(sys.argv) > 1 else "all"
     if which in ("all", "base"):
         run("baseline (wd=1e-4, bn_data)")

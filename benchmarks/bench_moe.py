@@ -58,10 +58,6 @@ if os.environ.get("JAX_PLATFORMS", "") == "cpu" and \
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8"
                                ).strip()
-if SMOKE:
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
 
 import numpy as np
 
@@ -302,4 +298,8 @@ def main():
 
 
 if __name__ == "__main__":
+    if not SMOKE:
+        from mxnet_tpu.cache_dirs import arm_compile_cache
+
+        arm_compile_cache()
     sys.exit(main())

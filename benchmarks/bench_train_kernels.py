@@ -207,4 +207,8 @@ def main():
 
 
 if __name__ == "__main__":
+    if not SMOKE:
+        from mxnet_tpu.cache_dirs import arm_compile_cache
+
+        arm_compile_cache()
     sys.exit(main())

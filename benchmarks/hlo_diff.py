@@ -206,8 +206,13 @@ if __name__ == "__main__":
         dump = sys.argv[sys.argv.index("--dump") + 1]
         os.makedirs(dump, exist_ok=True)
 
-    fw_text, fw_ca = framework_hlo()
+    # one process per chip: the child needs it, so it runs to its end
+    # before this process first touches jax (framework_hlo) and holds it
     raw_text, raw_ca = raw_hlo()
+    from mxnet_tpu.cache_dirs import arm_compile_cache
+
+    arm_compile_cache()
+    fw_text, fw_ca = framework_hlo()
 
     if dump:
         open(os.path.join(dump, "framework.hlo"), "w").write(fw_text)

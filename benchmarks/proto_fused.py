@@ -13,6 +13,7 @@ Run on the bench chip: `python benchmarks/proto_fused.py`.
 """
 import functools
 import os
+import sys
 import time
 
 import numpy as np
@@ -172,4 +173,8 @@ def main():
 
 
 if __name__ == "__main__":
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from mxnet_tpu.cache_dirs import arm_compile_cache
+
+    arm_compile_cache()
     main()

@@ -1,5 +1,7 @@
 """Block-size sweep for the fused BN-matmul kernel vs XLA floors."""
 import functools
+import os
+import sys
 import time
 
 import numpy as np
@@ -109,4 +111,8 @@ def main():
 
 
 if __name__ == "__main__":
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from mxnet_tpu.cache_dirs import arm_compile_cache
+
+    arm_compile_cache()
     main()

@@ -1,10 +1,16 @@
 """Raw-JAX ResNet-50 v2 fwd+bwd+SGD, NCHW vs NHWC, to find the chip ceiling."""
 import os
+import sys
 import time
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+from mxnet_tpu.cache_dirs import arm_compile_cache  # noqa: E402
+
+arm_compile_cache()
 
 N = int(os.environ.get("N", "256"))
 LAYOUT = os.environ.get("LAYOUT", "NHWC")
@@ -170,8 +176,6 @@ def _bn_coeffs(p, name, s1, s2, count):
 def forward_fused(p, x, y):
     """NHWC trunk where BN statistics flow through matmul epilogues and
     BN-apply+ReLU rides the 1x1-conv prologues (ops/pallas_fused kernels)."""
-    import sys
-    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
     from mxnet_tpu.ops import pallas_fused as pf
 
     assert LAYOUT == "NHWC" and not S2D

@@ -346,6 +346,10 @@ VARIANTS = {
 }
 
 if __name__ == "__main__":
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+    from mxnet_tpu.cache_dirs import arm_compile_cache
+
+    arm_compile_cache()
     names = sys.argv[1:] or list(VARIANTS)
     for name in names:
         cfg = dict(BASE)

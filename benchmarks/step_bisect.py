@@ -115,4 +115,7 @@ def main():
 
 
 if __name__ == "__main__":
+    from mxnet_tpu.cache_dirs import arm_compile_cache
+
+    arm_compile_cache()
     main()

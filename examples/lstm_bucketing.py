@@ -76,9 +76,13 @@ def main():
                                      num_layers=args.num_layers,
                                      num_embed=args.num_embed,
                                      vocab_size=vocab_size, fused=args.fused)
+    # the example picks its platform itself: mx.tpu() raises without a chip
+    import jax
+
+    ctx = mx.cpu() if jax.devices()[0].platform == "cpu" else mx.tpu()
     mod = mx.mod.BucketingModule(sym_gen,
                                  default_bucket_key=data_train.default_bucket_key,
-                                 context=mx.tpu())
+                                 context=ctx)
     mod.fit(data_train, eval_metric=mx.metric.Perplexity(ignore_label=0),
             initializer=mx.initializer.Xavier(),
             optimizer="adam", optimizer_params={"learning_rate": args.lr},

@@ -65,8 +65,8 @@ def main():
     import jax
 
     n_dev = max(1, len(jax.devices()))
-    # mx.cpu on the CPU platform; mx.tpu otherwise (it resolves to whatever
-    # accelerator platform JAX exposes, falling back to the default)
+    # mx.cpu on the CPU platform; mx.tpu otherwise (mx.tpu(i) raises when
+    # there is no chip i — it never falls back to the host)
     make_ctx = mx.cpu if jax.devices()[0].platform == "cpu" else mx.tpu
     devices = [make_ctx(i) for i in range(min(n_dev, args.num_layers + 2))]
     logging.info("placing %d LSTM layers over %d device(s)",

@@ -47,10 +47,14 @@ def main():
         label="%s/t10k-labels-idx1-ubyte" % args.data_dir,
         batch_size=args.batch_size, flat=flat, seed=1)
 
+    # the example picks its platform itself: mx.tpu(i) raises without chip i
+    import jax
+
+    make_ctx = mx.cpu if jax.devices()[0].platform == "cpu" else mx.tpu
     if args.gpus:
-        ctx = [mx.tpu(int(i)) for i in args.gpus.split(",")]
+        ctx = [make_ctx(int(i)) for i in args.gpus.split(",")]
     else:
-        ctx = mx.tpu()
+        ctx = make_ctx()
 
     mod = mx.mod.Module(net, context=ctx)
     mod.fit(train, eval_data=val,

@@ -236,7 +236,10 @@ class RetracePass(Pass):
 
 # jaxpr primitives that round-trip through the host; any of them inside a
 # hot-path program serializes the device on every step
-_CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback")
+# jax.debug.print is its own primitive (debug_print); jax.debug.callback
+# keeps debug_callback
+_CALLBACK_PRIMS = ("pure_callback", "io_callback", "debug_callback",
+                   "debug_print")
 # compiled-HLO ops that move data to/from the host mid-program.  send/recv
 # are deliberately NOT listed: they also carry device-to-device channel
 # traffic (cross-partition collectives can legalize through them).
@@ -247,9 +250,9 @@ class HostSyncPass(Pass):
     """No host round-trips inside device programs.
 
     Static scan: jaxpr callback primitives (``pure_callback`` /
-    ``io_callback`` / ``debug_callback`` — a stray ``jax.debug.print``
-    left in an op implementation lands here) and compiled-HLO host
-    transfer ops.  The runtime half is ``MXNET_TRANSFER_GUARD``, which
+    ``io_callback`` / ``debug_callback`` / ``debug_print`` — a stray
+    ``jax.debug.print`` left in an op implementation lands here) and
+    compiled-HLO host transfer ops.  The runtime half is ``MXNET_TRANSFER_GUARD``, which
     arms ``jax.transfer_guard_device_to_host`` around ``fit()``'s hot
     loop (docs/static_analysis.md).
 

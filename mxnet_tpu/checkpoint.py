@@ -103,23 +103,6 @@ def save_sharded(directory, step, module):
     return commit_step(path)
 
 
-def _disk_tree(ckptr, path):
-    """The saved checkpoint's structure-with-array-metadata, across orbax
-    API generations: modern releases return the tree dict directly from
-    ``metadata()``; older ones wrap it as ``.item_metadata.tree``."""
-    md = ckptr.metadata(path)
-    if isinstance(md, dict):
-        return md
-    item = getattr(md, "item_metadata", None)
-    tree = getattr(item, "tree", None)
-    if tree is not None:
-        return tree
-    if isinstance(item, dict):
-        return item
-    raise MXNetError("unrecognized orbax metadata layout for %s: %r"
-                     % (path, type(md).__name__))
-
-
 def load_sharded(directory, step, module):
     """Restore params/aux (+slots when both sides have them) in place,
     re-sharded to the module's live mesh placement.  Structure differences
@@ -151,7 +134,7 @@ def load_sharded(directory, step, module):
         # synthesize plain abstract leaves for on-disk sections the module
         # does not carry (e.g. slots into an inference module), and drop
         # module sections absent on disk (restored state leaves them as-is)
-        disk_tree = _disk_tree(ckptr, path)
+        disk_tree = ckptr.metadata(path).item_metadata.tree
         target = {}
         for key, sub in disk_tree.items():
             if key in abstract:

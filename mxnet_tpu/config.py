@@ -522,8 +522,8 @@ register("MXNET_PROGRAM_CACHE", str, "",
          "executables plus .json sidecars, keyed over (abstract args, "
          "donation map, partition rules, jax version, backend, mesh "
          "shape, model graph digest) — any drift is a key miss, never "
-         "a wrong program.  Empty (default) = ~/.cache/mxnet_tpu/"
-         "programs.  Shared read-only across fleet hosts; equal keys "
+         "a wrong program.  Empty (default) = <checkout>/"
+         ".mxnet_programs (cache_dirs.PROGRAM_CACHE).  Shared read-only across fleet hosts; equal keys "
          "prove byte-identical programs (docs/programs.md).")
 register("MXNET_HEARTBEAT_DIR", str, "",
          "Shared directory for worker liveness heartbeats (failure "

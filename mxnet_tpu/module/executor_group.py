@@ -108,8 +108,14 @@ class DataParallelExecutorGroup:
 
         devices = [c.jax_device for c in self.contexts]
         if len(set(devices)) != len(devices):
-            # fake multi-context on one physical device (reference test trick):
-            # fall back to single-device execution, semantics unchanged
+            if any(c.device_type not in ("cpu", "cpu_pinned")
+                   for c in self.contexts):
+                raise MXNetError("contexts %s name %d distinct device(s); "
+                                 "a multi-chip bind needs one chip per "
+                                 "context" % (self.contexts,
+                                              len(set(devices))))
+            # fake mx.cpu(N) multi-context on one host device (reference
+            # test trick): single-device execution, semantics unchanged
             self.logger.debug("contexts map to %d physical device(s); running "
                               "unsharded", len(set(devices)))
             return

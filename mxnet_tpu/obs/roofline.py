@@ -28,7 +28,7 @@ from __future__ import annotations
 import threading
 
 __all__ = ["ProgramAccounting", "PEAK_FLOPS", "peak_flops_for",
-           "auto_peak", "render_mfu_table"]
+           "require_peak_flops", "auto_peak", "render_mfu_table"]
 
 # peak bf16 FLOP/s per chip by TPU generation (public spec sheets) —
 # moved here from bench.py so the bench and the MFU table share one map
@@ -47,12 +47,23 @@ PEAK_FLOPS = {
 
 
 def peak_flops_for(device):
-    """``(peak_flops_or_None, device_kind)`` for a jax device."""
+    """``(peak_flops_or_None, device_kind)`` for a jax device.  The kind
+    must match a table row exactly: a prefix match would hand an unknown
+    "TPU v5..." string some other generation's peak."""
     kind = getattr(device, "device_kind", "")
-    for name, peak in PEAK_FLOPS.items():
-        if kind.lower().startswith(name.lower()):
-            return peak, kind
-    return None, kind
+    return PEAK_FLOPS.get(kind), kind
+
+
+def require_peak_flops(device):
+    """:func:`peak_flops_for` for the measuring paths (``bench.py``,
+    ``chip_smoke.py``): a device the table does not name is an error,
+    not an MFU column quietly left null."""
+    peak, kind = peak_flops_for(device)
+    if peak is None:
+        raise KeyError("device_kind %r is not in obs.roofline.PEAK_FLOPS "
+                       "(platform %r); add its spec-sheet peak before "
+                       "measuring on it" % (kind, device.platform))
+    return peak, kind
 
 
 def auto_peak():

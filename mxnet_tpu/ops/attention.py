@@ -569,10 +569,9 @@ def apply_kv_layout(buf, device=None):
     a ``--kv`` winner this probe ingested on this device generation
     (op ``"kv_layout"``, keyed by pool rank + dtype); a cached native
     winner or a cache miss is a plain ``device_put`` to ``device`` (or
-    the buffer as-is when no device is given).  Backends without
-    ``jax.experimental.layout`` support for the request — the CPU harness
-    — fall back to the native layout with a one-time warning, so the knob
-    is safe to leave set in mixed fleets."""
+    the buffer as-is when no device is given).  A backend that refuses
+    the requested layout falls back to its native one with a one-time
+    warning, so the knob is safe to leave set in mixed fleets."""
     import jax
 
     from .. import config as _config
@@ -590,23 +589,22 @@ def apply_kv_layout(buf, device=None):
             spec = ""
     if not spec:
         return jax.device_put(buf, device) if device is not None else buf
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
     try:
         order = tuple(int(t) for t in spec.split(","))
         if sorted(order) != list(range(buf.ndim)):
             raise ValueError(
                 "MXNET_KV_LAYOUT=%r is not a permutation of 0..%d"
                 % (spec, buf.ndim - 1))
-        from jax.experimental.layout import DeviceLocalLayout, Layout
-        from jax.sharding import SingleDeviceSharding
-
         dev = device if device is not None else jax.devices()[0]
-        target = Layout(DeviceLocalLayout(major_to_minor=order),
-                        SingleDeviceSharding(dev))
-        out = jax.device_put(buf, target)
-        # some backends accept the API but silently keep their native
+        # some backends accept the request but silently keep their native
         # layout; that is fine — the request is best-effort by design
-        return out
-    except Exception as exc:
+        return jax.device_put(buf, Format(Layout(major_to_minor=order),
+                                          SingleDeviceSharding(dev)))
+    except (ValueError, RuntimeError) as exc:
+        # a malformed spec, or a backend that refuses the layout
         if not _KV_LAYOUT_WARNED["done"]:
             _KV_LAYOUT_WARNED["done"] = True
             import warnings

@@ -168,13 +168,17 @@ def supported(q_shape, k_pool, v_pool, table_shape, num_heads,
 
 def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
             acc_ref, m_ref, l_ref, m_scr, l_scr, acc_scr, *, scale, tq,
-            page_tokens, pages_per_split, view_pages, quant):
+            page_tokens, pages_per_split, view_pages, quant, group):
     """One (b, h, s, ms) grid step: fold page ``s*pages_per_split + ms``
     of slot b's view into the running flash softmax for head h.
 
     ``ks_ref``/``vs_ref`` are the per-(token, head) scale pages of a
     quantized pool (None otherwise) — dequantization happens HERE, on
-    the (pt, hd) tile in VMEM, never in HBM.  At the split's last page
+    the (pt, hd) tile in VMEM, never in HBM.  A scale page arrives with
+    ALL its kv heads — Mosaic refuses a 1-wide lane block of the
+    (P, pt, H_kv) plane — and this head's column is picked by a one-hot
+    reduction (``group`` q-heads share kv-head ``h // group``).  At the
+    split's last page
     the UNNORMALIZED partial (acc, max, sum) is written out; the caller
     combines splits with a logsumexp reduction.
     """
@@ -183,6 +187,7 @@ def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
     from jax.experimental import pallas as pl
 
     b = pl.program_id(0)
+    kv_head = pl.program_id(1) // group
     ms = pl.program_id(3)
     nms = pl.num_programs(3)
     s = pl.program_id(2)
@@ -203,8 +208,14 @@ def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
         k = k_ref[0].astype(jnp.float32)                    # (pt, hd_k)
         v = v_ref[0].astype(jnp.float32)                    # (pt, hd_v)
         if quant:
-            k = k * ks_ref[0]                               # (pt, 1) scale
-            v = v * vs_ref[0]
+            def head_scale(ref):                            # -> (pt, 1)
+                page = ref[0]                               # (pt, H_kv)
+                col = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
+                return jnp.sum(jnp.where(col == kv_head, page, 0.0),
+                               axis=1, keepdims=True)
+
+            k = k * head_scale(ks_ref)
+            v = v * head_scale(vs_ref)
         logits = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * jnp.float32(scale)
@@ -278,7 +289,7 @@ def _paged_flash_call(q, k_pool, v_pool, table, lens, num_heads, scale,
 
     kernel = functools.partial(
         _kernel, scale=scale, tq=tq, page_tokens=pt, pages_per_split=ms,
-        view_pages=m, quant=quant)
+        view_pages=m, quant=quant, group=g)
 
     # index maps: every pool block is one page's one head-slice, located
     # through the scalar-prefetched table — the in-kernel gather
@@ -304,8 +315,10 @@ def _paged_flash_call(q, k_pool, v_pool, table, lens, num_heads, scale,
     ]
     args = [qh, kd, vd]
     if quant:
-        in_specs += [pl.BlockSpec((1, pt, 1), _page_map),
-                     pl.BlockSpec((1, pt, 1), _page_map)]
+        def _scale_map(bi, hi, si, mi, tr, lr):
+            return (tr[bi, si * ms + mi], 0, 0)
+
+        in_specs += [pl.BlockSpec((1, pt, kvh), _scale_map)] * 2
         args += [k_pool.scale, v_pool.scale]
     else:
         # keep ONE kernel signature: unquantized pools ride a zero-cost
@@ -345,7 +358,7 @@ def _paged_flash_call(q, k_pool, v_pool, table, lens, num_heads, scale,
             jax.ShapeDtypeStruct((b, h, s, tq, LANES), jnp.float32),
             jax.ShapeDtypeStruct((b, h, s, tq, LANES), jnp.float32),
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

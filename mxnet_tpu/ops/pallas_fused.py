@@ -104,9 +104,13 @@ def _tuned(m, k, n, dtype):
 
 
 def _fit_block(bm, m):
-    """Clamp a block preference onto divisor-of-m; the grid drops whole
-    rows otherwise."""
+    """Clamp a block preference onto a power-of-two divisor of m; the grid
+    drops whole rows otherwise.  The power-of-two floor comes first: the
+    halving walk from a preference like 1280 (``_auto_block_m`` at
+    k = n = 1024) ended on 5 rows, which Mosaic refuses (row blocks must be
+    a multiple of 8)."""
     bm = max(MIN_BLOCK_M, min(int(bm), m))
+    bm = 1 << (bm.bit_length() - 1)
     while m % bm and bm > MIN_BLOCK_M:
         bm //= 2
     return bm

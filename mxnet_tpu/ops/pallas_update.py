@@ -420,7 +420,7 @@ def _bucket_call(kind, nslots, has_wc, w, g, slots, wc, cdtype, lrb, wdb,
         # — no cross-block reduction — so the grid axis fans out across
         # megacores (the same marking pallas_decode gives its
         # independent axes; 'arbitrary' would serialize the whole slab)
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,
     )(jnp.asarray(lrb), jnp.asarray(wdb), jnp.asarray(hyp), *args)

@@ -1,58 +1,25 @@
-"""jax version compatibility for the explicit-collective parallel paths.
-
-The framework's shard_map regions (ring attention, pipeline schedules) are
-written against the current jax API — ``jax.shard_map`` with the
-``check_vma`` relaxation knob.  Older jax releases (< 0.5) ship the same
-machinery as ``jax.experimental.shard_map.shard_map`` with the knob named
-``check_rep``.  One wrapper here keeps every call site on the new spelling
-so nothing else in the tree branches on the jax version.
+"""The two jax spellings the explicit-collective paths (ring attention,
+pipeline schedules, MoE all-to-all) share, under the installed jax 0.9:
+``jax.shard_map`` with its ``check_vma`` knob, and ``lax.pcast(...,
+to="varying")``.  Kept as one module so every shard_map region in the tree
+imports from one place.
 """
 from __future__ import annotations
 
 __all__ = ["shard_map", "pvary"]
 
-_IMPL = None  # (callable, vma_kwarg_name) resolved once
 
+def shard_map(f, mesh, in_specs, out_specs, check_vma=True):
+    """``jax.shard_map``.  ``check_vma=False`` relaxes the varying-axes
+    typing that e.g. pallas interpreter mode cannot satisfy."""
+    import jax
 
-def _resolve():
-    global _IMPL
-    if _IMPL is None:
-        try:
-            from jax import shard_map as sm  # jax >= 0.5
-            _IMPL = (sm, "check_vma")
-        except ImportError:
-            from jax.experimental.shard_map import shard_map as sm
-            _IMPL = (sm, "check_rep")
-    return _IMPL
-
-
-def shard_map(f, mesh, in_specs, out_specs, check_vma=None):
-    """``jax.shard_map`` across jax versions.
-
-    ``check_vma=None`` keeps the backend's default; an explicit bool maps
-    onto whichever knob the installed jax spells it as (``check_vma`` new,
-    ``check_rep`` old — both gate the same replication/varying-axes typing
-    that e.g. pallas interpreter mode cannot satisfy).
-    """
-    sm, knob = _resolve()
-    kwargs = {} if check_vma is None else {knob: check_vma}
-    return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kwargs)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def pvary(x, axis_names):
-    """Mark ``x`` as device-varying over mesh axes (vma typing).
-
-    New jax spells this ``lax.pcast(..., to="varying")``; the releases
-    that introduced vma typing spell it ``lax.pvary``; older releases
-    have no varying-mesh-axes type system, where replicated and varying
-    values unify — the identity is exactly right there.
-    """
+    """Mark ``x`` as device-varying over mesh axes (vma typing)."""
     from jax import lax
 
-    pcast = getattr(lax, "pcast", None)
-    if pcast is not None:
-        return pcast(x, tuple(axis_names), to="varying")
-    pv = getattr(lax, "pvary", None)
-    if pv is not None:
-        return pv(x, tuple(axis_names))
-    return x
+    return lax.pcast(x, tuple(axis_names), to="varying")

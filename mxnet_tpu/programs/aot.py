@@ -33,7 +33,7 @@ probe keeps working unchanged.
 
 Arming: ``MXNET_AOT=1`` (off by default — nothing changes for existing
 paths), cache directory from ``MXNET_PROGRAM_CACHE`` (default
-``~/.cache/mxnet_tpu/programs``).  ``AOT_STATS`` carries the process
+``<checkout>/.mxnet_programs``, see :mod:`mxnet_tpu.cache_dirs`).  ``AOT_STATS`` carries the process
 counters the bench contract publishes (hits / misses / saves / errors /
 fallbacks).
 """
@@ -54,8 +54,6 @@ log = logging.getLogger(__name__)
 # scrape sees them; the python ints stay the bench's source of truth)
 AOT_STATS = {"hits": 0, "misses": 0, "saves": 0, "errors": 0,
              "fallbacks": 0}
-
-_DEFAULT_DIR = os.path.join("~", ".cache", "mxnet_tpu", "programs")
 
 
 def reset_stats():
@@ -84,11 +82,12 @@ def enabled():
 
 def cache_dir(create=False):
     """The program-cache directory (``MXNET_PROGRAM_CACHE``, default
-    ``~/.cache/mxnet_tpu/programs``), created on demand."""
+    ``<checkout>/.mxnet_programs``), created on demand."""
     from .. import config as _config
+    from ..cache_dirs import PROGRAM_CACHE
 
-    path = _config.get("MXNET_PROGRAM_CACHE") or _DEFAULT_DIR
-    path = os.path.expanduser(path)
+    path = os.path.expanduser(_config.get("MXNET_PROGRAM_CACHE")
+                              or PROGRAM_CACHE)
     if create:
         os.makedirs(path, exist_ok=True)
     return path

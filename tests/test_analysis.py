@@ -288,7 +288,7 @@ def test_host_sync_pass_catches_callback():
                             name="leaky", compile_program=False)
     rep = run_passes([art], passes=[HostSyncPass()])
     assert len(rep.errors) == 1
-    assert rep.errors[0].code == "debug_callback"
+    assert rep.errors[0].code == "debug_print"
 
 
 def test_host_sync_pass_catches_pure_callback():
@@ -322,11 +322,11 @@ def test_host_sync_pass_sanctioned_allowlist():
     art = artifact_from_jit(jax.jit(leaky),
                             (jax.ShapeDtypeStruct((4,), jnp.float32),),
                             name="fence", compile_program=False,
-                            host_sync_allow=["debug_callback"])
+                            host_sync_allow=["debug_print"])
     rep = run_passes([art], passes=[HostSyncPass()])
     assert rep.errors == []
     sanc = [f for f in rep.findings
-            if f.code == "sanctioned:debug_callback"]
+            if f.code == "sanctioned:debug_print"]
     assert len(sanc) == 1 and sanc[0].severity == "info", rep.findings
     # the waiver is code-specific: a different leak is still an error
     art2 = artifact_from_jit(jax.jit(leaky),
@@ -335,7 +335,7 @@ def test_host_sync_pass_sanctioned_allowlist():
                              host_sync_allow=["hlo-outfeed"])
     rep2 = run_passes([art2], passes=[HostSyncPass()])
     assert len(rep2.errors) == 1
-    assert rep2.errors[0].code == "debug_callback"
+    assert rep2.errors[0].code == "debug_print"
 
 
 def test_host_sync_pass_clean_program():

@@ -44,6 +44,11 @@ identity and zero retraces itself; the smoke re-pins the deterministic
 grouped-KV halves (pool ratio exactly 1/G, grouped attention bytes
 under the MHA price, int8 compounding under the grouping ratio) from
 the JSON.
+
+`chip_smoke.py` is the on-chip proof (train / serve / kernels /
+multichip on the TPU, one process).  Here: it refuses to run without a
+chip, and its `train` and `serve` phase functions — the same ones
+`main()` runs at full width — pass on `mx.cpu()` at a tiny size table.
 """
 import json
 import os
@@ -629,3 +634,71 @@ def test_mxlint_smoke_contract():
     for prog in ("paged_decode_step", "paged_verify_step"):
         assert cache_rows[prog]["detail"]["layout"] == "paged", \
             cache_rows[prog]
+
+
+# ---------------------------------------------------------------------------
+# chip_smoke.py: the on-chip proof, exercised here as far as a CPU can
+# ---------------------------------------------------------------------------
+CHIP_SMOKE_TINY = {
+    "train": dict(layers=18, classes=10, image=(3, 32, 32), batch=8,
+                  batches=2, epochs=2),
+    "serve": dict(vocab=64, seq_len=128, layers=2, embed=32, heads=2,
+                  ffn=64, cache_len=128, page_tokens=8, prefill_chunk=16,
+                  slots=2, max_prefill=32, requests=4, prompt_lo=8,
+                  prompt_hi=32, new_tokens=4),
+}
+
+
+def test_chip_smoke_refuses_without_a_chip():
+    """``python chip_smoke.py`` on the CPU platform exits non-zero within
+    seconds, names the platform it found, and prints no result."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    proc = subprocess.run(
+        [sys.executable, os.path.join(ROOT, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=60, cwd=ROOT, env=env)
+    assert proc.returncode != 0
+    assert proc.stdout.strip() == "", proc.stdout
+    assert "platform='cpu'" in proc.stderr, proc.stderr[-2000:]
+    assert "Traceback" not in proc.stderr, proc.stderr[-2000:]
+
+
+def test_chip_smoke_phases_at_tiny_size():
+    """The same ``train`` and ``serve`` phase functions ``main()`` runs at
+    full width on the chip, here on ``mx.cpu()`` at a tiny size table: the
+    fit loop performs no in-loop host sync, the server retires every
+    request at its cap without a retrace, and served tokens equal
+    ``generate``'s (exact on the CPU)."""
+    import mxnet_tpu as mx
+
+    sys.path.insert(0, ROOT)
+    try:
+        import chip_smoke
+    finally:
+        sys.path.remove(ROOT)
+
+    fit = chip_smoke.train(mx.cpu(), CHIP_SMOKE_TINY)
+    assert fit["host_syncs_per_step"] == 0 and fit["steps"] == 4, fit
+    served = chip_smoke.serve(mx.cpu(), CHIP_SMOKE_TINY)
+    assert served["retired_at_cap"] == 4, served
+    assert served["token_identical_to_generate"] == "4/4", served
+
+
+def test_compile_cache_helper_placement(monkeypatch, tmp_path):
+    """``JAX_COMPILATION_CACHE_DIR`` places the cache from outside and the
+    helper then configures nothing; unset, the cache goes to the fixed
+    ``<checkout>/.jax_cache``.  (No backend is touched, nothing compiles.)"""
+    import jax
+
+    from mxnet_tpu import cache_dirs
+
+    prior = jax.config.jax_compilation_cache_dir
+    try:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert cache_dirs.arm_compile_cache() == str(tmp_path)
+        assert jax.config.jax_compilation_cache_dir == prior
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+        want = os.path.join(ROOT, ".jax_cache")
+        assert cache_dirs.arm_compile_cache() == want
+        assert jax.config.jax_compilation_cache_dir == want
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prior)

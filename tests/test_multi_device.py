@@ -101,8 +101,17 @@ def test_batch_not_divisible_raises():
 
 
 def test_fake_context_ids_fall_back():
-    """Contexts beyond physical devices share hardware; executor falls back
-    to unsharded execution (reference fake-device trick still works)."""
+    """CPU contexts beyond physical devices share hardware; executor falls
+    back to unsharded execution (reference fake-device trick still works).
+    Accelerator contexts never do: where there is no TPU (this harness),
+    ``mx.tpu()``/``mx.gpu()`` raise instead of quietly naming the host."""
+    import jax
+
+    assert mx.cpu(8).jax_device == mx.cpu(0).jax_device == jax.devices()[0]
+    for ctx in (mx.tpu(), mx.gpu(3)):
+        with pytest.raises(mx.MXNetError, match="no TPU backend"):
+            ctx.jax_device
+    assert mx.num_devices("tpu") == 0
     net = _mlp()
     mod = mx.mod.Module(net, context=[mx.cpu(0), mx.cpu(8)])  # 8 wraps to 0
     mod.bind(data_shapes=[("data", (4, 10))],

@@ -15,6 +15,7 @@ syncs).
 """
 import json
 import os
+import re
 import threading
 import urllib.request
 
@@ -259,6 +260,16 @@ def _decode_artifact():
     return pred.decode_artifact(state)
 
 
+def _without_source_locations(hlo):
+    """Compiled HLO embeds python source locations (four header tables
+    plus a ``stack_frame_id`` per op).  The telemetry wrapper dispatches
+    the step from a different line of ``run()``, so those differ by
+    construction; everything else must match byte for byte."""
+    hlo = re.sub(r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+                 r"(?:\d+ .*\n)*", "", hlo, flags=re.M)
+    return re.sub(r" stack_frame_id=\d+", "", hlo)
+
+
 def test_instrumentation_is_free_hlo_byte_identical(telemetry):
     """The acceptance tripwire: telemetry on vs off, the fused train
     step and the donated decode step lower AND compile to byte-identical
@@ -275,9 +286,11 @@ def test_instrumentation_is_free_hlo_byte_identical(telemetry):
     decode_off = _decode_artifact()
 
     assert train_on.stablehlo_text == train_off.stablehlo_text
-    assert train_on.compiled_text == train_off.compiled_text
+    assert _without_source_locations(train_on.compiled_text) == \
+        _without_source_locations(train_off.compiled_text)
     assert decode_on.stablehlo_text == decode_off.stablehlo_text
-    assert decode_on.compiled_text == decode_off.compiled_text
+    assert _without_source_locations(decode_on.compiled_text) == \
+        _without_source_locations(decode_off.compiled_text)
 
     # zero new host syncs: the host-sync pass is green on the
     # INSTRUMENTED programs (no callback prims, no infeed/outfeed)

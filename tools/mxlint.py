@@ -87,14 +87,6 @@ if os.environ.get("JAX_PLATFORMS", "") == "cpu" and \
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8"
                                ).strip()
-if SMOKE:
-    import jax as _jax
-
-    _jax.config.update("jax_platforms", "cpu")
-    try:
-        _jax.config.update("jax_num_cpu_devices", 8)
-    except AttributeError:
-        pass
 
 
 def _parse_args(argv):
