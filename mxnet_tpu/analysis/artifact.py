@@ -27,12 +27,18 @@ __all__ = ["ProgramArtifact", "artifact_from_jit", "aval_of"]
 
 def aval_of(x):
     """``jax.ShapeDtypeStruct`` mirror of an array, sharding preserved
-    when it has one — the one helper behind every artifact probe, so the
-    committed-vs-uncommitted handling stays in a single place."""
+    when it is committed to one — the one helper behind every artifact
+    probe, so the committed-vs-uncommitted handling stays in a single
+    place.  An uncommitted array (a fresh ``jnp.asarray``) mirrors with
+    no sharding, as the dispatch itself resolves it: lowering the
+    mirrors then meets the very lowering the call made, and
+    ``.compile()`` hands back the executable already loaded
+    (``obs.programs.scope_map`` reads its text; nothing compiles)."""
     import jax
 
-    return jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                sharding=getattr(x, "sharding", None))
+    sharding = getattr(x, "sharding", None) \
+        if getattr(x, "committed", True) else None
+    return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding)
 
 
 @dataclass

@@ -703,6 +703,36 @@ def stablehlo_sort_scatter_stats(stablehlo_text):
     return stats
 
 
+# an instruction line's left-hand side: ``[ROOT] %name = `` (analysis.schedule
+# walks the entry computation with the same expression)
+_LHS_RE = re.compile(r"^\s*(?:ROOT\s+)?(%?[\w.\-]+)\s*=\s*")
+_META_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_MODULE_NAME_RE = re.compile(r"^HloModule\s+([^\s,]+)")
+
+
+def instruction_op_names(hlo_text):
+    """``(module name, [(instruction name, op_name or None), ...])`` of one
+    HLO module's text: every instruction line of every computation
+    (names are unique within a module) with the ``op_name`` of its own
+    ``metadata={...}`` — for a fusion, XLA keeps its root's.  The
+    module's name is the text's first line (``HloModule jit_step, ...``),
+    the stem a device trace prints on its ``XLA Modules`` line."""
+    lines = hlo_text.splitlines()
+    head = _MODULE_NAME_RE.match(lines[0]) if lines else None
+    out = []
+    for line in lines[1:]:
+        m = _LHS_RE.match(line)
+        if m is None:
+            continue
+        # the metadata is the line's tail: search from there, so a
+        # quoted op_name inside a backend_config cannot shadow it
+        tail = line.rfind("metadata={")
+        found = _META_OP_NAME_RE.search(line, tail) if tail >= 0 else None
+        out.append((m.group(1).lstrip("%"),
+                    found.group(1) if found else None))
+    return (head.group(1) if head else None), out
+
+
 def collective_stats(hlo_text):
     """Count collectives and sum their result payloads.
 

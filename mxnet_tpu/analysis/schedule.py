@@ -34,7 +34,7 @@ import re
 from dataclasses import dataclass, field
 
 from .framework import Pass
-from .hlo_parse import _scan_shape, dot_flops_report, shape_bytes
+from .hlo_parse import _LHS_RE, _scan_shape, dot_flops_report, shape_bytes
 
 __all__ = ["AsyncPair", "ScheduleModel", "SchedulePass", "parse_schedule"]
 
@@ -46,9 +46,9 @@ ASYNC_OPS = ("collective-permute", "all-reduce", "all-gather",
              "reduce-scatter", "all-to-all", "collective-broadcast",
              "copy")
 
-# '%name = shape op(...)' — the lhs instruction name (ROOT-prefixed on
-# the root), then the shape (balanced scan — tuples nest), then the op
-_LHS_RE = re.compile(r"^\s*(?:ROOT\s+)?(%?[\w.\-]+)\s*=\s*")
+# '%name = shape op(...)' — the lhs instruction name (hlo_parse._LHS_RE,
+# ROOT-prefixed on the root), then the shape (balanced scan — tuples
+# nest), then the op
 _OP_NAME_RE = re.compile(r"\s*([a-z][a-z0-9\-]*)\(")
 _OPERAND_RE = re.compile(r"%[\w.\-]+")
 # structural ops that are free at runtime: their result bytes are not

@@ -27,11 +27,18 @@ def arm_compile_cache():
     ``bench.py``, ``benchmarks/*.py`` outside ``--smoke``) — never by
     library import, and never by the tests (tests/conftest.py says why).
     ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: jax reads
-    it itself, so when it is set nothing is configured here."""
+    it itself, so when it is set no directory is configured here.
+
+    Either way the cache's key takes in the HLO metadata.  By default jax
+    leaves it out, and an executable read back then carries the
+    ``op_name`` of whichever build wrote it: a cache shared with another
+    build of this checkout (a parent commit, say) would hand
+    ``obs.programs.scope_map`` that build's layer scopes, or none."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", JAX_CACHE)
     return JAX_CACHE

@@ -384,10 +384,11 @@ class DecodePredictor:
     # roofline telemetry (mxnet_tpu.obs) — host-side only: the compiled
     # programs are byte-identical with telemetry on or off
     # ------------------------------------------------------------------
-    def _roofline_register(self, name, fn, args):
+    def _roofline_register(self, name, fn, args, static=True):
         """Snap ``args``' avals once and register a lazy static-cost
-        prober for program ``name`` (first dispatch only; later calls
-        are one dict hit)."""
+        prober (``static``: a row of the MFU table) and a lazy reader of
+        the optimized HLO (the scope map) for program ``name`` (first
+        dispatch only; later calls are one dict hit)."""
         if name in self._static_args or not _obs.enabled():
             return
         import weakref
@@ -400,9 +401,25 @@ class DecodePredictor:
         # weakly bound: a collected predictor must not stay pinned (env
         # params + snapped programs) by the process-global accounting
         ref = weakref.ref(self)
-        _obs.programs.register_static(
+        if static:
+            _obs.programs.register_static(
+                name, lambda n=name, r=ref: (
+                    r()._roofline_static(n) if r() is not None else None))
+        _obs.programs.register_hlo(
             name, lambda n=name, r=ref: (
-                r()._roofline_static(n) if r() is not None else None))
+                r()._program_hlo(n) if r() is not None else None))
+
+    def _program_hlo(self, name):
+        """Optimized HLO text of one snapped program, for
+        ``obs.programs.scope_map``.  Lowering the snapped avals again
+        meets jax's in-memory caches: the text is read off the
+        executable the program already dispatches, nothing compiles and
+        nothing is loaded a second time."""
+        from .programs.spec import probing
+
+        fn, args = self._static_args[name]
+        with probing(self):
+            return fn.lower(*args).compile().as_text()
 
     def _roofline_static(self, name):
         """Price one snapped program (trace+lower only; probe-flagged so
@@ -437,6 +454,7 @@ class DecodePredictor:
         import jax
         import jax.numpy as jnp
 
+        from .obs.scopes import node_scope as _node_scope
         from .ops import attention as _attn
 
         b, t = tokens.shape[0], tokens.shape[1]
@@ -460,91 +478,91 @@ class DecodePredictor:
             ins = [values[(id(s), i)] for s, i in node.inputs[:n_args]]
             aux_ins = [values[(id(s), i)] for s, i in node.inputs[n_args:]]
             opname = node.op.name
-            if opname == "dot_product_attention":
-                q, k, v = ins
-                heads = attrs.get("num_heads", 1)
-                # grouped-query attention: the K/V stream (and so the
-                # cache/pool) is physically kv_heads wide — every append/
-                # quantize below works in kv-head units, attends map
-                # q-head h to kv group h // G
-                kv_heads = attrs.get("num_kv_heads", 0) or heads
-                ai = ci
-                ci += 1
-                dims = dict(num_heads=int(heads),
-                            num_kv_heads=int(kv_heads),
-                            q_dim=int(q.shape[-1]),
-                            kv_dim=int(k.shape[-1]))
-                if ai < len(self._attn_dims):
-                    self._attn_dims[ai] = dims
-                else:
-                    self._attn_dims.append(dims)
-                scale = attrs.get("scale", 0.0) or None
-                if caches is None:
-                    outs = [_attn.sdpa(q, k, v, num_heads=heads,
-                                       causal=attrs.get("causal", False),
-                                       scale=scale,
-                                       num_kv_heads=kv_heads)]
-                    new_caches.append((self._fill_cache(k, kv_heads),
-                                       self._fill_cache(v, kv_heads)))
-                else:
-                    kc, vc = caches[ai]
-                    pos = jnp.asarray(pos0, jnp.int32).reshape(-1)
-                    mesh_on = self._mesh is not None
-                    if tables is not None:
-                        kc = _attn.paged_append(kc, tables, k, pos0,
-                                                num_heads=kv_heads,
-                                                active=active, valid=valid)
-                        vc = _attn.paged_append(vc, tables, v, pos0,
-                                                num_heads=kv_heads,
-                                                active=active, valid=valid)
-                        outs = [_attn.paged_attend(q, kc, vc, tables,
-                                                   pos + t, num_heads=heads,
-                                                   scale=scale,
-                                                   mesh_active=mesh_on,
-                                                   num_kv_heads=kv_heads)]
+            with _node_scope(node):
+                if opname == "dot_product_attention":
+                    q, k, v = ins
+                    heads = attrs.get("num_heads", 1)
+                    # grouped-query attention: the K/V stream (and so the
+                    # cache/pool) is physically kv_heads wide — every append/
+                    # quantize below works in kv-head units, attends map
+                    # q-head h to kv group h // G
+                    kv_heads = attrs.get("num_kv_heads", 0) or heads
+                    ai = ci
+                    ci += 1
+                    dims = dict(num_heads=int(heads),
+                                num_kv_heads=int(kv_heads),
+                                q_dim=int(q.shape[-1]),
+                                kv_dim=int(k.shape[-1]))
+                    if ai < len(self._attn_dims):
+                        self._attn_dims[ai] = dims
                     else:
-                        kc = _attn.cache_append(kc, k, pos0,
-                                                num_heads=kv_heads)
-                        vc = _attn.cache_append(vc, v, pos0,
-                                                num_heads=kv_heads)
-                        outs = [_attn.cache_attend(q, kc, vc, pos + t,
-                                                   num_heads=heads,
-                                                   scale=scale,
-                                                   mesh_active=mesh_on,
-                                                   num_kv_heads=kv_heads)]
-                    # PATH_TAKEN, recorded at trace time: which decode-
-                    # attention path this predictor's programs actually
-                    # lowered — refines artifact meta so a shape-gated
-                    # fallback ("einsum-gated") never false-trips the
-                    # mxlint pallas-fallback error
-                    self._decode_path = _attn.DECODE_PATH["last"]
-                    new_caches.append((kc, vc))
-            else:
-                if opname in _POSITION_BROADCAST_OPS and len(ins) == 2 \
-                        and getattr(ins[0], "ndim", 0) == 3 \
-                        and getattr(ins[1], "ndim", 0) == 3 \
-                        and ins[0].shape[1] != ins[1].shape[1] \
-                        and t in (ins[0].shape[1], ins[1].shape[1]):
-                    # learned positional table vs the (B, t, E) stream:
-                    # gather the rows for the CURRENT positions
-                    big_i = 0 if ins[0].shape[1] != t else 1
-                    big = ins[big_i]
-                    if big.shape[0] != 1:
-                        raise MXNetError(
-                            "decode: node %r mixes time-lengths %s without "
-                            "a broadcastable (1, S, E) side" %
-                            (node.name, (ins[0].shape, ins[1].shape)))
-                    s_len = big.shape[1]
-                    idx = (jnp.asarray(pos0, jnp.int32).reshape(-1, 1)
-                           + jnp.arange(t, dtype=jnp.int32)[None, :])
-                    idx = jnp.clip(idx, 0, s_len - 1)
-                    ins = list(ins)
-                    ins[big_i] = jnp.take(big[0], idx, axis=0)
-                octx = OpContext(
-                    is_train=False,
-                    rng=jax.random.fold_in(base_key, seq),
-                    mesh_active=self._mesh is not None, mesh=self._mesh)
-                outs, _ = node.op.fcompute(attrs, ins, aux_ins, octx)
+                        self._attn_dims.append(dims)
+                    scale = attrs.get("scale", 0.0) or None
+                    if caches is None:
+                        outs = [_attn.sdpa(q, k, v, num_heads=heads,
+                                           causal=attrs.get("causal", False),
+                                           scale=scale,
+                                           num_kv_heads=kv_heads)]
+                        new_caches.append((self._fill_cache(k, kv_heads),
+                                           self._fill_cache(v, kv_heads)))
+                    else:
+                        kc, vc = caches[ai]
+                        pos = jnp.asarray(pos0, jnp.int32).reshape(-1)
+                        mesh_on = self._mesh is not None
+                        if tables is not None:
+                            kc = _attn.paged_append(kc, tables, k, pos0,
+                                                    num_heads=kv_heads,
+                                                    active=active, valid=valid)
+                            vc = _attn.paged_append(vc, tables, v, pos0,
+                                                    num_heads=kv_heads,
+                                                    active=active, valid=valid)
+                            outs = [_attn.paged_attend(
+                                q, kc, vc, tables, pos + t, num_heads=heads,
+                                scale=scale, mesh_active=mesh_on,
+                                num_kv_heads=kv_heads)]
+                        else:
+                            kc = _attn.cache_append(kc, k, pos0,
+                                                    num_heads=kv_heads)
+                            vc = _attn.cache_append(vc, v, pos0,
+                                                    num_heads=kv_heads)
+                            outs = [_attn.cache_attend(q, kc, vc, pos + t,
+                                                       num_heads=heads,
+                                                       scale=scale,
+                                                       mesh_active=mesh_on,
+                                                       num_kv_heads=kv_heads)]
+                        # PATH_TAKEN, recorded at trace time: which decode-
+                        # attention path this predictor's programs actually
+                        # lowered — refines artifact meta so a shape-gated
+                        # fallback ("einsum-gated") never false-trips the
+                        # mxlint pallas-fallback error
+                        self._decode_path = _attn.DECODE_PATH["last"]
+                        new_caches.append((kc, vc))
+                else:
+                    if opname in _POSITION_BROADCAST_OPS and len(ins) == 2 \
+                            and getattr(ins[0], "ndim", 0) == 3 \
+                            and getattr(ins[1], "ndim", 0) == 3 \
+                            and ins[0].shape[1] != ins[1].shape[1] \
+                            and t in (ins[0].shape[1], ins[1].shape[1]):
+                        # learned positional table vs the (B, t, E) stream:
+                        # gather the rows for the CURRENT positions
+                        big_i = 0 if ins[0].shape[1] != t else 1
+                        big = ins[big_i]
+                        if big.shape[0] != 1:
+                            raise MXNetError(
+                                "decode: node %r mixes time-lengths %s "
+                                "without a broadcastable (1, S, E) side" %
+                                (node.name, (ins[0].shape, ins[1].shape)))
+                        s_len = big.shape[1]
+                        idx = (jnp.asarray(pos0, jnp.int32).reshape(-1, 1)
+                               + jnp.arange(t, dtype=jnp.int32)[None, :])
+                        idx = jnp.clip(idx, 0, s_len - 1)
+                        ins = list(ins)
+                        ins[big_i] = jnp.take(big[0], idx, axis=0)
+                    octx = OpContext(
+                        is_train=False,
+                        rng=jax.random.fold_in(base_key, seq),
+                        mesh_active=self._mesh is not None, mesh=self._mesh)
+                    outs, _ = node.op.fcompute(attrs, ins, aux_ins, octx)
             for i, o in enumerate(outs):
                 values[(id(node), i)] = o
         head_node, head_idx = self._symbol._outputs[0]
@@ -611,14 +629,16 @@ class DecodePredictor:
     def _sample(self, key, probs):
         import jax.numpy as jnp
 
+        from .obs.scopes import scope as _scope
         from .ops.sample import sample_tokens
 
-        if self._greedy:
-            # argmax(p) == argmax(log p): skip the log on the hot path
-            return jnp.argmax(probs, axis=-1).astype(jnp.int32)[:, None]
-        logits = jnp.log(probs.astype(jnp.float32) + 1e-30)
-        return sample_tokens(key, logits, self._temperature,
-                             self._top_k)[:, None]
+        with _scope("head_loss", "sample"):
+            if self._greedy:
+                # argmax(p) == argmax(log p): skip the log on the hot path
+                return jnp.argmax(probs, axis=-1).astype(jnp.int32)[:, None]
+            logits = jnp.log(probs.astype(jnp.float32) + 1e-30)
+            return sample_tokens(key, logits, self._temperature,
+                                 self._top_k)[:, None]
 
     def _policy_probs(self, probs):
         """The EXACT sampling distribution :meth:`_sample` draws from, as
@@ -2202,7 +2222,7 @@ class DecodeServer:
         self._m_ttft.observe(max(first - rec["submit"], 0.0))
         _prof.record_request(
             rec.get("admit", rec["submit"]) - rec["submit"],
-            first - rec["submit"], ntokens, now - first)
+            first - rec["submit"], ntokens, now - first, rid=rid)
         _obs.instant("retire", cat="serve",
                      args={"rid": rid, "tokens": int(ntokens)})
         self._done_rids.append(rid)
@@ -2461,6 +2481,7 @@ class DecodeServer:
             "act_mask": np.zeros(slots, np.int32),
             "pending": None,    # the one admission mid-chunked-prefill
             "blocked": 0,       # consecutive pool-gate-blocked ticks
+            "tick": 0,          # serve_tick calls (the serve.tick span's arg)
         }
         return self._ps
 
@@ -2731,12 +2752,24 @@ class DecodeServer:
         inactive rows masked.  Every device program here was traced
         once — page tables, active masks, slot indices, page ids and
         swapped page contents are all data.
+
+        Host phases land on the timeline as one ``serve.tick`` span with
+        the children ``serve.admit`` / ``serve.prefill`` /
+        ``serve.commit`` / ``serve.decode_dispatch`` / ``serve.readback``
+        (the wait for the device) / ``serve.deliver``
+        (docs/observability.md); a request's spans share its ``rid``.
         """
+        ps = self.serve_open()
+        ps["tick"] += 1
+        with _obs.span("serve.tick", cat="serve",
+                       args={"tick": ps["tick"]}):
+            self._tick(ps)
+
+    def _tick(self, ps):
         import jax
         import jax.numpy as jnp
 
         pred = self._pred
-        ps = self.serve_open()
         mgr = pred._manager
         slots = self._slots
         greedy = pred._greedy
@@ -2763,89 +2796,97 @@ class DecodeServer:
 
         deliver = self._deliver
 
-        # --- (1a) slot-full priority preemption: a waiter that OUTRANKS
-        # the lowest-priority resident evicts it even when the block is
-        # slots, not pages — priority scheduling; equal priorities keep
-        # the classic wait-for-retirement behavior
-        if ps["pending"] is None and len(active) >= slots:
-            self._preempt_for_waiter(ps, allow_bound=False)
-        # --- (1) admission gate: one request starts (or restores)
-        if ps["pending"] is None and self._queue and len(active) < slots:
-            got = self._admit_one(ps)
-            if got is None:
-                ps["blocked"] += 1
-                if not active:
-                    # nothing running to free pages: spill the whole
-                    # prefix cache, then the pool is genuinely too small
-                    if mgr.prefix_cache is not None:
-                        mgr.prefix_cache.evict(mgr.pool_pages)
-                        got = self._admit_one(ps)
-                    if got is None:
-                        raise MXNetError(
-                            "KV page pool (%d pages) cannot admit a "
-                            "%d-token request even with an empty batch — "
-                            "raise MXNET_KV_POOL_PAGES"
-                            % (mgr.pool_pages,
-                               self._queue[0]["prompt"].size))
-                else:
-                    # pool-gate preemption: a HIGHER-priority waiter
-                    # evicts immediately; any waiter evicts the
-                    # lowest-priority slot once the gate has blocked
-                    # MXNET_FLEET_DECODE_BOUND consecutive iterations.
-                    # The waiter admits on the freed pages and the
-                    # victim resumes bit-exactly
-                    got = self._preempt_for_waiter(ps, allow_bound=True)
-            if got is not None:
-                ps["blocked"] = 0
+        with _obs.span("serve.admit", cat="serve"):
+            # --- (1a) slot-full priority preemption: a waiter that OUTRANKS
+            # the lowest-priority resident evicts it even when the block is
+            # slots, not pages — priority scheduling; equal priorities keep
+            # the classic wait-for-retirement behavior
+            if ps["pending"] is None and len(active) >= slots:
+                self._preempt_for_waiter(ps, allow_bound=False)
+            # --- (1) admission gate: one request starts (or restores)
+            if ps["pending"] is None and self._queue and len(active) < slots:
+                got = self._admit_one(ps)
+                if got is None:
+                    ps["blocked"] += 1
+                    if not active:
+                        # nothing running to free pages: spill the whole
+                        # prefix cache, then the pool is genuinely too small
+                        if mgr.prefix_cache is not None:
+                            mgr.prefix_cache.evict(mgr.pool_pages)
+                            got = self._admit_one(ps)
+                        if got is None:
+                            raise MXNetError(
+                                "KV page pool (%d pages) cannot admit a "
+                                "%d-token request even with an empty batch "
+                                "— raise MXNET_KV_POOL_PAGES"
+                                % (mgr.pool_pages,
+                                   self._queue[0]["prompt"].size))
+                    else:
+                        # pool-gate preemption: a HIGHER-priority waiter
+                        # evicts immediately; any waiter evicts the
+                        # lowest-priority slot once the gate has blocked
+                        # MXNET_FLEET_DECODE_BOUND consecutive iterations.
+                        # The waiter admits on the freed pages and the
+                        # victim resumes bit-exactly
+                        got = self._preempt_for_waiter(ps, allow_bound=True)
+                if got is not None:
+                    ps["blocked"] = 0
         # --- (2) one prefill chunk of the in-flight admission
         if ps["pending"] is not None:
             p = ps["pending"]
             state = ps["state"]
             n = min(self._chunk_w, p["prompt"].size - p["pos"])
-            copies = mgr.ensure(p["slot"], p["pos"], p["pos"] + n)
-            caches = pred._run_forks(state.caches, copies) \
-                if copies else state.caches
-            sub = next_key()
-            _obs.instant("prefill_chunk", cat="serve",
-                         args={"slot": p["slot"], "pos": p["pos"],
-                               "tokens": int(n)})
-            with _obs.program_span("prefill"):
-                caches, probs, tok = pred._chunk_fn(
-                    pred._env, caches,
-                    jnp.asarray(mgr.tables[p["slot"]:p["slot"] + 1]),
-                    jnp.asarray(_pad_window(
-                        p["prompt"][p["pos"]:p["pos"] + n],
-                        self._chunk_w)),
-                    jnp.asarray([p["pos"]], jnp.int32),
-                    jnp.asarray([n], jnp.int32), sub)
-            ps["state"] = state = DecodeState(caches, state.lens,
-                                              state.tok)
-            p["pos"] += n
-            pred._chunk_widths.add(self._chunk_w)
+            where = {"rid": p["rid"], "slot": p["slot"], "pos": p["pos"],
+                     "tokens": int(n)}
+            with _obs.span("serve.prefill", cat="serve", args=where):
+                copies = mgr.ensure(p["slot"], p["pos"], p["pos"] + n)
+                caches = pred._run_forks(state.caches, copies) \
+                    if copies else state.caches
+                sub = next_key()
+                _obs.instant("prefill_chunk", cat="serve", args=where)
+                args = (pred._env, caches,
+                        jnp.asarray(mgr.tables[p["slot"]:p["slot"] + 1]),
+                        jnp.asarray(_pad_window(
+                            p["prompt"][p["pos"]:p["pos"] + n],
+                            self._chunk_w)),
+                        jnp.asarray([p["pos"]], jnp.int32),
+                        jnp.asarray([n], jnp.int32), sub)
+                # its dispatch wall accrues to the "prefill" row; only
+                # the scope map knows the chunk program by its own name
+                pred._roofline_register("prefill_chunk", pred._chunk_fn,
+                                        args, static=False)
+                with _obs.program_span("prefill"):
+                    caches, probs, tok = pred._chunk_fn(*args)
+                ps["state"] = state = DecodeState(caches, state.lens,
+                                                  state.tok)
+                p["pos"] += n
+                pred._chunk_widths.add(self._chunk_w)
             if p["pos"] >= p["prompt"].size:
                 # --- (3) commit: the slot joins the batch
-                slot, plen = p["slot"], p["prompt"].size
-                first = int(np.asarray(tok)[0, 0])
-                lens2, tok2 = pred._commit_fn(
-                    state.lens, state.tok, np.int32(slot),
-                    jnp.asarray([plen], jnp.int32), tok)
-                ps["state"] = DecodeState(state.caches, lens2, tok2)
-                mgr.publish(slot, p["prompt"], plen)
-                if proposer is not None \
-                        and getattr(proposer, "needs_prefill", False):
-                    ps["key"], sub = jax.random.split(ps["key"])
-                    proposer.admit(
-                        _pad_window(p["prompt"], self._max_prefill),
-                        plen, slot, slots, sub)
-                active[slot] = {"rid": p["rid"], "toks": [first],
-                                "cap": p["cap"], "prio": p["prio"],
-                                "prompt": p["prompt"]}
-                histories[slot] = list(p["prompt"]) + [first]
-                slot_lens[slot] = plen
-                act_mask[slot] = 1
-                self._req[p["rid"]]["first"] = time.time()
-                ps["pending"] = None
-                retire()        # a first-token EOS / cap-1 request
+                with _obs.span("serve.commit", cat="serve",
+                               args={"rid": p["rid"]}):
+                    slot, plen = p["slot"], p["prompt"].size
+                    first = int(np.asarray(tok)[0, 0])
+                    lens2, tok2 = pred._commit_fn(
+                        state.lens, state.tok, np.int32(slot),
+                        jnp.asarray([plen], jnp.int32), tok)
+                    ps["state"] = DecodeState(state.caches, lens2, tok2)
+                    mgr.publish(slot, p["prompt"], plen)
+                    if proposer is not None \
+                            and getattr(proposer, "needs_prefill", False):
+                        ps["key"], sub = jax.random.split(ps["key"])
+                        proposer.admit(
+                            _pad_window(p["prompt"], self._max_prefill),
+                            plen, slot, slots, sub)
+                    active[slot] = {"rid": p["rid"], "toks": [first],
+                                    "cap": p["cap"], "prio": p["prio"],
+                                    "prompt": p["prompt"]}
+                    histories[slot] = list(p["prompt"]) + [first]
+                    slot_lens[slot] = plen
+                    act_mask[slot] = 1
+                    self._req[p["rid"]]["first"] = time.time()
+                    ps["pending"] = None
+                    retire()        # a first-token EOS / cap-1 request
         self._note_gauges()
         if not active:
             return
@@ -2855,34 +2896,41 @@ class DecodeServer:
             and ps["pending"] is None \
             and max(slot_lens[s] for s in active) + k + 1 <= limit
         if can_spec:
-            hists = [histories.get(s) or [0] for s in range(slots)]
-            draft_toks, draft_probs = proposer.propose(
-                hists, ps["state"], slot_lens, sub)
-            sub = next_key()
-            state, out, counts = pred.paged_verify(
-                ps["state"], slot_lens, draft_toks, draft_probs, sub,
-                act_mask)
-            ps["state"] = state
-            out_h = np.asarray(out)
-            counts_h = np.asarray(counts).astype(np.int64)
-            self._note_step(spec=True)
-            for slot, rec in active.items():
-                emitted = out_h[slot, :counts_h[slot]]
-                self._note_accept(k, int(counts_h[slot]) - 1)
-                deliver(rec, emitted)
-                histories[slot].extend(int(t) for t in emitted)
-            slot_lens += counts_h
+            with _obs.span("serve.decode_dispatch", cat="serve"):
+                hists = [histories.get(s) or [0] for s in range(slots)]
+                draft_toks, draft_probs = proposer.propose(
+                    hists, ps["state"], slot_lens, sub)
+                sub = next_key()
+                state, out, counts = pred.paged_verify(
+                    ps["state"], slot_lens, draft_toks, draft_probs, sub,
+                    act_mask)
+                ps["state"] = state
+            with _obs.span("serve.readback", cat="serve"):
+                out_h = np.asarray(out)
+                counts_h = np.asarray(counts).astype(np.int64)
+            with _obs.span("serve.deliver", cat="serve"):
+                self._note_step(spec=True)
+                for slot, rec in active.items():
+                    emitted = out_h[slot, :counts_h[slot]]
+                    self._note_accept(k, int(counts_h[slot]) - 1)
+                    deliver(rec, emitted)
+                    histories[slot].extend(int(t) for t in emitted)
+                slot_lens += counts_h
+                retire()
         else:
-            state, _ = pred.paged_step(ps["state"], slot_lens, sub,
-                                       act_mask)
-            ps["state"] = state
-            toks = np.asarray(state.tok)[:, 0]
-            self._note_step()
-            for slot, rec in active.items():
-                deliver(rec, toks[slot:slot + 1])
-                histories[slot].append(int(toks[slot]))
-            slot_lens += act_mask.astype(np.int64)
-        retire()
+            with _obs.span("serve.decode_dispatch", cat="serve"):
+                state, _ = pred.paged_step(ps["state"], slot_lens, sub,
+                                           act_mask)
+                ps["state"] = state
+            with _obs.span("serve.readback", cat="serve"):
+                toks = np.asarray(state.tok)[:, 0]
+            with _obs.span("serve.deliver", cat="serve"):
+                self._note_step()
+                for slot, rec in active.items():
+                    deliver(rec, toks[slot:slot + 1])
+                    histories[slot].append(int(toks[slot]))
+                slot_lens += act_mask.astype(np.int64)
+                retire()
 
     def _note_gauges(self):
         """Refresh the per-host queue-depth / free-page gauges (the

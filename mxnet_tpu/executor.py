@@ -164,11 +164,13 @@ class Executor:
         import jax
 
         from . import profiler as _prof
+        from .obs.scopes import node_scope as _node_scope
 
         sym = self._symbol
         # per-node profiler spans are only meaningful when executing
         # eagerly on concrete values (under jit this loop runs once, at
-        # trace time); XLA-side op attribution comes from named_scope
+        # trace time); XLA-side op attribution comes from the layer scope
+        # (obs.scopes: mx.<layer>/<node name>, HLO metadata only)
         spans = False
         if _prof.is_running():
             probe = next(iter(env_args.values()), None)
@@ -207,7 +209,7 @@ class Executor:
                              mesh_active=getattr(self, "_mesh_active",
                                                  False),
                              mesh=getattr(self, "_mesh", None))
-            with jax.named_scope(node.name):
+            with _node_scope(node):
                 if spans:
                     with _prof.Scope(node.name):
                         outs, node_new_aux = node.op.fcompute(

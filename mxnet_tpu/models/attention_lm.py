@@ -13,17 +13,25 @@ from __future__ import annotations
 import numpy as np
 
 from .. import symbol as sym
+from ..base import AttrScope
+from ..obs.scopes import LAYER_ATTR
+
+# the element-wise nodes LayerNorm is built from would read as "other":
+# name their layer for the device-time breakdown (obs.scopes)
+_NORM = {LAYER_ATTR: "norm"}
+_HEAD = {LAYER_ATTR: "head_loss"}
 
 
 def _normalize(data):
     """The LayerNorm statistics half: (x - mean) / sqrt(var + eps) over
     the last axis, no affine — the gamma/beta tail rides either the
     broadcast ops (:func:`layer_norm`) or a FusedLNLinear segment."""
-    mean = sym.mean(data, axis=-1, keepdims=True)
-    centered = sym.broadcast_sub(data, mean)
-    var = sym.mean(sym.square(centered), axis=-1, keepdims=True)
-    inv = sym.rsqrt(var + 1e-5)
-    return sym.broadcast_mul(centered, inv)
+    with AttrScope(**_NORM):
+        mean = sym.mean(data, axis=-1, keepdims=True)
+        centered = sym.broadcast_sub(data, mean)
+        var = sym.mean(sym.square(centered), axis=-1, keepdims=True)
+        inv = sym.rsqrt(var + 1e-5)
+        return sym.broadcast_mul(centered, inv)
 
 
 def _ln_affine(name, embed):
@@ -38,7 +46,8 @@ def layer_norm(data, embed, name):
     scale/shift ride as learnable broadcast params via elementwise ops)."""
     normed = _normalize(data)
     gamma, beta = _ln_affine(name, embed)
-    return sym.broadcast_add(sym.broadcast_mul(normed, gamma), beta)
+    with AttrScope(**_NORM):
+        return sym.broadcast_add(sym.broadcast_mul(normed, gamma), beta)
 
 
 def block(data, embed, heads, ffn_hidden, name, moe_experts=0,
@@ -127,8 +136,9 @@ def get_symbol(vocab_size, seq_len, num_layers=2, embed=128, heads=4,
                     moe_capacity_factor=moe_capacity_factor,
                     moe_top_k=moe_top_k, num_kv_heads=num_kv_heads)
     net = layer_norm(net, embed, "final")
-    logits = sym.FullyConnected(sym.Reshape(net, shape=(-1, embed)),
-                                num_hidden=vocab_size, name="head")
-    flat_label = sym.Reshape(label, shape=(-1,))
-    return sym.SoftmaxOutput(logits, flat_label, use_ignore=True,
-                             ignore_label=-1, name="softmax")
+    with AttrScope(**_HEAD):
+        logits = sym.FullyConnected(sym.Reshape(net, shape=(-1, embed)),
+                                    num_hidden=vocab_size, name="head")
+        flat_label = sym.Reshape(label, shape=(-1,))
+        return sym.SoftmaxOutput(logits, flat_label, use_ignore=True,
+                                 ignore_label=-1, name="softmax")

@@ -243,46 +243,58 @@ class BaseModule:
                                       args={"epoch": int(epoch)}))
         with stack:
             while True:
-                t0 = time.perf_counter()
-                try:
-                    batch = next(it)
-                except StopIteration:
-                    break
-                _prof.record_input_wait(time.perf_counter() - t0)
-                if monitor is not None:
-                    monitor.tic()
-                self.forward_backward(batch)
-                self.update()
-                self.update_metric(eval_metric, batch.label)
-                fence = self._dispatch_fence()
-                if fence is not None:
-                    fences.append(fence)
-                    # at most `limit` dispatched-but-unfinished steps: with
-                    # limit=1 this waits on the step just issued
-                    # (synchronous)
-                    if len(fences) >= limit:
-                        t0 = time.perf_counter()
-                        _block_on(fences.popleft())
-                        _prof.record_host_wait(time.perf_counter() - t0)
-                if monitor is not None:
-                    monitor.toc_print()
-                _prof.record_step()
-                _fire(batch_end_callback,
-                      BatchEndParam(epoch, nbatch, eval_metric, locals()))
-                if self._elastic is not None:
-                    # fault injection, the periodic fence checkpoint, and
-                    # the liveness poll (which drains `fences` and raises
-                    # ReconfigureSignal when the mesh must re-form).  After
-                    # the callback, so user callbacks observe every
-                    # completed batch exactly once even across a resume.
-                    self._elastic.on_step(self, epoch, nbatch, fences)
-                nbatch += 1
+                # one fit_step span per iteration; its children are
+                # input_wait, the step's own train_step program span,
+                # metric_update, host_wait and batch_end_callback
+                with _obs.span("fit_step", cat="loop",
+                               args={"step": nbatch}):
+                    t0 = time.perf_counter()
+                    with _obs.mirror("input_wait"):
+                        try:
+                            batch = next(it)
+                        except StopIteration:
+                            break
+                    _prof.record_input_wait(time.perf_counter() - t0, t0)
+                    if monitor is not None:
+                        monitor.tic()
+                    self.forward_backward(batch)
+                    self.update()
+                    with _obs.span("metric_update", cat="loop"):
+                        self.update_metric(eval_metric, batch.label)
+                    fence = self._dispatch_fence()
+                    if fence is not None:
+                        fences.append(fence)
+                        # at most `limit` dispatched-but-unfinished steps:
+                        # with limit=1 this waits on the step just issued
+                        # (synchronous)
+                        if len(fences) >= limit:
+                            t0 = time.perf_counter()
+                            with _obs.mirror("host_wait"):
+                                _block_on(fences.popleft())
+                            _prof.record_host_wait(
+                                time.perf_counter() - t0, t0)
+                    if monitor is not None:
+                        monitor.toc_print()
+                    _prof.record_step()
+                    with _obs.span("batch_end_callback", cat="loop"):
+                        _fire(batch_end_callback,
+                              BatchEndParam(epoch, nbatch, eval_metric,
+                                            locals()))
+                    if self._elastic is not None:
+                        # fault injection, the periodic fence checkpoint,
+                        # and the liveness poll (which drains `fences` and
+                        # raises ReconfigureSignal when the mesh must
+                        # re-form).  After the callback, so user callbacks
+                        # observe every completed batch exactly once even
+                        # across a resume.
+                        self._elastic.on_step(self, epoch, nbatch, fences)
+                    nbatch += 1
         if fences:
             # steps chain through donated params, so the newest fence
             # transitively covers every outstanding step
             t0 = time.perf_counter()
             _block_on(fences[-1])
-            _prof.record_host_wait(time.perf_counter() - t0)
+            _prof.record_host_wait(time.perf_counter() - t0, t0)
             fences.clear()
         return time.time() - start
 

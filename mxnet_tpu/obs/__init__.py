@@ -10,12 +10,15 @@ Three layers (docs/observability.md), shared process-wide singletons:
   spans and instant events, exported as Chrome-trace JSON (Perfetto);
 * :data:`programs` — per-program roofline accounting
   (:mod:`~mxnet_tpu.obs.roofline`): measured dispatch wall per compiled
-  program joined against static FLOPs/bytes into the MFU table.
+  program joined against static FLOPs/bytes into the MFU table, and
+  each program's scope map (:mod:`~mxnet_tpu.obs.scopes`: which layer
+  every instruction of its optimized HLO belongs to).
 
 ``profiler`` (the historical module) is a thin compatibility facade over
 these; new code records here directly.  Instrumentation is HOST-side
-only: nothing in this package runs inside a traced program, so compiled
-HLO is byte-identical with telemetry on or off (``MXNET_TELEMETRY``),
+only: nothing in this package runs inside a traced program (the layer
+scopes are HLO metadata, and always there), so compiled HLO is
+byte-identical with telemetry on or off (``MXNET_TELEMETRY``),
 and the zero-overhead tripwire in ``tests/test_obs.py`` plus the
 analysis ``host-sync`` pass keep it that way.
 """
@@ -28,12 +31,13 @@ from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
 from .prom import MetricsServer
 from .roofline import (PEAK_FLOPS, ProgramAccounting, auto_peak,
                        peak_flops_for, render_mfu_table)
-from .trace import TraceTimeline
+from .trace import TraceTimeline, _annotation
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricsServer",
     "PEAK_FLOPS", "PeriodicExporter", "ProgramAccounting", "TraceTimeline",
-    "auto_peak", "enabled", "mfu_table", "peak_flops_for", "percentile",
+    "auto_peak", "enabled", "mfu_table", "mirror", "peak_flops_for",
+    "percentile",
     "program_span", "programs", "registry", "render_mfu_table",
     "serve_metrics", "span", "timeline",
 ]
@@ -83,22 +87,25 @@ _NULL = _NullCtx()
 
 class _ProgramSpan:
     """Times one compiled-program dispatch: feeds the roofline
-    accounting AND drops a span on the timeline (cat="program")."""
+    accounting AND drops a span on the timeline (cat="program"), off
+    one clock reading at each end."""
 
-    __slots__ = ("_name", "_t0", "_w0")
+    __slots__ = ("_name", "_t0", "_ann")
 
     def __init__(self, name):
         self._name = name
+        self._ann = _annotation(name, None)
 
     def __enter__(self):
-        self._t0 = time.perf_counter()
-        self._w0 = time.time()
+        self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        dt = time.perf_counter() - self._t0
-        programs.note(self._name, dt)
-        timeline.add_span(self._name, self._w0, dt, cat="program")
+        t1 = time.perf_counter_ns()
+        programs.note(self._name, (t1 - self._t0) * 1e-9)
+        timeline.add_span_ns(self._name, self._t0, t1, cat="program")
+        self._ann.__exit__(*exc)
         return False
 
 
@@ -109,8 +116,17 @@ def program_span(name):
 
 
 def span(name, cat="host", args=None):
-    """Context manager recording one timeline span (no-op when off)."""
-    return timeline.span(name, cat=cat, args=args) if enabled() else _NULL
+    """Context manager recording one timeline span, mirrored into the
+    profiler's trace as ``mx:<name>`` (no-op when off)."""
+    return timeline.span(name, cat=cat, args=args, mirror=True) \
+        if enabled() else _NULL
+
+
+def mirror(name):
+    """The ``mx:<name>`` annotation alone, for an interval whose timeline
+    span is recorded after the fact (``profiler.record_input_wait``): the
+    profiler's own trace then holds it too (no-op when off)."""
+    return _annotation(name, None) if enabled() else _NULL
 
 
 def instant(name, cat="event", args=None):

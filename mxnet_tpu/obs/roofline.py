@@ -25,6 +25,7 @@ never execute) and run at TABLE time, off every hot path.
 """
 from __future__ import annotations
 
+import logging
 import threading
 
 __all__ = ["ProgramAccounting", "PEAK_FLOPS", "peak_flops_for",
@@ -92,6 +93,8 @@ class ProgramAccounting:
         self._timing = {}   # name -> [calls, wall_s]
         self._probers = {}  # name -> () -> {"flops", "bytes"} | None
         self._static = {}   # name -> resolved {"flops", "bytes"} | error row
+        self._hlo = {}      # name -> () -> optimized HLO text | None
+        self._scopes = {}   # name -> (HLO module name, scope map)
 
     # ------------------------------------------------------------------
     def note(self, name, seconds):
@@ -113,6 +116,15 @@ class ProgramAccounting:
             self._probers[name] = prober
             self._static.pop(name, None)
 
+    def register_hlo(self, name, thunk):
+        """Attach a lazy reader of program ``name``'s optimized HLO text
+        (``() -> text | None``, weakly bound like the static probers;
+        the newest registration wins).  Read only by :meth:`scope_map`,
+        off every hot path."""
+        with self._lock:
+            self._hlo[name] = thunk
+            self._scopes.pop(name, None)
+
     def set_static(self, name, flops, bytes):
         """Directly record a program's static cost (mxstat --smoke, or a
         caller that already holds an artifact)."""
@@ -128,6 +140,8 @@ class ProgramAccounting:
             if clear_static:
                 self._probers.clear()
                 self._static.clear()
+                self._hlo.clear()
+                self._scopes.clear()
 
     # ------------------------------------------------------------------
     def _resolve_static(self, name):
@@ -154,6 +168,55 @@ class ProgramAccounting:
             # owner (a model's whole parameter store) for process life
             self._probers.pop(name, None)
         return cost
+
+    def _resolve_scopes(self, name):
+        """``(HLO module name, {instruction: "<layer>[/<sub>]"})`` of
+        program ``name`` (cached), or None while its program cannot be
+        read (never dispatched, owner collected)."""
+        with self._lock:
+            hit = self._scopes.get(name)
+            thunk = self._hlo.get(name)
+        if hit is not None or thunk is None:
+            return hit
+        try:
+            text = thunk()
+        except Exception as exc:    # a reader of telemetry never raises
+            logging.getLogger(__name__).warning(
+                "no scope map for program %r: %s", name, exc)
+            text = None
+        if not text:
+            return None
+        from .scopes import scope_map
+
+        hit = scope_map(text)
+        with self._lock:
+            self._scopes[name] = hit
+            self._hlo.pop(name, None)   # resolved: unpin the owner
+        return hit
+
+    def scope_map(self, name):
+        """``{instruction name: "<layer>[/<sub>]"}`` for the compiled
+        program ``name`` (``train_step``, ``paged_decode_step``, ...),
+        read from the optimized HLO of the executable its owner already
+        holds — the join between a device trace's instruction names and
+        the ``mx.<layer>`` scopes (:mod:`~mxnet_tpu.obs.scopes`).  None
+        before the program's first dispatch.  Computed when asked."""
+        hit = self._resolve_scopes(name)
+        return None if hit is None else hit[1]
+
+    def scope_maps(self):
+        """Every readable program's scope map, keyed by its HLO module's
+        own name (``jit_step``, ``jit__paged_decode_impl``) — the stem a
+        device trace prints on its ``XLA Modules`` line, within which
+        instruction names are unique."""
+        with self._lock:
+            names = sorted(set(self._hlo) | set(self._scopes))
+        out = {}
+        for name in names:
+            hit = self._resolve_scopes(name)
+            if hit is not None and hit[0]:
+                out.setdefault(hit[0], {}).update(hit[1])
+        return out
 
     def table(self, peak_flops=None):
         """The joined per-program rows, sorted by wall share (largest

@@ -13,14 +13,19 @@ loop interleave legibly) and nest naturally: complete ("X") events with
 overlapping [ts, ts+dur) on one thread render as a flame stack in any
 Chrome-trace viewer.  :meth:`TraceTimeline.export` writes the standard
 ``{"traceEvents": [...]}`` JSON — open it at ``chrome://tracing`` or
-https://ui.perfetto.dev — and merges any Chrome-format traces found in a
-``jax.profiler`` trace directory when one is given, so host spans and
-the XLA device timeline land in one file.
+https://ui.perfetto.dev.
+
+One clock: every stamp is ``time.perf_counter_ns()`` (monotonic; ``ts``
+is that reading in whole microseconds).  The framework's live spans
+(``obs.span`` / ``obs.program_span``) also enter a
+``jax.profiler.TraceAnnotation("mx:<name>")``, so a profiler session —
+``profiler.start()`` or anyone's ``jax.profiler.trace`` — holds the same
+spans in its own ``.xplane.pb`` beside the device's operations, on the
+device trace's clock by construction.  With no session active the
+annotation costs a flag test.
 """
 from __future__ import annotations
 
-import glob
-import gzip
 import json
 import os
 import threading
@@ -63,12 +68,21 @@ class TraceTimeline:
             self._total += 1
 
     def add_span(self, name, t0, dur, cat="host", tid=None, args=None):
-        """One complete ("X") event: ``t0`` epoch seconds, ``dur``
-        seconds.  Used both live (the :meth:`span` context manager) and
-        retroactively (``profiler.record_host_wait`` knows the duration
-        only after the wait)."""
+        """One complete ("X") event: ``t0`` a ``time.perf_counter()``
+        reading in seconds, ``dur`` seconds.  For spans recorded after
+        the fact (``profiler.record_host_wait`` knows the duration only
+        after the wait); live spans stamp nanoseconds themselves."""
+        self.add_span_ns(name, int(t0 * 1e9), int((t0 + dur) * 1e9),
+                         cat=cat, tid=tid, args=args)
+
+    def add_span_ns(self, name, t0_ns, t1_ns, cat="host", tid=None,
+                    args=None):
+        """One complete event between two ``time.perf_counter_ns()``
+        readings.  Both ends are floored to microseconds separately, so
+        a span that contains another in nanoseconds still does."""
+        ts = t0_ns // 1000
         ev = {"name": name, "cat": cat, "ph": "X",
-              "ts": int(t0 * 1e6), "dur": max(int(dur * 1e6), 0),
+              "ts": ts, "dur": max(t1_ns // 1000 - ts, 0),
               "pid": os.getpid(),
               "tid": tid if tid is not None else threading.get_ident()}
         if args:
@@ -80,16 +94,18 @@ class TraceTimeline:
         commits, COW forks, admissions/retirements, prefill-chunk
         windows.  ``scope`` "t"=thread, "p"=process, "g"=global."""
         ev = {"name": name, "cat": cat, "ph": "i", "s": scope,
-              "ts": int(time.time() * 1e6), "pid": os.getpid(),
+              "ts": time.perf_counter_ns() // 1000, "pid": os.getpid(),
               "tid": threading.get_ident()}
         if args:
             ev["args"] = dict(args)
         self._push(ev)
 
-    def span(self, name, cat="host", args=None):
+    def span(self, name, cat="host", args=None, mirror=False):
         """Context manager recording one complete event around the body
-        (nests: inner spans on the same thread stack in the viewer)."""
-        return _LiveSpan(self, name, cat, args)
+        (nests: inner spans on the same thread stack in the viewer).
+        ``mirror`` also enters ``TraceAnnotation("mx:<name>", **args)``
+        for the profiler's own trace."""
+        return _LiveSpan(self, name, cat, args, mirror)
 
     # ------------------------------------------------------------------
     def events(self):
@@ -105,17 +121,14 @@ class TraceTimeline:
     # ------------------------------------------------------------------
     # export
     # ------------------------------------------------------------------
-    def export(self, path=None, jax_trace_dir=None, extra_events=None):
+    def export(self, path=None, extra_events=None):
         """The Chrome-trace payload dict; written as JSON to ``path``
-        when given.  ``jax_trace_dir`` (the ``jax.profiler`` output
-        directory) is scanned for ``*.trace.json[.gz]`` files whose
-        ``traceEvents`` are merged in — host spans and the XLA device
-        timeline open as one Perfetto view."""
+        when given.  The ring only: the view joined with the device's
+        operations is the profiler's own file, which holds the same
+        spans as ``mx:<name>`` (see the module docstring)."""
         events = self.events()
         if extra_events:
             events.extend(extra_events)
-        if jax_trace_dir:
-            events.extend(_jax_trace_events(jax_trace_dir))
         payload = {"traceEvents": events, "displayTimeUnit": "ms"}
         if path is not None:
             with open(path, "w") as f:
@@ -123,47 +136,40 @@ class TraceTimeline:
         return payload
 
 
-class _LiveSpan:
-    __slots__ = ("_tl", "_name", "_cat", "_args", "_t0")
+_annotation_cls = None
 
-    def __init__(self, timeline, name, cat, args):
+
+def _annotation(name, args):
+    """``jax.profiler.TraceAnnotation("mx:<name>", **args)``; jax is
+    imported on first use, so the ring alone stays jax-free."""
+    global _annotation_cls
+    if _annotation_cls is None:
+        from jax.profiler import TraceAnnotation
+
+        _annotation_cls = TraceAnnotation
+    return _annotation_cls("mx:" + name, **args) if args \
+        else _annotation_cls("mx:" + name)
+
+
+class _LiveSpan:
+    __slots__ = ("_tl", "_name", "_cat", "_args", "_t0", "_ann")
+
+    def __init__(self, timeline, name, cat, args, mirror):
         self._tl = timeline
         self._name = name
         self._cat = cat
         self._args = args
+        self._ann = _annotation(name, args) if mirror else None
 
     def __enter__(self):
-        self._t0 = time.time()
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
-        self._tl.add_span(self._name, self._t0, time.time() - self._t0,
-                          cat=self._cat, args=self._args)
+        self._tl.add_span_ns(self._name, self._t0, time.perf_counter_ns(),
+                             cat=self._cat, args=self._args)
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
         return False
-
-
-def _jax_trace_events(trace_dir):
-    """Best-effort: Chrome-format trace events under a ``jax.profiler``
-    trace dir (TensorBoard layout writes ``*.trace.json.gz`` per host
-    alongside the xplane protobuf).  Unreadable files are skipped — the
-    merge must never break an export."""
-    events = []
-    for pattern in ("**/*.trace.json", "**/*.trace.json.gz"):
-        for fname in glob.glob(os.path.join(trace_dir, pattern),
-                               recursive=True):
-            try:
-                opener = gzip.open if fname.endswith(".gz") else open
-                with opener(fname, "rt") as f:
-                    payload = json.load(f)
-                found = payload.get("traceEvents") \
-                    if isinstance(payload, dict) else None
-                if found:
-                    # real events only: jax's writers pad with empty
-                    # objects, which downstream consumers index into
-                    events.extend(
-                        e for e in found
-                        if isinstance(e, dict) and e.get("ph")
-                        and ("name" in e or e["ph"] == "M"))
-            except (OSError, ValueError):
-                continue
-    return events

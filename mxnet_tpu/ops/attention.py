@@ -22,6 +22,7 @@ from typing import NamedTuple
 import numpy as np
 
 from ..attrs import Param, ParamSchema
+from ..obs.scopes import scope as _scope
 from ..registry import OpDef, register_op
 
 
@@ -90,29 +91,31 @@ def sdpa(q, k, v, num_heads=1, causal=False, scale=None, num_kv_heads=0):
         qh = q.reshape(b, tq, num_heads, hd)
         kh = k.reshape(b, tk, num_heads, hd)
         vh = v.reshape(b, tk, num_heads, ev // num_heads)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh,
-                            kh).astype(jnp.float32) * scale
-        if causal:
-            mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-            logits = jnp.where(mask[None, None], logits,
-                               jnp.finfo(jnp.float32).min)
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        p = jnp.exp(logits - m)
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
+        with _scope("attn", "scores"):
+            logits = jnp.einsum("bqhd,bkhd->bhqk", qh,
+                                kh).astype(jnp.float32) * scale
+            if causal:
+                mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+                logits = jnp.where(mask[None, None], logits,
+                                   jnp.finfo(jnp.float32).min)
+            m = jnp.max(logits, axis=-1, keepdims=True)
+            p = jnp.exp(logits - m)
+            p = p / jnp.sum(p, axis=-1, keepdims=True)
         out = jnp.einsum("bhqk,bkhe->bqhe", p.astype(vh.dtype), vh)
         return out.reshape(b, tq, ev)
     qh = q.reshape(b, tq, kvh, g, hd)
     kh = k.reshape(b, tk, kvh, hd)
     vh = v.reshape(b, tk, kvh, ev // kvh)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qh,
-                        kh).astype(jnp.float32) * scale
-    if causal:
-        mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
-        logits = jnp.where(mask[None, None, None], logits,
-                           jnp.finfo(jnp.float32).min)
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    p = jnp.exp(logits - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    with _scope("attn", "scores"):
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qh,
+                            kh).astype(jnp.float32) * scale
+        if causal:
+            mask = jnp.tril(jnp.ones((tq, tk), bool), k=tk - tq)
+            logits = jnp.where(mask[None, None, None], logits,
+                               jnp.finfo(jnp.float32).min)
+        m = jnp.max(logits, axis=-1, keepdims=True)
+        p = jnp.exp(logits - m)
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum("bhgqk,bkhe->bqhge", p.astype(vh.dtype), vh)
     return out.reshape(b, tq, num_heads * (ev // kvh))
 
@@ -228,30 +231,31 @@ def cache_append(cache, new, start_pos, num_heads=1):
     import jax
     import jax.numpy as jnp
 
-    if isinstance(cache, QuantKV):
-        qnew = quantize_kv(new, cache.data.dtype, num_heads)
-        return QuantKV(cache_append(cache.data, qnew.data, start_pos),
-                       cache_append(cache.scale, qnew.scale, start_pos))
-    b, t = new.shape[0], new.shape[1]
-    c = cache.shape[1]
-    start = jnp.broadcast_to(jnp.asarray(start_pos, jnp.int32).reshape(-1),
-                             (b,))
-    new = new.astype(cache.dtype)
-    if t == 1:
-        slot = start % c
-        zero = (jnp.int32(0),) * (new.ndim - 2)
-        return jax.vmap(
-            lambda buf, row, s: jax.lax.dynamic_update_slice(
-                buf, row, (s,) + zero))(cache, new, slot)
-    if t > c:
-        # only the latest C tokens can land; trimming BEFORE the scatter
-        # keeps the slot indices unique per row (scatter order with
-        # duplicate indices is backend-unspecified)
-        new = new[:, -c:]
-        start = start + (t - c)
-        t = c
-    pos = (start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]) % c
-    return cache.at[jnp.arange(b)[:, None], pos].set(new)
+    with _scope("attn", "kv_append"):
+        if isinstance(cache, QuantKV):
+            qnew = quantize_kv(new, cache.data.dtype, num_heads)
+            return QuantKV(cache_append(cache.data, qnew.data, start_pos),
+                           cache_append(cache.scale, qnew.scale, start_pos))
+        b, t = new.shape[0], new.shape[1]
+        c = cache.shape[1]
+        start = jnp.broadcast_to(jnp.asarray(start_pos, jnp.int32).reshape(-1),
+                                 (b,))
+        new = new.astype(cache.dtype)
+        if t == 1:
+            slot = start % c
+            zero = (jnp.int32(0),) * (new.ndim - 2)
+            return jax.vmap(
+                lambda buf, row, s: jax.lax.dynamic_update_slice(
+                    buf, row, (s,) + zero))(cache, new, slot)
+        if t > c:
+            # only the latest C tokens can land; trimming BEFORE the scatter
+            # keeps the slot indices unique per row (scatter order with
+            # duplicate indices is backend-unspecified)
+            new = new[:, -c:]
+            start = start + (t - c)
+            t = c
+        pos = (start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]) % c
+        return cache.at[jnp.arange(b)[:, None], pos].set(new)
 
 
 def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
@@ -269,8 +273,9 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
     b, tq, e = q.shape
     kvh, g = check_head_groups(num_heads, num_kv_heads, e,
                                where="sdpa_decode")
-    k_cache = dequantize_kv(k_cache, kvh)
-    v_cache = dequantize_kv(v_cache, kvh)
+    with _scope("attn", "kv_dequant"):
+        k_cache = dequantize_kv(k_cache, kvh)
+        v_cache = dequantize_kv(v_cache, kvh)
     c = k_cache.shape[1]
     ev = v_cache.shape[2]
     if ev % kvh != 0:
@@ -287,31 +292,34 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
         qh = q.reshape(b, tq, num_heads, hd)
         kh = k_cache.reshape(b, c, num_heads, hd)
         vh = v_cache.reshape(b, c, num_heads, ev // num_heads)
-        logits = jnp.einsum("bqhd,bkhd->bhqk", qh,
-                            kh).astype(jnp.float32) * scale
-        total = jnp.asarray(total_len, jnp.int32).reshape(-1, 1, 1, 1)
-        qpos = jnp.arange(tq, dtype=jnp.int32).reshape(1, 1, tq, 1)
-        limit = jnp.minimum(total - (tq - 1) + qpos, c)
-        slot = jnp.arange(c, dtype=jnp.int32).reshape(1, 1, 1, c)
-        logits = jnp.where(slot < limit, logits, jnp.finfo(jnp.float32).min)
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        p = jnp.exp(logits - m)
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
+        with _scope("attn", "scores"):
+            logits = jnp.einsum("bqhd,bkhd->bhqk", qh,
+                                kh).astype(jnp.float32) * scale
+            total = jnp.asarray(total_len, jnp.int32).reshape(-1, 1, 1, 1)
+            qpos = jnp.arange(tq, dtype=jnp.int32).reshape(1, 1, tq, 1)
+            limit = jnp.minimum(total - (tq - 1) + qpos, c)
+            slot = jnp.arange(c, dtype=jnp.int32).reshape(1, 1, 1, c)
+            logits = jnp.where(slot < limit, logits,
+                               jnp.finfo(jnp.float32).min)
+            m = jnp.max(logits, axis=-1, keepdims=True)
+            p = jnp.exp(logits - m)
+            p = p / jnp.sum(p, axis=-1, keepdims=True)
         out = jnp.einsum("bhqk,bkhe->bqhe", p.astype(vh.dtype), vh)
         return out.reshape(b, tq, ev)
     qh = q.reshape(b, tq, kvh, g, hd)
     kh = k_cache.reshape(b, c, kvh, hd)
     vh = v_cache.reshape(b, c, kvh, ev // kvh)
-    logits = jnp.einsum("bqhgd,bkhd->bhgqk", qh,
-                        kh).astype(jnp.float32) * scale
-    total = jnp.asarray(total_len, jnp.int32).reshape(-1, 1, 1, 1, 1)
-    qpos = jnp.arange(tq, dtype=jnp.int32).reshape(1, 1, 1, tq, 1)
-    limit = jnp.minimum(total - (tq - 1) + qpos, c)
-    slot = jnp.arange(c, dtype=jnp.int32).reshape(1, 1, 1, 1, c)
-    logits = jnp.where(slot < limit, logits, jnp.finfo(jnp.float32).min)
-    m = jnp.max(logits, axis=-1, keepdims=True)
-    p = jnp.exp(logits - m)
-    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    with _scope("attn", "scores"):
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qh,
+                            kh).astype(jnp.float32) * scale
+        total = jnp.asarray(total_len, jnp.int32).reshape(-1, 1, 1, 1, 1)
+        qpos = jnp.arange(tq, dtype=jnp.int32).reshape(1, 1, 1, tq, 1)
+        limit = jnp.minimum(total - (tq - 1) + qpos, c)
+        slot = jnp.arange(c, dtype=jnp.int32).reshape(1, 1, 1, 1, c)
+        logits = jnp.where(slot < limit, logits, jnp.finfo(jnp.float32).min)
+        m = jnp.max(logits, axis=-1, keepdims=True)
+        p = jnp.exp(logits - m)
+        p = p / jnp.sum(p, axis=-1, keepdims=True)
     out = jnp.einsum("bhgqk,bkhe->bqhge", p.astype(vh.dtype), vh)
     return out.reshape(b, tq, num_heads * (ev // kvh))
 
@@ -412,38 +420,40 @@ def paged_append(pool, table, new, start_pos, num_heads=1, active=None,
     """
     import jax.numpy as jnp
 
-    if isinstance(pool, QuantKV):
-        qnew = quantize_kv(new, pool.data.dtype, num_heads)
-        return QuantKV(
-            paged_append(pool.data, table, qnew.data, start_pos,
-                         active=active, valid=valid),
-            paged_append(pool.scale, table, qnew.scale, start_pos,
-                         active=active, valid=valid))
-    b, t = new.shape[0], new.shape[1]
-    m = table.shape[1]
-    pt = pool.shape[1]
-    c = m * pt
-    start = jnp.broadcast_to(jnp.asarray(start_pos, jnp.int32).reshape(-1),
-                             (b,))
-    new = new.astype(pool.dtype)
-    if t > c:
-        # only the latest C tokens can land (same trim as cache_append)
-        new = new[:, -c:]
-        start = start + (t - c)
-        t = c
-    pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]  # (B, t)
-    page = jnp.take_along_axis(table.astype(jnp.int32), (pos // pt) % m,
-                               axis=1)
-    write = jnp.ones((b, t), bool)
-    if active is not None:
-        write &= jnp.asarray(active).reshape(-1, 1).astype(bool)
-    if valid is not None:
-        write &= jnp.arange(t, dtype=jnp.int32)[None, :] \
-            < jnp.asarray(valid, jnp.int32).reshape(-1, 1)
-    page = jnp.where(write, page, 0)          # masked writes -> scratch
-    slot = pos % pt
-    return pool.at[page.reshape(-1), slot.reshape(-1)].set(
-        new.reshape(b * t, -1))
+    with _scope("attn", "kv_append"):
+        if isinstance(pool, QuantKV):
+            qnew = quantize_kv(new, pool.data.dtype, num_heads)
+            return QuantKV(
+                paged_append(pool.data, table, qnew.data, start_pos,
+                             active=active, valid=valid),
+                paged_append(pool.scale, table, qnew.scale, start_pos,
+                             active=active, valid=valid))
+        b, t = new.shape[0], new.shape[1]
+        m = table.shape[1]
+        pt = pool.shape[1]
+        c = m * pt
+        start = jnp.broadcast_to(jnp.asarray(start_pos, jnp.int32).reshape(-1),
+                                 (b,))
+        new = new.astype(pool.dtype)
+        if t > c:
+            # only the latest C tokens can land (same trim as cache_append)
+            new = new[:, -c:]
+            start = start + (t - c)
+            t = c
+        # (B, t)
+        pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+        page = jnp.take_along_axis(table.astype(jnp.int32), (pos // pt) % m,
+                                   axis=1)
+        write = jnp.ones((b, t), bool)
+        if active is not None:
+            write &= jnp.asarray(active).reshape(-1, 1).astype(bool)
+        if valid is not None:
+            write &= jnp.arange(t, dtype=jnp.int32)[None, :] \
+                < jnp.asarray(valid, jnp.int32).reshape(-1, 1)
+        page = jnp.where(write, page, 0)          # masked writes -> scratch
+        slot = pos % pt
+        return pool.at[page.reshape(-1), slot.reshape(-1)].set(
+            new.reshape(b * t, -1))
 
 
 def paged_copy(pool, src, dst):
@@ -523,9 +533,11 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
         DECODE_PATH["last"] = "einsum-gated"
     else:
         DECODE_PATH["last"] = "einsum"
-    return _sdpa_cache(q, paged_gather(k_pool, table),
-                       paged_gather(v_pool, table), total_len, num_heads,
-                       scale, num_kv_heads=num_kv_heads)
+    with _scope("attn", "kv_gather"):
+        k_view = paged_gather(k_pool, table)
+        v_view = paged_gather(v_pool, table)
+    return _sdpa_cache(q, k_view, v_view, total_len, num_heads, scale,
+                       num_kv_heads=num_kv_heads)
 
 
 def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,

@@ -6,9 +6,10 @@ store is now ``obs.timeline`` (an always-on bounded ring buffer), the loop
 counters live in ``obs.registry`` (typed metrics with JSON-lines and
 Prometheus exporters), and this module keeps the reference's API as a thin
 compatibility facade: ``dump_profile()`` still writes a chrome-trace JSON
-of whatever spans were recorded — now merged with the ``jax.profiler``
-trace directory when one was captured, so host spans and the XLA device
-timeline open as ONE Perfetto view.
+of whatever spans were recorded.  The view that joins them with the XLA
+device timeline is the ``jax.profiler`` capture ``start()`` makes beside
+it (``<filename stem>_xla/``): every ``obs.span`` is mirrored there as a
+``TraceAnnotation("mx:<name>")``, on the device trace's own clock.
 
 Thread-safety contract (this module's historical holes, now closed):
 ``profiler_set_state`` and ``dump_profile`` mutate/read shared state under
@@ -31,7 +32,7 @@ __all__ = ["profiler_set_config", "profiler_set_state", "dump_profile",
            "bump_recovery", "step_stats", "reset_step_stats"]
 
 _state = {"mode": "symbolic", "filename": "profile.json", "running": False,
-          "jax_trace_dir": None}
+          "jax_trace": False}
 _lock = threading.Lock()
 
 # ---------------------------------------------------------------------------
@@ -93,23 +94,27 @@ def _percentile(values, q):
     return _nearest_rank(values, q)
 
 
-def _loop_span(name, t0, dur):
+def _loop_span(name, seconds, t0=None, args=None):
     """Always-on loop span (host_wait/input_wait/ckpt_*/request) into the
-    bounded timeline; gated only by MXNET_TELEMETRY."""
+    bounded timeline; gated only by MXNET_TELEMETRY.  ``t0`` is the
+    ``time.perf_counter()`` reading the interval began at; without it
+    the span is taken to end now."""
     if _obs.enabled():
-        _obs.timeline.add_span(name, t0, dur, cat="loop")
+        if t0 is None:
+            t0 = time.perf_counter() - seconds
+        _obs.timeline.add_span(name, t0, seconds, cat="loop", args=args)
 
 
-def record_host_wait(seconds):
+def record_host_wait(seconds, t0=None):
     """Time the loop spent blocked on a device result (fence/metric sync)."""
     _c_host_wait.inc(seconds)
-    _loop_span("host_wait", time.time() - seconds, seconds)
+    _loop_span("host_wait", seconds, t0)
 
 
-def record_input_wait(seconds):
+def record_input_wait(seconds, t0=None):
     """Time the loop spent waiting for the input pipeline's next batch."""
     _c_input_wait.inc(seconds)
-    _loop_span("input_wait", time.time() - seconds, seconds)
+    _loop_span("input_wait", seconds, t0)
 
 
 def record_step(n=1):
@@ -134,7 +139,7 @@ def record_ckpt_stall(seconds):
     ``step_stats`` — the number async fenced checkpointing exists to
     drive toward zero."""
     _c_ckpt_stall.inc(seconds)
-    _loop_span("ckpt_stall", time.time() - seconds, seconds)
+    _loop_span("ckpt_stall", seconds)
 
 
 def record_ckpt_write(ms):
@@ -142,7 +147,7 @@ def record_ckpt_write(ms):
     inline): duration in milliseconds."""
     _c_ckpt_writes.inc()
     _g_last_ckpt_ms.set(float(ms))
-    _loop_span("ckpt_write", time.time() - ms / 1e3, ms / 1e3)
+    _loop_span("ckpt_write", ms / 1e3)
 
 
 def bump_recovery(n=1):
@@ -151,10 +156,13 @@ def bump_recovery(n=1):
     _c_recoveries.inc(n)
 
 
-def record_request(queue_wait_s, ttft_s, tokens, decode_s):
+def record_request(queue_wait_s, ttft_s, tokens, decode_s, rid=None):
     """One served request retired (decode.DecodeServer): time queued
     before admission, time to first token (from submit), tokens
-    delivered, and the wall time its post-first-token decode took."""
+    delivered, and the wall time its post-first-token decode took.  The
+    ``request`` span covers [submit, first token] and carries ``rid``,
+    the identifier its ``admit`` / ``prefill_chunk`` / ``retire`` events
+    share."""
     tokens = int(tokens)
     _c_requests.inc()
     _c_req_tokens.inc(tokens)
@@ -162,8 +170,10 @@ def record_request(queue_wait_s, ttft_s, tokens, decode_s):
     _h_ttft.observe(float(ttft_s))
     if tokens > 1:
         _h_decode_rate.observe((tokens - 1) / max(float(decode_s), 1e-9))
-    _loop_span("request", time.time() - max(float(ttft_s), 0.0),
-               max(float(ttft_s), 0.0))
+    ttft = max(float(ttft_s), 0.0)
+    _loop_span("request", ttft,
+               time.perf_counter() - max(float(decode_s), 0.0) - ttft,
+               args=None if rid is None else {"rid": rid})
 
 
 def reset_step_stats():
@@ -177,6 +187,9 @@ def reset_step_stats():
             m.reset()
         _obs.programs.reset()
         _t0 = time.time()
+    # the window's opening, on the timeline's clock: a reader of the ring
+    # finds the spans recorded since
+    _obs.instant("step_stats_reset", cat="loop")
 
 
 def step_stats():
@@ -243,12 +256,13 @@ def profiler_set_state(state="stop"):
             trace_dir = os.path.splitext(_state["filename"])[0] + "_xla"
             try:
                 jax.profiler.start_trace(trace_dir)
-                _state["jax_trace_dir"] = trace_dir
+                _state["jax_trace"] = True
             except Exception:  # profiling backend unavailable (CPU tests)
-                _state["jax_trace_dir"] = None
+                _state["jax_trace"] = False
         elif state == "stop" and _state["running"]:
             _state["running"] = False
-            if _state["jax_trace_dir"]:
+            if _state["jax_trace"]:
+                _state["jax_trace"] = False
                 try:
                     jax.profiler.stop_trace()
                 except Exception:
@@ -280,24 +294,24 @@ class Scope:
         self.category = category
 
     def __enter__(self):
-        self._t0 = time.time()
+        self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         if _state["running"] and _obs.enabled():
-            _obs.timeline.add_span(self.name, self._t0,
-                                   time.time() - self._t0,
-                                   cat=self.category)
+            _obs.timeline.add_span_ns(self.name, self._t0,
+                                      time.perf_counter_ns(),
+                                      cat=self.category)
         return False
 
 
 def dump_profile():
     """Write chrome-trace JSON (reference: profiler.py:46 dump_profile):
-    the current timeline ring contents, merged with any Chrome-format
-    traces the ``jax.profiler`` capture left in its trace directory."""
+    the current timeline ring contents.  The same spans, joined with the
+    device's operations on one clock, are in the ``jax.profiler`` capture
+    under ``<filename stem>_xla/`` (named ``mx:<span>``)."""
     with _lock:
-        _obs.timeline.export(_state["filename"],
-                             jax_trace_dir=_state["jax_trace_dir"])
+        _obs.timeline.export(_state["filename"])
 
 
 # reference env_var.md:71-79 — start profiling at library load

@@ -25,28 +25,30 @@ from __future__ import annotations
 import functools
 import logging
 import pickle
-import time
 
 import numpy as np
 
 from . import obs as _obs
 from .base import MXNetError
+from .obs.scopes import scope as _scope
 
 __all__ = ["CompiledTrainStep", "CompiledEvalStep"]
 
 
-def _weak_prober(step):
-    """A roofline static-cost prober that does NOT pin the step object
-    (and transitively its executor group + master weights) in the
-    process-global accounting: once the step is collected, the prober
-    resolves to None and the program's row simply keeps no statics."""
+def _weak_prober(step, method="roofline_static"):
+    """A lazy reader of ``step.<method>()`` (the roofline static cost; with
+    ``compiled_hlo``, the optimized HLO for ``obs.programs.scope_map``)
+    that does NOT pin the step object (and transitively its executor
+    group + master weights) in the process-global accounting: once the
+    step is collected, it resolves to None and the program's row simply
+    keeps no statics."""
     import weakref
 
     ref = weakref.ref(step)
 
     def prober():
         live = ref()
-        return live.roofline_static() if live is not None else None
+        return getattr(live, method)() if live is not None else None
 
     return prober
 
@@ -234,7 +236,8 @@ class CompiledEvalStep:
             arg_vals = [env[n] for n in exe._arg_names]
             outs, _ = exe._fwd_impl(arg_vals, aux, rng, False)
             labels = [data[n] for n in label_names]
-            return acc.update(mstate, labels, list(outs))
+            with _scope("metric"):
+                return acc.update(mstate, labels, list(outs))
 
         self._fn = jax.jit(step, donate_argnums=(2,))
         self._last_args = None   # aval snapshot for artifact probes
@@ -270,15 +273,8 @@ class CompiledEvalStep:
             _obs.programs.register_static(self.telemetry_name,
                                           _weak_prober(self))
             self._program_spec = _register_step_spec(self)
-        t0 = time.perf_counter()
-        w0 = time.time()
-        try:
+        with _obs.program_span(self.telemetry_name):
             return self._run_impl(data_batch)
-        finally:
-            dt = time.perf_counter() - t0
-            _obs.programs.note(self.telemetry_name, dt)
-            _obs.timeline.add_span(self.telemetry_name, w0, dt,
-                                   cat="program")
 
     def _run_impl(self, data_batch):
         from . import random as _rnd
@@ -685,19 +681,21 @@ class CompiledTrainStep:
                 cts = [jnp.ones_like(o) for o in outs]
                 (grads,) = vjp_fn(cts)
 
-                g_slabs = plan.pack(dict(zip(grad_names, grads)),
-                                    dtype_of_bucket=plan.grad_dtype)
-                hyp = jnp.concatenate([
-                    jnp.reshape(rescale, (1,)).astype(jnp.float32),
-                    jnp.reshape(clip, (1,)).astype(jnp.float32),
-                    extra.astype(jnp.float32)])
-                new_w, new_slot_slabs, new_wcast = plan.apply(
-                    w_slabs, g_slabs, slot_slabs, wcast, lrb, wdb, hyp)
+                with _scope("optimizer"):
+                    g_slabs = plan.pack(dict(zip(grad_names, grads)),
+                                        dtype_of_bucket=plan.grad_dtype)
+                    hyp = jnp.concatenate([
+                        jnp.reshape(rescale, (1,)).astype(jnp.float32),
+                        jnp.reshape(clip, (1,)).astype(jnp.float32),
+                        extra.astype(jnp.float32)])
+                    new_w, new_slot_slabs, new_wcast = plan.apply(
+                        w_slabs, g_slabs, slot_slabs, wcast, lrb, wdb, hyp)
                 new_aux = {n: v.astype(aux[n].dtype)
                            for n, v in zip(aux_names, new_aux_vals)}
                 if macc is not None:
                     labels = [data[n] for n in label_names]
-                    mstate = macc.update(mstate, labels, list(outs))
+                    with _scope("metric"):
+                        mstate = macc.update(mstate, labels, list(outs))
                 return (new_w, new_slot_slabs, new_aux, new_wcast, outs,
                         mstate)
 
@@ -728,23 +726,26 @@ class CompiledTrainStep:
 
             new_params = dict(params)
             new_slots = {}
-            for i, n in enumerate(grad_names):
-                g = grads[i].astype(params[n].dtype)
-                w, s = opt_apply(params[n], g, slots[n],
-                                 lrs[i], wds[i], rescale, clip, extra)
-                # float32 hyper scalars promote fp16/bf16 masters; cast the
-                # update back so param dtypes are stable across steps
-                new_params[n] = w.astype(params[n].dtype)
-                new_slots[n] = tuple(
-                    s_new.astype(s_old.dtype)
-                    for s_new, s_old in zip(s, slots[n]))
+            with _scope("optimizer"):
+                for i, n in enumerate(grad_names):
+                    g = grads[i].astype(params[n].dtype)
+                    w, s = opt_apply(params[n], g, slots[n],
+                                     lrs[i], wds[i], rescale, clip, extra)
+                    # float32 hyper scalars promote fp16/bf16 masters; cast
+                    # the update back so param dtypes are stable across
+                    # steps
+                    new_params[n] = w.astype(params[n].dtype)
+                    new_slots[n] = tuple(
+                        s_new.astype(s_old.dtype)
+                        for s_new, s_old in zip(s, slots[n]))
             new_aux = {n: v.astype(aux[n].dtype)
                        for n, v in zip(aux_names, new_aux_vals)}
             if macc is not None:
                 # metric accumulation reads the SAME outputs/labels the host
                 # path would; it feeds nothing back into the training math
                 labels = [data[n] for n in label_names]
-                mstate = macc.update(mstate, labels, list(outs))
+                with _scope("metric"):
+                    mstate = macc.update(mstate, labels, list(outs))
             return new_params, new_slots, new_aux, outs, mstate
 
         self.programs_built += 1
@@ -770,6 +771,8 @@ class CompiledTrainStep:
             self._static_registered = True
             _obs.programs.register_static(self.telemetry_name,
                                           _weak_prober(self))
+            _obs.programs.register_hlo(
+                self.telemetry_name, _weak_prober(self, "compiled_hlo"))
             self._program_spec = _register_step_spec(self)
             # the optimizer phase's own row: zero wall of its own (its
             # dispatch is inside train_step), but its priced bytes make
@@ -789,15 +792,8 @@ class CompiledTrainStep:
                     else "%s:lm_fused" % self.telemetry_name
                 _obs.programs.register_static(frow,
                                               _weak_fused_prober(self))
-        t0 = time.perf_counter()
-        w0 = time.time()
-        try:
+        with _obs.program_span(self.telemetry_name):
             return self._run_impl(data_batch, group)
-        finally:
-            dt = time.perf_counter() - t0
-            _obs.programs.note(self.telemetry_name, dt)
-            _obs.timeline.add_span(self.telemetry_name, w0, dt,
-                                   cat="program")
 
     def _run_impl(self, data_batch, group=None):
         from . import random as _rnd

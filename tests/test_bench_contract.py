@@ -685,20 +685,26 @@ def test_chip_smoke_phases_at_tiny_size():
 
 def test_compile_cache_helper_placement(monkeypatch, tmp_path):
     """``JAX_COMPILATION_CACHE_DIR`` places the cache from outside and the
-    helper then configures nothing; unset, the cache goes to the fixed
-    ``<checkout>/.jax_cache``.  (No backend is touched, nothing compiles.)"""
+    helper then configures no directory; unset, the cache goes to the fixed
+    ``<checkout>/.jax_cache``.  Either way the key takes in the HLO
+    metadata, so an executable read back carries this build's layer
+    scopes.  (No backend is touched, nothing compiles.)"""
     import jax
 
     from mxnet_tpu import cache_dirs
 
+    meta = "jax_compilation_cache_include_metadata_in_key"
     prior = jax.config.jax_compilation_cache_dir
+    prior_meta = getattr(jax.config, meta)
     try:
         monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
         assert cache_dirs.arm_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == prior
+        assert getattr(jax.config, meta) is True
         monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
         want = os.path.join(ROOT, ".jax_cache")
         assert cache_dirs.arm_compile_cache() == want
         assert jax.config.jax_compilation_cache_dir == want
     finally:
         jax.config.update("jax_compilation_cache_dir", prior)
+        jax.config.update(meta, prior_meta)

@@ -128,8 +128,26 @@ def test_ring_buffer_bound_under_sustained_spans():
     assert len(tl) == 0 and tl.dropped == 0
 
 
-def test_chrome_trace_schema_and_jax_merge(tmp_path):
-    import gzip
+def _profiled_host_names(logdir):
+    """Names of the host events in the newest ``.xplane.pb`` under a
+    ``jax.profiler`` trace directory."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = sorted(glob.glob(os.path.join(
+        logdir, "plugins", "profile", "*", "*.xplane.pb")))
+    assert paths, "the profiler session left no .xplane.pb"
+    names = set()
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                names.update(e.name for e in line.events)
+    return names
+
+
+def test_chrome_trace_schema_and_profiler_mirror(tmp_path):
+    import jax
 
     tl = TraceTimeline(capacity=1024)
     with tl.span("outer", cat="loop", args={"epoch": 0}):
@@ -140,19 +158,12 @@ def test_chrome_trace_schema_and_jax_merge(tmp_path):
                                                     1e-3))
     t.start()
     t.join()
-    # a fake jax.profiler capture to merge
-    jax_dir = tmp_path / "xla" / "plugins" / "host"
-    jax_dir.mkdir(parents=True)
-    with gzip.open(str(jax_dir / "h.trace.json.gz"), "wt") as f:
-        json.dump({"traceEvents": [
-            {"name": "xla-op", "ph": "X", "ts": 1, "dur": 2,
-             "pid": 1, "tid": 1}]}, f)
     out = str(tmp_path / "trace.json")
-    tl.export(out, jax_trace_dir=str(tmp_path / "xla"))
+    tl.export(out)
     payload = json.load(open(out))
     events = payload["traceEvents"]
-    assert {"outer", "inner", "commit", "other-thread", "xla-op"} \
-        <= {e["name"] for e in events}
+    assert {"outer", "inner", "commit", "other-thread"} \
+        == {e["name"] for e in events}
     tids = {e["tid"] for e in events if e["name"] in ("outer",
                                                       "other-thread")}
     assert len(tids) == 2          # thread-aware
@@ -168,6 +179,28 @@ def test_chrome_trace_schema_and_jax_merge(tmp_path):
     assert by["outer"]["ts"] <= by["inner"]["ts"]
     assert by["inner"]["ts"] + by["inner"]["dur"] \
         <= by["outer"]["ts"] + by["outer"]["dur"]
+    # one clock: a live span is stamped with perf_counter, not the epoch
+    import time
+    assert abs(by["outer"]["ts"] - time.perf_counter_ns() // 1000) < 60e6
+
+    # the mirror: a profiler session around obs.span / obs.program_span
+    # holds them as mx:<name> in its own .xplane.pb (the joined view);
+    # a plain TraceTimeline.span stays in its ring only
+    logdir = str(tmp_path / "xla")
+    jax.profiler.start_trace(logdir)
+    try:
+        with obs.span("serve.tick", cat="serve", args={"tick": 7}):
+            with obs.program_span("mirror-test-program"):
+                pass
+        with tl.span("ring-only"):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+    names = _profiled_host_names(logdir)
+    assert any(n.startswith("mx:serve.tick") for n in names), sorted(
+        n for n in names if "mx" in n)
+    assert "mx:mirror-test-program" in names
+    assert not any("ring-only" in n for n in names)
 
 
 # ---------------------------------------------------------------------------
@@ -200,8 +233,7 @@ def test_profiler_start_clears_stale_events(tmp_path):
         pass
     mx.profiler.profiler_set_state("stop")
     mx.profiler.dump_profile()
-    # merged jax.profiler events may be metadata records without a name
-    names = {e.get("name") for e in json.load(open(fname))["traceEvents"]}
+    names = {e["name"] for e in json.load(open(fname))["traceEvents"]}
     assert "fresh-span" in names
     assert "stale-span" not in names
 
@@ -318,3 +350,233 @@ def test_telemetry_off_records_nothing(telemetry):
     with obs.span("records"):
         pass
     assert len(obs.timeline) == before + 1
+
+
+# ---------------------------------------------------------------------------
+# layer scopes: every program's optimized HLO reads back as a scope map
+# ---------------------------------------------------------------------------
+def _lm_train_step():
+    from mxnet_tpu.analysis.programs import _LM, _drive_fused, _lm_symbol
+    from mxnet_tpu.io import DataBatch
+
+    d = _LM
+    mod = mx.mod.Module(_lm_symbol(), context=mx.cpu())
+    mod.bind(data_shapes=[mx.io.DataDesc("data", (d["batch"], d["seq_len"]),
+                                         layout="NT")],
+             label_shapes=[mx.io.DataDesc("softmax_label",
+                                          (d["batch"], d["seq_len"]),
+                                          layout="NT")])
+    mod.init_params(mx.initializer.Xavier())
+    mod.init_optimizer(optimizer="adam",
+                       optimizer_params={"learning_rate": 1e-3})
+    rng = np.random.RandomState(0)
+    toks = rng.randint(0, d["vocab"], (d["batch"], d["seq_len"]))
+    batch = DataBatch([mx.nd.array(toks.astype(np.float32))],
+                      [mx.nd.array(np.roll(toks, -1, 1).astype(np.float32))])
+    return _drive_fused(mod, batch, steps=2), "train_step"
+
+
+def _resnet_train_step():
+    from mxnet_tpu.analysis.programs import _drive_fused
+    from mxnet_tpu.io import DataBatch
+    from mxnet_tpu.models import resnet
+
+    sym = resnet.get_symbol(num_classes=4, num_layers=8,
+                            image_shape=(3, 16, 16))
+    mod = mx.mod.Module(sym, context=mx.cpu())
+    mod.bind(data_shapes=[("data", (2, 3, 16, 16))],
+             label_shapes=[("softmax_label", (2,))])
+    mod.init_params(mx.initializer.Xavier())
+    mod.init_optimizer(optimizer="sgd",
+                       optimizer_params={"learning_rate": 0.1,
+                                         "momentum": 0.9})
+    rng = np.random.RandomState(0)
+    batch = DataBatch(
+        [mx.nd.array(rng.uniform(-1, 1, (2, 3, 16, 16)).astype(np.float32))],
+        [mx.nd.array(rng.randint(0, 4, (2,)).astype(np.float32))])
+    return _drive_fused(mod, batch, steps=2), "train_step"
+
+
+def _paged_server(slots=2):
+    from mxnet_tpu.analysis.programs import _LM, _lm_params, _lm_symbol
+    from mxnet_tpu.decode import DecodePredictor, DecodeServer
+
+    d = _LM
+    sym = _lm_symbol()
+    pred = DecodePredictor(sym, _lm_params(sym, slots, d["seq_len"]),
+                           cache_len=d["seq_len"], temperature=0.0,
+                           kv_dtype="int8", paged=True, page_tokens=4,
+                           prefill_chunk=4)
+    return pred, DecodeServer(pred, max_prefill=12, slots=slots,
+                              max_new_tokens=3, spec_k=0)
+
+
+def _served(name):
+    pred, server = _paged_server()
+    rng = np.random.RandomState(1)
+    for n in (5, 7, 3):
+        server.submit(rng.randint(0, 32, size=(n,)))
+    assert len(server.run()) == 3
+    return pred, name
+
+
+_SCOPED_PROGRAMS = {
+    # program -> (builder, layers its map must hold)
+    "lm_train_step": (_lm_train_step, {
+        "attn", "attn/scores", "linear", "norm", "embed", "head_loss",
+        "optimizer", "other"}),
+    "resnet_train_step": (_resnet_train_step, {
+        "conv", "norm", "pool", "linear", "head_loss", "optimizer"}),
+    "paged_decode_step": (lambda: _served("paged_decode_step"), {
+        "attn", "attn/kv_append", "attn/kv_gather", "attn/kv_dequant",
+        "attn/scores", "linear", "norm", "embed", "head_loss"}),
+    "prefill_chunk": (lambda: _served("prefill_chunk"), {
+        "attn/kv_append", "attn/kv_gather", "attn/kv_dequant",
+        "attn/scores", "linear", "norm", "head_loss"}),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_SCOPED_PROGRAMS))
+def test_scope_map_names_every_instruction(program, telemetry):
+    """``obs.programs.scope_map``: every instruction of the program's
+    optimized HLO gets a layer of the one vocabulary, each layer the
+    model has is there, and reading it compiles nothing (the text comes
+    off the executable the program already dispatched)."""
+    import jax
+
+    from mxnet_tpu.obs import scopes
+
+    telemetry(True)
+    build, want = _SCOPED_PROGRAMS[program]
+    owner, name = build()      # keep the owner alive: the thunk is weak
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **_: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    smap = obs.programs.scope_map(name)
+    assert not compiles, "scope_map compiled %d program(s)" % len(compiles)
+    assert smap and obs.programs.scope_map(name) is smap     # cached
+    vocabulary = set(scopes.LAYERS) | {scopes.UNSCOPED} \
+        | {"attn/" + s for s in scopes.SUBSCOPES} | {"head_loss/sample"}
+    found = set(smap.values())
+    assert found <= vocabulary, found - vocabulary
+    assert want <= found, want - found
+    # keyed by the HLO module's own name for the device trace's join
+    stem = {"lm_train_step": "jit_step", "resnet_train_step": "jit_step",
+            "paged_decode_step": "jit__paged_decode_impl",
+            "prefill_chunk": "jit__chunk_impl"}[program]
+    assert smap.items() <= obs.programs.scope_maps()[stem].items()
+    # the scopes the framework traces are all read back: no mx.<layer>
+    # op_name falls to "unscoped"
+    fn_args = owner._static_args[name] if hasattr(owner, "_static_args") \
+        else None
+    text = owner._program_hlo(name) if fn_args else owner.compiled_hlo()
+    for line in text.splitlines():
+        m = re.match(r"^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=\s", line)
+        if m and re.search(r'op_name="[^"]*mx\.', line):
+            assert smap[m.group(1)] != scopes.UNSCOPED, line[:200]
+    del owner
+
+
+def test_scope_of_strips_the_backward_wrapper():
+    from mxnet_tpu.obs.scopes import scope_of
+
+    assert scope_of("jit(step)/mx.linear/layer0_q/dot_general") == "linear"
+    assert scope_of("jit(step)/transpose(jvp(mx.attn/att0))/mx.attn/scores"
+                    "/mul") == "attn/scores"
+    assert scope_of("jit(step)/transpose(jvp(mx.norm/bn1))/reduce_sum") \
+        == "norm"
+    assert scope_of("jit(step)/mx.optimizer/sub") == "optimizer"
+    # a node that happens to be named like a sub-scope: still its layer's
+    assert scope_of("jit(f)/mx.other/scores/add") == "other/scores"
+    assert scope_of("jit(step)/jvp()/reduce_sum") is None
+
+
+# ---------------------------------------------------------------------------
+# host phase spans
+# ---------------------------------------------------------------------------
+def _inside(child, parent):
+    return parent["ts"] <= child["ts"] and \
+        child["ts"] + child["dur"] <= parent["ts"] + parent["dur"]
+
+
+def test_serve_tick_spans_nest_and_share_the_request_id(telemetry):
+    telemetry(True)
+    pred, server = _paged_server()
+    rng = np.random.RandomState(2)
+    rid = server.submit(rng.randint(0, 32, size=(6,)))
+    obs.timeline.clear()
+    server.serve_reset()
+    server.serve_open()
+    while server.has_work:
+        server.serve_tick()
+    ev = obs.timeline.events()
+    ticks = [e for e in ev if e["name"] == "serve.tick"]
+    assert [e["args"]["tick"] for e in ticks] == \
+        list(range(1, len(ticks) + 1))
+    children = ("serve.admit", "serve.prefill", "serve.commit",
+                "serve.decode_dispatch", "serve.readback", "serve.deliver")
+    for name in children:
+        kids = [e for e in ev if e["name"] == name]
+        assert kids, name
+        for kid in kids:
+            assert sum(_inside(kid, t) for t in ticks) == 1, kid
+    # the dispatch spans hold the program spans
+    for prog, parent in (("prefill", "serve.prefill"),
+                         ("paged_decode_step", "serve.decode_dispatch")):
+        parents = [e for e in ev if e["name"] == parent]
+        for e in (e for e in ev if e["name"] == prog):
+            assert any(_inside(e, p) for p in parents), prog
+    # one identifier per request, on every event of its life
+    for name in ("admit", "prefill_chunk", "serve.prefill", "serve.commit",
+                 "retire", "request"):
+        got = [e["args"]["rid"] for e in ev if e["name"] == name]
+        assert got and set(got) == {rid}, (name, got)
+    # request = [submit, first token]: it ends where the commit ends
+    # (the first token's stamp), before the retire instant
+    req = next(e for e in ev if e["name"] == "request")
+    commit = next(e for e in ev if e["name"] == "serve.commit")
+    retire = next(e for e in ev if e["name"] == "retire")
+    end = req["ts"] + req["dur"]
+    assert commit["ts"] <= end <= retire["ts"]
+    assert abs(end - (commit["ts"] + commit["dur"])) < 50_000   # us
+
+
+def test_fit_step_spans_nest(telemetry):
+    telemetry(True)
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(
+        mx.sym.Variable("data"), num_hidden=4), name="softmax")
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.uniform(-1, 1, (12, 8)).astype(np.float32),
+                           rng.randint(0, 4, (12,)).astype(np.float32),
+                           batch_size=4)
+    seen = []
+    mod = mx.mod.Module(net, context=mx.cpu())
+    obs.timeline.clear()
+    mod.fit(it, num_epoch=1, optimizer="sgd",
+            batch_end_callback=lambda p: seen.append(p.nbatch))
+    ev = obs.timeline.events()
+    steps = [e for e in ev if e["name"] == "fit_step"]
+    # three batches, and the iteration that found the iterator empty
+    assert [e["args"]["step"] for e in steps] == [0, 1, 2, 3]
+    assert seen == [0, 1, 2]
+    epoch = next(e for e in ev if e["name"] == "fit_epoch")
+    assert all(_inside(s, epoch) for s in steps)
+    for name, count in (("input_wait", 3), ("train_step", 3),
+                        ("metric_update", 3), ("batch_end_callback", 3)):
+        kids = [e for e in ev if e["name"] == name]
+        assert len(kids) == count, (name, len(kids))
+        for kid, step in zip(kids, steps):
+            assert _inside(kid, step), (name, kid, step)
+    for kid in (e for e in ev if e["name"] == "host_wait"):
+        assert any(_inside(kid, s) for s in steps) or kid["ts"] >= \
+            steps[-1]["ts"]
+
+
+def test_loops_record_no_span_with_telemetry_off(telemetry):
+    telemetry(False)
+    pred, server = _paged_server()
+    server.submit(np.arange(5))
+    before = len(obs.timeline)
+    assert len(server.run()) == 1
+    assert len(obs.timeline) == before
