@@ -11,6 +11,12 @@ machine ``block_until_ready`` fences just as well — CHANGES.md, PR 19).
 
     python benchmarks/bench_flash_attention.py            # sweep
     python benchmarks/bench_flash_attention.py --train8k  # LM step, T=8192
+    python benchmarks/bench_flash_attention.py --crossover  # the dispatch's table
+
+``--crossover`` is the measurement behind ``ops.attention.FLASH_MIN_T`` and
+``pallas_attention``'s block constants: forward + backward at 8192 tokens a
+step for 32 heads of 64 and 16 heads of 128, then the kernel's block sizes
+swept at (4, 2048) for both widths; rows go to ``chiprun_out/attn_crossover.json``.
 """
 import os
 import sys
@@ -111,10 +117,7 @@ def train8k():
     import jax
     import jax.numpy as jnp
 
-    os.environ["MXNET_PALLAS_ATTENTION"] = "1"
     import mxnet_tpu as mx
-    from mxnet_tpu import config as _config
-    _config.refresh()
     from mxnet_tpu import symbol as sym
     from mxnet_tpu.ops.attention import PATH_TAKEN
 
@@ -154,6 +157,96 @@ def train8k():
     ex.backward()
     ex.grad_dict["q_weight"].asnumpy()
     print("steady-state step: %.1f ms" % ((time.perf_counter() - t0) * 1e3))
+
+
+def crossover():
+    """Forward + backward (``jax.grad`` of a sum, jitted, bf16, causal),
+    ``sdpa`` against ``sdpa_flash`` at 8192 tokens a step, and the block
+    sweep; the table the dispatch rule's thresholds are read from."""
+    import json
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import pallas_attention as pa
+    from mxnet_tpu.ops.attention import sdpa
+
+    on_tpu = jax.default_backend() == "tpu"
+    interp = not on_tpu
+    dev = jax.devices()[0]
+    shapes = [(32, 256), (16, 512), (8, 1024), (4, 2048)]
+    widths = [(32, 64), (16, 128)]
+    if interp:                          # CPU rehearsal: control flow only
+        shapes, widths = [(2, 128)], [(2, 64)]
+    out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
+           "table": [], "sweep_fwd": [], "sweep_bwd": []}
+
+    def qkv(b, t, e):
+        rng = np.random.RandomState(0)
+        return [jnp.asarray(rng.normal(size=(b, t, e)), jnp.bfloat16)
+                for _ in range(3)]
+
+    for heads, hd in widths:
+        for b, t in shapes:
+            q, k, v = qkv(b, t, heads * hd)
+
+            def grad_of(attend):
+                def loss(c, q_, k_, v_):
+                    return jnp.sum(attend(q_ * c, k_, v_)
+                                   .astype(jnp.float32))
+                return jax.jit(jax.grad(loss, argnums=(1, 2, 3)))
+
+            g_ein = grad_of(lambda q_, k_, v_: sdpa(
+                q_, k_, v_, num_heads=heads, causal=True))
+            g_fla = grad_of(lambda q_, k_, v_: pa.sdpa_flash(
+                q_, k_, v_, num_heads=heads, causal=True, scale=None,
+                interpret=interp))
+            row = {"heads": heads, "head_dim": hd, "b": b, "t": t,
+                   "einsum_ms": _bench(g_ein, q, k, v),
+                   "flash_ms": _bench(g_fla, q, k, v)}
+            row["flash_speedup"] = row["einsum_ms"] / row["flash_ms"]
+            ge = g_ein(jnp.float32(1), q, k, v)
+            gf = g_fla(jnp.float32(1), q, k, v)
+            row["grad_rel_err"] = max(
+                float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                      - b_.astype(jnp.float32)))
+                      / jnp.max(jnp.abs(a.astype(jnp.float32))))
+                for a, b_ in zip(ge, gf))
+            out["table"].append(row)
+            print(json.dumps(row), flush=True)
+
+    b, t = shapes[-1]
+    blocks = (128, 256, 512, 1024, 2048)
+    for heads, hd in widths:
+        q, k, v = [x.reshape(b, t, heads, hd).transpose(0, 2, 1, 3)
+                   .reshape(b * heads, t, hd) for x in qkv(b, t, heads * hd)]
+        scale = 1.0 / float(np.sqrt(hd))
+        o, lse = jax.jit(lambda q_, k_, v_: pa._fwd_call(
+            q_, k_, v_, scale, True, interp, with_lse=True))(q, k, v)
+        for bq in blocks[:3]:
+            for bk in blocks[1:]:
+                if bq > t or bk > t:
+                    continue
+                fwd = jax.jit(lambda c, q_, k_, v_, bq=bq, bk=bk:
+                              pa._fwd_call(q_ * c, k_, v_, scale, True,
+                                           interp, with_lse=True,
+                                           block_q=bq, block_k=bk))
+                bwd = jax.jit(lambda c, q_, k_, v_, bq=bq, bk=bk:
+                              pa._bwd_call(q_ * c, k_, v_, o, lse, o, scale,
+                                           True, interp, block_q=bq,
+                                           block_k=bk))
+                for key, fn in (("sweep_fwd", fwd), ("sweep_bwd", bwd)):
+                    row = {"head_dim": hd, "block_q": bq, "block_k": bk}
+                    try:
+                        row["ms"] = _bench(fn, q, k, v)
+                    except Exception as exc:  # VMEM overflow and the like
+                        row["error"] = str(exc).splitlines()[0][:200]
+                    out[key].append(row)
+                    print(key, json.dumps(row), flush=True)
+
+    os.makedirs("chiprun_out", exist_ok=True)
+    with open("chiprun_out/attn_crossover.json", "w") as f:
+        json.dump(out, f, indent=1)
 
 
 def ring_row():
@@ -241,5 +334,7 @@ if __name__ == "__main__":
         train8k()
     elif "--ring" in sys.argv:
         ring_row()
+    elif "--crossover" in sys.argv:
+        crossover()
     else:
         sweep()

@@ -7,8 +7,9 @@ LM trained fwd+bwd+update under a 2x2 grid —
 
 * ``kernels=off``  — the stock einsum/XLA graph (the baseline row);
 * ``kernels=on``   — ``MXNET_PALLAS_FUSED`` (LN->linear epilogue
-  segments), ``MXNET_PALLAS_ATTENTION`` (flash attention) and
-  ``MXNET_PALLAS_UPDATE`` (fused multi-tensor optimizer) all armed;
+  segments) and ``MXNET_PALLAS_UPDATE`` (fused multi-tensor optimizer)
+  armed (flash attention is no switch: ``dot_product_attention`` takes
+  it by shape in every row, ``ops.attention.flash_selected``);
 * ``blocks=default``   — each kernel's module-constant block shapes;
 * ``blocks=autotuned`` — ``MXNET_PALLAS_TUNE`` armed against a fresh
   tuning-cache directory, so every kernel's block shape resolves
@@ -107,7 +108,6 @@ def main():
                               "tuned" if autotuned else "default")
         overrides = {
             "MXNET_PALLAS_FUSED": kernels_on,
-            "MXNET_PALLAS_ATTENTION": kernels_on,
             "MXNET_PALLAS_UPDATE": kernels_on,
             "MXNET_PALLAS_INTERPRET": kernels_on and interp,
             "MXNET_PALLAS_TUNE": autotuned,

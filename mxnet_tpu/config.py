@@ -138,13 +138,6 @@ register("MXNET_ENGINE_TYPE", str, "",
          "debugging (reference src/engine/engine.cc:13-39).")
 register("MXNET_PROFILER_AUTOSTART", bool, False,
          "Start the profiler at import time (reference env_var.md:71-79).")
-register("MXNET_PALLAS_ATTENTION", bool, False,
-         "Use the Pallas flash-attention kernel for dot_product_attention "
-         "on supported shapes (self-attention, block-divisible T, head dim "
-         "multiple of 64): O(T) memory instead of the einsum path's O(T^2) "
-         "logits.  Differentiable (custom_vjp backward kernels), so "
-         "training takes the flash path too.  Falls back to einsum "
-         "otherwise.")
 register("MXNET_PALLAS_DECODE", bool, False,
          "Use the fused Pallas flash-decoding kernels "
          "(ops/pallas_decode.py) for decode/verify attention over KV "

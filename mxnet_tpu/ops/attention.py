@@ -468,11 +468,55 @@ def paged_copy(pool, src, dst):
     return pool.at[dst].set(pool[src])
 
 
-# Which path the last dot_product_attention dispatch traced: "flash" or
-# "einsum".  Written at trace time (dispatch happens under jit tracing), so
-# tests can assert the kernel path actually ran instead of silently
-# regressing to 100%-einsum (round-3 verdict, Weak #2).
+# Which path the last dot_product_attention dispatch traced: "flash",
+# "einsum" or "ring".  Written at trace time (dispatch happens under jit
+# tracing), so tests can assert the kernel path actually ran instead of
+# silently regressing to 100%-einsum (round-3 verdict, Weak #2).  The
+# counter mx_attn_dispatch_total{path=...} counts every such dispatch.
 PATH_TAKEN = {"last": None}
+
+# The smallest sequence length at which the flash kernel is taken, by head
+# width: the smallest measured T at which it ran forward + backward
+# >= 1.05x the einsum path (TPU v5 lite, jax 0.9.0, bf16, causal, 8192
+# tokens a step; benchmarks/bench_flash_attention.py --crossover, PR 26):
+#   32 heads of 64:  T 256 0.76x, 512 1.59x, 1024 2.53x, 2048 3.13x
+#   16 heads of 128: T 256 0.81x, 512 1.61x, 1024 2.56x, 2048 3.19x
+# Widths between and above take the row of the nearest measured width
+# below them.
+FLASH_MIN_T = {64: 512, 128: 512}
+
+
+def _note_path(path):
+    PATH_TAKEN["last"] = path
+    from .. import obs as _obs
+
+    _obs.registry.counter(
+        "mx_attn_dispatch_total",
+        "dot_product_attention nodes traced, by the path they took",
+        labels=("path",)).labels(path=path).inc()
+
+
+def flash_selected(q_shape, k_shape, causal, num_heads, num_kv_heads,
+                   mesh_active):
+    """``(take, interpret)``: whether ``dot_product_attention`` runs the
+    Pallas flash kernel for this call, decided from what the call shows.
+
+    All must hold: the backend is a TPU (or ``MXNET_PALLAS_INTERPRET``
+    forces the interpreter); no mesh shards the executor (the kernel is
+    opaque to GSPMD); ``pallas_attention.supported`` admits the shape;
+    and T has reached :data:`FLASH_MIN_T` for the head width.  Anything
+    else takes :func:`sdpa`."""
+    from . import pallas_attention as _pa
+
+    runs, interpret = _kernel_backend()
+    if mesh_active or not runs \
+            or not _pa.supported(q_shape, k_shape, causal, num_heads,
+                                 num_kv_heads=num_kv_heads):
+        return False, False
+    head_dim = q_shape[2] // num_heads
+    min_t = FLASH_MIN_T[max(w for w in FLASH_MIN_T if w <= head_dim)]
+    return q_shape[1] >= min_t, interpret
+
 
 # Same marker for the DECODE-side dispatch (paged_attend / cache_attend):
 # "pallas" when the fused flash-decoding kernel traced, "einsum" for the
@@ -487,20 +531,28 @@ PATH_TAKEN = {"last": None}
 DECODE_PATH = {"last": None}
 
 
+def _kernel_backend():
+    """``(runs, interpret)``: whether this backend can run a Pallas
+    attention kernel (a TPU natively, anything else only under
+    ``MXNET_PALLAS_INTERPRET``) and whether through the interpreter."""
+    import jax
+
+    from .. import config as _config
+
+    on_tpu = jax.default_backend() == "tpu"
+    interpret = bool(_config.get("MXNET_PALLAS_INTERPRET")) and not on_tpu
+    return on_tpu or interpret, interpret
+
+
 def decode_kernel_mode():
     """``(engage, interpret)`` for the fused decode kernel under the
     current config and backend: engaged when ``MXNET_PALLAS_DECODE`` is
-    set AND the backend can run it (TPU natively, anything else only
-    under ``MXNET_PALLAS_INTERPRET``)."""
+    set AND the backend can run it (:func:`_kernel_backend`)."""
     from .. import config as _config
 
     if not _config.get("MXNET_PALLAS_DECODE"):
         return False, False
-    import jax
-
-    interpret = bool(_config.get("MXNET_PALLAS_INTERPRET"))
-    on_tpu = jax.default_backend() == "tpu"
-    return (on_tpu or interpret), (interpret and not on_tpu)
+    return _kernel_backend()
 
 
 def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
@@ -700,33 +752,22 @@ def register_all():
                         double_buffer=dbuf, num_kv_heads=kv_heads),
                     mesh=octx.mesh, in_specs=(spec,) * 3, out_specs=spec,
                     check_vma=False)
-                PATH_TAKEN["last"] = "ring"
+                _note_path("ring")
                 return [ring(q, k, v)], []
 
         # single-chip fast path, training AND inference (the backward
-        # kernels + custom_vjp make pallas differentiable):
-        #  - it is opaque to GSPMD -> mesh-sharded executors take einsum
-        #    (which the partitioner splits over 'seq'); explicit-collective
-        #    long context uses parallel.ring instead;
-        #  - on non-TPU backends interpret mode would be a slow emulation,
-        #    so they take einsum too unless MXNET_PALLAS_INTERPRET forces
-        #    the kernel (tests exercise the real dispatch on CPU with it).
-        if not octx.mesh_active and _config.get("MXNET_PALLAS_ATTENTION"):
+        # kernels + custom_vjp make pallas differentiable), where the
+        # call's own shape says the kernel wins (flash_selected)
+        take, interpret = flash_selected(q.shape, k.shape, causal, heads,
+                                         kv_heads, octx.mesh_active)
+        if take:
             from . import pallas_attention as _pa
 
-            import jax
-
-            interpret = bool(_config.get("MXNET_PALLAS_INTERPRET"))
-            on_tpu = jax.default_backend() == "tpu"
-            if (on_tpu or interpret) \
-                    and _pa.supported(q.shape, k.shape, causal, heads,
-                                      num_kv_heads=kv_heads):
-                PATH_TAKEN["last"] = "flash"
-                out = _pa.sdpa_flash(q, k, v, heads, causal, scale,
-                                     interpret=interpret and not on_tpu,
-                                     num_kv_heads=kv_heads)
-                return [out], []
-        PATH_TAKEN["last"] = "einsum"
+            _note_path("flash")
+            return [_pa.sdpa_flash(q, k, v, heads, causal, scale,
+                                   interpret=interpret,
+                                   num_kv_heads=kv_heads)], []
+        _note_path("einsum")
         return [sdpa(q, k, v, num_heads=heads, causal=causal,
                      scale=scale, num_kv_heads=kv_heads)], []
 

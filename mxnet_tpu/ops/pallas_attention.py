@@ -22,8 +22,9 @@ broadcast across a 128-lane minor dimension — ``(BH, T, LANES)`` — so the
 backward kernels consume them with the same (rows, lanes) layout the MXU
 tiles want, and no kernel ever transposes a vector.
 
-Used by ``dot_product_attention`` when ``MXNET_PALLAS_ATTENTION`` enables
-it and shapes divide the block size; anything else falls back to the
+Used by ``dot_product_attention`` where ``ops.attention.flash_selected``
+says the call's shape wins with it (a TPU, no mesh, a supported shape, T at
+or past the measured crossover for the head width); anything else takes the
 einsum path.  ``interpret=True`` runs the same kernels on CPU for tests.
 """
 from __future__ import annotations
@@ -32,15 +33,22 @@ import functools
 
 import numpy as np
 
-# Block-size defaults for interpret/CPU mode (swept once on the bench
-# chip — TPU v5 lite, T=2k-8k: fwd favors small-Q/large-K streaming; bwd
-# favors a fatter Q block that amortizes the dQ/dK/dV accumulator
-# read-modify-writes).  On a live device the tuning cache
-# (ops/tuning.py) resolves per-(generation, shape-class, dtype) winners.
-BLOCK_Q = 128
-BLOCK_K = 512
-BLOCK_Q_BWD = 256
-BLOCK_K_BWD = 512
+# Block-size defaults, taken where the tuning cache (ops/tuning.py) holds no
+# winner for the (generation, shape-class, dtype) — a fresh checkout holds
+# none, .mxnet_programs/ is not in git.  Swept on TPU v5 lite, jax 0.9.0,
+# bf16 causal, (B, T) = (4, 2048), forward at (block_q, block_k) / backward
+# at (block_q_bwd, block_k_bwd) in ms
+# (benchmarks/bench_flash_attention.py --crossover, PR 26):
+#   32 heads of 64:  (128, 512) 6.51 / (256, 512) 9.07
+#                    (512, 1024) 3.69 / 6.86   (512, 2048) 3.56 / 6.88
+#   16 heads of 128: (128, 512) 2.77 / (256, 512) 3.86
+#                    (512, 1024) 1.33 / 2.97   (512, 2048) 1.38 / 3.06
+# (512, 1024) wins or ties at both widths, keeps the causal block skip at
+# T 2048 and asks half the VMEM of (512, 2048).
+BLOCK_Q = 512
+BLOCK_K = 1024
+BLOCK_Q_BWD = 512
+BLOCK_K_BWD = 1024
 LANES = 128
 MIN_BLOCK = 8
 
@@ -203,13 +211,23 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
             lse_ref[0] = m_fin + jnp.log(d_fin)
 
 
+@functools.lru_cache(maxsize=None)
+def _traced_once(fn):
+    """``fn`` under ``jax.jit``, its keyword-only arguments static.  A
+    model's N layers call the kernels with one set of shapes; jit's cache
+    then traces and lowers them once instead of N times (set-up time: the
+    4 layers of one LM step trace + lower in 0.08 s instead of 0.35 s on
+    the sandbox's CPU).  XLA inlines the call: the program is the same."""
+    import inspect
+
+    import jax
+
+    return jax.jit(
+        fn, static_argnames=inspect.getfullargspec(fn).kwonlyargs)
+
+
 def _fwd_call(q, k, v, scale, causal, interpret, with_lse, block_q=None,
               block_k=None, groups=1):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     bh, t, d = q.shape
     g = int(groups)
     if k.shape[0] * g != bh:
@@ -225,6 +243,17 @@ def _fwd_call(q, k, v, scale, causal, interpret, with_lse, block_q=None,
     if not bq or not bk:
         raise ValueError("flash_attention fwd blocks degenerate for T=%d "
                          "(callers must gate on supported())" % t)
+    return _traced_once(_fwd_kernel)(
+        q, k, v, scale=scale, causal=causal, interpret=interpret,
+        with_lse=with_lse, bq=bq, bk=bk, g=g)
+
+
+def _fwd_kernel(q, k, v, *, scale, causal, interpret, with_lse, bq, bk, g):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t, d = q.shape
     grid = (bh, t // bq, t // bk)
 
     # grouped K/V: folded Q batch index b encodes (batch, q-head) as
@@ -432,18 +461,12 @@ def _bwd_dkv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
 
 def _bwd_call(q, k, v, o, lse, do, scale, causal, interpret, block_q=None,
               block_k=None, groups=1):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
     bh, t, d = q.shape
     g = int(groups)
-    bh_kv = k.shape[0]
-    if bh_kv * g != bh:
+    if k.shape[0] * g != bh:
         raise ValueError(
             "flash_attention bwd: folded K/V batch %d * groups=%d != "
-            "folded Q batch %d" % (bh_kv, g, bh))
+            "folded Q batch %d" % (k.shape[0], g, bh))
     if block_q is None or block_k is None:
         cfg = _tuned(t, d, q.dtype, groups=g)
         block_q = block_q or cfg.get("block_q_bwd", BLOCK_Q_BWD)
@@ -453,6 +476,19 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, interpret, block_q=None,
     if not bq or not bk:
         raise ValueError("flash_attention bwd blocks degenerate for T=%d "
                          "(callers must gate on supported())" % t)
+    return _traced_once(_bwd_kernels)(
+        q, k, v, o, lse, do, scale=scale, causal=causal,
+        interpret=interpret, bq=bq, bk=bk, g=g)
+
+
+def _bwd_kernels(q, k, v, o, lse, do, *, scale, causal, interpret, bq, bk,
+                 g):
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    bh, t, d = q.shape
+    bh_kv = k.shape[0]
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)
@@ -693,11 +729,11 @@ def _tuning_candidates(shape_class, interpret):
         return [{"block_q": 128, "block_k": 128},
                 {"block_q": 128, "block_k": 256}]
     out = []
-    for bq in (128, 256):
-        for bk in (256, 512, 1024):
-            for bqb in (128, 256):
+    for bq in (128, 256, 512):
+        for bk in (512, 1024):
+            for bqb, bkb in ((256, 512), (512, 1024)):
                 out.append({"block_q": bq, "block_k": bk,
-                            "block_q_bwd": bqb, "block_k_bwd": 512})
+                            "block_q_bwd": bqb, "block_k_bwd": bkb})
     return out
 
 
