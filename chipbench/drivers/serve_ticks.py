@@ -35,11 +35,43 @@ def build_server(sym, traffic, params, ctx):
     return pred, server
 
 
-def check_against_reference(pred, cfg, traffic, params, seed):
+def weight_shapes(sym, cfg):
+    t = int(cfg["max_position_embeddings"])
+    arg_shapes, _, _ = sym.infer_shape(data=(1, t), softmax_label=(1, t))
+    return {n: s for n, s in zip(sym.list_arguments(), arg_shapes)
+            if n not in ("data", "softmax_label")}
+
+
+def reference_rows(cfg, traffic):
+    """``fwd(params, seq)``: the plain reference's logits, from one full
+    forward pass over ``check_prompt + check_decode`` tokens, at the
+    positions the comparison reads: the prompt's last and each decoded
+    one."""
+    ref = correct.reference_of(cfg)
+    plen = int(traffic["check_prompt"])
+    return jax.jit(lambda p, x: ref.forward(p, cfg, x)[0, plen - 1:])
+
+
+def control_case(cfg, traffic, seed):
+    """For ``chipbench.control``: the seeded weights, ``forward(params)``
+    as this driver's comparison calls the reference (over seeded tokens:
+    the run's own decoded ones are the system's), and the type the cell
+    computes in."""
+    params = weights.make_params(
+        weight_shapes(harness.build_symbol(cfg), cfg), cfg, seed,
+        cfg["serve_dtype"])
+    n = int(traffic["check_prompt"]) + int(traffic["check_decode"])
+    seq = traffic_mod.rng_of(seed, 4).integers(0, cfg["vocab_size"],
+                                               size=(1, n))
+    fwd = reference_rows(cfg, traffic)
+    return {"params": params, "forward": lambda p: fwd(p, seq),
+            "dtype": cfg["serve_dtype"]}
+
+
+def check_against_reference(pred, cfg, traffic, params, seed, atol):
     """Chunked prefill of one prompt, then ``check_decode`` decoded
     positions, through the paged pool and the very programs that serve —
     against the reference's one full forward pass over the same tokens."""
-    ref = correct.reference_of(cfg)
     slots, steps = int(traffic["slots"]), int(traffic["check_decode"])
     plen = int(traffic["check_prompt"])
     rng = traffic_mod.rng_of(seed, 4)
@@ -59,11 +91,8 @@ def check_against_reference(pred, cfg, traffic, params, seed):
         fed.append(int(np.asarray(state.tok)[0, 0]))
     del state
     seq = np.concatenate([prompt, np.asarray(fed[:-1])])[None, :]
-    fwd = jax.jit(lambda p, x: ref.forward(p, cfg, x)[0, plen - 1:])
-    key = cfg["family"] + (".int8_kv" if traffic["kv_dtype"] == "int8"
-                           else "")
-    return [correct.compare_logp(jnp.stack(got), fwd(params, seq),
-                                 correct.LOGP_ATOL[key])]
+    return [correct.compare_logp(
+        jnp.stack(got), reference_rows(cfg, traffic)(params, seq), atol)]
 
 
 def delivered(server):
@@ -78,11 +107,12 @@ def run(job):
     seed, tracer, counters = job["seed"], job["tracer"], job["counters"]
     seconds = job["seconds"]
     ctx = job["contexts"][0]
+    # read before anything is built: a configuration that states no limit
+    # for this pool's type fails here and not after the window
+    atol = correct.limit(cfg, "serve_ticks",
+                         "logp_atol." + traffic["kv_dtype"])
     sym = harness.build_symbol(cfg)
-    t = int(cfg["max_position_embeddings"])
-    arg_shapes, _, _ = sym.infer_shape(data=(1, t), softmax_label=(1, t))
-    shapes = {n: s for n, s in zip(sym.list_arguments(), arg_shapes)
-              if n not in ("data", "softmax_label")}
+    shapes = weight_shapes(sym, cfg)
     phases.mark("import_and_bind")
     params = weights.make_params(shapes, cfg, seed, cfg["serve_dtype"])
     jax.block_until_ready(params)
@@ -116,12 +146,18 @@ def run(job):
     job["memory"].sample()
     harness.quiesce()
     gc0 = harness.gc_counts()
+    # a traced window closes after `trace_ticks` ticks or `trace_seconds`,
+    # whichever comes first: the trace's size, and so the time it takes to
+    # stop, load and reduce, then does not grow as the tick gets shorter
+    max_ticks = min(MAX_TICKS, int(traffic.get("trace_ticks", MAX_TICKS))) \
+        if tracing else MAX_TICKS
     tracer.start()
+    phases.mark("trace_start")
     tokens0 = delivered(server)
     counters.window_open = True
     n = 0
     t0 = now = time.perf_counter()
-    while now - t0 < seconds and n < MAX_TICKS:
+    while now - t0 < seconds and n < max_ticks:
         active_before[n] = len(active)
         live_tokens[n] = int(lens.sum())
         if tracing:
@@ -134,9 +170,10 @@ def run(job):
         n += 1
     jax.block_until_ready(ps["state"])
     t1 = time.perf_counter()
+    phases.mark("window")
     counters.window_open = False
     tokens = delivered(server) - tokens0
-    tracer.stop()
+    tracer.stop(phases)
     gc1 = harness.gc_counts()
     job["memory"].sample()
     queue_left = len(server._queue)
@@ -147,7 +184,9 @@ def run(job):
     del active, lens, tick
     server.serve_reset()
     ps = None
-    checks = check_against_reference(pred, cfg, traffic, params, seed)
+    phases.mark("after_window")
+    checks = check_against_reference(pred, cfg, traffic, params, seed, atol)
+    phases.mark("check")
 
     stamps, active_before = stamps[:n], active_before[:n]
     live_tokens = live_tokens[:n]
@@ -174,7 +213,7 @@ def run(job):
         "setup_s": phases.since_start(t0),
         "attempted": len(results) + n_active, "failed": len(wrong_len),
         "checks": checks + [complete],
-        "trace": tracer.parsed,
+        "trace": tracer.parsed, "trace_bytes": tracer.trace_bytes,
         "facts": {"rate": rate, "ticks": n, "window_s": t1 - t0,
                   "slots": slots, "gap_p95_ms": p95, "gap_p50_ms": p50,
                   "mean_active": float(np.mean(active_before)),

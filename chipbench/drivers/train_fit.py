@@ -62,8 +62,9 @@ class Window:
     ``seconds`` have passed."""
 
     def __init__(self, fence, seconds, warmup, counters, tracer, source,
-                 phases, memory, max_steps=MAX_STEPS):
+                 phases, memory, counted, max_steps=MAX_STEPS):
         self._fence, self._seconds, self._warmup = fence, seconds, warmup
+        self._counted = counted
         self._counters, self._tracer, self._source = counters, tracer, source
         self._phases, self._memory = phases, memory
         self.stamps = [0.0] * max_steps
@@ -73,6 +74,7 @@ class Window:
         self.t0 = self.t1 = None
         self.stats = None
         self.gc0 = self.gc1 = None
+        self.counters_before = None
         self._span = None
 
     def __call__(self, param):
@@ -99,8 +101,10 @@ class Window:
         self._memory.sample()
         harness.quiesce()
         self.gc0 = harness.gc_counts()
+        self.counters_before = harness.program_counters(self._counted)
         profiler.reset_step_stats()
         self._tracer.start()
+        self._phases.mark("trace_start")
         if self._tracer.on:
             self._span = self._tracer.span("fit_step")
             self._span.__enter__()
@@ -111,6 +115,7 @@ class Window:
     def _close(self):
         self._fence()
         self.t1 = time.perf_counter()
+        self._phases.mark("window")
         self.state = 2
         self._counters.window_open = False
         if self._span is not None:
@@ -120,49 +125,13 @@ class Window:
         self.gc1 = harness.gc_counts()
         self._memory.sample()
         self._source.stop = True
-        self._tracer.stop()
+        self._tracer.stop(self._phases)
 
 
-def check_against_reference(mod, cfg, traffic, batch, layers, seeded):
-    """The system's forward pass on one of the run's own batches against the
-    plain reference on the same weights: the first ``check_samples`` images
-    (logits and loss), or the first ``check_tokens`` positions of the first
-    sequence.  A configuration with ``check_is_train`` (BatchNorm) is
-    compared in training mode, statistics over the whole batch on both
-    sides: with seeded moving statistics an evaluation-mode ResNet
-    saturates, and the batch's own are what the cell trains with.
-
-    Made after the window, with the module set back to the seeded weights
-    (``seeded()`` makes them again): the weights the window leaves depend
-    on how long it ran, and a trained net's larger logits carry a larger
-    bf16 error, so only the seeded ones give one reading to hold a limit
-    to."""
-    ref = correct.reference_of(cfg)
-    training = bool(cfg.get("check_is_train", False))
-    params, arg_params, aux_params = seeded()
-    mod.set_params(arg_params, aux_params)
-    mod.forward(batch, is_train=training)
-    probs = mod.get_outputs()[0].data
-    data, label = batch.data[0].data, batch.label[0].data
-    if "seq_len" in traffic:
-        n = min(int(traffic.get("check_tokens", 256)), data.shape[1])
-        toks = data[:1, :n]
-        fwd = jax.jit(lambda p, x: ref.forward(p, cfg, x, layers)[0])
-        out = correct.compare_logp(probs[:n], fwd(params, toks),
-                                   correct.LOGP_ATOL[cfg["family"]])
-        return [out]
-    n = min(int(traffic.get("check_samples", 8)), data.shape[0])
-    fwd = jax.jit(lambda p, x: ref.forward(p, cfg, x, layers,
-                                           training=training)[:n])
-    logits = fwd(params, data if training else data[:n])
-    return [correct.compare_logp(probs[:n], logits,
-                                 correct.LOGP_ATOL[cfg["family"]]),
-            correct.compare_loss(probs[:n], logits, label[:n])]
-
-
-def run(job):
-    cfg, traffic, phases = job["config"], job["traffic"], job["phases"]
-    seed, tracer = job["seed"], job["tracer"]
+def model_of(cfg, traffic):
+    """``(sym, layers, dshape, lshape, layout, args, auxs)``: the symbol at
+    the cell's size, the shapes of a batch, and the shapes of the weights
+    and of the auxiliary states."""
     layers = cfg[traffic["layers_key"]] if "layers_key" in traffic else None
     overrides = {}
     if layers is not None:
@@ -171,20 +140,97 @@ def run(job):
         overrides["seq_len"] = int(traffic["seq_len"])
     sym = harness.build_symbol(cfg, **overrides)
     dshape, lshape, layout = _shapes(cfg, traffic)
+    arg_shapes, _, aux_shapes = sym.infer_shape(data=dshape,
+                                                softmax_label=lshape)
+    args = {n: s for n, s in zip(sym.list_arguments(), arg_shapes)
+            if n not in ("data", "softmax_label")}
+    auxs = dict(zip(sym.list_auxiliary_states(), aux_shapes))
+    return sym, layers, dshape, lshape, layout, args, auxs
+
+
+def reference_rows(cfg, traffic, layers):
+    """``(n, fwd)``: ``fwd(params, data)`` is the plain reference's logits
+    over the part of a batch that the comparison reads, ``n`` rows of the
+    system's output: the first ``check_tokens`` positions of the first
+    sequence, or the first ``check_samples`` images.  A configuration with
+    ``check_is_train`` (BatchNorm) is computed in training mode, statistics
+    over the whole batch on both sides: with seeded moving statistics an
+    evaluation-mode ResNet saturates, and the batch's own are what the cell
+    trains with."""
+    ref = correct.reference_of(cfg)
+    if "seq_len" in traffic:
+        n = min(int(traffic.get("check_tokens", 256)),
+                int(traffic["seq_len"]))
+        return n, jax.jit(
+            lambda p, x: ref.forward(p, cfg, x[:1, :n], layers)[0])
+    training = bool(cfg.get("check_is_train", False))
+    n = min(int(traffic.get("check_samples", 8)), int(traffic["batch"]))
+    return n, jax.jit(lambda p, x: ref.forward(
+        p, cfg, x if training else x[:n], layers, training=training)[:n])
+
+
+def check_against_reference(mod, cfg, traffic, batch, layers, seeded,
+                            limits):
+    """The system's forward pass on one of the run's own batches against the
+    plain reference on the same weights (``reference_rows``): log-
+    probabilities, and for images the loss.
+
+    Made after the window, with the module set back to the seeded weights
+    (``seeded()`` makes them again): the weights the window leaves depend
+    on how long it ran, and a trained net's larger logits carry a larger
+    bf16 error, so only the seeded ones give one reading to hold a limit
+    to."""
+    params, arg_params, aux_params = seeded()
+    mod.set_params(arg_params, aux_params)
+    mod.forward(batch, is_train=bool(cfg.get("check_is_train", False)))
+    probs = mod.get_outputs()[0].data
+    n, fwd = reference_rows(cfg, traffic, layers)
+    logits = fwd(params, batch.data[0].data)
+    checks = [correct.compare_logp(probs[:n], logits, limits["logp_atol"])]
+    if "loss_rtol" in limits:
+        checks.append(correct.compare_loss(
+            probs[:n], logits, batch.label[0].data[:n],
+            limits["loss_rtol"]))
+    return checks
+
+
+def control_case(cfg, traffic, seed):
+    """For ``chipbench.control``: the seeded weights, ``forward(params)``
+    as this driver's comparison calls the reference on the run's first
+    batch, and the type the cell computes in."""
+    _, layers, _, _, _, args, auxs = model_of(cfg, traffic)
+    params = weights.make_params(dict(args, **auxs), cfg, seed,
+                                 cfg["master_dtype"])
+    data, _ = traffic_mod.train_batches(traffic, cfg, seed)()[0]
+    _, fwd = reference_rows(cfg, traffic, layers)
+    return {"params": params, "forward": lambda p: fwd(p, data),
+            "dtype": cfg["compute_dtype"]}
+
+
+def run(job):
+    cfg, traffic, phases = job["config"], job["traffic"], job["phases"]
+    seed, tracer = job["seed"], job["tracer"]
+    # what the program's counters held before this run traced anything
+    counted = harness.program_counters()
+    # the limits this run's comparison needs, read before anything is built:
+    # a configuration that states none fails here and not after the window
+    limits = {k: correct.limit(cfg, "train_fit", k) for k in (
+        ("logp_atol",) if "seq_len" in traffic
+        else ("logp_atol", "loss_rtol"))}
+    sym, layers, dshape, lshape, layout, args, auxs = model_of(cfg, traffic)
     if layout:
         descs = ([DataDesc("data", dshape, layout=layout)],
                  [DataDesc("softmax_label", lshape, layout=layout)])
     else:
         descs = ([DataDesc("data", dshape)],
                  [DataDesc("softmax_label", lshape)])
+    # a traffic file's `mesh` (axis sizes) is how a cell shards anything
+    # but the batch; without it Module spreads the batch over the contexts
+    mesh = {} if "mesh" not in traffic else {
+        "mesh_config": mx.parallel.MeshConfig(**traffic["mesh"])}
     mod = mx.mod.Module(sym, context=job["contexts"],
-                        compute_dtype=cfg["compute_dtype"])
+                        compute_dtype=cfg["compute_dtype"], **mesh)
     mod.bind(data_shapes=descs[0], label_shapes=descs[1])
-    arg_shapes, _, aux_shapes = sym.infer_shape(data=dshape,
-                                                softmax_label=lshape)
-    args = {n: s for n, s in zip(sym.list_arguments(), arg_shapes)
-            if n not in ("data", "softmax_label")}
-    auxs = dict(zip(sym.list_auxiliary_states(), aux_shapes))
     phases.mark("import_and_bind")
 
     ctx0 = job["contexts"][0]
@@ -214,7 +260,8 @@ def run(job):
     source = PoolIter(placed, batch, descs[0], descs[1])
     window = Window(lambda: jax.block_until_ready(mod._fused_step.params),
                     job["seconds"], int(traffic["warmup_steps"]),
-                    job["counters"], tracer, source, phases, job["memory"])
+                    job["counters"], tracer, source, phases, job["memory"],
+                    counted)
     metric = mx.metric.create("ce")
     opt = traffic["optimizer"]
     mod.fit(source, eval_metric=metric, num_epoch=1, optimizer=opt["name"],
@@ -225,8 +272,10 @@ def run(job):
     assert mod._fused_step is not None, "fused train step not active"
     # after the window: the reference costs no set-up time and shares no
     # memory with the step program's peak
+    phases.mark("after_window")
     checks = check_against_reference(mod, cfg, traffic, placed[0], layers,
-                                     seeded)
+                                     seeded, limits)
+    phases.mark("check")
 
     mean_loss = float(metric.get()[1])
     finite = bool(math.isfinite(mean_loss))
@@ -238,7 +287,7 @@ def run(job):
         "setup_s": phases.since_start(window.t0),
         "attempted": steps, "failed": 0 if finite else steps,
         "checks": checks + [{"ok": finite, "mean_loss_all_steps": mean_loss}],
-        "trace": tracer.parsed,
+        "trace": tracer.parsed, "trace_bytes": tracer.trace_bytes,
         "facts": {"rate": rate, "steps": steps, "step_stats": window.stats,
                   "window_s": window.t1 - window.t0, "batch": batch},
         "side": {
@@ -253,5 +302,6 @@ def run(job):
             "gc_collections_in_window": [b - a for a, b in
                                          zip(window.gc0, window.gc1)],
             "mean_loss_all_steps": mean_loss,
+            "program_counters_before_window": window.counters_before,
         },
     }

@@ -5,7 +5,6 @@ file.  Nothing here knows a cell by name.
 from __future__ import annotations
 
 import gc
-import importlib
 import json
 import os
 import time
@@ -77,8 +76,7 @@ class CompileCounters:
 def build_symbol(cfg, **overrides):
     """The system's own model builder, named by the configuration file and
     fed the configuration's sizes."""
-    mod_name, fn_name = cfg["builder"].split(":")
-    builder = getattr(importlib.import_module(mod_name), fn_name)
+    builder = manifest.load_named(cfg["builder"])
     kwargs = {arg: cfg[key] for arg, key in cfg["symbol_args"].items()}
     for k, v in kwargs.items():
         if isinstance(v, list):
@@ -104,6 +102,7 @@ class Tracer:
         self.dir = os.path.join(OUT_DIR, "trace-%s-%d" % (name, os.getpid()))
         self._window = None
         self.parsed = None
+        self.trace_bytes = None
 
     def start(self):
         if not self.on:
@@ -123,10 +122,11 @@ class Tracer:
 
         return jax.profiler.TraceAnnotation("chipbench:" + what)
 
-    def stop(self):
+    def stop(self, phases):
         """Close the trace and keep it, reduced (``trace.parse``'s dict), as
         ``self.parsed``.  Called right after the window's closing clock
-        reading, so that the trace holds the window and little else."""
+        reading, so that the trace holds the window and little else.  What
+        stopping and what loading cost go to ``phases``."""
         if not self.on:
             return
         import shutil
@@ -137,7 +137,11 @@ class Tracer:
 
         self._window.__exit__(None, None, None)
         jax.profiler.stop_trace()
-        self.parsed = trace.load(trace.find_xplane(self.dir))
+        phases.mark("trace_stop")
+        path = trace.find_xplane(self.dir)
+        self.trace_bytes = os.path.getsize(path)
+        self.parsed = trace.load(path)
+        phases.mark("trace_load")
         if os.environ.get("CHIPBENCH_KEEP_TRACE"):
             print("trace kept in %s" % self.dir, flush=True)
         else:
@@ -183,6 +187,29 @@ def write_side_file(workload, seed, payload):
     with open(path, "w") as f:
         json.dump(payload, f, indent=1, default=float)
     return path
+
+
+def program_counters(since=None):
+    """``{"name{label=value,...}": count}`` of the program's own labelled
+    counters (``obs.registry``) that have counted anything: which path a
+    dispatch took, by its label.  With ``since``, an earlier reading, only
+    what was counted after it: a driver reads once as it starts and again
+    as its window opens (before the step statistics' reset zeroes them), so
+    the side file holds this run's counts whatever the process did before."""
+    from mxnet_tpu import obs
+
+    out = {}
+    for name, fam in obs.registry.snapshot().items():
+        if fam["type"] != "counter" or not fam["label_names"]:
+            continue
+        for row in fam["series"]:
+            labels = ",".join("%s=%s" % kv
+                              for kv in sorted(row["labels"].items()))
+            key = "%s{%s}" % (name, labels)
+            value = row["value"] - (since or {}).get(key, 0)
+            if value:
+                out[key] = value
+    return out
 
 
 def gc_counts():

@@ -7,10 +7,12 @@ knows a cell, a model or a metric by name.
 """
 from __future__ import annotations
 
+import importlib
 import importlib.util
 import json
 import os
 import re
+import time
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 HERE = "chipbench"
@@ -18,6 +20,11 @@ HERE = "chipbench"
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = ("device_trace", "program_span", "program_counter", "host_clock")
+# all that `reduced` may name: how many layers are run, or how many experts
+# are held here (the chip's share of a stated deployment).  Any other key is
+# refused, so that a width under a name nobody foresaw is refused too
+REDUCIBLE = re.compile(r"^(\w+_)?(num|n)_(\w+_)?(layers?|experts?)$")
+NAMED = re.compile(r"^[A-Za-z_][\w.]*:[A-Za-z_]\w*$")
 TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
             "end_to_end", "per_layer"}
 
@@ -25,6 +32,13 @@ TOP_KEYS = {"command", "paths", "run_seconds", "configs", "workloads",
 def load_json(root, rel):
     with open(os.path.join(root, rel)) as f:
         return json.load(f)
+
+
+def load_named(spec):
+    """The object a data file names as ``"package.module:attribute"``: a
+    configuration's ``builder`` and each of its ``counts``."""
+    mod, _, attr = spec.partition(":")
+    return getattr(importlib.import_module(mod), attr)
 
 
 def load_manifest(root=ROOT):
@@ -80,15 +94,84 @@ def load_reader(metric, root=ROOT):
     return mod.read
 
 
-def read_layer_metrics(metrics, facts, root=ROOT):
+def read_layer_metrics(metrics, facts, root=ROOT, seconds=None):
     """``{name: {"value", "unit"}}`` for every reader that found something
-    to read; a reader that returns None leaves its metric out."""
+    to read; a reader that returns None leaves its metric out.  ``seconds``,
+    a dict, receives what each reader took."""
     out = {}
     for m in metrics:
+        began = time.perf_counter()
         value = load_reader(m["name"], root)(facts)
+        if seconds is not None:
+            seconds[m["name"]] = time.perf_counter() - began
         if value is not None:
             out[m["name"]] = {"value": float(value), "unit": m["unit"]}
     return out
+
+
+def driver_path(driver):
+    return "%s/drivers/%s.py" % (HERE, driver)
+
+
+def config_problems(entry, cfg, drivers):
+    """What is wrong with one configuration's file, given the loop drivers
+    of the cells that run it: it names its builder, its reference and its
+    counts; it states a limit, with the reason, for every driver that
+    compares it with the reference; and each key it ``reduced`` is a count
+    of layers or of experts (``REDUCIBLE``) and stands in the file at its published value beside the cut (a key
+    that ends in ``_<key>``, no greater)."""
+    bad, name = [], entry["name"]
+    if not NAMED.match(str(cfg.get("builder", ""))):
+        bad.append("config %s builder %r" % (name, cfg.get("builder")))
+    if not isinstance(cfg.get("reference"), str):
+        bad.append("config %s names no reference" % name)
+    counts = cfg.get("counts")
+    if not isinstance(counts, dict) or not counts or not all(
+            NAMED.match(str(v)) for v in counts.values()):
+        bad.append("config %s counts %r" % (name, counts))
+    stated = cfg.get("limits")
+    for driver in sorted(drivers):
+        limits = stated.get(driver) if isinstance(stated, dict) else None
+        if not isinstance(limits, dict) or not limits:
+            bad.append("config %s states no limit for driver %s"
+                       % (name, driver))
+            continue
+        for key, lim in limits.items():
+            value = lim.get("value") if isinstance(lim, dict) else None
+            if isinstance(value, bool) or not isinstance(
+                    value, (int, float)) or value < 0 or not lim.get("why"):
+                bad.append("config %s limit %s.%s %r"
+                           % (name, driver, key, lim))
+    for key in entry["reduced"]:
+        if not REDUCIBLE.match(key):
+            bad.append("config %s reduces %s: no count of layers or of "
+                       "experts" % (name, key))
+        cuts = [k for k in cfg if k != key and k.endswith("_" + key)]
+        if key not in cfg or not cuts or any(
+                not isinstance(cfg[k], int) or cfg[k] > cfg[key]
+                for k in cuts):
+            bad.append("config %s reduced key %s: the file needs the "
+                       "published value and the cut (<prefix>_%s) side by "
+                       "side" % (name, key, key))
+    return bad
+
+
+def traffic_problems(cell, traffic, root):
+    bad = []
+    driver = traffic.get("driver", "")
+    if not NAME.match(str(driver)) or not os.path.exists(
+            os.path.join(root, driver_path(driver))):
+        bad.append("workload %s driver %r" % (cell, driver))
+    mesh = traffic.get("mesh")
+    if mesh is not None and not (
+            isinstance(mesh, dict) and mesh and all(
+                isinstance(v, int) and not isinstance(v, bool)
+                for v in mesh.values())):
+        bad.append("workload %s mesh %r" % (cell, mesh))
+    ticks = traffic.get("trace_ticks")
+    if ticks is not None and (not isinstance(ticks, int) or ticks < 1):
+        bad.append("workload %s trace_ticks %r" % (cell, ticks))
+    return bad
 
 
 def validate(manifest, root=ROOT):
@@ -126,7 +209,7 @@ def validate(manifest, root=ROOT):
     files = [c["file"] for c in manifest["configs"]]
     if len(set(files)) != len(files):
         bad.append("two configurations share a file")
-    pairs = set()
+    pairs, drivers = set(), {}
     for w in manifest["workloads"]:
         if set(w) != {"name", "config", "traffic", "chips", "why"}:
             bad.append("workload %s keys" % w["name"])
@@ -139,12 +222,23 @@ def validate(manifest, root=ROOT):
         if not NAME.match(w["traffic"]) or not os.path.exists(
                 os.path.join(root, traffic_path(w["traffic"]))):
             bad.append("workload %s traffic %s" % (w["name"], w["traffic"]))
+        else:
+            traffic = load_json(root, traffic_path(w["traffic"]))
+            bad += traffic_problems(w["name"], traffic, root)
+            drivers.setdefault(w["config"], set()).add(
+                str(traffic.get("driver")))
         if (w["config"], w["traffic"]) in pairs:
             bad.append("pair %s/%s twice" % (w["config"], w["traffic"]))
         pairs.add((w["config"], w["traffic"]))
         used.add(w["config"])
     if used != set(names["configs"]):
         bad.append("unused configs %s" % sorted(set(names["configs"]) - used))
+    for c in manifest["configs"]:
+        if os.path.exists(os.path.join(root, c["file"])):
+            bad += config_problems(c, load_json(root, c["file"]),
+                                   drivers.get(c["name"], ()))
+    if not 1 <= len(cells) <= 24:
+        bad.append("%d cells" % len(cells))
     four = sum(w["chips"] == 4 for w in manifest["workloads"])
     if four > max(1, len(cells) // 4):
         bad.append("%d four-chip cells of %d" % (four, len(cells)))

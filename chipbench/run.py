@@ -96,7 +96,7 @@ def main(argv=None):
             "failed": result["failed"], "device": device}
     side = dict(result["side"], workload=args.workload, seed=args.seed,
                 trace=args.trace, setup_s=result["setup_s"],
-                setup_split_s=phases.seconds, checks=result["checks"],
+                checks=result["checks"],
                 compile_s=counters.compile_s, compiles=counters.compiles,
                 cache_hits=counters.cache_hits,
                 compiles_in_window=counters.in_window,
@@ -115,16 +115,34 @@ def main(argv=None):
                      compile_s=counters.compile_s,
                      compiles_in_window=counters.in_window,
                      memory_peak_bytes=device["memory_peak_bytes"])
-        line["metrics"] = manifest.read_layer_metrics(loaded["per_layer"],
-                                                      facts)
+        reader_s = {}
+        line["metrics"] = manifest.read_layer_metrics(
+            loaded["per_layer"], facts, seconds=reader_s)
+        phases.mark("read_layer_metrics")
         line["breakdown"] = {"device_ops": trace.top_ops(parsed),
                              "idle_gaps": trace.idle_gaps(parsed)}
+        phases.mark("breakdown")
         side["modules"] = trace.module_names(parsed)
         side["per_layer"] = line["metrics"]
+        side["trace_events"] = trace.event_counts(parsed)
+        side["trace_file_bytes"] = result["trace_bytes"]
+        side["reader_s"] = reader_s
     else:
         values = dict(result["end_to_end"], setup_s=result["setup_s"])
         line["metrics"] = {n: {"value": float(values[n]), "unit": u}
                            for n, u in units.items()}
+    # where the process's seconds went: the set-up phases up to the opening
+    # fence, and from the window on (a traced run: stopping, loading and
+    # reducing the trace; both: the comparison with the reference)
+    names = list(phases.seconds)
+    cut = names.index("window") if "window" in names else len(names)
+    side["setup_split_s"] = {k: phases.seconds[k] for k in names[:cut]}
+    side["run_split_s"] = dict(
+        {k: phases.seconds[k] for k in names[cut:]},
+        process_s=harness.process_age_s())
+    print("run split (s): %s" % json.dumps(
+        {k: round(v, 2) for k, v in side["run_split_s"].items()}),
+        flush=True)
     print("side file: %s" % harness.write_side_file(
         args.workload, args.seed, side), flush=True)
     print("checks: %s" % json.dumps(result["checks"]), flush=True)

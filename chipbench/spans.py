@@ -162,9 +162,7 @@ def children(al, names):
 def device_busy_inside(parsed, intervals):
     """ns of the first device's operation time inside the disjoint, sorted
     ``intervals`` ``[(lo, hi)]``."""
-    first = parsed["devices"][sorted(parsed["devices"])[0]]
-    ops = trace.merge(trace.spans(first[trace.OPS_LINE]))
-    return sum(trace.length(trace.clip(ops, a, b)) for a, b in intervals)
+    return sum(trace.busy_inside(parsed, a, b) for a, b in intervals)
 
 
 def innermost(spans):
@@ -204,9 +202,7 @@ def idle_by_span(facts, loop):
         return None
     parsed = facts["trace"]
     lo, hi = trace.window_of(parsed)
-    first = parsed["devices"][sorted(parsed["devices"])[0]]
-    gaps = trace.subtract([(lo, hi)],
-                          trace.merge(trace.spans(first[trace.OPS_LINE])))
+    gaps = trace.first_gaps(parsed, lo, hi)
     every = sorted(al["tops"] + al["spans"], key=lambda x: (x[1], -x[2]))
     out, named, j = {}, 0, 0
     for name, a, b in innermost(every):       # both lists sorted, disjoint

@@ -12,6 +12,8 @@ loop bodies ``chipbench:<what>``.
 """
 from __future__ import annotations
 
+import bisect
+import heapq
 import re
 
 OPS_LINE = "XLA Ops"
@@ -103,6 +105,52 @@ def subtract(a, b):
 
 def spans(events):
     return [(s, s + d) for _, s, d in events]
+
+
+def first_busy(trace):
+    """``(starts, ends, before)`` of the first device's merged operation
+    intervals, ``before[i]`` the busy ns ahead of interval i: computed once
+    per trace and kept in it, so that a reader which asks about every tick
+    costs the trace's events once and not once a tick."""
+    if "_first_busy" not in trace:
+        first = trace["devices"][sorted(trace["devices"])[0]]
+        merged = merge(spans(first[OPS_LINE]))
+        before, total = [], 0
+        for lo, hi in merged:
+            before.append(total)
+            total += hi - lo
+        trace["_first_busy"] = ([lo for lo, _ in merged],
+                                [hi for _, hi in merged], before + [total])
+    return trace["_first_busy"]
+
+
+def busy_inside(trace, lo, hi):
+    """ns in which the first device ran an operation inside ``[lo, hi)``."""
+    starts, ends, before = first_busy(trace)
+    if hi <= lo:
+        return 0
+    i = bisect.bisect_right(ends, lo)          # first interval ending > lo
+    j = bisect.bisect_left(starts, hi)         # first interval starting >= hi
+    if i >= j:
+        return 0
+    return (before[j] - before[i]) - max(0, lo - starts[i]) \
+        - max(0, ends[j - 1] - hi)
+
+
+def first_gaps(trace, lo, hi):
+    """The first device's idle intervals inside ``[lo, hi)``, sorted."""
+    starts, ends, _ = first_busy(trace)
+    return subtract([(lo, hi)], list(zip(starts, ends)))
+
+
+def event_counts(trace):
+    """How much there was to reduce: events on the first device's two lines
+    and harness marks on the host."""
+    first = trace["devices"][sorted(trace["devices"])[0]]
+    return {"ops": len(first[OPS_LINE]),
+            "modules": len(first.get(MODULES_LINE, ())),
+            "host_marks": len(trace["host"]),
+            "devices": len(trace["devices"])}
 
 
 # -- what the metrics read ----------------------------------------------------
@@ -199,19 +247,26 @@ def top_ops(trace, n=10):
 def idle_gaps(trace, n=10):
     """``[[what_the_host_was_doing, seconds], ...]``: idle time of the first
     device inside the window, by the innermost harness span that covers each
-    gap's middle (``host:other`` where none does), longest first."""
+    gap's middle (``host:other`` where none does), longest first.
+
+    One sweep: gaps and marks are both sorted by start, a gap's middle only
+    moves forward, so each mark is pushed once onto a heap ordered by length
+    and popped once when a middle has passed its end."""
     lo, hi = window_of(trace)
-    first = trace["devices"][sorted(trace["devices"])[0]]
-    gaps = subtract([(lo, hi)], merge(spans(first[OPS_LINE])))
     marks = [(name, s, s + d) for name, s, d in trace["host"]
              if name != WINDOW]
-    total = {}
-    for a, b in gaps:
-        mid, best = 0.5 * (a + b), None
-        for name, s, e in marks:
-            if s <= mid < e and (best is None or e - s < best[1]):
-                best = (name, e - s)
-        who = "host:" + (best[0][len(MARK):] if best else "other")
+    total, open_, nxt = {}, [], 0
+    for a, b in first_gaps(trace, lo, hi):
+        mid = 0.5 * (a + b)
+        while nxt < len(marks) and marks[nxt][1] <= mid:
+            name, s, e = marks[nxt]
+            heapq.heappush(open_, (e - s, nxt, e, name))
+            nxt += 1
+        # a mark whose end the middle has passed covers no later middle;
+        # one hidden under a shorter live mark is dropped when it surfaces
+        while open_ and open_[0][2] <= mid:
+            heapq.heappop(open_)
+        who = "host:" + (open_[0][3][len(MARK):] if open_ else "other")
         total[who] = total.get(who, 0.0) + (b - a)
     rows = sorted(total.items(), key=lambda kv: -kv[1])[:n]
     return [[k, v / 1e9] for k, v in rows]
@@ -221,20 +276,9 @@ def host_busy_inside(trace, mark):
     """For every host span named ``mark`` inside the window: ``(span_ms,
     device_busy_ms inside it)`` on the first device."""
     lo, hi = window_of(trace)
-    first = trace["devices"][sorted(trace["devices"])[0]]
-    ops = merge(spans(first[OPS_LINE]))
-    out, j = [], 0
-    for name, s, d in trace["host"]:
-        if name != mark or s < lo or s + d > hi:
-            continue
-        while j < len(ops) and ops[j][1] <= s:
-            j += 1
-        b, k = 0.0, j
-        while k < len(ops) and ops[k][0] < s + d:
-            b += min(ops[k][1], s + d) - max(ops[k][0], s)
-            k += 1
-        out.append((d / 1e6, b / 1e6))
-    return out
+    return [(d / 1e6, busy_inside(trace, s, s + d) / 1e6)
+            for name, s, d in trace["host"]
+            if name == mark and s >= lo and s + d <= hi]
 
 
 def find_xplane(logdir):

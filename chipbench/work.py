@@ -3,8 +3,16 @@
 Used for utilisation metrics only; kept with the benchmark so that no later
 PR can count differently.  Counts are of the mathematics the model requires:
 recomputation, padding and optimizer arithmetic are not work.
+
+A configuration file names its own counting functions under ``counts``
+(``"module:function"``, found as ``builder`` is): ``train_flops_per_sample``
+and ``decode_step_bytes`` below only look them up, so a model of a new
+kind (sparse experts: active FLOPs, expert-weight reads) brings its counts
+in a file of its own and the readers need no edit.
 """
 from __future__ import annotations
+
+from .manifest import load_named
 
 
 def resnet_fwd_flops(cfg):
@@ -43,14 +51,32 @@ def lm_fwd_flops_per_token(cfg, layers, context):
     return layers * per_layer + 2 * d * v
 
 
-def train_flops_per_sample(cfg, traffic):
-    """Forward + backward FLOPs of one training sample (backward = 2 x
-    forward): an image, or one sequence of ``seq_len`` tokens."""
-    if cfg["family"] == "resnet":
-        return 3 * resnet_fwd_flops(cfg)
+def resnet_train_flops_per_sample(cfg, traffic):
+    """Forward + backward FLOPs of one image (backward = 2 x forward)."""
+    return 3 * resnet_fwd_flops(cfg)
+
+
+def dense_lm_train_flops_per_sample(cfg, traffic):
+    """Forward + backward FLOPs of one sequence of ``seq_len`` tokens
+    through a dense decoder (backward = 2 x forward)."""
     t = traffic["seq_len"]
     layers = cfg[traffic.get("layers_key", "num_hidden_layers")]
     return 3 * t * lm_fwd_flops_per_token(cfg, layers, t)
+
+
+def count_of(cfg, what):
+    """The counting function ``cfg`` names for ``what``."""
+    try:
+        return load_named(cfg["counts"][what])
+    except KeyError:
+        raise KeyError("the configuration names no count %r under its "
+                       "'counts' key" % what) from None
+
+
+def train_flops_per_sample(cfg, traffic):
+    """Forward + backward FLOPs of one training sample, by the function the
+    configuration names."""
+    return count_of(cfg, "train_flops_per_sample")(cfg, traffic)
 
 
 def lm_weight_bytes(cfg, layers, bytes_per_param):
@@ -72,8 +98,14 @@ def kv_bytes_per_token(cfg, layers, kv_bytes, scale_bytes=4):
 
 
 def decode_step_bytes(cfg, traffic, live_tokens):
-    """Bytes one decode tick must read from HBM: the weights once, and the
-    keys and values of every live context token."""
+    """Bytes one decode tick must read from HBM, by the function the
+    configuration names."""
+    return count_of(cfg, "decode_step_bytes")(cfg, traffic, live_tokens)
+
+
+def dense_lm_decode_step_bytes(cfg, traffic, live_tokens):
+    """Bytes one decode tick of a dense decoder must read from HBM: the
+    weights once, and the keys and values of every live context token."""
     layers = cfg["num_hidden_layers"]
     kv = 1 if traffic.get("kv_dtype") == "int8" else 2
     return lm_weight_bytes(cfg, layers, 2) + \

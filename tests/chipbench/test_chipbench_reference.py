@@ -14,10 +14,11 @@ import tiny
 
 from chipbench import manifest
 
-INIT_RN = manifest.load_json(manifest.ROOT,
-                             "chipbench/configs/resnet50.json")["init"]
-INIT_LM = manifest.load_json(manifest.ROOT,
-                             "chipbench/configs/opt-1.3b.json")["init"]
+REAL_RN = manifest.load_json(manifest.ROOT,
+                             "chipbench/configs/resnet50.json")
+REAL_LM = manifest.load_json(manifest.ROOT,
+                             "chipbench/configs/opt-1.3b.json")
+INIT_RN, INIT_LM = REAL_RN["init"], REAL_LM["init"]
 
 
 def _bound(cfg, data_shape, label_shape, seed, **overrides):
@@ -112,7 +113,9 @@ def test_resnet_reference_forward_and_loss_match_the_system(rn):
     probs = ex.outputs[0].data
     logits = ref_rn.forward(params, cfg, x, training=True)
     assert correct.compare_logp(probs, logits, 1e-3)["ok"]
-    out = correct.compare_loss(probs, logits, y)
+    out = correct.compare_loss(probs, logits, y,
+                               correct.limit(REAL_RN, "train_fit",
+                                             "loss_rtol"))
     assert out["ok"] and out["loss"] == pytest.approx(
         float(ref_rn.loss(params, cfg, x, y, training=True)), rel=1e-4)
 
@@ -157,5 +160,27 @@ def test_the_tolerance_refuses_a_wrong_model(lm, fault):
         coarse = {k: jnp.round(v * 8) / 8 for k, v in params.items()}
         logits = ref_opt.forward(coarse, cfg, toks)
     out = correct.compare_logp(probs, logits.reshape(-1, cfg["vocab_size"]),
-                               correct.LOGP_ATOL["decoder_lm"])
+                               correct.limit(REAL_LM, "train_fit",
+                                             "logp_atol"))
     assert not out["ok"], out
+
+
+@pytest.mark.parametrize("cell", ["tiny_lm", "tiny_serve"])
+def test_the_control_reads_far_above_a_sound_run(cell, tmp_path_factory):
+    """``chipbench/control.py``: the reference with its matrices in the
+    precision below the configuration's (float32 here, so bfloat16), over
+    the rows the cell's own comparison reads, moves log-probabilities by
+    more than three times what the system's float32 differs from the
+    reference by at this size (under 1e-4, the tests above).  On the chip
+    the same reading, at the cell's size, has to stay above the cell's
+    limit (PERF.md keeps both)."""
+    from chipbench import control
+
+    root = tiny.make_root(tmp_path_factory.mktemp("control"))
+    loaded = manifest.load_cell(cell, root=root)
+    got = [control.reading(loaded, seed) for seed in (3, 2 ** 31 + 9)]
+    assert all(np.isfinite(g) and g > 3e-4 for g in got), got
+    # the same seed reads the same; 8-bit matrices read higher still
+    assert control.reading(loaded, 3) == got[0]
+    if cell == "tiny_lm":
+        assert control.reading(loaded, 3, below="float8_e4m3fn") > got[0]

@@ -58,10 +58,15 @@ def tiny_root(tmp_path_factory):
 def results(tiny_root):
     """Each tiny cell once, through ``run.run_cell`` on ``mx.cpu()``."""
     import mxnet_tpu as mx
+    from mxnet_tpu import obs
 
     counters = harness.CompileCounters().install()
     out = {}
     for cell, _, _ in tiny.TINY_CELLS:
+        # the program's counters are the process's: whatever ran before (an
+        # earlier test, here two dispatches made up) is not this run's
+        obs.registry.counter("mx_attn_dispatch_total", labels=("path",)
+                             ).labels(path="einsum").inc(2)
         loaded = manifest.load_cell(cell, root=tiny_root)
         out[cell] = (loaded, run.run_cell(
             loaded, 2 ** 31 + 5, 1.0, False, [mx.cpu()], counters,
@@ -86,6 +91,13 @@ def test_train_fit_driver(results, cell):
     assert side["gc_collections_in_window"][2] == 0
     assert res["setup_s"] > 0 and res["trace"] is None
     assert 0 < side["longest_step_s"] < side["window_s"]
+    # which attention path the step's nodes were traced on, from the
+    # program's own counter, counted from the run's start: the LM has one
+    # such node, ResNet none, whatever the process traced before
+    paths = {k: v for k, v in side["program_counters_before_window"].items()
+             if k.startswith("mx_attn_dispatch_total{")}
+    assert paths == ({"mx_attn_dispatch_total{path=einsum}": 1.0}
+                     if cell == "tiny_lm" else {})
 
 
 def test_serve_ticks_driver(results):
@@ -101,6 +113,78 @@ def test_serve_ticks_driver(results):
     assert res["end_to_end"]["serve_gap_p95_ms"] == side["gap_p95_ms"]
     assert 0 < facts["mean_active"] <= loaded["traffic"]["slots"]
     assert side["gc_collections_in_window"][2] == 0
+    # an untraced run takes no notice of the traffic file's `trace_ticks`
+    assert side["ticks"] > loaded["traffic"]["trace_ticks"]
+    assert side["window_s"] >= 1.0
+
+
+class StubTracer:
+    """A tracer that is on and records nothing: the CPU has no device
+    plane to read, and the drivers ask only for these."""
+
+    def __init__(self, on, name):
+        self.on, self.parsed, self.trace_bytes = True, None, None
+        self.stopped = 0
+
+    def start(self):
+        pass
+
+    def span(self, what):
+        import contextlib
+        return contextlib.nullcontext()
+
+    def stop(self, phases):
+        self.stopped += 1
+
+
+def test_a_traced_serving_window_closes_after_trace_ticks(tiny_root,
+                                                          monkeypatch):
+    """The traced window is a fixed number of ticks, so what a traced run
+    costs does not follow the tick's length; `trace_seconds` still caps
+    it."""
+    import mxnet_tpu as mx
+
+    monkeypatch.setattr(harness, "Tracer", StubTracer)
+    loaded = manifest.load_cell("tiny_serve", root=tiny_root)
+    assert loaded["traffic"]["trace_ticks"] == 5
+    phases = harness.Phases()
+    res = run.run_cell(loaded, 7, 30.0, True, [mx.cpu()],
+                       harness.CompileCounters(), phases,
+                       harness.MemoryPeak(1))
+    assert res["side"]["ticks"] == 5
+    assert res["side"]["window_s"] < loaded["traffic"]["trace_seconds"]
+    assert all(c["ok"] for c in res["checks"]), res["checks"]
+    # where the run's seconds went, in the order they were spent
+    names = list(phases.seconds)
+    assert names[names.index("trace_start"):] == [
+        "trace_start", "window", "after_window", "check"]
+
+
+def test_a_traffic_files_mesh_reaches_module_as_mesh_config(tiny_root,
+                                                            monkeypatch):
+    """`mesh` in a traffic file is the axis sizes of the cell's
+    `parallel.MeshConfig`; a file without the key passes nothing."""
+    import mxnet_tpu as mx
+
+    seen = []
+    real = mx.mod.Module
+
+    def recording(*args, **kwargs):
+        seen.append(kwargs.get("mesh_config", "absent"))
+        return real(*args, **kwargs)
+
+    monkeypatch.setattr(mx.mod, "Module", recording)
+    loaded = manifest.load_cell("tiny_lm", root=tiny_root)
+    meshed = dict(loaded, traffic=dict(loaded["traffic"],
+                                       mesh={"data": 2}))
+    for cell, contexts in ((meshed, [mx.cpu(0), mx.cpu(1)]),
+                           (loaded, [mx.cpu()])):
+        res = run.run_cell(cell, 11, 0.2, False, contexts,
+                           harness.CompileCounters(), harness.Phases(),
+                           harness.MemoryPeak(1))
+        assert all(c["ok"] for c in res["checks"]), res["checks"]
+    assert seen[0] == mx.parallel.MeshConfig(data=2)
+    assert seen[1] == "absent"
 
 
 def test_side_file_is_written(results, tmp_path, monkeypatch):

@@ -159,3 +159,132 @@ def test_recorded_trace(chips):
     assert (exposed is None) == (chips == 1)
     if exposed is not None:
         assert 0 <= exposed < 100
+
+
+# -- the sweeps against the loops they replaced ---------------------------------
+
+def _idle_gaps_by_loops(t, n=10):
+    """``trace.idle_gaps`` as it was: every gap against every mark."""
+    lo, hi = trace.window_of(t)
+    first = t["devices"][sorted(t["devices"])[0]]
+    gaps = trace.subtract([(lo, hi)],
+                          trace.merge(trace.spans(first[trace.OPS_LINE])))
+    marks = [(name, s, s + d) for name, s, d in t["host"]
+             if name != trace.WINDOW]
+    total = {}
+    for a, b in gaps:
+        mid, best = 0.5 * (a + b), None
+        for name, s, e in marks:
+            if s <= mid < e and (best is None or e - s < best[1]):
+                best = (name, e - s)
+        who = "host:" + (best[0][len(trace.MARK):] if best else "other")
+        total[who] = total.get(who, 0.0) + (b - a)
+    rows = sorted(total.items(), key=lambda kv: -kv[1])[:n]
+    return [[k, v / 1e9] for k, v in rows]
+
+
+def _busy_inside_by_loops(t, intervals):
+    """``spans.device_busy_inside`` as it was: the first device's merged
+    operations clipped against each interval in turn."""
+    first = t["devices"][sorted(t["devices"])[0]]
+    ops = trace.merge(trace.spans(first[trace.OPS_LINE]))
+    return [trace.length(trace.clip(ops, a, b)) for a, b in intervals]
+
+
+def _recorded():
+    out = []
+    for chips in (1, 4):
+        path = os.path.join(TESTDATA, "small_%dchip.xplane.pb" % chips)
+        if os.path.exists(path):
+            out.append(trace.load(path))
+    return out
+
+
+def _nested_marks():
+    """Marks that nest, overlap and tie in length, and gaps whose middles
+    fall on every kind of boundary between them."""
+    ops = [(1, 2000 + 100 * i, 40 + (i % 7)) for i in range(95)]
+    host = [(7, 2000, 10000), (8, 2000, 5000), (9, 2500, 1000),
+            (9, 2600, 300), (8, 2600, 300), (9, 6000, 2000),
+            (8, 6500, 1500), (9, 7900, 100), (8, 9000, 3000)]
+    return _space(ops, MODS, host)
+
+
+def test_the_sweeps_give_the_loops_answers_exactly():
+    traces = _recorded() + [_space(OPS, MODS, HOST),
+                            _space(OPS, MODS, HOST[:1]), _nested_marks()]
+    assert len(traces) >= 4
+    for t in traces:
+        assert trace.idle_gaps(t) == _idle_gaps_by_loops(t)
+        assert trace.idle_gaps(t, n=1) == _idle_gaps_by_loops(t, n=1)
+        lo, hi = (int(x) for x in trace.window_of(t))
+        step = max(1, (hi - lo) // 37)
+        cuts = [(a, a + w) for a in range(lo - step, hi + step, step)
+                for w in (1, step // 3, step, 5 * step)]
+        assert [trace.busy_inside(t, a, b) for a, b in cuts] == \
+            _busy_inside_by_loops(t, cuts)
+        from chipbench import spans
+        assert spans.device_busy_inside(t, cuts) == \
+            sum(_busy_inside_by_loops(t, cuts))
+        starts, ends, before = trace.first_busy(t)
+        assert before[-1] == sum(e - s for s, e in zip(starts, ends))
+        assert trace.first_gaps(t, lo, hi) == trace.subtract(
+            [(lo, hi)], list(zip(starts, ends)))
+
+
+# what the parent's loops (commit a017abf) read off the recorded traces
+RECORDED = {
+    1: ([["host:fit_step", 0.007730682]],
+        [(3.695629, 0.029284), (1.164899, 0.014794), (1.05721, 0.014392),
+         (0.99419, 0.014507), (0.85757, 0.0)]),
+    4: ([["host:fit_step", 0.006816071]],
+        [(1.80026, 0.054863), (1.44086, 0.054823), (1.26307, 0.054939),
+         (1.32745, 0.054964), (1.17504, 0.0)]),
+}
+
+
+@pytest.mark.parametrize("chips", [1, 4])
+def test_the_recorded_traces_breakdown_is_what_it_was(chips):
+    """Digit for digit what the loops gave before the sweeps replaced
+    them."""
+    t = trace.load(os.path.join(TESTDATA, "small_%dchip.xplane.pb" % chips))
+    assert trace.idle_gaps(t) == RECORDED[chips][0]
+    assert trace.host_busy_inside(t, "chipbench:fit_step") == \
+        RECORDED[chips][1]
+
+
+def _synthetic(n):
+    """``n`` operations with a gap after each, under ``n`` marks that nest
+    two deep: what a 30-s window of a 1.4-ms tick would hold."""
+    ops = [("fusion.%d" % (i % 50), 1000 * i, 600) for i in range(n)]
+    host = [("chipbench:window", 0, 1000 * n)]
+    for i in range(n // 2):
+        host.append(("chipbench:serve_tick", 2000 * i, 2000))
+        host.append(("chipbench:inner", 2000 * i + 500, 1000))
+    host.sort(key=lambda x: x[1])
+    return {"devices": {"/device:TPU:0": {
+        trace.OPS_LINE: ops, trace.MODULES_LINE: []}}, "host": host}
+
+
+def test_twenty_thousand_gaps_and_marks_reduce_in_seconds():
+    """Gaps x marks took 15 s here at this size (and a 7 x faster tick made
+    the traced serving run 7 x this large, PERF.md section 7 (a)); one sweep
+    takes 0.1 s.  The limit is generous: it catches a loop, not a slow
+    machine."""
+    import time
+
+    n = 20000
+    t = _synthetic(n)
+    began = time.perf_counter()
+    rows = dict(trace.idle_gaps(t))
+    ticks = trace.host_busy_inside(t, "chipbench:serve_tick")
+    took = time.perf_counter() - began
+    assert took < 10.0, took
+    # a gap's middle lies at 800 of every 1000 ns: the even ones inside an
+    # inner mark (500..1500 of 2000), the odd ones under the tick alone
+    assert rows == {"host:inner": pytest.approx(n / 2 * 400e-9),
+                    "host:serve_tick": pytest.approx(n / 2 * 400e-9)}
+    assert len(ticks) == n // 2 and ticks[7] == (
+        pytest.approx(2000e-6), pytest.approx(1200e-6))
+    small = _synthetic(400)
+    assert trace.idle_gaps(small) == _idle_gaps_by_loops(small)

@@ -50,7 +50,8 @@ TINY_TRAFFIC = {
         "kv_dtype": "int8", "prompt_min": 4, "prompt_max": 24,
         "output_min": 4, "output_max": 16, "requests": 512, "block": 16,
         "order_seed": 0,
-        "warmup_ticks": 2, "trace_seconds": 1, "check_prompt": 20,
+        "warmup_ticks": 2, "trace_seconds": 1, "trace_ticks": 5,
+        "check_prompt": 20,
         "check_decode": 4},
 }
 TINY_CELLS = [("tiny_rn", "tiny-resnet", "tiny_closed_img"),
@@ -71,26 +72,29 @@ def make_root(tmp_path):
                               "chipbench/configs/resnet50.json")
     real_lm = manifest.load_json(manifest.ROOT,
                                  "chipbench/configs/opt-1.3b.json")
-    for name, cfg, init in (("tiny-resnet", TINY_RESNET, real["init"]),
-                            ("tiny-lm", TINY_LM, real_lm["init"])):
+    for name, cfg, like in (("tiny-resnet", TINY_RESNET, real),
+                            ("tiny-lm", TINY_LM, real_lm)):
         rel = "chipbench/configs/%s.json" % name
         with open(os.path.join(root, rel), "w") as f:
-            json.dump(dict(cfg, init=init), f)
+            json.dump(dict(cfg, init=like["init"], counts=like["counts"],
+                           limits=like["limits"]), f)
         man["configs"].append({"name": name, "source": "test", "file": rel,
                                "reduced": [], "why": "CPU test size"})
     for name, traffic in TINY_TRAFFIC.items():
         with open(os.path.join(root, manifest.traffic_path(name)), "w") as f:
             json.dump(traffic, f)
+    # a tiny cell reports what the accepted cells of its kind report: a
+    # cell's kind is its traffic file's loop driver and its chips
+    kind_of = {w["name"]: (manifest.load_json(
+        manifest.ROOT, manifest.traffic_path(w["traffic"]))["driver"],
+        w["chips"]) for w in man["workloads"]}
     for cell, cfg, traffic in TINY_CELLS:
         man["workloads"].append({"name": cell, "config": cfg,
                                  "traffic": traffic, "chips": 1,
                                  "why": "CPU test size"})
-        kind = "serve" if traffic == "tiny_backlog" else "train"
+        kind = (TINY_TRAFFIC[traffic]["driver"], 1)
         for m in man["end_to_end"] + man["per_layer"]:
-            if "workloads" in m and (
-                    ("opt_serve_backlog" in m["workloads"]) == (
-                        kind == "serve")) \
-                    and m["name"] != "collective_exposed_pct":
+            if kind in {kind_of.get(w) for w in m.get("workloads", ())}:
                 m["workloads"].append(cell)
     with open(os.path.join(root, "BENCHMARK.json"), "w") as f:
         json.dump(man, f)
