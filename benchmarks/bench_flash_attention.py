@@ -14,9 +14,12 @@ machine ``block_until_ready`` fences just as well — CHANGES.md, PR 19).
     python benchmarks/bench_flash_attention.py --crossover  # the dispatch's table
 
 ``--crossover`` is the measurement behind ``ops.attention.FLASH_MIN_T`` and
-``pallas_attention``'s block constants: forward + backward at 8192 tokens a
-step for 32 heads of 64 and 16 heads of 128, then the kernel's block sizes
-swept at (4, 2048) for both widths; rows go to ``chiprun_out/attn_crossover.json``.
+``pallas_attention``'s block constants: at 8192 tokens a step, for 32 heads
+of 64 and 16 heads of 128, einsum against flash (forward + backward through
+``jax.grad``), the flash kernels' forward and backward apart, and their block
+sizes swept at (4, 2048), (8, 1024) and (16, 512); it ends on the table that
+``pallas_attention``'s comment and ``PERF.md`` quote; rows go to
+``chiprun_out/attn_crossover.json``.
 """
 import os
 import sys
@@ -27,17 +30,20 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 import numpy as np
 
 
-def _bench(fn, *args, n=10, trials=3):
-    """min-of-trials ms/call with host-readback sync."""
+def _bench(fn, *args, n=20, trials=3):
+    """min-of-trials ms/call with host-readback sync.  The scalars are on
+    the device before the clock starts: made inside the loop they put a
+    floor of 0.9 ms under every call (PR 31)."""
     import jax
     import jax.numpy as jnp
 
+    cs = [jnp.float32(i) for i in range(n)]
     np.asarray(jax.tree.leaves(fn(jnp.float32(1.0), *args))[0][(0,) * 2])
     times = []
     for _ in range(trials):
         t0 = time.perf_counter()
-        for i in range(n):
-            out = fn(jnp.float32(i), *args)
+        for c in cs:
+            out = fn(c, *args)
         np.asarray(jax.tree.leaves(out)[0][(0,) * 2])
         times.append((time.perf_counter() - t0) / n * 1e3)
     return min(times)
@@ -160,9 +166,10 @@ def train8k():
 
 
 def crossover():
-    """Forward + backward (``jax.grad`` of a sum, jitted, bf16, causal),
-    ``sdpa`` against ``sdpa_flash`` at 8192 tokens a step, and the block
-    sweep; the table the dispatch rule's thresholds are read from."""
+    """``sdpa`` against ``sdpa_flash`` forward + backward (``jax.grad`` of a
+    sum, jitted, bf16, causal) at 8192 tokens a step, the kernels' forward
+    and backward apart, and the block sweep; the table the dispatch rule's
+    thresholds and the kernels' block constants are read from."""
     import json
 
     import jax
@@ -176,8 +183,13 @@ def crossover():
     dev = jax.devices()[0]
     shapes = [(32, 256), (16, 512), (8, 1024), (4, 2048)]
     widths = [(32, 64), (16, 128)]
+    fwd_blocks = [(512, 512), (512, 1024), (1024, 512), (1024, 1024),
+                  (256, 1024)]
+    bwd_blocks = [(256, 512), (512, 512), (512, 1024), (1024, 512),
+                  (256, 1024)]
     if interp:                          # CPU rehearsal: control flow only
         shapes, widths = [(2, 128)], [(2, 64)]
+        fwd_blocks = bwd_blocks = [(128, 128)]
     out = {"device": {"platform": dev.platform, "kind": dev.device_kind},
            "table": [], "sweep_fwd": [], "sweep_bwd": []}
 
@@ -187,6 +199,7 @@ def crossover():
                 for _ in range(3)]
 
     for heads, hd in widths:
+        scale = 1.0 / float(np.sqrt(hd))
         for b, t in shapes:
             q, k, v = qkv(b, t, heads * hd)
 
@@ -212,38 +225,53 @@ def crossover():
                                       - b_.astype(jnp.float32)))
                       / jnp.max(jnp.abs(a.astype(jnp.float32))))
                 for a, b_ in zip(ge, gf))
+
+            # the kernels alone, on head-folded operands: forward (with the
+            # (BH, T) logsumexp residual, as the vjp runs it) and backward apart,
+            # at the blocks the module resolves, then at the swept ones
+            qf, kf, vf = [x.reshape(b, t, heads, hd).transpose(0, 2, 1, 3)
+                          .reshape(b * heads, t, hd) for x in (q, k, v)]
+
+            def fwd_at(bq=None, bk=None):
+                return jax.jit(lambda c, q_, k_, v_: pa._fwd_rows(
+                    q_, k_, v_, scale, True, interp, with_lse=True,
+                    block_q=bq, block_k=bk))
+
+            o, lse = fwd_at()(None, qf, kf, vf)
+
+            def bwd_at(bq=None, bk=None):
+                return jax.jit(lambda c, q_, k_, v_: pa._bwd_call(
+                    q_, k_, v_, o, lse, o, scale, True, interp,
+                    block_q=bq, block_k=bk))
+
+            row["flash_fwd_ms"] = _bench(fwd_at(), qf, kf, vf)
+            row["flash_bwd_ms"] = _bench(bwd_at(), qf, kf, vf)
             out["table"].append(row)
             print(json.dumps(row), flush=True)
-
-    b, t = shapes[-1]
-    blocks = (128, 256, 512, 1024, 2048)
-    for heads, hd in widths:
-        q, k, v = [x.reshape(b, t, heads, hd).transpose(0, 2, 1, 3)
-                   .reshape(b * heads, t, hd) for x in qkv(b, t, heads * hd)]
-        scale = 1.0 / float(np.sqrt(hd))
-        o, lse = jax.jit(lambda q_, k_, v_: pa._fwd_call(
-            q_, k_, v_, scale, True, interp, with_lse=True))(q, k, v)
-        for bq in blocks[:3]:
-            for bk in blocks[1:]:
-                if bq > t or bk > t:
-                    continue
-                fwd = jax.jit(lambda c, q_, k_, v_, bq=bq, bk=bk:
-                              pa._fwd_call(q_ * c, k_, v_, scale, True,
-                                           interp, with_lse=True,
-                                           block_q=bq, block_k=bk))
-                bwd = jax.jit(lambda c, q_, k_, v_, bq=bq, bk=bk:
-                              pa._bwd_call(q_ * c, k_, v_, o, lse, o, scale,
-                                           True, interp, block_q=bq,
-                                           block_k=bk))
-                for key, fn in (("sweep_fwd", fwd), ("sweep_bwd", bwd)):
-                    row = {"head_dim": hd, "block_q": bq, "block_k": bk}
+            if t < 512 and not interp:
+                continue
+            for key, at, blocks in (("sweep_fwd", fwd_at, fwd_blocks),
+                                    ("sweep_bwd", bwd_at, bwd_blocks)):
+                for bq, bk in blocks:
+                    if bq > t or bk > t:
+                        continue
+                    srow = {"head_dim": hd, "b": b, "t": t, "block_q": bq,
+                            "block_k": bk}
                     try:
-                        row["ms"] = _bench(fn, q, k, v)
+                        srow["ms"] = _bench(at(bq, bk), qf, kf, vf)
                     except Exception as exc:  # VMEM overflow and the like
-                        row["error"] = str(exc).splitlines()[0][:200]
-                    out[key].append(row)
-                    print(key, json.dumps(row), flush=True)
+                        srow["error"] = str(exc).splitlines()[0][:200]
+                    out[key].append(srow)
+                    print(key, json.dumps(srow), flush=True)
 
+    print("kernels alone, forward / backward ms at (B, T), blocks as "
+          "resolved (fwd %s, bwd %s):" % (
+              (pa.BLOCK_Q, pa.BLOCK_K), (pa.BLOCK_Q_BWD, pa.BLOCK_K_BWD)))
+    for heads, hd in widths:
+        print("  %2d heads of %3d: %s" % (heads, hd, "   ".join(
+            "(%d, %d) %.3f / %.3f" % (r["b"], r["t"], r["flash_fwd_ms"],
+                                      r["flash_bwd_ms"])
+            for r in out["table"] if r["head_dim"] == hd)))
     os.makedirs("chiprun_out", exist_ok=True)
     with open("chiprun_out/attn_crossover.json", "w") as f:
         json.dump(out, f, indent=1)

@@ -8,19 +8,29 @@ chip (flash-attention schedule; same numerics as the streaming
 accumulator in ``parallel/ring.py``, here at the kernel level).
 
 ``flash_attention`` is differentiable: a ``jax.custom_vjp`` pairs the
-forward kernel (which saves a per-row logsumexp residual) with two
-backward kernels — one accumulating dQ over key blocks, one accumulating
-dK/dV over query blocks — recomputing the (T, T) probabilities blockwise
-from the residual instead of storing them.  This is the TPU analog of the
+forward kernel (which saves a per-row logsumexp residual) with one
+backward kernel that recomputes each tile's probabilities from the
+residual, once, and accumulates dQ, dK and dV from them (two kernels, one
+for dQ and one for dK/dV, where K/V are grouped or one head's dQ outgrows
+VMEM).  This is the TPU analog of the
 reference's fused-kernel-that-trains precedent (its cuDNN RNN op
 implements forward *and* backward in one fused device kernel,
 ``src/operator/cudnn_rnn-inl.h``): long-context *training* runs the fast
 path, not just inference.
 
-Per-row residuals (logsumexp, and delta = rowsum(dO·O)) are stored
-broadcast across a 128-lane minor dimension — ``(BH, T, LANES)`` — so the
-backward kernels consume them with the same (rows, lanes) layout the MXU
-tiles want, and no kernel ever transposes a vector.
+What a tile costs on the chip (TPU v5 lite, PR 31): with heads of 64 the
+MXU runs these matmuls at half width, and beside it a tile pays per ROW for
+every cross-lane reduction — so the forward sums its probabilities lane by
+lane and reduces them once a query block, tiles are as tall and wide as
+VMEM holds, and the tile loop runs inside the kernel, its bounds cut to the
+causal diagonal (the mask is built only on tiles the diagonal crosses).
+
+The fused backward works on TRANSPOSED score tiles (keys down the
+sublanes, queries along the lanes): dK and dV are plain matmuls, dQ is the
+one matmul with a transposed left operand, and the per-row residuals
+(logsumexp, and delta = rowsum(dO·O)) are read as lane-major rows — so the
+forward writes its logsumexp that way, (BH, T) float32, a 128th of the
+(BH, T, LANES) broadcast the ring and the two-kernel backward still read.
 
 Used by ``dot_product_attention`` where ``ops.attention.flash_selected``
 says the call's shape wins with it (a TPU, no mesh, a supported shape, T at
@@ -35,20 +45,36 @@ import numpy as np
 
 # Block-size defaults, taken where the tuning cache (ops/tuning.py) holds no
 # winner for the (generation, shape-class, dtype) — a fresh checkout holds
-# none, .mxnet_programs/ is not in git.  Swept on TPU v5 lite, jax 0.9.0,
-# bf16 causal, (B, T) = (4, 2048), forward at (block_q, block_k) / backward
-# at (block_q_bwd, block_k_bwd) in ms
-# (benchmarks/bench_flash_attention.py --crossover, PR 26):
-#   32 heads of 64:  (128, 512) 6.51 / (256, 512) 9.07
-#                    (512, 1024) 3.69 / 6.86   (512, 2048) 3.56 / 6.88
-#   16 heads of 128: (128, 512) 2.77 / (256, 512) 3.86
-#                    (512, 1024) 1.33 / 2.97   (512, 2048) 1.38 / 3.06
-# (512, 1024) wins or ties at both widths, keeps the causal block skip at
-# T 2048 and asks half the VMEM of (512, 2048).
-BLOCK_Q = 512
+# none, .mxnet_programs/ is not in git.  A block is one TILE of scores; the
+# kernels loop over tiles inside a grid step that holds MAJOR_ROWS rows of
+# K/V (forward) or of Q/dO (backward).  Measured on TPU v5 lite, jax 0.9.0,
+# bf16 causal, 8192 tokens, forward / backward in ms at (B, T) =
+# (4, 2048) | (8, 1024) | (16, 512)
+# (benchmarks/bench_flash_attention.py --crossover, PR 31):
+#   32 heads of 64:  2.245 / 3.514 | 1.792 / 2.577 | 1.487 / 2.030
+#                    (PR 26's kernels, timed alike: 2.99 / 6.51 | 1.99 / 4.19 | 1.61 / 3.21)
+#   16 heads of 128: 0.889 / 1.395 | 0.676 / 0.890 | 0.512 / 0.616
+#                    (PR 26's: 1.28 / 2.67 | 0.765 / 1.60 | 0.573 / 0.986)
+# Swept at 32 heads of 64, (4, 2048) | (8, 1024), forward (block_q, block_k):
+#   (512, 512) 2.46 | 1.82   (512, 1024) 2.30 | 1.84   (1024, 512) 2.59 | 1.99
+#   (1024, 1024) 2.25 | 1.80   (256, 1024) 2.65 | 2.08
+# backward (block_q_bwd, block_k_bwd), fused:
+#   (256, 512) 3.69 | 2.68   (512, 512) 3.52 | 2.58   (512, 1024) 3.87 | 2.91
+#   (1024, 512) 3.90 | 2.99   (256, 1024) 3.96 | 2.97
+# Heads of 128 rank the same.  A tile pays per row as well as per score, so
+# the tallest and widest tile VMEM holds wins the forward, though at T 1024
+# it covers the whole square; the backward has no per-row reduction and
+# takes the finer causal skip of 512 x 512.
+BLOCK_Q = 1024
 BLOCK_K = 1024
 BLOCK_Q_BWD = 512
-BLOCK_K_BWD = 1024
+BLOCK_K_BWD = 512
+# rows of K/V (forward) or Q/dO (backward) one grid step holds: what a DMA
+# moves, not a tile of scores
+MAJOR_ROWS = 2048
+# the fused backward holds one head's dQ in VMEM, a (T, D) float32
+# accumulator and the output block, double-buffered: at most this much
+FUSED_BWD_BYTES = 8 << 20
 LANES = 128
 MIN_BLOCK = 8
 
@@ -134,8 +160,43 @@ def _lane_tile(x, n):
     return x[:, :n]
 
 
+def _scaled(x, scale):
+    """``x * scale`` in ``x``'s dtype, through float32 (v5e's vector unit has
+    no bf16): the softmax scale folded into an operand of the score matmul,
+    a multiply per element of Q or K instead of one per score."""
+    import jax.numpy as jnp
+
+    return (x.astype(jnp.float32) * jnp.float32(scale)).astype(x.dtype)
+
+
+def _scores(a, b):
+    """``a @ b.T`` accumulated in float32 (the MXU takes a transposed right
+    operand as it is)."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.dot_general(a, b, (((1,), (1,)), ((), ())),
+                               preferred_element_type=jnp.float32)
+
+
+def _tile_loop(lo, hi, update):
+    """``update(u)`` for tile ``u`` in [lo, hi); the bounds may be traced."""
+    import jax
+
+    def body(u, carry):
+        update(u)
+        return carry
+
+    jax.lax.fori_loop(lo, hi, body, 0)
+
+
 def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
             block_k, with_lse=False):
+    """One (query tile, major K/V block) grid step: a loop over the major
+    block's key tiles.  Under ``causal`` the loop stops at the diagonal and
+    only the tiles it crosses build a mask; tile 0 of block 0 holds key 0,
+    which every row sees, so the running maximum is finite from the first
+    update on and nothing guards against -inf."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
@@ -146,69 +207,62 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
         lse_ref = None
         m_scr, l_scr, acc_scr = rest
 
-    i = pl.program_id(1)
-    j = pl.program_id(2)
-    nj = pl.num_programs(2)
+    major = k_ref.shape[1]
+    ntiles = major // block_k
+    jm = pl.program_id(2)
+    row0 = pl.program_id(1) * block_q
+    col0 = jm * major
 
-    @pl.when(j == 0)
+    @pl.when(jm == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    def _update():
-        q = q_ref[0]                                # (BQ, D)
-        k = k_ref[0]                                # (BK, D)
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * jnp.float32(scale)
+    q = _scaled(q_ref[0], scale)                    # (BQ, D)
 
-        if causal:
-            qi = i * block_q + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 0)
-            kj = j * block_k + jax.lax.broadcasted_iota(
-                jnp.int32, (block_q, block_k), 1)
-            s_masked = jnp.where(qi >= kj, s, -jnp.inf)
-        else:
-            s_masked = s
-        s = s_masked
-
+    def update(u, masked):
+        c = pl.multiple_of(u * block_k, block_k)
+        k = k_ref[0, pl.ds(c, block_k), :]          # (BK, D)
+        v = v_ref[0, pl.ds(c, block_k), :]
+        s = _scores(q, k)
+        if masked:
+            qi = row0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+            kj = col0 + c + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+            s = jnp.where(qi >= kj, s, -jnp.inf)
         m_prev = m_scr[:, :1]                       # (BQ, 1)
-        blk_m = jnp.max(s, axis=1, keepdims=True)
-        m_new = jnp.maximum(m_prev, blk_m)
-        # rows with every key masked so far keep m = -inf; normalize safely
-        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        p = jnp.exp(s - m_safe)
-        p = jnp.where(s == -jnp.inf, 0.0, p)
-        corr = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_safe))
-
-        l_new = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
-        acc = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v_ref[0].astype(jnp.float32),
-            dimension_numbers=(((1,), (0,)), ((), ())),
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.exp(s - m_new)
+        corr = jnp.exp(m_prev - m_new)
+        # the row sum stays spread over the lanes (vector adds only); the
+        # cross-lane reduction, which costs per row, runs once in _finish
+        lanes = l_scr.shape[1]
+        p_lanes = p[:, :lanes]
+        for n in range(1, block_k // lanes):
+            p_lanes = p_lanes + p[:, n * lanes:(n + 1) * lanes]
+        l_scr[:] = l_scr[:] * corr + p_lanes
+        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
-
         m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-        l_scr[:] = jnp.broadcast_to(l_new, l_scr.shape)
-        acc_scr[:] = acc
 
     if causal:
-        # skip K/V blocks entirely above the diagonal (~2x on long T)
-        @pl.when(j * block_k <= i * block_q + block_q - 1)
-        def _masked_update():
-            _update()
+        # tiles [0, n_clear) lie wholly under the diagonal, [n_clear, n_run)
+        # are crossed by it, the rest see nothing
+        n_run = jnp.clip((row0 - col0 + block_q - 1) // block_k + 1, 0, ntiles)
+        n_clear = jnp.clip((row0 - col0 + 1) // block_k, 0, ntiles)
+        _tile_loop(0, n_clear, lambda u: update(u, False))
+        _tile_loop(n_clear, n_run, lambda u: update(u, True))
     else:
-        _update()
+        _tile_loop(0, ntiles, lambda u: update(u, False))
 
-    @pl.when(j == nj - 1)
+    @pl.when(jm == pl.num_programs(2) - 1)
     def _finish():
-        denom = l_scr[:, :1]
-        denom = jnp.where(denom == 0.0, 1.0, denom)
+        denom = jnp.sum(l_scr[:], axis=1, keepdims=True)
         o_ref[0] = (acc_scr[:] / denom).astype(o_ref.dtype)
         if lse_ref is not None:
-            m_fin = jnp.where(m_scr[:] == -jnp.inf, 0.0, m_scr[:])
-            d_fin = jnp.where(l_scr[:] == 0.0, 1.0, l_scr[:])
-            lse_ref[0] = m_fin + jnp.log(d_fin)
+            # (BQ, LANES) with all lanes equal -> one (1, BQ) row
+            lse_ref[0, 0] = (m_scr[:] + jnp.log(denom)).T[:1]
 
 
 @functools.lru_cache(maxsize=None)
@@ -228,6 +282,21 @@ def _traced_once(fn):
 
 def _fwd_call(q, k, v, scale, causal, interpret, with_lse, block_q=None,
               block_k=None, groups=1):
+    """``(out, lse)`` with the logsumexp broadcast across a 128-lane minor
+    dimension, ``(BH, T, LANES)`` — the ring's format (it reads lane 0 and
+    XLA folds the broadcast away); the custom_vjp keeps the (BH, T) rows
+    of :func:`_fwd_rows` as its residual."""
+    import jax.numpy as jnp
+
+    out, lse = _fwd_rows(q, k, v, scale, causal, interpret, with_lse,
+                         block_q, block_k, groups)
+    if lse is not None:
+        lse = jnp.broadcast_to(lse[..., None], lse.shape + (LANES,))
+    return out, lse
+
+
+def _fwd_rows(q, k, v, scale, causal, interpret, with_lse, block_q=None,
+              block_k=None, groups=1):
     bh, t, d = q.shape
     g = int(groups)
     if k.shape[0] * g != bh:
@@ -245,53 +314,143 @@ def _fwd_call(q, k, v, scale, causal, interpret, with_lse, block_q=None,
                          "(callers must gate on supported())" % t)
     return _traced_once(_fwd_kernel)(
         q, k, v, scale=scale, causal=causal, interpret=interpret,
-        with_lse=with_lse, bq=bq, bk=bk, g=g)
+        with_lse=with_lse, bq=bq, bk=bk, major=_major(bk, t), g=g)
 
 
-def _fwd_kernel(q, k, v, *, scale, causal, interpret, with_lse, bq, bk, g):
+def _major(block, t):
+    """Rows of the streamed operand one grid step holds: MAJOR_ROWS shrunk
+    to divide ``t``, and never less than one tile."""
+    return max(_pick_block(MAJOR_ROWS, t), block)
+
+
+def _fwd_kernel(q, k, v, *, scale, causal, interpret, with_lse, bq, bk,
+                major, g):
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     bh, t, d = q.shape
-    grid = (bh, t // bq, t // bk)
+    grid = (bh, t // bq, t // major)
 
     # grouped K/V: folded Q batch index b encodes (batch, q-head) as
     # b = batch*H + h, so its kv block lives at folded index
     # batch*H_kv + h//G == b // G — the h // G group map, in the
-    # BlockSpec index map (never a materialized broadcast)
-    if g == 1:
-        kv_map = lambda b, i, j: (b, j, 0)          # noqa: E731
+    # BlockSpec index map (never a materialized broadcast).  A major block
+    # wholly above the diagonal names the last one that is not: the
+    # pipeline fetches nothing for a step whose block is already there.
+    if causal:
+        def kv_map(b, i, jm):
+            return (b // g, jnp.minimum(jm, (i * bq + bq - 1) // major), 0)
     else:
-        kv_map = lambda b, i, j: (b // g, j, 0)     # noqa: E731
+        def kv_map(b, i, jm):
+            return (b // g, jm, 0)
 
     kernel = functools.partial(_kernel, scale=scale, causal=causal,
                                block_q=bq, block_k=bk, with_lse=with_lse)
     out_shape = [_out_sds(q.shape, q.dtype, q, k, v)]
     out_specs = [pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0))]
     if with_lse:
+        # one lane-major (1, bq) row of logsumexp a query tile: (BH, T) in
+        # memory, what the fused backward reads as it is
         out_shape.append(
-            _out_sds((bh, t, LANES), jnp.float32, q, k, v))
+            _out_sds((bh, t // bq, 1, bq), jnp.float32, q, k, v))
         out_specs.append(
-            pl.BlockSpec((1, bq, LANES), lambda b, i, j: (b, i, 0)))
+            pl.BlockSpec((1, 1, 1, bq), lambda b, i, j: (b, i, 0, 0)))
     res = pl.pallas_call(
         kernel,
         out_shape=out_shape,
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, bq, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, bk, d), kv_map),
-            pl.BlockSpec((1, bk, d), kv_map),
+            pl.BlockSpec((1, major, d), kv_map),
+            pl.BlockSpec((1, major, d), kv_map),
         ],
         out_specs=out_specs,
         scratch_shapes=[
-            pltpu.VMEM((bq, 128), jnp.float32),   # running max
-            pltpu.VMEM((bq, 128), jnp.float32),   # running sum
-            pltpu.VMEM((bq, d), jnp.float32),     # output accumulator
+            pltpu.VMEM((bq, LANES), jnp.float32),  # running max, all lanes
+            pltpu.VMEM((bq, min(bk, LANES)), jnp.float32),  # sum, by lane
+            pltpu.VMEM((bq, d), jnp.float32),      # output accumulator
         ],
         interpret=interpret,
     )(q, k, v)
-    return (res[0], res[1]) if with_lse else (res[0], None)
+    return (res[0], res[1].reshape(bh, t)) if with_lse else (res[0], None)
+
+
+def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, dq_ref,
+                dk_ref, dv_ref, dq_scr, dk_scr, dv_scr, *, scale, causal,
+                block_q, block_k):
+    """One (key block, major Q/dO block) grid step of the fused backward: a
+    loop over the major block's query tiles, each tile's p and ds computed
+    once and used for dQ, dK and dV.  Tiles are TRANSPOSED, (BK, BQ): dK and
+    dV are plain matmuls and dQ the one with a transposed left operand.
+    dK/dV accumulate over a key block's grid steps; dQ over the whole head,
+    in a (T, D) float32 scratch written out on the head's last step."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+
+    major = q_ref.shape[1]
+    ntiles = major // block_q
+    j = pl.program_id(1)
+    im = pl.program_id(2)
+    last_im = im == pl.num_programs(2) - 1
+    col0 = j * block_k
+    row0 = im * major
+
+    @pl.when((j == 0) & (im == 0))
+    def _init_dq():
+        dq_scr[:] = jnp.zeros_like(dq_scr)
+
+    @pl.when(im == 0)
+    def _init_dkv():
+        dk_scr[:] = jnp.zeros_like(dk_scr)
+        dv_scr[:] = jnp.zeros_like(dv_scr)
+
+    v = v_ref[0]                                    # (BK, D)
+    ks = _scaled(k_ref[0], scale)                   # scores AND dQ take it
+
+    def update(u, masked):
+        r = pl.multiple_of(u * block_q, block_q)
+        q = q_ref[0, pl.ds(r, block_q), :]          # (BQ, D)
+        do = do_ref[0, pl.ds(r, block_q), :]
+        st = _scores(ks, q)                         # (BK, BQ)
+        if masked:
+            kj = col0 + jax.lax.broadcasted_iota(jnp.int32, st.shape, 0)
+            qi = row0 + r + jax.lax.broadcasted_iota(jnp.int32, st.shape, 1)
+            st = jnp.where(qi >= kj, st, -jnp.inf)
+        pt = jnp.exp(st - lse_ref[0, u])            # (1, BQ) down the rows
+        dst = (pt * (_scores(v, do) - dta_ref[0, u])).astype(q.dtype)
+        dv_scr[:] += jax.lax.dot_general(
+            pt.astype(do.dtype), do, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        dk_scr[:] += jax.lax.dot_general(
+            dst, q, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        rows = pl.ds(pl.multiple_of(row0 + r, block_q), block_q)
+        dq_scr[rows, :] += jax.lax.dot_general(
+            dst, ks, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    if causal:
+        # query tiles [0, u_run) end above this key block, [u_run, u_clear)
+        # are crossed by the diagonal, the rest lie wholly under it
+        u_run = jnp.clip((col0 - row0) // block_q, 0, ntiles)
+        u_clear = jnp.clip(
+            (col0 - row0 + block_k - 1 + block_q - 1) // block_q,
+            u_run, ntiles)
+        _tile_loop(u_run, u_clear, lambda u: update(u, True))
+        _tile_loop(u_clear, ntiles, lambda u: update(u, False))
+    else:
+        _tile_loop(0, ntiles, lambda u: update(u, False))
+
+    @pl.when(last_im)
+    def _finish_dkv():
+        dk_ref[0] = (dk_scr[:] * jnp.float32(scale)).astype(dk_ref.dtype)
+        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
+
+    @pl.when(last_im & (j == pl.num_programs(1) - 1))
+    def _finish_dq():
+        dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _recompute_p_ds(refs, i, j, *, scale, causal, block_q, block_k):
@@ -362,59 +521,14 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, dq_ref,
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
-def _bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, dk_ref,
-                    dv_ref, dk_scr, dv_scr, *, scale, causal, block_q,
-                    block_k):
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-
-    j = pl.program_id(1)   # key block (outer)
-    i = pl.program_id(2)   # query block (inner, accumulated)
-    ni = pl.num_programs(2)
-
-    @pl.when(i == 0)
-    def _init():
-        dk_scr[:] = jnp.zeros_like(dk_scr)
-        dv_scr[:] = jnp.zeros_like(dv_scr)
-
-    def _update():
-        p, ds = _recompute_p_ds(
-            (q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref), i, j,
-            scale=scale, causal=causal, block_q=block_q, block_k=block_k)
-        q = q_ref[0]
-        do = do_ref[0]
-        dv_scr[:] += jax.lax.dot_general(
-            p.astype(do.dtype), do,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        dk_scr[:] += jax.lax.dot_general(
-            ds.astype(q.dtype), q,
-            dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-
-    if causal:
-        # query blocks strictly above this key block see none of it
-        @pl.when(i * block_q + block_q - 1 >= j * block_k)
-        def _masked_update():
-            _update()
-    else:
-        _update()
-
-    @pl.when(i == ni - 1)
-    def _finish():
-        dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
-        dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
-
-
 def _bwd_dkv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
                             dk_ref, dv_ref, dk_scr, dv_scr, *, scale,
                             causal, block_q, block_k):
-    """Grouped twin of :func:`_bwd_dkv_kernel`: the grid grows a trailing
-    group dim (B*H_kv, T/bk, T/bq, G) and the VMEM scratch accumulates
-    every one of a kv head's G q-heads' contributions before the single
-    write-back — dK/dV land at the GROUPED width, no q-width gradient is
-    ever materialized."""
+    """dK/dV of the two-kernel backward, key block outer and query block
+    accumulated: the grid ends in a group dim (B*H_kv, T/bk, T/bq, G) and
+    the VMEM scratch accumulates every one of a kv head's G q-heads'
+    contributions before the single write-back — dK/dV land at the GROUPED
+    width, no q-width gradient is ever materialized."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
 
@@ -467,10 +581,18 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, interpret, block_q=None,
         raise ValueError(
             "flash_attention bwd: folded K/V batch %d * groups=%d != "
             "folded Q batch %d" % (k.shape[0], g, bh))
+    if lse.ndim == 3:           # the ring's (BH, T, LANES): lane 0
+        lse = lse[:, :, 0]
+    # one kernel where a head's dQ fits VMEM and K/V are not grouped (a kv
+    # head's dK/dV would gather G q-heads' dQ scratches); else two
+    fused = g == 1 and t * d * (4 + 2 * q.dtype.itemsize) <= FUSED_BWD_BYTES
     if block_q is None or block_k is None:
         cfg = _tuned(t, d, q.dtype, groups=g)
         block_q = block_q or cfg.get("block_q_bwd", BLOCK_Q_BWD)
-        block_k = block_k or cfg.get("block_k_bwd", BLOCK_K_BWD)
+        # the two kernels pay per key block as the forward does, and take
+        # its key block (PR 26 measured 1024 for both)
+        block_k = block_k or (cfg.get("block_k_bwd", BLOCK_K_BWD) if fused
+                              else cfg.get("block_k", BLOCK_K))
     bq = _pick_block(block_q, t)
     bk = _pick_block(block_k, t)
     if not bq or not bk:
@@ -478,11 +600,14 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, interpret, block_q=None,
                          "(callers must gate on supported())" % t)
     return _traced_once(_bwd_kernels)(
         q, k, v, o, lse, do, scale=scale, causal=causal,
-        interpret=interpret, bq=bq, bk=bk, g=g)
+        interpret=interpret, bq=bq, bk=bk,
+        major=_major(bq, t) if fused else 0, g=g)
 
 
 def _bwd_kernels(q, k, v, o, lse, do, *, scale, causal, interpret, bq, bk,
-                 g):
+                 major, g):
+    """``lse`` is (BH, T).  ``major`` > 0: the fused kernel over Q/dO blocks
+    of that many rows; 0: the dQ kernel and the dK/dV kernel."""
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -492,6 +617,40 @@ def _bwd_kernels(q, k, v, o, lse, do, *, scale, causal, interpret, bq, bk,
 
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32),
                     axis=-1)
+
+    if major:
+        # logsumexp and delta as lane-major rows, one (1, bq) row a tile
+        rows = (bh, t // bq, 1, bq)
+        if causal:
+            # a major block wholly above this key block names the first
+            # that is not, and is not fetched
+            def first(j, im):
+                return jnp.maximum(im, (j * bk) // major)
+        else:
+            def first(j, im):
+                return im
+        q_spec = pl.BlockSpec((1, major, d),
+                              lambda b, j, im: (b, first(j, im), 0))
+        row_spec = pl.BlockSpec((1, major // bq, 1, bq),
+                                lambda b, j, im: (b, first(j, im), 0, 0))
+        kv_spec = pl.BlockSpec((1, bk, d), lambda b, j, im: (b, j, 0))
+        return pl.pallas_call(
+            functools.partial(_bwd_kernel, scale=scale, causal=causal,
+                              block_q=bq, block_k=bk),
+            out_shape=[_out_sds(x.shape, x.dtype, q, k, v, do, lse)
+                       for x in (q, k, v)],
+            grid=(bh, t // bk, t // major),
+            in_specs=[q_spec, kv_spec, kv_spec, q_spec, row_spec, row_spec],
+            out_specs=[pl.BlockSpec((1, t, d), lambda b, j, im: (b, 0, 0)),
+                       kv_spec, kv_spec],
+            scratch_shapes=[pltpu.VMEM((t, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32),
+                            pltpu.VMEM((bk, d), jnp.float32)],
+            interpret=interpret,
+        )(q, k, v, do, lse.reshape(rows), delta.reshape(rows))
+
+    # the two kernels read both per-row residuals broadcast over 128 lanes
+    lse = jnp.broadcast_to(lse[..., None], (bh, t, LANES))
     delta = jnp.broadcast_to(delta[..., None], (bh, t, LANES))
 
     if g == 1:
@@ -518,40 +677,10 @@ def _bwd_kernels(q, k, v, o, lse, do, *, scale, causal, interpret, bq, bk,
         interpret=interpret,
     )(q, k, v, do, lse, delta)
 
-    if g == 1:
-        dkv_kernel = functools.partial(_bwd_dkv_kernel, scale=scale,
-                                       causal=causal, block_q=bq,
-                                       block_k=bk)
-        dk, dv = pl.pallas_call(
-            dkv_kernel,
-            out_shape=[
-                _out_sds(k.shape, k.dtype, q, k, v, do, lse, delta),
-                _out_sds(v.shape, v.dtype, q, k, v, do, lse, delta)],
-            grid=(bh, t // bk, t // bq),
-            in_specs=[
-                pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bq, d), lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, bq, LANES),
-                             lambda b, j, i: (b, i, 0)),
-                pl.BlockSpec((1, bq, LANES),
-                             lambda b, j, i: (b, i, 0)),
-            ],
-            out_specs=[
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-                pl.BlockSpec((1, bk, d), lambda b, j, i: (b, j, 0)),
-            ],
-            scratch_shapes=[pltpu.VMEM((bk, d), jnp.float32),
-                            pltpu.VMEM((bk, d), jnp.float32)],
-            interpret=interpret,
-        )(q, k, v, do, lse, delta)
-        return dq, dk, dv
-
-    # grouped dK/dV: grid walks (kv batch, key block, query block, group
-    # member) — the b axis is the FOLDED KV batch, q/do/residual blocks
-    # index q-head b*G + gi, and the scratch accumulates across both i
-    # and gi before one grouped-width write-back
+    # dK/dV: grid walks (kv batch, key block, query block, group member) —
+    # the b axis is the FOLDED KV batch, q/do/residual blocks index q-head
+    # b*G + gi, and the scratch accumulates across both i and gi before one
+    # grouped-width write-back
     dkv_kernel = functools.partial(_bwd_dkv_kernel_grouped, scale=scale,
                                    causal=causal, block_q=bq, block_k=bk)
     dk, dv = pl.pallas_call(
@@ -593,12 +722,12 @@ def _flash_vjp():
 
     @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
     def _flash(q, k, v, scale, causal, interpret, groups):
-        out, _ = _fwd_call(q, k, v, scale, causal, interpret,
+        out, _ = _fwd_rows(q, k, v, scale, causal, interpret,
                            with_lse=False, groups=groups)
         return out
 
     def _fwd_rule(q, k, v, scale, causal, interpret, groups):
-        out, lse = _fwd_call(q, k, v, scale, causal, interpret,
+        out, lse = _fwd_rows(q, k, v, scale, causal, interpret,
                              with_lse=True, groups=groups)
         return out, (q, k, v, out, lse)
 
@@ -729,9 +858,9 @@ def _tuning_candidates(shape_class, interpret):
         return [{"block_q": 128, "block_k": 128},
                 {"block_q": 128, "block_k": 256}]
     out = []
-    for bq in (128, 256, 512):
+    for bq in (256, 512, 1024):
         for bk in (512, 1024):
-            for bqb, bkb in ((256, 512), (512, 1024)):
+            for bqb, bkb in ((256, 512), (512, 512), (512, 1024)):
                 out.append({"block_q": bq, "block_k": bk,
                             "block_q_bwd": bqb, "block_k_bwd": bkb})
     return out
