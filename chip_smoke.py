@@ -11,7 +11,7 @@ ONE process on the TPU, at the full width of the models the repo benchmarks
 * ``serve``     a 2-layer width-1024 ``attention_lm`` behind ``DecodeServer``
                 over a paged int8 KV pool, checked against the dense
                 f32-cache predictor on the same chip;
-* ``kernels``   each of the four Pallas families compiled by Mosaic once at a
+* ``kernels``   each of the three Pallas families compiled by Mosaic once at a
                 shape the phases above use, against its XLA reference;
 * ``multichip`` (>= 4 chips) data-parallel ResNet-50 and one ring-attention
                 LM step over a 4-way 'seq' mesh.
@@ -41,12 +41,11 @@ FULL = {
                   ffn=4096, cache_len=2048, page_tokens=16, prefill_chunk=64,
                   slots=4, max_prefill=512, requests=8, prompt_lo=64,
                   prompt_hi=512, new_tokens=32),
-    # flash: the LM's attention at T 2048; fused: one LN->linear segment of
-    # that LM (2 MiB bf16 weight).  The decode kernel takes the serve
-    # phase's pool, the optimizer kernel the train phase's parameter tree.
+    # flash: the LM's attention at T 2048.  The decode kernel takes the
+    # serve phase's pool, the optimizer kernel the train phase's parameter
+    # tree.
     "kernels": dict(interpret=False,
-                    flash=dict(batch=4, t=2048, heads=8, head_dim=128),
-                    fused=dict(m=8192, k=1024, n=1024)),
+                    flash=dict(batch=4, t=2048, heads=8, head_dim=128)),
     # benchmarks/bench_long_context.py's on-chip dims, depth 1
     "multichip": dict(train=dict(batches=3, epochs=1),
                       lm=dict(vocab=8192, t=8192, layers=1, embed=2048,
@@ -481,55 +480,8 @@ def kernel_update(ctx, sizes):
             "rel_err": _sig(worst), "bit_identical": exact}
 
 
-def kernel_fused(ctx, sizes):
-    """Fused LN->linear (``scale*x+shift`` prologue, bias epilogue) forward
-    + backward vs its plain-XLA reference."""
-    import jax
-    import jax.numpy as jnp
-
-    from mxnet_tpu.ops import pallas_fused
-
-    k = sizes["kernels"]
-    m, kd, n = k["fused"]["m"], k["fused"]["k"], k["fused"]["n"]
-    if not pallas_fused.supported(m, kd, n, jnp.bfloat16):
-        return {"outcome": "gated", "shape": (m, kd, n)}
-    rng = np.random.RandomState(4)
-
-    def put(x, dtype):
-        return jax.device_put(jnp.asarray(x, dtype), ctx.jax_device)
-
-    x = put(rng.normal(0, 1, (m, kd)), jnp.bfloat16)
-    w = put(rng.normal(0, kd ** -0.5, (n, kd)), jnp.bfloat16)
-    scale = put(rng.uniform(0.5, 1.5, (kd,)), jnp.float32)
-    shift = put(rng.normal(0, 0.1, (kd,)), jnp.float32)
-    bias = put(rng.normal(0, 0.1, (n,)), jnp.bfloat16)
-    dy = put(rng.normal(0, 1, (m, n)), jnp.bfloat16)
-
-    def loss(fn, **kw):
-        def f(x_, scale_, shift_, w_, bias_):
-            y = fn(x_, scale_, shift_, w_, relu=False, bias=bias_, wt=True,
-                   **kw)[0]
-            return jnp.sum((y * dy).astype(jnp.float32)), y
-        return jax.jit(jax.value_and_grad(f, argnums=(0, 1, 2, 3, 4),
-                                          has_aux=True))
-
-    (_, y_got), g_got = loss(pallas_fused.fused_scale_relu_matmul,
-                             interpret=k["interpret"])(x, scale, shift, w,
-                                                       bias)
-    (_, y_ref), g_ref = loss(pallas_fused.reference_impl)(x, scale, shift,
-                                                          w, bias)
-    errs = {"y": _rel_err(y_got, y_ref)}
-    for name, a, b in zip(("dx", "dscale", "dshift", "dw", "dbias"),
-                          g_got, g_ref):
-        errs[name] = _rel_err(a, b)
-    # bf16 operands, f32 accumulation over k on both sides
-    assert max(errs.values()) <= 3e-2, errs
-    return {"outcome": "compiled", "shape": (m, kd, n),
-            "rel_err": {n_: _sig(x_) for n_, x_ in errs.items()}}
-
-
 KERNELS = {"flash_attention": kernel_flash, "paged_decode": kernel_decode,
-           "fused_update": kernel_update, "fused_ln_linear": kernel_fused}
+           "fused_update": kernel_update}
 
 
 def kernels(ctx, sizes):

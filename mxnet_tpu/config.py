@@ -168,18 +168,6 @@ register("MXNET_PALLAS_UPDATE", bool, False,
          "and the eager opt_owner fall back to the existing per-param "
          "path unchanged (the mxlint flop-dtype pass's pallas-fallback "
          "tripwire covers the promise on canonical programs).")
-register("MXNET_PALLAS_FUSED", bool, False,
-         "Dispatch the LM training path's LN->linear segments (the "
-         "pre-norm block's LN->QKV projections and LN->MLP, including "
-         "the ReLU prologue and the residual add) to the fused Pallas "
-         "epilogue kernel (ops/pallas_fused.py): the affine apply, the "
-         "matmul, the bias, the ReLU and the residual run in ONE HBM "
-         "pass over the activations, forward AND backward (custom_vjp), "
-         "inside the compiled donated train step.  Engages on TPU, or "
-         "anywhere under MXNET_PALLAS_INTERPRET; unsupported shapes/"
-         "dtypes, mesh-sharded executors and every other caller fall "
-         "back to the einsum composition with identical semantics "
-         "(ops/fused_lm.py FUSED_PATH records which path traced).")
 register("MXNET_PALLAS_TUNE", bool, False,
          "Autotune Pallas kernel block shapes on the live device "
          "(ops/tuning.py): each kernel module's registered candidate "

@@ -85,7 +85,7 @@ import numpy as np
 from .base import MXNetError
 from . import context as ctx_mod
 from . import obs as _obs
-from .registry import OpContext
+from .registry import OpContext, producers_of
 
 __all__ = ["DecodePredictor", "DecodeServer", "DecodeState",
            "NGramProposer", "DraftProposer"]
@@ -561,7 +561,8 @@ class DecodePredictor:
                     octx = OpContext(
                         is_train=False,
                         rng=jax.random.fold_in(base_key, seq),
-                        mesh_active=self._mesh is not None, mesh=self._mesh)
+                        mesh_active=self._mesh is not None, mesh=self._mesh,
+                        producers=producers_of(node))
                     outs, _ = node.op.fcompute(attrs, ins, aux_ins, octx)
             for i, o in enumerate(outs):
                 values[(id(node), i)] = o

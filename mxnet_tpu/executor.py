@@ -27,7 +27,7 @@ import numpy as np
 
 from .base import MXNetError
 from .context import Context, current_context
-from .registry import OpContext
+from .registry import OpContext, producers_of
 from . import ndarray as nd
 
 __all__ = ["Executor"]
@@ -208,7 +208,8 @@ class Executor:
             octx = OpContext(is_train=is_train, rng=node_rng,
                              mesh_active=getattr(self, "_mesh_active",
                                                  False),
-                             mesh=getattr(self, "_mesh", None))
+                             mesh=getattr(self, "_mesh", None),
+                             producers=producers_of(node))
             with _node_scope(node):
                 if spans:
                     with _prof.Scope(node.name):

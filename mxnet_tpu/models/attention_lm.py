@@ -22,44 +22,24 @@ _NORM = {LAYER_ATTR: "norm"}
 _HEAD = {LAYER_ATTR: "head_loss"}
 
 
-def _normalize(data):
-    """The LayerNorm statistics half: (x - mean) / sqrt(var + eps) over
-    the last axis, no affine — the gamma/beta tail rides either the
-    broadcast ops (:func:`layer_norm`) or a FusedLNLinear segment."""
+def layer_norm(data, embed, name):
+    """LayerNorm over the last axis, built from registry ops (mean/var
+    through broadcast arithmetic; gamma/beta as 1-wide FC is avoided — the
+    scale/shift ride as learnable broadcast params via elementwise ops)."""
+    gamma = sym.Variable(name + "_ln_gamma", shape=(1, 1, embed))
+    beta = sym.Variable(name + "_ln_beta", shape=(1, 1, embed))
     with AttrScope(**_NORM):
         mean = sym.mean(data, axis=-1, keepdims=True)
         centered = sym.broadcast_sub(data, mean)
         var = sym.mean(sym.square(centered), axis=-1, keepdims=True)
         inv = sym.rsqrt(var + 1e-5)
-        return sym.broadcast_mul(centered, inv)
-
-
-def _ln_affine(name, embed):
-    gamma = sym.Variable(name + "_ln_gamma", shape=(1, 1, embed))
-    beta = sym.Variable(name + "_ln_beta", shape=(1, 1, embed))
-    return gamma, beta
-
-
-def layer_norm(data, embed, name):
-    """LayerNorm over the last axis, built from registry ops (mean/var
-    through broadcast arithmetic; gamma/beta as 1-wide FC is avoided — the
-    scale/shift ride as learnable broadcast params via elementwise ops)."""
-    normed = _normalize(data)
-    gamma, beta = _ln_affine(name, embed)
-    with AttrScope(**_NORM):
+        normed = sym.broadcast_mul(centered, inv)
         return sym.broadcast_add(sym.broadcast_mul(normed, gamma), beta)
 
 
 def block(data, embed, heads, ffn_hidden, name, moe_experts=0,
           moe_capacity_factor=0.0, moe_top_k=1, num_kv_heads=0):
     """One pre-norm decoder block.
-
-    The LN->linear segments run through :class:`FusedLNLinear` (the LN
-    affine tail + projection as one op): under ``MXNET_PALLAS_FUSED``
-    the op dispatches to the fused Pallas epilogue kernel forward and
-    backward, otherwise it traces the same five-op einsum composition
-    this graph always ran.  Parameter names/shapes are unchanged either
-    way (``*_ln_gamma``/``*_ln_beta``, FC-layout weight/bias).
 
     ``num_kv_heads`` < ``heads`` emits grouped-query attention: the K/V
     projections are physically ``num_kv_heads * head_dim`` wide (same
@@ -72,14 +52,13 @@ def block(data, embed, heads, ffn_hidden, name, moe_experts=0,
             "attention_lm.block: num_heads=%d not divisible by "
             "num_kv_heads=%d" % (heads, kv_heads))
     kv_hidden = kv_heads * (embed // heads)
-    normed = _normalize(data)
-    gamma, beta = _ln_affine(name + "_att", embed)
-    q = sym.FusedLNLinear(normed, gamma, beta, num_hidden=embed,
-                          name=name + "_q")
-    k = sym.FusedLNLinear(normed, gamma, beta, num_hidden=kv_hidden,
-                          name=name + "_k")
-    v = sym.FusedLNLinear(normed, gamma, beta, num_hidden=kv_hidden,
-                          name=name + "_v")
+    attn_in = layer_norm(data, embed, name + "_att")
+    q = sym.FullyConnected(attn_in, num_hidden=embed, flatten=False,
+                           name=name + "_q")
+    k = sym.FullyConnected(attn_in, num_hidden=kv_hidden, flatten=False,
+                           name=name + "_k")
+    v = sym.FullyConnected(attn_in, num_hidden=kv_hidden, flatten=False,
+                           name=name + "_v")
     if kv_heads != heads:
         att = sym.dot_product_attention(q, k, v, num_heads=heads,
                                         num_kv_heads=kv_heads, causal=True)
@@ -90,29 +69,24 @@ def block(data, embed, heads, ffn_hidden, name, moe_experts=0,
                              name=name + "_attout")
     data = data + att
 
-    ffn_normed = _normalize(data)
-    fgamma, fbeta = _ln_affine(name + "_ffn", embed)
+    ffn_in = layer_norm(data, embed, name + "_ffn")
     if moe_experts > 0:
         # MoEFFN routes tokens over the trailing axis; (B, T, E) in/out.
         # capacity_factor > 0 arms the sparse capacity-slot dispatch
         # (the explicit all-to-all program under an 'expert' mesh);
         # moe_top_k routes each token to its k best experts.
-        ffn_in = sym.broadcast_add(sym.broadcast_mul(ffn_normed, fgamma),
-                                   fbeta)
         ffn = sym.MoEFFN(ffn_in, num_experts=moe_experts,
                          hidden_size=ffn_hidden,
                          capacity_factor=moe_capacity_factor,
                          num_experts_per_tok=moe_top_k,
                          name=name + "_moe")
-        return data + ffn
-    h = sym.FusedLNLinear(ffn_normed, fgamma, fbeta,
-                          num_hidden=ffn_hidden, name=name + "_ffn1")
-    # ffn2 consumes the PRE-activation h: its ReLU is the fused op's
-    # prologue and the block's residual rides its epilogue, so the
-    # activated tensor never materializes in HBM on the kernel path
-    return sym.FusedLNLinear(h, residual=data, num_hidden=embed,
-                             relu=True, no_affine=True, has_residual=True,
-                             name=name + "_ffn2")
+    else:
+        h = sym.FullyConnected(ffn_in, num_hidden=ffn_hidden, flatten=False,
+                               name=name + "_ffn1")
+        h = sym.Activation(h, act_type="relu")
+        ffn = sym.FullyConnected(h, num_hidden=embed, flatten=False,
+                                 name=name + "_ffn2")
+    return data + ffn
 
 
 def get_symbol(vocab_size, seq_len, num_layers=2, embed=128, heads=4,

@@ -267,14 +267,6 @@ class ProgramAccounting:
                 for k in ("update_path", "per_param_bytes",
                           "fused_bytes"):
                     row[k] = cost.get(k)
-            if cost.get("fused_path"):
-                # the lm_fused row: which LN->linear path the LM step's
-                # FusedLNLinear segments dispatch, plus both paths'
-                # priced bytes — the kernel's HBM diet vs the einsum
-                # engine-op chain, per program
-                for k in ("fused_path", "fused_kernel_bytes",
-                          "fused_einsum_bytes", "fused_segments"):
-                    row[k] = cost.get(k)
             if "error" in cost:
                 row["error"] = cost["error"]
             if wall > 0 and calls > 0:

@@ -28,7 +28,6 @@ LAYER_ATTR = "__layer__"
 LAYER_OF_OP = {
     "dot_product_attention": "attn",
     "FullyConnected": "linear",
-    "FusedLNLinear": "linear",
     "BatchNorm": "norm",
     "Convolution": "conv",
     "Pooling": "pool",

@@ -201,21 +201,36 @@ def register_all():
                       num_inputs=1, hint="softmaxactivation"))
 
     # ---------------- FullyConnected ----------------
-    def _fc(attrs, data, weight, *bias):
-        if attrs.get("flatten", True):
+    def _fc(attrs, inputs, aux, octx):
+        data, weight, *bias = inputs
+        flatten = attrs.get("flatten", True)
+        # flatten=False contracts the last dim of a (B, T, K) operand as ONE
+        # (B*T, K) matmul: the reshape fuses into the element-wise op that
+        # made the operand, and under a mesh the weight gradient is
+        # all-reduced once over the axes the rows are sharded on.  Straight
+        # after attention the operand is taken as it comes: with that dot
+        # merged too XLA:TPU lays the whole residual stream out as rows and
+        # the LM step is 9 % slower (PERF.md section 6, PR 32)
+        merge = not flatten \
+            and "dot_product_attention" not in octx.producers
+        if flatten:
             x = data.reshape(data.shape[0], -1)
+        elif merge:
+            x = data.reshape(-1, data.shape[-1])
         else:
             x = data
         out = jnp.dot(x, weight.T)
         if bias:
             out = out + bias[0]
-        return out
+        if merge:
+            out = out.reshape(data.shape[:-1] + out.shape[-1:])
+        return [out], list(aux)
 
     fc_schema = ParamSchema(Param("num_hidden", int, required=True),
                             Param("no_bias", bool, default=False),
                             Param("flatten", bool, default=True))
     register_op(OpDef(
-        "FullyConnected", simple_compute(_fc), schema=fc_schema,
+        "FullyConnected", _fc, schema=fc_schema,
         num_inputs=lambda a: 2 if a.get("no_bias") else 3,
         arguments=lambda a: ["data", "weight"] if a.get("no_bias")
         else ["data", "weight", "bias"],

@@ -1,7 +1,7 @@
 """Pallas block-shape autotuner + persistent tuning cache.
 
 The kernel modules ship block constants "swept on the bench chip" —
-``pallas_attention.BLOCK_Q = 128``, ``pallas_fused.BLOCK_M_BWD = 256``
+``pallas_attention.BLOCK_Q = 1024``, ``pallas_update.BLOCK_ROWS = 16``
 and friends — which are exactly wrong the day the fleet moves to the
 next device generation.  This module closes the shape problem the way
 AutoTVM closed it (Chen et al., 2018): each kernel module registers its
@@ -108,8 +108,7 @@ def register_space(op, version, defaults, constants, candidates, runner):
 def spaces():
     """{op: space} of every registered tunable space (imports the
     kernel modules so their registrations ran)."""
-    from . import (pallas_attention, pallas_decode, pallas_fused,  # noqa
-                   pallas_update)
+    from . import pallas_attention, pallas_decode, pallas_update  # noqa
 
     return dict(_SPACES)
 

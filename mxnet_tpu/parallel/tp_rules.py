@@ -169,29 +169,6 @@ def plan_tensor_parallel(symbol):
                     if bnode is not None and bnode.is_variable:
                         plan[bnode.name] = ("model",)
                     out_state = "feat"
-        elif name == "FusedLNLinear":
-            # the LM step's fused LN->linear segment (ops/fused_lm.py)
-            # carries FC's (num_hidden, K) weight with optional
-            # gamma/beta/residual inputs ahead of it — same Megatron
-            # column/row pairing as FullyConnected, located through the
-            # op's argument list.  gamma/beta are per-INPUT-feature and
-            # only valid replicated, so the row-parallel role (sharded
-            # input features) is taken only for no_affine segments.
-            from ..ops.fused_lm import _arg_names
-
-            args = _arg_names(attrs)
-            data_st = instate(ins[0])
-            wnode = ins[args.index("weight")][0]
-            bnode = ins[args.index("bias")][0]
-            if wnode.is_variable:
-                if data_st == "feat" and attrs.get("no_affine", False):
-                    plan[wnode.name] = (None, "model")
-                    out_state = "rep"
-                else:
-                    plan[wnode.name] = ("model", None)
-                    if bnode.is_variable:
-                        plan[bnode.name] = ("model",)
-                    out_state = "feat"
         elif name == "Convolution":
             data_st = instate(ins[0])
             wnode = ins[1][0]

@@ -27,7 +27,8 @@ import functools
 from .attrs import FrozenAttrs, ParamSchema
 from .base import MXNetError
 
-__all__ = ["OpDef", "register", "get_op", "list_ops", "invoke", "OpContext"]
+__all__ = ["OpDef", "register", "get_op", "list_ops", "invoke", "OpContext",
+           "producers_of"]
 
 _OPS = {}
 
@@ -38,16 +39,26 @@ class OpContext:
     GSPMD-opaque fast paths, e.g. pallas kernels, bail out when set).
     ``mesh`` carries the executor's Mesh (or None) for ops that place
     sharding constraints themselves — e.g. sparse MoE dispatch pinning
-    its expert-major tensors to the 'expert' axis."""
+    its expert-major tensors to the 'expert' axis.  ``producers`` names
+    the ops whose outputs are this node's inputs (None for a variable;
+    empty where the caller walks no graph), for an op whose lowering
+    depends on what hands it its operand."""
 
-    __slots__ = ("is_train", "rng", "mesh_active", "mesh")
+    __slots__ = ("is_train", "rng", "mesh_active", "mesh", "producers")
 
     def __init__(self, is_train=False, rng=None, mesh_active=False,
-                 mesh=None):
+                 mesh=None, producers=()):
         self.is_train = is_train
         self.rng = rng
         self.mesh_active = mesh_active
         self.mesh = mesh
+        self.producers = producers
+
+
+def producers_of(node):
+    """``OpContext.producers`` of one graph node."""
+    return tuple(src.op.name if src.op is not None else None
+                 for src, _ in node.inputs)
 
 
 def _default_arg_names(n):

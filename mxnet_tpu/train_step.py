@@ -85,41 +85,6 @@ def _weak_update_prober(step):
     return prober
 
 
-def _weak_fused_prober(step):
-    """The ``lm_fused`` roofline row's static prober: the LN->linear
-    segments' priced HBM bytes on the path the step's FusedLNLinear
-    nodes CURRENTLY dispatch
-    (``ops.fused_lm.priced_fused_cost_for_step``) — so arming
-    MXNET_PALLAS_FUSED visibly moves the LM row from the einsum
-    engine-op chain's bytes to the fused kernel's.  Zero FLOPs of its
-    own (the matmul FLOPs already live in the train_step row); both
-    paths' bytes ride along for the table's consumer.  Weakly bound,
-    same lifetime rule as :func:`_weak_prober`."""
-    import weakref
-
-    ref = weakref.ref(step)
-
-    def prober():
-        live = ref()
-        if live is None:
-            return None
-        from .ops.fused_lm import priced_fused_cost_for_step
-
-        priced = priced_fused_cost_for_step(live)
-        if priced is None:
-            return None
-        armed = priced["fused_path"] == "pallas"
-        return {"flops": 0,
-                "bytes": priced["fused_kernel_bytes" if armed
-                                else "fused_einsum_bytes"],
-                "fused_path": priced["fused_path"],
-                "fused_kernel_bytes": priced["fused_kernel_bytes"],
-                "fused_einsum_bytes": priced["fused_einsum_bytes"],
-                "fused_segments": priced["segments"]}
-
-    return prober
-
-
 def _register_step_spec(step):
     """Register a step's :class:`~mxnet_tpu.programs.spec.ProgramSpec`
     with the process-wide program registry — name, donation map, lazy
@@ -784,14 +749,6 @@ class CompiledTrainStep:
                 else "%s:opt_update" % self.telemetry_name
             _obs.programs.register_static(row,
                                           _weak_update_prober(self))
-            # the LM fused-segment row, only for graphs that have the
-            # segments (ResNet-class steps keep their tables clean)
-            from .ops.fused_lm import step_has_fused_segments
-            if step_has_fused_segments(self):
-                frow = "lm_fused" if self.telemetry_name == "train_step" \
-                    else "%s:lm_fused" % self.telemetry_name
-                _obs.programs.register_static(frow,
-                                              _weak_fused_prober(self))
         with _obs.program_span(self.telemetry_name):
             return self._run_impl(data_batch, group)
 

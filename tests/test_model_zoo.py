@@ -92,6 +92,127 @@ def test_attention_lm_trains():
     assert score["Perplexity"] < 4.0, score
 
 
+# the LM's graph variants, at one tiny size: vocab 17, T 8, one layer of
+# width 16, 4 heads, ffn 32
+LM_VARIANTS = {"mha": {}, "gqa": {"num_kv_heads": 2}, "moe": {"moe_experts": 2}}
+_LN_OPS = {"mean", "broadcast_sub", "square", "_plus_scalar", "rsqrt",
+           "broadcast_mul", "broadcast_add"}
+_LM_OPS = _LN_OPS | {"Embedding", "FullyConnected", "dot_product_attention",
+                     "_plus", "Reshape", "SoftmaxOutput"}
+LM_OPS = {"mha": _LM_OPS | {"Activation"}, "gqa": _LM_OPS | {"Activation"},
+          "moe": _LM_OPS | {"MoEFFN"}}
+
+
+def _lm_variant(variant):
+    return models.get_attention_lm(vocab_size=17, seq_len=8, num_layers=1,
+                                   embed=16, heads=4, ffn_hidden=32,
+                                   **LM_VARIANTS[variant])
+
+
+def _lm_params(kv, ffn):
+    """The arguments of ``_lm_variant`` in ``list_arguments()`` order, as
+    the graph gave them before the block returned to plain ops (PR 32): the
+    names and layouts a checkpoint and the benchmark's weights are keyed
+    by."""
+    return ([("data", (2, 8)), ("embed_weight", (17, 16)),
+             ("pos_embed_weight", (1, 8, 16)),
+             ("layer0_att_ln_gamma", (1, 1, 16)),
+             ("layer0_att_ln_beta", (1, 1, 16)),
+             ("layer0_q_weight", (16, 16)), ("layer0_q_bias", (16,)),
+             ("layer0_k_weight", (kv, 16)), ("layer0_k_bias", (kv,)),
+             ("layer0_v_weight", (kv, 16)), ("layer0_v_bias", (kv,)),
+             ("layer0_attout_weight", (16, 16)),
+             ("layer0_attout_bias", (16,)),
+             ("layer0_ffn_ln_gamma", (1, 1, 16)),
+             ("layer0_ffn_ln_beta", (1, 1, 16))]
+            + ffn
+            + [("final_ln_gamma", (1, 1, 16)), ("final_ln_beta", (1, 1, 16)),
+               ("head_weight", (17, 16)), ("head_bias", (17,)),
+               ("softmax_label", (2, 8))])
+
+
+_DENSE_FFN = [("layer0_ffn1_weight", (32, 16)), ("layer0_ffn1_bias", (32,)),
+              ("layer0_ffn2_weight", (16, 32)), ("layer0_ffn2_bias", (16,))]
+LM_PARAMS = {
+    "mha": _lm_params(16, _DENSE_FFN),
+    "gqa": _lm_params(8, _DENSE_FFN),
+    "moe": _lm_params(16, [("layer0_moe_gate_weight", (16, 2)),
+                           ("layer0_moe_expert1_weight", (2, 16, 32)),
+                           ("layer0_moe_expert1_bias", (2, 32)),
+                           ("layer0_moe_expert2_weight", (2, 32, 16)),
+                           ("layer0_moe_expert2_bias", (2, 16))]),
+}
+
+
+@pytest.mark.parametrize("variant", sorted(LM_VARIANTS))
+def test_attention_lm_block_is_plain_ops(variant):
+    """The decoder block is expressed in the registry's ordinary ops: no
+    node of the LM belongs to an op that exists for this model alone."""
+    from mxnet_tpu.registry import list_ops
+
+    net = _lm_variant(variant)
+    ops = {nd_.op.name for nd_ in net._topo() if nd_.op is not None}
+    assert ops == LM_OPS[variant], sorted(ops ^ LM_OPS[variant])
+    # nor does the registry hold an LN->linear op for a block to reach for
+    assert not [name for name in list_ops() if "LNLinear" in name]
+
+
+@pytest.mark.parametrize("variant", sorted(LM_VARIANTS))
+def test_attention_lm_params_unchanged(variant):
+    """Argument names, order and inferred shapes are the literal table: a
+    checkpoint, and ``chipbench/weights.py``'s draw by sorted name, meet
+    the same parameters whatever ops the block is built from."""
+    net = _lm_variant(variant)
+    arg_shapes, _, _ = net.infer_shape(data=(2, 8), softmax_label=(2, 8))
+    got = list(zip(net.list_arguments(), [tuple(s) for s in arg_shapes]))
+    assert got == LM_PARAMS[variant]
+
+
+@pytest.mark.parametrize("variant", ["mha", "gqa"])
+def test_attention_lm_tp_plan(variant):
+    """``plan_tensor_parallel`` over the real model: Megatron pairs through
+    the attention op and through the FFN's ``Activation`` — column q/k/v/
+    ffn1 with sharded biases, row attout/ffn2 with replicated biases, and
+    LayerNorm's per-feature gamma/beta left out (replicated)."""
+    from mxnet_tpu.parallel.tp_rules import plan_tensor_parallel
+
+    plan = plan_tensor_parallel(_lm_variant(variant))
+    for name in ("q", "k", "v", "ffn1"):
+        assert plan["layer0_%s_weight" % name] == ("model", None), name
+        assert plan["layer0_%s_bias" % name] == ("model",), name
+    for name in ("attout", "ffn2"):
+        assert plan["layer0_%s_weight" % name] == (None, "model"), name
+        assert "layer0_%s_bias" % name not in plan, name
+    assert not [n for n in plan if n.endswith(("ln_gamma", "ln_beta"))], plan
+
+
+def test_fully_connected_row_merge_follows_producer():
+    """``FullyConnected(flatten=False)`` contracts a (B, T, K) operand as
+    one (B*T, K) matmul, except straight after attention, where the operand
+    is taken as it comes: the placement the LM cells' rates depend on
+    (PERF.md section 6, PR 32)."""
+    import jax
+
+    from mxnet_tpu.registry import OpContext, get_op
+
+    fc = get_op("FullyConnected")
+    attrs = {"num_hidden": 4, "flatten": False}
+    args = (np.zeros((2, 3, 5), np.float32), np.zeros((4, 5), np.float32),
+            np.zeros((4,), np.float32))
+
+    def lhs_ranks(producers):
+        octx = OpContext(producers=producers)
+        jaxpr = jax.make_jaxpr(
+            lambda *a: fc.fcompute(attrs, list(a), [], octx)[0][0])(*args)
+        assert jaxpr.out_avals[0].shape == (2, 3, 4)
+        return [e.invars[0].aval.ndim for e in jaxpr.eqns
+                if e.primitive.name == "dot_general"]
+
+    assert lhs_ranks(("broadcast_add", None, None)) == [2]
+    assert lhs_ranks(()) == [2]
+    assert lhs_ranks(("dot_product_attention", None, None)) == [3]
+
+
 def test_attention_lm_moe_variant_steps():
     b, t, vocab = 4, 8, 11
     net = models.get_attention_lm(vocab_size=vocab, seq_len=t,

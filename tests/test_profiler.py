@@ -91,3 +91,32 @@ def test_fit_with_monitor_taps(tmp_path):
             optimizer_params={"learning_rate": 0.1})
     assert mod._fused_step is None
     assert "fc_output" in seen
+
+
+def test_registered_options_are_read_and_documented():
+    """Every option ``config.py`` registers is read somewhere in the
+    package outside ``config.py`` and has a row in ``docs/env_vars.md``,
+    and that file names no ``MXNET_`` option that is not registered: an
+    option deleted (or added) by halves fails here."""
+    import re
+
+    from mxnet_tpu import config
+
+    pkg = os.path.dirname(os.path.abspath(mx.__file__))
+    registered = set(config._REGISTRY)
+    source = []
+    for folder, _, files in os.walk(pkg):
+        for name in files:
+            path = os.path.join(folder, name)
+            if name.endswith(".py") and path != os.path.join(pkg, "config.py"):
+                with open(path) as f:
+                    source.append(f.read())
+    read = set(re.findall(r"\bMXNET_[A-Z0-9_]+\b", "\n".join(source)))
+    assert registered - read == set(), sorted(registered - read)
+
+    with open(os.path.join(os.path.dirname(pkg), "docs", "env_vars.md")) as f:
+        doc = f.read()
+    rows = set(re.findall(r"^\| `(MXNET_[A-Z0-9_]+)` \|", doc, re.M))
+    assert registered - rows == set(), sorted(registered - rows)
+    named = set(re.findall(r"\bMXNET_[A-Z0-9_]+\b", doc))
+    assert named - registered == set(), sorted(named - registered)

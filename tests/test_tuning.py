@@ -168,11 +168,10 @@ def test_shape_class_roundtrip():
 
 
 def test_all_kernel_spaces_registered():
-    """The four shipped Pallas kernel modules all registered spaces —
+    """The three shipped Pallas kernel modules all registered spaces —
     the same surface the mxlint tuner-coverage pass audits."""
     spaces = tuning.spaces()
-    for op in ("pallas_fused", "pallas_attention", "pallas_decode",
-               "pallas_update"):
+    for op in ("pallas_attention", "pallas_decode", "pallas_update"):
         assert op in spaces, sorted(spaces)
         sp = spaces[op]
         assert sp.defaults and sp.constants
@@ -200,9 +199,7 @@ def test_cold_process_zero_probe_cache_hit(tune_cache):
     """The acceptance proof: sweep every REAL kernel space in this
     process, then a cold subprocess sharing only the cache directory
     resolves all of them with PROBE_COUNT == 0."""
-    cases = [("pallas_fused",
-              tuning.shape_class_for(m=256, k=128, n=256), "float32"),
-             ("pallas_attention",
+    cases = [("pallas_attention",
               tuning.shape_class_for(t=128, d=64), "float32"),
              ("pallas_decode", tuning.shape_class_for(m=64), "any"),
              ("pallas_update", tuning.shape_class_for(n=4096), "any")]
