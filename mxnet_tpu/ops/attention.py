@@ -259,23 +259,68 @@ def cache_append(cache, new, start_pos, num_heads=1):
 
 
 def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
-                num_kv_heads=0):
+                num_kv_heads=0, mesh_active=False):
     """Shared length-masked cache-attention core behind
     :func:`sdpa_decode` (tq == 1) and :func:`sdpa_verify` (tq == k+1).
-    Quantized caches (:class:`QuantKV`) dequantize here, per head, before
-    the score matmul — the logits are bit-identical to attending the
-    dequantized buffers densely, which is what the parity tests pin.
+
+    A quantized cache (:class:`QuantKV`) is attended as it is stored.  Its
+    scale is per (token, kv-head), constant along the contracted head
+    width, so it commutes with both products: they read the narrow
+    ``data`` plane cast to the query's dtype (every int8 / fp8 value is
+    exact in bfloat16) and accumulate in float32, the key scales multiply
+    the float32 logits before the mask, and the value scales multiply the
+    normalized probabilities before the PV product.  The float (B, C, E)
+    copy :func:`dequantize_kv` returns is never built; the result is that
+    of attending the dequantized buffers densely, which is what the
+    parity tests pin.  K and V go each by their own type, and a
+    plain-array cache takes the plain einsums.
+
+    One query row (tq == 1, the decode step) over a stored plane takes
+    the products in another shape.  Per head they are matrix-vector
+    products, which XLA:TPU lowers to a float32 multiply-reduce over a
+    float32 copy of the plane laid out by heads, whatever dtype it was
+    handed.  So the plane stays (B, C, E) and the query row becomes
+    block-diagonal over kv-heads, (B, E, H): one product per slot whose
+    contraction is the plane's own minor dimension, H columns of the MXU
+    where a head's product would fill one; PV likewise, keeping the
+    diagonal blocks of a (B, H, Ev) result.  The float operand (q, then
+    p) is taken at ``Precision.HIGHEST`` — three bfloat16 pieces against
+    a plane that is exact in one — because the multiply-reduce this
+    replaces was exact in float32.  With tq > 1 the per-head einsums are
+    matrix products already (one MXU pass, as over a float cache).  So
+    they are under a mesh (``mesh_active``), which shards the plane's E
+    by head groups: GSPMD cannot see that a block-diagonal contraction
+    is local to a shard, and would all-gather the plane.
+
     With ``num_kv_heads < num_heads`` the caches hold H_kv heads (and
     QuantKV scale planes are per-(token, kv-head)); q-head ``h`` scores
     kv-head ``h // G`` through the grouped einsum — no broadcast copy."""
     import jax.numpy as jnp
+    from jax.lax import Precision
 
     b, tq, e = q.shape
     kvh, g = check_head_groups(num_heads, num_kv_heads, e,
                                where="sdpa_decode")
+    # the q-head axes of the einsums: (H,), or (H_kv, G) when grouped;
+    # logits are (B, *heads, tq, C)
+    heads, hx = ((num_heads,), "h") if g == 1 else ((kvh, g), "hg")
+    ones = (1,) * len(heads)
+
+    def stored(cache):
+        # -> (the plane the products read, its scales shaped to multiply
+        # the logits, or None for a float cache)
+        if not isinstance(cache, QuantKV):
+            return cache, None
+        assert cache.scale.shape[-1] == kvh, \
+            "cache quantized with %d heads, caller expects %d" \
+            % (cache.scale.shape[-1], kvh)
+        return (cache.data.astype(q.dtype),
+                jnp.swapaxes(cache.scale, 1, 2).reshape(
+                    (b, kvh) + ones + (-1,)))
+
     with _scope("attn", "kv_dequant"):
-        k_cache = dequantize_kv(k_cache, kvh)
-        v_cache = dequantize_kv(v_cache, kvh)
+        k_cache, k_scale = stored(k_cache)
+        v_cache, v_scale = stored(v_cache)
     c = k_cache.shape[1]
     ev = v_cache.shape[2]
     if ev % kvh != 0:
@@ -287,40 +332,55 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
             "sdpa_decode: key cache dim %d != num_kv_heads=%d * "
             "head_dim=%d" % (k_cache.shape[2], kvh, hd))
     scale = scale or 1.0 / np.sqrt(hd)
-    if g == 1:
-        # ungrouped path kept verbatim (G=1 bit-identity)
-        qh = q.reshape(b, tq, num_heads, hd)
-        kh = k_cache.reshape(b, c, num_heads, hd)
-        vh = v_cache.reshape(b, c, num_heads, ev // num_heads)
-        with _scope("attn", "scores"):
-            logits = jnp.einsum("bqhd,bkhd->bhqk", qh,
-                                kh).astype(jnp.float32) * scale
-            total = jnp.asarray(total_len, jnp.int32).reshape(-1, 1, 1, 1)
-            qpos = jnp.arange(tq, dtype=jnp.int32).reshape(1, 1, tq, 1)
-            limit = jnp.minimum(total - (tq - 1) + qpos, c)
-            slot = jnp.arange(c, dtype=jnp.int32).reshape(1, 1, 1, c)
-            logits = jnp.where(slot < limit, logits,
-                               jnp.finfo(jnp.float32).min)
-            m = jnp.max(logits, axis=-1, keepdims=True)
-            p = jnp.exp(logits - m)
-            p = p / jnp.sum(p, axis=-1, keepdims=True)
-        out = jnp.einsum("bhqk,bkhe->bqhe", p.astype(vh.dtype), vh)
-        return out.reshape(b, tq, ev)
-    qh = q.reshape(b, tq, kvh, g, hd)
+    row = tq == 1 and not mesh_active
+    exact = (Precision.HIGHEST, Precision.DEFAULT)
+
+    def own():
+        # (j, 1, h, 1): kv-head j is q-head (h, g)'s own
+        return jnp.eye(kvh, dtype=bool)[:, None, :, None]
+
+    qh = q.reshape((b, tq) + heads + (hd,))
     kh = k_cache.reshape(b, c, kvh, hd)
     vh = v_cache.reshape(b, c, kvh, ev // kvh)
     with _scope("attn", "scores"):
-        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qh,
-                            kh).astype(jnp.float32) * scale
-        total = jnp.asarray(total_len, jnp.int32).reshape(-1, 1, 1, 1, 1)
-        qpos = jnp.arange(tq, dtype=jnp.int32).reshape(1, 1, 1, tq, 1)
+        if k_scale is not None and row:
+            # (B, j, d, h, g): q-head (h, g)'s row in the columns of its
+            # own kv-head, zeros in every other
+            qbd = jnp.where(own(), jnp.transpose(
+                q.reshape(b, kvh, g, hd), (0, 3, 1, 2))[:, None], 0)
+            logits = jnp.einsum(
+                "ben,bke->bnk", qbd.reshape(b, kvh * hd, num_heads),
+                k_cache, precision=exact, preferred_element_type=jnp.float32
+            ).reshape((b,) + heads + (1, c)) * scale
+        else:
+            logits = jnp.einsum(
+                "bq%sd,bkhd->b%sqk" % (hx, hx), qh, kh,
+                preferred_element_type=None if k_scale is None
+                else jnp.float32).astype(jnp.float32) * scale
+        if k_scale is not None:
+            with _scope("attn", "kv_dequant"):
+                logits = logits * k_scale
+        total = jnp.asarray(total_len, jnp.int32).reshape((-1, 1, 1) + ones)
+        qpos = jnp.arange(tq, dtype=jnp.int32).reshape((1,) + ones + (tq, 1))
         limit = jnp.minimum(total - (tq - 1) + qpos, c)
-        slot = jnp.arange(c, dtype=jnp.int32).reshape(1, 1, 1, 1, c)
+        slot = jnp.arange(c, dtype=jnp.int32).reshape((1, 1) + ones + (c,))
         logits = jnp.where(slot < limit, logits, jnp.finfo(jnp.float32).min)
         m = jnp.max(logits, axis=-1, keepdims=True)
         p = jnp.exp(logits - m)
         p = p / jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhgqk,bkhe->bqhge", p.astype(vh.dtype), vh)
+        if v_scale is not None:
+            with _scope("attn", "kv_dequant"):
+                p = p * v_scale
+    if v_scale is not None and row:
+        full = jnp.einsum("bnk,bke->bne", p.reshape(b, num_heads, c), v_cache,
+                          precision=exact,
+                          preferred_element_type=jnp.float32)
+        out = jnp.sum(jnp.where(
+            own(), full.reshape(b, kvh, g, kvh, ev // kvh), 0), axis=3)
+    else:
+        out = jnp.einsum(
+            "b%sqk,bkhe->bq%se" % (hx, hx), p.astype(vh.dtype), vh,
+            preferred_element_type=None if v_scale is None else jnp.float32)
     return out.reshape(b, tq, num_heads * (ev // kvh))
 
 
@@ -335,7 +395,8 @@ def sdpa_decode(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
     C); once the ring has wrapped every slot holds a live token and the
     window is all C slots.  Same fp32-softmax numerics as :func:`sdpa`, so
     prefill+decode logits match the full forward pass.  Caches may be
-    :class:`QuantKV` (dequantized per head inside the kernel).  With
+    :class:`QuantKV` (attended as stored, the scales applied to the
+    logits and the probabilities: :func:`_sdpa_cache`).  With
     tq > 1 the caller must not have wrapped past its own queries
     (total <= C) — that multi-position form is :func:`sdpa_verify`.
     """
@@ -566,10 +627,11 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
     int8/fp8 dequant and the length-masked softmax run in ONE HBM pass
     over the pool, split-K parallel over cache length.  Otherwise (knob
     off, unsupported shape, or a mesh-sharded pool — Pallas is opaque to
-    GSPMD) it falls back to the three-pass einsum path:
-    :func:`paged_gather` + :func:`sdpa_decode`/:func:`sdpa_verify`, whose
-    numerics the kernel matches within documented tolerances
-    (docs/inference.md)."""
+    GSPMD) it falls back to the two-pass einsum path:
+    :func:`paged_gather` + :func:`sdpa_decode`/:func:`sdpa_verify` over
+    the gathered view in the pool's storage dtype (an int8/fp8 view is
+    never dequantized into a float copy), whose numerics the kernel
+    matches within documented tolerances (docs/inference.md)."""
     engage, interp = decode_kernel_mode()
     if engage and not mesh_active:
         from . import pallas_decode as _pd
@@ -589,7 +651,7 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
         k_view = paged_gather(k_pool, table)
         v_view = paged_gather(v_pool, table)
     return _sdpa_cache(q, k_view, v_view, total_len, num_heads, scale,
-                       num_kv_heads=num_kv_heads)
+                       num_kv_heads=num_kv_heads, mesh_active=mesh_active)
 
 
 def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
@@ -616,7 +678,7 @@ def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
     else:
         DECODE_PATH["last"] = "einsum"
     return _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
-                       num_kv_heads=num_kv_heads)
+                       num_kv_heads=num_kv_heads, mesh_active=mesh_active)
 
 
 _KV_LAYOUT_WARNED = {"done": False}

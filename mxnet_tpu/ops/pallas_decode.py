@@ -2,11 +2,11 @@
 dequant + attention fused into ONE HBM pass.
 
 The serving path's einsum formulation walks the largest tensor in the
-system three times per generated token: ``ops.attention.paged_gather``
+system twice per generated token: ``ops.attention.paged_gather``
 materializes a full (B, M*pt, E) dense-ring view of the shared page pool
-in HBM, ``dequantize_kv`` materializes the f32 copy of an int8/fp8 pool,
-and ``sdpa_decode`` streams that copy again for the score/value matmuls.
-Decode attention is bandwidth-bound on exactly those bytes, so the three
+in HBM, and ``sdpa_decode`` streams that view again for the score/value
+matmuls (an int8/fp8 view as it is stored; no f32 copy is built).
+Decode attention is bandwidth-bound on exactly those bytes, so the two
 passes ARE the step time.
 
 These kernels implement the two fixes the literature names, together:

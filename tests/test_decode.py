@@ -579,6 +579,109 @@ def test_quantized_ring_wrap_matches_dense_window():
                                    err_msg="wrap mismatch at t=%d" % t)
 
 
+def _cache_attention_dequantized_first(q, k, v, total_len, num_heads, kvh):
+    """Cache attention as it was before QuantKV scales were folded in:
+    the caches are float (B, C, E) buffers (``dequantize_kv`` came
+    first), one einsum per product.  The reference for
+    ``test_quantized_attend_folds_scales``, and the jaxpr a float cache
+    must still trace."""
+    b, tq, e = q.shape
+    c, ev, hd, g = k.shape[1], v.shape[2], e // num_heads, num_heads // kvh
+    heads, hx = ((num_heads,), "h") if g == 1 else ((kvh, g), "hg")
+    ones = (1,) * len(heads)
+    qh = q.reshape((b, tq) + heads + (hd,))
+    kh = k.reshape(b, c, kvh, hd)
+    vh = v.reshape(b, c, kvh, ev // kvh)
+    logits = jnp.einsum("bq%sd,bkhd->b%sqk" % (hx, hx), qh,
+                        kh).astype(jnp.float32) * (1.0 / np.sqrt(hd))
+    total = jnp.asarray(total_len, jnp.int32).reshape((-1, 1, 1) + ones)
+    qpos = jnp.arange(tq, dtype=jnp.int32).reshape((1,) + ones + (tq, 1))
+    limit = jnp.minimum(total - (tq - 1) + qpos, c)
+    slot = jnp.arange(c, dtype=jnp.int32).reshape((1, 1) + ones + (c,))
+    logits = jnp.where(slot < limit, logits, jnp.finfo(jnp.float32).min)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    p = jnp.exp(logits - m)
+    p = p / jnp.sum(p, axis=-1, keepdims=True)
+    out = jnp.einsum("b%sqk,bkhe->bq%se" % (hx, hx), p.astype(vh.dtype), vh)
+    return out.reshape(b, tq, num_heads * (ev // kvh))
+
+
+def _eqn_outputs(jaxpr):
+    """Every intermediate a jaxpr computes, sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield from eqn.outvars
+        for sub in eqn.params.values():
+            sub = getattr(sub, "jaxpr", sub)
+            if hasattr(sub, "eqns"):
+                yield from _eqn_outputs(sub)
+
+
+@pytest.mark.parametrize("q_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("window", ["decode", "verify", "wrapped"])
+@pytest.mark.parametrize("kvh", [8, 2], ids=["ungrouped", "grouped4"])
+@pytest.mark.parametrize("kv_dtype", sorted(_KV_TOLS))
+def test_quantized_attend_folds_scales(kv_dtype, kvh, window, q_dtype):
+    """``_sdpa_cache`` attends a QuantKV cache as it is stored — the
+    narrow plane into the products, the per-(token, kv-head) scales onto
+    the float32 logits and probabilities — and equals attention over
+    ``dequantize_kv``'s output: a single query row (block-diagonal
+    products), the verify window (per-head einsums) and a wrapped ring,
+    ungrouped and grouped.  With a bfloat16 query it is no further from
+    the float32 answer than dequantizing first, and builds no float32
+    array of the cache's size; a float cache traces the jaxpr it always
+    did."""
+    heads, hd, c = 8, 32, 32
+    tq, total = {"decode": (1, [9, 20]), "verify": (4, [12, 32]),
+                 "wrapped": (1, [c + 5, c + 17])}[window]
+    rng = np.random.RandomState(40)
+    q = jnp.asarray(rng.normal(size=(B, tq, heads * hd)), q_dtype)
+    kc, vc = (attn.quantize_kv(
+        jnp.asarray(rng.normal(size=(B, c, kvh * hd)), jnp.float32),
+        kv_dtype, kvh) for _ in range(2))
+    total = jnp.asarray(total, jnp.int32)
+
+    def attend(q_, k_, v_):
+        return attn._sdpa_cache(q_, k_, v_, total, heads, None,
+                                num_kv_heads=kvh)
+
+    out = attend(q, kc, vc)
+    assert out.dtype == jnp.float32
+    kf, vf = attn.dequantize_kv(kc, kvh), attn.dequantize_kv(vc, kvh)
+    ref = np.asarray(_cache_attention_dequantized_first(
+        q.astype(jnp.float32), kf, vf, total, heads, kvh))
+    # one side quantized, the other a float buffer in the query's dtype:
+    # each goes by its own type (a bfloat16 V makes a bfloat16 output)
+    tol = dict(rtol=1e-5, atol=1e-6) if q_dtype == "float32" \
+        else dict(rtol=2e-2, atol=5e-2)
+    kq, vq = kf.astype(q_dtype), vf.astype(q_dtype)
+    for (k_, v_), (k32, v32) in (((kc, vq), (kf, vq.astype(jnp.float32))),
+                                 ((kq, vc), (kq.astype(jnp.float32), vf))):
+        np.testing.assert_allclose(
+            np.asarray(attend(q, k_, v_).astype(jnp.float32)),
+            np.asarray(_cache_attention_dequantized_first(
+                q.astype(jnp.float32), k32, v32, total, heads, kvh)), **tol)
+    if q_dtype == "float32":
+        np.testing.assert_allclose(np.asarray(out), ref, rtol=1e-5,
+                                   atol=1e-6)
+    else:
+        # dequantize-first in the query's dtype: what one MXU pass makes
+        # of float32 operands
+        first = _cache_attention_dequantized_first(q, kq, vq, total, heads,
+                                                   kvh)
+        err_first = np.abs(np.asarray(first.astype(jnp.float32)) - ref).max()
+        assert np.abs(np.asarray(out) - ref).max() <= err_first
+        big = B * c * kvh * hd
+        made = [v.aval for v in _eqn_outputs(
+            jax.make_jaxpr(attend)(q, kc, vc).jaxpr)]
+        assert made and not [a for a in made if a.dtype == jnp.float32
+                             and a.size >= big], made
+    # a float cache: the same jaxpr as ever
+    before = jax.make_jaxpr(
+        lambda q_, k_, v_: _cache_attention_dequantized_first(
+            q_, k_, v_, total, heads, kvh))(q, kq, vq)
+    assert str(jax.make_jaxpr(attend)(q, kq, vq)) == str(before)
+
+
 def test_quantize_dequantize_roundtrip_error_bound():
     """Per-(token, head) scales bound the int8 roundtrip error by
     amax_head / 127 per element."""
