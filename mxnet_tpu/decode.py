@@ -70,9 +70,20 @@ decode steps, so a long prompt never stalls the serving batch.
 
 The symbol contract (checked at trace time, documented in
 docs/inference.md): decoder-only graphs built from position-independent ops
-plus ``dot_product_attention`` for sequence mixing, with at most a learned
-positional table added via a ``broadcast_*`` op against a ``(1, S, E)``
-variable — ``models.attention_lm`` and the benchmark LMs qualify.
+plus ``dot_product_attention`` for sequence mixing.  Positions enter either
+as a learned positional table added via a ``broadcast_*`` op against a
+``(1, S, E)`` variable, or inside the attention node (``rotary_dim``: q and
+k are rotated at the positions the walk passes, and the keys are cached
+rotated) — ``models.attention_lm``, ``models.decoder_lm`` and the benchmark
+LMs qualify.
+
+**Cache groups.**  Each attention node has a cache layout read off it at
+bind time (:class:`CacheLayout`): full nodes hold ``cache_len`` positions a
+slot, a paged window node a ring of ``window + prefill_chunk`` positions
+rounded up to a page.  Nodes of one capacity form a group
+(``serve.CacheGroup``) with its own page count, page tables and allocator;
+a graph of one kind builds one group and the tables, programs and page
+counts it built before groups existed.
 """
 from __future__ import annotations
 
@@ -118,6 +129,27 @@ _POSITION_BROADCAST_OPS = {
 }
 
 
+def _per_group(items):
+    """What the programs take where a graph has cache groups: the one
+    item of a graph with one group, else a tuple, one a group."""
+    items = tuple(items)
+    return items if len(items) > 1 else items[0]
+
+
+class CacheLayout(NamedTuple):
+    """What one attention node keeps a slot, read off the node at bind
+    time: ``kind`` ("full" | "window"), its KV heads, and the positions
+    it holds (``capacity``); the key and value widths are the pools'
+    trailing dims (``DecodePredictor.cache_layouts`` adds them once the
+    shapes are probed)."""
+
+    kind: str
+    kv_heads: int
+    capacity: int
+    key_width: object = None
+    value_width: object = None
+
+
 class DecodeState(NamedTuple):
     """The donated per-step serving state (a jax pytree)."""
 
@@ -126,6 +158,10 @@ class DecodeState(NamedTuple):
                         # under a quantized MXNET_KV_DTYPE
     lens: object        # (B,) int32 — tokens appended to each cache so far
     tok: object         # (B, 1) int32 — last sampled token, not yet appended
+    moe: object = None  # int32 [rows held, rows elsewhere, held experts
+                        # visited], summed over the gated MoE layers of the
+                        # paged step that made this state; None (no leaf)
+                        # for a graph without them and on the way in
 
 
 class DecodePredictor:
@@ -256,6 +292,7 @@ class DecodePredictor:
             if kvh != a.get("num_heads", 1):
                 grouped.append(int(kvh))
         self._grouped_kv_heads = min(grouped) if grouped else None
+        self._bind_cache_groups()
 
         self._cache_sharding = None
         self._partition_rules = None
@@ -380,6 +417,69 @@ class DecodePredictor:
     def cache_len(self):
         return self._cache_len
 
+    def _bind_cache_groups(self):
+        """One :class:`CacheLayout` per attention node, and the nodes
+        grouped by what a slot holds of them.  A paged window node keeps
+        a ring of ``window + prefill_chunk`` positions (rounded up to a
+        page): a chunk is appended whole before it is attended, so the
+        ring has to hold the chunk and the window before its first
+        query.  Everything else, a window node of a dense predictor
+        included, keeps ``cache_len`` (its mask does the rest)."""
+        from .serve.manager import CacheGroup
+
+        pt = self._page_tokens
+        kind_of = lambda cap: "window" if cap < self._cache_len else "full"
+        self._layouts = []
+        for n in self._attn_nodes:
+            a = n.parsed_attrs()
+            window = int(a.get("window", 0) or 0)
+            cap = self._cache_len
+            if window and self._paged and self._prefill_chunk:
+                cap = min(cap, -(-(window + self._prefill_chunk) // pt) * pt)
+            self._layouts.append(CacheLayout(
+                kind_of(cap),
+                int(a.get("num_kv_heads", 0) or a.get("num_heads", 1)), cap))
+        caps = sorted({l.capacity for l in self._layouts}, reverse=True)
+        kinds = [kind_of(c) for c in caps]
+        self._groups = [
+            CacheGroup(k, c, [i for i, l in enumerate(self._layouts)
+                              if l.capacity == c],
+                       name=k if kinds.count(k) == 1 else "%s%d" % (k, c))
+            for k, c in zip(kinds, caps)]
+        self._group_of = [caps.index(l.capacity) for l in self._layouts]
+
+    @property
+    def has_window_group(self):
+        """Whether some attention node keeps a ring shorter than
+        ``cache_len``: then there is no prefix sharing, and the serving
+        loop refuses what a ring cannot carry."""
+        return len(self._groups) > 1 or self._groups[0].kind == "window"
+
+    def cache_layouts(self):
+        """The per-node :class:`CacheLayout`\\ s with the key and value
+        widths filled in from the probed pool shapes (paged mode)."""
+        from .ops.attention import QuantKV
+
+        if self._pools_template is None:
+            self._pools_template = self._probe_cache_shapes()
+        width = lambda a: int((a.data if isinstance(a, QuantKV)
+                               else a).shape[2])
+        return [l._replace(key_width=width(kc), value_width=width(vc))
+                for l, (kc, vc) in zip(self._layouts, self._pools_template)]
+
+    def _tables_of(self, mgr, rows=None):
+        """The manager's page tables as the programs take them: one
+        (B, M) array, or one a group where the graph has several.
+        ``rows`` (a slice) picks slots."""
+        import jax.numpy as jnp
+
+        rows = slice(None) if rows is None else rows
+        return _per_group(jnp.asarray(g.tables[rows]) for g in mgr.groups)
+
+    def _pool_pages_of(self, ai):
+        """Pages in the pool of attention node ``ai``: its group's."""
+        return self._manager.groups[self._group_of[ai]].pool_pages
+
     # ------------------------------------------------------------------
     # roofline telemetry (mxnet_tpu.obs) — host-side only: the compiled
     # programs are byte-identical with telemetry on or off
@@ -480,7 +580,7 @@ class DecodePredictor:
             opname = node.op.name
             with _node_scope(node):
                 if opname == "dot_product_attention":
-                    q, k, v = ins
+                    q, k, v = ins[:3]
                     heads = attrs.get("num_heads", 1)
                     # grouped-query attention: the K/V stream (and so the
                     # cache/pool) is physically kv_heads wide — every append/
@@ -498,11 +598,24 @@ class DecodePredictor:
                     else:
                         self._attn_dims.append(dims)
                     scale = attrs.get("scale", 0.0) or None
+                    # what the node adds to plain causal attention: a
+                    # window, a value scale, its scope, a sink (the fourth
+                    # input).  A plain node is called as it always was
+                    extra = _attn.node_extras(
+                        attrs, ins[3] if len(ins) > 3 else None)
+                    at = {"layer": extra["layer"]} if extra else {}
+                    if attrs.get("rotary_dim", 0):
+                        # rotated at the positions this call runs at; the
+                        # keys go into the cache rotated
+                        q, k = _attn.rotate_qk(
+                            attrs, q, k,
+                            jnp.asarray(pos0, jnp.int32).reshape(-1, 1)
+                            + jnp.arange(t, dtype=jnp.int32)[None, :])
                     if caches is None:
                         outs = [_attn.sdpa(q, k, v, num_heads=heads,
                                            causal=attrs.get("causal", False),
                                            scale=scale,
-                                           num_kv_heads=kv_heads)]
+                                           num_kv_heads=kv_heads, **extra)]
                         new_caches.append((self._fill_cache(k, kv_heads),
                                            self._fill_cache(v, kv_heads)))
                     else:
@@ -510,26 +623,33 @@ class DecodePredictor:
                         pos = jnp.asarray(pos0, jnp.int32).reshape(-1)
                         mesh_on = self._mesh is not None
                         if tables is not None:
-                            kc = _attn.paged_append(kc, tables, k, pos0,
+                            # a graph with several cache groups brings one
+                            # table a group
+                            tbl = tables[self._group_of[ai]] \
+                                if isinstance(tables, tuple) else tables
+                            kc = _attn.paged_append(kc, tbl, k, pos0,
                                                     num_heads=kv_heads,
-                                                    active=active, valid=valid)
-                            vc = _attn.paged_append(vc, tables, v, pos0,
+                                                    active=active, valid=valid,
+                                                    **at)
+                            vc = _attn.paged_append(vc, tbl, v, pos0,
                                                     num_heads=kv_heads,
-                                                    active=active, valid=valid)
+                                                    active=active, valid=valid,
+                                                    **at)
                             outs = [_attn.paged_attend(
-                                q, kc, vc, tables, pos + t, num_heads=heads,
+                                q, kc, vc, tbl, pos + t, num_heads=heads,
                                 scale=scale, mesh_active=mesh_on,
-                                num_kv_heads=kv_heads)]
+                                num_kv_heads=kv_heads, **extra)]
                         else:
                             kc = _attn.cache_append(kc, k, pos0,
-                                                    num_heads=kv_heads)
+                                                    num_heads=kv_heads, **at)
                             vc = _attn.cache_append(vc, v, pos0,
-                                                    num_heads=kv_heads)
+                                                    num_heads=kv_heads, **at)
                             outs = [_attn.cache_attend(q, kc, vc, pos + t,
                                                        num_heads=heads,
                                                        scale=scale,
                                                        mesh_active=mesh_on,
-                                                       num_kv_heads=kv_heads)]
+                                                       num_kv_heads=kv_heads,
+                                                       **extra)]
                         # PATH_TAKEN, recorded at trace time: which decode-
                         # attention path this predictor's programs actually
                         # lowered — refines artifact meta so a shape-gated
@@ -714,15 +834,22 @@ class DecodePredictor:
         preserved, so one traced program carries every batch occupancy."""
         import jax.numpy as jnp
 
+        from .ops.moe import collecting
+
         if not self._probing:
             self.trace_counts["decode"] += 1
-        probs3, caches = self._run(env, state.tok, state.caches, state.lens,
-                                   tables=tables, active=active)
+        with collecting(real=active) as moe_rows:
+            probs3, caches = self._run(env, state.tok, state.caches,
+                                       state.lens, tables=tables,
+                                       active=active)
         probs = probs3[:, 0]
         tok = self._sample(key, probs)
         act = jnp.asarray(active).reshape(-1, 1).astype(bool)
         tok = jnp.where(act, tok, state.tok)
         lens = state.lens + jnp.asarray(active, jnp.int32).reshape(-1)
+        if moe_rows:
+            # beside the sampled tokens, and read with them: no new sync
+            return DecodeState(caches, lens, tok, sum(moe_rows)), probs
         return DecodeState(caches, lens, tok), probs
 
     def _paged_verify_impl(self, env, state, tables, active, draft_toks,
@@ -762,16 +889,27 @@ class DecodePredictor:
         One trace per chunk width — chunked prefill never retraces."""
         import jax.numpy as jnp
 
+        from .ops.moe import collecting
+
         if not self._probing:
             self.trace_counts["chunk"] += 1
         ones = jnp.ones((toks.shape[0],), jnp.int32)
-        probs3, caches = self._run(env, toks, caches, pos0, tables=table1,
-                                   active=ones, valid=nvalid)
+        # built only if a gated layer asks: other graphs trace what they did
+        def real():
+            return jnp.arange(toks.shape[1])[None, :] \
+                < jnp.asarray(nvalid, jnp.int32).reshape(-1, 1)
+
+        with collecting(real=real) as moe_rows:
+            probs3, caches = self._run(env, toks, caches, pos0,
+                                       tables=table1, active=ones,
+                                       valid=nvalid)
         last = jnp.clip(jnp.asarray(nvalid, jnp.int32) - 1, 0,
                         toks.shape[1] - 1)
         probs = jnp.take_along_axis(
             probs3, last[:, None, None], axis=1)[:, 0]
         tok = self._sample(key, probs)
+        if moe_rows:
+            return caches, probs, tok, sum(moe_rows)
         return caches, probs, tok
 
     def _fork_impl(self, caches, src, dst):
@@ -905,24 +1043,35 @@ class DecodePredictor:
         import jax.numpy as jnp
 
         from .ops.attention import QuantKV
-        from .serve import PagedKVManager
+        from .serve import GroupedKVManager, PagedKVManager
 
-        self._manager = PagedKVManager(
-            slots, self._cache_len, self._page_tokens,
-            pool_pages=self._pool_pages,
-            prefix_cache=self._prefix_cache_on)
+        if len(self._groups) > 1:
+            self._manager = GroupedKVManager(
+                slots, self._groups, self._page_tokens,
+                pool_pages=self._pool_pages)
+        else:
+            # prefix sharing needs the whole context behind a matched
+            # prefix: a lone window group has none
+            group = self._groups[0]
+            self._manager = PagedKVManager(
+                slots, group.capacity, self._page_tokens,
+                pool_pages=self._pool_pages,
+                prefix_cache=self._prefix_cache_on
+                and group.kind == "full", kind=group.kind,
+                name=group.name)
         if self._pools_template is None:
             self._pools_template = self._probe_cache_shapes()
-        pp = self._manager.pool_pages
         pt = self._page_tokens
 
-        def pool_of(aval, is_scale=False):
-            return self._place_pool(
-                jnp.zeros((pp, pt, aval.shape[2]), aval.dtype),
-                is_scale=is_scale)
-
         pools = []
-        for kc, vc in self._pools_template:
+        for ai, (kc, vc) in enumerate(self._pools_template):
+            pp = self._pool_pages_of(ai)
+
+            def pool_of(aval, is_scale=False):
+                return self._place_pool(
+                    jnp.zeros((pp, pt, aval.shape[2]), aval.dtype),
+                    is_scale=is_scale)
+
             pair = []
             for aval in (kc, vc):
                 if isinstance(aval, QuantKV):
@@ -947,10 +1096,11 @@ class DecodePredictor:
             raise MXNetError("pool_bytes before any paged prefill/serve")
         if self._pools_template is None:
             self._pools_template = self._probe_cache_shapes()
-        pp, pt = self._manager.pool_pages, self._page_tokens
-        return sum(shape_bytes(shape_str((pp, pt, aval.shape[2]),
-                                         aval.dtype))
-                   for aval in jtu.tree_leaves(self._pools_template))
+        pt = self._page_tokens
+        return sum(shape_bytes(shape_str(
+            (self._pool_pages_of(ai), pt, aval.shape[2]), aval.dtype))
+            for ai, pair in enumerate(self._pools_template)
+            for aval in jtu.tree_leaves(pair))
 
     # ------------------------------------------------------------------
     # AOT-serialized program preparation — the fleet cold-start path
@@ -1017,51 +1167,62 @@ class DecodePredictor:
             self._pools_template = self._probe_cache_shapes()
         slots = int(slots)
         pt = self._page_tokens
-        m = self._cache_len // pt
-        pp = PagedKVManager.pool_sizing(slots, self._cache_len, pt,
-                                        self._pool_pages)
+        # per cache group: table width and pool pages (an explicit pool
+        # size sizes the first group, as the managers do)
+        ms = [g.capacity // pt for g in self._groups]
+        pps = [PagedKVManager.pool_sizing(
+            slots, g.capacity, pt, self._pool_pages if i == 0 else 0)
+            for i, g in enumerate(self._groups)]
+        m = ms[0]
         sds = jax.ShapeDtypeStruct
 
         def build(shape_of):
             pools = []
-            for kc, vc in self._pools_template:
+            for ai, (kc, vc) in enumerate(self._pools_template):
                 pair = []
                 for aval in (kc, vc):
                     if isinstance(aval, QuantKV):
                         pair.append(QuantKV(
-                            sds(shape_of(aval.data), aval.data.dtype),
-                            sds(shape_of(aval.scale), aval.scale.dtype)))
+                            sds(shape_of(ai, aval.data), aval.data.dtype),
+                            sds(shape_of(ai, aval.scale), aval.scale.dtype)))
                     else:
-                        pair.append(sds(shape_of(aval), aval.dtype))
+                        pair.append(sds(shape_of(ai, aval), aval.dtype))
                 pools.append(tuple(pair))
             return tuple(pools)
 
-        caches = build(lambda a: (pp, pt, a.shape[2]))
-        # one slot's extracted pages: the pool gathered at an (M,) row
-        data = build(lambda a: (m, pt, a.shape[2]))
+        caches = build(lambda ai, a: (pps[self._group_of[ai]], pt,
+                                      a.shape[2]))
         env = {n: aval_of(v) for n, v in self._env.items()}
         lens = sds((slots,), jnp.int32)
         tok = sds((slots, 1), jnp.int32)
         state = DecodeState(caches, lens, tok)
-        tables = sds((slots, m), jnp.int32)
+
+        def tables_of(rows):
+            return _per_group(sds((rows, w), jnp.int32) for w in ms)
+
         active = sds((slots,), jnp.int32)
         key = aval_of(self._zero_key)
         i32 = sds((), jnp.int32)
-        row = sds((m,), jnp.int32)
         cw = int(chunk_w or self._prefill_chunk or self._cache_len)
         out = {
-            "chunk": (env, caches, sds((1, m), jnp.int32),
+            "chunk": (env, caches, tables_of(1),
                       sds((1, cw), jnp.float32), sds((1,), jnp.int32),
                       sds((1,), jnp.int32), key),
-            "decode": (env, state, tables, active, key),
+            "decode": (env, state, tables_of(slots), active, key),
             "commit": (lens, tok, i32, sds((1,), jnp.int32),
                        sds((1, 1), jnp.int32)),
-            "fork": (caches, i32, i32),
-            "extract": (caches, row),
-            "install": (caches, row, data),
         }
+        if len(self._groups) == 1:
+            # page ids are one space a group: fork, extract and install
+            # are programs of a graph with one group
+            row = sds((m,), jnp.int32)
+            # one slot's extracted pages: the pool gathered at an (M,) row
+            data = build(lambda ai, a: (m, pt, a.shape[2]))
+            out.update({"fork": (caches, i32, i32),
+                        "extract": (caches, row),
+                        "install": (caches, row, data)})
         if spec_k:
-            out["verify"] = (env, state, tables, active,
+            out["verify"] = (env, state, tables_of(slots), active,
                              sds((slots, int(spec_k)), jnp.int32), None,
                              key)
         return out
@@ -1184,8 +1345,7 @@ class DecodePredictor:
         cached = getattr(self, "_tables_dev", None)
         if cached is None or cached[0] is not mgr \
                 or cached[1] != mgr.version:
-            self._tables_dev = (mgr, mgr.version,
-                                jnp.asarray(mgr.tables))
+            self._tables_dev = (mgr, mgr.version, self._tables_of(mgr))
         act_key = act.tobytes()
         cached = getattr(self, "_act_dev", None)
         if cached is None or cached[0] != act_key:
@@ -1290,12 +1450,14 @@ class DecodePredictor:
             if sub is None:
                 key, sub = jax.random.split(key)
             with _obs.program_span("prefill"):
+                # (a graph with gated MoE layers returns their row counts
+                # too: the serving loop reads them, this path does not)
                 caches, probs, tok = self._chunk_fn(
                     self._env, caches,
-                    jnp.asarray(mgr.tables[slot:slot + 1]),
+                    self._tables_of(mgr, slice(slot, slot + 1)),
                     jnp.asarray(_pad_window(prompt[pos:pos + n], w)),
                     jnp.asarray([pos], jnp.int32),
-                    jnp.asarray([n], jnp.int32), sub)
+                    jnp.asarray([n], jnp.int32), sub)[:3]
             pos += n
         return caches, tok, probs
 
@@ -1534,11 +1696,12 @@ class DecodePredictor:
         import jax.numpy as jnp
 
         b = state.lens.shape[0]
-        m = self._cache_len // self._page_tokens
         if self._manager is not None and self._manager.slots == b:
-            tables = jnp.asarray(self._manager.tables)
+            tables = self._tables_of(self._manager)
         else:
-            tables = jnp.zeros((b, m), jnp.int32)
+            tables = _per_group(
+                jnp.zeros((b, g.capacity // self._page_tokens), jnp.int32)
+                for g in self._groups)
         return tables, jnp.ones((b,), jnp.int32)
 
     def decode_step_text(self, state, key=None):
@@ -2059,6 +2222,17 @@ class DecodeServer:
             proposer = NGramProposer(spec_k)
         self._spec_k = int(spec_k or 0)
         self._proposer = proposer
+        # a 'window' cache group keeps a ring of its last positions: what
+        # a ring cannot carry is refused here, by name, and never served
+        # from a recycled page
+        self._ring = bool(getattr(predictor, "_paged", False)
+                          and predictor.has_window_group)
+        if self._ring and (self._spec_k or proposer is not None):
+            raise MXNetError(
+                "speculative decoding is not supported on a graph with a "
+                "'window' cache group (groups: %s): a rejected draft's keys "
+                "would sit in ring positions the length mask cannot hide"
+                % [g.name for g in predictor._groups])
         if proposer is not None and getattr(proposer, "cache_len", None):
             if self._max_prefill > proposer.cache_len:
                 raise MXNetError(
@@ -2081,12 +2255,28 @@ class DecodeServer:
             "mx_spec_proposed", "drafted tokens offered to verify")
         self._m_accepted = _obs.registry.counter(
             "mx_spec_accepted", "drafted tokens accepted by the target")
+        self._m_moe_rows = _obs.registry.counter(
+            "mx_moe_rows_total",
+            "(token, chosen expert) pairs routed by the gated MoE layers: "
+            "to an expert this chip holds, or elsewhere",
+            labels=("program", "where"))
+        self._m_moe_visits = _obs.registry.counter(
+            "mx_moe_expert_visits_total",
+            "held experts with at least one row, summed over MoE layers",
+            labels=("program",))
+        self._m_moe_calls = _obs.registry.counter(
+            "mx_moe_calls_total",
+            "runs of a program with gated MoE layers",
+            labels=("program",))
         # --- fleet/preemption state (paged loop) ---
         # fair admission: after this many consecutive pool-gate-blocked
         # iterations the lowest-priority slot is preempted (swap-out) so
         # a long decode can no longer wedge the admission gate
         self._fair_bound = int(_config.get("MXNET_FLEET_DECODE_BOUND"))
-        self._swap_armed = bool(_config.get("MXNET_FLEET_SWAP"))
+        # preemption moves a slot's pages to the host and back: a ring's
+        # pages are not restorable, so a window group disarms it
+        self._swap_armed = bool(_config.get("MXNET_FLEET_SWAP")) \
+            and not self._ring
         self._preempt_cb = None     # serve.fleet routes records back out
         self._verify_restore = False   # tests: assert restore bit-parity
         self._ps = None             # persistent paged session (tick API)
@@ -2142,6 +2332,13 @@ class DecodeServer:
         self._m_free_pages = _obs.registry.gauge(
             "mx_fleet_free_pages", "free pages in the host's KV pool",
             labels=("host",)).labels(**lab)
+        self._m_pages_in_use = _obs.registry.gauge(
+            "mx_kv_pages_in_use", "allocated pages of a cache group's pool",
+            labels=("group",))
+        self._m_pages_total = _obs.registry.gauge(
+            "mx_kv_pages_total",
+            "pages of a cache group's pool (the scratch page included)",
+            labels=("group",))
         self._m_ttft = _obs.registry.histogram(
             "mx_fleet_ttft", "seconds from submit to first token",
             labels=("host",)).labels(**lab)
@@ -2159,6 +2356,24 @@ class DecodeServer:
         if spec:
             self.spec_steps += 1
             self._m_spec.inc()
+
+    def _note_moe(self, unread, note):
+        """Count what the gated MoE layers of this tick's programs routed:
+        ``unread`` holds (program, int32 [held, elsewhere, visits]) as
+        ``serve.readback`` fetched them with the step's tokens.  The
+        decode step's own three also go into ``note``, the arguments of
+        the tick's ``serve.readback`` span, so that a reader can tell one
+        tick's routing from another's."""
+        for program, vec in unread:
+            held, elsewhere, visits = (int(v) for v in vec)
+            self._m_moe_rows.labels(program=program, where="held").inc(held)
+            self._m_moe_rows.labels(program=program,
+                                    where="elsewhere").inc(elsewhere)
+            self._m_moe_visits.labels(program=program).inc(visits)
+            self._m_moe_calls.labels(program=program).inc()
+            if program == "decode":
+                note.update(moe_rows_held=held, moe_rows_elsewhere=elsewhere,
+                            moe_expert_visits=visits)
 
     def _note_accept(self, proposed, accepted):
         """One slot's speculative window accounted."""
@@ -2193,6 +2408,10 @@ class DecodeServer:
         record admits through the normal reservation gate and restores
         by installing its saved pages (no prefill); SLO timestamps carry
         over so fleet TTFT stays honest.  Returns this host's rid."""
+        if self._ring:
+            raise MXNetError(
+                "inject: restoring a swapped or migrated request is not "
+                "supported on a graph with a 'window' cache group")
         rid = self._next_id
         self._next_id += 1
         entry = {"rid": rid, "prompt": record.prompt, "cap": record.cap,
@@ -2483,7 +2702,10 @@ class DecodeServer:
             "pending": None,    # the one admission mid-chunked-prefill
             "blocked": 0,       # consecutive pool-gate-blocked ticks
             "tick": 0,          # serve_tick calls (the serve.tick span's arg)
+            "moe_unread": [],   # (program, device row counts) not yet read
         }
+        for g in pred._manager.groups:      # a pool's size: once a session
+            self._m_pages_total.labels(group=g.name).set(g.pool_pages)
         return self._ps
 
     def serve_reset(self):
@@ -2846,7 +3068,7 @@ class DecodeServer:
                 sub = next_key()
                 _obs.instant("prefill_chunk", cat="serve", args=where)
                 args = (pred._env, caches,
-                        jnp.asarray(mgr.tables[p["slot"]:p["slot"] + 1]),
+                        pred._tables_of(mgr, slice(p["slot"], p["slot"] + 1)),
                         jnp.asarray(_pad_window(
                             p["prompt"][p["pos"]:p["pos"] + n],
                             self._chunk_w)),
@@ -2857,7 +3079,9 @@ class DecodeServer:
                 pred._roofline_register("prefill_chunk", pred._chunk_fn,
                                         args, static=False)
                 with _obs.program_span("prefill"):
-                    caches, probs, tok = pred._chunk_fn(*args)
+                    caches, probs, tok, *moe = pred._chunk_fn(*args)
+                if moe:
+                    ps["moe_unread"].append(("chunk", moe[0]))
                 ps["state"] = state = DecodeState(caches, state.lens,
                                                   state.tok)
                 p["pos"] += n
@@ -2923,8 +3147,22 @@ class DecodeServer:
                 state, _ = pred.paged_step(ps["state"], slot_lens, sub,
                                            act_mask)
                 ps["state"] = state
-            with _obs.span("serve.readback", cat="serve"):
-                toks = np.asarray(state.tok)[:, 0]
+            note = {}       # filled below, read as the span closes
+            with _obs.span("serve.readback", cat="serve", args=note):
+                unread = ps["moe_unread"]
+                if state.moe is not None:
+                    unread.append(("decode", state.moe))
+                if unread:
+                    # the MoE row counts come in the same transfer as the
+                    # tokens: one wait, not one an array
+                    toks, *rows = jax.device_get(
+                        [state.tok] + [vec for _, vec in unread])
+                    toks = toks[:, 0]
+                    self._note_moe([(program, vec) for (program, _), vec
+                                    in zip(unread, rows)], note)
+                    del unread[:]
+                else:
+                    toks = np.asarray(state.tok)[:, 0]
             with _obs.span("serve.deliver", cat="serve"):
                 self._note_step()
                 for slot, rec in active.items():
@@ -2940,3 +3178,6 @@ class DecodeServer:
         mgr = getattr(self._pred, "_manager", None)
         if mgr is not None:
             self._m_free_pages.set(mgr.allocator.free_pages)
+            for g in mgr.groups:
+                self._m_pages_in_use.labels(group=g.name).set(
+                    g.allocator.used_pages)

@@ -33,12 +33,18 @@ LAYER_OF_OP = {
     "Pooling": "pool",
     "Embedding": "embed",
     "SoftmaxOutput": "head_loss",
+    "MoEFFN": "moe",
+    "RMSNorm": "norm",
 }
 # every value a scope's <layer> may take: the table's, "other" for op
 # kinds it does not list, and the two fixed scopes of the train step
+# "attn_window" is what a model builder names its sliding-window attention
+# nodes (LAYER_ATTR), so that "attn" keeps meaning attention over the whole
+# context
 LAYERS = tuple(sorted(set(LAYER_OF_OP.values()))) + (
-    "other", "optimizer", "metric")
-SUBSCOPES = ("kv_append", "kv_gather", "kv_dequant", "scores")
+    "attn_window", "other", "optimizer", "metric")
+SUBSCOPES = ("kv_append", "kv_gather", "kv_dequant", "scores", "rope",
+             "route", "experts", "combine")
 # an instruction no mx.<layer> scope reaches (compiler-made copies,
 # casts between the step's phases)
 UNSCOPED = "unscoped"

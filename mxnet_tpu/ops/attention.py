@@ -61,7 +61,91 @@ def check_head_groups(num_heads, num_kv_heads, e, ev=None, kv_dim=None,
     return kvh, heads // kvh
 
 
-def sdpa(q, k, v, num_heads=1, causal=False, scale=None, num_kv_heads=0):
+def rope(x, positions, num_heads, rotary_dim, theta, layer="attn"):
+    """Rotary position embedding on the first ``rotary_dim`` dims of each
+    of ``x``'s (B, t, H*hd) heads, in the half-split (NeoX) pairing: dim
+    ``i`` pairs with ``i + rotary_dim/2`` and turns by ``position *
+    theta**(-2i/rotary_dim)``; the dims past ``rotary_dim`` pass as they
+    are.  ``positions`` is (t,) or (B, t) absolute indices.  Computed in
+    float32, returned in ``x``'s dtype."""
+    import jax.numpy as jnp
+
+    b, t, e = x.shape
+    hd = e // num_heads
+    half = int(rotary_dim) // 2
+    if half <= 0 or 2 * half > hd:
+        raise ValueError("rope: rotary_dim=%d does not fit heads of %d"
+                         % (rotary_dim, hd))
+    with _scope(layer, "rope"):
+        inv = float(theta) ** (-jnp.arange(half, dtype=jnp.float32) / half)
+        pos = jnp.broadcast_to(jnp.asarray(positions, jnp.float32), (b, t)) \
+            if jnp.ndim(positions) == 2 \
+            else jnp.asarray(positions, jnp.float32)[None, :]
+        ang = pos[:, :, None, None] * inv            # (B|1, t, 1, half)
+        cos, sin = jnp.cos(ang), jnp.sin(ang)
+        xh = x.reshape(b, t, num_heads, hd).astype(jnp.float32)
+        x1, x2 = xh[..., :half], xh[..., half:2 * half]
+        out = jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin,
+                               xh[..., 2 * half:]], axis=-1)
+        return out.reshape(b, t, e).astype(x.dtype)
+
+
+def _softmax_with_sink(logits, allowed, sink):
+    """Float32 softmax over the last axis of ``logits`` where ``allowed``;
+    ``sink`` (shaped to broadcast against the head axes, or None) is one
+    more logit in the maximum and the denominator that carries no value:
+    the probabilities then sum to less than one."""
+    import jax.numpy as jnp
+
+    logits = jnp.where(allowed, logits, jnp.finfo(jnp.float32).min)
+    m = jnp.max(logits, axis=-1, keepdims=True)
+    if sink is not None:
+        m = jnp.maximum(m, sink)
+    p = jnp.exp(logits - m)
+    den = jnp.sum(p, axis=-1, keepdims=True)
+    if sink is not None:
+        den = den + jnp.exp(sink - m)
+    return p / den
+
+
+def _sdpa_extra(q, k, v, num_heads, causal, scale, num_kv_heads, window,
+                sink, value_scale, layer):
+    """:func:`sdpa` for a node with a window, a sink or a value scale: the
+    grouped einsums with the mask built from positions (query ``i`` of
+    ``tq`` sits at ``tk - tq + i``) and the sink in the softmax."""
+    import jax.numpy as jnp
+
+    b, tq, e = q.shape
+    tk, ev = k.shape[1], v.shape[2]
+    kvh, g = check_head_groups(num_heads, num_kv_heads, e, ev, k.shape[2],
+                               where="sdpa")
+    hd = e // num_heads
+    scale = scale or 1.0 / np.sqrt(hd)
+    qh = q.reshape(b, tq, kvh, g, hd)
+    kh = k.reshape(b, tk, kvh, hd)
+    vh = v.reshape(b, tk, kvh, ev // kvh)
+    with _scope(layer, "scores"):
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qh,
+                            kh).astype(jnp.float32) * scale
+        qpos = jnp.arange(tq, dtype=jnp.int32)[:, None] + (tk - tq)
+        kpos = jnp.arange(tk, dtype=jnp.int32)[None, :]
+        allowed = jnp.ones((tq, tk), bool)
+        if causal:
+            allowed &= kpos <= qpos
+        if window:
+            allowed &= kpos > qpos - int(window)
+        p = _softmax_with_sink(
+            logits, allowed[None, None, None],
+            None if sink is None else jnp.asarray(sink, jnp.float32)
+            .reshape(1, kvh, g, 1, 1))
+    out = jnp.einsum("bhgqk,bkhe->bqhge", p.astype(vh.dtype), vh)
+    if value_scale != 1.0:
+        out = out * jnp.asarray(value_scale, out.dtype)
+    return out.reshape(b, tq, num_heads * (ev // kvh))
+
+
+def sdpa(q, k, v, num_heads=1, causal=False, scale=None, num_kv_heads=0,
+         window=0, sink=None, value_scale=1.0, layer="attn"):
     """Multi-head scaled-dot-product attention kernel.
 
     (B, Tq, E), (B, Tk, Ek), (B, Tk, Ev) -> (B, Tq, H*hdv).  The softmax
@@ -75,9 +159,18 @@ def sdpa(q, k, v, num_heads=1, causal=False, scale=None, num_kv_heads=0):
     q-head ``h`` attends kv-head ``h // G`` with ``G = H / H_kv`` —
     mapped INSIDE the einsum by reshaping q to (B, Tq, H_kv, G, hd), so
     the G× smaller K/V are never broadcast into a materialized copy.
+
+    ``window`` (0 = none) lets query ``i`` see keys ``i - window + 1 ..
+    i`` only; ``sink`` (H,) is a learned per-head logit in the softmax's
+    denominator that carries no value; ``value_scale`` multiplies the
+    output.  A call with none of the three traces what it traced before
+    them; ``layer`` names the scope the sub-scopes sit under.
     """
     import jax.numpy as jnp
 
+    if window or sink is not None or value_scale != 1.0:
+        return _sdpa_extra(q, k, v, num_heads, causal, scale, num_kv_heads,
+                           window, sink, value_scale, layer)
     b, tq, e = q.shape
     tk = k.shape[1]
     ev = v.shape[2]
@@ -207,7 +300,7 @@ def dequantize_kv(cache, num_heads=None, out_dtype=None):
     return x.astype(out_dtype) if out_dtype is not None else x
 
 
-def cache_append(cache, new, start_pos, num_heads=1):
+def cache_append(cache, new, start_pos, num_heads=1, layer="attn"):
     """Write ``new`` (B, t, E) into ring-buffer slots [start_pos,
     start_pos+t) mod C of ``cache`` (B, C, E).
 
@@ -231,11 +324,13 @@ def cache_append(cache, new, start_pos, num_heads=1):
     import jax
     import jax.numpy as jnp
 
-    with _scope("attn", "kv_append"):
+    with _scope(layer, "kv_append"):
         if isinstance(cache, QuantKV):
             qnew = quantize_kv(new, cache.data.dtype, num_heads)
-            return QuantKV(cache_append(cache.data, qnew.data, start_pos),
-                           cache_append(cache.scale, qnew.scale, start_pos))
+            return QuantKV(
+                cache_append(cache.data, qnew.data, start_pos, layer=layer),
+                cache_append(cache.scale, qnew.scale, start_pos,
+                             layer=layer))
         b, t = new.shape[0], new.shape[1]
         c = cache.shape[1]
         start = jnp.broadcast_to(jnp.asarray(start_pos, jnp.int32).reshape(-1),
@@ -259,7 +354,8 @@ def cache_append(cache, new, start_pos, num_heads=1):
 
 
 def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
-                num_kv_heads=0, mesh_active=False):
+                num_kv_heads=0, mesh_active=False, window=0, sink=None,
+                value_scale=1.0, layer="attn"):
     """Shared length-masked cache-attention core behind
     :func:`sdpa_decode` (tq == 1) and :func:`sdpa_verify` (tq == k+1).
 
@@ -294,7 +390,14 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
 
     With ``num_kv_heads < num_heads`` the caches hold H_kv heads (and
     QuantKV scale planes are per-(token, kv-head)); q-head ``h`` scores
-    kv-head ``h // G`` through the grouped einsum — no broadcast copy."""
+    kv-head ``h // G`` through the grouped einsum — no broadcast copy.
+
+    With ``window`` the mask is built from absolute positions: slot ``j``
+    of a ring of C holds the newest position ``p < total_len`` with ``p %
+    C == j``, and query ``i`` (at ``total_len - tq + i``) sees the slots
+    whose position lies in its last ``window``.  The ring may then have
+    wrapped under a multi-row query, as long as it is at least ``window +
+    tq - 1`` long.  ``sink`` and ``value_scale`` as in :func:`sdpa`."""
     import jax.numpy as jnp
     from jax.lax import Precision
 
@@ -318,7 +421,7 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
                 jnp.swapaxes(cache.scale, 1, 2).reshape(
                     (b, kvh) + ones + (-1,)))
 
-    with _scope("attn", "kv_dequant"):
+    with _scope(layer, "kv_dequant"):
         k_cache, k_scale = stored(k_cache)
         v_cache, v_scale = stored(v_cache)
     c = k_cache.shape[1]
@@ -342,7 +445,7 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
     qh = q.reshape((b, tq) + heads + (hd,))
     kh = k_cache.reshape(b, c, kvh, hd)
     vh = v_cache.reshape(b, c, kvh, ev // kvh)
-    with _scope("attn", "scores"):
+    with _scope(layer, "scores"):
         if k_scale is not None and row:
             # (B, j, d, h, g): q-head (h, g)'s row in the columns of its
             # own kv-head, zeros in every other
@@ -358,18 +461,35 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
                 preferred_element_type=None if k_scale is None
                 else jnp.float32).astype(jnp.float32) * scale
         if k_scale is not None:
-            with _scope("attn", "kv_dequant"):
+            with _scope(layer, "kv_dequant"):
                 logits = logits * k_scale
         total = jnp.asarray(total_len, jnp.int32).reshape((-1, 1, 1) + ones)
         qpos = jnp.arange(tq, dtype=jnp.int32).reshape((1,) + ones + (tq, 1))
-        limit = jnp.minimum(total - (tq - 1) + qpos, c)
-        slot = jnp.arange(c, dtype=jnp.int32).reshape((1, 1) + ones + (c,))
-        logits = jnp.where(slot < limit, logits, jnp.finfo(jnp.float32).min)
-        m = jnp.max(logits, axis=-1, keepdims=True)
-        p = jnp.exp(logits - m)
-        p = p / jnp.sum(p, axis=-1, keepdims=True)
+        if window or sink is not None:
+            slot = jnp.arange(c, dtype=jnp.int32).reshape(
+                (1, 1) + ones + (c,))
+            qabs = total - tq + qpos
+            allowed = slot < jnp.minimum(qabs + 1, c)
+            if window:
+                # the position slot j holds: the newest p < total, p % c == j
+                held = slot + c * jnp.floor_divide(total - 1 - slot, c)
+                allowed = (held >= 0) & (held <= qabs) \
+                    & (held > qabs - int(window))
+            p = _softmax_with_sink(
+                logits, allowed,
+                None if sink is None else jnp.asarray(sink, jnp.float32)
+                .reshape((1,) + heads + (1, 1)))
+        else:
+            limit = jnp.minimum(total - (tq - 1) + qpos, c)
+            slot = jnp.arange(c, dtype=jnp.int32).reshape(
+                (1, 1) + ones + (c,))
+            logits = jnp.where(slot < limit, logits,
+                               jnp.finfo(jnp.float32).min)
+            m = jnp.max(logits, axis=-1, keepdims=True)
+            p = jnp.exp(logits - m)
+            p = p / jnp.sum(p, axis=-1, keepdims=True)
         if v_scale is not None:
-            with _scope("attn", "kv_dequant"):
+            with _scope(layer, "kv_dequant"):
                 p = p * v_scale
     if v_scale is not None and row:
         full = jnp.einsum("bnk,bke->bne", p.reshape(b, num_heads, c), v_cache,
@@ -381,6 +501,8 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
         out = jnp.einsum(
             "b%sqk,bkhe->bq%se" % (hx, hx), p.astype(vh.dtype), vh,
             preferred_element_type=None if v_scale is None else jnp.float32)
+    if value_scale != 1.0:
+        out = out * jnp.asarray(value_scale, out.dtype)
     return out.reshape(b, tq, num_heads * (ev // kvh))
 
 
@@ -463,7 +585,7 @@ def paged_gather(pool, table):
 
 
 def paged_append(pool, table, new, start_pos, num_heads=1, active=None,
-                 valid=None):
+                 valid=None, layer="attn"):
     """Scatter ``new`` (B, t, E) into the page pool at ring positions
     [start_pos, start_pos + t) of each slot's page table.
 
@@ -481,14 +603,14 @@ def paged_append(pool, table, new, start_pos, num_heads=1, active=None,
     """
     import jax.numpy as jnp
 
-    with _scope("attn", "kv_append"):
+    with _scope(layer, "kv_append"):
         if isinstance(pool, QuantKV):
             qnew = quantize_kv(new, pool.data.dtype, num_heads)
             return QuantKV(
                 paged_append(pool.data, table, qnew.data, start_pos,
-                             active=active, valid=valid),
+                             active=active, valid=valid, layer=layer),
                 paged_append(pool.scale, table, qnew.scale, start_pos,
-                             active=active, valid=valid))
+                             active=active, valid=valid, layer=layer))
         b, t = new.shape[0], new.shape[1]
         m = table.shape[1]
         pt = pool.shape[1]
@@ -558,7 +680,7 @@ def _note_path(path):
 
 
 def flash_selected(q_shape, k_shape, causal, num_heads, num_kv_heads,
-                   mesh_active):
+                   mesh_active, plain=True):
     """``(take, interpret)``: whether ``dot_product_attention`` runs the
     Pallas flash kernel for this call, decided from what the call shows.
 
@@ -566,11 +688,13 @@ def flash_selected(q_shape, k_shape, causal, num_heads, num_kv_heads,
     forces the interpreter); no mesh shards the executor (the kernel is
     opaque to GSPMD); ``pallas_attention.supported`` admits the shape;
     and T has reached :data:`FLASH_MIN_T` for the head width.  Anything
-    else takes :func:`sdpa`."""
+    else takes :func:`sdpa`: so does a node that is not ``plain`` (a
+    window, a sink, or values of another head width than the keys: the
+    kernels know the causal mask only)."""
     from . import pallas_attention as _pa
 
     runs, interpret = _kernel_backend()
-    if mesh_active or not runs \
+    if mesh_active or not runs or not plain \
             or not _pa.supported(q_shape, k_shape, causal, num_heads,
                                  num_kv_heads=num_kv_heads):
         return False, False
@@ -616,8 +740,19 @@ def decode_kernel_mode():
     return _kernel_backend()
 
 
+def _extras(window, sink, value_scale, layer):
+    """The keywords of a node that is not plain, for :func:`_sdpa_cache`:
+    empty for a plain node, so that it is called as it was."""
+    if not window and sink is None and value_scale == 1.0 \
+            and layer == "attn":
+        return {}
+    return dict(window=window, sink=sink, value_scale=value_scale,
+                layer=layer)
+
+
 def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
-                 scale=None, mesh_active=False, num_kv_heads=0):
+                 scale=None, mesh_active=False, num_kv_heads=0, window=0,
+                 sink=None, value_scale=1.0, layer="attn"):
     """Decode/verify attention over shared page pools — the ONE entry the
     decode programs call.
 
@@ -631,9 +766,11 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
     :func:`paged_gather` + :func:`sdpa_decode`/:func:`sdpa_verify` over
     the gathered view in the pool's storage dtype (an int8/fp8 view is
     never dequantized into a float copy), whose numerics the kernel
-    matches within documented tolerances (docs/inference.md)."""
+    matches within documented tolerances (docs/inference.md).  A node with
+    a window, a sink or a value scale takes the einsum path."""
     engage, interp = decode_kernel_mode()
-    if engage and not mesh_active:
+    extra = _extras(window, sink, value_scale, layer)
+    if engage and not mesh_active and not extra:
         from . import pallas_decode as _pd
 
         if _pd.supported(q.shape, k_pool, v_pool, table.shape, num_heads,
@@ -647,15 +784,17 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
         DECODE_PATH["last"] = "einsum-gated"
     else:
         DECODE_PATH["last"] = "einsum"
-    with _scope("attn", "kv_gather"):
+    with _scope(layer, "kv_gather"):
         k_view = paged_gather(k_pool, table)
         v_view = paged_gather(v_pool, table)
     return _sdpa_cache(q, k_view, v_view, total_len, num_heads, scale,
-                       num_kv_heads=num_kv_heads, mesh_active=mesh_active)
+                       num_kv_heads=num_kv_heads, mesh_active=mesh_active,
+                       **extra)
 
 
 def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
-                 mesh_active=False, num_kv_heads=0):
+                 mesh_active=False, num_kv_heads=0, window=0, sink=None,
+                 value_scale=1.0, layer="attn"):
     """Decode/verify attention over dense (B, C, E) ring buffers — the
     non-paged twin of :func:`paged_attend`.  The fused path is the SAME
     kernel through an identity page table
@@ -663,7 +802,8 @@ def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
     plain KV-cached serving path gets split-K decode attention too;
     fallback is :func:`sdpa_decode`/:func:`sdpa_verify` unchanged."""
     engage, interp = decode_kernel_mode()
-    if engage and not mesh_active:
+    extra = _extras(window, sink, value_scale, layer)
+    if engage and not mesh_active and not extra:
         from . import pallas_decode as _pd
 
         if _pd.supported_dense(q.shape, k_cache, v_cache, num_heads,
@@ -678,7 +818,8 @@ def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
     else:
         DECODE_PATH["last"] = "einsum"
     return _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
-                       num_kv_heads=num_kv_heads, mesh_active=mesh_active)
+                       num_kv_heads=num_kv_heads, mesh_active=mesh_active,
+                       **extra)
 
 
 _KV_LAYOUT_WARNED = {"done": False}
@@ -741,8 +882,34 @@ def apply_kv_layout(buf, device=None):
         return jax.device_put(buf, device) if device is not None else buf
 
 
+def node_extras(attrs, sink=None):
+    """What a ``dot_product_attention`` node's attributes (and its fourth
+    input, the ``sink``) add to plain causal attention, as the keywords
+    :func:`sdpa`, :func:`paged_attend` and :func:`cache_attend` take;
+    empty for a plain node, which is then called as it always was.
+    ``layer`` is the node's ``__layer__`` attribute: the scope its
+    sub-scopes sit under."""
+    return _extras(int(attrs.get("window", 0) or 0), sink,
+                   float(attrs.get("value_scale", 1.0) or 1.0),
+                   attrs.get("__layer__") or "attn")
+
+
+def rotate_qk(attrs, q, k, positions):
+    """``q`` and ``k`` with the node's rotary embedding applied at
+    ``positions`` ((t,) or (B, t)); as they are where the node has none."""
+    rd = int(attrs.get("rotary_dim", 0) or 0)
+    if not rd:
+        return q, k
+    heads = attrs.get("num_heads", 1)
+    kv_heads = attrs.get("num_kv_heads", 0) or heads
+    theta = float(attrs.get("rope_theta", 10000.0))
+    layer = attrs.get("__layer__") or "attn"
+    return (rope(q, positions, heads, rd, theta, layer=layer),
+            rope(k, positions, kv_heads, rd, theta, layer=layer))
+
+
 def _attn_shape(attrs, in_shapes, aux_shapes):
-    q, k, v = in_shapes
+    q, k, v = in_shapes[:3]
     heads = attrs.get("num_heads", 1)
     kvh, _ = check_head_groups(heads, attrs.get("num_kv_heads", 0),
                                q[-1], v[-1], k[-1],
@@ -751,12 +918,13 @@ def _attn_shape(attrs, in_shapes, aux_shapes):
     # grouped K/V carry H_kv heads of width hdv each; the output is one
     # hdv-wide slice per Q head (v[-1] itself when H_kv == H)
     out = (q[0], q[1], heads * (v[-1] // kvh))
-    return [tuple(q), tuple(k), tuple(v)], [out], []
+    sink = [(heads,)] if attrs.get("sink") else []
+    return [tuple(q), tuple(k), tuple(v)] + sink, [out], []
 
 
 def register_all():
     def _compute_full(attrs, inputs, aux, octx):
-        q, k, v = inputs
+        q, k, v, *sink = inputs
         heads = attrs.get("num_heads", 1)
         kv_heads = attrs.get("num_kv_heads", 0) or heads
         causal = attrs.get("causal", False)
@@ -767,6 +935,18 @@ def register_all():
         check_head_groups(heads, kv_heads, q.shape[2], v.shape[2],
                           k.shape[2], where="dot_product_attention")
         from .. import config as _config
+
+        # a node that says more than "causal" (a window, a sink, a value
+        # scale, values narrower than keys) is attended by sdpa: the ring
+        # and the flash kernels know the causal mask only.  Rotary turns
+        # q and k first, at the positions of a whole forward pass
+        extra = node_extras(attrs, sink[0] if sink else None)
+        plain = not extra and v.shape[2] == k.shape[2]
+        q, k = rotate_qk(attrs, q, k, np.arange(q.shape[1]))
+        if not plain:
+            _note_path("einsum")
+            return [sdpa(q, k, v, num_heads=heads, causal=causal,
+                         scale=scale, num_kv_heads=kv_heads, **extra)], []
 
         # mesh path: with the time axis sharded on 'seq', run
         # explicit-collective ring attention INSIDE the executor program —
@@ -843,8 +1023,24 @@ def register_all():
             Param("causal", bool, default=False),
             Param("scale", float, default=0.0,
                   doc="0 = 1/sqrt(head_dim)"),
+            Param("window", int, default=0,
+                  doc="sliding window: a query sees its own position and "
+                      "the window - 1 before it; 0 = none"),
+            Param("sink", bool, default=False,
+                  doc="a fourth input (H,): one learned logit a head in "
+                      "the softmax's denominator, with no value"),
+            Param("rotary_dim", int, default=0,
+                  doc="rotary embedding on the first rotary_dim dims of "
+                      "each q and k head (half-split pairing), applied "
+                      "inside the node at the positions it is run at; "
+                      "0 = none"),
+            Param("rope_theta", float, default=10000.0),
+            Param("value_scale", float, default=1.0,
+                  doc="the output is multiplied by this"),
         ),
-        num_inputs=3, arguments=["query", "key", "value"],
+        num_inputs=lambda a: 4 if a.get("sink") else 3,
+        arguments=lambda a: ["query", "key", "value"]
+        + (["sink"] if a.get("sink") else []),
         infer_shape=_attn_shape, needs_train=True,
         doc="Multi-head scaled-dot-product attention over projected "
             "(B, T, E) inputs.  Leapfrog op: no reference analog "

@@ -154,12 +154,15 @@ def register_all():
             return jnp.logaddexp(x, 0.0)
         if act == "softsign":
             return x / (1 + jnp.abs(x))
+        if act == "silu":
+            return jax.nn.silu(x)
         raise ValueError("unknown act_type %s" % act)
 
     register_op(OpDef("Activation", simple_compute(_activation),
                       schema=ParamSchema(Param("act_type", str, required=True,
                                                enum=("relu", "sigmoid", "tanh",
-                                                     "softrelu", "softsign"))),
+                                                     "softrelu", "softsign",
+                                                     "silu"))),
                       num_inputs=1, hint="activation"))
 
     def _leaky_relu(attrs, x, *rest):
@@ -593,6 +596,24 @@ def register_all():
                       schema=ParamSchema(Param("eps", float, default=1e-3)),
                       num_inputs=3, arguments=["data", "gamma", "beta"],
                       infer_shape=_in_shape, hint="instancenorm"))
+
+    # ---------------- RMSNorm ----------------
+    def _rms_norm(attrs, x, gamma):
+        # x * rsqrt(mean(x^2) + eps) * gamma over the last axis; the mean
+        # is taken in float32 whatever the stream's dtype
+        x32 = x.astype(jnp.float32)
+        inv = lax.rsqrt(jnp.mean(x32 * x32, axis=-1, keepdims=True)
+                        + attrs.get("eps", 1e-5))
+        return (x32 * inv).astype(x.dtype) * gamma
+
+    def _rms_shape(attrs, in_shapes, aux_shapes):
+        d = in_shapes[0]
+        return [d, (d[-1],)], [d], []
+
+    register_op(OpDef("RMSNorm", simple_compute(_rms_norm),
+                      schema=ParamSchema(Param("eps", float, default=1e-5)),
+                      num_inputs=2, arguments=["data", "gamma"],
+                      infer_shape=_rms_shape, hint="rmsnorm"))
 
     # ---------------- L2Normalization ----------------
     def _l2norm(attrs, x):

@@ -44,10 +44,11 @@ from __future__ import annotations
 
 from .allocator import PageAllocator
 from .prefix_cache import PrefixCache, chain_hash
-from .manager import PagedKVManager
+from .manager import CacheGroup, GroupedKVManager, PagedKVManager
 from .swap import SwapStore, SwappedRequest
 
 __all__ = ["PageAllocator", "PrefixCache", "PagedKVManager",
+           "GroupedKVManager", "CacheGroup",
            "SwapStore", "SwappedRequest", "chain_hash"]
 
 

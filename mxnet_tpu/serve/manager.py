@@ -36,7 +36,7 @@ from ..base import MXNetError
 from .allocator import PageAllocator
 from .prefix_cache import PrefixCache
 
-__all__ = ["PagedKVManager"]
+__all__ = ["PagedKVManager", "GroupedKVManager", "CacheGroup"]
 
 
 def _pages_for(tokens, page_tokens):
@@ -52,7 +52,8 @@ class PagedKVManager:
     counterpart of the dense ring's wrap)."""
 
     def __init__(self, slots, capacity, page_tokens, pool_pages=0,
-                 prefix_cache=True):
+                 prefix_cache=True, kind="full", name=None):
+        self.kind, self.name = kind, name or kind
         self.page_tokens = int(page_tokens)
         if capacity % self.page_tokens:
             raise MXNetError(
@@ -280,6 +281,11 @@ class PagedKVManager:
             self.allocator.unreserve(int(self._reserve[slot]))
             self._reserve[slot] = 0
 
+    @property
+    def groups(self):
+        """The cache groups this manager keeps: itself."""
+        return [self]
+
     # ------------------------------------------------------------------
     def stats(self):
         a = self.allocator
@@ -296,4 +302,119 @@ class PagedKVManager:
                         "prefix_cache_hits": c.hits,
                         "prefix_cache_lookups": c.lookups,
                         "prefix_cache_pages": c.pages_held})
+        return out
+
+
+class CacheGroup:
+    """The attention nodes of one graph that share a cache layout: their
+    ``kind`` ("full": every position of the context; "window": a ring of
+    the last ``capacity`` positions), what a slot holds of each
+    (``capacity`` positions) and which nodes they are (``nodes``: indices
+    into the graph's attention nodes, in topological order).  ``name`` is
+    what statistics and gauges are keyed by: the kind, with the capacity
+    where two groups of a graph share a kind."""
+
+    def __init__(self, kind, capacity, nodes, name=None):
+        self.kind, self.capacity = kind, int(capacity)
+        self.nodes = tuple(nodes)
+        self.name = name or kind
+
+    def __repr__(self):
+        return "CacheGroup(%r, %d, %r)" % (self.name, self.capacity,
+                                           self.nodes)
+
+
+class GroupedKVManager:
+    """The manager of a graph with more than one cache group: one
+    :class:`PagedKVManager` a group, each with its own page count, its
+    own per-slot tables and its own allocator, gated together — a request
+    is admitted when every group can reserve its worst case, and retires
+    from all of them at once.
+
+    A window group's table ring-mods over its few pages (``PagedKVManager``
+    at the group's ``capacity``), so its nodes hold ``capacity`` positions
+    a slot however long the request: a slot recycles its own oldest page
+    in place.  What one ring cannot carry is refused by name and never
+    read stale: there is no prefix cache (a matched prefix's ring content
+    is gone once the donor has moved on), hence no copy-on-write fork; and
+    a slot's pages cannot be extracted or restored (:meth:`gate_pages`,
+    :meth:`restore_slot` raise)."""
+
+    prefix_cache = None
+
+    def __init__(self, slots, groups, page_tokens, pool_pages=0):
+        self.slots = int(slots)
+        self.page_tokens = int(page_tokens)
+        # an explicit pool size sizes the first group's pool (the groups
+        # are ordered widest first); a ring group is always whole
+        self.groups = [
+            PagedKVManager(slots, g.capacity, page_tokens,
+                           pool_pages=pool_pages if i == 0 else 0,
+                           prefix_cache=False, kind=g.kind, name=g.name)
+            for i, g in enumerate(groups)]
+
+    @property
+    def allocator(self):
+        """The first (widest) group's allocator: the one that runs out."""
+        return self.groups[0].allocator
+
+    @property
+    def version(self):
+        return sum(g.version for g in self.groups)
+
+    @property
+    def pool_pages(self):
+        return sum(g.pool_pages for g in self.groups)
+
+    def _refuse(self, what):
+        raise MXNetError(
+            "%s is not supported on a graph with cache groups %s: a "
+            "'window' group keeps a ring of its last positions only"
+            % (what, [g.name for g in self.groups]))
+
+    def gate(self, prompt, prompt_len, max_new, spec_k=0,
+             budget_wrap_forks=True):
+        """Reserve every group's worst case for a request, or none:
+        ``(0, [], needs)`` with ``needs`` the pages reserved a group, or
+        ``None`` on backpressure."""
+        total = int(prompt_len) + int(max_new) + int(spec_k) + 1
+        needs = [_pages_for(min(total, g.capacity), self.page_tokens)
+                 for g in self.groups]
+        for i, (g, n) in enumerate(zip(self.groups, needs)):
+            if not g.allocator.reserve(n):
+                for h, m in zip(self.groups[:i], needs[:i]):
+                    h.allocator.unreserve(m)
+                return None
+        return 0, [], needs
+
+    def map_slot(self, slot, pages, reserve_n):
+        for g, n in zip(self.groups, reserve_n):
+            g.map_slot(slot, [], n)
+
+    def ensure(self, slot, lo, hi):
+        for g in self.groups:
+            copies = g.ensure(slot, lo, hi)
+            assert not copies, "a page of an unshared group forked"
+        return []
+
+    def publish(self, slot, prompt, prompt_len):
+        """Nothing to publish: there is no prefix cache."""
+
+    def free_slot(self, slot):
+        for g in self.groups:
+            g.free_slot(slot)
+
+    def gate_pages(self, need):
+        self._refuse("restoring a swapped or migrated request")
+
+    def restore_slot(self, slot, valid, reserve_n):
+        self._refuse("restoring a swapped or migrated request")
+
+    def slot_page_count(self, slot):
+        return sum(g.slot_page_count(slot) for g in self.groups)
+
+    def stats(self):
+        out = self.groups[0].stats()
+        out["pool_pages"] = self.pool_pages
+        out["groups"] = {g.name: g.stats() for g in self.groups}
         return out

@@ -791,3 +791,118 @@ def test_sample_tokens_greedy_bypass_is_key_independent():
                                                 temperature=0.7,
                                                 top_k=1))))
     assert outs == {(2, 2, 2)}
+
+
+# ---------------------------------------------------------------------------
+# A graph with none of the attributes PR 35 added (window, sink, rotary,
+# value scale, gated experts) traces the programs it traced before them
+# ---------------------------------------------------------------------------
+
+# sha256[:16] of ``str(jax.make_jaxpr(program)(avals))``, taken on the commit
+# before PR 35 (b2cc690) with the very code below, under this directory's
+# conftest (the text of a jaxpr depends on jax's configuration).  A
+# PR that means to change one of these programs replaces its hash, and says
+# so; one that does not has changed what every accepted serving cell runs.
+PLAIN_PROGRAMS = {
+    "mha_int8": {"chunk": "2a106cba2448de0b", "decode": "83681617abb6a64f",
+                 "verify": "07f073f9fe1d82b6", "prefill": "88b686fb4db16476"},
+    "gqa_float": {"chunk": "b3c4b15365228b7f", "decode": "e11356bce6bbbc2d",
+                  "verify": "88735220e64c4515", "prefill": "ecb1cf4999e7e7a2"},
+}
+PLAIN_OPS = {"attn_mha": "cf554ea5f46f3f2f", "attn_gqa": "46ba4ccd9ef3b64f",
+             "moe_dense": "19b10ae9c8469cd3", "moe_sparse": "d710ac04554bceab"}
+
+
+def _jaxpr_hash(fn, avals):
+    import hashlib
+
+    return hashlib.sha256(
+        str(jax.make_jaxpr(fn)(*avals)).encode()).hexdigest()[:16]
+
+
+def _plain_predictors(kv_dtype, num_kv_heads):
+    sym = attention_lm.get_symbol(vocab_size=50, seq_len=32, num_layers=2,
+                                  embed=32, heads=4, ffn_hidden=64,
+                                  num_kv_heads=num_kv_heads)
+    arg_shapes, _, _ = sym.infer_shape(data=(1, 32), softmax_label=(1, 32))
+    rng = np.random.RandomState(0)
+    params = {n: mx.nd.array(rng.normal(size=s).astype(np.float32) * 0.05)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in ("data", "softmax_label")}
+    paged = DecodePredictor(sym, params, cache_len=32, paged=True,
+                            page_tokens=4, kv_dtype=kv_dtype,
+                            prefill_chunk=8)
+    dense = DecodePredictor(sym, params, cache_len=32, paged=False,
+                            kv_dtype=kv_dtype)
+    return paged, dense
+
+
+@pytest.mark.parametrize("name,kv_dtype,num_kv_heads", [
+    ("mha_int8", "int8", 0), ("gqa_float", "", 2)])
+def test_plain_graph_traces_the_serving_programs_it_traced_before(
+        name, kv_dtype, num_kv_heads):
+    from mxnet_tpu.programs.spec import probing
+
+    paged, dense = _plain_predictors(kv_dtype, num_kv_heads)
+    avals = paged.serving_avals(4, chunk_w=8, spec_k=3)
+    assert sorted(avals) == ["chunk", "commit", "decode", "extract", "fork",
+                             "install", "verify"]
+    fns = paged._aot_dispatches()
+    got = {}
+    with probing(paged):
+        for kind in ("chunk", "decode", "verify"):
+            got[kind] = _jaxpr_hash(fns[kind], avals[kind])
+    with probing(dense):
+        got["prefill"] = _jaxpr_hash(dense._prefill_impl,
+                                     dense._prefill_args(2, 16))
+    assert got == PLAIN_PROGRAMS[name]
+
+
+def test_plain_graph_builds_one_group_with_todays_page_count():
+    from mxnet_tpu.serve import PagedKVManager
+
+    paged, dense = _plain_predictors("int8", 0)
+    assert [(g.kind, g.capacity, g.nodes) for g in paged._groups] == [
+        ("full", 32, (0, 1))]
+    assert not paged.has_window_group and not dense.has_window_group
+    state = paged.paged_batch_state(4)
+    mgr = paged._manager
+    assert type(mgr) is PagedKVManager and mgr.groups == [mgr]
+    assert mgr.pool_pages == 4 * (32 // 4) + 1
+    assert mgr.prefix_cache is not None
+    assert mgr.tables.shape == (4, 8)
+    assert state.moe is None and len(jax.tree_util.tree_leaves(state)) \
+        == 2 * 2 * 2 + 2            # 2 nodes x (k, v) x (data, scale)
+    for (kc, vc) in state.caches:
+        assert kc.data.shape == (33, 4, 32) and kc.scale.shape == (33, 4, 4)
+    assert isinstance(paged._tables_of(mgr), jax.Array)
+    assert paged.pool_bytes() == 2 * 2 * (33 * 4 * 32 + 33 * 4 * 4 * 4)
+
+
+def test_plain_ops_trace_the_training_jaxprs_they_traced_before():
+    """Forward and backward of the two ops PR 35 extended, called with none
+    of the new attributes: what the training steps are made of."""
+    from mxnet_tpu.registry import OpContext, get_op
+
+    def grad_hash(op, attrs, shapes):
+        f = lambda *xs: op.fcompute(attrs, list(xs), [], OpContext())[0][0]
+        g = lambda *xs: jax.grad(lambda *ys: f(*ys).sum(),
+                                 argnums=tuple(range(len(shapes))))(*xs)
+        return _jaxpr_hash(g, [jax.ShapeDtypeStruct(s, jnp.float32)
+                               for s in shapes])
+
+    got = {}
+    op = get_op("dot_product_attention")
+    for name, kw, shapes in (
+            ("mha", dict(num_heads=4, causal=True), [(2, 16, 32)] * 3),
+            ("gqa", dict(num_heads=4, num_kv_heads=2, causal=True),
+             [(2, 16, 32), (2, 16, 16), (2, 16, 16)])):
+        got["attn_" + name] = grad_hash(op, op.parse_attrs(kw), shapes)
+    op = get_op("MoEFFN")
+    for name, kw in (("dense", {}),
+                     ("sparse", dict(capacity_factor=1.5,
+                                     num_experts_per_tok=2))):
+        got["moe_" + name] = grad_hash(
+            op, op.parse_attrs(dict(num_experts=4, hidden_size=10, **kw)),
+            [(12, 6), (6, 4), (4, 6, 10), (4, 10), (4, 10, 6), (4, 6)])
+    assert got == PLAIN_OPS

@@ -1,0 +1,258 @@
+"""``models.decoder_lm`` against the plain reference of the ``mimo_v2`` family
+(``chipbench/reference/mimo_v2.py``) on a 7-layer toy of the published
+pattern: one dense full-attention layer, then six expert layers of which five
+window and one full; 4 heads of 24/16, 1 KV head on full layers and 2 on
+window layers, window 8, 16 experts top 4 of which this "chip" holds 4.
+
+Tolerances.  ``FLOAT_ATOL`` 1e-4 on log-probabilities: system and reference
+both compute in float32 on the CPU and differ in the order of their sums only
+(1.4e-6 measured).  Every mechanism of the model moves the log-probabilities
+by far more when it is dropped (the parametrised test holds each to ten times
+the tolerance; the least, the bias kept in the weight, moves them 0.065), so
+the tolerance separates right from wrong.  ``INT8_ATOL`` 3e-2: an int8 pool
+stores each key and value off by up to 1/254 of its head's largest (0.011
+measured), still under half of what the least mechanism moves.
+"""
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from chipbench import correct, harness, manifest, weights
+from chipbench.reference import mimo_v2 as ref
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.decode import DecodePredictor, DecodeServer
+
+FLOAT_ATOL, INT8_ATOL = 1e-4, 3e-2
+T, PROMPT, CHUNK, PAGE = 40, 30, 8, 4
+
+TOY = dict(vocab_size=96, hidden_size=64, num_attention_heads=4, head_dim=24,
+           v_head_dim=16, swa_head_dim=24, swa_v_head_dim=16,
+           num_key_value_heads=1, swa_num_key_value_heads=2,
+           sliding_window=8, intermediate_size=128, moe_intermediate_size=32,
+           n_routed_experts=16, num_experts_per_tok=4,
+           held_n_routed_experts=4, first_held_expert=4,
+           max_position_embeddings=64)
+
+
+def toy_config(**over):
+    """The published configuration cut to the toy's sizes.  Its weights are
+    drawn wider than the cell's 0.02 (0.08: towards 1 / sqrt(hidden 64)),
+    so that attention logits and router scores are large enough for every
+    mechanism to move the output (0.06 to 1.1 in log-probability, the
+    bfloat16 reference 0.015)."""
+    cfg = manifest.load_json(manifest.ROOT, "chipbench/configs/mimo-v2.5.json")
+    init = [dict(r, std=0.08) if r["match"] == "_weight$" else r
+            for r in cfg["init"]]
+    return dict(cfg, init=init, **dict(TOY, **over))
+
+
+def build(cfg, seed=7):
+    sym = harness.build_symbol(cfg)
+    arg_shapes, _, _ = sym.infer_shape(data=(1, 64), softmax_label=(1, 64))
+    shapes = {n: s for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in ("data", "softmax_label")}
+    return sym, weights.make_params(shapes, cfg, seed, "float32")
+
+
+def system_probs(sym, params, toks):
+    ex = sym.simple_bind(mx.cpu(), grad_req="null", data=toks.shape,
+                         softmax_label=toks.shape)
+    for n, v in params.items():
+        ex.arg_dict[n]._set_data(v)
+    ex.arg_dict["data"]._set_data(jnp.asarray(toks, jnp.float32))
+    ex.forward(is_train=False)
+    return ex.outputs[0].data
+
+
+@pytest.fixture(scope="module")
+def toy():
+    cfg = toy_config()
+    sym, params = build(cfg)
+    toks = np.random.default_rng(0).integers(0, cfg["vocab_size"],
+                                             size=(1, T))
+    return cfg, sym, params, toks, system_probs(sym, params, toks)
+
+
+def test_pattern_and_shapes(toy):
+    cfg, sym, params, _, _ = toy
+    assert cfg["hybrid_layer_pattern"][:7] == [0, 1, 1, 1, 1, 0, 1]
+    assert cfg["moe_layer_freq"][:7] == [0, 1, 1, 1, 1, 1, 1]
+    assert params["layer0_k_weight"].shape == (1 * 24, 64)      # full
+    assert params["layer1_k_weight"].shape == (2 * 24, 64)      # window
+    assert params["layer1_v_weight"].shape == (2 * 16, 64)
+    assert params["layer1_att_sink"].shape == (4,)
+    assert "layer0_att_sink" not in params and "layer5_att_sink" not in params
+    assert params["layer0_ffn_gate_weight"].shape == (128, 64)
+    assert params["layer1_moe_gate_weight"].shape == (64, 16)   # all experts
+    assert params["layer1_moe_expert_gate_weight"].shape == (4, 64, 32)
+    assert not [n for n in params if n.endswith("_bias")
+                and "moe_gate" not in n]
+
+
+def test_full_forward_matches_the_reference(toy):
+    cfg, _, params, toks, probs = toy
+    out = correct.compare_logp(probs, ref.forward(params, cfg, toks)[0],
+                               FLOAT_ATOL)
+    assert out["ok"] and out["positions"] == T, out
+
+
+def _b_in_the_weight(p, n, cfg, x):
+    """``ref._experts`` with the correction bias left in the weight."""
+    first, held = ref.share(cfg)
+    s = jax.nn.sigmoid(x @ p[n + "moe_gate_weight"]) + p[n + "moe_gate_bias"]
+    w, chosen = jax.lax.top_k(s, cfg["num_experts_per_tok"])
+    w = w / (jnp.sum(w, -1, keepdims=True) + 1e-20)
+    y = jnp.zeros_like(x)
+    for e in range(held):
+        we = jnp.sum(jnp.where(chosen == first + e, w, 0.0), -1,
+                     keepdims=True)
+        y = y + we * ((jax.nn.silu(x @ p[n + "moe_expert_gate_weight"][e])
+                       * (x @ p[n + "moe_expert_up_weight"][e]))
+                      @ p[n + "moe_expert_down_weight"][e])
+    return y
+
+
+@pytest.mark.parametrize("dropped", [
+    "sink", "window", "partial_rotary", "rotary", "sigmoid_router",
+    "selection_only_bias", "value_scale", "share", "float32"])
+def test_each_mechanism_dropped_fails_the_tolerance(toy, dropped,
+                                                    monkeypatch):
+    """The comparison sees every mechanism: the system as built, against a
+    reference (or the reference as written, against a system) that lacks
+    one, is off by more than ten times ``FLOAT_ATOL``."""
+    cfg, sym, params, toks, probs = toy
+    ref_cfg, ref_params = dict(cfg), params
+    if dropped == "sink":
+        ref_cfg["add_swa_attention_sink_bias"] = False
+    elif dropped == "window":
+        ref_cfg["sliding_window"] = 10 ** 6
+    elif dropped == "partial_rotary":
+        ref_cfg["partial_rotary_factor"] = 1.0          # all 24 dims turn
+    elif dropped == "rotary":
+        ref_cfg.update(rope_theta=1e30, swa_rope_theta=1e30)    # no turn
+    elif dropped == "sigmoid_router":
+        probs = system_probs(
+            harness.build_symbol(dict(cfg, scoring_func="softmax")), params,
+            toks)
+    elif dropped == "selection_only_bias":
+        monkeypatch.setattr(ref, "_experts", _b_in_the_weight)
+    elif dropped == "value_scale":
+        ref_cfg["attention_value_scale"] = 1.0
+    elif dropped == "share":
+        ref_cfg["first_held_expert"] = 0        # another chip's experts
+    elif dropped == "float32":
+        ref_params = {n: v.astype(jnp.bfloat16).astype(jnp.float32)
+                      for n, v in params.items()}
+    out = correct.compare_logp(probs,
+                               ref.forward(ref_params, ref_cfg, toks)[0],
+                               FLOAT_ATOL)
+    assert not out["ok"] and out["max_abs_dlogp"] > 10 * FLOAT_ATOL, out
+
+
+@pytest.mark.parametrize("kv_dtype,atol", [("", FLOAT_ATOL),
+                                           ("int8", INT8_ATOL)])
+def test_chunked_prefill_and_decode_past_the_rings_wrap(toy, kv_dtype, atol):
+    """Through ``DecodePredictor`` with two cache groups: a 30-token prompt
+    in chunks of 8, then 10 decoded positions, against ONE forward pass of
+    the reference.  The window group's ring is 8 + 8 = 16 positions, so it
+    wraps inside the prompt and again while decoding."""
+    cfg, sym, params, toks, _ = toy
+    pred = DecodePredictor(
+        sym, {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()},
+        cache_len=64, ctx=mx.cpu(), paged=True, page_tokens=PAGE,
+        kv_dtype=kv_dtype, prefill_chunk=CHUNK)
+    assert [(g.kind, g.capacity, g.nodes) for g in pred._groups] == [
+        ("full", 64, (0, 5)), ("window", 16, (1, 2, 3, 4, 6))]
+    state, probs = pred.prefill(toks[:, :PROMPT].astype(np.float32),
+                                np.array([PROMPT]))
+    got = [probs[0]]
+    for i in range(PROMPT, T - 1):
+        # feed the sequence's own next token, whatever was sampled
+        state = state._replace(tok=jnp.asarray(toks[:, i:i + 1], jnp.int32))
+        state, probs = pred.step(state)
+        got.append(probs[0])
+    want = ref.forward(params, cfg, toks)[0, PROMPT - 1:T - 1]
+    out = correct.compare_logp(jnp.stack(got), want, atol)
+    assert out["ok"] and out["positions"] == T - PROMPT, out
+    layouts = pred.cache_layouts()
+    assert [(l.kind, l.kv_heads, l.capacity, l.key_width, l.value_width)
+            for l in layouts[:2]] == [("full", 1, 64, 24, 16),
+                                      ("window", 2, 16, 48, 32)]
+    # one trace of each program served every chunk and every step
+    assert pred.trace_counts["chunk"] == 1
+    assert pred.trace_counts["decode"] == 1
+    stats = pred._manager.stats()["groups"]
+    assert stats["window"]["used_pages"] <= 16 // PAGE
+    assert stats["full"]["used_pages"] == -(-(T - 1) // PAGE)
+
+
+def test_server_matches_generate_and_counts_moe_rows(toy):
+    """The serving loop over both groups gives each request the tokens of
+    its own ``generate``; the MoE counters count (token, choice) pairs."""
+    cfg, sym, params, _, _ = toy
+    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
+    pred = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
+                           page_tokens=PAGE, prefill_chunk=CHUNK)
+    server = DecodeServer(pred, max_prefill=32, slots=3, spec_k=0)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 26, 9, 30)]
+    from mxnet_tpu import obs
+
+    def held_rows():
+        fam = obs.registry.snapshot().get("mx_moe_rows_total", {})
+        return {tuple(sorted(r["labels"].items())): r["value"]
+                for r in fam.get("series", ())}
+
+    def noted():
+        return [e["args"] for e in obs.timeline.events()
+                if e["name"] == "serve.readback" and e.get("args")]
+
+    before, seen = held_rows(), len(noted())
+    rids = [server.submit(p, max_new_tokens=12) for p in prompts]
+    results = server.run()
+    alone = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
+                            page_tokens=PAGE, prefill_chunk=CHUNK)
+    for rid, p in zip(rids, prompts):
+        want = alone.generate(p[None].astype(np.float32), p.size,
+                              max_new_tokens=12)[0]
+        assert np.array_equal(results[rid], want), rid
+    after = held_rows()
+    key = lambda program, where: (("program", program), ("where", where))
+    moved = {k: after.get(k, 0) - before.get(k, 0) for k in after}
+    # 6 MoE layers x 4 choices a token, counted over the rows that are
+    # tokens: every prompt token once through a chunk (its padding never),
+    # every later token once through a decode step (idle slots never)
+    decode = moved[key("decode", "held")] + moved[key("decode", "elsewhere")]
+    assert decode == 6 * 4 * len(prompts) * (12 - 1)
+    chunk = moved[key("chunk", "held")] + moved[key("chunk", "elsewhere")]
+    assert chunk == 6 * 4 * sum(p.size for p in prompts)
+    assert 0 < moved[key("decode", "held")] < decode
+    # each decode tick's own counts ride its serve.readback span
+    ticks = noted()[seen:]
+    assert sum(a["moe_rows_held"] + a["moe_rows_elsewhere"]
+               for a in ticks) == decode
+    assert all(0 <= a["moe_expert_visits"] <= 6 * 4 for a in ticks)
+    gauges = obs.registry.snapshot()["mx_kv_pages_total"]["series"]
+    assert {r["labels"]["group"] for r in gauges} >= {"full", "window"}
+
+
+def test_what_a_ring_cannot_carry_is_refused_by_name(toy):
+    cfg, sym, params, _, _ = toy
+    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
+    pred = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
+                           page_tokens=PAGE, prefill_chunk=CHUNK)
+    assert pred.has_window_group
+    with pytest.raises(MXNetError, match="'window' cache group"):
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=2)
+    server = DecodeServer(pred, max_prefill=32, slots=2, spec_k=0)
+    assert not server._swap_armed
+    with pytest.raises(MXNetError, match="'window' cache group"):
+        server.inject(object())
+    # no prefix cache: a repeated prompt is computed again, never shared
+    server.submit(np.arange(20), max_new_tokens=4)
+    server.submit(np.arange(20), max_new_tokens=4)
+    out = server.run()
+    assert pred._manager is None or pred._manager.prefix_cache is None
+    assert np.array_equal(out[0], out[1])

@@ -599,3 +599,210 @@ def test_moe_dispatch_sort_prices_differently():
     assert s["sort_scatter_bytes"] > 0, s
     assert o["sort_scatter_bytes"] == 0, o
     assert s["bytes"] != o["bytes"]
+
+
+# ---------------------------------------------------------------------------
+# the gated layer at one chip's share (gated=True): sigmoid scores with a
+# selection-only bias, SwiGLU experts, num_held / first_held, no drops
+# ---------------------------------------------------------------------------
+
+def _np_gated(x, wr, b, wg, wu, wd, k, first=0, held=None):
+    """The uncut layer in numpy, one token and one expert at a time; with
+    ``first`` / ``held`` the part that experts [first, first + held) add."""
+    e = wr.shape[1]
+    held = e if held is None else held
+    s = 1.0 / (1.0 + np.exp(-(x @ wr)))
+    y = np.zeros_like(x)
+    for i in range(x.shape[0]):
+        chosen = np.argsort(-(s[i] + b), kind="stable")[:k]
+        w = s[i, chosen] / (s[i, chosen].sum() + 1e-20)
+        for c, wc in zip(chosen, w):
+            if first <= c < first + held:
+                g = x[i] @ wg[c]
+                y[i] += wc * ((g / (1.0 + np.exp(-g)) * (x[i] @ wu[c]))
+                              @ wd[c])
+    return y
+
+
+def _gated_weights(rng, d, e, h):
+    return (rng.normal(0, 0.5, (d, e)).astype(np.float32),
+            rng.normal(0, 0.3, (e,)).astype(np.float32),
+            rng.normal(0, 0.5, (e, d, h)).astype(np.float32),
+            rng.normal(0, 0.5, (e, d, h)).astype(np.float32),
+            rng.normal(0, 0.5, (e, h, d)).astype(np.float32))
+
+
+def _gated(x, wr, b, wg, wu, wd, k, first=0, held=0):
+    e = wr.shape[1]
+    sl = slice(first, first + (held or e))
+    return nd.MoEFFN(nd.array(x), nd.array(wr), nd.array(b),
+                     nd.array(wg[sl]), nd.array(wu[sl]), nd.array(wd[sl]),
+                     num_experts=e, hidden_size=wg.shape[2], gated=True,
+                     score_func="sigmoid", score_bias=True,
+                     num_experts_per_tok=k, num_held=held,
+                     first_held=first).asnumpy()
+
+
+@pytest.mark.parametrize("held", [4, 8])
+def test_gated_shares_add_up(held):
+    """The outputs of the 4 (or 2) shares of a 16-expert layer sum to the
+    uncut layer's output: nothing is lost or counted twice at a share's
+    edge, and the weights are normalised over all the chosen, not the
+    held."""
+    rng = np.random.RandomState(7)
+    n, d, e, h, k = 24, 8, 16, 6, 4
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    w = _gated_weights(rng, d, e, h)
+    whole = _np_gated(x, *w, k=k)
+    firsts = range(0, e, held)
+    parts = [_gated(x, *w, k=k, first=f, held=held) for f in firsts]
+    for f, part in zip(firsts, parts):
+        assert_almost_equal(part, _np_gated(x, *w, k=k, first=f, held=held),
+                            rtol=1e-4, atol=1e-5)
+    assert_almost_equal(sum(parts), whole, rtol=1e-4, atol=1e-5)
+    assert_almost_equal(_gated(x, *w, k=k), whole, rtol=1e-4, atol=1e-5)
+
+
+def test_gated_bias_selects_and_is_not_in_the_weight():
+    rng = np.random.RandomState(8)
+    n, d, e, h, k = 10, 8, 8, 6, 2
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    wr, b, wg, wu, wd = _gated_weights(rng, d, e, h)
+    b = np.zeros(e, np.float32)
+    b[3] = 10.0                     # expert 3 is always chosen...
+    out = _gated(x, wr, b, wg, wu, wd, k=k)
+    assert_almost_equal(out, _np_gated(x, wr, b, wg, wu, wd, k=k),
+                        rtol=1e-4, atol=1e-5)
+    # ... and with its plain score as weight: a weight of score + 10 would
+    # leave the other chosen expert next to nothing
+    alone = _np_gated(x, wr, b, wg, wu, wd, k=k, first=3, held=1)
+    assert np.abs(out - alone).max() > 0.1
+
+
+@pytest.mark.parametrize("first", [4, 8])
+def test_gated_routing_is_dropless_under_a_skewed_router(first):
+    """Every token to the same two experts, one of them held by either
+    share: a capacity dispatch would drop most of them; here none is."""
+    rng = np.random.RandomState(9)
+    n, d, e, h, k = 40, 8, 16, 6, 2
+    x = np.abs(rng.normal(size=(n, d))).astype(np.float32)
+    wr, b, wg, wu, wd = _gated_weights(rng, d, e, h)
+    wr[:] = 0.0
+    b[:] = 0.0
+    b[5], b[9] = 5.0, 4.0           # all tokens choose 5 and 9
+    out = _gated(x, wr, b, wg, wu, wd, k=k, first=first, held=4)
+    want = _np_gated(x, wr, b, wg, wu, wd, k=k, first=first, held=4)
+    assert np.abs(want).min(axis=1).max() > 0       # every token has a part
+    assert_almost_equal(out, want, rtol=1e-4, atol=1e-5)
+
+
+def _counting_run(attrs, w, sl, traces):
+    """A jitted call of the gated op that returns its output and what it
+    counted over the rows ``real`` marks."""
+    import jax
+
+    from mxnet_tpu.ops import moe
+    from mxnet_tpu.registry import OpContext, get_op
+
+    op = get_op("MoEFFN")
+    attrs = op.parse_attrs(attrs)
+    wr, _, wg, wu, wd = w
+
+    @jax.jit
+    def run(x, bias, real):
+        traces.append(1)
+        with moe.collecting(real=real) as rows:
+            out, _ = op.fcompute(
+                attrs, [x, wr, bias, wg[sl], wu[sl], wd[sl]], [],
+                OpContext())
+        return out[0], rows[0]
+
+    return run
+
+
+def test_gated_row_count_is_static_as_routing_changes():
+    """One trace serves every routing: the work is a static held x n rows
+    whatever the tokens chose."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import moe
+
+    rng = np.random.RandomState(10)
+    n, d, e, h, k = 12, 8, 16, 6, 4
+    w = _gated_weights(rng, d, e, h)
+    traces = []
+    run = _counting_run(dict(
+        num_experts=e, hidden_size=h, gated=True, score_func="sigmoid",
+        score_bias=True, num_experts_per_tok=k, num_held=4, first_held=8),
+        w, slice(8, 12), traces)
+    seen = set()
+    for seed in range(4):
+        x = np.random.RandomState(seed).normal(size=(n, d)).astype(np.float32)
+        bias = np.roll(w[1], seed)
+        out, rows = run(jnp.asarray(x), jnp.asarray(bias),
+                        jnp.ones((n,), jnp.int32))
+        assert_almost_equal(
+            np.asarray(out),
+            _np_gated(x, w[0], bias, *w[2:], k=k, first=8, held=4),
+            rtol=1e-4, atol=1e-5)
+        held_rows, elsewhere, visits = (int(v) for v in np.asarray(rows))
+        assert held_rows + elsewhere == n * k and 0 <= visits <= 4
+        seen.add(held_rows)
+    assert len(traces) == 1 and moe.MOE_PATH["last"] == "held_dense"
+    assert len(seen) > 1            # the routing did change
+
+
+def test_gated_counts_leave_out_the_rows_that_are_no_tokens():
+    """Idle slots and a chunk's padding are computed like any row (static
+    shapes) and counted nowhere: the counts over the first 5 of 12 rows are
+    the counts of a call of those 5 alone, and the output is untouched."""
+    import jax.numpy as jnp
+
+    rng = np.random.RandomState(11)
+    n, d, e, h, k = 12, 8, 16, 6, 4
+    w = _gated_weights(rng, d, e, h)
+    attrs = dict(num_experts=e, hidden_size=h, gated=True,
+                 score_func="sigmoid", score_bias=True,
+                 num_experts_per_tok=k, num_held=4, first_held=4)
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    bias = jnp.asarray(w[1])
+    run = _counting_run(attrs, w, slice(4, 8), [])
+    real = (np.arange(n) < 5).astype(np.int32)
+    out_all, rows_all = run(jnp.asarray(x), bias, jnp.ones((n,), jnp.int32))
+    out_5, rows_5 = run(jnp.asarray(x), bias, jnp.asarray(real))
+    _, rows_alone = run(jnp.asarray(x[:5]), bias, jnp.ones((5,), jnp.int32))
+    assert_almost_equal(np.asarray(out_5), np.asarray(out_all))
+    assert list(np.asarray(rows_5)) == list(np.asarray(rows_alone))
+    assert int(rows_5[0] + rows_5[1]) == 5 * k
+    assert int(rows_all[0]) >= int(rows_5[0])
+    assert int(rows_all[0] + rows_all[1]) == n * k
+
+
+def test_gated_attributes_belong_to_the_gated_layer():
+    rng = np.random.RandomState(12)
+    x = rng.normal(size=(4, 6)).astype(np.float32)
+    wg, w1, b1, w2, b2 = _weights(rng, 6, 4, 10)
+    with pytest.raises(Exception, match="gated"):
+        nd.MoEFFN(nd.array(x), nd.array(wg), nd.array(w1), nd.array(b1),
+                  nd.array(w2), nd.array(b2), num_experts=4, hidden_size=10,
+                  score_func="sigmoid")
+    wr, b, eg, eu, ed = _gated_weights(rng, 6, 8, 5)
+    with pytest.raises(Exception, match="not among"):
+        nd.MoEFFN(nd.array(x), nd.array(wr), nd.array(b), nd.array(eg[:4]),
+                  nd.array(eu[:4]), nd.array(ed[:4]), num_experts=8,
+                  hidden_size=5, gated=True, score_bias=True, num_held=4,
+                  first_held=6)
+
+
+def test_gated_symbol_names_and_shapes():
+    s = sym.MoEFFN(sym.Variable("data"), num_experts=16, hidden_size=5,
+                   gated=True, score_bias=True, num_held=4, first_held=4,
+                   name="moe")
+    assert s.list_arguments() == [
+        "data", "moe_gate_weight", "moe_gate_bias",
+        "moe_expert_gate_weight", "moe_expert_up_weight",
+        "moe_expert_down_weight"]
+    arg_shapes, out_shapes, _ = s.infer_shape(data=(2, 3, 8))
+    assert arg_shapes[1:] == [(8, 16), (16,), (4, 8, 5), (4, 8, 5),
+                              (4, 5, 8)]
+    assert out_shapes == [(2, 3, 8)]

@@ -543,6 +543,16 @@ def test_instance_norm():
     assert_almost_equal(out, ref, rtol=1e-3, atol=1e-4)
 
 
+def test_rms_norm():
+    rng = _rng(72)
+    x = rng.normal(size=(2, 3, 8)).astype(np.float32)
+    g = rng.normal(size=(8,)).astype(np.float32)
+    eps = 1e-5
+    out = nd.RMSNorm(nd.array(x), nd.array(g), eps=eps).asnumpy()
+    ref = x / np.sqrt((x ** 2).mean(axis=-1, keepdims=True) + eps) * g
+    assert_almost_equal(out, ref, rtol=1e-4, atol=1e-5)
+
+
 def test_l2_normalization():
     rng = _rng(73)
     x = rng.normal(size=(2, 3, 4)).astype(np.float32)
@@ -680,7 +690,7 @@ TESTED_HERE = (set(UNARY) | set(BINARY) | set(SCALAR) | set(REDUCE)
                   "rmsprop_update", "rmspropalex_update", "fft", "ifft",
                   "_contrib_fft", "_contrib_ifft", "quantize", "dequantize",
                   "_contrib_quantize", "_contrib_dequantize", "InstanceNorm",
-                  "L2Normalization", "LRN", "SVMOutput",
+                  "RMSNorm", "L2Normalization", "LRN", "SVMOutput",
                   "IdentityAttachKLSparseReg", "Correlation", "MakeLoss",
                   "ElementWiseSum", "elemwise_sum", "add_n", "crop", "sort",
                   "argsort", "topk", "ctc_loss", "_contrib_CTCLoss",
