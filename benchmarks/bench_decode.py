@@ -87,6 +87,14 @@ BENCH_PREFILL_CHUNK, BENCH_GQA_GROUPS (comma list of group factors G;
 default "1,4,8" filtered to divisors of BENCH_HEADS).
 ``--smoke``: the tier-1 CI entry — tiny dims on the forced-CPU platform
 (tests/test_bench_contract.py invokes it).
+
+``--live-blocks``: kernel-alone rows of the paged einsum path on the chip
+(no model: one attention node's pools, table and query) — the whole view
+(``paged_gather`` + ``_sdpa_cache``) against the walk over live blocks
+(``_attend_live_blocks``) at the two serving cells' shapes, live shares of
+1/3, 1/2 and 1, a decode row and a prefill chunk; one json row a case on
+stderr.  ``--sweep`` adds the block widths and step sizes the constants in
+``ops/attention.py`` were chosen from.
 """
 import json
 import os
@@ -399,14 +407,15 @@ def main():
     # machine-noise-free even in --smoke): the paged decode step's
     # attention traffic = one pass over the shared KV pool PLUS any
     # materialized gather intermediates.  The einsum path's paged_gather
-    # writes (and its attention re-reads) a full (B, M*pt, E) dense-ring
-    # view per K and V per layer — program_cost's gather_bytes term; the
+    # writes (and its attention re-reads) the blocks the slots have reached
+    # of the (B, M*pt, E) dense-ring view per K and V per layer (all of it
+    # where the view is one block) — program_cost's gather_bytes term; the
     # fused Pallas kernel (MXNET_PALLAS_DECODE) walks the page table
     # inside the kernel and has no such gather, so its priced bytes must
     # drop >= 2x — the mfu_table row ISSUE-11's acceptance pins.
     from mxnet_tpu import config as _cfg
     from mxnet_tpu.analysis.cost import program_cost
-    from mxnet_tpu.ops.attention import decode_kernel_mode
+    from mxnet_tpu.ops.attention import decode_kernel_mode, live_block_plan
 
     def _price_decode_attn(arm, psym=sym, pparams=params):
         knobs = {"MXNET_PALLAS_DECODE": "1" if arm else "0"}
@@ -425,6 +434,14 @@ def main():
                     pp2._decode_fn, (pp2._env, st, tables, active, key))
             finally:
                 pp2._probing = False
+            # the static count sees the walk over live blocks as ONE step
+            # of a loop whose trip count is data: price the einsum path
+            # with every block live, the case the kernel is compared at
+            plan = None if arm else live_block_plan(
+                (slots, 1), (slots, paged_cap // page_tokens), page_tokens)
+            if plan is not None:
+                cost["gather_bytes"] *= -(
+                    -slots * -(-paged_cap // plan[0]) // plan[1])
             return pp2.pool_bytes() + cost["gather_bytes"], cost
 
     attn_einsum, cost_e = _price_decode_attn(False)
@@ -608,10 +625,114 @@ def main():
         "mha_pool_bytes_f32": mha_pool_f32,
     }))
 
+# the two serving cells' attention nodes over the whole context
+# (chipbench/configs, chipbench/traffic): slots, capacity, q heads, kv heads,
+# key and value head widths, the prefill chunk, whether a sink and a value
+# scale join
+LIVE_SHAPES = {
+    "opt-1.3b": dict(slots=32, cap=2048, heads=32, kv_heads=32, hd=64,
+                     hdv=64, chunk=256, sink=False, value_scale=1.0),
+    "mimo-v2.5": dict(slots=64, cap=9216, heads=64, kv_heads=4, hd=192,
+                      hdv=128, chunk=512, sink=True, value_scale=0.707),
+}
+
+
+def live_block_case(shape, tq, share, seed=0, page_tokens=16):
+    """``(args, whole, walk)`` of one kernel-alone case: the arrays, and the
+    two attends as functions of them (``walk(block, group)`` builds one).
+    Slots' lengths are spread evenly over 0.5x to 1.5x of ``share`` of the
+    capacity (all full at share 1); a chunk is one slot at ``share``."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu.ops import attention as attn
+
+    d = LIVE_SHAPES[shape]
+    b = d["slots"] if tq == 1 else 1
+    m = d["cap"] // page_tokens
+    rng = np.random.RandomState(seed)
+    pools = [attn.QuantKV(
+        jnp.asarray(rng.randint(-127, 128, (b * m + 1, page_tokens,
+                                            d["kv_heads"] * w), np.int8)),
+        jnp.asarray(rng.uniform(0.005, 0.02, (b * m + 1, page_tokens,
+                                              d["kv_heads"])), jnp.float32))
+        for w in (d["hd"], d["hdv"])]
+    table = jnp.asarray(rng.permutation(b * m).reshape(b, m) + 1, jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, tq, d["heads"] * d["hd"])),
+                    jnp.bfloat16)
+    mean = share * d["cap"]
+    total = np.full(b, mean) if share >= 1 or b == 1 \
+        else np.linspace(0.5 * mean, 1.5 * mean, b)
+    total = jnp.asarray(np.clip(total, tq, d["cap"]), jnp.int32)
+    sink = jnp.asarray(rng.normal(size=(d["heads"],)), jnp.float32) \
+        if d["sink"] else None
+    kw = dict(sink=sink, value_scale=d["value_scale"], layer="attn")
+
+    def whole(q, kp, vp, table, total):
+        return attn._sdpa_cache(
+            q, attn.paged_gather(kp, table), attn.paged_gather(vp, table),
+            total, d["heads"], None, num_kv_heads=d["kv_heads"], **kw)
+
+    def walk(block, group):
+        return lambda q, kp, vp, table, total: attn._attend_live_blocks(
+            q, kp, vp, table, total, d["heads"], None, d["kv_heads"],
+            block, group, **kw)
+
+    return (q, pools[0], pools[1], table, total), whole, walk
+
+
+def live_blocks_rows(sweep):
+    import jax
+
+    from mxnet_tpu.ops import attention as attn
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        raise SystemExit("--live-blocks times kernels: it needs the chip")
+
+    def ms(fn, args, calls=20):
+        fn = jax.jit(fn)
+        out = jax.block_until_ready(fn(*args))
+        tic = time.perf_counter()
+        for _ in range(calls):
+            last = fn(*args)
+        jax.block_until_ready(last)
+        return (time.perf_counter() - tic) / calls * 1e3, out
+
+    for shape, d in LIVE_SHAPES.items():
+        for tq in (1, d["chunk"]):
+            for share in (1 / 3, 1 / 2, 1):
+                args, whole, walk = live_block_case(shape, tq, share)
+                plan = attn.live_block_plan(args[0].shape, args[3].shape, 16)
+                plans = [plan]
+                if sweep and share != 1 / 2:
+                    plans += [(w, max(1, r // (w * tq)))
+                              for w in (128, 256, 512, 1024)
+                              for r in ((4096, 8192, 16384, 32768)
+                                        if tq == 1 else (w * tq, 2 * w * tq))
+                              if (w, max(1, r // (w * tq))) != plan]
+                t_whole, ref = ms(whole, args)
+                for block, group in plans:
+                    t_walk, got = ms(walk(block, group), args)
+                    err = float(abs(np.asarray(got, np.float32)
+                                    - np.asarray(ref, np.float32)).max())
+                    print(json.dumps({
+                        "phase": "live_blocks", "shape": shape, "tq": tq,
+                        "live_share": round(share, 3), "block": block,
+                        "group": group, "chosen": (block, group) == plan,
+                        "whole_view_ms": round(t_whole, 4),
+                        "live_blocks_ms": round(t_walk, 4),
+                        "speedup": round(t_whole / t_walk, 3),
+                        "max_abs_diff": err,
+                        "device_kind": dev.device_kind}),
+                        file=sys.stderr, flush=True)
+
 
 if __name__ == "__main__":
     if not SMOKE:
         from mxnet_tpu.cache_dirs import arm_compile_cache
 
         arm_compile_cache()
-    main()
+    if "--live-blocks" in sys.argv:
+        live_blocks_rows("--sweep" in sys.argv)
+    else:
+        main()

@@ -55,7 +55,10 @@ def program_cost(fn, args):
     path honestly: ``paged_gather``'s (B, M*page_tokens, E) dense-ring
     view of the KV pool is the largest intermediate in the serving
     system and is invisible to arg/output accounting, which understated
-    decode bytes and OVERstated decode MFU until ISSUE-11.  The
+    decode bytes and OVERstated decode MFU until ISSUE-11 (a view longer
+    than one block is gathered by a loop whose trip count is data,
+    ``ops.attention._attend_live_blocks``: a static count prices ONE
+    step of it, and the caller scales by the steps it assumes).  The
     sort/scatter term does the same for the MoE dispatch algorithms
     (``MXNET_MOE_DISPATCH``): the sort path's key sort and slot scatter
     are priced, so the mfu_table compares it honestly against the

@@ -88,7 +88,7 @@ counts it built before groups existed.
 from __future__ import annotations
 
 import time
-from collections import deque
+from collections import Counter, deque
 from typing import NamedTuple
 
 import numpy as np
@@ -202,7 +202,9 @@ class DecodePredictor:
         ``MXNET_KV_POOL_PAGES`` / ``MXNET_PREFILL_CHUNK``.
         ``cache_len`` must divide by ``page_tokens`` (the table ring-mods
         over ``cache_len // page_tokens`` entries, so paged results stay
-        bit-parity with a dense ring of the same capacity).
+        in parity with a dense ring of the same capacity: to the bit for
+        a view of one block, within float32 tolerance where longer views
+        are attended block by block, see ``ops.attention.paged_attend``).
     prefix_cache : bool
         Arm copy-on-write prefix sharing in paged mode (default on).
     """
@@ -448,6 +450,28 @@ class DecodePredictor:
             for k, c in zip(kinds, caps)]
         self._group_of = [caps.index(l.capacity) for l in self._layouts]
 
+    def attn_walk(self, slots):
+        """``[(capacity, block)]``, one entry per attention node that keeps
+        the whole context in pages: the width of the blocks the decode
+        step over ``slots`` slots walks its view by
+        (``ops.attention.live_block_plan``, from the shapes the step
+        itself shows it), or the capacity where the view is gathered
+        whole."""
+        from .ops import attention as _attn
+
+        out = []
+        armed = _attn.decode_kernel_mode()[0]
+        for layout, node in zip(self._layouts, self._attn_nodes):
+            if not self._paged or layout.kind != "full":
+                continue
+            cap, pt = layout.capacity, self._page_tokens
+            plan = None if armed else _attn.live_block_plan(
+                (slots, 1), (slots, cap // pt), pt,
+                mesh_active=self._mesh is not None,
+                window=int(node.parsed_attrs().get("window", 0) or 0))
+            out.append((cap, plan[0] if plan else cap))
+        return out
+
     @property
     def has_window_group(self):
         """Whether some attention node keeps a ring shorter than
@@ -547,8 +571,9 @@ class DecodePredictor:
         attention against the cache.  With ``tables`` given the caches
         are shared page pools: appends scatter through the per-slot page
         tables (``active``/``valid`` masks redirect non-writes to the
-        scratch page) and attention runs over the gathered dense-ring
-        view — same numerics, paged storage.  Returns ``(probs (B, t, V),
+        scratch page) and attention gathers what the slots have reached
+        of the dense-ring view (``ops.attention.paged_attend``) — paged
+        storage, every live position attended.  Returns ``(probs (B, t, V),
         caches)``.
         """
         import jax
@@ -2260,6 +2285,13 @@ class DecodeServer:
             "(token, chosen expert) pairs routed by the gated MoE layers: "
             "to an expert this chip holds, or elsewhere",
             labels=("program", "where"))
+        self._attn_walk = None      # the predictor's attn_walk, counted
+        self._m_attn_blocks = _obs.registry.counter(
+            "mx_attn_blocks_total",
+            "blocks of the full-context page tables a decode step's "
+            "attention nodes attended (live) and hold (view); a table "
+            "gathered whole is one block a slot",
+            labels=("kind",))
         self._m_moe_visits = _obs.registry.counter(
             "mx_moe_expert_visits_total",
             "held experts with at least one row, summed over MoE layers",
@@ -2374,6 +2406,26 @@ class DecodeServer:
             if program == "decode":
                 note.update(moe_rows_held=held, moe_rows_elsewhere=elsewhere,
                             moe_expert_visits=visits)
+
+    def _note_attn_blocks(self, slot_lens, act_mask, note):
+        """Count the blocks this tick's decode step attends, from the host's
+        own lengths (no device value is read): over the attention nodes
+        that keep the whole context, a slot that decodes reaches ``ceil((len
+        + 1) / block)`` of its table's blocks (all of them once its ring
+        has wrapped), any other slot one.  live / view is the share of
+        the tables the step attended; both also go into ``note``."""
+        if self._attn_walk is None:
+            self._attn_walk = Counter(self._pred.attn_walk(len(slot_lens)))
+        live = view = 0
+        lens = np.where(act_mask > 0, slot_lens + 1, 0)
+        for (cap, block), nodes in self._attn_walk.items():
+            nb = -(-cap // block)
+            live += nodes * int(np.where(lens >= cap, nb, np.clip(
+                -(-lens // block), 1, nb)).sum())
+            view += nodes * nb * len(lens)
+        self._m_attn_blocks.labels(kind="live").inc(live)
+        self._m_attn_blocks.labels(kind="view").inc(view)
+        note.update(attn_blocks_live=live, attn_blocks_view=view)
 
     def _note_accept(self, proposed, accepted):
         """One slot's speculative window accounted."""
@@ -3149,6 +3201,7 @@ class DecodeServer:
                 ps["state"] = state
             note = {}       # filled below, read as the span closes
             with _obs.span("serve.readback", cat="serve", args=note):
+                self._note_attn_blocks(slot_lens, act_mask, note)
                 unread = ps["moe_unread"]
                 if state.moe is not None:
                     unread.append(("decode", state.moe))

@@ -355,7 +355,7 @@ def cache_append(cache, new, start_pos, num_heads=1, layer="attn"):
 
 def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
                 num_kv_heads=0, mesh_active=False, window=0, sink=None,
-                value_scale=1.0, layer="attn"):
+                value_scale=1.0, layer="attn", block=None):
     """Shared length-masked cache-attention core behind
     :func:`sdpa_decode` (tq == 1) and :func:`sdpa_verify` (tq == k+1).
 
@@ -397,7 +397,14 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
     C == j``, and query ``i`` (at ``total_len - tq + i``) sees the slots
     whose position lies in its last ``window``.  The ring may then have
     wrapped under a multi-row query, as long as it is at least ``window +
-    tq - 1`` long.  ``sink`` and ``value_scale`` as in :func:`sdpa`."""
+    tq - 1`` long.  ``sink`` and ``value_scale`` as in :func:`sdpa`.
+
+    ``block=(first, capacity)`` says the planes are one block of a longer
+    view (:func:`_attend_live_blocks`): slot ``j`` of row ``b``'s plane
+    is index ``first[b] + j`` of a view of ``capacity``.  The result is
+    then the block's share of the softmax, not yet normalized: ``(max (B,
+    tq, H), sum (B, tq, H), acc (B, tq, H, hdv))``, all float32, with no
+    sink and no value scale: they join where the blocks are combined."""
     import jax.numpy as jnp
     from jax.lax import Precision
 
@@ -465,7 +472,7 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
                 logits = logits * k_scale
         total = jnp.asarray(total_len, jnp.int32).reshape((-1, 1, 1) + ones)
         qpos = jnp.arange(tq, dtype=jnp.int32).reshape((1,) + ones + (tq, 1))
-        if window or sink is not None:
+        if window or (sink is not None and block is None):
             slot = jnp.arange(c, dtype=jnp.int32).reshape(
                 (1, 1) + ones + (c,))
             qabs = total - tq + qpos
@@ -480,14 +487,20 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
                 None if sink is None else jnp.asarray(sink, jnp.float32)
                 .reshape((1,) + heads + (1, 1)))
         else:
-            limit = jnp.minimum(total - (tq - 1) + qpos, c)
+            limit = jnp.minimum(total - (tq - 1) + qpos,
+                                c if block is None else block[1])
             slot = jnp.arange(c, dtype=jnp.int32).reshape(
                 (1, 1) + ones + (c,))
+            if block is not None:
+                slot = slot + jnp.asarray(block[0], jnp.int32).reshape(
+                    (-1, 1) + ones + (1,))
             logits = jnp.where(slot < limit, logits,
                                jnp.finfo(jnp.float32).min)
             m = jnp.max(logits, axis=-1, keepdims=True)
             p = jnp.exp(logits - m)
-            p = p / jnp.sum(p, axis=-1, keepdims=True)
+            den = jnp.sum(p, axis=-1, keepdims=True)
+            if block is None:
+                p = p / den
         if v_scale is not None:
             with _scope(layer, "kv_dequant"):
                 p = p * v_scale
@@ -500,7 +513,15 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
     else:
         out = jnp.einsum(
             "b%sqk,bkhe->bq%se" % (hx, hx), p.astype(vh.dtype), vh,
-            preferred_element_type=None if v_scale is None else jnp.float32)
+            preferred_element_type=None if v_scale is None and block is None
+            else jnp.float32)
+    if block is not None:
+        # (B, *heads, tq, 1) -> (B, tq, H); the row form's (B, H_kv, G, hdv)
+        # has tq == 1
+        m, den = (jnp.swapaxes(x.reshape(b, num_heads, tq), 1, 2)
+                  .astype(jnp.float32) for x in (m, den))
+        return m, den, out.reshape(b, tq, num_heads, ev // kvh) \
+            .astype(jnp.float32)
     if value_scale != 1.0:
         out = out * jnp.asarray(value_scale, out.dtype)
     return out.reshape(b, tq, num_heads * (ev // kvh))
@@ -555,16 +576,26 @@ def sdpa_verify(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
 # table_width], p % page_tokens].  The table is DATA, not shape — one traced
 # decode/verify/chunk program serves every page mapping (admissions, COW
 # forks, retirements never retrace).  Because the table indexes ring-mod over
-# its width, the gathered per-slot view is laid out exactly like a dense
+# its width, a slot's pages in table order are laid out exactly like a dense
 # ring buffer of capacity table_width * page_tokens, so sdpa_decode /
-# sdpa_verify's length masking (including wrap) applies unchanged and paged
-# results are bit-parity with a dense ring of the same capacity.  Page id 0
+# sdpa_verify's length masking (including wrap) applies unchanged.  A view of
+# one block (LIVE_BLOCK_TOKENS, 256 positions) is gathered whole and attended
+# by the ops a dense ring is attended by: bit-parity with a dense ring of the
+# same capacity.  A longer view is never built: its blocks are gathered and
+# attended a few at a time, only those a slot's length has reached, and
+# combined by one log-sum-exp (_attend_live_blocks): the same positions, the
+# softmax's sums in another order, parity within float32 tolerance.  Page id 0
 # is reserved as a scratch page: unmapped table entries point at it (their
 # slots are masked anyway) and writes of inactive rows are redirected into
 # it, which is what lets one fixed-shape batched program carry slots that
 # are empty or mid-prefill.  The host side (allocator, refcounts,
 # copy-on-write prefix sharing) lives in mxnet_tpu/serve/.
 # ---------------------------------------------------------------------------
+
+def _plane(pool):
+    """The (P, page_tokens, E) array of a pool, quantized or not."""
+    return pool.data if isinstance(pool, QuantKV) else pool
+
 
 def paged_gather(pool, table):
     """Gather a per-slot dense-ring view out of the shared page pool.
@@ -575,7 +606,9 @@ def paged_gather(pool, table):
     ``v == p % (M*page_tokens)`` — the dense ring layout, so the cached
     attention kernels mask it exactly like a ring buffer.  Unmapped table
     entries (id 0, the scratch page) gather garbage into slots the length
-    mask already hides."""
+    mask already hides.  ``table`` may be any rows of page ids:
+    :func:`_attend_live_blocks` hands it one block's pages a row, and gets
+    the blocks' views."""
     if isinstance(pool, QuantKV):
         return QuantKV(paged_gather(pool.data, table),
                        paged_gather(pool.scale, table))
@@ -750,6 +783,167 @@ def _extras(window, sink, value_scale, layer):
                 layer=layer)
 
 
+# The einsum path of :func:`paged_attend` attends a view block by block, and
+# only the blocks a slot's length has reached (:func:`_attend_live_blocks`).
+# LIVE_BLOCK_TOKENS is a block's width by the view's capacity (the widest
+# capacity listed that the view reaches).  Measured kernel alone on the chip
+# (TPU v5 lite, jax 0.9.0, int8 pools, the two serving cells' shapes: 32
+# slots x 2048 of 32 heads of 64, and 64 x 9216 of 64 heads of 192/128 over
+# 4 KV heads; benchmarks/bench_decode.py --live-blocks --sweep, PR 36; ms a
+# layer, whole view first):
+#   decode row, 30 % live:   1.59 -> block 256 0.61, 512 0.61, 1024 0.63
+#   decode row, all live:    1.59 -> block 256 1.42, 512 1.22, 1024 1.15
+#   64 x 9216, half / all:   7.78 -> block 256 4.09 / 7.41, 512 3.56 / 6.37,
+#                                    1024 3.25 / 5.63
+#   chunk of 512, the same:  5.57 -> block 256 1.20 / 2.31, 512 1.16 / 2.23
+#                            (1024: 0.66 ms a block where 512 takes 0.13)
+# and, where kernel alone two widths tie, in the cell whose lengths are its
+# traffic's (prompts log-uniform 128-1024 in a view of 2048: most slots
+# short), tokens/s over three seeds a width: block 256 1172-1175, 512
+# 1129-1147; at 64 x 9216, 512 1240, 1024 1206.
+# A step of the walk does best at 4096-8192 positions (a decode row; at
+# 16384 the last step's idle blocks cost more than the steps saved), a chunk
+# of 512 at one block of 512 a step (two: 3.11 / 5.53, the scores no longer
+# fit where the product leaves them); and a table in fewer than 16 steps
+# leaves the last one mostly idle (32 x 2048 at 30 %: 0.67 in steps of 16
+# blocks of 512, 0.61 in steps of 8).
+LIVE_BLOCK_TOKENS = {0: 256, 8192: 512}
+LIVE_STEP_TOKENS = 8192
+LIVE_STEP_SCORES = 512 * 512
+
+
+def live_block_plan(q_shape, table_shape, page_tokens, mesh_active=False,
+                    window=0):
+    """``(block, group)`` for :func:`_attend_live_blocks` — positions a
+    block and blocks a step — from what the call shows, or None where the
+    view is to be gathered whole: it is one block at most, a block is
+    not whole pages, the node has a ``window`` (its ring is full once
+    reached, and its mask is built from absolute positions), or a mesh
+    shards the pools (GSPMD and a gather loop whose trip count is data
+    have not met)."""
+    b, tq = q_shape[0], q_shape[1]
+    cap = table_shape[1] * page_tokens
+    block = LIVE_BLOCK_TOKENS[max(c for c in LIVE_BLOCK_TOKENS if c <= cap)]
+    if mesh_active or window or cap <= block or block % page_tokens:
+        return None
+    blocks = b * -(-cap // block)
+    return block, max(1, min(LIVE_STEP_TOKENS // block,
+                             LIVE_STEP_SCORES // (block * tq), blocks // 16))
+
+
+def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
+                        scale, num_kv_heads, block, group, sink=None,
+                        value_scale=1.0, layer="attn"):
+    """:func:`paged_gather` + :func:`_sdpa_cache` over the blocks the slots
+    have reached, and no others.
+
+    A slot's view is cut into blocks of ``block`` positions; block ``j`` of
+    slot ``b`` is live when ``total_len[b] > j * block`` (every block, once
+    the ring has wrapped; the first always, so that an empty slot has a
+    finite answer).  The live blocks of all slots make one flat list, built
+    on the device from ``total_len``.  A loop whose trip count is data,
+    ``ceil(n_live / group)``, takes ``group`` of them a step: it gathers
+    their pages as they are stored, takes the two products as
+    :func:`_sdpa_cache` takes them over a whole view, and keeps each
+    block's maximum, sum and accumulated values.  One log-sum-exp over a
+    slot's blocks then combines them; the sink joins there.  Every live
+    position is attended and nothing is approximated: against the whole
+    view only the order of the softmax's sums differs.  The pools are
+    constants of the loop, never carried."""
+    import jax
+    import jax.numpy as jnp
+
+    b, tq, _ = q.shape
+    pt = _plane(k_pool).shape[1]
+    ppb = block // pt
+    cap = table.shape[1] * pt
+    nb = -(-table.shape[1] // ppb)
+    rows = -(-b * nb // group) * group            # the list, padded
+    total = jnp.broadcast_to(
+        jnp.asarray(total_len, jnp.int32).reshape(-1), (b,))
+    with _scope(layer, "kv_gather"):
+        # a last block that is not whole reads the scratch page past the
+        # table's end: those positions lie at or above the capacity
+        pages = jnp.pad(table.astype(jnp.int32),
+                        ((0, 0), (0, nb * ppb - table.shape[1])))
+        reached = jnp.where(total >= cap, nb,
+                            jnp.clip(-(-total // block), 1, nb))
+        ends = jnp.cumsum(reached)
+        flat = jnp.arange(rows, dtype=jnp.int32)
+        slot = jnp.minimum(
+            jnp.sum(flat[:, None] >= ends[None, :], axis=1), b - 1)
+        first = ends - reached
+        blk = jnp.clip(flat - first[slot], 0, nb - 1)
+        pages = pages.reshape(b, nb, ppb)[slot, blk]          # (rows, ppb)
+        steps = -(-ends[-1] // group)
+    hdv = _plane(v_pool).shape[2] // (int(num_kv_heads) or num_heads)
+    # B slots' blocks are kept apart until the loop has ended, one row a
+    # block (row ``rows`` is never written: a slot's dead blocks read it).
+    # One slot's blocks fold into one running row as the loop goes: a
+    # prefill chunk's rows would be nb x tq x H x hdv floats to write,
+    # read back and sum, most of them for blocks never reached
+    running = b == 1
+    fill = jnp.finfo(jnp.float32).min
+    kept = 1 if running else rows + 1
+    parts = (jnp.full((kept, tq, num_heads), fill, jnp.float32),
+             jnp.zeros((kept, tq, num_heads), jnp.float32),
+             jnp.zeros((kept, tq, num_heads, hdv), jnp.float32))
+
+    def fold(parts, got, at):
+        (m0, den0, acc0), (m, den, acc) = parts, got
+        live = at + jnp.arange(group, dtype=jnp.int32) < ends[-1]
+        m = jnp.where(live[:, None, None], m, fill)
+        top = jnp.maximum(m0, jnp.max(m, axis=0, keepdims=True))
+        w0, w = jnp.exp(m0 - top), jnp.exp(m - top)
+        return (top, w0 * den0 + jnp.sum(w * den, axis=0, keepdims=True),
+                w0[..., None] * acc0
+                + jnp.sum(w[..., None] * acc, axis=0, keepdims=True))
+
+    def step(carry):
+        i, parts = carry
+        at = i * group
+        take = lambda x: jax.lax.dynamic_slice_in_dim(x, at, group)
+        rows_of, ids = take(slot), take(pages)
+        with _scope(layer, "kv_gather"):
+            k_blk = paged_gather(k_pool, ids)
+            v_blk = paged_gather(v_pool, ids)
+        got = _sdpa_cache(
+            jnp.broadcast_to(q, (group,) + q.shape[1:]) if running
+            else q[rows_of], k_blk, v_blk, total[rows_of], num_heads, scale,
+            num_kv_heads=num_kv_heads, layer=layer,
+            block=(take(blk) * block, cap))
+        with _scope(layer, "scores"):
+            if running:
+                return i + 1, fold(parts, got, at)
+            return i + 1, tuple(
+                jax.lax.dynamic_update_slice_in_dim(buf, x, at, 0)
+                for buf, x in zip(parts, got))
+
+    _, parts = jax.lax.while_loop(lambda carry: carry[0] < steps, step,
+                                  (jnp.int32(0), parts))
+    with _scope(layer, "scores"):
+        if running:
+            m, den, acc = (buf[:, None] for buf in parts)
+        else:
+            j = jnp.arange(nb, dtype=jnp.int32)[None, :]
+            where = jnp.where(j < reached[:, None], first[:, None] + j, rows)
+            m, den, acc = (buf[where] for buf in parts)  # (B, nb, tq, H[, e])
+        top = jnp.max(m, axis=1)
+        if sink is not None:
+            sink = jnp.asarray(sink, jnp.float32).reshape(1, 1, num_heads)
+            top = jnp.maximum(top, sink)
+        w = jnp.exp(m - top[:, None])
+        den = jnp.sum(w * den, axis=1)
+        if sink is not None:
+            den = den + jnp.exp(sink - top)
+        out = jnp.sum(w[..., None] * acc, axis=1) / den[..., None]
+    if value_scale != 1.0:
+        out = out * jnp.asarray(value_scale, out.dtype)
+    if not isinstance(v_pool, QuantKV):
+        out = out.astype(v_pool.dtype)
+    return out.reshape(b, tq, num_heads * hdv)
+
+
 def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
                  scale=None, mesh_active=False, num_kv_heads=0, window=0,
                  sink=None, value_scale=1.0, layer="attn"):
@@ -762,12 +956,21 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
     int8/fp8 dequant and the length-masked softmax run in ONE HBM pass
     over the pool, split-K parallel over cache length.  Otherwise (knob
     off, unsupported shape, or a mesh-sharded pool — Pallas is opaque to
-    GSPMD) it falls back to the two-pass einsum path:
-    :func:`paged_gather` + :func:`sdpa_decode`/:func:`sdpa_verify` over
-    the gathered view in the pool's storage dtype (an int8/fp8 view is
-    never dequantized into a float copy), whose numerics the kernel
-    matches within documented tolerances (docs/inference.md).  A node with
-    a window, a sink or a value scale takes the einsum path."""
+    GSPMD) it falls back to the two-pass einsum path: gather, then the
+    products of :func:`sdpa_decode`/:func:`sdpa_verify` in the pool's
+    storage dtype (an int8/fp8 view is never dequantized into a float
+    copy), whose numerics the kernel matches within documented tolerances
+    (docs/inference.md).  A node with a window, a sink or a value scale
+    takes the einsum path.
+
+    The einsum path gathers and attends only the blocks the slots have
+    reached (:func:`_attend_live_blocks`, by :func:`live_block_plan`): one
+    program, ``total_len`` as data, no view of the whole table.  Where the
+    plan is None — a view of one block, a window node, a sharded pool —
+    it gathers the whole view (:func:`paged_gather`) and attends it as a
+    dense ring is attended, the same jaxpr as ever and bit-parity with a
+    dense ring; the walk agrees with that within the tolerance of
+    reordered float32 sums."""
     engage, interp = decode_kernel_mode()
     extra = _extras(window, sink, value_scale, layer)
     if engage and not mesh_active and not extra:
@@ -784,6 +987,13 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
         DECODE_PATH["last"] = "einsum-gated"
     else:
         DECODE_PATH["last"] = "einsum"
+    plan = live_block_plan(q.shape, table.shape, _plane(k_pool).shape[1],
+                           mesh_active=mesh_active, window=window)
+    if plan is not None:
+        return _attend_live_blocks(q, k_pool, v_pool, table, total_len,
+                                   num_heads, scale, num_kv_heads, *plan,
+                                   sink=sink, value_scale=value_scale,
+                                   layer=layer)
     with _scope(layer, "kv_gather"):
         k_view = paged_gather(k_pool, table)
         v_view = paged_gather(v_pool, table)

@@ -234,6 +234,11 @@ def test_server_matches_generate_and_counts_moe_rows(toy):
     assert sum(a["moe_rows_held"] + a["moe_rows_elsewhere"]
                for a in ticks) == decode
     assert all(0 <= a["moe_expert_visits"] <= 6 * 4 for a in ticks)
+    # beside them, the blocks the tick attended of its full-context tables:
+    # two full nodes whose 64 positions are one block a slot, attended whole
+    assert pred.attn_walk(3) == [(64, 64)] * 2
+    assert all(a["attn_blocks_live"] == a["attn_blocks_view"] == 2 * 3
+               for a in ticks)
     gauges = obs.registry.snapshot()["mx_kv_pages_total"]["series"]
     assert {r["labels"]["group"] for r in gauges} >= {"full", "window"}
 

@@ -1,0 +1,226 @@
+"""Paged attention over the blocks a slot has reached
+(``ops.attention._attend_live_blocks`` behind ``paged_attend``).
+
+The walk over live blocks against what it replaces, ``paged_gather`` +
+``_sdpa_cache`` over the whole view: every live position is attended and
+only the order of the softmax's sums differs, so the two agree within the
+tolerance ``tests/test_pallas_decode.py`` sets for reordered sums (rtol
+1e-4, atol 1e-5).  A view of one block, a node with a window and a sharded
+pool trace what they traced before; one decode program serves every length;
+and the server counts the blocks it attends from its own lengths.
+"""
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import mxnet_tpu as mx
+from mxnet_tpu import obs
+from mxnet_tpu.decode import DecodePredictor, DecodeServer
+from mxnet_tpu.models import attention_lm
+from mxnet_tpu.ops import attention as attn
+
+PT, PAGES, BLOCK = 4, 16, 16          # a view of 64 positions in 4 blocks
+CAP = PT * PAGES
+TOL = dict(rtol=1e-4, atol=1e-5)
+
+# heads, kv heads, key head width, value head width, sink, value scale
+LAYOUTS = {
+    "mha": (4, 4, 8, 8, False, 1.0),
+    "grouped": (8, 2, 8, 8, False, 1.0),
+    "sink_unequal_kv": (8, 2, 12, 8, True, 0.707),
+}
+# an empty (inactive) slot, one token, a block's edge, one past it, a view
+# exactly full, a wrapped ring, and two lengths inside a block
+RAGGED = [0, 1, BLOCK, BLOCK + 1, CAP, CAP + 9, 40, 33]
+
+
+def _pools(rng, pages, kvh, hd, hdv, kv_dtype, pt=PT):
+    out = []
+    for width in (hd, hdv):
+        x = jnp.asarray(rng.normal(size=(pages, pt, kvh * width)),
+                        jnp.float32)
+        out.append(attn.quantize_kv(x, kv_dtype, kvh) if kv_dtype else x)
+    return out
+
+
+def _whole_view(q, kp, vp, table, total, heads, kvh, sink, value_scale):
+    return attn._sdpa_cache(
+        q, attn.paged_gather(kp, table), attn.paged_gather(vp, table),
+        total, heads, None, num_kv_heads=kvh,
+        **attn._extras(0, sink, value_scale, "attn"))
+
+
+@pytest.mark.parametrize("query", ["row", "verify", "chunk"])
+@pytest.mark.parametrize("kv_dtype", ["int8", ""], ids=["int8", "float"])
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_live_blocks_match_the_whole_view(layout, kv_dtype, query):
+    heads, kvh, hd, hdv, has_sink, value_scale = LAYOUTS[layout]
+    rng = np.random.RandomState(7)
+    sink = jnp.asarray(rng.normal(size=(heads,)), jnp.float32) \
+        if has_sink else None
+    if query == "chunk":
+        # one slot, a chunk of 8 at position 21 of which 5 rows are real:
+        # the pad rows' keys never reach the pool
+        b, tq, pos0, nvalid, group = 1, 8, 21, 5, 3
+        total = [pos0 + tq]
+    else:
+        b, tq, group = len(RAGGED), (1 if query == "row" else 4), 3
+        # a multi-row query has its own rows behind it and has not wrapped
+        total = RAGGED if tq == 1 else [min(max(n, tq), CAP) for n in RAGGED]
+    kp, vp = _pools(rng, 1 + b * PAGES, kvh, hd, hdv, kv_dtype)
+    table = jnp.asarray(
+        1 + rng.permutation(b * PAGES).reshape(b, PAGES), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, tq, heads * hd)), jnp.float32)
+    if query == "chunk":
+        new = [jnp.asarray(rng.normal(size=(b, tq, kvh * w)), jnp.float32)
+               for w in (hd, hdv)]
+        kp, vp = (attn.paged_append(pool, table, x, pos0, num_heads=kvh,
+                                    valid=jnp.asarray([nvalid]))
+                  for pool, x in zip((kp, vp), new))
+    total = jnp.asarray(total, jnp.int32)
+    want = _whole_view(q, kp, vp, table, total, heads, kvh, sink,
+                       value_scale)
+    got = jax.jit(lambda *a: attn._attend_live_blocks(
+        *a, heads, None, kvh, BLOCK, group, sink=sink,
+        value_scale=value_scale))(q, kp, vp, table, total)
+    assert got.shape == want.shape and got.dtype == want.dtype
+    rows = slice(0, nvalid) if query == "chunk" else slice(None)
+    live = np.asarray(total) > 0      # an empty slot's answer is never read
+    assert np.isfinite(np.asarray(got)).all()
+    np.testing.assert_allclose(np.asarray(got)[live, rows],
+                               np.asarray(want)[live, rows], **TOL)
+
+
+def test_a_last_block_that_is_not_whole_reads_the_scratch_page():
+    """A table of 18 pages in blocks of 4 pages: the fifth block holds two
+    pages, and the walk pads it with the scratch page above the capacity."""
+    rng = np.random.RandomState(8)
+    pages, b = 18, 3
+    kp, vp = _pools(rng, 1 + b * pages, 4, 8, 8, "int8")
+    table = jnp.asarray(
+        1 + rng.permutation(b * pages).reshape(b, pages), jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, 1, 32)), jnp.float32)
+    total = jnp.asarray([70, 72, 100], jnp.int32)
+    want = _whole_view(q, kp, vp, table, total, 4, 4, None, 1.0)
+    got = attn._attend_live_blocks(q, kp, vp, table, total, 4, None, 4,
+                                   BLOCK, 4)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), **TOL)
+
+
+def _attend_jaxpr(cap, **kw):
+    b, heads, hd = 2, 4, 8
+    kp, vp = _pools(np.random.RandomState(0), 1 + b * cap // 16, heads, hd,
+                    hd, "int8", pt=16)
+    table = jnp.ones((b, cap // 16), jnp.int32)
+    q = jnp.ones((b, 1, heads * hd), jnp.float32)
+    total = jnp.asarray([3, cap], jnp.int32)
+    paged = jax.make_jaxpr(lambda *a: attn.paged_attend(
+        *a, num_heads=heads, **kw))(q, kp, vp, table, total)
+    extra = attn._extras(kw.get("window", 0), None, 1.0, "attn")
+    whole = jax.make_jaxpr(lambda q, kp, vp, table, total: attn._sdpa_cache(
+        q, attn.paged_gather(kp, table), attn.paged_gather(vp, table), total,
+        heads, None, num_kv_heads=0,
+        mesh_active=kw.get("mesh_active", False), **extra))(
+            q, kp, vp, table, total)
+    return str(paged), str(whole)
+
+
+@pytest.mark.parametrize("case,cap,kw,walks", [
+    ("one_block", 256, {}, False),
+    ("two_blocks", 512, {}, True),
+    ("window", 512, {"window": 128}, False),
+    ("mesh", 512, {"mesh_active": True}, False),
+])
+def test_what_paged_attend_traces(case, cap, kw, walks):
+    """A view of one block, a window node and a sharded pool are gathered
+    whole and attended by the jaxpr they were attended by before the walk;
+    a longer view is walked by a loop whose trip count is data."""
+    paged, whole = _attend_jaxpr(cap, **kw)
+    assert ("while" in paged) == walks
+    assert (paged == whole) == (not walks)
+    assert (attn.live_block_plan((2, 1), (2, cap // 16), 16, **kw)
+            is not None) == walks
+
+
+def test_the_plan_follows_the_calls_shapes():
+    plan = attn.live_block_plan
+    # the two serving cells' decode steps and prefill chunks
+    assert plan((32, 1), (32, 128), 16) == (256, 16)
+    assert plan((64, 1), (64, 576), 16) == (512, 16)
+    assert plan((1, 256), (1, 128), 16) == (256, 1)
+    assert plan((1, 512), (1, 576), 16) == (512, 1)
+    # the verify window: eight blocks a step, as the slots' rows allow
+    assert plan((32, 9), (32, 128), 16) == (256, 16)
+    # a block is whole pages, or the view is gathered whole
+    assert plan((4, 1), (4, 100), 48) is None
+
+
+def _tiny_lm(seq_len):
+    sym = attention_lm.get_symbol(17, seq_len, num_layers=2, embed=8, heads=2,
+                                  ffn_hidden=16)
+    rng = np.random.RandomState(0)
+    shapes, _, _ = sym.infer_shape(data=(1, seq_len),
+                                   softmax_label=(1, seq_len))
+    return sym, {n: rng.normal(0, 0.5, s).astype(np.float32)
+                 for n, s in zip(sym.list_arguments(), shapes)
+                 if n not in ("data", "softmax_label")}
+
+
+def _attn_blocks():
+    fam = obs.registry.snapshot().get("mx_attn_blocks_total", {})
+    return {r["labels"]["kind"]: r["value"] for r in fam.get("series", ())}
+
+
+def test_one_decode_program_serves_every_length_and_counts_its_blocks():
+    """Slots at 10 and at 700 positions of a 1024-position view ride one
+    traced decode step and one traced chunk, deliver what a dense ring of
+    the same capacity generates, and the server's counter follows their
+    lengths: one block of four for the short slot, three for the long."""
+    cap, block = 1024, 256
+    sym, params = _tiny_lm(cap)
+    rng = np.random.RandomState(3)
+    prompts = [rng.randint(0, 17, (n,)) for n in (10, 700)]
+    dense = DecodePredictor(sym, params, cache_len=cap, kv_dtype="int8")
+    paged = DecodePredictor(sym, params, cache_len=cap, kv_dtype="int8",
+                            paged=True, page_tokens=16, prefill_chunk=64)
+    assert paged.attn_walk(2) == [(cap, block)] * 2
+    server = DecodeServer(paged, max_prefill=cap, slots=2, max_new_tokens=6)
+    before, seen = _attn_blocks(), len(obs.timeline.events())
+    ids = [server.submit(p) for p in prompts]
+    results = server.run()
+    for rid, p in zip(ids, prompts):
+        want = dense.generate(p[None].astype(np.float32), p.size,
+                              max_new_tokens=6, seed=0)[0]
+        np.testing.assert_array_equal(results[rid], want)
+    assert paged.trace_counts["decode"] == 1
+    assert paged.trace_counts["chunk"] == 1
+    ticks = [e["args"] for e in obs.timeline.events()[seen:]
+             if e["name"] == "serve.readback" and e.get("args")]
+    assert ticks and all(0 < a["attn_blocks_live"] <= a["attn_blocks_view"]
+                         for a in ticks)
+    # 2 nodes x 2 slots x 4 blocks a tick; while both decode, the short
+    # slot reaches one block and the long one three
+    assert {a["attn_blocks_view"] for a in ticks} == {2 * 2 * 4}
+    assert 2 * (1 + 3) in {a["attn_blocks_live"] for a in ticks}
+    after = _attn_blocks()
+    assert after["live"] - before.get("live", 0) \
+        == sum(a["attn_blocks_live"] for a in ticks)
+    assert after["view"] - before.get("view", 0) \
+        == sum(a["attn_blocks_view"] for a in ticks)
+
+
+def test_a_table_of_one_block_counts_as_attended_whole():
+    sym, params = _tiny_lm(32)
+    paged = DecodePredictor(sym, params, cache_len=32, paged=True,
+                            page_tokens=4)
+    assert paged.attn_walk(3) == [(32, 32)] * 2
+    server = DecodeServer(paged, max_prefill=16, slots=3, max_new_tokens=3)
+    seen = len(obs.timeline.events())
+    server.submit(np.arange(5))
+    server.run()
+    ticks = [e["args"] for e in obs.timeline.events()[seen:]
+             if e["name"] == "serve.readback" and e.get("args")]
+    assert ticks and all(a["attn_blocks_live"] == a["attn_blocks_view"]
+                         == 2 * 3 for a in ticks)
