@@ -1,0 +1,25 @@
+"""The chunk program's convolution and chunked scan as a share of their
+roofline: for the chunks of the window (the real tokens of each, from the
+``serve.prefill`` spans), ``max(FLOPs / peak FLOP/s, bytes / HBM peak)`` by
+``work_ssm.chunk_scan_work`` over the layers run, the mean a chunk, over the
+chunk program's device time under ``mx.ssm/scan`` and ``mx.ssm/conv`` a run.
+"""
+
+from chipbench import work_ssm
+
+
+def read(facts):
+    chunks = work_ssm.noted(facts, "serve.prefill", "tokens")
+    took = work_ssm.scope_seconds(facts, r"chunk_impl",
+                                  {"ssm/scan", "ssm/conv"})
+    if not chunks or not took or not took[0]:
+        return None
+    seconds, runs = took
+    cfg, peaks = facts["config"], facts["peaks"]
+    floor = 0.0
+    for tokens in chunks:
+        flops, moved = work_ssm.chunk_scan_work(cfg, tokens)
+        floor += max(flops / peaks["bf16_flops_per_s"],
+                     moved / peaks["hbm_bytes_per_s"])
+    floor *= work_ssm.layers_run(cfg) / len(chunks)
+    return 100.0 * floor / (seconds / runs)

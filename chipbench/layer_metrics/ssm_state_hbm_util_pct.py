@@ -1,0 +1,20 @@
+"""The decode step's recurrence as a share of its roofline: the rows a tick
+advanced (``ssm_rows``, the mean over the window's ticks) times what one row
+must move (``work_ssm.state_step_bytes``: the state and the conv tail, read
+once and written once), over the decode program's device time under
+``mx.ssm/step`` a run, as a share of the chip's HBM peak.  A program that
+reads the state twice, or copies it, moves more than that and shows it here.
+"""
+
+from chipbench import work_ssm
+
+
+def read(facts):
+    rows = work_ssm.noted(facts, "serve.readback", "ssm_rows")
+    took = work_ssm.scope_seconds(facts, r"paged_decode", {"ssm/step"})
+    if not rows or not took or not took[0]:
+        return None
+    seconds, runs = took
+    need = sum(rows) / len(rows) * work_ssm.state_step_bytes(facts["config"])
+    return 100.0 * need / (seconds / runs) \
+        / facts["peaks"]["hbm_bytes_per_s"]

@@ -70,20 +70,27 @@ decode steps, so a long prompt never stalls the serving batch.
 
 The symbol contract (checked at trace time, documented in
 docs/inference.md): decoder-only graphs built from position-independent ops
-plus ``dot_product_attention`` for sequence mixing.  Positions enter either
+plus two stateful ones for sequence mixing, ``dot_product_attention`` (keys
+and values a position) and ``SelectiveSSM`` (``ops.ssm``: a conv tail and a
+recurrent state a sequence, no positions).  Positions enter either
 as a learned positional table added via a ``broadcast_*`` op against a
 ``(1, S, E)`` variable, or inside the attention node (``rotary_dim``: q and
 k are rotated at the positions the walk passes, and the keys are cached
 rotated) — ``models.attention_lm``, ``models.decoder_lm`` and the benchmark
 LMs qualify.
 
-**Cache groups.**  Each attention node has a cache layout read off it at
-bind time (:class:`CacheLayout`): full nodes hold ``cache_len`` positions a
-slot, a paged window node a ring of ``window + prefill_chunk`` positions
-rounded up to a page.  Nodes of one capacity form a group
-(``serve.CacheGroup``) with its own page count, page tables and allocator;
-a graph of one kind builds one group and the tables, programs and page
-counts it built before groups existed.
+**Cache groups.**  Each stateful node has a cache layout read off it at
+bind time (:class:`CacheLayout`), of one of three kinds: a "full" attention
+node holds ``cache_len`` positions a slot, a paged "window" node a ring of
+``window + prefill_chunk`` positions rounded up to a page, a "state" node
+(``SelectiveSSM``) one fixed row a slot and no positions at all.  Nodes of
+one kind and capacity form a group (``serve.CacheGroup``): the paged kinds
+with their own page count, page tables and allocator, the state group with
+one row a slot whose "table" is the row's index.  A graph of one kind builds
+one group and the tables, programs and page counts it built before groups
+existed.  A state row has no scratch page: the decode step masks its write
+by ``active``, and a chunk at ``pos0 == 0`` starts from zero state, so a
+slot is reused with no clearing program.
 """
 from __future__ import annotations
 
@@ -137,11 +144,13 @@ def _per_group(items):
 
 
 class CacheLayout(NamedTuple):
-    """What one attention node keeps a slot, read off the node at bind
-    time: ``kind`` ("full" | "window"), its KV heads, and the positions
-    it holds (``capacity``); the key and value widths are the pools'
-    trailing dims (``DecodePredictor.cache_layouts`` adds them once the
-    shapes are probed)."""
+    """What one stateful node keeps a slot, read off the node at bind
+    time: ``kind`` ("full" | "window" | "state"), its KV heads, and the
+    positions it holds (``capacity``; a "state" node has neither: it keeps
+    one row, whatever the sequence's length); the key and value widths are
+    the pools' trailing dims, for a state node the conv tail's and the
+    state's own (``DecodePredictor.cache_layouts`` adds them once the shapes
+    are probed)."""
 
     kind: str
     kv_heads: int
@@ -153,15 +162,19 @@ class CacheLayout(NamedTuple):
 class DecodeState(NamedTuple):
     """The donated per-step serving state (a jax pytree)."""
 
-    caches: tuple       # ((k, v), ...) per attention node: (B, C, E)
-                        # arrays, or ops.attention.QuantKV (data + scales)
-                        # under a quantized MXNET_KV_DTYPE
+    caches: tuple       # one entry per stateful node, in graph order.  An
+                        # attention node: (k, v), (B, C, E) arrays, or
+                        # ops.attention.QuantKV (data + scales) under a
+                        # quantized MXNET_KV_DTYPE.  A SelectiveSSM node:
+                        # (conv tail (B, K-1, C), state (B, H, P, N))
     lens: object        # (B,) int32 — tokens appended to each cache so far
     tok: object         # (B, 1) int32 — last sampled token, not yet appended
     moe: object = None  # int32 [rows held, rows elsewhere, held experts
                         # visited], summed over the gated MoE layers of the
                         # paged step that made this state; None (no leaf)
                         # for a graph without them and on the way in
+    ssm: object = None  # int32: (slot, SelectiveSSM node) rows whose state
+                        # the paged step advanced; None as ``moe`` is
 
 
 class DecodePredictor:
@@ -217,6 +230,7 @@ class DecodePredictor:
         import jax.numpy as jnp
 
         from . import symbol as sym_mod
+        from .ops import ssm as _ssm
         from .predictor import _as_param_dicts
 
         if isinstance(symbol, str):
@@ -275,12 +289,28 @@ class DecodePredictor:
         if data_name not in free:
             raise MXNetError("%r is not a free input of the symbol (free "
                              "inputs: %s)" % (data_name, free))
-        self._attn_nodes = [n for n in symbol._topo()
-                            if not n.is_variable
-                            and n.op.name == "dot_product_attention"]
+        # the stateful nodes, in graph order: DecodeState.caches, the
+        # layouts and the groups are indexed by position in this list
+        self._cache_nodes = [n for n in symbol._topo()
+                             if not n.is_variable and n.op.name in (
+                                 "dot_product_attention", _ssm.OP_NAME)]
+        self._attn_nodes = [n for n in self._cache_nodes
+                            if n.op.name == "dot_product_attention"]
         if not self._attn_nodes:
             raise MXNetError("symbol has no dot_product_attention node; "
                              "nothing to cache — use Predictor")
+        if len(self._cache_nodes) > len(self._attn_nodes):
+            if not self._paged:
+                raise MXNetError(
+                    "a graph with %s nodes is served paged only "
+                    "(paged=True): the dense ring's prefill pads a prompt "
+                    "to its window and its verify step rolls lengths back, "
+                    "and a recurrent state can take neither" % _ssm.OP_NAME)
+            if mesh is not None:
+                raise MXNetError(
+                    "a graph with %s nodes is served on one device: "
+                    "parallel.tp_rules has no plan for the mixer's heads "
+                    "and groups" % _ssm.OP_NAME)
         # per-attention-node head dims, recorded at trace time by _run
         # (num_heads / num_kv_heads / q_dim / kv_dim) — the grouped-layout
         # source of truth for cache meta and CacheBytesPass
@@ -420,19 +450,25 @@ class DecodePredictor:
         return self._cache_len
 
     def _bind_cache_groups(self):
-        """One :class:`CacheLayout` per attention node, and the nodes
+        """One :class:`CacheLayout` per stateful node, and the nodes
         grouped by what a slot holds of them.  A paged window node keeps
         a ring of ``window + prefill_chunk`` positions (rounded up to a
         page): a chunk is appended whole before it is attended, so the
         ring has to hold the chunk and the window before its first
-        query.  Everything else, a window node of a dense predictor
-        included, keeps ``cache_len`` (its mask does the rest)."""
+        query.  Every other attention node, a window node of a dense
+        predictor included, keeps ``cache_len`` (its mask does the rest).
+        A state node keeps no positions (capacity 0): its group comes
+        last."""
         from .serve.manager import CacheGroup
 
         pt = self._page_tokens
-        kind_of = lambda cap: "window" if cap < self._cache_len else "full"
+        kind_of = lambda cap: "state" if not cap else \
+            "window" if cap < self._cache_len else "full"
         self._layouts = []
-        for n in self._attn_nodes:
+        for n in self._cache_nodes:
+            if n.op.name != "dot_product_attention":
+                self._layouts.append(CacheLayout("state", 0, 0))
+                continue
             a = n.parsed_attrs()
             window = int(a.get("window", 0) or 0)
             cap = self._cache_len
@@ -461,7 +497,7 @@ class DecodePredictor:
 
         out = []
         armed = _attn.decode_kernel_mode()[0]
-        for layout, node in zip(self._layouts, self._attn_nodes):
+        for layout, node in zip(self._layouts, self._cache_nodes):
             if not self._paged or layout.kind != "full":
                 continue
             cap, pt = layout.capacity, self._page_tokens
@@ -477,7 +513,25 @@ class DecodePredictor:
         """Whether some attention node keeps a ring shorter than
         ``cache_len``: then there is no prefix sharing, and the serving
         loop refuses what a ring cannot carry."""
-        return len(self._groups) > 1 or self._groups[0].kind == "window"
+        return any(g.kind == "window" for g in self._groups)
+
+    @property
+    def unshared_groups(self):
+        """The cache groups that hold something other than every position
+        of the context (a "window" ring, a "state" row; widest first, as
+        the groups are): a graph with one has no prefix sharing, and the
+        serving loop refuses by name what such a group cannot carry
+        (``serve.manager.WHY_NOT``)."""
+        return [g for g in self._groups if g.kind != "full"]
+
+    def state_row_bytes(self):
+        """Bytes one slot holds in the "state" group: the conv tails and
+        the states of every ``SelectiveSSM`` node (0 without one)."""
+        if self._pools_template is None:
+            self._pools_template = self._probe_cache_shapes()
+        return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
+                   for l, pair in zip(self._layouts, self._pools_template)
+                   if l.kind == "state" for a in pair)
 
     def cache_layouts(self):
         """The per-node :class:`CacheLayout`\\ s with the key and value
@@ -487,22 +541,48 @@ class DecodePredictor:
         if self._pools_template is None:
             self._pools_template = self._probe_cache_shapes()
         width = lambda a: int((a.data if isinstance(a, QuantKV)
-                               else a).shape[2])
+                               else a).shape[-1])
         return [l._replace(key_width=width(kc), value_width=width(vc))
                 for l, (kc, vc) in zip(self._layouts, self._pools_template)]
 
-    def _tables_of(self, mgr, rows=None):
+    def _tables_of(self, mgr):
         """The manager's page tables as the programs take them: one
-        (B, M) array, or one a group where the graph has several.
-        ``rows`` (a slice) picks slots."""
+        (B, M) array, or one a group where the graph has several."""
         import jax.numpy as jnp
 
-        rows = slice(None) if rows is None else rows
-        return _per_group(jnp.asarray(g.tables[rows]) for g in mgr.groups)
+        return _per_group(jnp.asarray(g.tables) for g in mgr.groups)
+
+    def _chunk_operands(self, slot, tokens, pos, width):
+        """The chunk program's small operands for ``tokens`` of ``slot``
+        from position ``pos``: the slot's table rows, the window padded to
+        ``width``, the start and the count.  Host arrays, which the call
+        ships with its other arguments: a ``jnp.asarray`` each took the
+        host 1.1 ms a chunk more (PERF.md §6, PR 39), with the device
+        waiting for the chunk meanwhile."""
+        rows = slice(slot, slot + 1)
+        return (_per_group(g.tables[rows].copy()
+                           for g in self._manager.groups),
+                _pad_window(tokens, width),
+                np.asarray([pos], np.int32),
+                np.asarray([len(tokens)], np.int32))
 
     def _pool_pages_of(self, ai):
-        """Pages in the pool of attention node ``ai``: its group's."""
+        """Pages in the pool of stateful node ``ai``: its group's (the
+        rows of a state group)."""
         return self._manager.groups[self._group_of[ai]].pool_pages
+
+    def _table_width(self, group):
+        """Entries of one slot's table in ``group``: its pages, or the one
+        row index of a state group."""
+        return group.capacity // self._page_tokens or 1
+
+    def _pool_shape(self, ai, aval, pages):
+        """Shape of node ``ai``'s pool (or state array) built from the
+        probed batch-1 ``aval``: ``pages`` pages of ``page_tokens``
+        positions, or ``pages`` state rows."""
+        if self._layouts[ai].kind == "state":
+            return (pages,) + tuple(aval.shape[1:])
+        return (pages, self._page_tokens, aval.shape[2])
 
     # ------------------------------------------------------------------
     # roofline telemetry (mxnet_tpu.obs) — host-side only: the compiled
@@ -573,18 +653,24 @@ class DecodePredictor:
         tables (``active``/``valid`` masks redirect non-writes to the
         scratch page) and attention gathers what the slots have reached
         of the dense-ring view (``ops.attention.paged_attend``) — paged
-        storage, every live position attended.  Returns ``(probs (B, t, V),
-        caches)``.
+        storage, every live position attended.  A ``SelectiveSSM`` node
+        carries ``(conv tail, state)`` rows the same way: from zero in
+        prefill mode; one token a row in place, its write masked by
+        ``active``, in a decode step; the rows its group's table names, the
+        padding past ``valid`` skipped, in a chunk.  Returns ``(probs (B, t,
+        V), caches)``; ``self._ssm_rows`` holds the rows each such node
+        advanced, for the program that called.
         """
         import jax
         import jax.numpy as jnp
 
         from .obs.scopes import node_scope as _node_scope
-        from .ops import attention as _attn
+        from .ops import attention as _attn, ssm as _ssm
 
         b, t = tokens.shape[0], tokens.shape[1]
         new_caches = []
-        ci = 0
+        self._ssm_rows = []
+        ci = qi = 0
         values = {}
         base_key = jax.random.PRNGKey(0)
         for seq, node in enumerate(self._symbol._topo()):
@@ -618,10 +704,11 @@ class DecodePredictor:
                                 num_kv_heads=int(kv_heads),
                                 q_dim=int(q.shape[-1]),
                                 kv_dim=int(k.shape[-1]))
-                    if ai < len(self._attn_dims):
-                        self._attn_dims[ai] = dims
+                    if qi < len(self._attn_dims):
+                        self._attn_dims[qi] = dims
                     else:
                         self._attn_dims.append(dims)
+                    qi += 1
                     scale = attrs.get("scale", 0.0) or None
                     # what the node adds to plain causal attention: a
                     # window, a value scale, its scope, a sink (the fourth
@@ -682,6 +769,37 @@ class DecodePredictor:
                         # mxlint pallas-fallback error
                         self._decode_path = _attn.DECODE_PATH["last"]
                         new_caches.append((kc, vc))
+                elif opname == _ssm.OP_NAME:
+                    ai = ci
+                    ci += 1
+                    if caches is None:
+                        out, carried, rows = _ssm.mix(attrs, *ins)
+                    elif valid is not None:
+                        # a chunk: the rows the state group's table names
+                        at = tables[self._group_of[ai]][:, 0]
+                        out, rows_new, rows = _ssm.mix(
+                            attrs, *ins, pos0=pos0, nvalid=valid,
+                            state=tuple(jnp.take(a, at, axis=0)
+                                        for a in caches[ai]))
+                        carried = tuple(
+                            a.at[at].set(r) for a, r in zip(caches[ai],
+                                                            rows_new))
+                    elif t == 1 and active is not None:
+                        # a decode step: every slot's row in place, the
+                        # write masked (no scratch row to send junk to)
+                        out, carried, rows = _ssm.mix(
+                            attrs, *ins, state=caches[ai], active=active)
+                    else:
+                        raise MXNetError(
+                            "decode: node %r (%s) carries a recurrent state "
+                            "one token at a time or one chunk at a time; a "
+                            "step of %d tokens over a carried state (the "
+                            "speculative verify window) would advance it "
+                            "past what a rejected draft can roll back"
+                            % (node.name, opname, t))
+                    self._ssm_rows.append(rows)
+                    outs = [out]
+                    new_caches.append(carried)
                 else:
                     if opname in _POSITION_BROADCAST_OPS and len(ins) == 2 \
                             and getattr(ins[0], "ndim", 0) == 3 \
@@ -855,8 +973,9 @@ class DecodePredictor:
     def _paged_decode_impl(self, env, state, tables, active, key):
         """One paged decode step at fixed batch shape.  ``active`` (B,)
         0/1 gates rows that are empty or mid-chunked-prefill: their
-        appends redirect to the scratch page and their lens/tok are
-        preserved, so one traced program carries every batch occupancy."""
+        appends redirect to the scratch page, their recurrent state comes
+        out as it went in, and their lens/tok are preserved, so one traced
+        program carries every batch occupancy."""
         import jax.numpy as jnp
 
         from .ops.moe import collecting
@@ -872,10 +991,11 @@ class DecodePredictor:
         act = jnp.asarray(active).reshape(-1, 1).astype(bool)
         tok = jnp.where(act, tok, state.tok)
         lens = state.lens + jnp.asarray(active, jnp.int32).reshape(-1)
-        if moe_rows:
-            # beside the sampled tokens, and read with them: no new sync
-            return DecodeState(caches, lens, tok, sum(moe_rows)), probs
-        return DecodeState(caches, lens, tok), probs
+        # beside the sampled tokens, and read with them: no new sync
+        return DecodeState(caches, lens, tok,
+                           sum(moe_rows) if moe_rows else None,
+                           sum(self._ssm_rows) if self._ssm_rows
+                           else None), probs
 
     def _paged_verify_impl(self, env, state, tables, active, draft_toks,
                            draft_probs, key):
@@ -909,8 +1029,10 @@ class DecodePredictor:
         chunk's K/V at positions [pos0, pos0 + nvalid) of the slot's page
         table (pad positions past ``nvalid`` are never written), attend
         causally against everything cached so far, and sample at the
-        chunk's last real position.  The final chunk's sample IS the
-        request's first token; earlier chunks' samples are discarded.
+        chunk's last real position; a recurrent state advances over the
+        real positions only, from zero where ``pos0`` is 0.  The final
+        chunk's sample IS the request's first token; earlier chunks'
+        samples are discarded.
         One trace per chunk width — chunked prefill never retraces."""
         import jax.numpy as jnp
 
@@ -1065,6 +1187,7 @@ class DecodePredictor:
         cache + page tables) and zeroed pools.  Pool shapes depend only
         on (pool_pages, page_tokens, E), so repeated batches at one
         sizing reuse every compiled program."""
+        import jax
         import jax.numpy as jnp
 
         from .ops.attention import QuantKV
@@ -1091,6 +1214,13 @@ class DecodePredictor:
         pools = []
         for ai, (kc, vc) in enumerate(self._pools_template):
             pp = self._pool_pages_of(ai)
+            if self._layouts[ai].kind == "state":
+                # one row a slot; no layout to choose, nothing to shard
+                pools.append(tuple(
+                    jax.device_put(jnp.zeros(self._pool_shape(ai, a, pp),
+                                             a.dtype), self._ctx.jax_device)
+                    for a in (kc, vc)))
+                continue
 
             def pool_of(aval, is_scale=False):
                 return self._place_pool(
@@ -1121,9 +1251,8 @@ class DecodePredictor:
             raise MXNetError("pool_bytes before any paged prefill/serve")
         if self._pools_template is None:
             self._pools_template = self._probe_cache_shapes()
-        pt = self._page_tokens
         return sum(shape_bytes(shape_str(
-            (self._pool_pages_of(ai), pt, aval.shape[2]), aval.dtype))
+            self._pool_shape(ai, aval, self._pool_pages_of(ai)), aval.dtype))
             for ai, pair in enumerate(self._pools_template)
             for aval in jtu.tree_leaves(pair))
 
@@ -1194,8 +1323,8 @@ class DecodePredictor:
         pt = self._page_tokens
         # per cache group: table width and pool pages (an explicit pool
         # size sizes the first group, as the managers do)
-        ms = [g.capacity // pt for g in self._groups]
-        pps = [PagedKVManager.pool_sizing(
+        ms = [self._table_width(g) for g in self._groups]
+        pps = [slots if g.kind == "state" else PagedKVManager.pool_sizing(
             slots, g.capacity, pt, self._pool_pages if i == 0 else 0)
             for i, g in enumerate(self._groups)]
         m = ms[0]
@@ -1215,8 +1344,8 @@ class DecodePredictor:
                 pools.append(tuple(pair))
             return tuple(pools)
 
-        caches = build(lambda ai, a: (pps[self._group_of[ai]], pt,
-                                      a.shape[2]))
+        caches = build(lambda ai, a: self._pool_shape(
+            ai, a, pps[self._group_of[ai]]))
         env = {n: aval_of(v) for n, v in self._env.items()}
         lens = sds((slots,), jnp.int32)
         tok = sds((slots, 1), jnp.int32)
@@ -1454,7 +1583,6 @@ class DecodePredictor:
         program in fixed-width windows; returns (caches, first-token,
         first-token probs) from the final chunk."""
         import jax
-        import jax.numpy as jnp
 
         mgr = self._manager
         total = int(prompt.size)
@@ -1479,10 +1607,8 @@ class DecodePredictor:
                 # too: the serving loop reads them, this path does not)
                 caches, probs, tok = self._chunk_fn(
                     self._env, caches,
-                    self._tables_of(mgr, slice(slot, slot + 1)),
-                    jnp.asarray(_pad_window(prompt[pos:pos + n], w)),
-                    jnp.asarray([pos], jnp.int32),
-                    jnp.asarray([n], jnp.int32), sub)[:3]
+                    *self._chunk_operands(slot, prompt[pos:pos + n], pos, w),
+                    sub)[:3]
             pos += n
         return caches, tok, probs
 
@@ -1725,7 +1851,7 @@ class DecodePredictor:
             tables = self._tables_of(self._manager)
         else:
             tables = _per_group(
-                jnp.zeros((b, g.capacity // self._page_tokens), jnp.int32)
+                jnp.zeros((b, self._table_width(g)), jnp.int32)
                 for g in self._groups)
         return tables, jnp.ones((b,), jnp.int32)
 
@@ -1816,8 +1942,11 @@ class DecodePredictor:
         from . import config as _config
         from .ops.attention import QuantKV
 
+        # the keys' and values' planes; a state node's rows are not theirs
+        kv = [pair for l, pair in zip(self._layouts, state.caches)
+              if l.kind != "state"]
         dtypes = set()
-        for kc, vc in state.caches:
+        for kc, vc in kv:
             for c in (kc, vc):
                 dtypes.add(str((c.data if isinstance(c, QuantKV)
                                 else c).dtype))
@@ -1850,7 +1979,7 @@ class DecodePredictor:
             meta["num_kv_heads"] = int(self._grouped_kv_heads)
             meta["attn_dims"] = [dict(d) for d in self._attn_dims]
             widths = set()
-            for kc, vc in state.caches:
+            for kc, vc in kv:
                 for c in (kc, vc):
                     widths.add(int((c.data if isinstance(c, QuantKV)
                                     else c).shape[2]))
@@ -2247,17 +2376,20 @@ class DecodeServer:
             proposer = NGramProposer(spec_k)
         self._spec_k = int(spec_k or 0)
         self._proposer = proposer
-        # a 'window' cache group keeps a ring of its last positions: what
-        # a ring cannot carry is refused here, by name, and never served
-        # from a recycled page
-        self._ring = bool(getattr(predictor, "_paged", False)
-                          and predictor.has_window_group)
-        if self._ring and (self._spec_k or proposer is not None):
+        # a 'window' cache group keeps a ring of its last positions and a
+        # 'state' group one recurrent state a slot: what such a group
+        # cannot carry is refused here, by name and with the group's own
+        # reason, and never served from a recycled page or a stale row
+        from .serve.manager import why_not
+
+        self._unshared = predictor.unshared_groups \
+            if getattr(predictor, "_paged", False) else []
+        if self._unshared and (self._spec_k or proposer is not None):
+            kind, reason = why_not("speculation", self._unshared)
             raise MXNetError(
                 "speculative decoding is not supported on a graph with a "
-                "'window' cache group (groups: %s): a rejected draft's keys "
-                "would sit in ring positions the length mask cannot hide"
-                % [g.name for g in predictor._groups])
+                "%r cache group (groups: %s): %s"
+                % (kind, [g.name for g in predictor._groups], reason))
         if proposer is not None and getattr(proposer, "cache_len", None):
             if self._max_prefill > proposer.cache_len:
                 raise MXNetError(
@@ -2300,15 +2432,30 @@ class DecodeServer:
             "mx_moe_calls_total",
             "runs of a program with gated MoE layers",
             labels=("program",))
+        self._m_ssm_rows = _obs.registry.counter(
+            "mx_ssm_rows_total",
+            "(slot, SelectiveSSM node) rows whose recurrent state a decode "
+            "step advanced (idle and mid-prefill slots left out)")
+        self._m_ssm_chunk_tokens = _obs.registry.counter(
+            "mx_ssm_chunk_tokens_total",
+            "(prompt token, SelectiveSSM node) pairs the chunk program "
+            "scanned (a chunk's padding left out)")
+        self._m_ssm_state_bytes = _obs.registry.gauge(
+            "mx_ssm_state_bytes",
+            "bytes of the state cache group: every slot's conv tails and "
+            "recurrent states")
+        self._ssm_nodes = sum(
+            l.kind == "state" for l in getattr(predictor, "_layouts", ()))
         # --- fleet/preemption state (paged loop) ---
         # fair admission: after this many consecutive pool-gate-blocked
         # iterations the lowest-priority slot is preempted (swap-out) so
         # a long decode can no longer wedge the admission gate
         self._fair_bound = int(_config.get("MXNET_FLEET_DECODE_BOUND"))
-        # preemption moves a slot's pages to the host and back: a ring's
-        # pages are not restorable, so a window group disarms it
+        # preemption moves a slot's pages to the host and back: neither a
+        # ring's pages nor a state row are restorable, so such a group
+        # disarms it
         self._swap_armed = bool(_config.get("MXNET_FLEET_SWAP")) \
-            and not self._ring
+            and not self._unshared
         self._preempt_cb = None     # serve.fleet routes records back out
         self._verify_restore = False   # tests: assert restore bit-parity
         self._ps = None             # persistent paged session (tick API)
@@ -2460,10 +2607,13 @@ class DecodeServer:
         record admits through the normal reservation gate and restores
         by installing its saved pages (no prefill); SLO timestamps carry
         over so fleet TTFT stays honest.  Returns this host's rid."""
-        if self._ring:
+        if self._unshared:
+            from .serve.manager import why_not
+
             raise MXNetError(
                 "inject: restoring a swapped or migrated request is not "
-                "supported on a graph with a 'window' cache group")
+                "supported on a graph with a %r cache group: %s"
+                % why_not("restore", self._unshared))
         rid = self._next_id
         self._next_id += 1
         entry = {"rid": rid, "prompt": record.prompt, "cap": record.cap,
@@ -2506,6 +2656,9 @@ class DecodeServer:
         cap and retiring at an EOS inside the window (shared by the
         dense and paged loops — ONE copy of the retirement rule)."""
         toks, max_new = rec["toks"], rec["cap"]
+        if self._eos_id is not None and toks and toks[-1] == self._eos_id:
+            # ended at its first token, read after this step was queued
+            return
         for t in emitted:
             if len(toks) >= max_new:
                 break
@@ -2758,6 +2911,8 @@ class DecodeServer:
         }
         for g in pred._manager.groups:      # a pool's size: once a session
             self._m_pages_total.labels(group=g.name).set(g.pool_pages)
+        if self._ssm_nodes:
+            self._m_ssm_state_bytes.set(slots * pred.state_row_bytes())
         return self._ps
 
     def serve_reset(self):
@@ -3021,7 +3176,9 @@ class DecodeServer:
         interleaves with decode instead of stalling the batch; (3) on
         the final chunk, splice the first token/length into the batch
         state, publish the prompt's pages to the prefix cache and
-        activate the slot; (4) retire finished requests — freeing their
+        activate the slot (the host reads that token under
+        ``serve.readback``, after step (5) is queued behind the chunk;
+        with a proposer, whose drafts need it, at the commit); (4) retire finished requests — freeing their
         pages IMMEDIATELY, EOS-mid-speculation-window included; (5) run
         one decode (or speculative verify) step over the active slots,
         inactive rows masked.  Every device program here was traced
@@ -3070,6 +3227,16 @@ class DecodeServer:
             self._retire_finished(active, ps["results"], on_retire)
 
         deliver = self._deliver
+        unsettled = []      # (slot, device token) of this tick's commit
+
+        def settle_first():
+            """Read the committed slot's first token: a wait for the
+            chunk alone, whatever is queued behind it."""
+            slot, tok = unsettled.pop()
+            first = int(np.asarray(tok)[0, 0])
+            active[slot]["toks"].append(first)
+            histories[slot].append(first)
+            self._req[active[slot]["rid"]]["first"] = time.time()
 
         with _obs.span("serve.admit", cat="serve"):
             # --- (1a) slot-full priority preemption: a waiter that OUTRANKS
@@ -3119,13 +3286,9 @@ class DecodeServer:
                     if copies else state.caches
                 sub = next_key()
                 _obs.instant("prefill_chunk", cat="serve", args=where)
-                args = (pred._env, caches,
-                        pred._tables_of(mgr, slice(p["slot"], p["slot"] + 1)),
-                        jnp.asarray(_pad_window(
-                            p["prompt"][p["pos"]:p["pos"] + n],
-                            self._chunk_w)),
-                        jnp.asarray([p["pos"]], jnp.int32),
-                        jnp.asarray([n], jnp.int32), sub)
+                args = (pred._env, caches) + pred._chunk_operands(
+                    p["slot"], p["prompt"][p["pos"]:p["pos"] + n],
+                    p["pos"], self._chunk_w) + (sub,)
                 # its dispatch wall accrues to the "prefill" row; only
                 # the scope map knows the chunk program by its own name
                 pred._roofline_register("prefill_chunk", pred._chunk_fn,
@@ -3134,16 +3297,21 @@ class DecodeServer:
                     caches, probs, tok, *moe = pred._chunk_fn(*args)
                 if moe:
                     ps["moe_unread"].append(("chunk", moe[0]))
+                self._m_ssm_chunk_tokens.inc(int(n) * self._ssm_nodes)
                 ps["state"] = state = DecodeState(caches, state.lens,
                                                   state.tok)
                 p["pos"] += n
                 pred._chunk_widths.add(self._chunk_w)
             if p["pos"] >= p["prompt"].size:
-                # --- (3) commit: the slot joins the batch
+                # --- (3) commit: the slot joins the batch.  The splice
+                # takes the first token where it is, on the device; the
+                # host reads it (`settle_first`) once the decode step is
+                # queued behind the chunk, so the device goes from one to
+                # the other without waiting for the host.  A proposer
+                # drafts from the histories, so with one it is read here
                 with _obs.span("serve.commit", cat="serve",
                                args={"rid": p["rid"]}):
                     slot, plen = p["slot"], p["prompt"].size
-                    first = int(np.asarray(tok)[0, 0])
                     lens2, tok2 = pred._commit_fn(
                         state.lens, state.tok, np.int32(slot),
                         jnp.asarray([plen], jnp.int32), tok)
@@ -3155,15 +3323,17 @@ class DecodeServer:
                         proposer.admit(
                             _pad_window(p["prompt"], self._max_prefill),
                             plen, slot, slots, sub)
-                    active[slot] = {"rid": p["rid"], "toks": [first],
+                    active[slot] = {"rid": p["rid"], "toks": [],
                                     "cap": p["cap"], "prio": p["prio"],
                                     "prompt": p["prompt"]}
-                    histories[slot] = list(p["prompt"]) + [first]
+                    histories[slot] = list(p["prompt"])
                     slot_lens[slot] = plen
                     act_mask[slot] = 1
-                    self._req[p["rid"]]["first"] = time.time()
                     ps["pending"] = None
-                    retire()        # a first-token EOS / cap-1 request
+                    unsettled.append((slot, tok))
+                    if proposer is not None:
+                        settle_first()
+                        retire()    # a first-token EOS / cap-1 request
         self._note_gauges()
         if not active:
             return
@@ -3202,15 +3372,21 @@ class DecodeServer:
             note = {}       # filled below, read as the span closes
             with _obs.span("serve.readback", cat="serve", args=note):
                 self._note_attn_blocks(slot_lens, act_mask, note)
+                if unsettled:
+                    settle_first()
                 unread = ps["moe_unread"]
                 if state.moe is not None:
                     unread.append(("decode", state.moe))
-                if unread:
-                    # the MoE row counts come in the same transfer as the
-                    # tokens: one wait, not one an array
-                    toks, *rows = jax.device_get(
-                        [state.tok] + [vec for _, vec in unread])
+                beside = [vec for _, vec in unread] \
+                    + ([state.ssm] if state.ssm is not None else [])
+                if beside:
+                    # what the step counted comes in the same transfer as
+                    # the tokens: one wait, not one an array
+                    toks, *rows = jax.device_get([state.tok] + beside)
                     toks = toks[:, 0]
+                    if state.ssm is not None:
+                        note["ssm_rows"] = int(rows.pop())
+                        self._m_ssm_rows.inc(note["ssm_rows"])
                     self._note_moe([(program, vec) for (program, _), vec
                                     in zip(unread, rows)], note)
                     del unread[:]

@@ -22,9 +22,27 @@ model of the family is a configuration file and no code:
   selection-only bias (``topk_method`` ``noaux_tc``);
 * ``num_held`` / ``first_held``: the experts this chip holds of every MoE
   layer (expert parallelism's share; 0 = all): the router keeps all its
-  outputs, the layer adds its own experts' part.
+  outputs, the layer adds its own experts' part;
+* ``mamba_d_ssm`` > 0: every block is parallel.  A state-space mixer
+  (``ops.ssm``: ``mamba_n_heads`` heads of ``mamba_d_head`` channels, state
+  ``mamba_d_state``, ``mamba_n_groups`` groups of B and C, a convolution of
+  ``mamba_d_conv``, the chunked scan at ``mamba_chunk_size``, its state kept
+  in ``ssm_state_dtype``) and the attention read one normed input and are
+  summed before the residual.  Its two projections are ``FullyConnected``
+  nodes (``mamba_proj_bias``) and only what lies between them is the op;
+* the muP multipliers, each a scalar multiply in the graph and none where
+  it is 1: ``embedding_multiplier`` on the embedding rows,
+  ``lm_head_multiplier`` on the logits, ``attention_in_multiplier`` /
+  ``attention_out_multiplier`` and ``ssm_in_multiplier`` /
+  ``ssm_out_multiplier`` around the two mixers, ``ssm_multipliers`` on the
+  segments z, x, B, C, dt of the mixer's projected stream,
+  ``mlp_multipliers`` on the MLP's gate and on its output, and
+  ``key_multiplier`` on the keys (folded into the attention node's
+  ``scale``, where it is the same product).
 
-The first ``num_layers`` entries of the two per-layer lists are built.
+``hybrid_layer_pattern`` and ``moe_layer_freq`` default to zeros: full
+attention and a dense MLP in every layer.  The first ``num_layers`` entries
+of the two per-layer lists are built.
 """
 from __future__ import annotations
 
@@ -45,37 +63,76 @@ def rotary_dims(head_dim, partial_rotary_factor):
     return int(head_dim * float(partial_rotary_factor)) // 2 * 2
 
 
+def times(x, multiplier):
+    """``x * multiplier`` as a graph op; ``x`` itself where it is 1."""
+    return x if float(multiplier) == 1.0 else x * float(multiplier)
+
+
 def attention(data, name, window, hidden, heads, kv_heads, head_dim,
-              v_head_dim, rotary_dim, theta, value_scale, sink):
+              v_head_dim, rotary_dim, theta, value_scale, sink,
+              key_multiplier=1.0):
     q = sym.FullyConnected(data, num_hidden=heads * head_dim, no_bias=True,
                            flatten=False, name=name + "_q")
     k = sym.FullyConnected(data, num_hidden=kv_heads * head_dim,
                            no_bias=True, flatten=False, name=name + "_k")
     v = sym.FullyConnected(data, num_hidden=kv_heads * v_head_dim,
                            no_bias=True, flatten=False, name=name + "_v")
+    # keys x key_multiplier: the same logits as a scale of multiplier /
+    # sqrt(head_dim), and the cached keys keep their own range
+    scaled = {} if float(key_multiplier) == 1.0 else {
+        "scale": float(key_multiplier) / float(head_dim) ** 0.5}
     with AttrScope(**(_WINDOW if window else {})):
         att = sym.dot_product_attention(
             q, k, v, num_heads=heads, num_kv_heads=kv_heads, causal=True,
             window=window, sink=sink, rotary_dim=rotary_dim,
-            rope_theta=theta, value_scale=value_scale, name=name + "_att")
+            rope_theta=theta, value_scale=value_scale, name=name + "_att",
+            **scaled)
     return sym.FullyConnected(att, num_hidden=hidden, no_bias=True,
                               flatten=False, name=name + "_attout")
 
 
-def gated_mlp(data, name, hidden, width):
+def gated_mlp(data, name, hidden, width, multipliers=(1.0, 1.0)):
     gate = sym.FullyConnected(data, num_hidden=width, no_bias=True,
                               flatten=False, name=name + "_ffn_gate")
     up = sym.FullyConnected(data, num_hidden=width, no_bias=True,
                             flatten=False, name=name + "_ffn_up")
     with AttrScope(**_MLP):
-        h = sym.Activation(gate, act_type="silu") * up
-    return sym.FullyConnected(h, num_hidden=hidden, no_bias=True,
-                              flatten=False, name=name + "_ffn_down")
+        h = sym.Activation(times(gate, multipliers[0]),
+                           act_type="silu") * up
+    return times(sym.FullyConnected(h, num_hidden=hidden, no_bias=True,
+                                    flatten=False, name=name + "_ffn_down"),
+                 multipliers[1])
+
+
+def ssm_mixer(data, name, hidden, heads, head_dim, state, groups, conv,
+              chunk, eps, proj_bias, state_dtype, multipliers):
+    """The state-space mixer: ``hidden`` -> [z | x | B | C | dt] ->
+    ``ops.ssm`` -> ``hidden``; ``multipliers`` scale the five segments."""
+    d_ssm, bc = heads * head_dim, groups * state
+    widths = (d_ssm, d_ssm, bc, bc, heads)
+    proj = sym.FullyConnected(data, num_hidden=sum(widths),
+                              no_bias=not proj_bias, flatten=False,
+                              name=name + "_ssm_in")
+    if any(float(m) != 1.0 for m in multipliers):
+        parts, at = [], 0
+        for width, m in zip(widths, multipliers):
+            parts.append(times(sym.slice_axis(proj, axis=2, begin=at,
+                                              end=at + width), m))
+            at += width
+        proj = sym.Concat(*parts, dim=2)
+    mixed = sym.SelectiveSSM(
+        proj, num_heads=heads, head_dim=head_dim, state_size=state,
+        n_groups=groups, conv_kernel=conv, chunk_size=chunk, eps=eps,
+        state_dtype=state_dtype,
+        name=name + "_ssm")
+    return sym.FullyConnected(mixed, num_hidden=hidden,
+                              no_bias=not proj_bias, flatten=False,
+                              name=name + "_ssm_out")
 
 
 def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
-               head_dim, hybrid_layer_pattern, moe_layer_freq,
-               intermediate_size, num_key_value_heads=0, v_head_dim=0,
+               head_dim, hybrid_layer_pattern=None, moe_layer_freq=None,
+               intermediate_size=0, num_key_value_heads=0, v_head_dim=0,
                swa_num_key_value_heads=0, sliding_window=0,
                partial_rotary_factor=1.0, rope_theta=10000.0,
                swa_rope_theta=0.0, attention_value_scale=1.0,
@@ -84,23 +141,51 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                moe_intermediate_size=0, n_routed_experts=0,
                num_experts_per_tok=1, scoring_func="softmax",
                norm_topk_prob=True, topk_method="greedy", num_held=0,
-               first_held=0, **kwargs):
+               first_held=0, mamba_d_ssm=0, mamba_n_heads=0, mamba_d_head=0,
+               mamba_d_state=0, mamba_n_groups=1, mamba_d_conv=4,
+               mamba_chunk_size=128, mamba_proj_bias=False,
+               mamba_conv_bias=True, ssm_state_dtype="float32",
+               embedding_multiplier=1.0, lm_head_multiplier=1.0,
+               attention_in_multiplier=1.0, attention_out_multiplier=1.0,
+               key_multiplier=1.0, ssm_in_multiplier=1.0,
+               ssm_out_multiplier=1.0, ssm_multipliers=(1.0,) * 5,
+               mlp_multipliers=(1.0, 1.0), **kwargs):
     """data (B, T) int tokens -> softmax over the vocabulary at every
     position (``softmax_label`` (B, T) next tokens, pad = -1 ignored)."""
     heads = int(num_attention_heads)
     v_head_dim = int(v_head_dim) or int(head_dim)
     rotary = rotary_dims(head_dim, partial_rotary_factor)
+    zeros = (0,) * int(num_layers)
+    hybrid_layer_pattern = hybrid_layer_pattern or zeros
+    moe_layer_freq = moe_layer_freq or zeros
+    if mamba_d_ssm and int(mamba_d_ssm) != int(mamba_n_heads) \
+            * int(mamba_d_head):
+        raise ValueError("mamba_d_ssm %d != mamba_n_heads %d x mamba_d_head "
+                         "%d" % (mamba_d_ssm, mamba_n_heads, mamba_d_head))
+    if mamba_d_ssm and not mamba_conv_bias:
+        raise ValueError("mamba_conv_bias false: the mixer's convolution is "
+                         "built with its bias (ops.ssm)")
     data = sym.Variable("data")
     label = sym.Variable("softmax_label")
-    net = sym.Embedding(data, input_dim=vocab_size, output_dim=hidden_size,
-                        name="embed")
+    net = times(sym.Embedding(data, input_dim=vocab_size,
+                              output_dim=hidden_size, name="embed"),
+                embedding_multiplier)
     for i in range(int(num_layers)):
         name = "layer%d" % i
         windowed = bool(hybrid_layer_pattern[i])
         normed = sym.RMSNorm(net, eps=layernorm_epsilon,
                              name=name + "_att_norm")
-        net = net + attention(
-            normed, name,
+        if mamba_d_ssm:
+            # the parallel block: both mixers read the one normed input
+            net = net + times(ssm_mixer(
+                times(normed, ssm_in_multiplier), name, hidden_size,
+                int(mamba_n_heads), int(mamba_d_head), int(mamba_d_state),
+                int(mamba_n_groups), int(mamba_d_conv),
+                int(mamba_chunk_size), layernorm_epsilon,
+                bool(mamba_proj_bias), ssm_state_dtype, ssm_multipliers),
+                ssm_out_multiplier)
+        net = net + times(attention(
+            times(normed, attention_in_multiplier), name,
             window=int(sliding_window) if windowed else 0,
             hidden=hidden_size, heads=heads,
             kv_heads=int((swa_num_key_value_heads if windowed
@@ -111,7 +196,8 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                         or rope_theta),
             value_scale=float(attention_value_scale),
             sink=bool(add_swa_attention_sink_bias if windowed
-                      else add_full_attention_sink_bias))
+                      else add_full_attention_sink_bias),
+            key_multiplier=key_multiplier), attention_out_multiplier)
         normed = sym.RMSNorm(net, eps=layernorm_epsilon,
                              name=name + "_ffn_norm")
         if moe_layer_freq[i]:
@@ -125,13 +211,13 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                 first_held=int(first_held), name=name + "_moe")
         else:
             ffn = gated_mlp(normed, name, hidden_size,
-                            int(intermediate_size))
+                            int(intermediate_size), mlp_multipliers)
         net = net + ffn
     net = sym.RMSNorm(net, eps=layernorm_epsilon, name="final_norm")
     with AttrScope(**_HEAD):
-        logits = sym.FullyConnected(
+        logits = times(sym.FullyConnected(
             sym.Reshape(net, shape=(-1, hidden_size)), num_hidden=vocab_size,
-            no_bias=True, name="head")
+            no_bias=True, name="head"), lm_head_multiplier)
         flat_label = sym.Reshape(label, shape=(-1,))
         return sym.SoftmaxOutput(logits, flat_label, use_ignore=True,
                                  ignore_label=-1, name="softmax")

@@ -35,6 +35,7 @@ LAYER_OF_OP = {
     "SoftmaxOutput": "head_loss",
     "MoEFFN": "moe",
     "RMSNorm": "norm",
+    "SelectiveSSM": "ssm",
 }
 # every value a scope's <layer> may take: the table's, "other" for op
 # kinds it does not list, and the two fixed scopes of the train step
@@ -44,7 +45,11 @@ LAYER_OF_OP = {
 LAYERS = tuple(sorted(set(LAYER_OF_OP.values()))) + (
     "attn_window", "other", "optimizer", "metric")
 SUBSCOPES = ("kv_append", "kv_gather", "kv_dequant", "scores", "rope",
-             "route", "experts", "combine")
+             "route", "experts", "combine",
+             # the state-space mixer: its convolution, the recurrence over a
+             # chunk or a sequence ("scan") and over one token a slot
+             # ("step"), the gate with its grouped norm
+             "conv", "scan", "step", "gate_norm")
 # an instruction no mx.<layer> scope reaches (compiler-made copies,
 # casts between the step's phases)
 UNSCOPED = "unscoped"

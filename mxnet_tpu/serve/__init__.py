@@ -44,11 +44,12 @@ from __future__ import annotations
 
 from .allocator import PageAllocator
 from .prefix_cache import PrefixCache, chain_hash
-from .manager import CacheGroup, GroupedKVManager, PagedKVManager
+from .manager import (CacheGroup, GroupedKVManager, PagedKVManager,
+                      StateRows)
 from .swap import SwapStore, SwappedRequest
 
 __all__ = ["PageAllocator", "PrefixCache", "PagedKVManager",
-           "GroupedKVManager", "CacheGroup",
+           "GroupedKVManager", "CacheGroup", "StateRows",
            "SwapStore", "SwappedRequest", "chain_hash"]
 
 

@@ -36,7 +36,38 @@ from ..base import MXNetError
 from .allocator import PageAllocator
 from .prefix_cache import PrefixCache
 
-__all__ = ["PagedKVManager", "GroupedKVManager", "CacheGroup"]
+__all__ = ["PagedKVManager", "GroupedKVManager", "CacheGroup", "StateRows",
+           "WHY_NOT", "why_not"]
+
+# what a cache group that does not hold every position of the context cannot
+# carry, and why, by the group's kind: the text of every refusal
+WHY_NOT = {
+    "window": {
+        "prefix": "a matched prefix's ring content is gone once the donor "
+                  "has moved on",
+        "speculation": "a rejected draft's keys would sit in ring positions "
+                       "the length mask cannot hide",
+        "restore": "a ring keeps its last positions only, and its pages are "
+                   "not a request's whole context to extract or install",
+    },
+    "state": {
+        "prefix": "a matched prefix has pages and no recurrent state: the "
+                  "state after the prefix would have to be computed again",
+        "speculation": "a rejected draft has already advanced the recurrent "
+                       "state, and a state has no positions to mask",
+        "restore": "a state row is not in pages: swap, extract and install "
+                   "move pages only",
+    },
+}
+
+
+def why_not(what, groups):
+    """``(kind, reason)`` of the first of ``groups`` that cannot carry
+    ``what`` ("prefix" | "speculation" | "restore"); None where all can."""
+    for g in groups:
+        if g.kind in WHY_NOT:
+            return g.kind, WHY_NOT[g.kind][what]
+    return None
 
 
 def _pages_for(tokens, page_tokens):
@@ -195,6 +226,10 @@ class PagedKVManager:
         """Mapped pages of ``slot`` (swap accounting)."""
         return int(np.count_nonzero(self.tables[slot]))
 
+    def pages_for(self, total):
+        """Pages a request of ``total`` positions holds at its longest."""
+        return _pages_for(min(int(total), self.capacity), self.page_tokens)
+
     def ensure(self, slot, lo, hi):
         """Make positions [lo, hi) of ``slot`` writable.
 
@@ -305,12 +340,55 @@ class PagedKVManager:
         return out
 
 
+class StateRows:
+    """The "state" cache group's bookkeeping: one fixed row a slot (slot
+    ``s`` holds row ``s``), never short, nothing to page.  It answers what
+    :class:`GroupedKVManager` asks of a group; its "table" is the row's
+    index, constant, so the programs find a slot's row as they find its
+    pages.  The allocator only counts: rows in use are what the gauges and
+    :meth:`stats` show."""
+
+    kind = "state"
+    capacity = 0
+
+    def __init__(self, slots, name=None):
+        self.name = name or self.kind
+        self.slots = self.pool_pages = int(slots)
+        self.allocator = PageAllocator(self.slots + 1)
+        self.tables = np.arange(self.slots, dtype=np.int32).reshape(-1, 1)
+        self.version = 0
+        self._held = {}
+
+    def pages_for(self, total):
+        return 1
+
+    def map_slot(self, slot, pages, reserve_n):
+        assert slot not in self._held, "mapping into a held row %d" % slot
+        self._held[slot] = self.allocator.alloc(from_reserve=True)
+
+    def ensure(self, slot, lo, hi):
+        return []
+
+    def free_slot(self, slot):
+        if slot in self._held:
+            self.allocator.decref(self._held.pop(slot))
+
+    def slot_page_count(self, slot):
+        return 0
+
+    def stats(self):
+        a = self.allocator
+        return {"rows": self.slots, "used_rows": a.used_pages,
+                "peak_used_rows": a.peak_used}
+
+
 class CacheGroup:
-    """The attention nodes of one graph that share a cache layout: their
+    """The stateful nodes of one graph that share a cache layout: their
     ``kind`` ("full": every position of the context; "window": a ring of
-    the last ``capacity`` positions), what a slot holds of each
-    (``capacity`` positions) and which nodes they are (``nodes``: indices
-    into the graph's attention nodes, in topological order).  ``name`` is
+    the last ``capacity`` positions; "state": one recurrent state a slot,
+    ``capacity`` 0), what a slot holds of each (``capacity`` positions) and
+    which nodes they are (``nodes``: indices into the graph's stateful
+    nodes, in topological order).  ``name`` is
     what statistics and gauges are keyed by: the kind, with the capacity
     where two groups of a graph share a kind."""
 
@@ -326,10 +404,10 @@ class CacheGroup:
 
 class GroupedKVManager:
     """The manager of a graph with more than one cache group: one
-    :class:`PagedKVManager` a group, each with its own page count, its
-    own per-slot tables and its own allocator, gated together — a request
-    is admitted when every group can reserve its worst case, and retires
-    from all of them at once.
+    :class:`PagedKVManager` a paged group, each with its own page count,
+    its own per-slot tables and its own allocator, and :class:`StateRows`
+    for a "state" group, gated together — a request is admitted when every
+    group can reserve its worst case, and retires from all of them at once.
 
     A window group's table ring-mods over its few pages (``PagedKVManager``
     at the group's ``capacity``), so its nodes hold ``capacity`` positions
@@ -338,7 +416,8 @@ class GroupedKVManager:
     read stale: there is no prefix cache (a matched prefix's ring content
     is gone once the donor has moved on), hence no copy-on-write fork; and
     a slot's pages cannot be extracted or restored (:meth:`gate_pages`,
-    :meth:`restore_slot` raise)."""
+    :meth:`restore_slot` raise).  A state group refuses the same three, for
+    its own reasons (:data:`WHY_NOT`)."""
 
     prefix_cache = None
 
@@ -348,6 +427,7 @@ class GroupedKVManager:
         # an explicit pool size sizes the first group's pool (the groups
         # are ordered widest first); a ring group is always whole
         self.groups = [
+            StateRows(slots, name=g.name) if g.kind == "state" else
             PagedKVManager(slots, g.capacity, page_tokens,
                            pool_pages=pool_pages if i == 0 else 0,
                            prefix_cache=False, kind=g.kind, name=g.name)
@@ -364,13 +444,15 @@ class GroupedKVManager:
 
     @property
     def pool_pages(self):
-        return sum(g.pool_pages for g in self.groups)
+        return sum(g.pool_pages for g in self.groups
+                   if g.kind != "state")
 
-    def _refuse(self, what):
+    def _refuse_restore(self):
         raise MXNetError(
-            "%s is not supported on a graph with cache groups %s: a "
-            "'window' group keeps a ring of its last positions only"
-            % (what, [g.name for g in self.groups]))
+            "restoring a swapped or migrated request is not supported on a "
+            "graph with cache groups %s: a %r group cannot carry it: %s"
+            % (([g.name for g in self.groups],)
+               + why_not("restore", self.groups)))
 
     def gate(self, prompt, prompt_len, max_new, spec_k=0,
              budget_wrap_forks=True):
@@ -378,8 +460,7 @@ class GroupedKVManager:
         ``(0, [], needs)`` with ``needs`` the pages reserved a group, or
         ``None`` on backpressure."""
         total = int(prompt_len) + int(max_new) + int(spec_k) + 1
-        needs = [_pages_for(min(total, g.capacity), self.page_tokens)
-                 for g in self.groups]
+        needs = [g.pages_for(total) for g in self.groups]
         for i, (g, n) in enumerate(zip(self.groups, needs)):
             if not g.allocator.reserve(n):
                 for h, m in zip(self.groups[:i], needs[:i]):
@@ -405,10 +486,10 @@ class GroupedKVManager:
             g.free_slot(slot)
 
     def gate_pages(self, need):
-        self._refuse("restoring a swapped or migrated request")
+        self._refuse_restore()
 
     def restore_slot(self, slot, valid, reserve_n):
-        self._refuse("restoring a swapped or migrated request")
+        self._refuse_restore()
 
     def slot_page_count(self, slot):
         return sum(g.slot_page_count(slot) for g in self.groups)
@@ -417,4 +498,6 @@ class GroupedKVManager:
         out = self.groups[0].stats()
         out["pool_pages"] = self.pool_pages
         out["groups"] = {g.name: g.stats() for g in self.groups}
+        out["prefix_cache_off"] = "%r group: %s" % why_not("prefix",
+                                                           self.groups)
         return out

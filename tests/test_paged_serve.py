@@ -324,6 +324,40 @@ def test_eos_mid_window_frees_pages_immediately():
     assert pred._manager.allocator.used_pages == 0
 
 
+@pytest.mark.parametrize("ends", ["eos", "cap"])
+def test_request_that_ends_at_its_first_token(ends):
+    """Without a proposer the host reads a committed slot's first token
+    after the decode step is queued behind the chunk: a request that ends
+    at that token (EOS, or a cap of one) still gets it alone, its pages go
+    back at once, and its neighbours get what ``generate`` gives them."""
+    sym, params = _lm_and_params()
+    rng = np.random.RandomState(11)
+    pred = DecodePredictor(sym, params, cache_len=16, paged=True,
+                           page_tokens=4, prefix_cache=False)
+    ref_pred = DecodePredictor(sym, params, cache_len=16)
+    prompts = [rng.randint(0, VOCAB, (n,)) for n in (6, 5, 7)]
+    refs = [ref_pred.generate(q[None].astype(np.float32), len(q),
+                              max_new_tokens=5)[0] for q in prompts]
+    short = 1
+    eos = int(refs[short][0]) if ends == "eos" else None
+    srv = DecodeServer(pred, max_prefill=8, slots=2, eos_id=eos,
+                       max_new_tokens=5, spec_k=0)
+    ids = [srv.submit(q, max_new_tokens=1 if ends == "cap" and i == short
+                      else 5) for i, q in enumerate(prompts)]
+    res = srv.run()
+    for i, rid in enumerate(ids):
+        want = refs[i]
+        if eos is not None and eos in want:
+            want = want[:int(np.flatnonzero(want == eos)[0]) + 1]
+        if ends == "cap" and i == short:
+            want = want[:1]
+        np.testing.assert_array_equal(res[rid], want)
+    assert len(res[ids[short]]) == 1
+    assert pred._manager.allocator.used_pages == 0
+    stats = srv.stats()
+    assert stats["requests_completed"] == 3 and stats["ttft_p95_s"] > 0
+
+
 def test_allocator_and_prefix_cache_units():
     """Unit coverage of the host-side bookkeeping: refcounts, reservation
     accounting, LRU eviction, partial-page matching, release_page."""
