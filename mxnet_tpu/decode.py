@@ -423,6 +423,10 @@ class DecodePredictor:
                 "page_install", jax.jit(
                     self._install_impl,
                     donate_argnums=(0,) if self._donate else ()))
+            # a step's tokens for the host, which reads them after the
+            # next step is queued: that step's donation consumes `state.tok`
+            # and leaves this copy alone
+            self._keep_fn = jax.jit(lambda tok: tok + 0)
             self._manager = None          # serve.PagedKVManager, per batch
             self._pools_template = None   # per-node cache avals (probed)
             self._paged_lens = None       # host mirror for standalone use
@@ -547,10 +551,13 @@ class DecodePredictor:
 
     def _tables_of(self, mgr):
         """The manager's page tables as the programs take them: one
-        (B, M) array, or one a group where the graph has several."""
+        (B, M) array, or one a group where the graph has several.  Copies:
+        the manager writes its tables in place (a retirement zeroes a row)
+        while the step they were shipped with may still be queued, and an
+        array made from a host buffer may go on reading that buffer."""
         import jax.numpy as jnp
 
-        return _per_group(jnp.asarray(g.tables) for g in mgr.groups)
+        return _per_group(jnp.asarray(g.tables.copy()) for g in mgr.groups)
 
     def _chunk_operands(self, slot, tokens, pos, width):
         """The chunk program's small operands for ``tokens`` of ``slot``
@@ -2412,6 +2419,16 @@ class DecodeServer:
             "mx_spec_proposed", "drafted tokens offered to verify")
         self._m_accepted = _obs.registry.counter(
             "mx_spec_accepted", "drafted tokens accepted by the target")
+        self._m_ticks = _obs.registry.counter(
+            "mx_serve_ticks_total",
+            "serve_tick calls: behind, the tick queued its programs before "
+            "it read the previous tick's tokens; first, nothing was unread "
+            "when it queued them (a first tick, a proposer, a drain)",
+            labels=("read",))
+        self._m_dropped = _obs.registry.counter(
+            "mx_serve_dropped_rows_total",
+            "rows of a decode step whose token was dropped: the step was "
+            "queued before the host had read the slot's EOS")
         self._m_moe_rows = _obs.registry.counter(
             "mx_moe_rows_total",
             "(token, chosen expert) pairs routed by the gated MoE layers: "
@@ -2614,6 +2631,7 @@ class DecodeServer:
                 "inject: restoring a swapped or migrated request is not "
                 "supported on a graph with a %r cache group: %s"
                 % why_not("restore", self._unshared))
+        self._settle()      # a restore splices into a batch that is read
         rid = self._next_id
         self._next_id += 1
         entry = {"rid": rid, "prompt": record.prompt, "cap": record.cap,
@@ -2657,7 +2675,7 @@ class DecodeServer:
         dense and paged loops — ONE copy of the retirement rule)."""
         toks, max_new = rec["toks"], rec["cap"]
         if self._eos_id is not None and toks and toks[-1] == self._eos_id:
-            # ended at its first token, read after this step was queued
+            # ended at its EOS, which was read after this step was queued
             return
         for t in emitted:
             if len(toks) >= max_new:
@@ -2673,17 +2691,34 @@ class DecodeServer:
         paged loop frees the slot's pages here, immediately)."""
         for slot in list(active):
             rec = active[slot]
-            rid, toks = rec["rid"], rec["toks"]
-            if (self._eos_id is not None and toks
-                    and toks[-1] == self._eos_id) \
-                    or len(toks) >= rec["cap"]:
-                results[rid] = np.asarray(toks, np.int32)
-                self.tokens_out += len(toks)
-                self._m_tokens.inc(len(toks))
-                self._finish(rid, len(toks))
+            if self._ended(rec):
+                self._close(rec, results)
                 del active[slot]
                 if on_retire is not None:
                     on_retire(slot)
+
+    def _ended(self, rec):
+        """Whether the tokens a request HOLDS end it: an EOS delivered, or
+        its cap reached."""
+        toks = rec["toks"]
+        return (self._eos_id is not None and bool(toks)
+                and toks[-1] == self._eos_id) or len(toks) >= rec["cap"]
+
+    def _count_out(self, rec):
+        """Book a request's tokens as out of the slots: all it holds when it
+        leaves its slot, the rest when its last token has been read, so that
+        ``tokens_out`` plus what the slots hold is every token delivered."""
+        n = len(rec["toks"]) - rec.get("counted", 0)
+        rec["counted"] = len(rec["toks"])
+        self.tokens_out += n
+        self._m_tokens.inc(n)
+
+    def _close(self, rec, results):
+        """Record a finished request's result and close its SLO record."""
+        rec["closed"] = True
+        results[rec["rid"]] = np.asarray(rec["toks"], np.int32)
+        self._count_out(rec)
+        self._finish(rec["rid"], len(rec["toks"]))
 
     def stats(self):
         """Serving-side SLO snapshot: loop counters, per-request
@@ -2691,6 +2726,7 @@ class DecodeServer:
         mode — pool utilization and prefix-cache hit accounting."""
         from .profiler import _percentile
 
+        self._settle()
         done = [r for r in self._req.values() if "retire" in r]
         out = {"steps": self.steps, "spec_steps": self.spec_steps,
                "tokens_out": self.tokens_out,
@@ -2907,7 +2943,8 @@ class DecodeServer:
             "pending": None,    # the one admission mid-chunked-prefill
             "blocked": 0,       # consecutive pool-gate-blocked ticks
             "tick": 0,          # serve_tick calls (the serve.tick span's arg)
-            "moe_unread": [],   # (program, device row counts) not yet read
+            "unread": None,     # what the last tick queued and nobody has
+                                # read yet (_tick's `cur`); _settle reads it
         }
         for g in pred._manager.groups:      # a pool's size: once a session
             self._m_pages_total.labels(group=g.name).set(g.pool_pages)
@@ -2921,7 +2958,10 @@ class DecodeServer:
         fresh memory manager.  The predictor's manager is dropped NOW,
         not at reopen: a fleet router polls :meth:`serve_summary`
         before the first tick, and scoring prompts against the previous
-        session's ghost chains would mis-route the whole first burst."""
+        session's ghost chains would mis-route the whole first burst.  A
+        step still unread is read and delivered first: a request whose last
+        token it holds is closed, not lost."""
+        self._settle()
         self._ps = None
         if getattr(self._pred, "_paged", False):
             self._pred._manager = None
@@ -2929,17 +2969,21 @@ class DecodeServer:
     @property
     def has_work(self):
         """Whether the paged session still has queued, mid-prefill or
-        decoding requests."""
+        decoding requests, or tokens on the device that no tick has read."""
         if self._ps is None:
             return bool(self._queue)
         ps = self._ps
-        return bool(self._queue or ps["active"] or ps["pending"])
+        return bool(self._queue or ps["active"] or ps["pending"]
+                    or ps["unread"])
 
     def serve_results(self, clear=True):
         """``{rid: np.int32 tokens}`` finished since the session opened
-        (or since the last ``clear``)."""
+        (or since the last ``clear``).  Reads first what the last tick left
+        unread, so the answer is that of a loop that reads every tick at
+        its end."""
         if self._ps is None:
             return {}
+        self._settle()
         out = dict(self._ps["results"])
         if clear:
             self._ps["results"].clear()
@@ -3032,6 +3076,7 @@ class DecodeServer:
         need = rec.n_pages + max(target - rec.n_pages, 0) + fork
         if not mgr.gate_pages(need):
             return None
+        self._settle()      # the splice below joins a batch that is read
         self._queue.popleft()
         slot = next(s for s in range(self._slots)
                     if s not in ps["active"])
@@ -3070,10 +3115,11 @@ class DecodeServer:
             req["first"] = rec.first_ts
         else:
             req["first"] = req["admit"]
+        ps["histories"][slot] = hist = list(rec.history)
         ps["active"][slot] = {"rid": rid, "toks": list(rec.delivered),
                               "cap": rec.cap, "prio": rec.priority,
-                              "prompt": rec.prompt}
-        ps["histories"][slot] = list(rec.history)
+                              "prompt": rec.prompt, "hist": hist,
+                              "unread": 0}
         ps["slot_lens"][slot] = rec.lens
         ps["act_mask"][slot] = 1
         if rec.kind == "swap":
@@ -3153,6 +3199,15 @@ class DecodeServer:
         if active[victim]["prio"] >= self._queue[0]["prio"] \
                 and not bound_hit:
             return None
+        if ps["unread"] is not None:
+            # a swap-out takes the victim's tokens with it: read them first
+            # (the choice above needed none).  An EOS among them frees a
+            # slot and its pages, and then the waiter may need nobody's
+            n = len(active)
+            self._settle()
+            if len(active) < n:
+                # (1a) leaves the freed slot to the gate that follows it
+                return self._admit_one(ps) if allow_bound else None
         self._swap_out(ps, victim)
         got = self._admit_one(ps)
         # one swap per bound window: the counter restarts even when the
@@ -3175,27 +3230,49 @@ class DecodeServer:
         mapped at the gate, only the tail computes), so a long prompt
         interleaves with decode instead of stalling the batch; (3) on
         the final chunk, splice the first token/length into the batch
-        state, publish the prompt's pages to the prefix cache and
-        activate the slot (the host reads that token under
-        ``serve.readback``, after step (5) is queued behind the chunk;
-        with a proposer, whose drafts need it, at the commit); (4) retire finished requests — freeing their
-        pages IMMEDIATELY, EOS-mid-speculation-window included; (5) run
-        one decode (or speculative verify) step over the active slots,
-        inactive rows masked.  Every device program here was traced
-        once — page tables, active masks, slot indices, page ids and
-        swapped page contents are all data.
+        state on the device, publish the prompt's pages to the prefix
+        cache and activate the slot; (4) queue THIS tick's decode (or
+        speculative verify) step over the active slots, inactive rows
+        masked; (5) read, deliver and settle what the PREVIOUS tick left
+        on the device — its step's tokens, the first token of a slot it
+        committed and what its programs counted, one transfer — and return.
+        The device has step n behind step n - 1 before the host waits for
+        n - 1, so the host's part of a tick runs beside the device's.
+
+        What lags, and what does not.  A token of step n reaches its
+        request during tick n + 1 (or at the next :meth:`serve_results`,
+        :meth:`stats`, :meth:`serve_reset`, :meth:`inject`, which read
+        first).  A request that ends by its CAP ends at a count the host
+        holds: it leaves its slot as its last step is queued, its pages and
+        slot are free for the next tick's admission, and its record closes
+        when that token has been read.  A request that ends by ``eos_id``
+        is seen one step late: the slot rides the next step, that row is
+        dropped (``mx_serve_dropped_rows_total``) and the slot retires at
+        the read, with the tokens it would have had; where the dropped
+        row's position would need a page beyond the slot's reservation the
+        tick reads first.  What needs the tokens reads first, chosen from
+        what the loop sees and not from an option: with a proposer (drafts
+        come from the histories) a tick reads its own step at its end, tick
+        for tick the order before; a swap-out and a restore read before
+        they touch the batch.  ``mx_serve_ticks_total{read=}`` counts the
+        ticks that queued their programs ``behind`` an unread step and
+        those that had nothing unread (``first``); the ``serve.tick``
+        span's arguments carry the same word.  Every device program here
+        was traced once — page tables, active masks, slot indices, page
+        ids and swapped page contents are all data.
 
         Host phases land on the timeline as one ``serve.tick`` span with
         the children ``serve.admit`` / ``serve.prefill`` /
         ``serve.commit`` / ``serve.decode_dispatch`` / ``serve.readback``
-        (the wait for the device) / ``serve.deliver``
+        (the wait for the previous tick's step) / ``serve.deliver``
         (docs/observability.md); a request's spans share its ``rid``.
         """
         ps = self.serve_open()
         ps["tick"] += 1
-        with _obs.span("serve.tick", cat="serve",
-                       args={"tick": ps["tick"]}):
-            self._tick(ps)
+        args = {"tick": ps["tick"]}
+        with _obs.span("serve.tick", cat="serve", args=args):
+            args["read"] = self._tick(ps)
+        self._m_ticks.labels(read=args["read"]).inc()
 
     def _tick(self, ps):
         import jax
@@ -3226,17 +3303,26 @@ class DecodeServer:
         def retire():
             self._retire_finished(active, ps["results"], on_retire)
 
-        deliver = self._deliver
-        unsettled = []      # (slot, device token) of this tick's commit
+        def leave_due():
+            """Retirement by count, taken at dispatch: a request whose
+            delivered and queued tokens reach its cap leaves its slot now
+            (masked out of the next step, pages and slot free for the next
+            admission); :meth:`_settle` closes it at its last token."""
+            for slot, rec in list(active.items()):
+                if len(rec["toks"]) + rec["unread"] >= rec["cap"]:
+                    del active[slot]
+                    on_retire(slot)
+                    self._count_out(rec)
 
-        def settle_first():
-            """Read the committed slot's first token: a wait for the
-            chunk alone, whatever is queued behind it."""
-            slot, tok = unsettled.pop()
-            first = int(np.asarray(tok)[0, 0])
-            active[slot]["toks"].append(first)
-            histories[slot].append(first)
-            self._req[active[slot]["rid"]]["first"] = time.time()
+        deliver = self._deliver
+        # what this tick queues and leaves on the device for the next tick
+        # (or a drain) to read: ps["unread"] once the tick is over
+        cur = {"firsts": [],    # (record, device token) of a commit
+               "moe": [],       # (program, device row counts)
+               "ssm": None,     # device count of state rows stepped
+               "toks": None,    # the step's tokens, a copy not donated on
+               "rows": [],      # (slot, record) the step computed for
+               "note": {}}      # the arguments of its serve.readback span
 
         with _obs.span("serve.admit", cat="serve"):
             # --- (1a) slot-full priority preemption: a waiter that OUTRANKS
@@ -3296,7 +3382,7 @@ class DecodeServer:
                 with _obs.program_span("prefill"):
                     caches, probs, tok, *moe = pred._chunk_fn(*args)
                 if moe:
-                    ps["moe_unread"].append(("chunk", moe[0]))
+                    cur["moe"].append(("chunk", moe[0]))
                 self._m_ssm_chunk_tokens.inc(int(n) * self._ssm_nodes)
                 ps["state"] = state = DecodeState(caches, state.lens,
                                                   state.tok)
@@ -3305,10 +3391,9 @@ class DecodeServer:
             if p["pos"] >= p["prompt"].size:
                 # --- (3) commit: the slot joins the batch.  The splice
                 # takes the first token where it is, on the device; the
-                # host reads it (`settle_first`) once the decode step is
-                # queued behind the chunk, so the device goes from one to
-                # the other without waiting for the host.  A proposer
-                # drafts from the histories, so with one it is read here
+                # host reads it with the tokens of the step that is queued
+                # behind the chunk, one tick on.  A proposer drafts from
+                # the histories, so with one it is read here
                 with _obs.span("serve.commit", cat="serve",
                                args={"rid": p["rid"]}):
                     slot, plen = p["slot"], p["prompt"].size
@@ -3323,26 +3408,40 @@ class DecodeServer:
                         proposer.admit(
                             _pad_window(p["prompt"], self._max_prefill),
                             plen, slot, slots, sub)
-                    active[slot] = {"rid": p["rid"], "toks": [],
-                                    "cap": p["cap"], "prio": p["prio"],
-                                    "prompt": p["prompt"]}
-                    histories[slot] = list(p["prompt"])
+                    histories[slot] = hist = list(p["prompt"])
+                    rec = active[slot] = {
+                        "rid": p["rid"], "toks": [], "cap": p["cap"],
+                        "prio": p["prio"], "prompt": p["prompt"],
+                        "hist": hist,   # histories[slot], while it holds it
+                        "unread": 0}    # tokens queued for it and not read
                     slot_lens[slot] = plen
                     act_mask[slot] = 1
                     ps["pending"] = None
-                    unsettled.append((slot, tok))
                     if proposer is not None:
-                        settle_first()
+                        first = int(np.asarray(tok)[0, 0])
+                        rec["toks"].append(first)
+                        hist.append(first)
+                        self._req[rec["rid"]]["first"] = time.time()
                         retire()    # a first-token EOS / cap-1 request
+                    else:
+                        cur["firsts"].append((rec, tok))
+                        rec["unread"] = 1
+                        leave_due()     # a cap of one: it rides no step
         self._note_gauges()
-        if not active:
-            return
-        # --- (5) one decode / verify step over the active slots
-        sub = next_key()
-        can_spec = proposer is not None and k > 0 \
+        # --- (4) this tick's decode / verify step over the active slots
+        can_spec = bool(active) and proposer is not None and k > 0 \
             and ps["pending"] is None \
             and max(slot_lens[s] for s in active) + k + 1 <= limit
+        if self._eos_id is not None and ps["unread"] is not None \
+                and not all(mgr.within_reserve(
+                    s, int(slot_lens[s]), int(slot_lens[s]) + 1)
+                    for s in active):
+            # a slot whose EOS is still unread would ride this step, and the
+            # page its row needs is beyond what it reserved: find out first,
+            # so that a dropped row can take nobody's page
+            self._settle()
         if can_spec:
+            sub = next_key()
             with _obs.span("serve.decode_dispatch", cat="serve"):
                 hists = [histories.get(s) or [0] for s in range(slots)]
                 draft_toks, draft_probs = proposer.propose(
@@ -3364,41 +3463,90 @@ class DecodeServer:
                     histories[slot].extend(int(t) for t in emitted)
                 slot_lens += counts_h
                 retire()
-        else:
+        elif active:
+            sub = next_key()
             with _obs.span("serve.decode_dispatch", cat="serve"):
                 state, _ = pred.paged_step(ps["state"], slot_lens, sub,
                                            act_mask)
                 ps["state"] = state
-            note = {}       # filled below, read as the span closes
-            with _obs.span("serve.readback", cat="serve", args=note):
-                self._note_attn_blocks(slot_lens, act_mask, note)
-                if unsettled:
-                    settle_first()
-                unread = ps["moe_unread"]
-                if state.moe is not None:
-                    unread.append(("decode", state.moe))
-                beside = [vec for _, vec in unread] \
-                    + ([state.ssm] if state.ssm is not None else [])
-                if beside:
-                    # what the step counted comes in the same transfer as
-                    # the tokens: one wait, not one an array
-                    toks, *rows = jax.device_get([state.tok] + beside)
-                    toks = toks[:, 0]
-                    if state.ssm is not None:
-                        note["ssm_rows"] = int(rows.pop())
-                        self._m_ssm_rows.inc(note["ssm_rows"])
-                    self._note_moe([(program, vec) for (program, _), vec
-                                    in zip(unread, rows)], note)
-                    del unread[:]
-                else:
-                    toks = np.asarray(state.tok)[:, 0]
-            with _obs.span("serve.deliver", cat="serve"):
-                self._note_step()
-                for slot, rec in active.items():
-                    deliver(rec, toks[slot:slot + 1])
-                    histories[slot].append(int(toks[slot]))
-                slot_lens += act_mask.astype(np.int64)
-                retire()
+                # state.tok is donated into the next step, which is queued
+                # before the host reads this one: it reads a copy
+                cur["toks"] = pred._keep_fn(state.tok)
+            if state.moe is not None:
+                cur["moe"].append(("decode", state.moe))
+            cur["ssm"] = state.ssm
+            cur["rows"] = list(active.items())
+            for rec in active.values():
+                rec["unread"] += 1
+            self._note_attn_blocks(slot_lens, act_mask, cur["note"])
+            self._note_step()
+            slot_lens += act_mask.astype(np.int64)
+            leave_due()
+        # --- (5) read the PREVIOUS tick: its step is done or running, and
+        # this tick's programs are queued behind it
+        read = "behind" if ps["unread"] is not None else "first"
+        self._settle()
+        if cur["firsts"] or cur["moe"] or cur["rows"]:
+            ps["unread"] = cur
+        if proposer is not None:
+            self._settle()      # the drafts of the next tick need these
+        return read
+
+    def _settle(self):
+        """Read what the last tick left on the device (``ps["unread"]``:
+        its step's tokens, the first token of a slot it committed, what its
+        programs counted — one transfer, a wait for that tick's programs
+        alone), deliver the tokens, and close what they end: a request that
+        left its slot by count and now holds its last token; a slot whose
+        token is the EOS, which retires here.  Nothing unread: nothing
+        done."""
+        ps = self._ps
+        if ps is None or ps["unread"] is None:
+            return
+        import jax
+
+        fl, ps["unread"] = ps["unread"], None
+        note = fl["note"]       # filled below too, read as the span closes
+        firsts, moe, rows = fl["firsts"], fl["moe"], fl["rows"]
+        with _obs.span("serve.readback", cat="serve", args=note):
+            got = jax.device_get(
+                [tok for _, tok in firsts] + [vec for _, vec in moe]
+                + [a for a in (fl["toks"], fl["ssm"]) if a is not None])
+            now = time.time()
+            if fl["ssm"] is not None:
+                note["ssm_rows"] = int(got.pop())
+                self._m_ssm_rows.inc(note["ssm_rows"])
+            toks = got.pop()[:, 0] if fl["toks"] is not None else None
+            self._note_moe([(program, vec) for (program, _), vec
+                            in zip(moe, got[len(firsts):])], note)
+        with _obs.span("serve.deliver", cat="serve"):
+            for (rec, _), tok in zip(firsts, got):
+                first = int(tok[0, 0])
+                rec["unread"] -= 1
+                rec["toks"].append(first)
+                rec["hist"].append(first)
+                self._req[rec["rid"]]["first"] = now
+            dropped = 0
+            for slot, rec in rows:
+                if rec.get("closed"):   # retired at its EOS, a step ago
+                    dropped += 1
+                    continue
+                rec["unread"] -= 1
+                held = len(rec["toks"])
+                self._deliver(rec, toks[slot:slot + 1])
+                dropped += len(rec["toks"]) == held
+                rec["hist"].append(int(toks[slot]))
+            if dropped:
+                self._m_dropped.inc(dropped)
+            for rec in [r for r, _ in firsts] + [r for _, r in rows]:
+                # out of its slot since its last step was queued
+                if "counted" in rec and not rec.get("closed"):
+                    if self._ended(rec):
+                        self._close(rec, ps["results"])
+                    else:
+                        self._count_out(rec)
+            self._retire_finished(ps["active"], ps["results"],
+                                  self._on_retire_paged(ps))
 
     def _note_gauges(self):
         """Refresh the per-host queue-depth / free-page gauges (the

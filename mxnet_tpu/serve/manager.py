@@ -288,6 +288,26 @@ class PagedKVManager:
                          args={"slot": int(slot), "copies": len(copies)})
         return copies
 
+    def within_reserve(self, slot, lo, hi):
+        """Whether :meth:`ensure` over [lo, hi) of ``slot`` can be met from
+        pages the slot maps or has reserved: then the append takes nothing
+        from the pool's headroom or the prefix cache.  The serving loop asks
+        before it queues a step whose row may be dropped (an EOS not yet
+        read).  A recycled page the prefix cache alone still holds counts as
+        one to fork (it may not be: the answer errs toward False)."""
+        if hi <= lo:
+            return True
+        row, m = self.tables[slot], self.pages_per_slot
+        need = 0
+        for ti in range(int(lo) // self.page_tokens,
+                        (int(hi) - 1) // self.page_tokens + 1):
+            idx = ti % m
+            page = int(row[idx])
+            if page == 0 or (self.allocator.shared(page) and
+                             (ti >= m or not self._own[slot, idx])):
+                need += 1
+        return need <= self._reserve[slot]
+
     def publish(self, slot, prompt, prompt_len):
         """Insert a finished prefill's prompt pages into the prefix
         cache (no-op when the cache is disabled)."""
@@ -368,6 +388,9 @@ class StateRows:
 
     def ensure(self, slot, lo, hi):
         return []
+
+    def within_reserve(self, slot, lo, hi):
+        return True
 
     def free_slot(self, slot):
         if slot in self._held:
@@ -477,6 +500,9 @@ class GroupedKVManager:
             copies = g.ensure(slot, lo, hi)
             assert not copies, "a page of an unshared group forked"
         return []
+
+    def within_reserve(self, slot, lo, hi):
+        return all(g.within_reserve(slot, lo, hi) for g in self.groups)
 
     def publish(self, slot, prompt, prompt_len):
         """Nothing to publish: there is no prefix cache."""

@@ -485,3 +485,67 @@ def assert_chrome_trace(payload, required_names=()):
     assert not missing, ("missing trace events %s (have %d events)"
                         % (sorted(missing), len(events)))
     return names
+
+
+# -- serving-loop helpers ---------------------------------------------------
+
+
+def serve_reading_first(server):
+    """Drain a paged ``DecodeServer``'s queue in a fresh session with every
+    tick's step read at that tick's end (``serve_results`` after each
+    ``serve_tick``): the order the loop had before it read one tick behind,
+    and the oracle for the order it has now.  Returns ``{rid: tokens}``."""
+    server.serve_reset()
+    server.serve_open()
+    while server.has_work:
+        server.serve_tick()
+        server.serve_results(clear=False)
+    return server.serve_results(clear=True)
+
+
+def serve_tick_counts(since=None):
+    """``{"behind": n, "first": n, "dropped": n}``: the process's
+    ``mx_serve_ticks_total`` by ``read`` and ``mx_serve_dropped_rows_total``,
+    less an earlier reading ``since`` (tests count across one drive)."""
+    from . import obs
+
+    ticks = obs.registry.get("mx_serve_ticks_total")
+    dropped = obs.registry.get("mx_serve_dropped_rows_total")
+    out = {read: int(ticks.labels(read=read).get()) if ticks else 0
+           for read in ("behind", "first")}
+    out["dropped"] = int(dropped.get()) if dropped else 0
+    return {k: v - since[k] for k, v in out.items()} if since else out
+
+
+def check_reading_behind(make_server, prompts, caps, with_eos):
+    """The serving loop reads one tick behind; its oracle is the same loop
+    made to read first.  ``make_server(eos_id)`` builds a fresh paged
+    ``DecodeServer``; ``prompts`` with ``caps`` go through it both ways.
+    Without an EOS every request must have as many tokens as its cap and no
+    row may be dropped; ``with_eos`` picks a token some answer reaches after
+    two others at least, so that answer ends in its middle, a step late."""
+    def serve(eos_id, drive):
+        server = make_server(eos_id)
+        rids = [server.submit(p, max_new_tokens=c)
+                for p, c in zip(prompts, caps)]
+        out = drive(server)
+        return [out[r] for r in rids]
+
+    before = serve_tick_counts()
+    behind = serve(None, lambda server: server.run())
+    moved = serve_tick_counts(before)
+    assert moved["behind"] > 3 * moved["first"] and not moved["dropped"], \
+        moved
+    assert [len(t) for t in behind] == list(caps)
+    eos_id = None
+    if with_eos:
+        plain = behind
+        eos_id = next(int(t[j]) for t in plain for j in range(2, len(t))
+                      if t[j] not in t[:j])
+        before = serve_tick_counts()
+        behind = serve(eos_id, lambda server: server.run())
+        assert serve_tick_counts(before)["dropped"] > 0
+        assert any(len(b) < len(t) for b, t in zip(behind, plain))
+    first = serve(eos_id, serve_reading_first)
+    for a, b in zip(behind, first):
+        np.testing.assert_array_equal(a, b)

@@ -243,6 +243,32 @@ def test_server_matches_generate_and_counts_moe_rows(toy):
     assert {r["labels"]["group"] for r in gauges} >= {"full", "window"}
 
 
+@pytest.mark.parametrize("kv_dtype,eos", [("", False), ("int8", False),
+                                          ("", True)])
+def test_reading_behind_gives_the_tokens_of_reading_first(toy, kv_dtype,
+                                                          eos):
+    """Window rings and gated experts under the loop that reads a tick
+    behind: six requests of mixed lengths through two slots (each reused, a
+    ring recycled under a step still queued) get the tokens of the loop
+    that reads first, as many as their caps; with an ``eos_id`` one answer
+    ends in its middle, a step late, and no token moves."""
+    from mxnet_tpu.test_utils import check_reading_behind
+
+    cfg, sym, params, _, _ = toy
+    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 26, 9, 30, 12)]
+
+    def make_server(eos_id):
+        pred = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(),
+                               paged=True, page_tokens=PAGE,
+                               kv_dtype=kv_dtype, prefill_chunk=CHUNK)
+        return DecodeServer(pred, max_prefill=32, slots=2, spec_k=0,
+                            eos_id=eos_id)
+
+    check_reading_behind(make_server, prompts, (9, 3, 12, 1, 6, 8), eos)
+
+
 def test_what_a_ring_cannot_carry_is_refused_by_name(toy):
     cfg, sym, params, _, _ = toy
     nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}

@@ -212,6 +212,30 @@ def test_server_logits_match_the_reference(toy, kv_dtype, atol):
     assert "'state' group" in stats["prefix_cache_off"]
 
 
+@pytest.mark.parametrize("kv_dtype,eos", [("", False), ("int8", False),
+                                          ("", True)])
+def test_reading_behind_gives_the_tokens_of_reading_first(toy, kv_dtype,
+                                                          eos):
+    """A state row beside the pages under the loop that reads a tick
+    behind: six requests of mixed lengths through two slots (each reused; a
+    row's next owner starts from zero while the last step of its old one is
+    still queued) get the tokens of the loop that reads first, as many as
+    their caps; with an ``eos_id`` one answer ends in its middle, a step
+    late (the dropped step advances a state nobody reads again), and no
+    token moves."""
+    from mxnet_tpu.test_utils import check_reading_behind
+
+    cfg, sym, params, _, _ = toy
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 27, 9, 30, 12)]
+
+    def make_server(eos_id):
+        return DecodeServer(predictor(sym, params, kv_dtype),
+                            max_prefill=32, slots=2, spec_k=0, eos_id=eos_id)
+
+    check_reading_behind(make_server, prompts, (9, 3, 12, 1, 6, 8), eos)
+
+
 def test_the_benchmarks_comparison_at_a_toy_size(toy):
     """``serve_ticks.check_against_reference`` as the cell's run calls it:
     one long row (21 tokens: two chunks and 5 of a third), the other rows

@@ -533,16 +533,25 @@ def test_serve_tick_spans_nest_and_share_the_request_id(telemetry):
         got = [e["args"]["rid"] for e in ev if e["name"] == name]
         assert got and set(got) == {rid}, (name, got)
     # request = [submit, first token]: it ends at the first token's stamp,
-    # which the host takes in the commit tick's readback (the decode step
-    # is queued behind the chunk by then), before the retire instant
+    # which the host takes as the readback of the tick AFTER the commit's
+    # returns (the commit tick queues its step behind the chunk and reads
+    # the tick before it; the token comes with that step's, one tick on),
+    # before the retire instant.  The stamp is wall-clock and the spans are
+    # perf_counter: the two agree to some tens of microseconds
     req = next(e for e in ev if e["name"] == "request")
     commit = next(e for e in ev if e["name"] == "serve.commit")
     retire = next(e for e in ev if e["name"] == "retire")
+    at = next(i for i, t in enumerate(ticks) if _inside(commit, t))
+    assert not any(e["name"] == "serve.readback" and _inside(e, ticks[at])
+                   for e in ev)     # nothing was unread in the commit's tick
     readback = next(e for e in ev if e["name"] == "serve.readback"
-                    and e["ts"] >= commit["ts"])
-    end = req["ts"] + req["dur"]
-    assert commit["ts"] + commit["dur"] <= end <= retire["ts"]
-    assert readback["ts"] <= end <= readback["ts"] + readback["dur"]
+                    and _inside(e, ticks[at + 1]))
+    assert [t["args"]["read"] for t in ticks[at:at + 2]] == ["first",
+                                                             "behind"]
+    end, slack = req["ts"] + req["dur"], 200
+    assert commit["ts"] + commit["dur"] <= end <= retire["ts"] + slack
+    assert readback["ts"] - slack <= end \
+        <= readback["ts"] + readback["dur"] + slack
 
 
 def test_fit_step_spans_nest(telemetry):

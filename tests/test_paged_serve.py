@@ -19,6 +19,7 @@ import mxnet_tpu as mx
 from mxnet_tpu.decode import DecodePredictor, DecodeServer
 from mxnet_tpu.models import attention_lm
 from mxnet_tpu.serve import PageAllocator, PrefixCache
+from mxnet_tpu.test_utils import serve_reading_first, serve_tick_counts
 
 VOCAB, T, EMBED, HEADS = 17, 16, 8, 2
 B = 2
@@ -356,6 +357,288 @@ def test_request_that_ends_at_its_first_token(ends):
     assert pred._manager.allocator.used_pages == 0
     stats = srv.stats()
     assert stats["requests_completed"] == 3 and stats["ttft_p95_s"] > 0
+
+
+# ---------------------------------------------------------------------------
+# the loop reads one tick behind (PR 40): tick n + 1's programs are queued
+# before tick n's tokens are read.  The oracle is the same loop made to read
+# first every tick (``serve_results`` after each ``serve_tick``).
+# ---------------------------------------------------------------------------
+MIXED_PROMPTS = (5, 9, 3, 7, 4, 8, 6)
+MIXED_CAPS = (6, 2, 7, 1, 5, 3, 4)
+
+
+def _mixed_server(kv_dtype="", eos_id=None, slots=2, **kw):
+    sym, params = _lm_and_params()
+    pred = DecodePredictor(sym, params, cache_len=T, paged=True,
+                           page_tokens=4, prefill_chunk=4, kv_dtype=kv_dtype,
+                           **kw)
+    rng = np.random.RandomState(21)
+    prompts = [rng.randint(0, VOCAB, (n,)) for n in MIXED_PROMPTS]
+    return pred, DecodeServer(pred, max_prefill=12, slots=slots,
+                              eos_id=eos_id, spec_k=0), prompts
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+def test_reading_behind_gives_the_tokens_of_reading_first(kv_dtype):
+    """Seven requests of mixed prompt and output lengths through two slots
+    (every slot reused, a cap of one among them): the loop that reads a tick
+    behind gives each request the tokens of the loop that reads first, as
+    many as its cap; a tick that follows a step queues its programs behind
+    it, unread."""
+    pred, srv, prompts = _mixed_server(kv_dtype)
+    before = serve_tick_counts()
+    ids = [srv.submit(p, max_new_tokens=c)
+           for p, c in zip(prompts, MIXED_CAPS)]
+    behind = srv.run()
+    moved = serve_tick_counts(before)
+    # first: the session's first tick, and one that follows a tick which
+    # queued no step (a prompt's earlier chunk into an empty batch)
+    assert moved["behind"] > 10 and moved["first"] <= 4, moved
+    assert moved["dropped"] == 0
+    assert srv.tokens_out == sum(MIXED_CAPS)
+    before = serve_tick_counts()
+    ids2 = [srv.submit(p, max_new_tokens=c)
+            for p, c in zip(prompts, MIXED_CAPS)]
+    first = serve_reading_first(srv)
+    moved = serve_tick_counts(before)
+    assert moved["behind"] == 0 and moved["first"] > 10, moved
+    for a, b, cap in zip(ids, ids2, MIXED_CAPS):
+        np.testing.assert_array_equal(behind[a], first[b])
+        assert len(behind[a]) == cap
+    assert pred._manager.allocator.used_pages == \
+        pred._manager.prefix_cache.pages_held
+    tc = pred.trace_counts
+    assert tc["chunk"] == 1 and tc["decode"] == 1 and tc["commit"] == 1
+
+
+@pytest.mark.parametrize("ends", ["mid", "first", "cap1"])
+def test_an_eos_is_seen_one_step_late_and_moves_no_token(ends):
+    """With ``eos_id`` a slot rides one step past its EOS, the row is
+    dropped and counted, and every request has the tokens of the loop that
+    reads first and of its own ``generate``: an EOS in the middle of an
+    answer, an EOS as the first token, and a cap of one beside an EOS."""
+    sym, params = _lm_and_params()
+    ref_pred = DecodePredictor(sym, params, cache_len=T)
+    rng = np.random.RandomState(21)
+    prompts = [rng.randint(0, VOCAB, (n,)) for n in MIXED_PROMPTS]
+    refs = [ref_pred.generate(_pad(p, 12), p.size, max_new_tokens=6)[0]
+            for p in prompts]
+    if ends == "first":
+        eos = int(refs[1][0])
+    else:       # a token some answer reaches after two others at least
+        eos = next(int(r[j]) for r in refs for j in range(2, 6)
+                   if r[j] not in r[:j])
+    caps = [1 if ends == "cap1" and i == 2 else 6
+            for i in range(len(prompts))]
+    want = []
+    for r, cap in zip(refs, caps):
+        r = r[:cap]
+        hit = np.flatnonzero(r == eos)
+        want.append(r[:int(hit[0]) + 1] if hit.size else r)
+    assert any(1 < len(w) < 6 for w in want) or ends == "first"
+    pred, srv, _ = _mixed_server(eos_id=eos)
+    before = serve_tick_counts()
+    ids = [srv.submit(p, max_new_tokens=c) for p, c in zip(prompts, caps)]
+    behind = srv.run()
+    moved = serve_tick_counts(before)
+    assert moved["dropped"] >= 1 and moved["behind"] > 0, moved
+    ids2 = [srv.submit(p, max_new_tokens=c) for p, c in zip(prompts, caps)]
+    first = serve_reading_first(srv)
+    for a, b, w in zip(ids, ids2, want):
+        np.testing.assert_array_equal(behind[a], w)
+        np.testing.assert_array_equal(first[b], w)
+    assert srv.tokens_out == 2 * sum(len(w) for w in want)
+    assert pred._manager.allocator.used_pages == \
+        pred._manager.prefix_cache.pages_held
+
+
+def _pad(prompt, width):
+    from mxnet_tpu.decode import _pad_window
+
+    return _pad_window(prompt, width)
+
+
+def _tick_until_unread_alone(srv):
+    """Tick until nothing is queued, mid-prefill or in a slot, and the last
+    step's tokens are still on the device."""
+    srv.serve_reset()
+    ps = srv.serve_open()
+    while srv._queue or ps["active"] or ps["pending"]:
+        srv.serve_tick()
+    assert ps["unread"] is not None
+    return ps
+
+
+@pytest.mark.parametrize("reader", ["has_work", "serve_results",
+                                    "serve_reset", "stats", "inject"])
+def test_a_step_unread_is_read_by_whoever_needs_its_tokens(reader):
+    """A request that ended by its cap has left its slot while its last
+    token is unread: ``has_work`` stays true for the tick that reads it, and
+    ``serve_results`` / ``stats`` / ``serve_reset`` / ``inject`` read it
+    themselves; its record closes with all its tokens either way."""
+    pred, srv, prompts = _mixed_server()
+    rid = srv.submit(prompts[0], max_new_tokens=3)
+    ps = _tick_until_unread_alone(srv)
+    assert srv.has_work and not ps["results"] and srv.tokens_out == 2
+    assert "retire" not in srv._req[rid]
+    if reader == "has_work":
+        srv.serve_tick()
+        assert not srv.has_work
+        assert len(srv.serve_results()[rid]) == 3
+    elif reader == "serve_results":
+        assert len(srv.serve_results(clear=False)[rid]) == 3
+        assert not srv.has_work
+    elif reader == "stats":
+        assert srv.stats()["requests_completed"] == 1
+        assert not srv.has_work
+    elif reader == "inject":
+        from mxnet_tpu.serve.swap import SwappedRequest
+
+        record = SwappedRequest(
+            prompts[1], [1], list(prompts[1]) + [1], 2, 0, prompts[1].size,
+            1, np.zeros(pred._manager.pages_per_slot, bool), None)
+        srv.inject(record)
+        assert ps["unread"] is None and len(ps["results"][rid]) == 3
+    else:
+        srv.serve_reset()
+        assert srv._ps is None and not srv.has_work
+    assert srv._req[rid]["tokens"] == 3 and srv.tokens_out == 3
+
+
+@pytest.mark.parametrize("why", ["proposer", "swap", "no_reserve"])
+def test_what_needs_the_tokens_reads_first(why):
+    """A proposer drafts from the histories, a swap-out takes the victim's
+    tokens with it, and a row that may be dropped may not take a page
+    beyond its slot's reservation: each reads before it queues, counted
+    ``mx_serve_ticks_total{read="first"}``, and the tokens are ``generate``'s."""
+    from mxnet_tpu import config as _cfg
+
+    sym, params = _lm_and_params(seed=3)
+    rng = np.random.RandomState(3)
+    long_p, short_p = rng.randint(0, VOCAB, (6,)), rng.randint(0, VOCAB, (5,))
+    ref_pred = DecodePredictor(sym, params, cache_len=T)
+    ref_long = ref_pred.generate(_pad(long_p, 8), 6, max_new_tokens=10)[0]
+    ref_short = ref_pred.generate(_pad(short_p, 8), 5, max_new_tokens=4)[0]
+    before = serve_tick_counts()
+    if why == "swap":
+        with _cfg.overrides(MXNET_FLEET_DECODE_BOUND="4",
+                            MXNET_FLEET_SWAP="1"):
+            pred = DecodePredictor(sym, params, cache_len=T, paged=True,
+                                   page_tokens=4, prefill_chunk=4,
+                                   pool_pages=6, prefix_cache=False)
+            srv = DecodeServer(pred, max_prefill=8, slots=2, spec_k=0)
+            r1 = srv.submit(long_p, 10, priority=-1)
+            r2 = srv.submit(short_p, 4, priority=1)
+            res = srv.run()
+        moved = serve_tick_counts(before)
+        assert srv.swap_outs >= 1
+        # the session's first tick, the tick that swapped out, the tick
+        # that restored (and one after a tick that queued a chunk alone)
+        assert 1 + srv.swap_outs + srv.swap_ins <= moved["first"] \
+            < moved["behind"], moved
+    else:
+        eos = None
+        if why == "no_reserve":
+            eos = next(t for t in range(VOCAB)
+                       if t not in ref_long and t not in ref_short)
+        pred = DecodePredictor(sym, params, cache_len=T, paged=True,
+                               page_tokens=4, prefill_chunk=4)
+        srv = DecodeServer(pred, max_prefill=8, slots=2, eos_id=eos,
+                           spec_k=3 if why == "proposer" else 0)
+        r1, r2 = srv.submit(long_p, 10), srv.submit(short_p, 4)
+        if why == "no_reserve":
+            mgr = srv.serve_open() and pred._manager
+            asked = []
+            mgr.within_reserve = lambda *a: asked.append(a) or False
+            while srv.has_work:
+                srv.serve_tick()
+            res = srv.serve_results()
+            assert asked
+        else:
+            res = srv.run()
+            assert srv.spec_steps > 0
+        moved = serve_tick_counts(before)
+        # no decode step is queued behind an unread one (a tick that steps
+        # nothing, the last one, has only the read left)
+        assert moved["first"] > 4 and moved["behind"] <= \
+            (0 if why == "proposer" else 2), moved
+    np.testing.assert_array_equal(res[r1], ref_long)
+    np.testing.assert_array_equal(res[r2], ref_short)
+
+
+def test_within_reserve_counts_the_pages_an_append_would_take():
+    from mxnet_tpu.serve import PagedKVManager
+
+    mgr = PagedKVManager(2, 16, 4, prefix_cache=False)
+    got = mgr.gate(np.arange(6), 6, 4)
+    mgr.map_slot(0, got[1], got[2])
+    mgr.ensure(0, 0, 6)                     # two pages, from the reservation
+    assert mgr.within_reserve(0, 6, 7)      # inside the second page
+    assert mgr.within_reserve(0, 8, 9)      # a third, still reserved
+    mgr.allocator.unreserve(int(mgr._reserve[0]))
+    mgr._reserve[0] = 0
+    assert mgr.within_reserve(0, 7, 8) and not mgr.within_reserve(0, 8, 9)
+    assert mgr.within_reserve(0, 8, 8)      # nothing to write
+
+
+def test_the_read_comes_after_the_dispatch_and_reads_the_tick_before(
+        monkeypatch):
+    """Structure of the new order, from the timeline and the transfers: in
+    a tick no ``serve.readback`` begins before that tick's
+    ``serve.decode_dispatch`` has ended, and what it fetches is the token
+    copy the PREVIOUS tick left in ``ps["unread"]``, not this tick's."""
+    from mxnet_tpu import config, obs
+
+    monkeypatch.setenv("MXNET_TELEMETRY", "1")
+    config.refresh("MXNET_TELEMETRY")
+    try:
+        pred, srv, prompts = _mixed_server()
+        for p, c in zip(prompts[:4], (5, 6, 4, 5)):
+            srv.submit(p, max_new_tokens=c)
+        fetched = []
+        real = jax.device_get
+        monkeypatch.setattr(jax, "device_get",
+                            lambda x: fetched.append(list(x)) or real(x))
+        obs.timeline.clear()
+        srv.serve_reset()
+        ps = srv.serve_open()
+        left, reads = [], []        # after tick i; during tick i
+        while srv.has_work:
+            n = len(fetched)
+            srv.serve_tick()
+            reads.append(fetched[n:])
+            left.append(ps["unread"])
+        for i in range(1, len(left)):
+            if left[i - 1] is None:
+                continue
+            assert len(reads[i]) == 1       # one transfer a tick
+            want = left[i - 1]["toks"]
+            assert want is None or any(a is want for a in reads[i][0])
+            if left[i] is not None and left[i]["toks"] is not None:
+                assert not any(a is left[i]["toks"] for a in reads[i][0])
+        ev = obs.timeline.events()
+    finally:
+        monkeypatch.undo()
+        config.refresh("MXNET_TELEMETRY")
+    ticks = [e for e in ev if e["name"] == "serve.tick"]
+    # behind, wherever the tick before left something to read
+    assert [t["args"]["read"] for t in ticks] == ["first"] + [
+        "first" if was is None else "behind" for was in left[:-1]]
+    assert [t["args"]["read"] for t in ticks].count("behind") > 10
+    inside = lambda e, t: t["ts"] <= e["ts"] and \
+        e["ts"] + e["dur"] <= t["ts"] + t["dur"]
+    seen = 0
+    for t in ticks:
+        disp = [e for e in ev if e["name"] == "serve.decode_dispatch"
+                and inside(e, t)]
+        back = [e for e in ev if e["name"] == "serve.readback"
+                and inside(e, t)]
+        assert len(back) <= 1 and len(disp) <= 1
+        if disp and back:
+            seen += 1
+            assert disp[0]["ts"] + disp[0]["dur"] <= back[0]["ts"]
+    assert seen > 5
 
 
 def test_allocator_and_prefix_cache_units():
