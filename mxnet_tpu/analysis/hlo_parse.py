@@ -15,10 +15,13 @@ Three families of entry points:
   report carries ``uncounted_ops`` so dot-like ops the counter cannot
   parse are a signal, not a silent zero);
 * program metadata — :func:`input_output_aliases` (compiled-HLO donation
-  aliasing).
+  aliasing) and :func:`instructions` (every instruction of an optimized
+  module with its opcode, shape, operands and ``op_name``: what
+  ``obs.scopes`` builds its maps on).
 """
 from __future__ import annotations
 
+import collections
 import re
 
 __all__ = [
@@ -26,6 +29,7 @@ __all__ = [
     "dot_flops",
     "dot_flops_report",
     "input_output_aliases",
+    "instructions",
     "shape_bytes",
     "shape_bytes_report",
     "shape_str",
@@ -708,28 +712,110 @@ def stablehlo_sort_scatter_stats(stablehlo_text):
 _LHS_RE = re.compile(r"^\s*(?:ROOT\s+)?(%?[\w.\-]+)\s*=\s*")
 _META_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
 _MODULE_NAME_RE = re.compile(r"^HloModule\s+([^\s,]+)")
+# a computation's header: ``[ENTRY] %name (params...) -> shape {``
+_COMPUTATION_RE = re.compile(r"^\s*(ENTRY\s+)?%?([\w.\-]+)\s*\(.*\{\s*$")
+_OPCODE_RE = re.compile(r"\s*([\w\-]+)\(")
+_OPERAND_RE = re.compile(r"%?([A-Za-z_][\w.\-]*)\s*$")
+_TUPLE_INDEX_RE = re.compile(r"\bindex=(\d+)")
+# the computations an instruction runs: a fusion's ``calls=``, a while's
+# ``body=`` and ``condition=``, a reduce's ``to_apply=``, a conditional's
+# ``branch_computations={...}``
+_CALLED_RE = re.compile(
+    r"\b(?:calls|body|condition|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+
+Instruction = collections.namedtuple("Instruction", (
+    "name",          # unique within the module, no leading %
+    "opcode",        # ``fusion``, ``copy-start``, ``parameter``, ...
+    "shape",         # result shape as printed, layout and memory space too
+    "bytes",         # of the result; an async start's: what it delivers
+    "operands",      # names, in order
+    "op_name",       # of its own metadata={...}, or None
+    "computation",   # the computation that lists it
+    "entry",         # whether that is the module's ENTRY computation
+    "called",        # names of the computations it runs
+    "root",          # whether it is its computation's ROOT
+    "index"))        # a ``parameter(N)``'s N, a ``get-tuple-element``'s
+                     # ``index=N``; else None
 
 
-def instruction_op_names(hlo_text):
-    """``(module name, [(instruction name, op_name or None), ...])`` of one
-    HLO module's text: every instruction line of every computation
-    (names are unique within a module) with the ``op_name`` of its own
-    ``metadata={...}`` — for a fusion, XLA keeps its root's.  The
-    module's name is the text's first line (``HloModule jit_step, ...``),
-    the stem a device trace prints on its ``XLA Modules`` line."""
+def _closing(line, start):
+    """Index of the parenthesis that closes the one at ``line[start]``."""
+    depth = 0
+    for i in range(start, len(line)):
+        if line[i] == "(":
+            depth += 1
+        elif line[i] == ")":
+            depth -= 1
+            if depth == 0:
+                return i
+    return len(line)
+
+
+def _delivered_bytes(opcode, shape_s):
+    """Bytes of an instruction's result.  An async start's tuple holds the
+    operand beside the result and context scalars; counted is what the
+    matching done delivers: of ``copy-start``'s ``(destination, source,
+    context)`` the destination, of the others what :func:`_start_bytes`
+    counts."""
+    if not (opcode.endswith("-start") and shape_s.startswith("(")):
+        return shape_bytes(shape_s)
+    if opcode == "copy-start":
+        return shape_bytes(_split_top_level(shape_s)[0])
+    return _start_bytes(opcode[:-len("-start")], shape_s)
+
+
+def instructions(hlo_text):
+    """``(module name, [Instruction, ...])`` of one HLO module's text:
+    every instruction line of every computation (names are unique within a
+    module), each with its opcode, its result shape as printed (``f32[12289,
+    16,4]{2,1,0:T(8,128)S(1)}``: layout and memory space are part of what a
+    move costs), the result's bytes, its operands' names, the ``op_name``
+    of its own ``metadata={...}`` (for a fusion XLA keeps its root's) and
+    the computations it runs.  The module's name is the text's first line
+    (``HloModule jit_step, ...``), the stem a device trace prints on its
+    ``XLA Modules`` line.  The one parser under ``obs.scopes``."""
     lines = hlo_text.splitlines()
     head = _MODULE_NAME_RE.match(lines[0]) if lines else None
-    out = []
+    out, computation, entry = [], None, False
     for line in lines[1:]:
         m = _LHS_RE.match(line)
         if m is None:
+            c = _COMPUTATION_RE.match(line)
+            if c is not None:
+                computation, entry = c.group(2), bool(c.group(1))
             continue
+        shape_s, end = _scan_shape(line, m.end())
+        om = _OPCODE_RE.match(line, end)
+        if om is None:
+            continue
+        opcode = om.group(1)
+        close = _closing(line, om.end() - 1)
+        inner = line[om.end():close]
+        number, operands = None, []
+        if opcode == "parameter":
+            number = int(inner) if inner.strip().isdigit() else None
+        elif opcode != "constant":
+            for piece in _split_top_level("(%s)" % inner):
+                found = _OPERAND_RE.search(piece)
+                if found is not None:
+                    operands.append(found.group(1))
         # the metadata is the line's tail: search from there, so a
         # quoted op_name inside a backend_config cannot shadow it
         tail = line.rfind("metadata={")
         found = _META_OP_NAME_RE.search(line, tail) if tail >= 0 else None
-        out.append((m.group(1).lstrip("%"),
-                    found.group(1) if found else None))
+        if opcode == "get-tuple-element":
+            at = _TUPLE_INDEX_RE.search(line, close)
+            number = int(at.group(1)) if at else None
+        called = []
+        for one, many in _CALLED_RE.findall(line, close):
+            called += [one] if one else [
+                c.strip().lstrip("%") for c in many.split(",") if c.strip()]
+        out.append(Instruction(
+            m.group(1).lstrip("%"), opcode, shape_s,
+            _delivered_bytes(opcode, shape_s), tuple(operands),
+            found.group(1) if found else None, computation, entry,
+            tuple(called), line.lstrip().startswith("ROOT "), number))
     return (head.group(1) if head else None), out
 
 

@@ -136,6 +136,33 @@ _POSITION_BROADCAST_OPS = {
 }
 
 
+def _keep_tok(tok):
+    """A step's tokens, copied (a function with a name, so that its
+    program is module ``jit__keep_tok`` on a device trace)."""
+    return tok + 0
+
+
+def _same_avals(a, b):
+    import jax.tree_util as jtu
+
+    what = lambda x: (x.shape, x.dtype, getattr(x, "weak_type", False),
+                      getattr(x, "sharding", None))
+    return jtu.tree_structure(a) == jtu.tree_structure(b) and all(
+        what(x) == what(y)
+        for x, y in zip(jtu.tree_leaves(a), jtu.tree_leaves(b)))
+
+
+def _loaded(lowered):
+    """Whether this process has already compiled ``lowered`` (a
+    ``jax.stages.Lowered``).  jax caches a lowering by the traced
+    function and its arguments' shardings and keeps the executable on it:
+    lowering a dispatched program's avals again meets that lowering, and
+    ``.compile()`` hands back the very executable the dispatch calls.
+    False where jax does not show it."""
+    return getattr(getattr(lowered, "_lowering", None),
+                   "_executable", None) is not None
+
+
 def _per_group(items):
     """What the programs take where a graph has cache groups: the one
     item of a graph with one group, else a tuple, one a group."""
@@ -426,7 +453,7 @@ class DecodePredictor:
             # a step's tokens for the host, which reads them after the
             # next step is queued: that step's donation consumes `state.tok`
             # and leaves this copy alone
-            self._keep_fn = jax.jit(lambda tok: tok + 0)
+            self._keep_fn = jax.jit(_keep_tok)
             self._manager = None          # serve.PagedKVManager, per batch
             self._pools_template = None   # per-node cache avals (probed)
             self._paged_lens = None       # host mirror for standalone use
@@ -445,6 +472,7 @@ class DecodePredictor:
         # snapped once on the first dispatch so obs.programs can price
         # the program lazily (trace+lower at TABLE time, off hot paths)
         self._static_args = {}
+        self._steady = None     # the state's avals from the second tick on
         # jnp dummies reused every call (sample_tokens at temperature 0
         # never reads the key, but the jit signature keeps it)
         self._zero_key = jax.random.PRNGKey(0)
@@ -599,7 +627,9 @@ class DecodePredictor:
         """Snap ``args``' avals once and register a lazy static-cost
         prober (``static``: a row of the MFU table) and a lazy reader of
         the optimized HLO (the scope map) for program ``name`` (first
-        dispatch only; later calls are one dict hit)."""
+        dispatch only; later calls are one dict hit).  The first paged
+        step also registers readers for the small programs the serving
+        loop runs beside it (:data:`_BESIDE`), which are not priced."""
         if name in self._static_args or not _obs.enabled():
             return
         import weakref
@@ -616,21 +646,132 @@ class DecodePredictor:
             _obs.programs.register_static(
                 name, lambda n=name, r=ref: (
                     r()._roofline_static(n) if r() is not None else None))
-        _obs.programs.register_hlo(
-            name, lambda n=name, r=ref: (
-                r()._program_hlo(n) if r() is not None else None))
+        names = (name,) + (tuple(self._BESIDE) if self._paged and name in (
+            "paged_decode_step", "paged_verify_step") else ())
+        for n in names:
+            _obs.programs.register_hlo(
+                n, lambda n=n, r=ref: (
+                    r()._program_hlo(n) if r() is not None else None),
+                owner=self)
+
+    # the paged programs that hand the serving state on, and those the
+    # loop dispatches beside them: map name -> (attribute of the dispatch,
+    # kind in serving_avals)
+    _STEPS = ("prefill_chunk", "paged_decode_step", "paged_verify_step")
+    _BESIDE = {"slot_commit": ("_commit_fn", "commit"),
+               "page_fork": ("_fork_fn", "fork"),
+               "page_extract": ("_extract_fn", "extract"),
+               "page_install": ("_install_fn", "install"),
+               "keep_tok": ("_keep_fn", None)}
+
+    def _steady_state(self):
+        """Avals of the serving state (pools, lengths, last tokens) as the
+        paged programs hand it to each other from a session's second tick
+        on: what the snapped step programs make of it, by abstract
+        evaluation until it stops changing, committed to the device as
+        outputs are.  A session's first dispatches are handed fresh arrays
+        (uncommitted; and a carried value whose type one of the programs
+        does not keep comes back as another), and jax traces or compiles a
+        program of its own for every later one.  Computed once, inside
+        ``probing``."""
+        import jax
+        import jax.tree_util as jtu
+
+        if self._steady is not None:
+            return self._steady
+        here = None if self._mesh is not None else \
+            jax.sharding.SingleDeviceSharding(self._ctx.jax_device)
+
+        def back(old, new):
+            # an argument placed by a sharding keeps it; the outputs of a
+            # single device's programs are committed to it
+            return jax.ShapeDtypeStruct(
+                new.shape, new.dtype, weak_type=getattr(new, "weak_type",
+                                                        False),
+                sharding=getattr(old, "sharding", None) or here)
+
+        snapped = {n: self._static_args[n] for n in self._STEPS
+                   if n in self._static_args}
+        state = next((a[1] for n, (_, a) in snapped.items()
+                      if n != "prefill_chunk"), None)
+        if state is None:       # chunks alone so far: the pools
+            state = DecodeState(snapped["prefill_chunk"][1][1], None, None)
+        for _ in range(4):
+            before = state
+            for n, (fn, args) in snapped.items():
+                if n == "prefill_chunk":
+                    out = fn.eval_shape(args[0], state.caches, *args[2:])
+                    new = DecodeState(out[0], state.lens, state.tok)
+                else:
+                    new = fn.eval_shape(args[0], state, *args[2:])[0]
+                    new = DecodeState(new.caches, new.lens, new.tok)
+                state = jtu.tree_map(back, state, new)
+            if _same_avals(before, state):
+                break
+        self._steady = state
+        return state
+
+    def _steady_args(self, name):
+        """``(dispatch, avals)`` of paged program ``name`` as its dispatches
+        see it from a session's second tick on; avals None where the graph
+        has no such program."""
+        import jax
+
+        state = self._steady_state()
+        if name in self._BESIDE:
+            attr, kind = self._BESIDE[name]
+            fn = getattr(self, attr)
+            if kind is None:
+                return fn, (state.tok,)
+            args = self.serving_avals(state.lens.shape[0]).get(kind)
+            if args is None:
+                return fn, None
+            if kind == "commit":
+                # the lengths, the tokens, and a chunk's first token
+                first = jax.ShapeDtypeStruct(args[4].shape, args[4].dtype,
+                                             sharding=state.tok.sharding)
+                return fn, (state.lens, state.tok) + tuple(args[2:4]) \
+                    + (first,)
+            return fn, (state.caches,) + tuple(args[1:])
+        fn, args = self._static_args[name]
+        carried = state.caches if name == "prefill_chunk" \
+            else args[1]._replace(caches=state.caches, lens=state.lens,
+                                  tok=state.tok)
+        return fn, (args[0], carried) + tuple(args[2:])
 
     def _program_hlo(self, name):
-        """Optimized HLO text of one snapped program, for
-        ``obs.programs.scope_map``.  Lowering the snapped avals again
-        meets jax's in-memory caches: the text is read off the
-        executable the program already dispatches, nothing compiles and
-        nothing is loaded a second time."""
+        """Optimized HLO text of the executable that program ``name``
+        dispatches, for ``obs.programs``' maps; None where it has not
+        run.  An armed ``AotDispatch`` hands over the executable that
+        last answered.  For a ``jax.jit`` the avals are lowered again, a
+        paged program's first as every dispatch after a session's first
+        sees them (:meth:`_steady_state`), then as snapped at the first:
+        the lowering that already holds a loaded executable is the one the
+        dispatch calls, and its text is read with nothing compiled.
+        Where neither does, a snapped program is compiled as snapped (the
+        accounting sees the compile and marks the map ``"relowered"``)
+        and a program beside the steps is left without a map."""
         from .programs.spec import probing
 
-        fn, args = self._static_args[name]
+        fn, snapped = self._static_args[name] if name in self._static_args \
+            else (getattr(self, self._BESIDE[name][0]), None)
+        armed = getattr(fn, "executable", None)
+        if armed is not None and armed() is not None:
+            return armed().as_text()
         with probing(self):
-            return fn.lower(*args).compile().as_text()
+            tries = []
+            if name in self._STEPS or name in self._BESIDE:
+                steady = self._steady_args(name)[1]
+                if steady is None:
+                    return None     # no such program on this graph
+                tries.append(steady)
+            if snapped is not None:
+                tries.append(snapped)
+            for avals in tries:
+                lowered = fn.lower(*avals)
+                if _loaded(lowered):
+                    return lowered.compile().as_text()
+            return None if snapped is None else lowered.compile().as_text()
 
     def _roofline_static(self, name):
         """Price one snapped program (trace+lower only; probe-flagged so
@@ -3371,7 +3512,6 @@ class DecodeServer:
                 caches = pred._run_forks(state.caches, copies) \
                     if copies else state.caches
                 sub = next_key()
-                _obs.instant("prefill_chunk", cat="serve", args=where)
                 args = (pred._env, caches) + pred._chunk_operands(
                     p["slot"], p["prompt"][p["pos"]:p["pos"] + n],
                     p["pos"], self._chunk_w) + (sub,)

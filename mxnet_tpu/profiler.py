@@ -161,8 +161,8 @@ def record_request(queue_wait_s, ttft_s, tokens, decode_s, rid=None):
     before admission, time to first token (from submit), tokens
     delivered, and the wall time its post-first-token decode took.  The
     ``request`` span covers [submit, first token] and carries ``rid``,
-    the identifier its ``admit`` / ``prefill_chunk`` / ``retire`` events
-    share."""
+    the identifier its ``admit`` / ``retire`` events and its
+    ``serve.prefill`` / ``serve.commit`` spans share."""
     tokens = int(tokens)
     _c_requests.inc()
     _c_req_tokens.inc(tokens)

@@ -231,6 +231,12 @@ class AotDispatch:
     def armed(self):
         return bool(self._armed)
 
+    def executable(self):
+        """The armed executable that answered last (the first armed
+        before any call), or None when disarmed: what a dispatch calls,
+        so ``obs.programs`` reads the program's text off it."""
+        return self._armed[0][0] if self._armed else None
+
     def __call__(self, *args):
         if self._armed and not _trace_clean():
             return self.fn(*args)

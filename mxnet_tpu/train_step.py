@@ -737,7 +737,8 @@ class CompiledTrainStep:
             _obs.programs.register_static(self.telemetry_name,
                                           _weak_prober(self))
             _obs.programs.register_hlo(
-                self.telemetry_name, _weak_prober(self, "compiled_hlo"))
+                self.telemetry_name, _weak_prober(self, "compiled_hlo"),
+                owner=self)
             self._program_spec = _register_step_spec(self)
             # the optimizer phase's own row: zero wall of its own (its
             # dispatch is inside train_step), but its priced bytes make

@@ -392,3 +392,49 @@ def test_serving_avals_and_pool_bytes_know_the_state_group(toy):
     half = predictor(harness.build_symbol(dict(
         cfg, ssm_state_dtype="bfloat16")), params)
     assert half.state_row_bytes() == 3 * (4 * 3 * 96 + 2 * 4 * 16 * 8)
+
+
+@pytest.mark.parametrize("program,stem", [
+    ("paged_decode_step", "jit__paged_decode_impl"),
+    ("prefill_chunk", "jit__chunk_impl")])
+def test_the_maps_are_the_programs_the_loop_runs_from_its_second_tick(
+        toy, program, stem):
+    """With bfloat16 weights a mixer's conv tail is allocated bfloat16 and
+    comes back float32 from every block after the first, so jax traces a
+    second decode and a second chunk program on a session's second tick,
+    and those run from then on.  ``obs.programs``' maps must be theirs, not
+    the first dispatch's (which ran once): read with nothing compiled, and
+    with the entry parameters of the state the loop really carries."""
+    import re
+
+    from mxnet_tpu import obs
+    from mxnet_tpu.analysis.hlo_parse import shape_str
+
+    cfg, sym, params, _, _ = toy
+    obs.programs.reset(clear_static=True)
+    pred = predictor(sym, {k: v.astype(jnp.bfloat16)
+                           for k, v in params.items()}, "int8")
+    server = DecodeServer(pred, max_prefill=32, slots=3, spec_k=0)
+    rng = np.random.default_rng(1)
+    for n in (9, 12, 7, 10):
+        server.submit(rng.integers(0, cfg["vocab_size"], size=(n,)),
+                      max_new_tokens=4)
+    server.serve_reset()
+    ps = server.serve_open()
+    while server.has_work:
+        server.serve_tick()
+    live = sorted(shape_str(a.shape, a.dtype)
+                  for a in jax.tree_util.tree_leaves(ps["state"].caches))
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **_: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    entry = obs.programs.instruction_maps()[stem]
+    assert not compiles and entry["source"] == "dispatched"
+    carried = sorted(
+        v["shape"].split("{")[0] for k, v in entry["instructions"].items()
+        if v["opcode"] == "parameter" and re.match(r"(state_)?caches_", k))
+    assert carried == live
+    assert obs.programs.scope_map(program) == {
+        k: v["scope"] for k, v in entry["instructions"].items()}
+    obs.programs.reset(clear_static=True)

@@ -493,6 +493,315 @@ def test_scope_of_strips_the_backward_wrapper():
 
 
 # ---------------------------------------------------------------------------
+# instruction maps: what an instruction is, what it carries, what it feeds
+# ---------------------------------------------------------------------------
+_HAND_HLO = """HloModule jit_hand, is_scheduled=true, entry_computation_layout={(f32[8,16]{1,0:T(8,128)}, f32[4,4]{1,0})->f32[8,16]{1,0}}
+
+%fused_computation.1 (param_0.1: f32[8,16], param_1.1: f32[8,16]) -> f32[8,16] {
+  %param_0.1 = f32[8,16]{1,0:T(8,128)S(1)} parameter(0)
+  %param_1.1 = f32[8,16]{1,0:T(8,128)} parameter(1)
+  ROOT %add.1 = f32[8,16]{1,0:T(8,128)} add(%param_0.1, %param_1.1), metadata={op_name="jit(hand)/mx.linear/fc1/add"}
+}
+
+%copy_fusion.clone (param_0.2: f32[8,16]) -> f32[16,8] {
+  %param_0.2 = f32[8,16]{1,0:T(8,128)} parameter(0)
+  %bitcast.3 = f32[8,16]{1,0:T(8,128)} bitcast(%param_0.2)
+  ROOT %transpose.4 = f32[16,8]{1,0:T(8,128)} transpose(%bitcast.3), dimensions={1,0}
+}
+
+%async_computation (param_0.3: f32[8,16]) -> f32[2,16] {
+  %param_0.3 = f32[8,16]{1,0:T(8,128)} parameter(0)
+  ROOT %slice.7 = f32[2,16]{1,0:T(2,128)S(1)} slice(%param_0.3), slice={[0:2], [0:16]}
+}
+
+%body (p.1: (s32[], f32[8,16])) -> (s32[], f32[8,16]) {
+  %p.1 = (s32[]{:T(128)}, f32[8,16]{1,0:T(8,128)}) parameter(0)
+  %i.1 = s32[]{:T(128)} get-tuple-element(%p.1), index=0
+  %x.1 = f32[8,16]{1,0:T(8,128)} get-tuple-element(%p.1), index=1
+  %copy.9 = f32[8,16]{0,1:T(8,128)} copy(%x.1)
+  %fusion.9 = f32[8,16]{1,0:T(8,128)} fusion(%copy.9, %x.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(hand)/while/body/mx.attn/att0/mx.attn/kv_gather/add"}
+  ROOT %tuple.9 = (s32[]{:T(128)}, f32[8,16]{1,0:T(8,128)}) tuple(%i.1, %fusion.9)
+}
+
+%cond (p.2: (s32[], f32[8,16])) -> pred[] {
+  %p.2 = (s32[]{:T(128)}, f32[8,16]{1,0:T(8,128)}) parameter(0)
+  %i.2 = s32[]{:T(128)} get-tuple-element(%p.2), index=0
+  %c.2 = s32[]{:T(128)} constant(3)
+  ROOT %lt.2 = pred[]{:T(512)} compare(%i.2, %c.2), direction=LT
+}
+
+ENTRY %main.1 (env__fc1_weight__.1: f32[8,16], Arg_1.2: f32[4,4]) -> f32[8,16] {
+  %env__fc1_weight__.1 = f32[8,16]{1,0:T(8,128)} parameter(0), sharding={replicated}, metadata={op_name="env[\\'fc1_weight\\']"}
+  %Arg_1.2 = f32[4,4]{1,0:T(4,128)} parameter(1)
+  %copy-start = (f32[8,16]{1,0:T(8,128)S(1)}, f32[8,16]{1,0:T(8,128)}, u32[]{:S(2)}) copy-start(%env__fc1_weight__.1), cross_program_prefetch_index=0
+  %copy-done = f32[8,16]{1,0:T(8,128)S(1)} copy-done(%copy-start)
+  %fusion.1 = f32[8,16]{1,0:T(8,128)} fusion(%copy-done, %env__fc1_weight__.1), kind=kLoop, calls=%fused_computation.1, metadata={op_name="jit(hand)/mx.linear/fc1/add" stack_frame_id=7}, backend_config={"op_name":"not this one"}
+  %reshape.2 = f32[16,8]{1,0:T(8,128)} reshape(%fusion.1), metadata={op_name="jit(hand)/mx.linear/fc1/reshape"}
+  %fusion.5 = f32[16,8]{1,0:T(8,128)} fusion(%fusion.1), kind=kLoop, calls=%copy_fusion.clone
+  %slice-start = ((f32[8,16]{1,0:T(8,128)}), f32[2,16]{1,0:T(2,128)S(1)}, s32[]{:S(2)}) async-start(%fusion.1), calls=%async_computation
+  %slice-done = f32[2,16]{1,0:T(2,128)S(1)} async-done(%slice-start)
+  %copy.3 = f32[4,4]{0,1:T(4,128)} copy(%Arg_1.2)
+  %dot.6 = f32[8,16]{1,0:T(8,128)} custom-call(%fusion.5, %slice-done, %copy.3), custom_call_target="x", metadata={op_name="jit(hand)/mx.attn/att0/mx.attn/scores/dot_general"}
+  %zero.1 = s32[]{:T(128)} constant(0)
+  %tuple.1 = (s32[]{:T(128)}, f32[8,16]{1,0:T(8,128)}) tuple(%zero.1, %copy-done)
+  %while.1 = (s32[]{:T(128)}, f32[8,16]{1,0:T(8,128)}) while(%tuple.1), condition=%cond, body=%body, metadata={op_name="jit(hand)/mx.attn/att0/while"}
+  %gte.1 = f32[8,16]{1,0:T(8,128)} get-tuple-element(%while.1), index=1
+  %copy.8 = f32[8,16]{1,0} copy(%gte.1)
+  ROOT %tuple.2 = (f32[8,16]{1,0}) tuple(%copy.8)
+}
+"""
+
+
+def test_instructions_reads_opcode_shape_operands_and_computations():
+    from mxnet_tpu.analysis.hlo_parse import instructions
+
+    module, rows = instructions(_HAND_HLO)
+    assert module == "jit_hand"
+    by = {r.name: r for r in rows}
+    assert len(by) == len(rows)                 # names are unique
+    start = by["copy-start"]
+    assert start.opcode == "copy-start" and start.entry \
+        and start.computation == "main.1"
+    assert start.operands == ("env__fc1_weight__.1",)
+    # the shape as printed, layout and memory space too; an async start
+    # counts what its done delivers, not its whole tuple
+    assert start.shape.startswith("(f32[8,16]{1,0:T(8,128)S(1)}, ")
+    assert start.bytes == 8 * 16 * 4 == by["copy-done"].bytes
+    assert by["copy-done"].shape == "f32[8,16]{1,0:T(8,128)S(1)}"
+    assert by["slice-start"].opcode == "async-start" \
+        and by["slice-start"].called == ("async_computation",) \
+        and by["slice-start"].bytes == 2 * 16 * 4
+    p = by["env__fc1_weight__.1"]
+    assert p.opcode == "parameter" and p.index == 0 \
+        and p.op_name == "env[\\'fc1_weight\\']" and p.operands == ()
+    assert by["param_0.1"].index == 0 and not by["param_0.1"].entry
+    assert by["x.1"].index == 1 and by["fusion.1"].index is None
+    f = by["fusion.1"]
+    assert f.opcode == "fusion" and f.called == ("fused_computation.1",) \
+        and f.operands == ("copy-done", "env__fc1_weight__.1")
+    # the metadata's own op_name, not one quoted in a backend_config
+    assert f.op_name == "jit(hand)/mx.linear/fc1/add"
+    assert by["while.1"].called == ("cond", "body") \
+        and by["while.1"].operands == ("tuple.1",)
+    assert by["tuple.2"].root and by["add.1"].root and not f.root
+    assert by["zero.1"].operands == () and by["copy.9"].computation == "body"
+
+
+_HAND_WANT = {
+    # a scopeless copy of an entry parameter, feeding a scoped fusion
+    # through copy-start -> copy-done (and the loop, listed later)
+    "copy-start": dict(scope="unscoped", moves=True,
+                       src="env['fc1_weight']", feeds="linear", n_feeds=2),
+    "copy-done": dict(scope="unscoped", moves=True, src="env['fc1_weight']",
+                      feeds="linear", n_feeds=2, opcode="copy-done",
+                      shape="f32[8,16]{1,0:T(8,128)S(1)}", bytes=512),
+    # a scoped reshape is a move all the same, and carries its producer's
+    "reshape.2": dict(scope="linear", moves=True, src="linear", feeds=None),
+    # a fusion of moves only; its producer has a scope, its consumer too
+    "fusion.5": dict(scope="unscoped", moves=True, src="linear",
+                     feeds="attn/scores", n_feeds=1),
+    "fusion.1": dict(scope="linear", moves=False, src=None, feeds=None),
+    # slice-start / slice-done: an async pair round a slice
+    "slice-start": dict(scope="unscoped", moves=True, src="linear",
+                        feeds="attn/scores"),
+    "slice-done": dict(scope="unscoped", moves=True, src="linear",
+                       feeds="attn/scores"),
+    # a parameter jax gave no name: its number and shape
+    "copy.3": dict(scope="unscoped", moves=True,
+                   src="parameter(1) f32[4,4]", feeds="attn/scores"),
+    # inside the while body: the loop's state is followed to the operand
+    # the loop was entered with, and on to the entry parameter
+    "copy.9": dict(scope="unscoped", moves=True, src="env['fc1_weight']",
+                   feeds="attn/kv_gather", n_feeds=1),
+    # what only the program's result reads
+    "copy.8": dict(scope="unscoped", moves=True, src="attn", feeds="output"),
+    "dot.6": dict(scope="attn/scores", moves=False),
+    "lt.2": dict(scope="unscoped", moves=False, src=None, feeds=None,
+                 n_feeds=0),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_HAND_WANT))
+def test_instruction_map_says_what_an_instruction_is(name):
+    from mxnet_tpu.obs import scopes
+
+    module, imap = scopes.instruction_map(_HAND_HLO)
+    assert module == "jit_hand"
+    assert set(imap[name]) == {"scope", "opcode", "shape", "bytes", "moves",
+                               "src", "feeds", "n_feeds"}
+    got = {k: imap[name][k] for k in _HAND_WANT[name]}
+    assert got == _HAND_WANT[name]
+    # the scope map is the same text's, instruction for instruction
+    assert {k: v["scope"] for k, v in imap.items()} \
+        == scopes.scope_map(_HAND_HLO)[1]
+
+
+def _count_compiles():
+    import jax
+
+    seen = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **_: seen.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    return seen
+
+
+_MAPPED_PROGRAMS = {
+    # program -> (builder, the module its map is keyed by)
+    "lm_train_step": (_lm_train_step, "jit_step"),
+    "paged_decode_step": (lambda: _served("paged_decode_step"),
+                          "jit__paged_decode_impl"),
+    "prefill_chunk": (lambda: _served("prefill_chunk"), "jit__chunk_impl"),
+}
+
+
+@pytest.mark.parametrize("program", sorted(_MAPPED_PROGRAMS))
+def test_instruction_maps_are_the_dispatched_programs(program, telemetry):
+    """``obs.programs.instruction_maps``: read off the executable the
+    program dispatches, with nothing compiled, and marked so."""
+    from mxnet_tpu.obs import scopes
+
+    telemetry(True)
+    obs.programs.reset(clear_static=True)
+    build, stem = _MAPPED_PROGRAMS[program]
+    owner, name = build()
+    compiles = _count_compiles()
+    maps = obs.programs.instruction_maps()
+    assert not compiles, "reading the maps compiled %d" % len(compiles)
+    entry = maps[stem]
+    assert entry["source"] == "dispatched" and entry["conflicts"] == 0
+    rows = entry["instructions"]
+    assert {k: v["scope"] for k, v in rows.items()} \
+        == obs.programs.scope_maps()[stem] == obs.programs.scope_map(name)
+    # every entry parameter is known by the name jax gave it
+    params = [v for v in rows.values() if v["opcode"] == "parameter"]
+    assert params
+    # a scopeless move at the top of the program names what it carries or
+    # what it feeds
+    loose = [v for v in rows.values() if v["scope"] == scopes.UNSCOPED
+             and v["moves"] and (v["src"] or v["feeds"])]
+    assert loose
+    vocabulary = set(scopes.LAYERS) | {scopes.OUTPUT, None} \
+        | {"attn/" + s for s in scopes.SUBSCOPES} | {"head_loss/sample"}
+    assert {v["feeds"] for v in rows.values()} <= vocabulary
+    del owner
+
+
+def test_a_map_read_by_compiling_says_relowered(telemetry):
+    """A reader whose text comes from a compile made while it is read
+    (another compile's ``fusion.N`` need not be the running program's) is
+    marked ``"relowered"``, not ``"dispatched"``."""
+    import jax
+    import jax.numpy as jnp
+
+    telemetry(True)
+    obs.programs.reset(clear_static=True)
+    x = jnp.ones((4, 4))
+    loaded = jax.jit(lambda a: a @ a + 1.0).lower(x).compile().as_text()
+
+    def fresh():
+        return jax.jit(lambda a: a @ a - 2.0).lower(x).compile().as_text()
+
+    obs.programs.register_hlo("held", lambda: loaded)
+    obs.programs.register_hlo("fresh", fresh)
+    assert obs.programs.scope_map("held") is not None
+    by_name = {r.name: r for r in obs.programs._hlo.values()}
+    obs.programs.instruction_maps()
+    assert by_name["held"].source == "dispatched"
+    assert by_name["fresh"].source == "relowered"
+    obs.programs.reset(clear_static=True)
+
+
+def test_two_predictors_keep_their_own_maps_and_count_the_conflict(
+        telemetry, caplog):
+    """Two live programs of one module name: ``scope_maps`` joins with the
+    newest registered whose owner lives, whole, and counts the instruction
+    names they disagree on; a collected owner's map no longer counts."""
+    import gc
+    import logging
+
+    from mxnet_tpu.analysis.programs import _LM, _lm_params, _lm_symbol
+    from mxnet_tpu.decode import DecodePredictor, DecodeServer
+
+    telemetry(True)
+    obs.programs.reset(clear_static=True)
+    rng = np.random.RandomState(3)
+
+    def serve(slots):
+        sym = _lm_symbol()
+        pred = DecodePredictor(sym, _lm_params(sym, slots, _LM["seq_len"]),
+                               cache_len=_LM["seq_len"], temperature=0.0,
+                               kv_dtype="int8", paged=True, page_tokens=4,
+                               prefill_chunk=4)
+        server = DecodeServer(pred, max_prefill=12, slots=slots,
+                              max_new_tokens=3, spec_k=0)
+        for n in (5, 7, 3):
+            server.submit(rng.randint(0, 32, size=(n,)))
+        assert len(server.run()) == 3
+        return pred
+
+    stem = "jit__paged_decode_impl"
+    first = serve(2)
+    alone = dict(obs.programs.scope_maps()[stem])
+    assert obs.programs.instruction_maps()[stem]["conflicts"] == 0
+    second = serve(3)
+    with caplog.at_level(logging.WARNING, logger="mxnet_tpu.obs.roofline"):
+        entry = obs.programs.instruction_maps()[stem]
+        obs.programs.instruction_maps()
+    # the newer predictor's program, whole: not the two merged
+    assert entry["conflicts"] > 0
+    newest = obs.programs.scope_maps()[stem]
+    assert newest == {k: v["scope"] for k, v in entry["instructions"].items()}
+    assert newest == obs.programs.scope_map("paged_decode_step")
+    shapes = {v["shape"] for v in entry["instructions"].values()}
+    assert any(s.startswith("s32[3]") for s in shapes) \
+        and not any(s.startswith("s32[2]{") for s in shapes)
+    # said once, not at every reading
+    said = [r for r in caplog.records if stem in r.getMessage()]
+    assert len(said) == 1
+    # the newer owner gone: the older one's map is the module's again
+    del second
+    gc.collect()
+    assert obs.programs.scope_maps()[stem] == alone
+    entry = obs.programs.instruction_maps()[stem]
+    assert entry["conflicts"] == 0 and any(
+        v["shape"].startswith("s32[2]{")
+        for v in entry["instructions"].values())
+    del first
+    obs.programs.reset(clear_static=True)
+
+
+@pytest.mark.parametrize("program,stem", [
+    ("slot_commit", "jit__commit_impl"), ("page_fork", "jit__fork_impl"),
+    ("keep_tok", "jit__keep_tok")])
+def test_programs_beside_the_steps_have_maps_after_a_serve(program, stem,
+                                                           telemetry):
+    """What the serving loop dispatches beside its steps (the commit of a
+    slot, a copy-on-write fork, the copy of a step's tokens) registers
+    its program too, read with nothing compiled; a program that never
+    ran is not compiled for its map."""
+    telemetry(True)
+    obs.programs.reset(clear_static=True)
+    pred, server = _paged_server()
+    rng = np.random.RandomState(5)
+    prefix = rng.randint(0, 32, size=(6,))
+    for n in (3, 2, 4, 3):
+        server.submit(np.concatenate([prefix, rng.randint(0, 32, (n,))]))
+    assert len(server.run()) == 4
+    assert pred.trace_counts["fork"] == 1 and pred.trace_counts["commit"] == 1
+    compiles = _count_compiles()
+    maps = obs.programs.instruction_maps()
+    assert not compiles
+    assert maps[stem]["source"] == "dispatched" and maps[stem]["instructions"]
+    assert obs.programs.scope_map(program) == obs.programs.scope_maps()[stem]
+    # never dispatched here: no map, and nothing compiled to make one
+    assert "jit__extract_impl" not in maps and "jit__install_impl" not in maps
+    assert obs.programs.scope_map("page_extract") is None
+    assert not compiles
+    obs.programs.reset(clear_static=True)
+
+
+# ---------------------------------------------------------------------------
 # host phase spans
 # ---------------------------------------------------------------------------
 def _inside(child, parent):
@@ -528,7 +837,7 @@ def test_serve_tick_spans_nest_and_share_the_request_id(telemetry):
         for e in (e for e in ev if e["name"] == prog):
             assert any(_inside(e, p) for p in parents), prog
     # one identifier per request, on every event of its life
-    for name in ("admit", "prefill_chunk", "serve.prefill", "serve.commit",
+    for name in ("admit", "serve.prefill", "serve.commit",
                  "retire", "request"):
         got = [e["args"]["rid"] for e in ev if e["name"] == name]
         assert got and set(got) == {rid}, (name, got)

@@ -201,7 +201,7 @@ def test_paged_server_shared_prefix_matches_dense():
 
     assert_chrome_trace(
         obs.timeline.export(),
-        required_names=("admit", "retire", "prefill_chunk", "prefill",
+        required_names=("admit", "retire", "serve.prefill", "prefill",
                         "paged_decode_step"))
 
 
