@@ -650,12 +650,14 @@ def live_block_case(shape, tq, share, seed=0, page_tokens=16):
     b = d["slots"] if tq == 1 else 1
     m = d["cap"] // page_tokens
     rng = np.random.RandomState(seed)
-    pools = [attn.QuantKV(
-        jnp.asarray(rng.randint(-127, 128, (b * m + 1, page_tokens,
-                                            d["kv_heads"] * w), np.int8)),
-        jnp.asarray(rng.uniform(0.005, 0.02, (b * m + 1, page_tokens,
-                                              d["kv_heads"])), jnp.float32))
-        for w in (d["hd"], d["hdv"])]
+    # a node's pools as ops.attention stores them: one scale plane, a
+    # page a row, beside the K data
+    data = [jnp.asarray(rng.randint(-127, 128, (b * m + 1, page_tokens,
+                                                d["kv_heads"] * w), np.int8))
+            for w in (d["hd"], d["hdv"])]
+    pools = [attn.QuantKV(data[0], jnp.asarray(rng.uniform(
+        0.005, 0.02, (b * m + 1, page_tokens * 2 * d["kv_heads"])),
+        jnp.float32)), attn.QuantKV(data[1], None)]
     table = jnp.asarray(rng.permutation(b * m).reshape(b, m) + 1, jnp.int32)
     q = jnp.asarray(rng.normal(size=(b, tq, d["heads"] * d["hd"])),
                     jnp.bfloat16)
@@ -669,7 +671,7 @@ def live_block_case(shape, tq, share, seed=0, page_tokens=16):
 
     def whole(q, kp, vp, table, total):
         return attn._sdpa_cache(
-            q, attn.paged_gather(kp, table), attn.paged_gather(vp, table),
+            q, *attn.paged_gather_kv(kp, vp, table),
             total, d["heads"], None, num_kv_heads=d["kv_heads"], **kw)
 
     def walk(block, group):

@@ -170,6 +170,19 @@ def _per_group(items):
     return items if len(items) > 1 else items[0]
 
 
+def _pool_pair(kc, vc, make):
+    """A node's two page pools from its probed K and V avals:
+    ``make(aval, is_scale=False)`` builds one plane.  Quantized pools keep
+    one scale plane between them, beside the K data
+    (``ops.attention.QuantKV``)."""
+    from .ops.attention import QuantKV
+
+    if isinstance(kc, QuantKV):
+        return (QuantKV(make(kc.data), make(kc.scale, is_scale=True)),
+                QuantKV(make(vc.data), None))
+    return make(kc), make(vc)
+
+
 class CacheLayout(NamedTuple):
     """What one stateful node keeps a slot, read off the node at bind
     time: ``kind`` ("full" | "window" | "state"), its KV heads, and the
@@ -611,12 +624,16 @@ class DecodePredictor:
         row index of a state group."""
         return group.capacity // self._page_tokens or 1
 
-    def _pool_shape(self, ai, aval, pages):
+    def _pool_shape(self, ai, aval, pages, is_scale=False):
         """Shape of node ``ai``'s pool (or state array) built from the
         probed batch-1 ``aval``: ``pages`` pages of ``page_tokens``
-        positions, or ``pages`` state rows."""
+        positions, or ``pages`` state rows.  The scale plane a node's two
+        quantized pools share (``is_scale``) is a row a page, (pages,
+        page_tokens * 2 * H_kv): ``ops.attention.QuantKV``."""
         if self._layouts[ai].kind == "state":
             return (pages,) + tuple(aval.shape[1:])
+        if is_scale:
+            return (pages, self._page_tokens * 2 * aval.shape[2])
         return (pages, self._page_tokens, aval.shape[2])
 
     # ------------------------------------------------------------------
@@ -887,14 +904,9 @@ class DecodePredictor:
                             # table a group
                             tbl = tables[self._group_of[ai]] \
                                 if isinstance(tables, tuple) else tables
-                            kc = _attn.paged_append(kc, tbl, k, pos0,
-                                                    num_heads=kv_heads,
-                                                    active=active, valid=valid,
-                                                    **at)
-                            vc = _attn.paged_append(vc, tbl, v, pos0,
-                                                    num_heads=kv_heads,
-                                                    active=active, valid=valid,
-                                                    **at)
+                            kc, vc = _attn.paged_append_kv(
+                                kc, vc, tbl, k, v, pos0, num_heads=kv_heads,
+                                active=active, valid=valid, **at)
                             outs = [_attn.paged_attend(
                                 q, kc, vc, tbl, pos + t, num_heads=heads,
                                 scale=scale, mesh_active=mesh_on,
@@ -1299,10 +1311,13 @@ class DecodePredictor:
                 lambda e, t: self._run(e, t, None, 0)[1], env, toks)
 
     def _place_pool(self, buf, is_scale=False):
-        """Mesh placement for a (P, page_tokens, E|H) pool: heads shard
-        on 'model' (``tp_rules.kv_pool_pspec``), page dim replicated; a
-        scale plane whose H does not divide the model axis replicates
-        (same degrade rule as the dense :meth:`_scale_sharding`)."""
+        """Mesh placement for a (P, page_tokens, E) pool: heads shard on
+        'model' (``tp_rules.kv_pool_pspec``), page dim replicated.  A
+        node's scale plane is (P, page_tokens * 2 * H_kv), a page a row
+        (``ops.attention.QuantKV``): a split of its trailing dimension
+        would cut tokens, not head groups, so under a mesh it replicates
+        (1/16 of the data planes' bytes at heads of 64), recorded beside
+        the trailing dims the model axis does not divide."""
         import jax
 
         from .ops.attention import apply_kv_layout
@@ -1319,13 +1334,20 @@ class DecodePredictor:
         spec = kv_pool_pspec(self._mesh.shape,
                              num_kv_heads=self._grouped_kv_heads,
                              degrades=self._replicated_degrades)
-        if spec[2] is not None and \
-                buf.shape[2] % dict(self._mesh.shape)[spec[2]] != 0:
+        axis = spec[2]
+        if is_scale:
+            if axis is not None:
+                self._replicated_degrades.append({
+                    "site": "pool-scale",
+                    "reason": "a row of %d is tokens x heads: no split of "
+                    "it on %s is a head-group split" % (buf.shape[1], axis)})
+            spec = P(None, None)
+        elif axis is not None and \
+                buf.shape[2] % dict(self._mesh.shape)[axis] != 0:
             self._replicated_degrades.append({
-                "site": "pool-scale" if is_scale else "pool",
+                "site": "pool",
                 "reason": "trailing dim %d %% %s=%d != 0"
-                % (buf.shape[2], spec[2],
-                   dict(self._mesh.shape)[spec[2]])})
+                % (buf.shape[2], axis, dict(self._mesh.shape)[axis])})
             spec = P(None, None, None)
         return jax.device_put(buf, NamedSharding(self._mesh, spec))
 
@@ -1338,7 +1360,6 @@ class DecodePredictor:
         import jax
         import jax.numpy as jnp
 
-        from .ops.attention import QuantKV
         from .serve import GroupedKVManager, PagedKVManager
 
         if len(self._groups) > 1:
@@ -1357,7 +1378,6 @@ class DecodePredictor:
                 name=group.name)
         if self._pools_template is None:
             self._pools_template = self._probe_cache_shapes()
-        pt = self._page_tokens
 
         pools = []
         for ai, (kc, vc) in enumerate(self._pools_template):
@@ -1372,17 +1392,10 @@ class DecodePredictor:
 
             def pool_of(aval, is_scale=False):
                 return self._place_pool(
-                    jnp.zeros((pp, pt, aval.shape[2]), aval.dtype),
-                    is_scale=is_scale)
+                    jnp.zeros(self._pool_shape(ai, aval, pp, is_scale),
+                              aval.dtype), is_scale=is_scale)
 
-            pair = []
-            for aval in (kc, vc):
-                if isinstance(aval, QuantKV):
-                    pair.append(QuantKV(pool_of(aval.data),
-                                        pool_of(aval.scale, is_scale=True)))
-                else:
-                    pair.append(pool_of(aval))
-            pools.append(tuple(pair))
+            pools.append(_pool_pair(kc, vc, pool_of))
         self._paged_lens = np.zeros(slots, np.int64)
         return DecodeState(tuple(pools), jnp.zeros((slots,), jnp.int32),
                            jnp.zeros((slots, 1), jnp.int32))
@@ -1399,6 +1412,8 @@ class DecodePredictor:
             raise MXNetError("pool_bytes before any paged prefill/serve")
         if self._pools_template is None:
             self._pools_template = self._probe_cache_shapes()
+        # a node's scale plane holds what a (pages, page_tokens, H) plane a
+        # pool would: the template's two
         return sum(shape_bytes(shape_str(
             self._pool_shape(ai, aval, self._pool_pages_of(ai)), aval.dtype))
             for ai, pair in enumerate(self._pools_template)
@@ -1460,7 +1475,6 @@ class DecodePredictor:
         import jax.numpy as jnp
 
         from .analysis.artifact import aval_of
-        from .ops.attention import QuantKV
         from .serve.manager import PagedKVManager
 
         if not self._paged:
@@ -1481,19 +1495,13 @@ class DecodePredictor:
         def build(shape_of):
             pools = []
             for ai, (kc, vc) in enumerate(self._pools_template):
-                pair = []
-                for aval in (kc, vc):
-                    if isinstance(aval, QuantKV):
-                        pair.append(QuantKV(
-                            sds(shape_of(ai, aval.data), aval.data.dtype),
-                            sds(shape_of(ai, aval.scale), aval.scale.dtype)))
-                    else:
-                        pair.append(sds(shape_of(ai, aval), aval.dtype))
-                pools.append(tuple(pair))
+                pools.append(_pool_pair(
+                    kc, vc, lambda a, is_scale=False, ai=ai: sds(
+                        shape_of(ai, a, is_scale), a.dtype)))
             return tuple(pools)
 
-        caches = build(lambda ai, a: self._pool_shape(
-            ai, a, pps[self._group_of[ai]]))
+        caches = build(lambda ai, a, is_scale=False: self._pool_shape(
+            ai, a, pps[self._group_of[ai]], is_scale))
         env = {n: aval_of(v) for n, v in self._env.items()}
         lens = sds((slots,), jnp.int32)
         tok = sds((slots, 1), jnp.int32)
@@ -1519,7 +1527,8 @@ class DecodePredictor:
             # are programs of a graph with one group
             row = sds((m,), jnp.int32)
             # one slot's extracted pages: the pool gathered at an (M,) row
-            data = build(lambda ai, a: (m, pt, a.shape[2]))
+            data = build(lambda ai, a, is_scale=False:
+                         self._pool_shape(ai, a, m, is_scale))
             out.update({"fork": (caches, i32, i32),
                         "extract": (caches, row),
                         "install": (caches, row, data)})

@@ -239,6 +239,20 @@ class QuantKV(NamedTuple):
     leaves donate/shard independently: ``data`` follows
     ``tp_rules.kv_cache_pspec``; ``scale``'s trailing head dim shards the
     same way, an H-split IS the same head-group split).
+
+    The two PAGED pools of a node (``data`` (P, page_tokens, E)) keep
+    their scales in ONE plane, the K pool's ``scale``, (P, page_tokens *
+    2 * H): a page is one row, token-major, a token's 2 * H floats K's H
+    then V's H; the V pool's ``scale`` is None.  A plane (P, page_tokens,
+    H) has a minor dimension of 4-32 heads, which fills no lane tile:
+    XLA:TPU gave it one layout for the append's scatter, another for the
+    products and a third as a parameter, and converted the whole plane
+    between them in every serving program, since layout assignment
+    carries a consumer's preference back through a gather to its
+    operand.  A row of 128-1024 lanes is taken as it lies by the append
+    and by the gather (:func:`paged_append_kv`, :func:`paged_gather_kv`),
+    which read and write whole rows, and one gather serves both pools.
+    The dense ring's plane stays (B, C, H).
     """
 
     data: object
@@ -597,30 +611,116 @@ def _plane(pool):
     return pool.data if isinstance(pool, QuantKV) else pool
 
 
+def _latest(new, start_pos, capacity):
+    """``(new, start (B,), t)`` with ``new`` (B, t, E) cut to the latest
+    ``capacity`` tokens, the only ones that can land in a ring of that
+    many (same trim as :func:`cache_append`)."""
+    import jax.numpy as jnp
+
+    b, t = new.shape[0], new.shape[1]
+    start = jnp.broadcast_to(jnp.asarray(start_pos, jnp.int32).reshape(-1),
+                             (b,))
+    if t > capacity:
+        new = new[:, -capacity:]
+        start = start + (t - capacity)
+        t = capacity
+    return new, start, t
+
+
+def _append_scales(plane, table, new, start, active, valid):
+    """Write ``new`` (B, t, W) per-token scales into a scale plane (P,
+    page_tokens * W), a page a row, at ring positions [start, start + t)
+    of each slot's table; ``active`` and ``valid`` as in
+    :func:`paged_append`.
+
+    XLA:TPU scatters whole rows as one native scatter and expands a
+    window narrower than the row into a loop of one update a token (12 ms
+    for a 256-token chunk into 12 planes, my chip run, PR 42), so a
+    token's scales are not scattered: the rows the tokens fall in are
+    read, the tokens' scales put in their places by a select on those few
+    rows, and the rows written back whole; a row no token lands in is
+    written to the scratch page.  A row is touched by one slot (the
+    caller owns every page it really writes), so nothing is lost between
+    the read and the write."""
+    import jax.numpy as jnp
+
+    b, t, w = new.shape
+    m = table.shape[1]
+    pt = plane.shape[1] // w
+    n = min((t + pt - 2) // pt + 1, m)        # pages t tokens can touch
+    first = (start // pt)[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
+    page = jnp.take_along_axis(table.astype(jnp.int32), first % m, axis=1)
+    # position j of a slot's n pages takes token tok[j] where ok[j]
+    tok = jnp.arange(n * pt, dtype=jnp.int32)[None, :] \
+        - (start % pt)[:, None]
+    if n == m:
+        # the pages are the whole ring: what runs past its end lands at
+        # its start
+        tok = tok % (m * pt)
+    ok = (tok >= 0) & (tok < t)
+    if active is not None:
+        ok &= jnp.asarray(active).reshape(-1, 1).astype(bool)
+    if valid is not None:
+        ok &= tok < jnp.asarray(valid, jnp.int32).reshape(-1, 1)
+    page = jnp.where(jnp.any(ok.reshape(b, n, pt), axis=2), page, 0)
+    got = jnp.take_along_axis(new.astype(plane.dtype),
+                              jnp.clip(tok, 0, t - 1)[:, :, None], axis=1)
+    rows = jnp.where(ok[:, :, None], got,       # (B, n * pt, W)
+                     plane[page].reshape(got.shape))
+    return plane.at[page.reshape(-1)].set(rows.reshape(-1, pt * w))
+
+
 def paged_gather(pool, table):
     """Gather a per-slot dense-ring view out of the shared page pool.
 
-    ``pool`` is (P, page_tokens, E) (or :class:`QuantKV` of pools);
-    ``table`` is (B, M) int32 page ids.  Returns the (B, M*page_tokens, E)
-    view whose index ``v`` holds the slot's position ``p`` with
-    ``v == p % (M*page_tokens)`` — the dense ring layout, so the cached
-    attention kernels mask it exactly like a ring buffer.  Unmapped table
-    entries (id 0, the scratch page) gather garbage into slots the length
-    mask already hides.  ``table`` may be any rows of page ids:
-    :func:`_attend_live_blocks` hands it one block's pages a row, and gets
-    the blocks' views."""
-    if isinstance(pool, QuantKV):
-        return QuantKV(paged_gather(pool.data, table),
-                       paged_gather(pool.scale, table))
+    ``pool`` is (P, page_tokens, E); ``table`` is (B, M) int32 page ids.
+    Returns the (B, M*page_tokens, E) view whose index ``v`` holds the
+    slot's position ``p`` with ``v == p % (M*page_tokens)`` — the dense
+    ring layout, so the cached attention kernels mask it exactly like a
+    ring buffer.  Unmapped table entries (id 0, the scratch page) gather
+    garbage into slots the length mask already hides.  ``table`` may be
+    any rows of page ids: :func:`_attend_live_blocks` hands it one block's
+    pages a row, and gets the blocks' views.  A node's pools, quantized or
+    not, are gathered as a pair: :func:`paged_gather_kv`."""
     b, m = table.shape
     pages = pool[table]                       # (B, M, page_tokens, E)
     return pages.reshape(b, m * pool.shape[1], pool.shape[2])
 
 
-def paged_append(pool, table, new, start_pos, num_heads=1, active=None,
-                 valid=None, layer="attn"):
-    """Scatter ``new`` (B, t, E) into the page pool at ring positions
-    [start_pos, start_pos + t) of each slot's page table.
+def paged_gather_kv(k_pool, v_pool, table):
+    """:func:`paged_gather` of a node's K and V pools: ``(k_view,
+    v_view)``, each what a dense ring of the table's capacity holds.
+
+    Quantized pools (:class:`QuantKV`) keep both pools' scales in one
+    plane, ``k_pool.scale`` (P, page_tokens * 2 * H), a page a row: whole
+    rows are taken by ONE gather for the two, and only what was gathered
+    is reshaped and split into the (B, M*page_tokens, H) planes of two
+    dense rings.  The transposition that puts positions on the lanes for
+    the products (:func:`_sdpa_cache`) is paid on the view, a few hundred
+    kilobytes, and the pool is read as it lies."""
+    import jax.numpy as jnp
+
+    if not isinstance(k_pool, QuantKV):
+        return paged_gather(k_pool, table), paged_gather(v_pool, table)
+    k_view = paged_gather(k_pool.data, table)
+    rows = k_pool.scale[table]                # (B, M, page_tokens * 2 * H)
+    # positions onto the lanes once, for both pools; each half goes back
+    # to the (B, C, H) a dense ring's plane has, which ``_sdpa_cache``
+    # turns again: XLA drops the pair, and the products read the halves.
+    # (Split first, (B, C, 2, H), and turned a pool: tiles of 2 x 128 with
+    # H lanes filled, 2.0 ms of an OPT tick more; my chip runs, PR 42)
+    both = jnp.swapaxes(rows.reshape(k_view.shape[:2] + (-1,)), 1, 2)
+    h = both.shape[1] // 2
+    return (QuantKV(k_view, jnp.swapaxes(both[:, :h], 1, 2)),
+            QuantKV(paged_gather(v_pool.data, table),
+                    jnp.swapaxes(both[:, h:], 1, 2)))
+
+
+def paged_append(pool, table, new, start_pos, active=None, valid=None,
+                 layer="attn"):
+    """Scatter ``new`` (B, t, E) into the page pool (P, page_tokens, E)
+    at ring positions [start_pos, start_pos + t) of each slot's page
+    table.
 
     ``start_pos`` — scalar or (B,) tokens already appended per slot.
     ``active`` — optional (B,) 0/1 mask: rows with 0 (empty or mid-prefill
@@ -628,34 +728,22 @@ def paged_append(pool, table, new, start_pos, num_heads=1, active=None,
     scratch page instead of touching real pages.  ``valid`` — optional (B,)
     count of REAL rows within ``new``'s width (a padded final prefill
     chunk): positions >= valid are redirected too, so pad garbage is never
-    written at all.  A :class:`QuantKV` pool quantizes on the way in, both
-    planes at the same slots.  The caller (serve.PagedKVManager) guarantees
+    written at all.  The caller (serve.PagedKVManager) guarantees
     every really-written page is exclusively owned — copy-on-write forks
     shared pages BEFORE the step — so scatter indices never collide except
-    on the scratch page, whose contents are never read unmasked.
+    on the scratch page, whose contents are never read unmasked.  A
+    node's pools, quantized or not, are appended as a pair:
+    :func:`paged_append_kv`.
     """
     import jax.numpy as jnp
 
     with _scope(layer, "kv_append"):
-        if isinstance(pool, QuantKV):
-            qnew = quantize_kv(new, pool.data.dtype, num_heads)
-            return QuantKV(
-                paged_append(pool.data, table, qnew.data, start_pos,
-                             active=active, valid=valid, layer=layer),
-                paged_append(pool.scale, table, qnew.scale, start_pos,
-                             active=active, valid=valid, layer=layer))
-        b, t = new.shape[0], new.shape[1]
+        new, start, t = _latest(new, start_pos, table.shape[1]
+                                * pool.shape[1])
+        b = new.shape[0]
         m = table.shape[1]
         pt = pool.shape[1]
-        c = m * pt
-        start = jnp.broadcast_to(jnp.asarray(start_pos, jnp.int32).reshape(-1),
-                                 (b,))
         new = new.astype(pool.dtype)
-        if t > c:
-            # only the latest C tokens can land (same trim as cache_append)
-            new = new[:, -c:]
-            start = start + (t - c)
-            t = c
         # (B, t)
         pos = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
         page = jnp.take_along_axis(table.astype(jnp.int32), (pos // pt) % m,
@@ -672,16 +760,76 @@ def paged_append(pool, table, new, start_pos, num_heads=1, active=None,
             new.reshape(b * t, -1))
 
 
+def paged_append_kv(k_pool, v_pool, table, k, v, start_pos, num_heads=1,
+                    active=None, valid=None, layer="attn"):
+    """:func:`paged_append` of a node's new ``k`` and ``v`` (B, t, E_k /
+    E_v) into its two pools: ``(k_pool, v_pool)``.
+
+    :class:`QuantKV` pools quantize on the way in (per (token, head):
+    pass ``num_heads``, the K/V head count), the data planes at the same
+    slots; a token's scales, K's H then V's H, are one stretch of its
+    page's row in the one scale plane the pair shares
+    (:func:`_append_scales`).  No op here or in :func:`paged_gather_kv`
+    reshapes, transposes or slices a POOL: that is what would let layout
+    assignment carry a consumer's preferred layout back through a gather
+    and convert all of it."""
+    import jax.numpy as jnp
+
+    if not isinstance(k_pool, QuantKV):
+        return (paged_append(k_pool, table, k, start_pos, active=active,
+                             valid=valid, layer=layer),
+                paged_append(v_pool, table, v, start_pos, active=active,
+                             valid=valid, layer=layer))
+    with _scope(layer, "kv_append"):
+        qk = quantize_kv(k, k_pool.data.dtype, num_heads)
+        qv = quantize_kv(v, v_pool.data.dtype, num_heads)
+        k_data, v_data = (
+            paged_append(pool.data, table, q.data, start_pos, active=active,
+                         valid=valid, layer=layer)
+            for pool, q in ((k_pool, qk), (v_pool, qv)))
+        pt = k_pool.data.shape[1]
+        scales, start, _ = _latest(
+            jnp.concatenate([qk.scale, qv.scale], axis=-1), start_pos,
+            table.shape[1] * pt)
+        return (QuantKV(k_data, _append_scales(
+            k_pool.scale, table, scales, start, active, valid)),
+            QuantKV(v_data, None))
+
+
+def quantize_pools(k, v, dtype, num_heads=1):
+    """(P, page_tokens, E_k / E_v) float pages of a node's K and V -> its
+    quantized pools as the paged ops store them: ``(QuantKV(k data, the
+    scales of both), QuantKV(v data, None))``."""
+    import jax.numpy as jnp
+
+    qk = quantize_kv(k, dtype, num_heads)
+    qv = quantize_kv(v, dtype, num_heads)
+    scales = jnp.concatenate([qk.scale, qv.scale], axis=-1)
+    return (QuantKV(qk.data, scales.reshape(k.shape[0], -1)),
+            QuantKV(qv.data, None))
+
+
 def paged_copy(pool, src, dst):
     """Copy page ``src`` -> page ``dst`` (traced scalar ids) in one pool —
     the device half of a copy-on-write fork: the host allocator picks
     ``dst``, this kernel duplicates the shared page, and the forking slot's
-    next append diverges in its own copy.  :class:`QuantKV` pools copy both
-    planes."""
-    if isinstance(pool, QuantKV):
-        return QuantKV(paged_copy(pool.data, src, dst),
-                       paged_copy(pool.scale, src, dst))
-    return pool.at[dst].set(pool[src])
+    next append diverges in its own copy.  :class:`QuantKV` pools copy
+    every plane they hold."""
+    import jax.tree_util as jtu
+
+    return jtu.tree_map(lambda plane: plane.at[dst].set(plane[src]), pool)
+
+
+def _kernel_pools(k_pool, v_pool):
+    """A node's pools as :mod:`~mxnet_tpu.ops.pallas_decode` reads them,
+    each with a (P, page_tokens, H) scale plane of its own: the shared
+    plane's rows are split at the kernel's door."""
+    if not isinstance(k_pool, QuantKV):
+        return k_pool, v_pool
+    p, pt = k_pool.data.shape[:2]
+    scales = k_pool.scale.reshape(p, pt, 2, -1)
+    return (QuantKV(k_pool.data, scales[:, :, 0]),
+            QuantKV(v_pool.data, scales[:, :, 1]))
 
 
 # Which path the last dot_product_attention dispatch traced: "flash",
@@ -905,8 +1053,7 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         take = lambda x: jax.lax.dynamic_slice_in_dim(x, at, group)
         rows_of, ids = take(slot), take(pages)
         with _scope(layer, "kv_gather"):
-            k_blk = paged_gather(k_pool, ids)
-            v_blk = paged_gather(v_pool, ids)
+            k_blk, v_blk = paged_gather_kv(k_pool, v_pool, ids)
         got = _sdpa_cache(
             jnp.broadcast_to(q, (group,) + q.shape[1:]) if running
             else q[rows_of], k_blk, v_blk, total[rows_of], num_heads, scale,
@@ -976,12 +1123,13 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
     if engage and not mesh_active and not extra:
         from . import pallas_decode as _pd
 
-        if _pd.supported(q.shape, k_pool, v_pool, table.shape, num_heads,
+        k_kern, v_kern = _kernel_pools(k_pool, v_pool)
+        if _pd.supported(q.shape, k_kern, v_kern, table.shape, num_heads,
                          interpret=interp, num_kv_heads=num_kv_heads):
             DECODE_PATH["last"] = "pallas"
             fn = _pd.flash_sdpa_decode if q.shape[1] == 1 \
                 else _pd.flash_sdpa_verify
-            return fn(q, k_pool, v_pool, table, total_len,
+            return fn(q, k_kern, v_kern, table, total_len,
                       num_heads=num_heads, scale=scale, interpret=interp,
                       num_kv_heads=num_kv_heads)
         DECODE_PATH["last"] = "einsum-gated"
@@ -995,8 +1143,7 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
                                    sink=sink, value_scale=value_scale,
                                    layer=layer)
     with _scope(layer, "kv_gather"):
-        k_view = paged_gather(k_pool, table)
-        v_view = paged_gather(v_pool, table)
+        k_view, v_view = paged_gather_kv(k_pool, v_pool, table)
     return _sdpa_cache(q, k_view, v_view, total_len, num_heads, scale,
                        num_kv_heads=num_kv_heads, mesh_active=mesh_active,
                        **extra)

@@ -17,8 +17,10 @@ These kernels implement the two fixes the literature names, together:
   pool BlockSpec's index map reads it — ``(table[b, m], 0, h)`` — so each
   grid step DMAs one page's one head-slice straight from the pool.  No
   gathered view, no dequantized copy: int8/fp8 pages dequantize in VMEM
-  (per-(token, head) scales, the ``QuantKV`` layout) on their way into
-  the score matmul.
+  (per-(token, head) scales, a (P, page_tokens, H) plane a pool: the
+  paged pools' shared plane of rows is split into two such at this
+  kernel's door, ``ops.attention._kernel_pools``) on their way into the
+  score matmul.
 * **Flash-Decoding** (Dao et al., 2023): the grid parallelizes over the
   CACHE-LENGTH axis, not just (batch, head).  At decode (tq=1) with
   batch = serving slots, a (B, H) grid strands the chip when B*H is

@@ -803,9 +803,12 @@ def test_sample_tokens_greedy_bypass_is_key_independent():
 # conftest (the text of a jaxpr depends on jax's configuration).  A
 # PR that means to change one of these programs replaces its hash, and says
 # so; one that does not has changed what every accepted serving cell runs.
+# PR 42 replaced the three paged int8 programs' (a node's pools keep their
+# scales in one plane, read and written by rows); the dense prefill and the
+# float pools' programs are the ones of b2cc690.
 PLAIN_PROGRAMS = {
-    "mha_int8": {"chunk": "2a106cba2448de0b", "decode": "83681617abb6a64f",
-                 "verify": "07f073f9fe1d82b6", "prefill": "88b686fb4db16476"},
+    "mha_int8": {"chunk": "6df5d92584f6a4d6", "decode": "ae3f5eda93fe8a7a",
+                 "verify": "844c2c86a9ae31ae", "prefill": "88b686fb4db16476"},
     "gqa_float": {"chunk": "b3c4b15365228b7f", "decode": "e11356bce6bbbc2d",
                   "verify": "88735220e64c4515", "prefill": "ecb1cf4999e7e7a2"},
 }
@@ -872,11 +875,65 @@ def test_plain_graph_builds_one_group_with_todays_page_count():
     assert mgr.prefix_cache is not None
     assert mgr.tables.shape == (4, 8)
     assert state.moe is None and len(jax.tree_util.tree_leaves(state)) \
-        == 2 * 2 * 2 + 2            # 2 nodes x (k, v) x (data, scale)
+        == 2 * 3 + 2        # 2 nodes x (k data, v data, the scales of both)
     for (kc, vc) in state.caches:
-        assert kc.data.shape == (33, 4, 32) and kc.scale.shape == (33, 4, 4)
+        # one scale plane a node, a page a row: page_tokens x (k, v) x heads
+        assert kc.data.shape == vc.data.shape == (33, 4, 32)
+        assert kc.scale.shape == (33, 4 * 2 * 4) and vc.scale is None
     assert isinstance(paged._tables_of(mgr), jax.Array)
     assert paged.pool_bytes() == 2 * 2 * (33 * 4 * 32 + 33 * 4 * 4 * 4)
+
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+@pytest.mark.parametrize("kind", ["decode", "chunk", "verify"])
+def test_no_serving_program_moves_a_whole_scale_plane(kind):
+    """A count, not a time: nothing in a paged int8 program reshapes,
+    transposes, slices or copies an array as large as a node's scale plane.
+    A reshape of a POOL is what lets XLA:TPU's layout assignment carry a
+    consumer's layout back through the gather and convert the whole plane in
+    every program (PERF.md, PR 42): the plane is only gathered from, by
+    rows, and scattered into, by rows."""
+    from mxnet_tpu.obs.scopes import instruction_map
+    from mxnet_tpu.programs.spec import probing
+
+    paged, _ = _plain_predictors("int8", 0)
+    avals = paged.serving_avals(4, chunk_w=8, spec_k=3)[kind]
+    plane = avals[1].caches[0][0].scale if kind != "chunk" \
+        else avals[1][0][0].scale
+    assert plane.shape == (33, 32)
+    count = int(np.prod(plane.shape))
+    fn = paged._aot_dispatches()[kind].fn
+    with probing(paged):
+        jaxpr = jax.make_jaxpr(fn)(*avals)
+        text = fn.lower(*avals).compile().as_text()
+    # as traced: the plane goes into gathers, scatters and the loops and
+    # calls that hold them, and nowhere else
+    takes = {"gather", "scatter", "while", "pjit", "jit", "cond",
+             "custom_jvp_call", "closed_call"}
+    for eqn in _eqns(jaxpr.jaxpr):
+        for v in eqn.invars:
+            shape = getattr(getattr(v, "aval", None), "shape", None)
+            if shape == plane.shape and v.aval.dtype == plane.dtype:
+                assert eqn.primitive.name in takes, eqn
+    # as compiled (here for the CPU): no instruction that only moves data
+    # gives out an array of the plane's size
+    _, rows = instruction_map(text)
+    moved = [(name, r["opcode"], r["shape"]) for name, r in rows.items()
+             if r["moves"] and r["shape"].startswith("f32[")
+             and "(" not in r["shape"]
+             and int(np.prod([int(d) for d in r["shape"][4:].split("]")[0]
+                              .split(",") if d])) == count]
+    assert not moved, moved
 
 
 def test_plain_ops_trace_the_training_jaxprs_they_traced_before():

@@ -333,18 +333,21 @@ def test_health_grace_hysteresis():
 # ---------------------------------------------------------------------------
 # swap-out / readmit bit parity (single host)
 # ---------------------------------------------------------------------------
-def test_swap_out_readmit_bit_parity():
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["float", "int8"])
+def test_swap_out_readmit_bit_parity(kv_dtype):
     """A tight pool plus the fair-admission bound preempts the
     low-priority long decode; its readmission restores the pages
     bit-exactly (asserted inside the restore under _verify_restore),
     the final tokens equal the never-preempted reference, the model
-    parameters are untouched, and every page drains at the end."""
+    parameters are untouched, and every page drains at the end.  With
+    int8 pools the pages carry the node's one scale plane beside the K
+    data (the V pool's ``scale`` is None: the trees skip it)."""
     sym, params = _lm_and_params(seed=3)
     rng = np.random.RandomState(3)
     T2 = 16
     long_p = rng.randint(0, VOCAB, (6,))
     short_p = rng.randint(0, VOCAB, (5,))
-    ref_pred = DecodePredictor(sym, params, cache_len=T2)
+    ref_pred = DecodePredictor(sym, params, cache_len=T2, kv_dtype=kv_dtype)
     ref_long = ref_pred.generate(long_p[None].astype(np.float32), 6,
                                  max_new_tokens=24, seed=0)[0]
     ref_short = ref_pred.generate(short_p[None].astype(np.float32), 5,
@@ -353,7 +356,7 @@ def test_swap_out_readmit_bit_parity():
     with _cfg.overrides(MXNET_FLEET_DECODE_BOUND="4",
                         MXNET_FLEET_SWAP="1"):
         pred = _mk_pred(sym, params, cache_len=T2, pool_pages=6,
-                        prefix_cache=False)
+                        prefix_cache=False, kv_dtype=kv_dtype)
         srv = DecodeServer(pred, max_prefill=8, slots=2,
                            max_new_tokens=24)
         srv._verify_restore = True

@@ -37,17 +37,14 @@ RAGGED = [0, 1, BLOCK, BLOCK + 1, CAP, CAP + 9, 40, 33]
 
 
 def _pools(rng, pages, kvh, hd, hdv, kv_dtype, pt=PT):
-    out = []
-    for width in (hd, hdv):
-        x = jnp.asarray(rng.normal(size=(pages, pt, kvh * width)),
-                        jnp.float32)
-        out.append(attn.quantize_kv(x, kv_dtype, kvh) if kv_dtype else x)
-    return out
+    k, v = (jnp.asarray(rng.normal(size=(pages, pt, kvh * width)),
+                        jnp.float32) for width in (hd, hdv))
+    return attn.quantize_pools(k, v, kv_dtype, kvh) if kv_dtype else (k, v)
 
 
 def _whole_view(q, kp, vp, table, total, heads, kvh, sink, value_scale):
     return attn._sdpa_cache(
-        q, attn.paged_gather(kp, table), attn.paged_gather(vp, table),
+        q, *attn.paged_gather_kv(kp, vp, table),
         total, heads, None, num_kv_heads=kvh,
         **attn._extras(0, sink, value_scale, "attn"))
 
@@ -76,9 +73,9 @@ def test_live_blocks_match_the_whole_view(layout, kv_dtype, query):
     if query == "chunk":
         new = [jnp.asarray(rng.normal(size=(b, tq, kvh * w)), jnp.float32)
                for w in (hd, hdv)]
-        kp, vp = (attn.paged_append(pool, table, x, pos0, num_heads=kvh,
-                                    valid=jnp.asarray([nvalid]))
-                  for pool, x in zip((kp, vp), new))
+        kp, vp = attn.paged_append_kv(kp, vp, table, *new, pos0,
+                                      num_heads=kvh,
+                                      valid=jnp.asarray([nvalid]))
     total = jnp.asarray(total, jnp.int32)
     want = _whole_view(q, kp, vp, table, total, heads, kvh, sink,
                        value_scale)
@@ -120,7 +117,7 @@ def _attend_jaxpr(cap, **kw):
         *a, num_heads=heads, **kw))(q, kp, vp, table, total)
     extra = attn._extras(kw.get("window", 0), None, 1.0, "attn")
     whole = jax.make_jaxpr(lambda q, kp, vp, table, total: attn._sdpa_cache(
-        q, attn.paged_gather(kp, table), attn.paged_gather(vp, table), total,
+        q, *attn.paged_gather_kv(kp, vp, table), total,
         heads, None, num_kv_heads=0,
         mesh_active=kw.get("mesh_active", False), **extra))(
             q, kp, vp, table, total)

@@ -109,18 +109,25 @@ def test_page_recycle_matches_ring_wrap():
                                       np.asarray(ds.tok))
 
 
-def test_cow_fork_no_crosstalk():
+@pytest.mark.parametrize("kv_dtype", ["", "int8"], ids=["float", "int8"])
+def test_cow_fork_no_crosstalk(kv_dtype):
     """Two slots sharing a prefix diverge without cross-talk: identical
     prompts map the same pages (prefix cache), teacher-forcing different
     next tokens forks the shared partial page, and both rows' outputs
-    match independent dense rows."""
+    match independent dense rows.  With int8 pools the fork copies the
+    page's row of the node's one scale plane, and the append that follows
+    reads that row, replaces one token's scales and writes it back."""
     sym, params = _lm_and_params()
+    # a dense int8 prefill attends float K/V and the paged one the chunks it
+    # has quantized: 2e-5 apart, before and after PR 42 to the last digit
+    tol = dict(rtol=1e-3, atol=1e-4) if kv_dtype else \
+        dict(rtol=1e-5, atol=1e-6)
     rng = np.random.RandomState(4)
     same = rng.randint(0, VOCAB, (6,))
     xb = np.stack([same, same]).astype(np.float32)
 
     paged = DecodePredictor(sym, params, cache_len=T, paged=True,
-                            page_tokens=4)
+                            page_tokens=4, kv_dtype=kv_dtype)
     ps, _ = paged.prefill(xb, 6)
     # row 1 matched row 0's published pages (shared, refcounted)
     mgr = paged._manager
@@ -130,18 +137,16 @@ def test_cow_fork_no_crosstalk():
     ps, pp = paged.step(ps)
     assert mgr.allocator.forks > 0        # the divergent write forked
 
-    dense = DecodePredictor(sym, params, cache_len=T)
+    dense = DecodePredictor(sym, params, cache_len=T, kv_dtype=kv_dtype)
     ds, _ = dense.prefill(xb, 6)
     ds = ds._replace(tok=jnp.asarray([[1], [2]], jnp.int32))
     ds, dp = dense.step(ds)
-    np.testing.assert_allclose(np.asarray(pp), np.asarray(dp),
-                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(np.asarray(pp), np.asarray(dp), **tol)
     # a few more steps: the forked slots keep decoding independently
     for _ in range(2):
         ds, dp = dense.step(ds)
         ps, pp = paged.step(ps)
-        np.testing.assert_allclose(np.asarray(pp), np.asarray(dp),
-                                   rtol=1e-5, atol=1e-6)
+        np.testing.assert_allclose(np.asarray(pp), np.asarray(dp), **tol)
 
     # retirement: dropping every slot leaves only prefix-cache-held pages
     for s in range(mgr.slots):
@@ -708,3 +713,130 @@ def test_cache_bytes_pass_understands_paged_layouts():
     assert ("decode_step", "dense-under-paged") in codes
     assert any(f.severity == "error" for f in report.findings
                if f.code == "dense-under-paged")
+
+
+@pytest.mark.parametrize("t", [1, 8], ids=["step", "chunk"])
+@pytest.mark.parametrize("kvh", [4, 8, 32])
+def test_quantized_pools_keep_what_a_plane_a_pool_kept(kvh, t):
+    """``paged_append_kv`` then ``paged_gather_kv`` over a node's int8 pools,
+    whose scales share one plane (P, page_tokens * 2 * H), return element
+    for element what a (P, page_tokens, H) plane a pool returned: the
+    expectation is built by plain numpy indexing.  A step of one token a
+    slot with an inactive row, and a chunk whose last rows lie past
+    ``valid`` and that starts inside a page: the scratch page alone takes
+    the masked writes."""
+    from mxnet_tpu.ops import attention as attn
+
+    pt, m, hd = 4, 6, 8
+    b = 3 if t == 1 else 1
+    pages = 1 + b * m
+    rng = np.random.RandomState(kvh + t)
+    old = [rng.normal(size=(pages, pt, kvh * hd)).astype(np.float32)
+           for _ in range(2)]
+    kp, vp = attn.quantize_pools(*map(jnp.asarray, old), "int8", kvh)
+    assert kp.scale.shape == (pages, pt * 2 * kvh) and vp.scale is None
+    table = 1 + rng.permutation(b * m).reshape(b, m).astype(np.int32)
+    new = [rng.normal(size=(b, t, kvh * hd)).astype(np.float32)
+           for _ in range(2)]
+    start = np.asarray([5, 0, 22][:b], np.int32)     # 22: wraps the ring
+    active = np.asarray([1, 0, 1][:b], np.int32)
+    valid = None if t == 1 else np.asarray([t - 3], np.int32)
+
+    # a plane a pool, as the pools were stored: (P, pt, E) and (P, pt, H)
+    want = [attn.quantize_kv(jnp.asarray(x), "int8", kvh) for x in old]
+    want = [[np.asarray(w.data).copy(), np.asarray(w.scale).copy()]
+            for w in want]
+    fresh = [attn.quantize_kv(jnp.asarray(x), "int8", kvh) for x in new]
+    written = set()
+    for r in range(b):
+        for j in range(t):
+            if not active[r] or (valid is not None and j >= valid[r]):
+                continue
+            pos = int(start[r]) + j
+            page, slot = table[r, (pos // pt) % m], pos % pt
+            written.add(int(page))
+            for w, f in zip(want, fresh):
+                w[0][page, slot] = np.asarray(f.data)[r, j]
+                w[1][page, slot] = np.asarray(f.scale)[r, j]
+
+    # op by op, as the expectation's scales were computed (under jit XLA
+    # folds the division by 127 another way, an ulp apart)
+    got_k, got_v = attn.paged_append_kv(
+        kp, vp, jnp.asarray(table), *map(jnp.asarray, new),
+        jnp.asarray(start), num_heads=kvh, active=jnp.asarray(active),
+        valid=None if valid is None else jnp.asarray(valid))
+    assert got_k.scale.shape == kp.scale.shape and got_v.scale is None
+    # every page but the scratch page, as stored
+    plane = np.asarray(got_k.scale).reshape(pages, pt, 2, kvh)
+    for i, (got, w) in enumerate(zip((got_k, got_v), want)):
+        np.testing.assert_array_equal(np.asarray(got.data)[1:], w[0][1:])
+        np.testing.assert_array_equal(plane[1:, :, i], w[1][1:])
+    assert written and 0 not in written
+    # and as the slots' views show them: a dense ring a slot
+    view_k, view_v = attn.paged_gather_kv(got_k, got_v, jnp.asarray(table))
+    for view, w in zip((view_k, view_v), want):
+        np.testing.assert_array_equal(
+            np.asarray(view.data), w[0][table].reshape(b, m * pt, -1))
+        np.testing.assert_array_equal(
+            np.asarray(view.scale), w[1][table].reshape(b, m * pt, kvh))
+
+
+def test_a_chunk_as_long_as_its_ring_lands_where_it_wraps():
+    """The pages a chunk can touch are then the whole ring, and the tokens
+    that run past its end land at its start, in the page the chunk began
+    in."""
+    from mxnet_tpu.ops import attention as attn
+
+    pt, m, kvh, hd = 4, 2, 2, 4
+    rng = np.random.RandomState(5)
+    zeros = jnp.zeros((3, pt, kvh * hd), jnp.float32)
+    kp, vp = attn.quantize_pools(zeros, zeros, "int8", kvh)
+    table = jnp.asarray([[2, 1]], jnp.int32)
+    new = [jnp.asarray(rng.normal(size=(1, m * pt, kvh * hd)), jnp.float32)
+           for _ in range(2)]
+    kp, vp = attn.paged_append_kv(kp, vp, table, *new, 3, num_heads=kvh)
+    view_k, view_v = attn.paged_gather_kv(kp, vp, table)
+    for view, x in zip((view_k, view_v), new):
+        want = attn.quantize_kv(x, "int8", kvh)
+        # token j sits at ring position (3 + j) % 8
+        order = (np.arange(m * pt) - 3) % (m * pt)
+        np.testing.assert_array_equal(np.asarray(view.scale)[0],
+                                      np.asarray(want.scale)[0][order])
+        np.testing.assert_array_equal(np.asarray(view.data)[0],
+                                      np.asarray(want.data)[0][order])
+
+
+def test_paged_int8_pools_under_a_mesh_replicate_the_scale_plane():
+    """A row of the scale plane is tokens x heads: no split of it is a
+    head-group split, so under a mesh the plane replicates (and says so)
+    while the data planes keep the model-axis split; logits match the
+    unsharded predictor."""
+    from mxnet_tpu.parallel import MeshConfig, build_mesh
+
+    if len(jax.devices()) < 8:
+        pytest.skip("needs the 8-virtual-device harness")
+    mesh = build_mesh(MeshConfig(data=2, seq=2, model=2))
+    sym, params = _lm_and_params()
+    rng = np.random.RandomState(9)
+    x = rng.randint(0, VOCAB, (B, 8)).astype(np.float32)
+    plain = DecodePredictor(sym, params, cache_len=T, paged=True,
+                            page_tokens=4, kv_dtype="int8")
+    shard = DecodePredictor(sym, params, cache_len=T, paged=True,
+                            page_tokens=4, kv_dtype="int8", mesh=mesh)
+    # as placed (what a program hands back is GSPMD's to choose)
+    kc, vc = shard.paged_batch_state(B).caches[0]
+    assert "model" in tuple(kc.data.sharding.spec), kc.data.sharding
+    assert "model" in tuple(vc.data.sharding.spec), vc.data.sharding
+    assert kc.scale.shape == (kc.data.shape[0], 4 * 2 * HEADS)
+    assert vc.scale is None
+    assert not any(tuple(kc.scale.sharding.spec)), kc.scale.sharding
+    assert any(d["site"] == "pool-scale" for d in shard._replicated_degrades)
+    s_state, s_probs = shard.prefill(x, 8)
+    p_state, p_probs = plain.prefill(x, 8)
+    np.testing.assert_allclose(np.asarray(s_probs), np.asarray(p_probs),
+                               rtol=1e-4, atol=1e-5)
+    for _ in range(2):
+        s_state, s_probs = shard.step(s_state)
+        p_state, p_probs = plain.step(p_state)
+        np.testing.assert_allclose(np.asarray(s_probs), np.asarray(p_probs),
+                                   rtol=1e-4, atol=1e-5)

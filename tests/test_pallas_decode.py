@@ -45,17 +45,12 @@ def _pools(rng, pages, pt, e, dtype=None, heads=HEADS):
     if dtype is None:
         return k, v
     # quantize through the production path so scales match exactly
-    def q(x):
-        flat = attn.quantize_kv(x.reshape(1, pages * pt, e), dtype, heads)
-        return attn.QuantKV(flat.data.reshape(pages, pt, e),
-                            flat.scale.reshape(pages, pt, heads))
-    return q(k), q(v)
+    return attn.quantize_pools(k, v, dtype, heads)
 
 
 def _einsum_paged(q, kp, vp, table, lens, heads):
-    return attn._sdpa_cache(q, attn.paged_gather(kp, table),
-                            attn.paged_gather(vp, table), lens, heads,
-                            None)
+    return attn._sdpa_cache(q, *attn.paged_gather_kv(kp, vp, table), lens,
+                            heads, None)
 
 
 # ---------------------------------------------------------------------------
@@ -117,7 +112,9 @@ def test_quantized_pool_parity_in_kernel_dequant(dtype):
     for tq in (1, 3):
         q = jnp.asarray(rng.randn(B, tq, EMBED).astype(np.float32))
         fn = pd.flash_sdpa_decode if tq == 1 else pd.flash_sdpa_verify
-        out = fn(q, kp, vp, table, lens, num_heads=HEADS, interpret=True)
+        # the kernel reads a scale plane a pool: the door splits the rows
+        out = fn(q, *attn._kernel_pools(kp, vp), table, lens,
+                 num_heads=HEADS, interpret=True)
         ref = _einsum_paged(q, kp, vp, table, lens, HEADS)
         assert np.asarray(out).dtype == np.float32
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
