@@ -70,9 +70,14 @@ decode steps, so a long prompt never stalls the serving batch.
 
 The symbol contract (checked at trace time, documented in
 docs/inference.md): decoder-only graphs built from position-independent ops
-plus two stateful ones for sequence mixing, ``dot_product_attention`` (keys
-and values a position) and ``SelectiveSSM`` (``ops.ssm``: a conv tail and a
-recurrent state a sequence, no positions).  Positions enter either
+plus the stateful ones for sequence mixing: ``dot_product_attention`` (keys
+and values a position; with ``sparse_topk`` an index of compressed keys
+beside them, a row a page) and the recurrent ops :func:`state_ops` lists,
+each a statement of what it keeps a sequence and how a chunk and a step
+update it (``SelectiveSSM``, ``ops.ssm``: a conv tail and a state;
+``LightningAttention``, ``ops.linattn``: one matrix state a head; no
+positions in either but those the op's own rotation reads).  Positions enter
+either
 as a learned positional table added via a ``broadcast_*`` op against a
 ``(1, S, E)`` variable, or inside the attention node (``rotary_dim``: q and
 k are rotated at the positions the walk passes, and the keys are cached
@@ -83,7 +88,7 @@ LMs qualify.
 bind time (:class:`CacheLayout`), of one of three kinds: a "full" attention
 node holds ``cache_len`` positions a slot, a paged "window" node a ring of
 ``window + prefill_chunk`` positions rounded up to a page, a "state" node
-(``SelectiveSSM``) one fixed row a slot and no positions at all.  Nodes of
+(a recurrent op) one fixed row a slot and no positions at all.  Nodes of
 one kind and capacity form a group (``serve.CacheGroup``): the paged kinds
 with their own page count, page tables and allocator, the state group with
 one row a slot whose "table" is the row's index.  A graph of one kind builds
@@ -170,17 +175,43 @@ def _per_group(items):
     return items if len(items) > 1 else items[0]
 
 
-def _pool_pair(kc, vc, make):
+def _pool_pair(kc, vc, make, *index):
     """A node's two page pools from its probed K and V avals:
-    ``make(aval, is_scale=False)`` builds one plane.  Quantized pools keep
-    one scale plane between them, beside the K data
-    (``ops.attention.QuantKV``)."""
+    ``make(aval, is_scale=False, is_index=False)`` builds one plane.
+    Quantized pools keep one scale plane between them, beside the K data
+    (``ops.attention.QuantKV``).  A node with sparse selection brings a
+    third aval, its ``index``: one more plane, a row a page."""
     from .ops.attention import QuantKV
 
+    more = tuple(make(a, is_index=True) for a in index)
     if isinstance(kc, QuantKV):
         return (QuantKV(make(kc.data), make(kc.scale, is_scale=True)),
-                QuantKV(make(vc.data), None))
-    return make(kc), make(vc)
+                QuantKV(make(vc.data), None)) + more
+    return (make(kc), make(vc)) + more
+
+
+class StateOp(NamedTuple):
+    """What a recurrent op keeps a slot and how the walk drives it, stated
+    once so that :meth:`DecodePredictor._run` names no op: ``mix(attrs,
+    *inputs, state=, pos0=, nvalid=, active=)`` -> ``(out, state, rows)`` is
+    the op's one mathematics in its three forms (a sequence from zero:
+    ``state`` None; a chunk of a carried state: ``nvalid``; one token a row
+    in place: ``active``), ``state`` a tuple of (rows, ...) leaves whose
+    shapes the shape probe reads off a (1, 1) sequence, and ``counts`` the
+    name under which the rows a decode step advanced are counted (a leaf of
+    :class:`DecodeState`, an argument of the ``serve.readback`` span)."""
+
+    mix: object
+    counts: str
+
+
+def state_ops():
+    """``{op name: StateOp}``: the recurrent ops the decode walk carries a
+    state for, each in a "state" cache layout."""
+    from .ops import linattn as _linattn, ssm as _ssm
+
+    return {_ssm.OP_NAME: StateOp(_ssm.mix, "ssm_rows"),
+            _linattn.OP_NAME: StateOp(_linattn.mix, "linattn_rows")}
 
 
 class CacheLayout(NamedTuple):
@@ -197,6 +228,8 @@ class CacheLayout(NamedTuple):
     capacity: int
     key_width: object = None
     value_width: object = None
+    index: int = 0      # positions a row of the node's index of compressed
+                        # keys stands for (sparse selection); 0 = no index
 
 
 class DecodeState(NamedTuple):
@@ -205,8 +238,10 @@ class DecodeState(NamedTuple):
     caches: tuple       # one entry per stateful node, in graph order.  An
                         # attention node: (k, v), (B, C, E) arrays, or
                         # ops.attention.QuantKV (data + scales) under a
-                        # quantized MXNET_KV_DTYPE.  A SelectiveSSM node:
-                        # (conv tail (B, K-1, C), state (B, H, P, N))
+                        # quantized MXNET_KV_DTYPE; a paged node with
+                        # sparse selection adds its index (P, H_kv * D).  A
+                        # recurrent op's node (state_ops): its state's
+                        # leaves, each (B, ...)
     lens: object        # (B,) int32 — tokens appended to each cache so far
     tok: object         # (B, 1) int32 — last sampled token, not yet appended
     moe: object = None  # int32 [rows held, rows elsewhere, held experts
@@ -215,6 +250,9 @@ class DecodeState(NamedTuple):
                         # for a graph without them and on the way in
     ssm: object = None  # int32: (slot, SelectiveSSM node) rows whose state
                         # the paged step advanced; None as ``moe`` is
+    counts: object = None   # {name: int32} of what else the paged step's
+                            # nodes counted (linattn_rows, sparse_blocks_
+                            # chosen / _live); None as ``moe`` is
 
 
 class DecodePredictor:
@@ -270,7 +308,6 @@ class DecodePredictor:
         import jax.numpy as jnp
 
         from . import symbol as sym_mod
-        from .ops import ssm as _ssm
         from .predictor import _as_param_dicts
 
         if isinstance(symbol, str):
@@ -331,26 +368,40 @@ class DecodePredictor:
                              "inputs: %s)" % (data_name, free))
         # the stateful nodes, in graph order: DecodeState.caches, the
         # layouts and the groups are indexed by position in this list
+        self._state_ops = state_ops()
         self._cache_nodes = [n for n in symbol._topo()
-                             if not n.is_variable and n.op.name in (
-                                 "dot_product_attention", _ssm.OP_NAME)]
+                             if not n.is_variable and (
+                                 n.op.name == "dot_product_attention"
+                                 or n.op.name in self._state_ops)]
         self._attn_nodes = [n for n in self._cache_nodes
                             if n.op.name == "dot_product_attention"]
         if not self._attn_nodes:
             raise MXNetError("symbol has no dot_product_attention node; "
                              "nothing to cache — use Predictor")
         if len(self._cache_nodes) > len(self._attn_nodes):
+            recurrent = "/".join(sorted({
+                n.op.name for n in self._cache_nodes
+                if n.op.name in self._state_ops}))
             if not self._paged:
                 raise MXNetError(
                     "a graph with %s nodes is served paged only "
                     "(paged=True): the dense ring's prefill pads a prompt "
                     "to its window and its verify step rolls lengths back, "
-                    "and a recurrent state can take neither" % _ssm.OP_NAME)
+                    "and a recurrent state can take neither" % recurrent)
             if mesh is not None:
                 raise MXNetError(
                     "a graph with %s nodes is served on one device: "
                     "parallel.tp_rules has no plan for the mixer's heads "
-                    "and groups" % _ssm.OP_NAME)
+                    "and groups" % recurrent)
+        from .ops.attention import sparse_spec
+
+        if any(sparse_spec(n.parsed_attrs()) for n in self._attn_nodes) \
+                and (not self._paged or mesh is not None):
+            raise MXNetError(
+                "a graph whose attention selects its blocks (sparse_topk) "
+                "is served paged and on one device: the index of compressed "
+                "keys is a row a page, and the list of chosen blocks has no "
+                "plan under a mesh")
         # per-attention-node head dims, recorded at trace time by _run
         # (num_heads / num_kv_heads / q_dim / kv_dim) — the grouped-layout
         # source of truth for cache meta and CacheBytesPass
@@ -504,6 +555,7 @@ class DecodePredictor:
         predictor included, keeps ``cache_len`` (its mask does the rest).
         A state node keeps no positions (capacity 0): its group comes
         last."""
+        from .ops import attention as _attn
         from .serve.manager import CacheGroup
 
         pt = self._page_tokens
@@ -519,9 +571,11 @@ class DecodePredictor:
             cap = self._cache_len
             if window and self._paged and self._prefill_chunk:
                 cap = min(cap, -(-(window + self._prefill_chunk) // pt) * pt)
+            spec = _attn.sparse_spec(a)
             self._layouts.append(CacheLayout(
                 kind_of(cap),
-                int(a.get("num_kv_heads", 0) or a.get("num_heads", 1)), cap))
+                int(a.get("num_kv_heads", 0) or a.get("num_heads", 1)), cap,
+                index=spec.stride if spec else 0))
         caps = sorted({l.capacity for l in self._layouts}, reverse=True)
         kinds = [kind_of(c) for c in caps]
         self._groups = [
@@ -569,14 +623,25 @@ class DecodePredictor:
         (``serve.manager.WHY_NOT``)."""
         return [g for g in self._groups if g.kind != "full"]
 
-    def state_row_bytes(self):
-        """Bytes one slot holds in the "state" group: the conv tails and
-        the states of every ``SelectiveSSM`` node (0 without one)."""
+    def state_nodes(self, counts):
+        """How many of the graph's recurrent nodes are of the op that
+        counts its rows as ``counts`` ("ssm_rows", "linattn_rows")."""
+        return sum(n.op.name in self._state_ops
+                   and self._state_ops[n.op.name].counts == counts
+                   for n in self._cache_nodes)
+
+    def state_row_bytes(self, counts=None):
+        """Bytes one slot holds in the "state" group: every leaf of every
+        recurrent node's state (0 without one); with ``counts``, of the
+        nodes of that op alone (:meth:`state_nodes`)."""
         if self._pools_template is None:
             self._pools_template = self._probe_cache_shapes()
         return sum(int(np.prod(a.shape[1:])) * a.dtype.itemsize
-                   for l, pair in zip(self._layouts, self._pools_template)
-                   if l.kind == "state" for a in pair)
+                   for n, l, leaves in zip(self._cache_nodes, self._layouts,
+                                           self._pools_template)
+                   if l.kind == "state" and counts in (
+                       None, self._state_ops[n.op.name].counts)
+                   for a in leaves)
 
     def cache_layouts(self):
         """The per-node :class:`CacheLayout`\\ s with the key and value
@@ -587,8 +652,9 @@ class DecodePredictor:
             self._pools_template = self._probe_cache_shapes()
         width = lambda a: int((a.data if isinstance(a, QuantKV)
                                else a).shape[-1])
-        return [l._replace(key_width=width(kc), value_width=width(vc))
-                for l, (kc, vc) in zip(self._layouts, self._pools_template)]
+        return [l._replace(key_width=width(leaves[0]),
+                           value_width=width(leaves[-1]))
+                for l, leaves in zip(self._layouts, self._pools_template)]
 
     def _tables_of(self, mgr):
         """The manager's page tables as the programs take them: one
@@ -624,14 +690,17 @@ class DecodePredictor:
         row index of a state group."""
         return group.capacity // self._page_tokens or 1
 
-    def _pool_shape(self, ai, aval, pages, is_scale=False):
+    def _pool_shape(self, ai, aval, pages, is_scale=False, is_index=False):
         """Shape of node ``ai``'s pool (or state array) built from the
         probed batch-1 ``aval``: ``pages`` pages of ``page_tokens``
         positions, or ``pages`` state rows.  The scale plane a node's two
         quantized pools share (``is_scale``) is a row a page, (pages,
-        page_tokens * 2 * H_kv): ``ops.attention.QuantKV``."""
+        page_tokens * 2 * H_kv): ``ops.attention.QuantKV``; so is the index
+        of a node with sparse selection (``is_index``), (pages, H_kv * D)."""
         if self._layouts[ai].kind == "state":
             return (pages,) + tuple(aval.shape[1:])
+        if is_index:
+            return (pages, aval.shape[2])
         if is_scale:
             return (pages, self._page_tokens * 2 * aval.shape[2])
         return (pages, self._page_tokens, aval.shape[2])
@@ -818,23 +887,26 @@ class DecodePredictor:
         tables (``active``/``valid`` masks redirect non-writes to the
         scratch page) and attention gathers what the slots have reached
         of the dense-ring view (``ops.attention.paged_attend``) — paged
-        storage, every live position attended.  A ``SelectiveSSM`` node
-        carries ``(conv tail, state)`` rows the same way: from zero in
-        prefill mode; one token a row in place, its write masked by
+        storage, every live position attended; a node with sparse
+        selection keeps its index beside them and attends what it chooses
+        (``ops.attention.paged_attend_sparse``).  A recurrent op's node
+        (:func:`state_ops`) carries its state's rows the same way: from zero
+        in prefill mode; one token a row in place, its write masked by
         ``active``, in a decode step; the rows its group's table names, the
         padding past ``valid`` skipped, in a chunk.  Returns ``(probs (B, t,
-        V), caches)``; ``self._ssm_rows`` holds the rows each such node
-        advanced, for the program that called.
+        V), caches)``; ``self._counts`` holds, by name, what each such node
+        counted (the rows it advanced, the blocks it chose), for the
+        program that called.
         """
         import jax
         import jax.numpy as jnp
 
         from .obs.scopes import node_scope as _node_scope
-        from .ops import attention as _attn, ssm as _ssm
+        from .ops import attention as _attn
 
         b, t = tokens.shape[0], tokens.shape[1]
         new_caches = []
-        self._ssm_rows = []
+        self._counts = counts = {}
         ci = qi = 0
         values = {}
         base_key = jax.random.PRNGKey(0)
@@ -888,7 +960,40 @@ class DecodePredictor:
                             attrs, q, k,
                             jnp.asarray(pos0, jnp.int32).reshape(-1, 1)
                             + jnp.arange(t, dtype=jnp.int32)[None, :])
-                    if caches is None:
+                    spec = _attn.sparse_spec(attrs)
+                    if spec is not None:
+                        # keys, values and the index of compressed keys; the
+                        # blocks each row chose and could have, counted
+                        if caches is None:
+                            outs = [_attn.sdpa_sparse(
+                                q, k, v, spec, num_heads=heads, scale=scale,
+                                num_kv_heads=kv_heads, **at)]
+                            new_caches.append((
+                                self._fill_cache(k, kv_heads),
+                                self._fill_cache(v, kv_heads), k[:, :1]))
+                        else:
+                            tbl = tables[self._group_of[ai]] \
+                                if isinstance(tables, tuple) else tables
+                            kc, vc, index = caches[ai]
+                            kc, vc = _attn.paged_append_kv(
+                                kc, vc, tbl, k, v, pos0, num_heads=kv_heads,
+                                active=active, valid=valid, **at)
+                            index = _attn.paged_append_index(
+                                index, kc, tbl, pos0, t, spec, active=active,
+                                valid=valid, **at)
+                            out, (chosen, live) = _attn.paged_attend_sparse(
+                                q, kc, vc, index, tbl, jnp.asarray(
+                                    pos0, jnp.int32).reshape(-1) + t, spec,
+                                num_heads=heads, scale=scale,
+                                num_kv_heads=kv_heads, active=active, **at)
+                            outs = [out]
+                            self._decode_path = "einsum"
+                            counts.setdefault("sparse_blocks_chosen",
+                                              []).append(chosen)
+                            counts.setdefault("sparse_blocks_live",
+                                              []).append(live)
+                            new_caches.append((kc, vc, index))
+                    elif caches is None:
                         outs = [_attn.sdpa(q, k, v, num_heads=heads,
                                            causal=attrs.get("causal", False),
                                            scale=scale,
@@ -929,15 +1034,16 @@ class DecodePredictor:
                         # mxlint pallas-fallback error
                         self._decode_path = _attn.DECODE_PATH["last"]
                         new_caches.append((kc, vc))
-                elif opname == _ssm.OP_NAME:
+                elif opname in self._state_ops:
+                    op = self._state_ops[opname]
                     ai = ci
                     ci += 1
                     if caches is None:
-                        out, carried, rows = _ssm.mix(attrs, *ins)
+                        out, carried, rows = op.mix(attrs, *ins)
                     elif valid is not None:
                         # a chunk: the rows the state group's table names
                         at = tables[self._group_of[ai]][:, 0]
-                        out, rows_new, rows = _ssm.mix(
+                        out, rows_new, rows = op.mix(
                             attrs, *ins, pos0=pos0, nvalid=valid,
                             state=tuple(jnp.take(a, at, axis=0)
                                         for a in caches[ai]))
@@ -947,8 +1053,9 @@ class DecodePredictor:
                     elif t == 1 and active is not None:
                         # a decode step: every slot's row in place, the
                         # write masked (no scratch row to send junk to)
-                        out, carried, rows = _ssm.mix(
-                            attrs, *ins, state=caches[ai], active=active)
+                        out, carried, rows = op.mix(
+                            attrs, *ins, state=caches[ai], pos0=pos0,
+                            active=active)
                     else:
                         raise MXNetError(
                             "decode: node %r (%s) carries a recurrent state "
@@ -957,7 +1064,7 @@ class DecodePredictor:
                             "speculative verify window) would advance it "
                             "past what a rejected draft can roll back"
                             % (node.name, opname, t))
-                    self._ssm_rows.append(rows)
+                    counts.setdefault(op.counts, []).append(rows)
                     outs = [out]
                     new_caches.append(carried)
                 else:
@@ -1152,10 +1259,11 @@ class DecodePredictor:
         tok = jnp.where(act, tok, state.tok)
         lens = state.lens + jnp.asarray(active, jnp.int32).reshape(-1)
         # beside the sampled tokens, and read with them: no new sync
+        counted = {name: sum(got) for name, got in self._counts.items()}
         return DecodeState(caches, lens, tok,
                            sum(moe_rows) if moe_rows else None,
-                           sum(self._ssm_rows) if self._ssm_rows
-                           else None), probs
+                           counted.pop("ssm_rows", None),
+                           counted or None), probs
 
     def _paged_verify_impl(self, env, state, tables, active, draft_toks,
                            draft_probs, key):
@@ -1380,22 +1488,23 @@ class DecodePredictor:
             self._pools_template = self._probe_cache_shapes()
 
         pools = []
-        for ai, (kc, vc) in enumerate(self._pools_template):
+        for ai, leaves in enumerate(self._pools_template):
             pp = self._pool_pages_of(ai)
             if self._layouts[ai].kind == "state":
                 # one row a slot; no layout to choose, nothing to shard
                 pools.append(tuple(
                     jax.device_put(jnp.zeros(self._pool_shape(ai, a, pp),
                                              a.dtype), self._ctx.jax_device)
-                    for a in (kc, vc)))
+                    for a in leaves))
                 continue
 
-            def pool_of(aval, is_scale=False):
+            def pool_of(aval, is_scale=False, is_index=False):
                 return self._place_pool(
-                    jnp.zeros(self._pool_shape(ai, aval, pp, is_scale),
-                              aval.dtype), is_scale=is_scale)
+                    jnp.zeros(self._pool_shape(ai, aval, pp, is_scale,
+                                               is_index),
+                              aval.dtype), is_scale=is_scale or is_index)
 
-            pools.append(_pool_pair(kc, vc, pool_of))
+            pools.append(_pool_pair(*leaves[:2], pool_of, *leaves[2:]))
         self._paged_lens = np.zeros(slots, np.int64)
         return DecodeState(tuple(pools), jnp.zeros((slots,), jnp.int32),
                            jnp.zeros((slots, 1), jnp.int32))
@@ -1415,9 +1524,11 @@ class DecodePredictor:
         # a node's scale plane holds what a (pages, page_tokens, H) plane a
         # pool would: the template's two
         return sum(shape_bytes(shape_str(
-            self._pool_shape(ai, aval, self._pool_pages_of(ai)), aval.dtype))
-            for ai, pair in enumerate(self._pools_template)
-            for aval in jtu.tree_leaves(pair))
+            self._pool_shape(ai, aval, self._pool_pages_of(ai),
+                             is_index=i >= 2), aval.dtype))
+            for ai, leaves in enumerate(self._pools_template)
+            for i, leaf in enumerate(leaves)
+            for aval in jtu.tree_leaves(leaf))
 
     # ------------------------------------------------------------------
     # AOT-serialized program preparation — the fleet cold-start path
@@ -1494,14 +1605,18 @@ class DecodePredictor:
 
         def build(shape_of):
             pools = []
-            for ai, (kc, vc) in enumerate(self._pools_template):
-                pools.append(_pool_pair(
-                    kc, vc, lambda a, is_scale=False, ai=ai: sds(
-                        shape_of(ai, a, is_scale), a.dtype)))
+            for ai, leaves in enumerate(self._pools_template):
+                make = lambda a, is_scale=False, is_index=False, ai=ai: sds(
+                    shape_of(ai, a, is_scale, is_index), a.dtype)
+                pools.append(
+                    tuple(make(a) for a in leaves)
+                    if self._layouts[ai].kind == "state"
+                    else _pool_pair(*leaves[:2], make, *leaves[2:]))
             return tuple(pools)
 
-        caches = build(lambda ai, a, is_scale=False: self._pool_shape(
-            ai, a, pps[self._group_of[ai]], is_scale))
+        caches = build(lambda ai, a, is_scale=False, is_index=False:
+                       self._pool_shape(ai, a, pps[self._group_of[ai]],
+                                        is_scale, is_index))
         env = {n: aval_of(v) for n, v in self._env.items()}
         lens = sds((slots,), jnp.int32)
         tok = sds((slots, 1), jnp.int32)
@@ -1527,8 +1642,8 @@ class DecodePredictor:
             # are programs of a graph with one group
             row = sds((m,), jnp.int32)
             # one slot's extracted pages: the pool gathered at an (M,) row
-            data = build(lambda ai, a, is_scale=False:
-                         self._pool_shape(ai, a, m, is_scale))
+            data = build(lambda ai, a, is_scale=False, is_index=False:
+                         self._pool_shape(ai, a, m, is_scale, is_index))
             out.update({"fork": (caches, i32, i32),
                         "extract": (caches, row),
                         "install": (caches, row, data)})
@@ -2103,7 +2218,7 @@ class DecodePredictor:
         kv = [pair for l, pair in zip(self._layouts, state.caches)
               if l.kind != "state"]
         dtypes = set()
-        for kc, vc in kv:
+        for kc, vc, *_ in kv:
             for c in (kc, vc):
                 dtypes.add(str((c.data if isinstance(c, QuantKV)
                                 else c).dtype))
@@ -2136,7 +2251,7 @@ class DecodePredictor:
             meta["num_kv_heads"] = int(self._grouped_kv_heads)
             meta["attn_dims"] = [dict(d) for d in self._attn_dims]
             widths = set()
-            for kc, vc in kv:
+            for kc, vc, *_ in kv:
                 for c in (kc, vc):
                     widths.add(int((c.data if isinstance(c, QuantKV)
                                     else c).shape[2]))
@@ -2611,8 +2726,23 @@ class DecodeServer:
             "mx_ssm_state_bytes",
             "bytes of the state cache group: every slot's conv tails and "
             "recurrent states")
-        self._ssm_nodes = sum(
-            l.kind == "state" for l in getattr(predictor, "_layouts", ()))
+        self._m_linattn_rows = _obs.registry.counter(
+            "mx_linattn_rows_total",
+            "(slot, LightningAttention node) rows whose matrix state a "
+            "decode step advanced (idle and mid-prefill slots left out)")
+        self._m_linattn_state_bytes = _obs.registry.gauge(
+            "mx_linattn_state_bytes",
+            "bytes of the state cache group's LightningAttention rows: "
+            "every slot's (H, D, D) float32 states")
+        self._m_sparse_blocks = _obs.registry.counter(
+            "mx_attn_sparse_blocks_total",
+            "(slot, KV group, node) blocks of the attention nodes with "
+            "sparse selection in a decode step: those a row attended "
+            "(chosen) and those its context holds (live)",
+            labels=("kind",))
+        nodes_of = getattr(predictor, "state_nodes", lambda counts: 0)
+        self._ssm_nodes = nodes_of("ssm_rows")
+        self._linattn_nodes = nodes_of("linattn_rows")
         # --- fleet/preemption state (paged loop) ---
         # fair admission: after this many consecutive pool-gate-blocked
         # iterations the lowest-priority slot is preempted (swap-out) so
@@ -2720,6 +2850,17 @@ class DecodeServer:
             if program == "decode":
                 note.update(moe_rows_held=held, moe_rows_elsewhere=elsewhere,
                             moe_expert_visits=visits)
+
+    def _note_counts(self, note):
+        """Mirror into the registry what the decode step's lightning and
+        sparse nodes counted (``note`` holds them by name, as the step's
+        ``serve.readback`` span shows them)."""
+        if "linattn_rows" in note:
+            self._m_linattn_rows.inc(note["linattn_rows"])
+        for kind in ("chosen", "live"):
+            if "sparse_blocks_" + kind in note:
+                self._m_sparse_blocks.labels(kind=kind).inc(
+                    note["sparse_blocks_" + kind])
 
     def _note_attn_blocks(self, slot_lens, act_mask, note):
         """Count the blocks this tick's decode step attends, from the host's
@@ -3099,7 +3240,11 @@ class DecodeServer:
         for g in pred._manager.groups:      # a pool's size: once a session
             self._m_pages_total.labels(group=g.name).set(g.pool_pages)
         if self._ssm_nodes:
-            self._m_ssm_state_bytes.set(slots * pred.state_row_bytes())
+            self._m_ssm_state_bytes.set(
+                slots * pred.state_row_bytes("ssm_rows"))
+        if self._linattn_nodes:
+            self._m_linattn_state_bytes.set(
+                slots * pred.state_row_bytes("linattn_rows"))
         return self._ps
 
     def serve_reset(self):
@@ -3470,6 +3615,7 @@ class DecodeServer:
         cur = {"firsts": [],    # (record, device token) of a commit
                "moe": [],       # (program, device row counts)
                "ssm": None,     # device count of state rows stepped
+               "counts": {},    # {name: device count} of the step's others
                "toks": None,    # the step's tokens, a copy not donated on
                "rows": [],      # (slot, record) the step computed for
                "note": {}}      # the arguments of its serve.readback span
@@ -3624,6 +3770,7 @@ class DecodeServer:
             if state.moe is not None:
                 cur["moe"].append(("decode", state.moe))
             cur["ssm"] = state.ssm
+            cur["counts"] = state.counts or {}
             cur["rows"] = list(active.items())
             for rec in active.values():
                 rec["unread"] += 1
@@ -3658,10 +3805,15 @@ class DecodeServer:
         note = fl["note"]       # filled below too, read as the span closes
         firsts, moe, rows = fl["firsts"], fl["moe"], fl["rows"]
         with _obs.span("serve.readback", cat="serve", args=note):
+            names = sorted(fl["counts"])
             got = jax.device_get(
                 [tok for _, tok in firsts] + [vec for _, vec in moe]
-                + [a for a in (fl["toks"], fl["ssm"]) if a is not None])
+                + [a for a in (fl["toks"], fl["ssm"]) if a is not None]
+                + [fl["counts"][name] for name in names])
             now = time.time()
+            for name in reversed(names):
+                note[name] = int(got.pop())
+            self._note_counts(note)
             if fl["ssm"] is not None:
                 note["ssm_rows"] = int(got.pop())
                 self._m_ssm_rows.inc(note["ssm_rows"])

@@ -40,9 +40,28 @@ model of the family is a configuration file and no code:
   ``key_multiplier`` on the keys (folded into the attention node's
   ``scale``, where it is the same product).
 
+* ``mixer_types[l]``: ``"minicpm4"`` = attention that selects its blocks
+  (InfLLM-v2: ``sparse_config``'s ``kernel_size``, ``kernel_stride``,
+  ``init_blocks``, ``block_size``, ``window_size``, ``topk``, ``dense_len``;
+  rotary only where ``attn_use_rope``; a sigmoid gate on its output where
+  ``attn_use_output_gate``), ``"lightning-attn"`` = lightning linear
+  attention (``ops.linattn``: ``lightning_nh`` heads of
+  ``lightning_head_dim``, a matrix state a head; ``qk_norm``,
+  ``lightning_use_rope``, ``use_output_norm``, ``use_output_gate``; its
+  decay rates scaled by ``1 - l / (total_layers - 1) + 1e-5``), anything
+  else the attention ``hybrid_layer_pattern`` says;
+* ``first_layer`` / ``total_layers``: the ``num_layers`` layers built are
+  the published layers ``first_layer ..``, named and indexed into the
+  per-layer lists as such, of a model of ``total_layers`` (0 =
+  ``num_layers``);
+* MiniCPM's muP: ``scale_depth`` (every residual branch x ``scale_depth /
+  sqrt(total_layers)``; 0 = none) and ``dim_model_base`` (the logits /
+  ``hidden_size / dim_model_base``; 0 = none); ``scale_emb`` is
+  ``embedding_multiplier``.
+
 ``hybrid_layer_pattern`` and ``moe_layer_freq`` default to zeros: full
-attention and a dense MLP in every layer.  The first ``num_layers`` entries
-of the two per-layer lists are built.
+attention and a dense MLP in every layer.  Entries ``first_layer ..
+first_layer + num_layers - 1`` of the per-layer lists are built.
 """
 from __future__ import annotations
 
@@ -53,6 +72,7 @@ from ..obs.scopes import LAYER_ATTR
 # what the device-time breakdown files each node under (obs.scopes): window
 # attention apart from full, the gate's activation with its matrices
 _WINDOW = {LAYER_ATTR: "attn_window"}
+_SPARSE = {LAYER_ATTR: "attn_sparse"}
 _MLP = {LAYER_ATTR: "linear"}
 _HEAD = {LAYER_ATTR: "head_loss"}
 
@@ -68,9 +88,19 @@ def times(x, multiplier):
     return x if float(multiplier) == 1.0 else x * float(multiplier)
 
 
+def sparse_attrs(sparse_config):
+    """A published ``sparse_config`` as the attention node's attributes."""
+    names = {"topk": "sparse_topk", "block_size": "sparse_block",
+             "kernel_size": "sparse_kernel", "kernel_stride": "sparse_stride",
+             "init_blocks": "sparse_init_blocks",
+             "window_size": "sparse_window", "dense_len": "sparse_dense_len"}
+    return {names[k]: int(v) for k, v in dict(sparse_config).items()
+            if k in names}
+
+
 def attention(data, name, window, hidden, heads, kv_heads, head_dim,
               v_head_dim, rotary_dim, theta, value_scale, sink,
-              key_multiplier=1.0):
+              key_multiplier=1.0, sparse=None, gate=False):
     q = sym.FullyConnected(data, num_hidden=heads * head_dim, no_bias=True,
                            flatten=False, name=name + "_q")
     k = sym.FullyConnected(data, num_hidden=kv_heads * head_dim,
@@ -81,14 +111,38 @@ def attention(data, name, window, hidden, heads, kv_heads, head_dim,
     # sqrt(head_dim), and the cached keys keep their own range
     scaled = {} if float(key_multiplier) == 1.0 else {
         "scale": float(key_multiplier) / float(head_dim) ** 0.5}
-    with AttrScope(**(_WINDOW if window else {})):
+    scaled.update(sparse_attrs(sparse) if sparse else {})
+    with AttrScope(**(_WINDOW if window else _SPARSE if sparse else {})):
         att = sym.dot_product_attention(
             q, k, v, num_heads=heads, num_kv_heads=kv_heads, causal=True,
             window=window, sink=sink, rotary_dim=rotary_dim,
             rope_theta=theta, value_scale=value_scale, name=name + "_att",
             **scaled)
+        if gate:
+            att = att * sym.Activation(sym.FullyConnected(
+                data, num_hidden=heads * v_head_dim, no_bias=True,
+                flatten=False, name=name + "_gate"), act_type="sigmoid")
     return sym.FullyConnected(att, num_hidden=hidden, no_bias=True,
                               flatten=False, name=name + "_attout")
+
+
+def lightning_mixer(data, name, hidden, heads, head_dim, theta, eps,
+                    slope_scale, rotary=True, qk_norm=True, output_norm=True,
+                    output_gate=True):
+    """Lightning linear attention: ``hidden`` -> q, k, v and the gate ->
+    ``ops.linattn`` -> ``hidden``."""
+    width = heads * head_dim
+    streams = [sym.FullyConnected(data, num_hidden=width, no_bias=True,
+                                  flatten=False, name=name + "_lin_" + part)
+               for part in ("q", "k", "v") + (("gate",) if output_gate
+                                              else ())]
+    mixed = sym.LightningAttention(
+        *streams, num_heads=heads, head_dim=head_dim, rotary=bool(rotary),
+        rope_theta=float(theta), qk_norm=bool(qk_norm),
+        output_norm=bool(output_norm), output_gate=bool(output_gate),
+        slope_scale=float(slope_scale), eps=float(eps), name=name + "_lin")
+    return sym.FullyConnected(mixed, num_hidden=hidden, no_bias=True,
+                              flatten=False, name=name + "_lin_out")
 
 
 def gated_mlp(data, name, hidden, width, multipliers=(1.0, 1.0)):
@@ -149,15 +203,28 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                attention_in_multiplier=1.0, attention_out_multiplier=1.0,
                key_multiplier=1.0, ssm_in_multiplier=1.0,
                ssm_out_multiplier=1.0, ssm_multipliers=(1.0,) * 5,
-               mlp_multipliers=(1.0, 1.0), **kwargs):
+               mlp_multipliers=(1.0, 1.0), mixer_types=None, first_layer=0,
+               total_layers=0, sparse_config=None, attn_use_rope=True,
+               attn_use_output_gate=False, lightning_nh=0,
+               lightning_head_dim=0, lightning_use_rope=True, qk_norm=True,
+               use_output_norm=True, use_output_gate=True, scale_depth=0.0,
+               dim_model_base=0, **kwargs):
     """data (B, T) int tokens -> softmax over the vocabulary at every
     position (``softmax_label`` (B, T) next tokens, pad = -1 ignored)."""
     heads = int(num_attention_heads)
     v_head_dim = int(v_head_dim) or int(head_dim)
     rotary = rotary_dims(head_dim, partial_rotary_factor)
-    zeros = (0,) * int(num_layers)
+    first = int(first_layer)
+    total = int(total_layers) or int(num_layers)
+    zeros = (0,) * (first + int(num_layers))
     hybrid_layer_pattern = hybrid_layer_pattern or zeros
     moe_layer_freq = moe_layer_freq or zeros
+    mixer_types = mixer_types or ("",) * len(zeros)
+    # MiniCPM's muP: every residual branch, and the logits
+    depth = float(scale_depth) / total ** 0.5 if float(scale_depth) else 1.0
+    if int(dim_model_base):
+        lm_head_multiplier = float(lm_head_multiplier) \
+            * int(dim_model_base) / int(hidden_size)
     if mamba_d_ssm and int(mamba_d_ssm) != int(mamba_n_heads) \
             * int(mamba_d_head):
         raise ValueError("mamba_d_ssm %d != mamba_n_heads %d x mamba_d_head "
@@ -170,11 +237,26 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
     net = times(sym.Embedding(data, input_dim=vocab_size,
                               output_dim=hidden_size, name="embed"),
                 embedding_multiplier)
-    for i in range(int(num_layers)):
+    for i in range(first, first + int(num_layers)):
         name = "layer%d" % i
         windowed = bool(hybrid_layer_pattern[i])
         normed = sym.RMSNorm(net, eps=layernorm_epsilon,
                              name=name + "_att_norm")
+        if mixer_types[i] == "lightning-attn":
+            net = net + times(lightning_mixer(
+                normed, name, hidden_size, int(lightning_nh),
+                int(lightning_head_dim), rope_theta, layernorm_epsilon,
+                1.0 - i / max(total - 1, 1) + 1e-5,
+                rotary=lightning_use_rope, qk_norm=qk_norm,
+                output_norm=use_output_norm, output_gate=use_output_gate),
+                depth)
+            net = net + times(gated_mlp(
+                sym.RMSNorm(net, eps=layernorm_epsilon,
+                            name=name + "_ffn_norm"),
+                name, hidden_size, int(intermediate_size), mlp_multipliers),
+                depth)
+            continue
+        selects = mixer_types[i] == "minicpm4"
         if mamba_d_ssm:
             # the parallel block: both mixers read the one normed input
             net = net + times(ssm_mixer(
@@ -191,13 +273,16 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
             kv_heads=int((swa_num_key_value_heads if windowed
                           else num_key_value_heads) or heads),
             head_dim=int(head_dim), v_head_dim=v_head_dim,
-            rotary_dim=rotary,
+            rotary_dim=rotary if attn_use_rope or not selects else 0,
             theta=float((swa_rope_theta if windowed else 0.0)
                         or rope_theta),
             value_scale=float(attention_value_scale),
             sink=bool(add_swa_attention_sink_bias if windowed
                       else add_full_attention_sink_bias),
-            key_multiplier=key_multiplier), attention_out_multiplier)
+            key_multiplier=key_multiplier,
+            sparse=sparse_config if selects else None,
+            gate=bool(selects and attn_use_output_gate)),
+            attention_out_multiplier * depth)
         normed = sym.RMSNorm(net, eps=layernorm_epsilon,
                              name=name + "_ffn_norm")
         if moe_layer_freq[i]:
@@ -212,7 +297,7 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
         else:
             ffn = gated_mlp(normed, name, hidden_size,
                             int(intermediate_size), mlp_multipliers)
-        net = net + ffn
+        net = net + times(ffn, depth)
     net = sym.RMSNorm(net, eps=layernorm_epsilon, name="final_norm")
     with AttrScope(**_HEAD):
         logits = times(sym.FullyConnected(

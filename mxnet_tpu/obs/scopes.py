@@ -37,20 +37,26 @@ LAYER_OF_OP = {
     "MoEFFN": "moe",
     "RMSNorm": "norm",
     "SelectiveSSM": "ssm",
+    "LightningAttention": "linattn",
 }
 # every value a scope's <layer> may take: the table's, "other" for op
 # kinds it does not list, and the two fixed scopes of the train step
 # "attn_window" is what a model builder names its sliding-window attention
 # nodes (LAYER_ATTR), so that "attn" keeps meaning attention over the whole
-# context
+# context; "attn_sparse" likewise its nodes with sparse selection
 LAYERS = tuple(sorted(set(LAYER_OF_OP.values()))) + (
-    "attn_window", "other", "optimizer", "metric")
+    "attn_window", "attn_sparse", "other", "optimizer", "metric")
 SUBSCOPES = ("kv_append", "kv_gather", "kv_dequant", "scores", "rope",
              "route", "experts", "combine",
              # the state-space mixer: its convolution, the recurrence over a
              # chunk or a sequence ("scan") and over one token a slot
              # ("step"), the gate with its grouped norm
-             "conv", "scan", "step", "gate_norm")
+             "conv", "scan", "step", "gate_norm",
+             # lightning linear attention: its recurrence over a chunk or a
+             # sequence ("chunk"; "step" and "gate_norm" as above).  Sparse
+             # selection: the index's rows written, and the scoring, pooling
+             # and top-k that choose a row's blocks
+             "chunk", "index_append", "select")
 # an instruction no mx.<layer> scope reaches (compiler-made copies,
 # casts between the step's phases)
 UNSCOPED = "unscoped"

@@ -369,7 +369,7 @@ def cache_append(cache, new, start_pos, num_heads=1, layer="attn"):
 
 def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
                 num_kv_heads=0, mesh_active=False, window=0, sink=None,
-                value_scale=1.0, layer="attn", block=None):
+                value_scale=1.0, layer="attn", block=None, allow=None):
     """Shared length-masked cache-attention core behind
     :func:`sdpa_decode` (tq == 1) and :func:`sdpa_verify` (tq == k+1).
 
@@ -418,7 +418,12 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
     is index ``first[b] + j`` of a view of ``capacity``.  The result is
     then the block's share of the softmax, not yet normalized: ``(max (B,
     tq, H), sum (B, tq, H), acc (B, tq, H, hdv))``, all float32, with no
-    sink and no value scale: they join where the blocks are combined."""
+    sink and no value scale: they join where the blocks are combined.
+
+    ``allow`` (broadcast against the logits, (B, H_kv, [G,] tq, C) with 1
+    where it does not vary) is a selection laid over the length mask: a
+    slot is attended where both say so (sparse selection,
+    :func:`paged_attend_sparse`)."""
     import jax.numpy as jnp
     from jax.lax import Precision
 
@@ -508,8 +513,8 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
             if block is not None:
                 slot = slot + jnp.asarray(block[0], jnp.int32).reshape(
                     (-1, 1) + ones + (1,))
-            logits = jnp.where(slot < limit, logits,
-                               jnp.finfo(jnp.float32).min)
+            seen = slot < limit if allow is None else (slot < limit) & allow
+            logits = jnp.where(seen, logits, jnp.finfo(jnp.float32).min)
             m = jnp.max(logits, axis=-1, keepdims=True)
             p = jnp.exp(logits - m)
             den = jnp.sum(p, axis=-1, keepdims=True)
@@ -981,7 +986,7 @@ def live_block_plan(q_shape, table_shape, page_tokens, mesh_active=False,
 
 def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
                         scale, num_kv_heads, block, group, sink=None,
-                        value_scale=1.0, layer="attn"):
+                        value_scale=1.0, layer="attn", chosen=None):
     """:func:`paged_gather` + :func:`_sdpa_cache` over the blocks the slots
     have reached, and no others.
 
@@ -997,7 +1002,13 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
     slot's blocks then combines them; the sink joins there.  Every live
     position is attended and nothing is approximated: against the whole
     view only the order of the softmax's sums differs.  The pools are
-    constants of the loop, never carried."""
+    constants of the loop, never carried.
+
+    ``chosen`` = ``(mask (B, H_kv, tq, n), width)`` lays a selection over
+    the walk: query row ``i`` of kv group ``g`` attends position ``p`` only
+    where ``mask[b, g, i, p // width]`` (``width`` divides ``block``).  The
+    walk still visits every live block; what a row did not choose is masked
+    out of its softmax."""
     import jax
     import jax.numpy as jnp
 
@@ -1054,11 +1065,24 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         rows_of, ids = take(slot), take(pages)
         with _scope(layer, "kv_gather"):
             k_blk, v_blk = paged_gather_kv(k_pool, v_pool, ids)
+        allow = {}
+        if chosen is not None:
+            mask, width = chosen
+            per = block // width
+            at_blk = take(blk)[:, None] * per \
+                + jnp.arange(per, dtype=jnp.int32)[None, :]    # (group, per)
+            got_mask = mask[rows_of[:, None], :, :,
+                            jnp.minimum(at_blk, mask.shape[3] - 1)]
+            # (group, per, H_kv, tq) -> (group, H_kv, [1,] tq, block)
+            got_mask = jnp.repeat(jnp.transpose(got_mask, (0, 2, 3, 1)),
+                                  width, axis=3)
+            allow = {"allow": got_mask if num_heads == mask.shape[1]
+                     else got_mask[:, :, None]}
         got = _sdpa_cache(
             jnp.broadcast_to(q, (group,) + q.shape[1:]) if running
             else q[rows_of], k_blk, v_blk, total[rows_of], num_heads, scale,
             num_kv_heads=num_kv_heads, layer=layer,
-            block=(take(blk) * block, cap))
+            block=(take(blk) * block, cap), **allow)
         with _scope(layer, "scores"):
             if running:
                 return i + 1, fold(parts, got, at)
@@ -1147,6 +1171,396 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
     return _sdpa_cache(q, k_view, v_view, total_len, num_heads, scale,
                        num_kv_heads=num_kv_heads, mesh_active=mesh_active,
                        **extra)
+
+
+# ---------------------------------------------------------------------------
+# Sparse selection (InfLLM-v2, the ``minicpm4`` layers of ``minicpm_sala``):
+# a node with ``sparse_topk`` attends, past ``sparse_dense_len`` positions of
+# context, only the blocks it chooses.  Beside the keys and values it keeps
+# an INDEX of compressed keys a KV head, ``kbar_j = mean(k[stride * j :
+# stride * j + kernel])``.  The query at position t of a context of n = t + 1
+# scores the complete windows (softmax over j of q . kbar_j / sqrt(D), summed
+# over the query heads of a KV group), a block of ``block`` positions takes
+# the largest score of the windows that overlap it, the first ``init_blocks``
+# and the ``window / block`` blocks that end at the query's own are always
+# taken, and the highest scores fill the list up to ``topk`` blocks a KV
+# group.  With n <= dense_len every position is attended.
+#
+# Paged, the index is one more plane of the node, (P, H_kv * D), a row a
+# PAGE: ``page_tokens`` is the stride, so page j of a slot owns window j, and
+# the row is written when the window is complete, one page later
+# (:func:`paged_append_index`), from the pool's own (dequantized) keys: the
+# same row whatever the chunking.  A row is read only where the slot's length
+# says it is complete, so a page that is freed and taken again needs no
+# clearing.  The selection is data a slot and a KV group; the switch at
+# ``dense_len`` is the slot's length.
+# ---------------------------------------------------------------------------
+
+class SparseSpec(NamedTuple):
+    """The sizes of a node's sparse selection (its ``sparse_*``
+    attributes), in positions."""
+
+    topk: int
+    block: int
+    kernel: int
+    stride: int
+    init_blocks: int
+    window: int
+    dense_len: int
+
+    @property
+    def list_width(self):
+        """Blocks a decode row's list holds: ``topk``, or every block of a
+        context still attended densely where that is more."""
+        return max(self.topk, -(-self.dense_len // self.block))
+
+
+def sparse_spec(attrs):
+    """The :class:`SparseSpec` of a ``dot_product_attention`` node, or None
+    where it selects nothing (``sparse_topk`` 0)."""
+    topk = int(attrs.get("sparse_topk", 0) or 0)
+    if not topk:
+        return None
+    spec = SparseSpec(topk, *(
+        int(attrs.get("sparse_" + f, default)) for f, default in zip(
+            SparseSpec._fields[1:], (64, 32, 16, 1, 2048, 8192))))
+    if spec.kernel != 2 * spec.stride or spec.block % spec.stride \
+            or spec.window % spec.block:
+        raise ValueError(
+            "dot_product_attention: sparse selection is built for windows "
+            "of two strides, blocks of whole strides and a local window of "
+            "whole blocks; got %r" % (spec,))
+    return spec
+
+
+def compress_keys(k, spec):
+    """(B, T, E) keys -> (B, W, E) means of the ``W = (T - kernel) // stride
+    + 1`` complete windows, float32 (W 0 where none is)."""
+    import jax.numpy as jnp
+
+    b, t, e = k.shape
+    w = max(0, (t - spec.kernel) // spec.stride + 1)
+    if not w:
+        return jnp.zeros((b, 0, e), jnp.float32)
+    halves = k[:, :(w + 1) * spec.stride].astype(jnp.float32).reshape(
+        b, w + 1, spec.stride, e).sum(axis=2)
+    return (halves[:, :-1] + halves[:, 1:]) / spec.kernel
+
+
+def sparse_block_scores(q, kbar, n, spec, blocks, num_heads, num_kv_heads,
+                        scale=None):
+    """``R (B, H_kv, tq, blocks)`` float32: each block's score for each
+    query row, +inf where the block is always taken, -inf where it holds no
+    position the row may see.  ``q`` (B, tq, H * D); ``kbar`` (B, W, H_kv *
+    D) compressed keys, window ``j`` at row ``j`` (rows of windows that are
+    not complete are masked by ``n``); ``n`` (B, tq) the context's length at
+    each row, the row's own position included.  Where ``n <= dense_len``
+    every block the row may see reads +inf."""
+    import jax.numpy as jnp
+
+    b, tq, e = q.shape
+    kvh, g = check_head_groups(num_heads, num_kv_heads, e,
+                               where="sparse selection")
+    hd = e // num_heads
+    w = kbar.shape[1]
+    per, extra = spec.block // spec.stride, spec.kernel // spec.stride - 1
+    n = jnp.asarray(n, jnp.int32).reshape(b, 1, tq, 1)
+    blk = jnp.arange(blocks, dtype=jnp.int32).reshape(1, 1, 1, blocks)
+    own = (n - 1) // spec.block
+    if w:
+        logits = jnp.einsum(
+            "bqhgd,bwhd->bhgqw", q.reshape(b, tq, kvh, g, hd),
+            kbar.astype(q.dtype).reshape(b, w, kvh, hd),
+            preferred_element_type=jnp.float32) \
+            * (scale or 1.0 / np.sqrt(hd))
+        complete = jnp.arange(w, dtype=jnp.int32).reshape(1, 1, 1, 1, w) \
+            * spec.stride + spec.kernel <= n[:, :, None]
+        p = _softmax_with_sink(logits, complete, None)
+        r = jnp.sum(jnp.where(complete, p, 0.0), axis=2)    # (B, kvh, tq, W)
+        # block c overlaps windows per * c - extra .. per * c + per - 1
+        r = jnp.pad(r, ((0, 0),) * 3 + ((0, max(0, blocks * per - w)),))
+        r = r[..., :blocks * per]
+        score = jnp.max(r.reshape(b, kvh, tq, blocks, per), axis=-1)
+        for back in range(1, extra + 1):
+            before = jnp.pad(r, ((0, 0),) * 3 + ((back, 0),))[
+                ..., :blocks * per:per]
+            score = jnp.maximum(score, before)
+    else:
+        score = jnp.zeros((b, kvh, tq, blocks), jnp.float32)
+    forced = (blk < spec.init_blocks) | (blk > own - spec.window // spec.block)
+    score = jnp.where(forced | (n <= spec.dense_len), jnp.inf, score)
+    return jnp.where(blk <= own, score, -jnp.inf)
+
+
+def sparse_choose(score, n, spec, width):
+    """``(blocks, valid)``, each (B, H_kv, tq, width): the ``width``
+    highest-scored blocks of each row of ``score``
+    (:func:`sparse_block_scores`), best first, and which of them the row
+    attends: the first ``topk`` (all of them where ``n <= dense_len``) that
+    hold a position it may see."""
+    import jax
+    import jax.numpy as jnp
+
+    width = min(int(width), score.shape[-1])
+    top, blocks = jax.lax.top_k(score, width)
+    rank = jnp.arange(width, dtype=jnp.int32)
+    dense = jnp.asarray(n, jnp.int32).reshape(
+        score.shape[0], 1, -1, 1) <= spec.dense_len
+    return blocks.astype(jnp.int32), \
+        (top > -jnp.inf) & ((rank < spec.topk) | dense)
+
+
+def _largest_mask(score, k):
+    """bool like ``score`` (..., n): its ``k`` largest entries along the
+    last axis, the earlier index first among equals: what
+    ``jax.lax.top_k`` lists, as a mask and without a sort.  XLA:TPU lowers
+    ``top_k`` to a whole sort a row (17 ms a tick of the first form of
+    ``sala_serve_longctx``, my chip run, PR 43); here the k-th largest
+    value is found by bisection over the bits of a float's ordered integer
+    image, 32 counts a row."""
+    import jax
+    import jax.numpy as jnp
+
+    bits = jax.lax.bitcast_convert_type(score.astype(jnp.float32), jnp.int32)
+    # order-preserving image: negative floats reversed
+    keys = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    lowest = jnp.iinfo(jnp.int32).min
+    at_least = lambda t: jnp.sum(keys >= t[..., None], axis=-1) >= k
+    # the sign bit first, then the 31 below it: the largest t with at
+    # least k keys >= t is the k-th largest key
+    t = jnp.where(at_least(jnp.zeros(keys.shape[:-1], jnp.int32)), 0, lowest)
+    for bit in range(30, -1, -1):
+        up = t + jnp.int32(1 << bit)
+        t = jnp.where(at_least(up), up, t)
+    above = keys > t[..., None]
+    ties = keys == t[..., None]
+    room = k - jnp.sum(above, axis=-1, keepdims=True)
+    return above | (ties & (jnp.cumsum(ties, axis=-1) <= room))
+
+
+def sparse_block_mask(q, kbar, n, spec, blocks, num_heads, num_kv_heads,
+                      scale=None, rows=256):
+    """(B, H_kv, tq, blocks) bool: the blocks each query row attends, as a
+    mask (the chunk's and the whole sequence's form of the selection that
+    :func:`sparse_choose` lists for a decode row).  Computed ``rows`` query
+    rows at a time: the scores over the index are (H, rows, W) floats."""
+    import jax
+    import jax.numpy as jnp
+
+    b, tq, e = q.shape
+    n = jnp.broadcast_to(jnp.asarray(n, jnp.int32).reshape(b, -1), (b, tq))
+
+    def part(args):
+        q_part, n_part = args
+        score = sparse_block_scores(q_part, kbar, n_part, spec, blocks,
+                                    num_heads, num_kv_heads, scale)
+        seen = score > -jnp.inf
+        dense = (n_part <= spec.dense_len)[:, None, :, None]
+        if blocks <= spec.topk:
+            return seen
+        return seen & (dense | _largest_mask(score, spec.topk))
+
+    rows = min(int(rows), tq)
+    pad = -tq % rows
+    if pad:
+        q = jnp.pad(q, ((0, 0), (0, pad), (0, 0)))
+        n = jnp.pad(n, ((0, 0), (0, pad)), constant_values=1)
+    parts = (jnp.moveaxis(q.reshape(b, -1, rows, e), 1, 0),
+             jnp.moveaxis(n.reshape(b, -1, rows), 1, 0))
+    if parts[0].shape[0] == 1:
+        mask = part((parts[0][0], parts[1][0]))
+    else:
+        got = jax.lax.map(part, parts)          # (parts, B, H_kv, rows, n)
+        mask = jnp.moveaxis(got, 0, 2).reshape(
+            b, got.shape[2], -1, blocks)
+    return mask[:, :, :tq]
+
+
+def sdpa_sparse(q, k, v, spec, num_heads=1, scale=None, num_kv_heads=0,
+                layer="attn_sparse"):
+    """Causal attention with sparse selection over a whole sequence, no
+    cache (``Module`` forward): the index from the keys themselves, the
+    selection as a mask over the dense scores."""
+    import jax.numpy as jnp
+
+    b, t, e = q.shape
+    kvh, g = check_head_groups(num_heads, num_kv_heads, e, v.shape[2],
+                               k.shape[2], where="sdpa_sparse")
+    hd = e // num_heads
+    blocks = -(-t // spec.block)
+    with _scope(layer, "select"):
+        mask = sparse_block_mask(
+            q, compress_keys(k, spec), jnp.arange(1, t + 1)[None, :], spec,
+            blocks, num_heads, num_kv_heads, scale)
+    qh = q.reshape(b, t, kvh, g, hd)
+    with _scope(layer, "scores"):
+        logits = jnp.einsum("bqhgd,bkhd->bhgqk", qh,
+                            k.reshape(b, t, kvh, hd)).astype(jnp.float32) \
+            * (scale or 1.0 / np.sqrt(hd))
+        pos = jnp.arange(t, dtype=jnp.int32)
+        seen = jnp.repeat(mask, spec.block, axis=3)[..., :t] \
+            & (pos[None, :] <= pos[:, None])
+        p = _softmax_with_sink(logits, seen[:, :, None], None)
+    vh = v.reshape(b, t, kvh, v.shape[2] // kvh)
+    out = jnp.einsum("bhgqk,bkhe->bqhge", p.astype(vh.dtype), vh)
+    return out.reshape(b, t, num_heads * (v.shape[2] // kvh))
+
+
+def paged_append_index(index, k_pool, table, start_pos, t, spec,
+                       active=None, valid=None, layer="attn_sparse"):
+    """Write the index rows of the windows that the ``t`` positions just
+    appended at ``start_pos`` completed: window ``j`` (positions ``stride *
+    j .. stride * j + kernel - 1``, pages ``j`` and ``j + 1`` of the slot's
+    table) is the mean of its keys as the pool holds them, written at the
+    row of page ``j``.  ``index`` is (P, H_kv * D); ``k_pool`` the node's key
+    pool AFTER the append.  Rows of windows not completed here (and those
+    of a masked slot) go to the scratch row."""
+    import jax.numpy as jnp
+
+    data = _plane(k_pool)
+    pt = data.shape[1]
+    if pt != spec.stride:
+        raise ValueError(
+            "sparse selection keeps one index row a page: page_tokens %d "
+            "has to be the compression's stride %d" % (pt, spec.stride))
+    b, m = table.shape
+    with _scope(layer, "index_append"):
+        start = jnp.broadcast_to(
+            jnp.asarray(start_pos, jnp.int32).reshape(-1), (b,))
+        end = start + (t if valid is None
+                       else jnp.asarray(valid, jnp.int32).reshape(-1))
+        nw = (t + pt - 2) // pt + 1         # windows t tokens can complete
+        first = start // pt - 1
+        j = first[:, None] + jnp.arange(nw, dtype=jnp.int32)[None, :]
+        done = (j >= 0) & (j * pt + spec.kernel <= end[:, None]) \
+            & (j * pt + spec.kernel > start[:, None])
+        if active is not None:
+            done &= jnp.asarray(active).reshape(-1, 1).astype(bool)
+        ids = jnp.take_along_axis(
+            table.astype(jnp.int32),
+            jnp.clip(first[:, None] + jnp.arange(nw + 1, dtype=jnp.int32),
+                     0, m - 1), axis=1)                    # (B, nw + 1)
+        keys = data[ids].astype(jnp.float32)       # (B, nw + 1, pt, E)
+        if isinstance(k_pool, QuantKV):
+            kvh = k_pool.scale.shape[1] // (2 * pt)
+            scales = k_pool.scale[ids].reshape(b, nw + 1, pt, 2, kvh)[..., 0, :]
+            keys = (keys.reshape(b, nw + 1, pt, kvh, -1)
+                    * scales[..., None]).reshape(keys.shape)
+        halves = jnp.sum(keys, axis=2)
+        rows = ((halves[:, :-1] + halves[:, 1:]) / spec.kernel) \
+            .astype(index.dtype)
+        at = jnp.where(done, ids[:, :-1], 0)
+        return index.at[at.reshape(-1)].set(rows.reshape(b * nw, -1))
+
+
+def _attend_block_list(q, k_pool, v_pool, table, blocks, valid, n, spec,
+                       num_heads, num_kv_heads, scale, layer):
+    """One query row a slot over the blocks its KV groups chose: ``blocks``
+    / ``valid`` (B, H_kv, L).  The L blocks of every group are gathered
+    (whole pages, as the pool lies) into one view a slot, group after
+    group, and each head attends its own group's stretch."""
+    import jax.numpy as jnp
+
+    b, kvh, width = blocks.shape
+    pt = _plane(k_pool).shape[1]
+    per = spec.block // pt
+    with _scope(layer, "kv_gather"):
+        pages = blocks[..., None] * per \
+            + jnp.arange(per, dtype=jnp.int32)              # (B, kvh, L, per)
+        ids = jnp.take_along_axis(
+            table.astype(jnp.int32),
+            jnp.clip(pages.reshape(b, -1), 0, table.shape[1] - 1), axis=1)
+        ids = jnp.where(jnp.repeat(valid.reshape(b, -1), per, axis=1),
+                        ids, 0)
+        k_view, v_view = paged_gather_kv(k_pool, v_pool, ids)
+    c = kvh * width * spec.block
+    pos = (blocks[..., None] * spec.block
+           + jnp.arange(spec.block, dtype=jnp.int32)).reshape(b, kvh, -1)
+    seen = (pos < jnp.asarray(n, jnp.int32).reshape(b, 1, 1)) \
+        & jnp.repeat(valid, spec.block, axis=2)             # (B, kvh, L * blk)
+    # head group g reads stretch g of the view
+    allow = (jnp.eye(kvh, dtype=bool)[None, :, :, None]
+             & seen[:, :, None, :]).reshape(b, kvh, c)
+    g = num_heads // kvh
+    allow = allow.reshape((b, kvh) + ((1,) if g > 1 else ()) + (1, c))
+    return _sdpa_cache(q, k_view, v_view, jnp.full((b,), c, jnp.int32),
+                       num_heads, scale, num_kv_heads=num_kv_heads,
+                       layer=layer, allow=allow)
+
+
+def paged_attend_sparse(q, k_pool, v_pool, index, table, total_len, spec,
+                        num_heads=1, scale=None, num_kv_heads=0,
+                        active=None, mesh_active=False, layer="attn_sparse"):
+    """:func:`paged_attend` for a node with sparse selection: ``(out,
+    (blocks chosen, blocks live))``, the counts int32 scalars summed over
+    the rows, the KV groups and (a chunk) the query rows, masked slots left
+    out.
+
+    One query row a slot (the decode step): the index rows of the slot's
+    pages are scored, the list of blocks chosen (:func:`sparse_choose`) and
+    only those blocks' pages gathered and attended
+    (:func:`_attend_block_list`): what the step reads of the pools follows
+    the selection, not the context's length.  The list is ``topk`` wide;
+    only while some slot is still under ``dense_len`` with more live blocks
+    than that does the step take the wide branch
+    (:attr:`SparseSpec.list_width`), one program for both.
+
+    More rows (a chunk): each row has its own list, and this first form
+    lays it as a mask over the walk of every live block
+    (:func:`_attend_live_blocks`): the same mathematics, at dense cost."""
+    import jax
+    import jax.numpy as jnp
+
+    b, tq, _ = q.shape
+    kvh = int(num_kv_heads) or num_heads
+    pt = _plane(k_pool).shape[1]
+    m = table.shape[1]
+    nblocks = -(-m * pt // spec.block)
+    total = jnp.broadcast_to(
+        jnp.asarray(total_len, jnp.int32).reshape(-1), (b,))
+    n = total[:, None] - (tq - 1) + jnp.arange(tq, dtype=jnp.int32)[None, :]
+    on = jnp.ones((b,), bool) if active is None \
+        else jnp.asarray(active).reshape(-1).astype(bool)
+    live = jnp.sum(jnp.where(on[:, None], -(-n // spec.block), 0)) * kvh
+    with _scope(layer, "select"):
+        kbar = index[table]                               # (B, M, E)
+    if tq == 1:
+        width = min(spec.list_width, nblocks)
+        with _scope(layer, "select"):
+            score = sparse_block_scores(q, kbar, n, spec, nblocks, num_heads,
+                                        kvh, scale)
+            blocks, valid = sparse_choose(score, n, spec, width)
+            blocks, valid = blocks[:, :, 0], valid[:, :, 0]
+            chosen = jnp.sum(jnp.where(on[:, None, None], valid, False))
+        attend = lambda w: _attend_block_list(
+            q, k_pool, v_pool, table, blocks[..., :w], valid[..., :w],
+            total, spec, num_heads, kvh, scale, layer)
+        narrow = min(spec.topk, width)
+        if narrow < width:
+            wide = jnp.any(valid[..., narrow:] & on[:, None, None])
+            out = jax.lax.cond(wide, lambda: attend(width),
+                               lambda: attend(narrow))
+        else:
+            out = attend(width)
+        return out, (chosen.astype(jnp.int32), live.astype(jnp.int32))
+    with _scope(layer, "select"):
+        mask = sparse_block_mask(q, kbar, n, spec, nblocks, num_heads, kvh,
+                                 scale)
+        chosen = jnp.sum(jnp.where(on[:, None, None, None], mask, False))
+    plan = live_block_plan(q.shape, table.shape, pt, mesh_active=mesh_active)
+    if plan is not None and plan[0] % spec.block == 0:
+        out = _attend_live_blocks(q, k_pool, v_pool, table, total, num_heads,
+                                  scale, kvh, *plan, layer=layer,
+                                  chosen=(mask, spec.block))
+    else:
+        with _scope(layer, "kv_gather"):
+            k_view, v_view = paged_gather_kv(k_pool, v_pool, table)
+        allow = jnp.repeat(mask, spec.block, axis=3)[..., :m * pt]
+        out = _sdpa_cache(q, k_view, v_view, total, num_heads, scale,
+                          num_kv_heads=kvh, mesh_active=mesh_active,
+                          layer=layer,
+                          allow=allow if kvh == num_heads else allow[:, :, None])
+    return out, (chosen.astype(jnp.int32), live.astype(jnp.int32))
 
 
 def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
@@ -1300,6 +1714,13 @@ def register_all():
         extra = node_extras(attrs, sink[0] if sink else None)
         plain = not extra and v.shape[2] == k.shape[2]
         q, k = rotate_qk(attrs, q, k, np.arange(q.shape[1]))
+        spec = sparse_spec(attrs)
+        if spec is not None:
+            _note_path("einsum")
+            return [sdpa_sparse(q, k, v, spec, num_heads=heads, scale=scale,
+                                num_kv_heads=kv_heads,
+                                layer=attrs.get("__layer__")
+                                or "attn_sparse")], []
         if not plain:
             _note_path("einsum")
             return [sdpa(q, k, v, num_heads=heads, causal=causal,
@@ -1394,6 +1815,24 @@ def register_all():
             Param("rope_theta", float, default=10000.0),
             Param("value_scale", float, default=1.0,
                   doc="the output is multiplied by this"),
+            Param("sparse_topk", int, default=0,
+                  doc="sparse selection (SparseSpec): past "
+                      "sparse_dense_len positions of context a query "
+                      "attends this many blocks a KV group, chosen from an "
+                      "index of compressed keys; 0 = attend everything"),
+            Param("sparse_block", int, default=64,
+                  doc="positions a selectable block"),
+            Param("sparse_kernel", int, default=32,
+                  doc="positions a compressed key averages"),
+            Param("sparse_stride", int, default=16,
+                  doc="positions between two compressed keys (paged: the "
+                      "page size)"),
+            Param("sparse_init_blocks", int, default=1,
+                  doc="leading blocks always attended"),
+            Param("sparse_window", int, default=2048,
+                  doc="trailing positions always attended, in whole blocks"),
+            Param("sparse_dense_len", int, default=8192,
+                  doc="contexts up to this long are attended whole"),
         ),
         num_inputs=lambda a: 4 if a.get("sink") else 3,
         arguments=lambda a: ["query", "key", "value"]

@@ -24,6 +24,15 @@ decode layer executes:
   finishes — EOS mid-speculation-window included — so the pages are
   available to the very next admission attempt.
 
+What a node keeps a PAGE, and not a position, lives and dies with the page
+and needs no bookkeeping of its own: the planes of the decode layer's pools
+share the page ids this manager hands out, so the row of an int8 pool's
+scale plane and the row of a sparse-selection node's index of compressed
+keys (``ops.attention.paged_append_index``: one compressed key a KV head a
+page) are allocated when the page is mapped and free when it is freed.  A
+recycled page's stale rows are never read: the slot's length says which
+windows are complete, and a complete window's row has been rewritten.
+
 Tables are plain numpy; the decode layer ships them to the device as
 DATA every step (a few hundred int32s), which is what keeps one traced
 program serving every page mapping — the zero-retrace invariant.

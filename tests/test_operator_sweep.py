@@ -754,6 +754,7 @@ TESTED_ELSEWHERE = {
     "_contrib_DotProductAttention": "test_seq_parallel.py",
     "MoEFFN": "test_moe.py", "_contrib_MoEFFN": "test_moe.py",
     "SelectiveSSM": "test_ssm.py",
+    "LightningAttention": "test_linattn.py",
     "count_sketch": "test_spatial_contrib.py",
     "_contrib_count_sketch": "test_spatial_contrib.py",
     "_slice_assign": "test_reference_parity.py",
