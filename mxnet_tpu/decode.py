@@ -147,6 +147,15 @@ def _keep_tok(tok):
     return tok + 0
 
 
+def _split_key(key):
+    """A session's key split in two, as ONE program with a name (module
+    ``jit__split_key``): a sampling server runs it every tick, and a device
+    trace's time is joined to programs by their names."""
+    import jax
+
+    return tuple(jax.random.split(key))
+
+
 def _same_avals(a, b):
     import jax.tree_util as jtu
 
@@ -253,6 +262,12 @@ class DecodeState(NamedTuple):
     counts: object = None   # {name: int32} of what else the paged step's
                             # nodes counted (linattn_rows, sparse_blocks_
                             # chosen / _live); None as ``moe`` is
+    draft: object = None    # (B, 1) int32: the token the graph's own
+                            # prediction block drafted for the position after
+                            # ``tok``; None (no leaf) unless the state is a
+                            # self-drafting server's
+    draft_probs: object = None  # (B, V) float32: the distribution ``draft``
+                                # was drawn from; None under greedy sampling
 
 
 class DecodePredictor:
@@ -375,6 +390,11 @@ class DecodePredictor:
                                  or n.op.name in self._state_ops)]
         self._attn_nodes = [n for n in self._cache_nodes
                             if n.op.name == "dot_product_attention"]
+        # a multi-token-prediction block: the nodes that read the variable
+        # MTP_DATA (the token AFTER each position) run after the main head
+        # has said what that token is (:meth:`_run`'s ``between``)
+        self._late = self._nodes_after(self.MTP_DATA) \
+            if self.MTP_DATA in free and len(symbol._outputs) > 1 else None
         if not self._attn_nodes:
             raise MXNetError("symbol has no dot_product_attention node; "
                              "nothing to cache — use Predictor")
@@ -518,6 +538,22 @@ class DecodePredictor:
             # next step is queued: that step's donation consumes `state.tok`
             # and leaves this copy alone
             self._keep_fn = jax.jit(_keep_tok)
+            self._split_fn = jax.jit(_split_key)
+            if self._late:
+                # the three programs of a graph that drafts for itself; the
+                # tick's name holds "paged_decode", by which a reader of a
+                # device trace finds a decode program
+                self._mtp_fn = AotDispatch(
+                    "paged_decode_mtp_step", jax.jit(
+                        self._paged_decode_mtp_impl, donate_argnums=donate))
+                self._chunk_mtp_fn = AotDispatch(
+                    "prefill_chunk_mtp", jax.jit(self._mtp_chunk_impl,
+                                                 donate_argnums=half))
+                self._commit_mtp_fn = AotDispatch(
+                    "slot_commit_mtp", jax.jit(
+                        self._commit_mtp_impl,
+                        donate_argnums=(0, 1, 2, 3) if self._donate
+                        else ()))
             self._manager = None          # serve.PagedKVManager, per batch
             self._pools_template = None   # per-node cache avals (probed)
             self._paged_lens = None       # host mirror for standalone use
@@ -544,6 +580,43 @@ class DecodePredictor:
     @property
     def cache_len(self):
         return self._cache_len
+
+    # the free input a prediction block reads its next tokens from
+    MTP_DATA = "mtp_data"
+
+    @property
+    def self_drafting(self):
+        """Whether the graph brings a multi-token-prediction block: a second
+        output that, from the main stack's hidden state at a position and
+        the token after it, predicts the token after that."""
+        return self._late is not None
+
+    def _vocab_size(self):
+        """Width of the head's distribution, from an abstract walk over one
+        token (once)."""
+        import jax
+        import jax.numpy as jnp
+
+        from .analysis.artifact import aval_of
+        from .programs.spec import probing
+
+        if getattr(self, "_vocab", None) is None:
+            env = {n: aval_of(v) for n, v in self._env.items()}
+            with probing(self):
+                self._vocab = int(jax.eval_shape(
+                    lambda e: self._run(e, jnp.zeros((1, 1), jnp.float32),
+                                        None, 0)[0], env).shape[-1])
+        return self._vocab
+
+    def _nodes_after(self, variable):
+        """``{id(node)}`` of every node that depends on free input
+        ``variable``, itself included."""
+        late = set()
+        for node in self._symbol._topo():
+            if (node.is_variable and node.name == variable) or any(
+                    id(src) in late for src, _ in node.inputs):
+                late.add(id(node))
+        return late
 
     def _bind_cache_groups(self):
         """One :class:`CacheLayout` per stateful node, and the nodes
@@ -622,6 +695,14 @@ class DecodePredictor:
         serving loop refuses by name what such a group cannot carry
         (``serve.manager.WHY_NOT``)."""
         return [g for g in self._groups if g.kind != "full"]
+
+    def ring_slack(self, group):
+        """Positions a window group's ring holds beyond the widest window
+        of its nodes: what a step may write before it evicts a key that a
+        later query still sees."""
+        return group.capacity - max(
+            int(self._cache_nodes[i].parsed_attrs().get("window", 0) or 0)
+            for i in group.nodes)
 
     def state_nodes(self, counts):
         """How many of the graph's recurrent nodes are of the op that
@@ -733,7 +814,8 @@ class DecodePredictor:
                 name, lambda n=name, r=ref: (
                     r()._roofline_static(n) if r() is not None else None))
         names = (name,) + (tuple(self._BESIDE) if self._paged and name in (
-            "paged_decode_step", "paged_verify_step") else ())
+            "paged_decode_step", "paged_verify_step",
+            "paged_decode_mtp_step") else ())
         for n in names:
             _obs.programs.register_hlo(
                 n, lambda n=n, r=ref: (
@@ -743,12 +825,15 @@ class DecodePredictor:
     # the paged programs that hand the serving state on, and those the
     # loop dispatches beside them: map name -> (attribute of the dispatch,
     # kind in serving_avals)
-    _STEPS = ("prefill_chunk", "paged_decode_step", "paged_verify_step")
+    _CHUNKS = ("prefill_chunk", "prefill_chunk_mtp")
+    _STEPS = _CHUNKS + ("paged_decode_step", "paged_verify_step",
+                        "paged_decode_mtp_step")
     _BESIDE = {"slot_commit": ("_commit_fn", "commit"),
                "page_fork": ("_fork_fn", "fork"),
                "page_extract": ("_extract_fn", "extract"),
                "page_install": ("_install_fn", "install"),
-               "keep_tok": ("_keep_fn", None)}
+               "keep_tok": ("_keep_fn", None),
+               "key_split": ("_split_fn", None)}
 
     def _steady_state(self):
         """Avals of the serving state (pools, lengths, last tokens) as the
@@ -779,18 +864,23 @@ class DecodePredictor:
         snapped = {n: self._static_args[n] for n in self._STEPS
                    if n in self._static_args}
         state = next((a[1] for n, (_, a) in snapped.items()
-                      if n != "prefill_chunk"), None)
+                      if n not in self._CHUNKS), None)
         if state is None:       # chunks alone so far: the pools
-            state = DecodeState(snapped["prefill_chunk"][1][1], None, None)
+            state = DecodeState(next(iter(snapped.values()))[1][1], None,
+                                None)
         for _ in range(4):
             before = state
             for n, (fn, args) in snapped.items():
-                if n == "prefill_chunk":
+                if n in self._CHUNKS:
                     out = fn.eval_shape(args[0], state.caches, *args[2:])
-                    new = DecodeState(out[0], state.lens, state.tok)
+                    new = state._replace(caches=out[0])
                 else:
                     new = fn.eval_shape(args[0], state, *args[2:])[0]
-                    new = DecodeState(new.caches, new.lens, new.tok)
+                    new = state._replace(
+                        caches=new.caches, lens=new.lens, tok=new.tok,
+                        **({} if new.draft is None else {
+                            "draft": new.draft,
+                            "draft_probs": new.draft_probs}))
                 state = jtu.tree_map(back, state, new)
             if _same_avals(before, state):
                 break
@@ -807,6 +897,11 @@ class DecodePredictor:
         if name in self._BESIDE:
             attr, kind = self._BESIDE[name]
             fn = getattr(self, attr)
+            if name == "key_split":
+                # a key split from an uncommitted key stays uncommitted
+                from .analysis.artifact import aval_of
+
+                return fn, (aval_of(self._zero_key),)
             if kind is None:
                 return fn, (state.tok,)
             args = self.serving_avals(state.lens.shape[0]).get(kind)
@@ -820,9 +915,12 @@ class DecodePredictor:
                     + (first,)
             return fn, (state.caches,) + tuple(args[1:])
         fn, args = self._static_args[name]
-        carried = state.caches if name == "prefill_chunk" \
-            else args[1]._replace(caches=state.caches, lens=state.lens,
-                                  tok=state.tok)
+        carried = state.caches if name in self._CHUNKS \
+            else args[1]._replace(
+                caches=state.caches, lens=state.lens, tok=state.tok,
+                **({} if args[1].draft is None else {
+                    "draft": state.draft,
+                    "draft_probs": state.draft_probs}))
         return fn, (args[0], carried) + tuple(args[2:])
 
     def _program_hlo(self, name):
@@ -876,7 +974,7 @@ class DecodePredictor:
     # the shared graph walk (traced inside both programs)
     # ------------------------------------------------------------------
     def _run(self, env, tokens, caches, pos0, tables=None, active=None,
-             valid=None):
+             valid=None, between=None):
         """Execute the symbol on (B, t) tokens.
 
         ``caches is None`` = prefill mode: full causal attention, fresh
@@ -897,6 +995,14 @@ class DecodePredictor:
         V), caches)``; ``self._counts`` holds, by name, what each such node
         counted (the rows it advanced, the blocks it chose), for the
         program that called.
+
+        A graph with a prediction block (:attr:`self_drafting`) is walked in
+        two parts: the main stack and its head, then ``between(probs (B, t,
+        V))`` says which token follows each row (``MTP_DATA``, (B, t)), then
+        the block, at the same rows and positions; its distributions are
+        left in ``self._mtp_probs`` (B, t, V).  Without ``between`` a walk
+        over caches leaves the block out and hands its cache on as it came;
+        a walk that builds caches (prefill, the shape probe) feeds it zeros.
         """
         import jax
         import jax.numpy as jnp
@@ -910,10 +1016,30 @@ class DecodePredictor:
         ci = qi = 0
         values = {}
         base_key = jax.random.PRNGKey(0)
-        for seq, node in enumerate(self._symbol._topo()):
+        order = list(enumerate(self._symbol._topo()))
+        late, next_tokens = self._late, None
+        if late:
+            # no node of the stack reads one of the block's: the stack
+            # first, each part in the order it had
+            order.sort(key=lambda sn: id(sn[1]) in late)
+        for seq, node in order:
+            if late and id(node) in late:
+                if between is None and caches is not None:
+                    if not node.is_variable and (
+                            node.op.name == "dot_product_attention"
+                            or node.op.name in self._state_ops):
+                        new_caches.append(caches[ci])
+                        ci += 1
+                    continue
+                if next_tokens is None:
+                    next_tokens = jnp.zeros((b, t), jnp.float32) \
+                        if between is None else between(
+                            self._head_probs(values, 0, b, t))
             if node.is_variable:
                 if node.name == self._data_name:
                     val = tokens
+                elif late and node.name == self.MTP_DATA:
+                    val = next_tokens
                 elif node.name in env:
                     val = env[node.name]
                 else:
@@ -1096,14 +1222,20 @@ class DecodePredictor:
                     outs, _ = node.op.fcompute(attrs, ins, aux_ins, octx)
             for i, o in enumerate(outs):
                 values[(id(node), i)] = o
-        head_node, head_idx = self._symbol._outputs[0]
+        if late and between is not None:
+            self._mtp_probs = self._head_probs(values, 1, b, t)
+        return self._head_probs(values, 0, b, t), tuple(new_caches)
+
+    def _head_probs(self, values, which, b, t):
+        """Output ``which`` of the symbol as (B, t, V)."""
+        head_node, head_idx = self._symbol._outputs[which]
         out = values[(id(head_node), head_idx)]
         if out.ndim == 2 and out.shape[0] == b * t:
             out = out.reshape(b, t, -1)
         elif out.ndim != 3:
             raise MXNetError("decode: head output shape %s is not (B*t, V) "
                              "or (B, t, V)" % (out.shape,))
-        return out, tuple(new_caches)
+        return out
 
     def _fill_cache(self, x, num_heads=1):
         """(B, t, E) prefill K/V -> a (B, C, E) ring buffer holding the t
@@ -1327,6 +1459,149 @@ class DecodePredictor:
             return caches, probs, tok, sum(moe_rows)
         return caches, probs, tok
 
+    # ------------------------------------------------------------------
+    # a graph that drafts for itself (a multi-token-prediction block): the
+    # tick verifies the last draft, commits one or two tokens a slot and
+    # leaves the next draft, in one program
+    # ------------------------------------------------------------------
+    def _draft_of(self, key, probs):
+        """``(draft (B, 1), its distribution (B, V) or None)`` from the
+        block's output at one row a slot: drawn as :meth:`_sample` draws,
+        from the distribution :meth:`_policy_probs` states (None under
+        greedy sampling: the draft is the row's argmax)."""
+        return self._sample(key, probs), \
+            None if self._greedy else self._policy_probs(probs)
+
+    def _paged_decode_mtp_impl(self, env, state, tables, active, key):
+        """One self-drafting tick at fixed batch shape: the stack over the
+        two rows ``[tok, draft]`` of every slot, ``speculative_accept`` with
+        the draft's own distribution, then the prediction block over the
+        same two rows with the tokens that were committed after them, and a
+        new draft from the last committed row.  Returns ``(state, out (B,
+        2), counts (B,), probs (B, 2, V), block_probs (B, V))``: ``counts``
+        tokens of ``out`` were committed (0 for an inactive row); the
+        stack's distributions at the two rows and the block's at the row
+        the new draft came from are what a comparison reads, and the
+        serving loop leaves them on the device.  A rejected draft's keys
+        lie past the slot's new length in every pool, the block's included,
+        and the next tick's first row overwrites them before anything reads
+        them."""
+        import jax
+        import jax.numpy as jnp
+
+        from .ops.moe import collecting
+        from .ops.sample import speculative_accept
+
+        if not self._probing:
+            self.trace_counts["decode"] += 1
+        k_accept, k_draft = jax.random.split(key)
+        act = jnp.asarray(active).reshape(-1).astype(bool)
+        toks_in = jnp.concatenate([state.tok.astype(jnp.int32),
+                                   state.draft.astype(jnp.int32)], axis=1)
+        got = {}
+
+        def between(probs3):
+            pi = probs3 if self._greedy else self._policy_probs(probs3)
+            counts, out = speculative_accept(
+                k_accept, pi, state.draft,
+                None if self._greedy else state.draft_probs[:, None, :],
+                greedy=self._greedy)
+            got.update(counts=counts, out=out, moe_main=len(moe_rows),
+                       probs=probs3)
+            return out
+
+        with collecting(real=lambda: jnp.repeat(act, 2)) as moe_rows:
+            _, caches = self._run(env, toks_in, state.caches, state.lens,
+                                  tables=tables, active=active,
+                                  between=between)
+        counts, out = got["counts"], got["out"]
+        last = (counts - 1)[:, None]
+        block = jnp.take_along_axis(self._mtp_probs, last[:, :, None],
+                                    axis=1)[:, 0]
+        draft, dprobs = self._draft_of(k_draft, block)
+        tok = jnp.take_along_axis(out, last, axis=1)
+        keep = lambda new, old: jnp.where(act[:, None], new, old)
+        counts = jnp.where(act, counts, 0)
+        counted = {name: sum(v) for name, v in self._counts.items()}
+        n_main = got["moe_main"]
+        if moe_rows[n_main:]:
+            # the block's experts, apart: its time is the block's too
+            rows = sum(moe_rows[n_main:])
+            counted.update(mtp_moe_rows_held=rows[0],
+                           mtp_moe_expert_visits=rows[2])
+        return (DecodeState(
+            caches, state.lens + counts, keep(tok, state.tok),
+            sum(moe_rows[:n_main]) if n_main else None,
+            counted.pop("ssm_rows", None), counted or None,
+            keep(draft, state.draft),
+            None if dprobs is None else keep(dprobs, state.draft_probs)),
+            out, counts, got["probs"], block)
+
+    def _mtp_chunk_impl(self, env, caches, table1, toks, pos0, nvalid,
+                        next_tok, key):
+        """:meth:`_chunk_impl` for a graph that drafts for itself: the
+        prediction block runs over the chunk's rows too, each with the
+        prompt's own next token (``next_tok`` (1,) after the chunk's last
+        row; negative where the prompt ends there, and the token sampled
+        from that row takes its place), so the block's cache is whole when
+        decoding starts and the chunk that ends a prompt leaves the first
+        draft.  Returns ``(caches, probs, tok, draft, draft_probs,
+        block_probs[, moe])``, ``block_probs`` (1, V) the block's own
+        distribution at the chunk's last row."""
+        import jax
+        import jax.numpy as jnp
+
+        from .ops.moe import collecting
+
+        if not self._probing:
+            self.trace_counts["chunk"] += 1
+        k_tok, k_draft = jax.random.split(key)
+        ones = jnp.ones((toks.shape[0],), jnp.int32)
+        width = toks.shape[1]
+        nvalid = jnp.asarray(nvalid, jnp.int32).reshape(-1)
+        last = jnp.clip(nvalid - 1, 0, width - 1)
+        at_last = lambda x: jnp.take_along_axis(
+            x, last[:, None, None], axis=1)[:, 0]
+        got = {}
+
+        def between(probs3):
+            got["probs"] = probs = at_last(probs3)
+            got["tok"] = tok = self._sample(k_tok, probs)
+            nxt = jnp.asarray(next_tok, jnp.int32).reshape(-1, 1)
+            nxt = jnp.where(nxt < 0, tok, nxt).astype(toks.dtype)
+            shifted = jnp.concatenate(
+                [toks[:, 1:], jnp.zeros_like(toks[:, :1])], axis=1)
+            return jnp.where(jnp.arange(width)[None, :] == last[:, None],
+                             nxt, shifted)
+
+        def real():
+            return jnp.arange(width)[None, :] < nvalid[:, None]
+
+        with collecting(real=real) as moe_rows:
+            _, caches = self._run(env, toks, caches, pos0, tables=table1,
+                                  active=ones, valid=nvalid,
+                                  between=between)
+        block = at_last(self._mtp_probs)
+        draft, dprobs = self._draft_of(k_draft, block)
+        out = (caches, got["probs"], got["tok"], draft, dprobs, block)
+        return out + ((sum(moe_rows),) if moe_rows else ())
+
+    def _commit_mtp_impl(self, lens, tok, draft, dprobs, slot, new_len,
+                         new_tok, new_draft, new_dprobs):
+        """:meth:`_commit_impl` with the slot's first draft and its
+        distribution (None under greedy sampling) beside the first token."""
+        import jax
+        import jax.numpy as jnp
+
+        if not self._probing:
+            self.trace_counts["commit"] += 1
+        zero = jnp.int32(0)
+        put = lambda full, one: jax.lax.dynamic_update_slice(
+            full, one.astype(full.dtype), (slot, zero))
+        return (jax.lax.dynamic_update_slice(lens, new_len, (slot,)),
+                put(tok, new_tok), put(draft, new_draft),
+                None if dprobs is None else put(dprobs, new_dprobs))
+
     def _fork_impl(self, caches, src, dst):
         """Copy-on-write fork: duplicate page ``src`` into ``dst`` across
         every pool (page ids are one global space).  Traced once — the
@@ -1459,12 +1734,14 @@ class DecodePredictor:
             spec = P(None, None, None)
         return jax.device_put(buf, NamedSharding(self._mesh, spec))
 
-    def paged_batch_state(self, slots):
+    def paged_batch_state(self, slots, drafting=False):
         """Fresh paged serving state over ``slots`` slots: a new
         :class:`~mxnet_tpu.serve.PagedKVManager` (allocator + prefix
         cache + page tables) and zeroed pools.  Pool shapes depend only
         on (pool_pages, page_tokens, E), so repeated batches at one
-        sizing reuse every compiled program."""
+        sizing reuse every compiled program.  ``drafting``: the state of a
+        self-drafting server, with a draft token a slot and (unless
+        sampling is greedy) the distribution it was drawn from."""
         import jax
         import jax.numpy as jnp
 
@@ -1506,8 +1783,15 @@ class DecodePredictor:
 
             pools.append(_pool_pair(*leaves[:2], pool_of, *leaves[2:]))
         self._paged_lens = np.zeros(slots, np.int64)
-        return DecodeState(tuple(pools), jnp.zeros((slots,), jnp.int32),
-                           jnp.zeros((slots, 1), jnp.int32))
+        state = DecodeState(tuple(pools), jnp.zeros((slots,), jnp.int32),
+                            jnp.zeros((slots, 1), jnp.int32))
+        if drafting:
+            vocab = self._vocab_size()
+            state = state._replace(
+                draft=jnp.zeros((slots, 1), jnp.int32),
+                draft_probs=None if self._greedy else jnp.full(
+                    (slots, vocab), 1.0 / vocab, jnp.float32))
+        return state
 
     def pool_bytes(self):
         """Static bytes of the shared page pools — the paged serving HBM
@@ -1651,6 +1935,14 @@ class DecodePredictor:
             out["verify"] = (env, state, tables_of(slots), active,
                              sds((slots, int(spec_k)), jnp.int32), None,
                              key)
+        if self._late:
+            # a graph that drafts for itself: its tick and its chunk
+            drafting = state._replace(
+                draft=tok, draft_probs=None if self._greedy
+                else sds((slots, self._vocab_size()), jnp.float32))
+            out["mtp_step"] = (env, drafting, tables_of(slots), active, key)
+            out["mtp_chunk"] = out["chunk"][:-1] + (sds((1,), jnp.int32),
+                                                    key)
         return out
 
     def prepare_programs(self, slots, chunk_w=None, spec_k=0,
@@ -1776,7 +2068,9 @@ class DecodePredictor:
         cached = getattr(self, "_act_dev", None)
         if cached is None or cached[0] != act_key:
             self._act_dev = (act_key, jnp.asarray(act))
-        return (DecodeState(caches, state.lens, state.tok),
+        return (DecodeState(caches, state.lens, state.tok,
+                            draft=state.draft,
+                            draft_probs=state.draft_probs),
                 self._tables_dev[2], self._act_dev[1])
 
     def paged_step(self, state, lens_h, key=None, active=None):
@@ -1789,6 +2083,109 @@ class DecodePredictor:
         self._roofline_register("paged_decode_step", self._decode_fn, args)
         with _obs.program_span("paged_decode_step"):
             return self._decode_fn(*args)
+
+    def paged_mtp_step(self, state, lens_h, key=None, active=None,
+                       behind=0):
+        """One self-drafting tick (:meth:`_paged_decode_mtp_impl`), its whole
+        result: ``(state, out, counts, probs, block_probs)``.  ``lens_h`` is
+        the most each slot can hold by now and ``behind`` how far below it
+        the slot's length may lie (2 a tick the host has not read): the two
+        rows written start somewhere in between, and every page they may
+        land on is made writable."""
+        lo = np.maximum(np.asarray(lens_h, np.int64) - int(behind), 0)
+        state, tables, act = self.paged_prepare(
+            state, lo, 2 + int(behind), active)
+        args = (self._env, state, tables, act,
+                key if key is not None else self._zero_key)
+        self._roofline_register("paged_decode_mtp_step", self._mtp_fn, args)
+        with _obs.program_span("paged_decode_mtp_step"):
+            return self._mtp_fn(*args)
+
+    def mtp_chunk(self, caches, slot, prompt, pos, width, key):
+        """One chunk of ``prompt`` from ``pos`` through the self-drafting
+        chunk program (:meth:`_mtp_chunk_impl`), the prompt's next token
+        beside it: the program's whole result."""
+        n = min(int(width), int(prompt.size) - int(pos))
+        end = int(pos) + n
+        args = (self._env, caches) + self._chunk_operands(
+            slot, prompt[pos:end], pos, width) + (np.asarray(
+                [prompt[end] if end < prompt.size else -1], np.int32), key)
+        # its dispatch wall accrues to the "prefill" row; only the scope
+        # map knows the chunk program by its own name
+        self._roofline_register("prefill_chunk_mtp", self._chunk_mtp_fn,
+                                args, static=False)
+        with _obs.program_span("prefill"):
+            return self._chunk_mtp_fn(*args)
+
+    def mtp_commit(self, state, slot, plen, tok, draft, dprobs):
+        """``state`` with a prefilled slot's length, first token and first
+        draft spliced in (:meth:`_commit_mtp_impl`)."""
+        import jax.numpy as jnp
+
+        args = (state.lens, state.tok, state.draft, state.draft_probs,
+                np.int32(slot), jnp.asarray([plen], jnp.int32), tok, draft,
+                dprobs)
+        self._roofline_register("slot_commit_mtp", self._commit_mtp_fn, args,
+                                static=False)
+        lens, tok, draft, dprobs = self._commit_mtp_fn(*args)
+        return DecodeState(state.caches, lens, tok, draft=draft,
+                           draft_probs=dprobs)
+
+    def mtp_prefill(self, tokens, prompt_len=None, key=None):
+        """:meth:`prefill` for a graph that drafts for itself, by the
+        programs a self-drafting server runs: ``(state, probs (B, V),
+        block_probs (B, V))``, the stack's distribution of each row's first
+        token and the block's of the token after it."""
+        import jax
+        import jax.numpy as jnp
+
+        tokens = np.asarray(tokens)
+        b = tokens.shape[0]
+        lens_h = np.broadcast_to(np.asarray(
+            tokens.shape[1] if prompt_len is None else prompt_len,
+            np.int64).reshape(-1), (b,)).copy()
+        state = self.paged_batch_state(b, drafting=True)
+        mgr = self._manager
+        key = key if key is not None else self._zero_key
+        width = max(1, min(self._prefill_chunk or tokens.shape[1],
+                           self._cache_len))
+        probs_out, block_out = [], []
+        for row in range(b):
+            prompt = tokens[row, :int(lens_h[row])].astype(np.int64)
+            gate = mgr.gate(prompt, prompt.size, self._cache_len, 1,
+                            budget_wrap_forks=False)
+            if gate is None:
+                raise MXNetError(
+                    "KV page pool cannot admit a %d-token prompt — raise "
+                    "MXNET_KV_POOL_PAGES (pool: %d pages)"
+                    % (prompt.size, mgr.pool_pages))
+            mgr.map_slot(row, gate[1], gate[2])
+            pos, caches = int(gate[0]), state.caches
+            while pos < prompt.size:
+                copies = mgr.ensure(row, pos, min(pos + width, prompt.size))
+                if copies:
+                    caches = self._run_forks(caches, copies)
+                key, sub = jax.random.split(key)
+                caches, probs, tok, draft, dprobs, block = self.mtp_chunk(
+                    caches, row, prompt, pos, width, sub)[:6]
+                pos += width
+            mgr.publish(row, prompt, prompt.size)
+            state = self.mtp_commit(state._replace(caches=caches), row,
+                                    prompt.size, tok, draft, dprobs)
+            probs_out.append(probs)
+            block_out.append(block)
+        self._chunk_widths.add(width)
+        self._paged_lens = lens_h
+        return (state, jnp.concatenate(probs_out, axis=0),
+                jnp.concatenate(block_out, axis=0))
+
+    def mtp_step(self, state, key=None):
+        """One self-drafting tick after :meth:`mtp_prefill`:
+        :meth:`_paged_decode_mtp_impl`'s whole result.  Reads the counts, to
+        advance the host's lengths by what was committed."""
+        out = self.paged_mtp_step(state, self._paged_lens, key)
+        self._paged_lens += np.asarray(out[2]).astype(np.int64)
+        return out
 
     def paged_verify(self, state, lens_h, draft_toks, draft_probs=None,
                      key=None, active=None):
@@ -2644,7 +3041,19 @@ class DecodeServer:
         elif draft is not None:
             spec_k = int(spec_k) or 4
             proposer = DraftProposer(draft, spec_k)
-        elif spec_k:
+        # a graph with a prediction block of its own drafts for itself, on
+        # the device and inside the tick's program: no proposer, and the
+        # loop still reads one tick behind
+        self._mtp = bool(spec_k) and proposer is None \
+            and getattr(predictor, "self_drafting", False) \
+            and getattr(predictor, "_paged", False)
+        if self._mtp:
+            if int(spec_k) != 1:
+                raise MXNetError(
+                    "a graph that drafts with its own prediction block "
+                    "drafts one token a tick (spec_k=1); got spec_k=%d"
+                    % int(spec_k))
+        elif spec_k and proposer is None:
             proposer = NGramProposer(spec_k)
         self._spec_k = int(spec_k or 0)
         self._proposer = proposer
@@ -2656,12 +3065,17 @@ class DecodeServer:
 
         self._unshared = predictor.unshared_groups \
             if getattr(predictor, "_paged", False) else []
-        if self._unshared and (self._spec_k or proposer is not None):
-            kind, reason = why_not("speculation", self._unshared)
+        refused = why_not("speculation", self._unshared,
+                          rows=self._spec_k + 1,
+                          slack=predictor.ring_slack) \
+            if self._unshared and (self._spec_k or proposer is not None) \
+            else None
+        if refused:
             raise MXNetError(
                 "speculative decoding is not supported on a graph with a "
                 "%r cache group (groups: %s): %s"
-                % (kind, [g.name for g in predictor._groups], reason))
+                % (refused[0], [g.name for g in predictor._groups],
+                   refused[1]))
         if proposer is not None and getattr(proposer, "cache_len", None):
             if self._max_prefill > proposer.cache_len:
                 raise MXNetError(
@@ -2752,7 +3166,7 @@ class DecodeServer:
         # ring's pages nor a state row are restorable, so such a group
         # disarms it
         self._swap_armed = bool(_config.get("MXNET_FLEET_SWAP")) \
-            and not self._unshared
+            and not self._unshared and not self._mtp
         self._preempt_cb = None     # serve.fleet routes records back out
         self._verify_restore = False   # tests: assert restore bit-parity
         self._ps = None             # persistent paged session (tick API)
@@ -2903,6 +3317,12 @@ class DecodeServer:
         self._next_id += 1
         cap = int(max_new_tokens) if max_new_tokens is not None \
             else self._max_new
+        if self._mtp and tokens.size + cap + 2 > self._pred.cache_len:
+            raise MXNetError(
+                "a self-drafting server keeps a whole request in its "
+                "pages: prompt %d + max_new_tokens %d + a verify step's 2 "
+                "rows exceed cache_len %d"
+                % (tokens.size, cap, self._pred.cache_len))
         self._queue.append({"rid": rid, "prompt": tokens, "cap": cap,
                             "prio": int(priority), "swap": None})
         self._req[rid] = {"submit": time.time()}
@@ -3225,7 +3645,8 @@ class DecodeServer:
                 else "dense (non-paged)")
         self._ps = {
             "key": jax.random.PRNGKey(self._seed),
-            "state": pred.paged_batch_state(slots),
+            "state": pred.paged_batch_state(slots, drafting=True)
+            if self._mtp else pred.paged_batch_state(slots),
             "active": {},       # slot -> request record dict
             "results": {},
             "histories": {},
@@ -3583,7 +4004,8 @@ class DecodeServer:
             # split dispatches (a measurable slice of small-batch serve)
             if greedy:
                 return pred._zero_key
-            ps["key"], sub = jax.random.split(ps["key"])
+            ps["key"], sub = getattr(pred, "_split_fn",
+                                     jax.random.split)(ps["key"])
             return sub
 
         active = ps["active"]
@@ -3617,6 +4039,8 @@ class DecodeServer:
                "ssm": None,     # device count of state rows stepped
                "counts": {},    # {name: device count} of the step's others
                "toks": None,    # the step's tokens, a copy not donated on
+               "accepts": None,     # a self-drafting step: how many of its
+                                    # two tokens a slot each row committed
                "rows": [],      # (slot, record) the step computed for
                "note": {}}      # the arguments of its serve.readback span
 
@@ -3667,20 +4091,28 @@ class DecodeServer:
                 caches = pred._run_forks(state.caches, copies) \
                     if copies else state.caches
                 sub = next_key()
-                args = (pred._env, caches) + pred._chunk_operands(
-                    p["slot"], p["prompt"][p["pos"]:p["pos"] + n],
-                    p["pos"], self._chunk_w) + (sub,)
-                # its dispatch wall accrues to the "prefill" row; only
-                # the scope map knows the chunk program by its own name
-                pred._roofline_register("prefill_chunk", pred._chunk_fn,
-                                        args, static=False)
-                with _obs.program_span("prefill"):
-                    caches, probs, tok, *moe = pred._chunk_fn(*args)
+                if self._mtp:
+                    # the block reads each row's next token too: the
+                    # prompt's own after the chunk, none where it ends
+                    caches, probs, tok, draft, dprobs, _, *moe = \
+                        pred.mtp_chunk(caches, p["slot"], p["prompt"],
+                                       p["pos"], self._chunk_w, sub)
+                else:
+                    args = (pred._env, caches) + pred._chunk_operands(
+                        p["slot"], p["prompt"][p["pos"]:p["pos"] + n],
+                        p["pos"], self._chunk_w) + (sub,)
+                    # its dispatch wall accrues to the "prefill" row; only
+                    # the scope map knows the chunk program by its own name
+                    pred._roofline_register("prefill_chunk", pred._chunk_fn,
+                                            args, static=False)
+                    with _obs.program_span("prefill"):
+                        caches, probs, tok, *moe = pred._chunk_fn(*args)
                 if moe:
                     cur["moe"].append(("chunk", moe[0]))
                 self._m_ssm_chunk_tokens.inc(int(n) * self._ssm_nodes)
-                ps["state"] = state = DecodeState(caches, state.lens,
-                                                  state.tok)
+                ps["state"] = state = DecodeState(
+                    caches, state.lens, state.tok, draft=state.draft,
+                    draft_probs=state.draft_probs)
                 p["pos"] += n
                 pred._chunk_widths.add(self._chunk_w)
             if p["pos"] >= p["prompt"].size:
@@ -3692,10 +4124,14 @@ class DecodeServer:
                 with _obs.span("serve.commit", cat="serve",
                                args={"rid": p["rid"]}):
                     slot, plen = p["slot"], p["prompt"].size
-                    lens2, tok2 = pred._commit_fn(
-                        state.lens, state.tok, np.int32(slot),
-                        jnp.asarray([plen], jnp.int32), tok)
-                    ps["state"] = DecodeState(state.caches, lens2, tok2)
+                    if self._mtp:
+                        ps["state"] = pred.mtp_commit(state, slot, plen, tok,
+                                                      draft, dprobs)
+                    else:
+                        lens2, tok2 = pred._commit_fn(
+                            state.lens, state.tok, np.int32(slot),
+                            jnp.asarray([plen], jnp.int32), tok)
+                        ps["state"] = DecodeState(state.caches, lens2, tok2)
                     mgr.publish(slot, p["prompt"], plen)
                     if proposer is not None \
                             and getattr(proposer, "needs_prefill", False):
@@ -3729,7 +4165,7 @@ class DecodeServer:
             and max(slot_lens[s] for s in active) + k + 1 <= limit
         if self._eos_id is not None and ps["unread"] is not None \
                 and not all(mgr.within_reserve(
-                    s, int(slot_lens[s]), int(slot_lens[s]) + 1)
+                    s, int(slot_lens[s]), int(slot_lens[s]) + 1 + self._mtp)
                     for s in active):
             # a slot whose EOS is still unread would ride this step, and the
             # page its row needs is beyond what it reserved: find out first,
@@ -3761,22 +4197,35 @@ class DecodeServer:
         elif active:
             sub = next_key()
             with _obs.span("serve.decode_dispatch", cat="serve"):
-                state, _ = pred.paged_step(ps["state"], slot_lens, sub,
-                                           act_mask)
+                if self._mtp:
+                    # verify the last draft and leave the next, in one
+                    # program: one or two tokens a slot, how many the host
+                    # learns one tick on.  Until then ``slot_lens`` holds
+                    # the most a slot can have (two a tick), which
+                    # :meth:`_settle` takes back down; the step's tokens
+                    # and counts are results of its own, not donated on
+                    behind = 2 if ps["unread"] and ps["unread"]["rows"] \
+                        else 0
+                    state, cur["toks"], cur["accepts"] = \
+                        pred.paged_mtp_step(ps["state"], slot_lens, sub,
+                                            act_mask, behind=behind)[:3]
+                else:
+                    state, _ = pred.paged_step(ps["state"], slot_lens, sub,
+                                               act_mask)
+                    # state.tok is donated into the next step, which is
+                    # queued before the host reads this one: it reads a copy
+                    cur["toks"] = pred._keep_fn(state.tok)
                 ps["state"] = state
-                # state.tok is donated into the next step, which is queued
-                # before the host reads this one: it reads a copy
-                cur["toks"] = pred._keep_fn(state.tok)
             if state.moe is not None:
                 cur["moe"].append(("decode", state.moe))
             cur["ssm"] = state.ssm
             cur["counts"] = state.counts or {}
             cur["rows"] = list(active.items())
             for rec in active.values():
-                rec["unread"] += 1
+                rec["unread"] += 1      # at least: what leave_due counts on
             self._note_attn_blocks(slot_lens, act_mask, cur["note"])
-            self._note_step()
-            slot_lens += act_mask.astype(np.int64)
+            self._note_step(spec=self._mtp)
+            slot_lens += (1 + self._mtp) * act_mask.astype(np.int64)
             leave_due()
         # --- (5) read the PREVIOUS tick: its step is done or running, and
         # this tick's programs are queued behind it
@@ -3808,7 +4257,8 @@ class DecodeServer:
             names = sorted(fl["counts"])
             got = jax.device_get(
                 [tok for _, tok in firsts] + [vec for _, vec in moe]
-                + [a for a in (fl["toks"], fl["ssm"]) if a is not None]
+                + [a for a in (fl["toks"], fl["accepts"], fl["ssm"])
+                   if a is not None]
                 + [fl["counts"][name] for name in names])
             now = time.time()
             for name in reversed(names):
@@ -3817,7 +4267,17 @@ class DecodeServer:
             if fl["ssm"] is not None:
                 note["ssm_rows"] = int(got.pop())
                 self._m_ssm_rows.inc(note["ssm_rows"])
-            toks = got.pop()[:, 0] if fl["toks"] is not None else None
+            accepts = got.pop() if fl["accepts"] is not None else None
+            toks = got.pop() if fl["toks"] is not None else None
+            if accepts is not None:
+                # a draft a live row, and what the device took and
+                # committed of them (a cap or an EOS inside a pair may cut
+                # what reaches the request: mx_serve_tokens counts that)
+                live = [slot for slot, rec in rows if not rec.get("closed")]
+                took = int(sum(accepts[slot] for slot in live)) - len(live)
+                self._note_accept(len(live), took)
+                note.update(spec_proposed=len(live), spec_accepted=took,
+                            tokens_committed=len(live) + took)
             self._note_moe([(program, vec) for (program, _), vec
                             in zip(moe, got[len(firsts):])], note)
         with _obs.span("serve.deliver", cat="serve"):
@@ -3829,14 +4289,18 @@ class DecodeServer:
                 self._req[rec["rid"]]["first"] = now
             dropped = 0
             for slot, rec in rows:
+                # one token a row; a self-drafting step's one or two
+                n = 1 if accepts is None else int(accepts[slot])
+                if accepts is not None and ps["active"].get(slot) is rec:
+                    ps["slot_lens"][slot] -= 2 - n
                 if rec.get("closed"):   # retired at its EOS, a step ago
                     dropped += 1
                     continue
                 rec["unread"] -= 1
                 held = len(rec["toks"])
-                self._deliver(rec, toks[slot:slot + 1])
+                self._deliver(rec, toks[slot, :n])
                 dropped += len(rec["toks"]) == held
-                rec["hist"].append(int(toks[slot]))
+                rec["hist"].extend(int(t) for t in toks[slot, :n])
             if dropped:
                 self._m_dropped.inc(dropped)
             for rec in [r for r, _ in firsts] + [r for _, r in rows]:

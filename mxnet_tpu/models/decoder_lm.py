@@ -59,6 +59,28 @@ model of the family is a configuration file and no code:
   ``hidden_size / dim_model_base``; 0 = none); ``scale_emb`` is
   ``embedding_multiplier``.
 
+* ``layer_types[l]`` (``"sliding_attention"`` | ``"full_attention"``) and
+  ``mlp_layer_types[l]`` (``"dense"`` | ``"sparse"``): the same two choices
+  under the names newer configurations give them; ``rope_parameters``
+  (``{"rope_theta": ...}``) likewise for ``rope_theta``;
+* ``attn_qk_norm``: every q and k head is normed by an RMSNorm with one
+  learned gain of ``head_dim`` a layer (``_q_norm`` / ``_k_norm``), before
+  the rotation; ``full_attn_use_rope`` false: the full-attention layers
+  take no positions, the window layers keep their rotary;
+* ``n_shared_experts`` / ``routed_scaling_factor``: an always-on gated MLP
+  of ``n_shared_experts x moe_intermediate_size`` beside the routed experts
+  of every MoE layer (inside the op and its share), and the routed weights
+  x the factor after normalising;
+* ``num_nextn_predict_layers`` 1: a multi-token-prediction block after the
+  stack, every node under the scope ``mtp``.  At position i it reads the
+  stack's last hidden state (before the final norm) and the embedding of
+  token i + 1 (the variable ``mtp_data``, (B, T)): ``u = W_p [RMSNorm_e(
+  Emb[t[i+1]]) ; RMSNorm_h(x_L[i])]``, one block of the expert layers' kind
+  with full attention, then the main model's head over ``RMSNorm_m``: the
+  distribution of token i + 2 (``mtp_softmax``, labels ``mtp_label``).
+  Embedding and head are the main model's own matrices.  The symbol is
+  then a group of two outputs, the main softmax first.
+
 ``hybrid_layer_pattern`` and ``moe_layer_freq`` default to zeros: full
 attention and a dense MLP in every layer.  Entries ``first_layer ..
 first_layer + num_layers - 1`` of the per-layer lists are built.
@@ -75,6 +97,7 @@ _WINDOW = {LAYER_ATTR: "attn_window"}
 _SPARSE = {LAYER_ATTR: "attn_sparse"}
 _MLP = {LAYER_ATTR: "linear"}
 _HEAD = {LAYER_ATTR: "head_loss"}
+_MTP = {LAYER_ATTR: "mtp"}
 
 
 def rotary_dims(head_dim, partial_rotary_factor):
@@ -100,11 +123,17 @@ def sparse_attrs(sparse_config):
 
 def attention(data, name, window, hidden, heads, kv_heads, head_dim,
               v_head_dim, rotary_dim, theta, value_scale, sink,
-              key_multiplier=1.0, sparse=None, gate=False):
+              key_multiplier=1.0, sparse=None, gate=False, qk_norm_eps=0.0,
+              layer=None):
     q = sym.FullyConnected(data, num_hidden=heads * head_dim, no_bias=True,
                            flatten=False, name=name + "_q")
     k = sym.FullyConnected(data, num_hidden=kv_heads * head_dim,
                            no_bias=True, flatten=False, name=name + "_k")
+    if qk_norm_eps:
+        q, k = (sym.Reshape(sym.RMSNorm(
+            sym.Reshape(x, shape=(0, 0, -1, head_dim)), eps=qk_norm_eps,
+            name="%s_%s_norm" % (name, part)), shape=(0, 0, -1))
+            for x, part in ((q, "q"), (k, "k")))
     v = sym.FullyConnected(data, num_hidden=kv_heads * v_head_dim,
                            no_bias=True, flatten=False, name=name + "_v")
     # keys x key_multiplier: the same logits as a scale of multiplier /
@@ -112,7 +141,8 @@ def attention(data, name, window, hidden, heads, kv_heads, head_dim,
     scaled = {} if float(key_multiplier) == 1.0 else {
         "scale": float(key_multiplier) / float(head_dim) ** 0.5}
     scaled.update(sparse_attrs(sparse) if sparse else {})
-    with AttrScope(**(_WINDOW if window else _SPARSE if sparse else {})):
+    with AttrScope(**(layer or (_WINDOW if window else _SPARSE if sparse
+                                else {}))):
         att = sym.dot_product_attention(
             q, k, v, num_heads=heads, num_kv_heads=kv_heads, causal=True,
             window=window, sink=sink, rotary_dim=rotary_dim,
@@ -208,7 +238,11 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                attn_use_output_gate=False, lightning_nh=0,
                lightning_head_dim=0, lightning_use_rope=True, qk_norm=True,
                use_output_norm=True, use_output_gate=True, scale_depth=0.0,
-               dim_model_base=0, **kwargs):
+               dim_model_base=0, layer_types=None, mlp_layer_types=None,
+               rope_parameters=None, attn_qk_norm=False,
+               full_attn_use_rope=True, n_shared_experts=0,
+               routed_scaling_factor=1.0, num_nextn_predict_layers=0,
+               **kwargs):
     """data (B, T) int tokens -> softmax over the vocabulary at every
     position (``softmax_label`` (B, T) next tokens, pad = -1 ignored)."""
     heads = int(num_attention_heads)
@@ -217,8 +251,56 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
     first = int(first_layer)
     total = int(total_layers) or int(num_layers)
     zeros = (0,) * (first + int(num_layers))
+    if layer_types:
+        hybrid_layer_pattern = tuple(
+            int(kind == "sliding_attention") for kind in layer_types)
+    if mlp_layer_types:
+        moe_layer_freq = tuple(int(kind == "sparse")
+                               for kind in mlp_layer_types)
+    if rope_parameters:
+        rope_theta = dict(rope_parameters).get("rope_theta", rope_theta)
+    if int(num_nextn_predict_layers) not in (0, 1):
+        raise ValueError("num_nextn_predict_layers %r: one multi-token-"
+                         "prediction block is built, or none"
+                         % (num_nextn_predict_layers,))
     hybrid_layer_pattern = hybrid_layer_pattern or zeros
     moe_layer_freq = moe_layer_freq or zeros
+    # the experts' op attributes that only some configurations state: left
+    # off the node where they say nothing, so that the others' graphs stand
+    moe_more = {}
+    if int(n_shared_experts or 0):
+        moe_more["n_shared_experts"] = int(n_shared_experts)
+    if float(routed_scaling_factor or 1.0) != 1.0:
+        moe_more["routed_scaling_factor"] = float(routed_scaling_factor)
+
+    def experts(normed, name):
+        return sym.MoEFFN(
+            normed, num_experts=int(n_routed_experts),
+            hidden_size=int(moe_intermediate_size), gated=True,
+            num_experts_per_tok=int(num_experts_per_tok),
+            score_func=scoring_func, score_bias=topk_method == "noaux_tc",
+            norm_topk=bool(norm_topk_prob), num_held=int(num_held),
+            first_held=int(first_held), name=name + "_moe", **moe_more)
+
+    def attend(normed, name, windowed, selects=False, layer=None):
+        return attention(
+            normed, name, window=int(sliding_window) if windowed else 0,
+            hidden=hidden_size, heads=heads,
+            kv_heads=int((swa_num_key_value_heads if windowed
+                          else num_key_value_heads) or heads),
+            head_dim=int(head_dim), v_head_dim=v_head_dim,
+            rotary_dim=rotary if (attn_use_rope or not selects) and (
+                windowed or full_attn_use_rope) else 0,
+            theta=float((swa_rope_theta if windowed else 0.0)
+                        or rope_theta),
+            value_scale=float(attention_value_scale),
+            sink=bool(add_swa_attention_sink_bias if windowed
+                      else add_full_attention_sink_bias),
+            key_multiplier=key_multiplier,
+            sparse=sparse_config if selects else None,
+            gate=bool(selects and attn_use_output_gate),
+            qk_norm_eps=float(layernorm_epsilon) if attn_qk_norm else 0.0,
+            layer=layer)
     mixer_types = mixer_types or ("",) * len(zeros)
     # MiniCPM's muP: every residual branch, and the logits
     depth = float(scale_depth) / total ** 0.5 if float(scale_depth) else 1.0
@@ -234,9 +316,13 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                          "built with its bias (ops.ssm)")
     data = sym.Variable("data")
     label = sym.Variable("softmax_label")
+    # with a prediction block the embedding is read twice: one variable,
+    # under the name it would have had
+    embed_w = {"weight": sym.Variable("embed_weight")} \
+        if int(num_nextn_predict_layers) else {}
     net = times(sym.Embedding(data, input_dim=vocab_size,
-                              output_dim=hidden_size, name="embed"),
-                embedding_multiplier)
+                              output_dim=hidden_size, name="embed",
+                              **embed_w), embedding_multiplier)
     for i in range(first, first + int(num_layers)):
         name = "layer%d" % i
         windowed = bool(hybrid_layer_pattern[i])
@@ -266,43 +352,58 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                 int(mamba_chunk_size), layernorm_epsilon,
                 bool(mamba_proj_bias), ssm_state_dtype, ssm_multipliers),
                 ssm_out_multiplier)
-        net = net + times(attention(
-            times(normed, attention_in_multiplier), name,
-            window=int(sliding_window) if windowed else 0,
-            hidden=hidden_size, heads=heads,
-            kv_heads=int((swa_num_key_value_heads if windowed
-                          else num_key_value_heads) or heads),
-            head_dim=int(head_dim), v_head_dim=v_head_dim,
-            rotary_dim=rotary if attn_use_rope or not selects else 0,
-            theta=float((swa_rope_theta if windowed else 0.0)
-                        or rope_theta),
-            value_scale=float(attention_value_scale),
-            sink=bool(add_swa_attention_sink_bias if windowed
-                      else add_full_attention_sink_bias),
-            key_multiplier=key_multiplier,
-            sparse=sparse_config if selects else None,
-            gate=bool(selects and attn_use_output_gate)),
+        net = net + times(attend(
+            times(normed, attention_in_multiplier), name, windowed, selects),
             attention_out_multiplier * depth)
         normed = sym.RMSNorm(net, eps=layernorm_epsilon,
                              name=name + "_ffn_norm")
         if moe_layer_freq[i]:
-            ffn = sym.MoEFFN(
-                normed, num_experts=int(n_routed_experts),
-                hidden_size=int(moe_intermediate_size), gated=True,
-                num_experts_per_tok=int(num_experts_per_tok),
-                score_func=scoring_func,
-                score_bias=topk_method == "noaux_tc",
-                norm_topk=bool(norm_topk_prob), num_held=int(num_held),
-                first_held=int(first_held), name=name + "_moe")
+            ffn = experts(normed, name)
         else:
             ffn = gated_mlp(normed, name, hidden_size,
                             int(intermediate_size), mlp_multipliers)
         net = net + times(ffn, depth)
-    net = sym.RMSNorm(net, eps=layernorm_epsilon, name="final_norm")
-    with AttrScope(**_HEAD):
-        logits = times(sym.FullyConnected(
-            sym.Reshape(net, shape=(-1, hidden_size)), num_hidden=vocab_size,
-            no_bias=True, name="head"), lm_head_multiplier)
-        flat_label = sym.Reshape(label, shape=(-1,))
-        return sym.SoftmaxOutput(logits, flat_label, use_ignore=True,
-                                 ignore_label=-1, name="softmax")
+    if not int(num_nextn_predict_layers):
+        net = sym.RMSNorm(net, eps=layernorm_epsilon, name="final_norm")
+        with AttrScope(**_HEAD):
+            logits = times(sym.FullyConnected(
+                sym.Reshape(net, shape=(-1, hidden_size)),
+                num_hidden=vocab_size, no_bias=True, name="head"),
+                lm_head_multiplier)
+            flat_label = sym.Reshape(label, shape=(-1,))
+            return sym.SoftmaxOutput(logits, flat_label, use_ignore=True,
+                                     ignore_label=-1, name="softmax")
+    # the head's matrix too is read twice: one variable
+    head_w = sym.Variable("head_weight")
+
+    def head(x, lab, scope, name):
+        with AttrScope(**scope):
+            logits = times(sym.FullyConnected(
+                sym.Reshape(x, shape=(-1, hidden_size)), weight=head_w,
+                num_hidden=vocab_size, no_bias=True, name=name + "head"),
+                lm_head_multiplier)
+            return sym.SoftmaxOutput(
+                logits, sym.Reshape(lab, shape=(-1,)), use_ignore=True,
+                ignore_label=-1, name=name + "softmax")
+
+    main = head(sym.RMSNorm(net, eps=layernorm_epsilon, name="final_norm"),
+                label, _HEAD, "")
+    with AttrScope(**_MTP):
+        nxt = times(sym.Embedding(
+            sym.Variable("mtp_data"), input_dim=vocab_size,
+            output_dim=hidden_size, name="mtp_embed", **embed_w),
+            embedding_multiplier)
+        u = sym.FullyConnected(sym.Concat(
+            sym.RMSNorm(nxt, eps=layernorm_epsilon, name="mtp_enorm"),
+            sym.RMSNorm(net, eps=layernorm_epsilon, name="mtp_hnorm"),
+            dim=2), num_hidden=hidden_size, no_bias=True, flatten=False,
+            name="mtp_proj")
+        u = u + times(attend(
+            sym.RMSNorm(u, eps=layernorm_epsilon, name="mtp_att_norm"),
+            "mtp", windowed=False, layer=_MTP), depth)
+        u = u + times(experts(
+            sym.RMSNorm(u, eps=layernorm_epsilon, name="mtp_ffn_norm"),
+            "mtp"), depth)
+        u = sym.RMSNorm(u, eps=layernorm_epsilon, name="mtp_final_norm")
+    return sym.Group([main, head(u, sym.Variable("mtp_label"), _MTP,
+                                 "mtp_")])

@@ -44,10 +44,14 @@ LAYER_OF_OP = {
 # "attn_window" is what a model builder names its sliding-window attention
 # nodes (LAYER_ATTR), so that "attn" keeps meaning attention over the whole
 # context; "attn_sparse" likewise its nodes with sparse selection
+# "mtp" is what a builder names every node of a multi-token-prediction block:
+# the block's attention, experts and head are then one layer of their own
 LAYERS = tuple(sorted(set(LAYER_OF_OP.values()))) + (
-    "attn_window", "attn_sparse", "other", "optimizer", "metric")
+    "attn_window", "attn_sparse", "mtp", "other", "optimizer", "metric")
 SUBSCOPES = ("kv_append", "kv_gather", "kv_dequant", "scores", "rope",
              "route", "experts", "combine",
+             # the always-on gated MLP beside the routed experts
+             "shared",
              # the state-space mixer: its convolution, the recurrence over a
              # chunk or a sequence ("scan") and over one token a slot
              # ("step"), the gate with its grouped norm

@@ -70,6 +70,14 @@ def _held(attrs):
     return int(attrs.get("num_held", 0) or 0) or int(attrs["num_experts"])
 
 
+def _shared_width(attrs):
+    """Width of the always-on gated MLP beside the routed experts:
+    ``n_shared_experts`` experts of the routed experts' width, as one MLP
+    (0 = none)."""
+    return int(attrs.get("n_shared_experts", 0) or 0) \
+        * int(attrs["hidden_size"])
+
+
 def _moe_shape(attrs, in_shapes, aux_shapes):
     x = in_shapes[0]
     e = attrs["num_experts"]
@@ -80,6 +88,9 @@ def _moe_shape(attrs, in_shapes, aux_shapes):
         bias = [(e,)] if attrs.get("score_bias") else []
         want = [tuple(x), (d, e)] + bias + [(held, d, h), (held, d, h),
                                             (held, h, d)]
+        hs = _shared_width(attrs)
+        if hs:
+            want += [(d, hs), (d, hs), (hs, d)]
         return want, [tuple(x)], []
     want = [tuple(x), (d, e), (e, d, h), (e, h), (e, h, d), (e, d)]
     return want, [tuple(x)], []
@@ -90,7 +101,9 @@ def _moe_arguments(attrs):
         return ["data", "gate_weight"] \
             + (["gate_bias"] if attrs.get("score_bias") else []) \
             + ["expert_gate_weight", "expert_up_weight",
-               "expert_down_weight"]
+               "expert_down_weight"] \
+            + (["shared_gate_weight", "shared_up_weight",
+                "shared_down_weight"] if _shared_width(attrs) else [])
     return ["data", "gate_weight", "expert1_weight", "expert1_bias",
             "expert2_weight", "expert2_bias"]
 
@@ -152,9 +165,13 @@ def _scores(xt, wr, bias, k, score_func, norm_topk):
     return choice, weight
 
 
-def _moe_share(x, wr, bias, wg, wu, wd, attrs, k):
+def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None):
     """The gated layer at this chip's share: what the held experts add to
-    each token's output, and nothing in place of the others.
+    each token's output, and nothing in place of the others.  ``shared``
+    (gate, up, down; ``n_shared_experts``) is an always-on gated MLP that
+    every chip holds whole and adds to its own tokens: it is in every share,
+    so where shares are added up it is counted in one of them.  The routed
+    weights are scaled by ``routed_scaling_factor`` after normalising.
 
     Every held expert runs over every token and the unchosen pairs weigh
     zero: held x n rows of work whatever the routing, so no token is ever
@@ -172,8 +189,11 @@ def _moe_share(x, wr, bias, wg, wu, wd, attrs, k):
     import jax
     import jax.numpy as jnp
 
-    from ..obs.scopes import scope as _scope
+    from ..obs.scopes import LAYER_ATTR, scope as _scope
 
+    # the scope the node's time is filed under: "moe", unless the builder
+    # names another (a block that is a layer of its own)
+    layer = attrs.get(LAYER_ATTR) or "moe"
     first = int(attrs.get("first_held", 0) or 0)
     held = _held(attrs)
     e = int(attrs["num_experts"])
@@ -182,24 +202,33 @@ def _moe_share(x, wr, bias, wg, wu, wd, attrs, k):
                          "not among the layer's %d"
                          % (first, first + held, wg.shape[0], e))
     xt = x.reshape(-1, x.shape[-1])
-    with _scope("moe", "route"):
+    with _scope(layer, "route"):
         choice, weight = _scores(xt, wr, bias, min(k, e),
                                  attrs.get("score_func", "softmax"),
                                  bool(attrs.get("norm_topk", True)))
+        factor = float(attrs.get("routed_scaling_factor", 1.0) or 1.0)
+        if factor != 1.0:
+            weight = weight * factor
         # which held expert each (token, choice) pair goes to, if any
         here = choice[:, :, None] \
             == (first + jnp.arange(held))[None, None, :]  # (n, k, held)
     MOE_PATH["last"] = "held_dense"
-    with _scope("moe", "experts"):
+    with _scope(layer, "experts"):
         g = jnp.einsum("nd,edh->enh", xt, wg)
         u = jnp.einsum("nd,edh->enh", xt, wu)
         y = jnp.einsum("enh,ehd->end", jax.nn.silu(g) * u, wd,
                        preferred_element_type=jnp.float32)
-    with _scope("moe", "combine"):
+    with _scope(layer, "combine"):
         w = (here * weight[:, :, None]).sum(1)            # (n, held)
         out = jnp.einsum("end,ne->nd", y, w)
+    if shared is not None:
+        sg, su, sd = shared
+        with _scope(layer, "shared"):
+            out = out + jnp.dot(
+                jax.nn.silu(jnp.dot(xt, sg)) * jnp.dot(xt, su), sd,
+                preferred_element_type=jnp.float32)
     if _STATS["sink"] is not None:
-        with _scope("moe", "route"):
+        with _scope(layer, "route"):
             pairs = here.any(-1)                          # (n, k)
             real = _STATS["real"]
             if callable(real):
@@ -672,7 +701,9 @@ def register_all():
         if attrs.get("gated"):
             x, wr, *rest = inputs
             bias = rest.pop(0) if attrs.get("score_bias") else None
-            return [_moe_share(x, wr, bias, *rest, attrs, k)], []
+            shared = tuple(rest[3:]) if _shared_width(attrs) else None
+            return [_moe_share(x, wr, bias, *rest[:3], attrs, k,
+                               shared=shared)], []
         if attrs.get("score_bias") or attrs.get("num_held") \
                 or attrs.get("score_func", "softmax") != "softmax":
             raise ValueError("MoEFFN: score_func, score_bias and num_held "
@@ -733,8 +764,16 @@ def register_all():
                       "and the output is the held experts' part; 0 = all"),
             Param("first_held", int, default=0,
                   doc="the first expert held"),
+            Param("n_shared_experts", int, default=0,
+                  doc="always-on gated experts beside the routed ones, "
+                      "held as one MLP of n_shared_experts x hidden_size "
+                      "(shared_gate/up/down_weight, (d, w) and (w, d)) and "
+                      "added in every share"),
+            Param("routed_scaling_factor", float, default=1.0,
+                  doc="the routed weights x this, after norm_topk"),
         ),
-        num_inputs=lambda a: (5 + bool(a.get("score_bias")))
+        num_inputs=lambda a: (5 + bool(a.get("score_bias"))
+                              + 3 * bool(a.get("n_shared_experts")))
         if a.get("gated") else 6,
         arguments=_moe_arguments,
         infer_shape=_moe_shape,

@@ -54,8 +54,10 @@ WHY_NOT = {
     "window": {
         "prefix": "a matched prefix's ring content is gone once the donor "
                   "has moved on",
-        "speculation": "a rejected draft's keys would sit in ring positions "
-                       "the length mask cannot hide",
+        "speculation": "the ring has fewer positions beyond its window "
+                       "than a verify step writes rows (k + 1): a rejected "
+                       "draft's keys would evict keys a later query still "
+                       "sees",
         "restore": "a ring keeps its last positions only, and its pages are "
                    "not a request's whole context to extract or install",
     },
@@ -70,10 +72,18 @@ WHY_NOT = {
 }
 
 
-def why_not(what, groups):
+def why_not(what, groups, rows=0, slack=None):
     """``(kind, reason)`` of the first of ``groups`` that cannot carry
-    ``what`` ("prefix" | "speculation" | "restore"); None where all can."""
+    ``what`` ("prefix" | "speculation" | "restore"); None where all can.  A
+    window group carries speculation where its ring has ``rows`` (a verify
+    step's k + 1) positions beyond its window (``slack(group)``): keys are
+    masked by position and not by ring index, a verify's writes then evict
+    nothing a later query sees, and a rejected row is overwritten by the
+    next step's first write before any query reads it."""
     for g in groups:
+        if g.kind == "window" and what == "speculation" \
+                and slack is not None and 0 < rows <= slack(g):
+            continue
         if g.kind in WHY_NOT:
             return g.kind, WHY_NOT[g.kind][what]
     return None

@@ -1,0 +1,431 @@
+"""A server that drafts with its graph's own multi-token-prediction block:
+one program a tick verifies the last draft over two rows a slot, commits one
+or two tokens and leaves the next draft, and the loop still reads one tick
+behind.  The oracle throughout is the same graph served without drafts.
+
+Greedy sampling makes acceptance a fact and not a chance (a draft is taken
+iff it is the stack's argmax), so the tests put the drafts they want into the
+device state between ticks: the oracle's own next token (accepted for sure),
+or another (rejected for sure).
+"""
+import hashlib
+import json
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import mxnet_tpu as mx
+from mxnet_tpu import obs
+from mxnet_tpu.base import MXNetError
+from mxnet_tpu.decode import DecodePredictor, DecodeServer
+from mxnet_tpu.models import decoder_lm
+
+VOCAB, CACHE, PAGE, CHUNK = 64, 64, 4, 8
+FREE = ("data", "softmax_label", "mtp_data", "mtp_label")
+
+
+def toy_symbol(**over):
+    """Four layers (window, window, full, window; the first dense, the rest
+    experts with a shared one), q/k norms, no positions on the full layer,
+    and the prediction block."""
+    args = dict(
+        vocab_size=VOCAB, hidden_size=32, num_layers=4,
+        num_attention_heads=4, head_dim=8, num_key_value_heads=2,
+        swa_num_key_value_heads=2,
+        layer_types=["sliding_attention", "sliding_attention",
+                     "full_attention", "sliding_attention"],
+        mlp_layer_types=["dense", "sparse", "sparse", "sparse"],
+        intermediate_size=48, moe_intermediate_size=16, n_routed_experts=8,
+        num_experts_per_tok=2, scoring_func="sigmoid",
+        topk_method="noaux_tc", sliding_window=4,
+        rope_parameters={"rope_theta": 1e6}, attn_qk_norm=True,
+        full_attn_use_rope=False, n_shared_experts=1,
+        routed_scaling_factor=2.5, num_nextn_predict_layers=1)
+    args.update(over)
+    return decoder_lm.get_symbol(**args)
+
+
+def toy_params(sym, seed=0, head_std=0.15):
+    shapes, _, _ = sym.infer_shape(**{n: (1, CACHE) for n in FREE})
+    rng = np.random.default_rng(seed)
+    out = {}
+    for name, shape in zip(sym.list_arguments(), shapes):
+        if name in FREE:
+            continue
+        z = rng.standard_normal(shape).astype(np.float32)
+        out[name] = mx.nd.NDArray(jnp.asarray(
+            1.0 + 0.1 * z if name.endswith("gamma")
+            else 0.01 * z if name.endswith("gate_bias")
+            else head_std * z if name == "head_weight" else 0.15 * z),
+            mx.cpu())
+    return out
+
+
+@pytest.fixture(scope="module")
+def toy():
+    sym = toy_symbol()
+    return sym, toy_params(sym)
+
+
+def make_server(toy, spec_k, eos=None, temperature=0.0, kv_dtype="",
+                slots=2, seed=0, chunk=CHUNK):
+    sym, params = toy
+    pred = DecodePredictor(sym, params, cache_len=CACHE, ctx=mx.cpu(),
+                           paged=True, page_tokens=PAGE, kv_dtype=kv_dtype,
+                           prefill_chunk=chunk, temperature=temperature)
+    return pred, DecodeServer(pred, max_prefill=32, slots=slots,
+                              spec_k=spec_k, eos_id=eos, seed=seed)
+
+
+PROMPTS = [np.random.default_rng(5).integers(0, VOCAB, size=n)
+           for n in (5, 19, 26, 9, 30, 12, 7)]
+CAPS = (9, 3, 12, 1, 6, 8, 2)
+
+
+def oracle(toy, eos=None, kv_dtype=""):
+    _, server = make_server(toy, 0, eos, kv_dtype=kv_dtype)
+    for p, c in zip(PROMPTS, CAPS):
+        server.submit(p, max_new_tokens=c)
+    return server.run()
+
+
+def drive(server, want, schedule):
+    """Run the self-drafting server tick by tick.  ``schedule(tick)`` says
+    what each tick's drafts are: "accept" puts the oracle's next token of
+    every live slot into the device state, "reject" another token, None
+    leaves what the block drafted.  The test keeps the count of tokens each
+    slot has committed ON THE DEVICE (the host's record lags a tick): one at
+    the commit, then two a forced accept, one a forced reject."""
+    for p, c in zip(PROMPTS, CAPS):
+        server.submit(p, max_new_tokens=c)
+    ps = server.serve_open()
+    on_device, tick = {}, 0
+    while server.has_work:
+        how = schedule(tick)
+        live = dict(ps["active"])
+        for slot in list(on_device):
+            if live.get(slot) is not on_device[slot][0]:
+                del on_device[slot]
+        for slot, rec in live.items():
+            on_device.setdefault(slot, [rec, 1])
+        if how and live:
+            draft = np.asarray(ps["state"].draft).copy()
+            for slot, (rec, n) in on_device.items():
+                ref = want[rec["rid"]]
+                nxt = int(ref[n]) if n < len(ref) else 0
+                draft[slot, 0] = nxt if how == "accept" \
+                    else (nxt + 1) % VOCAB
+            ps["state"] = ps["state"]._replace(draft=jax.device_put(
+                jnp.asarray(draft), mx.cpu().jax_device))
+        for slot, pair in on_device.items():
+            known = pair[1] < len(want[pair[0]["rid"]])
+            pair[1] += 2 if how == "accept" and known else 1
+        if not how:
+            on_device.clear()       # the block's own drafts: nothing forced
+        server.serve_tick()
+        tick += 1
+    return server.serve_results()
+
+
+SCHEDULES = {
+    "block": lambda t: None,
+    "accept": lambda t: "accept",
+    "reject": lambda t: "reject",
+    "alternate": lambda t: "accept" if t % 2 else "reject",
+    "mostly_accept": lambda t: "reject" if t % 5 == 3 else "accept",
+}
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+@pytest.mark.parametrize("how", sorted(SCHEDULES))
+def test_greedy_tokens_are_those_of_a_server_that_never_drafts(toy, how,
+                                                               kv_dtype):
+    """Token for token, whatever was accepted and rejected on the way: a
+    rejected draft's keys in the window rings, the full pool and the block's
+    own cache are hidden or overwritten before anything reads them, and a
+    cap inside an accepted pair cuts the pair."""
+    want = oracle(toy, kv_dtype=kv_dtype)
+    pred, server = make_server(toy, 1, kv_dtype=kv_dtype)
+    got = drive(server, want, SCHEDULES[how])
+    assert sorted(got) == sorted(want)
+    for rid in want:
+        assert got[rid].tolist() == want[rid].tolist(), (how, rid)
+    assert [len(got[r]) for r in sorted(got)] == list(CAPS)
+    assert server.tokens_out == sum(CAPS)
+    if how == "accept":
+        assert server.accepted > 10
+    if how == "reject":
+        assert server.accepted == 0
+    assert server.accept_rate == server.accepted / max(server.proposed, 1)
+    assert server.spec_steps == server.steps > 0
+
+
+@pytest.mark.parametrize("how", ["accept", "alternate", "block"])
+@pytest.mark.parametrize("at", [1, 2, 4, 5])
+def test_an_eos_inside_an_accepted_pair_ends_the_request_there(toy, how, at):
+    """The EOS is token ``at`` of the longest answer: first or second of a
+    pair by the schedule, and the request ends AT it either way."""
+    free = oracle(toy)
+    eos = int(free[2][at])
+    want = oracle(toy, eos=eos)
+    assert len(want[2]) <= at + 1 and want[2][-1] == eos
+    _, server = make_server(toy, 1, eos=eos)
+    got = drive(server, want, SCHEDULES[how])
+    for rid in want:
+        assert got[rid].tolist() == want[rid].tolist(), (how, at, rid)
+        assert len(got[rid]) <= CAPS[rid]
+
+
+def test_the_self_drafting_tick_is_read_behind_and_traced_once(toy):
+    ticks = obs.registry.get("mx_serve_ticks_total")
+    before = {r: ticks.labels(read=r).get() for r in ("behind", "first")}
+    proposed = obs.registry.get("mx_spec_proposed").get()
+    pred, server = make_server(toy, 1)
+    want = oracle(toy)
+    got = drive(server, want, SCHEDULES["accept"])
+    assert got.keys() == want.keys()
+    behind = ticks.labels(read="behind").get() - before["behind"]
+    first = ticks.labels(read="first").get() - before["first"]
+    assert behind > 10 and first <= 2, (behind, first)
+    assert obs.registry.get("mx_spec_proposed").get() - proposed \
+        == server.proposed > 0
+    # one tick program, one chunk program, one commit: nothing retraced as
+    # slots filled, emptied and accepted or rejected
+    assert pred.trace_counts["decode"] == 1
+    assert pred.trace_counts["chunk"] == 1
+    assert pred.trace_counts["commit"] == 1
+    assert pred.trace_counts["verify"] == 0
+    # what the tick's span says of itself
+    notes = [e for e in obs.timeline.events()
+             if e.get("name") == "serve.readback"
+             and "spec_proposed" in (e.get("args") or {})]
+    assert notes
+    for e in notes[-5:]:
+        a = e["args"]
+        assert a["tokens_committed"] == a["spec_proposed"] \
+            + a["spec_accepted"]
+        assert "moe_rows_held" in a and "mtp_moe_expert_visits" in a
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_sampled_requests_get_exactly_their_caps(toy, seed):
+    """At temperature 1 with a flat head most drafts are accepted: pairs
+    everywhere, and no request receives a token beyond its cap."""
+    sym, _ = toy
+    flat = (sym, toy_params(sym, head_std=0.02))
+    _, server = make_server(flat, 1, temperature=1.0, seed=seed)
+    for p, c in zip(PROMPTS, CAPS):
+        server.submit(p, max_new_tokens=c)
+    got = server.run()
+    assert [len(got[r]) for r in sorted(got)] == list(CAPS)
+    assert all(0 <= t < VOCAB for toks in got.values() for t in toks)
+    assert 0.3 < server.accept_rate <= 1.0
+    assert server.tokens_out == sum(CAPS)
+
+
+@pytest.mark.parametrize("seed", range(4))
+def test_the_acceptance_rule_keeps_the_targets_distribution(seed):
+    """On a tiny vocabulary, exactly: a draft d ~ q accepted with min(1, p(d)
+    / q(d)), else a token from the residual, is a token from p."""
+    from mxnet_tpu.ops.sample import residual_probs
+
+    rng = np.random.default_rng(seed)
+    p = rng.dirichlet(np.ones(6)).astype(np.float32)
+    q = rng.dirichlet(np.ones(6)).astype(np.float32)
+    taken = np.minimum(p, q)                    # q(d) min(1, p(d) / q(d))
+    rest = np.asarray(residual_probs(jnp.asarray(p)[None],
+                                     jnp.asarray(q)[None]))[0]
+    assert np.allclose(taken + (1.0 - taken.sum()) * rest, p, atol=1e-6)
+
+
+def test_a_request_that_would_wrap_is_refused_at_submit(toy):
+    _, server = make_server(toy, 1)
+    with pytest.raises(MXNetError, match="keeps a whole request"):
+        server.submit(np.arange(30), max_new_tokens=CACHE - 30)
+    server.submit(np.arange(30), max_new_tokens=CACHE - 32)
+
+
+def test_more_than_one_draft_a_tick_is_refused(toy):
+    sym, params = toy
+    pred = DecodePredictor(sym, params, cache_len=CACHE, ctx=mx.cpu(),
+                           paged=True, page_tokens=PAGE, prefill_chunk=CHUNK)
+    assert pred.self_drafting
+    with pytest.raises(MXNetError, match="one token a tick"):
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=2)
+
+
+def test_a_ring_without_slack_still_refuses_by_name(toy):
+    """Window 4 in pages of 4 with no chunk to make room for: the ring is
+    the window itself, and a verify step's two rows have nowhere to go."""
+    sym, params = toy
+    pred = DecodePredictor(sym, params, cache_len=CACHE, ctx=mx.cpu(),
+                           paged=True, page_tokens=1, prefill_chunk=1)
+    ring = next(g for g in pred._groups if g.kind == "window")
+    assert pred.ring_slack(ring) < 2
+    with pytest.raises(MXNetError, match="'window' cache group.*fewer "
+                                         "positions beyond its window"):
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=1)
+    # with chunks of 8 the ring holds 12 and the same server is built
+    make_server(toy, 1)
+
+
+def test_a_state_group_still_refuses_speculation_by_name():
+    from chipbench import harness, manifest, weights
+
+    cfg = dict(manifest.load_json(
+        manifest.ROOT, "chipbench/configs/falcon-h1-34b.json"),
+        vocab_size=96, hidden_size=64, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, intermediate_size=128,
+        mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+        mamba_n_groups=2, mamba_chunk_size=8, max_position_embeddings=64,
+        serve_num_hidden_layers=2)
+    sym = harness.build_symbol(cfg)
+    shapes, _, _ = sym.infer_shape(data=(1, 64), softmax_label=(1, 64))
+    params = weights.make_params(
+        {n: s for n, s in zip(sym.list_arguments(), shapes)
+         if n not in FREE}, cfg, 3, "float32")
+    pred = DecodePredictor(
+        sym, {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()},
+        cache_len=64, ctx=mx.cpu(), paged=True, page_tokens=PAGE,
+        prefill_chunk=CHUNK)
+    with pytest.raises(MXNetError, match="'state' cache group.*rejected "
+                                         "draft has already advanced"):
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=1)
+
+
+def test_a_proposer_drafts_over_a_ring_with_slack(toy):
+    """What lifted the refusal is the ring's slack, not who drafts: an
+    n-gram proposer over the same window rings gives the oracle's tokens."""
+    from mxnet_tpu.decode import NGramProposer
+
+    sym, params = toy
+    plain = (toy_symbol(num_nextn_predict_layers=0),
+             {n: v for n, v in params.items() if not n.startswith("mtp_")})
+    want = None
+    for proposer in (None, NGramProposer(2)):
+        pred = DecodePredictor(plain[0], plain[1], cache_len=CACHE,
+                               ctx=mx.cpu(), paged=True, page_tokens=PAGE,
+                               prefill_chunk=CHUNK)
+        server = DecodeServer(pred, max_prefill=32, slots=2, spec_k=0,
+                              proposer=proposer)
+        for p, c in zip(PROMPTS, CAPS):
+            server.submit(p, max_new_tokens=c)
+        got = server.run()
+        if want is None:
+            want = got
+    assert {r: v.tolist() for r, v in got.items()} \
+        == {r: v.tolist() for r, v in want.items()}
+
+
+# ---------------------------------------------------------------------------
+# The accepted serving cells' programs are the text they were: sha256[:16] of
+# ``str(jax.make_jaxpr(program)(avals))`` of the paged chunk and decode
+# programs of ``decoder_lm`` at toy widths of the three configurations that
+# share the builder, the op and the walk with this PR's, taken on the commit
+# before PR 46 (65b7e2c) with the very code below, under this directory's
+# conftest (the text of a jaxpr depends on jax's configuration).  A PR that
+# means to change one replaces its hash and says so.
+# ---------------------------------------------------------------------------
+ACCEPTED_TOYS = {
+    "mimo-v2.5": dict(
+        vocab_size=96, hidden_size=64, num_attention_heads=4, head_dim=24,
+        v_head_dim=16, num_key_value_heads=1, swa_num_key_value_heads=2,
+        sliding_window=8, intermediate_size=128, moe_intermediate_size=32,
+        n_routed_experts=16, num_experts_per_tok=4, held_n_routed_experts=4,
+        first_held_expert=4, max_position_embeddings=64),
+    "falcon-h1-34b": dict(
+        vocab_size=96, hidden_size=64, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, intermediate_size=128,
+        mamba_d_ssm=64, mamba_n_heads=4, mamba_d_head=16, mamba_d_state=8,
+        mamba_n_groups=2, mamba_chunk_size=8, max_position_embeddings=64,
+        serve_num_hidden_layers=3),
+    "minicpm-sala": dict(
+        vocab_size=96, hidden_size=64, num_attention_heads=4,
+        num_key_value_heads=2, head_dim=16, intermediate_size=128,
+        lightning_nh=4, lightning_head_dim=16, dim_model_base=4,
+        max_position_embeddings=128,
+        sparse_config=dict(kernel_size=8, kernel_stride=4, init_blocks=1,
+                           block_size=8, window_size=16, topk=4,
+                           dense_len=24),
+        serve_num_hidden_layers=3),
+}
+ACCEPTED_PROGRAMS = {
+    "mimo-v2.5.chunk": "304f6c02b8c2bd19",
+    "mimo-v2.5.decode": "be3b0206035659b4",
+    "falcon-h1-34b.chunk": "fdd5120649bf79f0",
+    "falcon-h1-34b.decode": "3cfe71bb2005f08a",
+    "minicpm-sala.chunk": "1549f8c907fe88c3",
+    "minicpm-sala.decode": "e8395fda7e5399e5",
+}
+
+
+@pytest.mark.parametrize("which", sorted(ACCEPTED_PROGRAMS))
+def test_an_accepted_cells_program_is_the_text_it_was(which):
+    from chipbench import harness, manifest
+    from mxnet_tpu.programs.spec import probing
+
+    name, kind = which.rsplit(".", 1)
+    cfg = dict(manifest.load_json(manifest.ROOT,
+                                  "chipbench/configs/%s.json" % name),
+               **ACCEPTED_TOYS[name])
+    sym = harness.build_symbol(cfg)
+    shapes, _, _ = sym.infer_shape(data=(1, 64), softmax_label=(1, 64))
+    params = {n: mx.nd.NDArray(jnp.zeros(s, "float32"), mx.cpu())
+              for n, s in zip(sym.list_arguments(), shapes)
+              if n not in FREE}
+    pred = DecodePredictor(sym, params, cache_len=64, ctx=mx.cpu(),
+                           paged=True, page_tokens=4, kv_dtype="int8",
+                           prefill_chunk=8)
+    assert not getattr(pred, "self_drafting", False)
+    fn = {"chunk": pred._chunk_impl, "decode": pred._paged_decode_impl}[kind]
+    with probing(pred):
+        text = str(jax.make_jaxpr(fn)(*pred.serving_avals(2, chunk_w=8)[kind]))
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == ACCEPTED_PROGRAMS[which], json.dumps({which: got})
+
+
+@pytest.mark.parametrize("program,stem", [
+    ("paged_decode_mtp_step", "jit__paged_decode_mtp_impl"),
+    ("prefill_chunk_mtp", "jit__mtp_chunk_impl"),
+    ("slot_commit_mtp", "jit__commit_mtp_impl")])
+def test_the_scope_maps_are_the_self_drafting_programs_that_ran(toy, program,
+                                                                stem):
+    """``obs.programs``' maps of the tick, the chunk and the commit are read
+    off the executables the loop dispatches, with nothing compiled; the
+    block's nodes are filed under ``mtp`` and the stack's shared expert under
+    ``moe/shared``."""
+    obs.programs.reset(clear_static=True)
+    pred, server = make_server(toy, 1, kv_dtype="int8")
+    for p, c in zip(PROMPTS, CAPS):
+        server.submit(p, max_new_tokens=c)
+    server.run()
+    compiles = []
+    jax.monitoring.register_event_duration_secs_listener(
+        lambda event, _secs, **_: compiles.append(event)
+        if event == "/jax/core/compile/backend_compile_duration" else None)
+    entry = obs.programs.instruction_maps()[stem]
+    assert not compiles and entry["source"] == "dispatched"
+    scopes = {v["scope"] for v in entry["instructions"].values()}
+    if program != "slot_commit_mtp":
+        assert {"mtp", "mtp/experts", "mtp/shared", "mtp/kv_append",
+                "moe/shared", "moe/experts", "attn_window/kv_append"} \
+            <= scopes, sorted(scopes)
+    obs.programs.reset(clear_static=True)
+
+
+def test_a_sampling_servers_key_split_is_a_program_with_a_map(toy):
+    """Every tick of a sampling server splits its key on the device: one
+    named program, read off the executable that ran, so that a traced cell's
+    busy time is joined to a map to the last tick."""
+    sym, _ = toy
+    obs.programs.reset(clear_static=True)
+    _, server = make_server((sym, toy_params(sym, head_std=0.02)), 1,
+                            temperature=1.0)
+    for p, c in zip(PROMPTS, CAPS):
+        server.submit(p, max_new_tokens=c)
+    server.run()
+    maps = obs.programs.instruction_maps()
+    assert maps["jit__split_key"]["source"] == "dispatched"
+    obs.programs.reset(clear_static=True)

@@ -275,8 +275,10 @@ def test_what_a_ring_cannot_carry_is_refused_by_name(toy):
     pred = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
                            page_tokens=PAGE, prefill_chunk=CHUNK)
     assert pred.has_window_group
+    # a verify step of k + 1 rows needs that many ring positions beyond the
+    # window (PR 46): this ring of 16 has 8, and k = 8 is refused by name
     with pytest.raises(MXNetError, match="'window' cache group"):
-        DecodeServer(pred, max_prefill=32, slots=2, spec_k=2)
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=8)
     server = DecodeServer(pred, max_prefill=32, slots=2, spec_k=0)
     assert not server._swap_armed
     with pytest.raises(MXNetError, match="'window' cache group"):
