@@ -141,6 +141,18 @@ _POSITION_BROADCAST_OPS = {
 }
 
 
+def _when(on, fn):
+    """``fn()`` where the scalar ``on`` holds, else zeros of its types: one
+    ``lax.cond``, so what ``fn`` computes costs nothing where it is not
+    read (a chunk that does not end its prompt)."""
+    import jax
+    import jax.numpy as jnp
+
+    zeros = lambda: jax.tree_util.tree_map(
+        lambda a: jnp.zeros(a.shape, a.dtype), jax.eval_shape(fn))
+    return jax.lax.cond(on, fn, zeros)
+
+
 def _keep_tok(tok):
     """A step's tokens, copied (a function with a name, so that its
     program is module ``jit__keep_tok`` on a device trace)."""
@@ -395,6 +407,7 @@ class DecodePredictor:
         # has said what that token is (:meth:`_run`'s ``between``)
         self._late = self._nodes_after(self.MTP_DATA) \
             if self.MTP_DATA in free and len(symbol._outputs) > 1 else None
+        self._heads = self._head_regions()
         if not self._attn_nodes:
             raise MXNetError("symbol has no dot_product_attention node; "
                              "nothing to cache — use Predictor")
@@ -617,6 +630,39 @@ class DecodePredictor:
                     id(src) in late for src, _ in node.inputs):
                 late.add(id(node))
         return late
+
+    def _head_regions(self):
+        """Per output of the symbol, ``{id(node)}`` of its head: the nodes
+        that turn each row of the last hidden state into that row's
+        distribution, and that a chunk program runs over the one row it
+        reads (:meth:`_run`'s ``head_rows``).  Output 0's are the nodes of
+        layer ``head_loss`` (``obs.scopes.layer_of``) on its path; a further
+        output's (a prediction block's, whose nodes all carry the block's
+        layer) the nodes of those kinds on its own path.  Empty where the
+        graph names no such layer, or where a node outside reads one
+        inside: such a graph is walked whole."""
+        from .obs.scopes import layer_of
+
+        topo = self._symbol._topo()
+
+        def back(node, keep):
+            region, stack = set(), [node]
+            while stack:
+                node = stack.pop()
+                if not node.is_variable and id(node) not in region \
+                        and keep(node):
+                    region.add(id(node))
+                    stack.extend(src for src, _ in node.inputs)
+            closed = all(id(src) not in region for n in topo
+                         if id(n) not in region for src, _ in n.inputs)
+            return frozenset(region if closed else ())
+
+        outputs = [node for node, _ in self._symbol._outputs]
+        first = back(outputs[0], lambda n: layer_of(n) == "head_loss")
+        kinds = {n.op.name for n in topo if id(n) in first}
+        return (first,) + tuple(
+            back(node, lambda n: n.op.name in kinds)
+            for node in outputs[1:])
 
     def _bind_cache_groups(self):
         """One :class:`CacheLayout` per stateful node, and the nodes
@@ -974,7 +1020,7 @@ class DecodePredictor:
     # the shared graph walk (traced inside both programs)
     # ------------------------------------------------------------------
     def _run(self, env, tokens, caches, pos0, tables=None, active=None,
-             valid=None, between=None):
+             valid=None, between=None, head_rows=None):
         """Execute the symbol on (B, t) tokens.
 
         ``caches is None`` = prefill mode: full causal attention, fresh
@@ -1003,6 +1049,14 @@ class DecodePredictor:
         left in ``self._mtp_probs`` (B, t, V).  Without ``between`` a walk
         over caches leaves the block out and hands its cache on as it came;
         a walk that builds caches (prefill, the shape probe) feeds it zeros.
+
+        With ``head_rows`` (B,) int32, a chunk program's walk, the nodes of
+        each output's head (:meth:`_head_regions`) are left out of the walk,
+        and what the walk hands on as an output's distributions (returned,
+        given to ``between``, left in ``self._mtp_probs``) is a function of
+        no arguments that runs them over row ``head_rows`` of each sequence
+        alone, (B, 1, V): the caller calls it where that row is read, behind
+        its conditional (:func:`_when`), or not at all.
         """
         import jax
         import jax.numpy as jnp
@@ -1022,7 +1076,62 @@ class DecodePredictor:
             # no node of the stack reads one of the block's: the stack
             # first, each part in the order it had
             order.sort(key=lambda sn: id(sn[1]) in late)
+        cut = self._heads if head_rows is not None else ()
+
+        def plain(seq, node, attrs, ins, aux_ins):
+            return node.op.fcompute(attrs, ins, aux_ins, OpContext(
+                is_train=False, rng=jax.random.fold_in(base_key, seq),
+                mesh_active=self._mesh is not None, mesh=self._mesh,
+                producers=producers_of(node)))[0]
+
+        def head(which):
+            """Output ``which``: (B, t, V), or under ``head_rows`` what
+            computes (B, 1, V) from the rows of what its head reads."""
+            if head_rows is None:
+                return self._head_probs(values, which, b, t)
+            at = jnp.asarray(head_rows, jnp.int32).reshape(b)
+
+            def rows(src, x):
+                shape = tuple(getattr(x, "shape", ()))
+                if src.is_variable and src.name in env:
+                    return x        # a parameter
+                if shape[:2] == (b, t):
+                    return jnp.take_along_axis(
+                        x, at.reshape((b, 1) + (1,) * (x.ndim - 2)), axis=1)
+                if shape[:1] == (b * t,):
+                    # the rows of every sequence, flattened
+                    return rows(src, x.reshape((b, t) + shape[1:])).reshape(
+                        (b,) + shape[1:])
+                return x            # nothing with a row a position
+
+            def read():
+                if not cut[which]:
+                    # no head to cut: the row of what the walk computed
+                    return rows(self._symbol._outputs[which][0],
+                                self._head_probs(values, which, b, t))
+                local = {}
+                for seq, node in order:
+                    if id(node) not in cut[which]:
+                        continue
+                    for src, i in node.inputs:
+                        if id(src) not in cut[which]:
+                            local[(id(src), i)] = rows(
+                                src, values[(id(src), i)])
+                    attrs = node.parsed_attrs()
+                    n_args = node.op.n_inputs(attrs)
+                    got = [local[(id(src), i)] for src, i in node.inputs]
+                    with _node_scope(node):
+                        outs = plain(seq, node, attrs, got[:n_args],
+                                     got[n_args:])
+                    local.update(((id(node), i), o)
+                                 for i, o in enumerate(outs))
+                return self._head_probs(local, which, b, 1)
+
+            return read
+
         for seq, node in order:
+            if any(id(node) in region for region in cut):
+                continue
             if late and id(node) in late:
                 if between is None and caches is not None:
                     if not node.is_variable and (
@@ -1033,8 +1142,7 @@ class DecodePredictor:
                     continue
                 if next_tokens is None:
                     next_tokens = jnp.zeros((b, t), jnp.float32) \
-                        if between is None else between(
-                            self._head_probs(values, 0, b, t))
+                        if between is None else between(head(0))
             if node.is_variable:
                 if node.name == self._data_name:
                     val = tokens
@@ -1214,17 +1322,12 @@ class DecodePredictor:
                         idx = jnp.clip(idx, 0, s_len - 1)
                         ins = list(ins)
                         ins[big_i] = jnp.take(big[0], idx, axis=0)
-                    octx = OpContext(
-                        is_train=False,
-                        rng=jax.random.fold_in(base_key, seq),
-                        mesh_active=self._mesh is not None, mesh=self._mesh,
-                        producers=producers_of(node))
-                    outs, _ = node.op.fcompute(attrs, ins, aux_ins, octx)
+                    outs = plain(seq, node, attrs, ins, aux_ins)
             for i, o in enumerate(outs):
                 values[(id(node), i)] = o
         if late and between is not None:
-            self._mtp_probs = self._head_probs(values, 1, b, t)
-        return self._head_probs(values, 0, b, t), tuple(new_caches)
+            self._mtp_probs = head(1)
+        return head(0), tuple(new_caches)
 
     def _head_probs(self, values, which, b, t):
         """Output ``which`` of the symbol as (B, t, V)."""
@@ -1424,15 +1527,17 @@ class DecodePredictor:
         tok = jnp.where(act[:, None], tok, state.tok)
         return (DecodeState(caches, state.lens + counts, tok), out, counts)
 
-    def _chunk_impl(self, env, caches, table1, toks, pos0, nvalid, key):
+    def _chunk_impl(self, env, caches, table1, toks, pos0, nvalid, last,
+                    key):
         """One fixed-width prefill chunk for a single slot: append the
         chunk's K/V at positions [pos0, pos0 + nvalid) of the slot's page
-        table (pad positions past ``nvalid`` are never written), attend
-        causally against everything cached so far, and sample at the
-        chunk's last real position; a recurrent state advances over the
-        real positions only, from zero where ``pos0`` is 0.  The final
-        chunk's sample IS the request's first token; earlier chunks'
-        samples are discarded.
+        table (pad positions past ``nvalid`` are never written) and attend
+        causally against everything cached so far; a recurrent state
+        advances over the real positions only, from zero where ``pos0`` is
+        0.  The output head runs on the chunk's last real row alone, and
+        only where ``last`` (1,) is not 0, the chunk that ends its prompt:
+        its sample IS the request's first token.  Any other chunk returns
+        zeros for ``probs`` and ``tok``, which nobody reads.
         One trace per chunk width — chunked prefill never retraces."""
         import jax.numpy as jnp
 
@@ -1446,15 +1551,18 @@ class DecodePredictor:
             return jnp.arange(toks.shape[1])[None, :] \
                 < jnp.asarray(nvalid, jnp.int32).reshape(-1, 1)
 
+        row = jnp.clip(jnp.asarray(nvalid, jnp.int32).reshape(-1) - 1, 0,
+                       toks.shape[1] - 1)
         with collecting(real=real) as moe_rows:
-            probs3, caches = self._run(env, toks, caches, pos0,
-                                       tables=table1, active=ones,
-                                       valid=nvalid)
-        last = jnp.clip(jnp.asarray(nvalid, jnp.int32) - 1, 0,
-                        toks.shape[1] - 1)
-        probs = jnp.take_along_axis(
-            probs3, last[:, None, None], axis=1)[:, 0]
-        tok = self._sample(key, probs)
+            head, caches = self._run(env, toks, caches, pos0,
+                                     tables=table1, active=ones,
+                                     valid=nvalid, head_rows=row)
+
+        def read():
+            probs = head()[:, 0]
+            return probs, self._sample(key, probs)
+
+        probs, tok = _when(jnp.asarray(last).reshape(-1)[0] != 0, read)
         if moe_rows:
             return caches, probs, tok, sum(moe_rows)
         return caches, probs, tok
@@ -1547,7 +1655,9 @@ class DecodePredictor:
         decoding starts and the chunk that ends a prompt leaves the first
         draft.  Returns ``(caches, probs, tok, draft, draft_probs,
         block_probs[, moe])``, ``block_probs`` (1, V) the block's own
-        distribution at the chunk's last row."""
+        distribution at the chunk's last row.  Both heads run on that row
+        alone and only in the chunk that ends a prompt; the block itself
+        runs over every row of every chunk."""
         import jax
         import jax.numpy as jnp
 
@@ -1560,19 +1670,22 @@ class DecodePredictor:
         width = toks.shape[1]
         nvalid = jnp.asarray(nvalid, jnp.int32).reshape(-1)
         last = jnp.clip(nvalid - 1, 0, width - 1)
-        at_last = lambda x: jnp.take_along_axis(
-            x, last[:, None, None], axis=1)[:, 0]
+        nxt = jnp.asarray(next_tok, jnp.int32).reshape(-1, 1)
+        ends = nxt[0, 0] < 0
         got = {}
 
-        def between(probs3):
-            got["probs"] = probs = at_last(probs3)
-            got["tok"] = tok = self._sample(k_tok, probs)
-            nxt = jnp.asarray(next_tok, jnp.int32).reshape(-1, 1)
-            nxt = jnp.where(nxt < 0, tok, nxt).astype(toks.dtype)
+        def between(head):
+            def read():
+                probs = head()[:, 0]
+                return probs, self._sample(k_tok, probs)
+
+            probs, tok = _when(ends, read)
+            got.update(probs=probs, tok=tok)
             shifted = jnp.concatenate(
                 [toks[:, 1:], jnp.zeros_like(toks[:, :1])], axis=1)
             return jnp.where(jnp.arange(width)[None, :] == last[:, None],
-                             nxt, shifted)
+                             jnp.where(nxt < 0, tok, nxt).astype(toks.dtype),
+                             shifted)
 
         def real():
             return jnp.arange(width)[None, :] < nvalid[:, None]
@@ -1580,10 +1693,13 @@ class DecodePredictor:
         with collecting(real=real) as moe_rows:
             _, caches = self._run(env, toks, caches, pos0, tables=table1,
                                   active=ones, valid=nvalid,
-                                  between=between)
-        block = at_last(self._mtp_probs)
-        draft, dprobs = self._draft_of(k_draft, block)
-        out = (caches, got["probs"], got["tok"], draft, dprobs, block)
+                                  between=between, head_rows=last)
+
+        def read_block():
+            block = self._mtp_probs()[:, 0]
+            return self._draft_of(k_draft, block) + (block,)
+
+        out = (caches, got["probs"], got["tok"]) + _when(ends, read_block)
         return out + ((sum(moe_rows),) if moe_rows else ())
 
     def _commit_mtp_impl(self, lens, tok, draft, dprobs, slot, new_len,
@@ -1914,9 +2030,10 @@ class DecodePredictor:
         i32 = sds((), jnp.int32)
         cw = int(chunk_w or self._prefill_chunk or self._cache_len)
         out = {
+            # its last small operand: whether the chunk ends its prompt
             "chunk": (env, caches, tables_of(1),
                       sds((1, cw), jnp.float32), sds((1,), jnp.int32),
-                      sds((1,), jnp.int32), key),
+                      sds((1,), jnp.int32), sds((1,), jnp.int32), key),
             "decode": (env, state, tables_of(slots), active, key),
             "commit": (lens, tok, i32, sds((1,), jnp.int32),
                        sds((1, 1), jnp.int32)),
@@ -1941,8 +2058,9 @@ class DecodePredictor:
                 draft=tok, draft_probs=None if self._greedy
                 else sds((slots, self._vocab_size()), jnp.float32))
             out["mtp_step"] = (env, drafting, tables_of(slots), active, key)
-            out["mtp_chunk"] = out["chunk"][:-1] + (sds((1,), jnp.int32),
-                                                    key)
+            # (there the operand is the prompt's next token, negative
+            # where the chunk ends it)
+            out["mtp_chunk"] = out["chunk"]
         return out
 
     def prepare_programs(self, slots, chunk_w=None, spec_k=0,
@@ -2277,7 +2395,7 @@ class DecodePredictor:
                 caches, probs, tok = self._chunk_fn(
                     self._env, caches,
                     *self._chunk_operands(slot, prompt[pos:pos + n], pos, w),
-                    sub)[:3]
+                    np.asarray([pos + n >= total], np.int32), sub)[:3]
             pos += n
         return caches, tok, probs
 
@@ -3104,6 +3222,12 @@ class DecodeServer:
             "it read the previous tick's tokens; first, nothing was unread "
             "when it queued them (a first tick, a proposer, a drain)",
             labels=("read",))
+        self._m_chunks = _obs.registry.counter(
+            "mx_serve_chunks_total",
+            "prefill chunks the loop queued: run, the chunk ended its "
+            "prompt and ran the output head on its last row; skipped, an "
+            "earlier chunk, whose program skips the head",
+            labels=("head",))
         self._m_dropped = _obs.registry.counter(
             "mx_serve_dropped_rows_total",
             "rows of a decode step whose token was dropped: the step was "
@@ -4084,8 +4208,11 @@ class DecodeServer:
             p = ps["pending"]
             state = ps["state"]
             n = min(self._chunk_w, p["prompt"].size - p["pos"])
+            # the output head runs in the chunk that ends the prompt alone
+            ends = bool(p["pos"] + n >= p["prompt"].size)
             where = {"rid": p["rid"], "slot": p["slot"], "pos": p["pos"],
-                     "tokens": int(n)}
+                     "tokens": int(n), "head": ends}
+            self._m_chunks.labels(head="run" if ends else "skipped").inc()
             with _obs.span("serve.prefill", cat="serve", args=where):
                 copies = mgr.ensure(p["slot"], p["pos"], p["pos"] + n)
                 caches = pred._run_forks(state.caches, copies) \
@@ -4100,7 +4227,8 @@ class DecodeServer:
                 else:
                     args = (pred._env, caches) + pred._chunk_operands(
                         p["slot"], p["prompt"][p["pos"]:p["pos"] + n],
-                        p["pos"], self._chunk_w) + (sub,)
+                        p["pos"], self._chunk_w) + (
+                            np.asarray([ends], np.int32), sub)
                     # its dispatch wall accrues to the "prefill" row; only
                     # the scope map knows the chunk program by its own name
                     pred._roofline_register("prefill_chunk", pred._chunk_fn,

@@ -805,11 +805,13 @@ def test_sample_tokens_greedy_bypass_is_key_independent():
 # so; one that does not has changed what every accepted serving cell runs.
 # PR 42 replaced the three paged int8 programs' (a node's pools keep their
 # scales in one plane, read and written by rows); the dense prefill and the
-# float pools' programs are the ones of b2cc690.
+# float pools' programs are the ones of b2cc690.  PR 47 replaced the two chunk
+# programs' (the head on the one row that is read, behind a conditional, and
+# one more small operand); decode, verify and prefill are as they were.
 PLAIN_PROGRAMS = {
-    "mha_int8": {"chunk": "6df5d92584f6a4d6", "decode": "ae3f5eda93fe8a7a",
+    "mha_int8": {"chunk": "a3e1ef33a85058fc", "decode": "ae3f5eda93fe8a7a",
                  "verify": "844c2c86a9ae31ae", "prefill": "88b686fb4db16476"},
-    "gqa_float": {"chunk": "b3c4b15365228b7f", "decode": "e11356bce6bbbc2d",
+    "gqa_float": {"chunk": "10aae20472856a52", "decode": "e11356bce6bbbc2d",
                   "verify": "88735220e64c4515", "prefill": "ecb1cf4999e7e7a2"},
 }
 PLAIN_OPS = {"attn_mha": "cf554ea5f46f3f2f", "attn_gqa": "46ba4ccd9ef3b64f",

@@ -326,7 +326,9 @@ def test_a_proposer_drafts_over_a_ring_with_slack(toy):
 # share the builder, the op and the walk with this PR's, taken on the commit
 # before PR 46 (65b7e2c) with the very code below, under this directory's
 # conftest (the text of a jaxpr depends on jax's configuration).  A PR that
-# means to change one replaces its hash and says so.
+# means to change one replaces its hash and says so.  PR 47 replaced the
+# three chunk programs' (the head on the one row that is read, behind a
+# conditional); the decode programs' are the ones of 65b7e2c.
 # ---------------------------------------------------------------------------
 ACCEPTED_TOYS = {
     "mimo-v2.5": dict(
@@ -352,11 +354,11 @@ ACCEPTED_TOYS = {
         serve_num_hidden_layers=3),
 }
 ACCEPTED_PROGRAMS = {
-    "mimo-v2.5.chunk": "304f6c02b8c2bd19",
+    "mimo-v2.5.chunk": "4c3218d7fcab1fd6",
     "mimo-v2.5.decode": "be3b0206035659b4",
-    "falcon-h1-34b.chunk": "fdd5120649bf79f0",
+    "falcon-h1-34b.chunk": "1573e8f198e7a1e2",
     "falcon-h1-34b.decode": "3cfe71bb2005f08a",
-    "minicpm-sala.chunk": "1549f8c907fe88c3",
+    "minicpm-sala.chunk": "7944ed24335f46a1",
     "minicpm-sala.decode": "e8395fda7e5399e5",
 }
 
@@ -429,3 +431,285 @@ def test_a_sampling_servers_key_split_is_a_program_with_a_map(toy):
     maps = obs.programs.instruction_maps()
     assert maps["jit__split_key"]["source"] == "dispatched"
     obs.programs.reset(clear_static=True)
+
+
+# ---------------------------------------------------------------------------
+# PR 47: a chunk program runs each output's head on the one row that is read,
+# behind a conditional that holds only in the chunk that ends its prompt.
+# The oracle is the walk without the cut (``_run(head_rows=None)``, the row
+# taken afterwards): the chunk programs as they were before this PR.
+# ---------------------------------------------------------------------------
+
+def _old_chunk(pred):
+    """The chunk program of the commit before PR 47, over the same operands
+    (the seventh unread by a graph without a block): every row through the
+    head, then the chunk's last real row taken."""
+    from mxnet_tpu.ops.moe import collecting
+
+    def program(env, caches, table1, toks, pos0, nvalid, seventh, key):
+        width = toks.shape[1]
+        nvalid = jnp.asarray(nvalid, jnp.int32).reshape(-1)
+        last = jnp.clip(nvalid - 1, 0, width - 1)
+        at_last = lambda x: jnp.take_along_axis(
+            x, last[:, None, None], axis=1)[:, 0]
+        real = lambda: jnp.arange(width)[None, :] < nvalid[:, None]
+        run = dict(tables=table1, valid=nvalid,
+                   active=jnp.ones((toks.shape[0],), jnp.int32))
+        if not pred.self_drafting:
+            with collecting(real=real) as moe:
+                probs3, caches = pred._run(env, toks, caches, pos0, **run)
+            probs = at_last(probs3)
+            return (caches, probs, pred._sample(key, probs)) \
+                + ((sum(moe),) if moe else ())
+        k_tok, k_draft = jax.random.split(key)
+        got = {}
+
+        def between(probs3):
+            got["probs"] = probs = at_last(probs3)
+            got["tok"] = tok = pred._sample(k_tok, probs)
+            nxt = jnp.asarray(seventh, jnp.int32).reshape(-1, 1)
+            nxt = jnp.where(nxt < 0, tok, nxt).astype(toks.dtype)
+            shifted = jnp.concatenate(
+                [toks[:, 1:], jnp.zeros_like(toks[:, :1])], axis=1)
+            return jnp.where(jnp.arange(width)[None, :] == last[:, None],
+                             nxt, shifted)
+
+        with collecting(real=real) as moe:
+            _, caches = pred._run(env, toks, caches, pos0, between=between,
+                                  **run)
+        block = at_last(pred._mtp_probs)
+        return (caches, got["probs"], got["tok"]) \
+            + pred._draft_of(k_draft, block) + (block,) \
+            + ((sum(moe),) if moe else ())
+
+    return jax.jit(program)
+
+
+def _attention_lm(vocab, layers):
+    from mxnet_tpu.models import attention_lm
+
+    sym = attention_lm.get_symbol(vocab_size=vocab, seq_len=64,
+                                  num_layers=layers, embed=32, heads=4,
+                                  ffn_hidden=64)
+    shapes, _, _ = sym.infer_shape(data=(1, 64), softmax_label=(1, 64))
+    rng = np.random.default_rng(3)
+    params = {n: mx.nd.array(rng.normal(size=s).astype(np.float32) * 0.1)
+              for n, s in zip(sym.list_arguments(), shapes) if n not in FREE}
+    return DecodePredictor(sym, params, cache_len=64, ctx=mx.cpu(),
+                           paged=True, page_tokens=4, prefill_chunk=8)
+
+
+def _cut_graph(which):
+    """``(predictor, vocabulary)`` of one of the graphs the cut has to fit,
+    at a toy size, float weights drawn by the configuration's own rules; a
+    vocabulary of 101, which is no other width of any of them."""
+    from chipbench import harness, manifest, weights
+
+    kw = dict(cache_len=64, ctx=mx.cpu(), paged=True, page_tokens=4,
+              prefill_chunk=8)
+    if which == "self-drafting":
+        sym = toy_symbol(vocab_size=101)
+        return DecodePredictor(sym, toy_params(sym), kv_dtype="int8",
+                               **kw), 101
+    if which == "attention_lm":
+        return _attention_lm(101, 2), 101
+    cfg = dict(manifest.load_json(manifest.ROOT,
+                                  "chipbench/configs/%s.json" % which),
+               **dict(ACCEPTED_TOYS[which], vocab_size=101))
+    sym = harness.build_symbol(cfg)
+    shapes, _, _ = sym.infer_shape(data=(1, 64), softmax_label=(1, 64))
+    drawn = weights.make_params(
+        {n: s for n, s in zip(sym.list_arguments(), shapes)
+         if n not in FREE}, cfg, 11, "float32")
+    params = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in drawn.items()}
+    return DecodePredictor(sym, params, kv_dtype="int8", **kw), 101
+
+
+CUT_GRAPHS = ["attention_lm", "mimo-v2.5", "falcon-h1-34b", "minicpm-sala",
+              "self-drafting"]
+
+
+def _fed(pred, prompt, program, key):
+    """``prompt`` through ``program`` in chunks of 8 from a fresh state: what
+    each chunk returned, every leaf on the host."""
+    state = pred.paged_batch_state(1, drafting=pred.self_drafting)
+    mgr = pred._manager
+    gate = mgr.gate(prompt, prompt.size, 64, 1, budget_wrap_forks=False)
+    mgr.map_slot(0, gate[1], gate[2])
+    caches, outs = state.caches, []
+    for pos in range(0, prompt.size, 8):
+        end = min(pos + 8, prompt.size)
+        copies = mgr.ensure(0, pos, end)
+        assert not copies
+        ends = end >= prompt.size
+        seventh = [(-1 if ends else prompt[end]) if pred.self_drafting
+                   else int(ends)]
+        out = program(pred._env, caches,
+                      *pred._chunk_operands(0, prompt[pos:end], pos, 8),
+                      np.asarray(seventh, np.int32),
+                      jax.random.fold_in(key, pos))
+        caches = out[0]
+        outs.append(jax.tree_util.tree_map(np.asarray, out))
+    return outs
+
+
+@pytest.mark.parametrize("temperature", [0.0, 1.0])
+@pytest.mark.parametrize("which", CUT_GRAPHS)
+def test_a_chunks_head_runs_on_the_row_that_is_read(which, temperature):
+    """A prompt of 20 tokens in 3 chunks: after every chunk the pools, the
+    recurrent states, the index planes and the experts' row counts are
+    bit-identical to the walk's without the cut; after the last, the first
+    token (and the first draft) is equal and its distribution (and the
+    block's) equal to float32 rounding: a product of one row may be blocked
+    otherwise than one of eight, so rtol 1e-6 (atol 1e-9: a probability under
+    1e-3 of the largest carries its rounding absolutely)."""
+    pred, vocab = _cut_graph(which)
+    pred._temperature = temperature
+    prompt = np.random.default_rng(9).integers(0, vocab, size=20)
+    key = jax.random.PRNGKey(4)
+    new = pred._chunk_mtp_fn if pred.self_drafting else pred._chunk_fn
+    want = _fed(pred, prompt, _old_chunk(pred), key)
+    got = _fed(pred, prompt, new, key)
+    assert len(got) == len(want) == 3
+    # caches first, and the experts' counts last where the graph has them
+    counted = len(want[0]) > (6 if pred.self_drafting else 3)
+    for n, (a, b) in enumerate(zip(got, want)):
+        assert len(a) == len(b)
+        leaves_a, leaves_b = (jax.tree_util.tree_leaves(x[0])
+                              for x in (a, b))
+        assert len(leaves_a) == len(leaves_b) > 0
+        for x, y in zip(leaves_a, leaves_b):
+            np.testing.assert_array_equal(x, y, err_msg="chunk %d" % n)
+        if counted:
+            np.testing.assert_array_equal(a[-1], b[-1])
+    if which in ("mimo-v2.5", "self-drafting"):
+        assert counted
+    a, b = got[-1], want[-1]
+    np.testing.assert_array_equal(a[2], b[2])           # the first token
+    np.testing.assert_allclose(a[1], b[1], rtol=1e-6, atol=1e-9)
+    assert a[1].shape == (1, vocab) and abs(a[1].sum() - 1) < 1e-5
+    if pred.self_drafting:
+        np.testing.assert_array_equal(a[3], b[3])       # the first draft
+        np.testing.assert_allclose(a[5], b[5], rtol=1e-6, atol=1e-9)
+        if temperature:
+            np.testing.assert_allclose(a[4], b[4], rtol=1e-6, atol=1e-9)
+    # a chunk that does not end its prompt hands back zeros, unread
+    for early in got[:-1]:
+        assert not early[1].any() and not early[2].any()
+
+
+def _eqns(jaxpr, inside=False):
+    """``(eqn, inside a cond)`` of a jaxpr and every jaxpr inside it."""
+    for eqn in jaxpr.eqns:
+        yield eqn, inside
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inside or eqn.primitive.name == "cond")
+
+
+@pytest.mark.parametrize("which", CUT_GRAPHS)
+def test_a_chunk_program_multiplies_one_row_by_the_vocabulary(which):
+    """No product of the chunk program has a row a position and the
+    vocabulary's width; the products that have the vocabulary's width have
+    one row and sit inside a conditional, which alone reads the head's
+    matrix: a chunk that does not end its prompt never touches it."""
+    from mxnet_tpu.programs.spec import probing
+
+    pred, vocab = _cut_graph(which)
+    kind, fn = ("mtp_chunk", pred._mtp_chunk_impl) if pred.self_drafting \
+        else ("chunk", pred._chunk_impl)
+    avals = pred.serving_avals(2, chunk_w=8)[kind]
+    with probing(pred):
+        closed = jax.make_jaxpr(fn)(*avals)
+    heads = []
+    for eqn, inside in _eqns(closed.jaxpr):
+        if eqn.primitive.name != "dot_general":
+            continue
+        shape = eqn.outvars[0].aval.shape
+        if shape[-1] == vocab:
+            assert int(np.prod(shape[:-1])) == 1 and inside, shape
+            heads.append(shape)
+    assert len(heads) == (2 if pred.self_drafting else 1)
+    paths = [jax.tree_util.keystr(p) for p, _ in
+             jax.tree_util.tree_flatten_with_path(avals)[0]]
+    matrix = closed.jaxpr.invars[
+        next(i for i, p in enumerate(paths) if "head_weight" in p)]
+    readers = [eqn.primitive.name for eqn in closed.jaxpr.eqns
+               if matrix in eqn.invars]
+    assert readers == ["cond"] * len(heads)
+
+
+@pytest.mark.parametrize("output", ["SoftmaxOutput", "softmax"])
+def test_a_graph_that_names_no_head_still_chunks(monkeypatch, output):
+    """A user's symbol that names no ``head_loss`` layer: its
+    ``SoftmaxOutput`` is of that layer by its kind and is the whole region
+    (the product runs over every row, the softmax over one); with a plain
+    ``softmax`` for an output there is no region, the walk runs every node
+    over every row as it did and the chunk's row is taken from what it
+    computed.  Either way the first token, its distribution and the pools are
+    those of the graph that names its head."""
+    from mxnet_tpu import symbol
+    from mxnet_tpu.models import attention_lm
+
+    build = lambda: _attention_lm(50, 1)
+
+    named = build()
+    # the two Reshapes, the product and the softmax
+    assert len(named._heads[0]) == 4
+    monkeypatch.setattr(attention_lm, "_HEAD", {})
+    if output == "softmax":
+        monkeypatch.setattr(
+            symbol, "SoftmaxOutput", lambda data, label, name, **_:
+            symbol.softmax(data, axis=-1, name=name) + 0 * symbol.sum(label))
+    plain = build()
+    assert len(plain._heads[0]) == (1 if output == "SoftmaxOutput" else 0)
+    toks = np.random.default_rng(1).integers(0, 50, size=(1, 20))
+    state_a, probs_a = named.prefill(toks.astype(np.float32))
+    state_b, probs_b = plain.prefill(toks.astype(np.float32))
+    np.testing.assert_array_equal(state_a.tok, state_b.tok)
+    np.testing.assert_allclose(probs_a, probs_b, rtol=1e-6, atol=1e-9)
+    for x, y in zip(jax.tree_util.tree_leaves(state_a.caches),
+                    jax.tree_util.tree_leaves(state_b.caches)):
+        np.testing.assert_array_equal(x, y)
+
+
+@pytest.mark.parametrize("drafting", [False, True])
+def test_the_loop_counts_the_chunks_that_ran_the_head(toy, drafting):
+    """A prompt of 20 tokens in chunks of 8: two chunks skip the head, the
+    third runs it; the counter and the ``serve.prefill`` spans say so."""
+    _, server = make_server(toy, int(drafting), slots=1)
+    chunks = obs.registry.get("mx_serve_chunks_total")
+    before = {h: chunks.labels(head=h).get() for h in ("run", "skipped")}
+    seen = len(obs.timeline.events())
+    server.submit(np.random.default_rng(2).integers(0, VOCAB, size=20),
+                  max_new_tokens=3)
+    assert len(server.run()[0]) == 3
+    assert chunks.labels(head="skipped").get() - before["skipped"] == 2
+    assert chunks.labels(head="run").get() - before["run"] == 1
+    spans = [e["args"] for e in obs.timeline.events()[seen:]
+             if e.get("name") == "serve.prefill"]
+    assert [(a["pos"], a["tokens"], a["head"]) for a in spans] == [
+        (0, 8, False), (8, 8, False), (16, 4, True)]
+
+
+# sha256[:16] of the jaxprs of the programs that pass ``_run`` no
+# ``head_rows``, for the toy graph with a prediction block, taken on the
+# commit before PR 47 (c7bdf15) with the very code below: the cut is an
+# argument that only the two chunk programs pass.
+UNCUT_PROGRAMS = {"mtp_step": "c9b3515ae947beaa",
+                  "decode": "e11e1b96aaea3a43",
+                  "verify": "7e6f23d8ab6ab61f"}
+
+
+@pytest.mark.parametrize("kind", sorted(UNCUT_PROGRAMS))
+def test_a_program_that_passes_no_rows_is_the_text_it_was(toy, kind):
+    from mxnet_tpu.programs.spec import probing
+
+    pred, _ = make_server(toy, 1, kv_dtype="int8")
+    fn = {"mtp_step": pred._paged_decode_mtp_impl,
+          "decode": pred._paged_decode_impl,
+          "verify": pred._paged_verify_impl}[kind]
+    with probing(pred):
+        text = str(jax.make_jaxpr(fn)(
+            *pred.serving_avals(2, chunk_w=8, spec_k=2)[kind]))
+    got = hashlib.sha256(text.encode()).hexdigest()[:16]
+    assert got == UNCUT_PROGRAMS[kind], json.dumps({kind: got})
