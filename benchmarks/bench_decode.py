@@ -38,13 +38,15 @@ attention-LM generating tokens through ``mxnet_tpu.decode`` —
   is the serving bottleneck PagedAttention removes.
 
 * **pallas_decode** — static attention-traffic pricing of the paged
-  decode step, einsum vs the fused Pallas flash-decoding kernel
-  (``MXNET_PALLAS_DECODE``, ops/pallas_decode.py): attention bytes = one
-  pool pass + materialized gather intermediates
+  decode step, the walk over live blocks vs the decode row's Pallas kernel
+  (ops/pallas_decode.py, taken where
+  ``ops.attention.decode_kernel_selected`` admits the shapes): attention
+  bytes = one pool pass + materialized gather intermediates
   (``analysis.cost.program_cost``'s gather_bytes term).  Published as
   ``decode_attn_bytes_per_token`` (+ per-path variants and the ratio) and
-  ``pallas_decode_enabled``; non-smoke asserts the fused path prices
-  <= 0.5x the einsum path's bytes at T=2048 — the mfu_table traffic win.
+  ``pallas_decode_enabled``; non-smoke asserts the kernel's path prices
+  <= 0.5x the walk's bytes at T=2048 — the mfu_table traffic win.  (At
+  the --smoke dims the rule keeps the whole view: both prices are one.)
 
 * **gqa** — grouped-query attention (``num_kv_heads = heads/G``,
   docs/inference.md): for each group factor G in the grid the bench
@@ -402,26 +404,30 @@ def main():
             "paged serve is %.2fx the dense-ring tokens/s/GB " \
             "(acceptance: >= 2x at T=%d)" % (vs_pr6_per_gb, t)
 
-    # ---- fused flash-decoding kernel: priced attention traffic ---------
+    # ---- the decode row's kernel: priced attention traffic -------------
     # Static pricing only (trace+lower, no execution, so it is exact and
     # machine-noise-free even in --smoke): the paged decode step's
     # attention traffic = one pass over the shared KV pool PLUS any
-    # materialized gather intermediates.  The einsum path's paged_gather
-    # writes (and its attention re-reads) the blocks the slots have reached
-    # of the (B, M*pt, E) dense-ring view per K and V per layer (all of it
+    # materialized gather intermediates.  The walk's paged_gather writes
+    # (and its attention re-reads) the blocks the slots have reached of
+    # the (B, M*pt, E) dense-ring view per K and V per layer (all of it
     # where the view is one block) — program_cost's gather_bytes term; the
-    # fused Pallas kernel (MXNET_PALLAS_DECODE) walks the page table
-    # inside the kernel and has no such gather, so its priced bytes must
-    # drop >= 2x — the mfu_table row ISSUE-11's acceptance pins.
-    from mxnet_tpu import config as _cfg
+    # decode row's Pallas kernel (ops/pallas_decode.py, taken where
+    # ops.attention.decode_kernel_selected admits the shapes) copies the
+    # live blocks' pages inside the kernel and has no such gather, so its
+    # priced bytes must drop >= 2x where it is taken.
     from mxnet_tpu.analysis.cost import program_cost
-    from mxnet_tpu.ops.attention import decode_kernel_mode, live_block_plan
+    from mxnet_tpu.ops import attention as _attn_ops
+    from mxnet_tpu.ops.attention import live_block_plan
 
-    def _price_decode_attn(arm, psym=sym, pparams=params):
-        knobs = {"MXNET_PALLAS_DECODE": "1" if arm else "0"}
-        if arm and SMOKE:
-            knobs["MXNET_PALLAS_INTERPRET"] = "1"
-        with _cfg.overrides(**knobs):
+    def _price_decode_attn(kernel, psym=sym, pparams=params):
+        # kernel: a backend that runs Pallas (the interpreter in --smoke),
+        # so the rule decides from the shapes; else one that is shown
+        # none, so the walk (or the whole view) serves every shape
+        backend = _attn_ops._kernel_backend
+        _attn_ops._kernel_backend = (lambda: (True, SMOKE)) if kernel \
+            else (lambda: (False, False))
+        try:
             pp2 = DecodePredictor(
                 psym, pparams, cache_len=paged_cap, ctx=ctx,
                 temperature=0.0, kv_dtype=kv_dtype, paged=True,
@@ -434,23 +440,28 @@ def main():
                     pp2._decode_fn, (pp2._env, st, tables, active, key))
             finally:
                 pp2._probing = False
-            # the static count sees the walk over live blocks as ONE step
-            # of a loop whose trip count is data: price the einsum path
-            # with every block live, the case the kernel is compared at
-            plan = None if arm else live_block_plan(
-                (slots, 1), (slots, paged_cap // page_tokens), page_tokens)
-            if plan is not None:
-                cost["gather_bytes"] *= -(
-                    -slots * -(-paged_cap // plan[0]) // plan[1])
-            return pp2.pool_bytes() + cost["gather_bytes"], cost
+        finally:
+            _attn_ops._kernel_backend = backend
+        took = "decode-kernel" in pp2._decode_paths.get(1, ())
+        # the static count sees the walk over live blocks as ONE step
+        # of a loop whose trip count is data: price the walk with every
+        # block live, the case the kernel is compared at
+        plan = None if took else live_block_plan(
+            (slots, 1), (slots, paged_cap // page_tokens), page_tokens)
+        if plan is not None:
+            cost["gather_bytes"] *= -(
+                -slots * -(-paged_cap // plan[0]) // plan[1])
+        cost["decode_kernel"] = took
+        return pp2.pool_bytes() + cost["gather_bytes"], cost
 
     attn_einsum, cost_e = _price_decode_attn(False)
     attn_fused, cost_f = _price_decode_attn(True)
-    # what the TIMED serve phases above actually dispatched (the ambient
-    # config: TPU rigs arm MXNET_PALLAS_DECODE; the CPU-harness smoke
-    # keeps the einsum path — interpret-mode kernels would measure the
-    # Pallas interpreter, not the serving loop)
-    pallas_enabled = bool(decode_kernel_mode()[0])
+    # what the TIMED serve phases above actually dispatched: the kernel
+    # where this backend runs Pallas and the rule took the shapes (the
+    # CPU-harness smoke keeps the walk: interpret-mode kernels would
+    # measure the Pallas interpreter, not the serving loop)
+    pallas_enabled = bool(_attn_ops._kernel_backend()[0]
+                          and cost_f["decode_kernel"])
     attn_active = attn_fused if pallas_enabled else attn_einsum
     attn_ratio = attn_einsum / max(attn_fused, 1)
     emit({"phase": "pallas_decode",
@@ -463,9 +474,10 @@ def main():
           "program_bytes_fused": cost_f["bytes"],
           "attn_bytes_ratio": round(attn_ratio, 3)})
     if not SMOKE:
-        # the kernel acceptance line at full dims (T=2048): fusing
-        # gather + dequant + attention into one HBM pass must at least
-        # halve the decode step's priced attention bytes
+        # the kernel acceptance line at full dims (T=2048): reading the
+        # live pages inside the kernel must at least halve the decode
+        # step's priced attention bytes
+        assert cost_f["decode_kernel"], "the rule refused the full dims"
         assert attn_fused * 2 <= attn_einsum, \
             "fused decode attention prices %d bytes vs einsum %d " \
             "(acceptance: <= 0.5x at T=%d)" % (attn_fused, attn_einsum, t)

@@ -633,10 +633,11 @@ def stablehlo_gather_stats(stablehlo_text):
     materializes a full (B, M*page_tokens, E) dense-ring view of the KV
     pool per K and V per layer, the single largest intermediate in the
     serving system, which pure arg+output accounting cannot see.  The
-    fused Pallas flash-decoding kernel has no such gather (the page walk
-    happens inside the kernel), so the paged decode step's priced bytes
-    visibly drop when ``MXNET_PALLAS_DECODE`` engages — the mfu_table
-    delta the ISSUE-11 acceptance line pins."""
+    decode row's Pallas kernel has no such gather (it copies the live
+    blocks' pages inside the kernel, ``ops/pallas_decode.py``), so a paged
+    decode step's priced bytes visibly drop where
+    ``ops.attention.decode_kernel_selected`` takes it (``DECODE_PATH``
+    ``decode-kernel``)."""
     count = 0
     nbytes = 0
     for line in stablehlo_text.splitlines():

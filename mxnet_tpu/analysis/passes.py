@@ -326,29 +326,29 @@ class FlopDtypePass(Pass):
     legalization (XLA:CPU rewrites bf16 dots through f32) happens later
     and is out of scope.
 
-    Pallas-decode tripwire: a decode/verify artifact built while
-    ``MXNET_PALLAS_DECODE`` was armed carries ``meta['pallas_decode']``
-    — the config PROMISED the fused flash-decoding kernel
-    (``ops/pallas_decode.py``: gather + dequant + attention in one HBM
-    pass).  The promise is checked at the artifact level: the traced
-    jaxpr must contain a ``pallas_call`` (interpret or compiled) or the
-    lowered StableHLO a TPU custom-call.  A program that quietly fell
-    back to the three-pass ``paged_gather`` + einsum path — a shape
-    gate, a dispatch regression — is an *error* here, so the fallback
-    costs a red lint run instead of a silent 3x decode-bandwidth loss.
+    Pallas-decode tripwire: a decode/verify artifact whose trace took the
+    decode row's kernel at some attention node
+    (``ops.attention.DECODE_PATH`` read ``decode-kernel``, chosen by
+    ``decode_kernel_selected`` from the call's shapes) carries
+    ``meta['pallas_decode']``: the dispatch PROMISED
+    ``ops/pallas_decode.py``'s kernel, the live blocks' pages read once.
+    The promise is checked at the artifact level: the traced jaxpr must
+    contain a ``pallas_call`` (interpret or compiled) or the lowered
+    StableHLO a TPU custom-call.  A program that counted the kernel and
+    lowered without it is an *error* here.
     """
 
     name = "flop-dtype"
     requires = ("stablehlo",)
 
     _PALLAS_PROMISES = (
-        ("pallas_decode", "MXNET_PALLAS_DECODE", "pallas-decode",
-         "fused Pallas flash-decoding kernel present "
-         "(MXNET_PALLAS_DECODE honored)",
-         "MXNET_PALLAS_DECODE promises the fused flash-decoding kernel "
-         "but no pallas_call lowered into this program — decode "
-         "attention silently fell back to the three-pass "
-         "paged_gather+einsum path (shape gate or dispatch regression)"),
+        ("pallas_decode", "DECODE_PATH decode-kernel", "pallas-decode",
+         "the decode row's Pallas kernel present (the dispatch's "
+         "decode-kernel path lowered)",
+         "the dispatch took the decode row's Pallas kernel "
+         "(DECODE_PATH decode-kernel) but no pallas_call lowered into "
+         "this program: the live blocks are attended some other way "
+         "(dispatch regression)"),
         ("pallas_update", "MXNET_PALLAS_UPDATE", "pallas-update",
          "fused multi-tensor Pallas optimizer-update kernel present "
          "(MXNET_PALLAS_UPDATE honored)",

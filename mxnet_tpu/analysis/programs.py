@@ -20,12 +20,12 @@ the compiled surfaces behind every headline number so far:
   verify programs over SHARED page pools: per-slot page tables and
   active masks ride in as data (zero retraces across admissions, COW
   forks and retirements), appends scatter through the tables, and
-  attention runs through the FUSED Pallas flash-decoding kernel
-  (``MXNET_PALLAS_DECODE`` armed for the drive; interpret mode off-TPU)
-  — the flop-dtype pass's ``pallas-fallback`` tripwire proves the
-  kernel lowered instead of the three-pass einsum fallback; their
-  cache-bytes meta is the POOL total (the paged serving HBM bill the
-  cache-bytes pass budgets);
+  attention takes the path ``ops.attention.paged_attend`` chooses from
+  the call's shapes (``meta['attn_paths']``; at these toy sizes a view is
+  one block and is gathered whole: the decode row's Pallas kernel and its
+  ``pallas-fallback`` tripwire are driven at a size the kernel tiles in
+  tests/test_pallas_decode.py); their cache-bytes meta is the POOL total
+  (the paged serving HBM bill the cache-bytes pass budgets);
 * ``gqa_decode_step`` — the paged decode program under a grouped-query
   layout (num_kv_heads < num_heads): pools allocate H_kv head slices,
   and the cache-bytes pass's ``mha-under-gqa`` tripwire proves the G×
@@ -285,7 +285,7 @@ def _speculative_artifacts():
 
 def _paged_artifacts():
     """paged_decode_step / paged_verify_step, driven by a real
-    shared-prefix paged serve WITH THE FUSED KERNEL ON.
+    shared-prefix paged serve.
 
     Four requests sharing a 6-token prefix drain through a
     :class:`~mxnet_tpu.decode.DecodeServer` over a paged predictor
@@ -294,100 +294,83 @@ def _paged_artifacts():
     page tables and immediate retirement all run before the artifacts
     snapshot — each program's trace counter must then read exactly one.
 
-    The drive arms ``MXNET_PALLAS_DECODE`` (interpret mode off-TPU), so
-    the canonical paged programs are audited as they SERVE: decode/verify
-    attention through the fused flash-decoding kernel
-    (``ops/pallas_decode.py``), with the flop-dtype pass's
-    ``pallas-fallback`` tripwire proving the kernel actually lowered —
-    a dispatch regression that silently fell back to the einsum path is
-    a red lint run, not a quiet 3x decode-bandwidth loss.
+    The programs are audited as they SERVE: which path their attention
+    took is the dispatch's own choice from the shapes
+    (``ops.attention.decode_kernel_selected``), recorded in
+    ``meta['attn_paths']``.
     """
-    from mxnet_tpu import config as _config
     from mxnet_tpu.decode import DecodePredictor, DecodeServer
 
-    import jax
-
-    knobs = {"MXNET_PALLAS_DECODE": "1"}
-    if jax.default_backend() != "tpu":
-        knobs["MXNET_PALLAS_INTERPRET"] = "1"
-    with _config.overrides(**knobs):
-        d = _LM
-        rng = np.random.RandomState(3)
-        pred = DecodePredictor(
-            _lm_symbol(), _lm_params(_lm_symbol(), d["batch"],
-                                     d["seq_len"]),
-            cache_len=d["seq_len"], temperature=0.0, kv_dtype="",
-            paged=True, page_tokens=4, prefill_chunk=4)
-        server = DecodeServer(pred, max_prefill=12, slots=d["batch"],
-                              max_new_tokens=3, spec_k=_SPEC_K)
-        prefix = rng.randint(0, d["vocab"], size=(6,))
-        for n in (3, 5, 2, 4):          # shared prefix, mixed tails
-            server.submit(np.concatenate(
-                [prefix, rng.randint(0, d["vocab"], size=(n,))]))
-        results = server.run()
-        stats = server.stats()
-        if len(results) != 4 or server.spec_steps == 0 \
-                or stats.get("prefix_cache_hit_rate", 0) <= 0:
-            raise MXNetError(
-                "paged serve drive did not exercise the paged programs "
-                "(results=%d, spec_steps=%d, hit_rate=%s)"
-                % (len(results), server.spec_steps,
-                   stats.get("prefix_cache_hit_rate")))
-        # a fresh batch state at the same sizing lowers the SAME traces
-        state = pred.paged_batch_state(d["batch"])
-        return (pred.decode_artifact(state, name="paged_decode_step"),
-                pred.verify_artifact(state, _SPEC_K,
-                                     name="paged_verify_step"))
+    d = _LM
+    rng = np.random.RandomState(3)
+    pred = DecodePredictor(
+        _lm_symbol(), _lm_params(_lm_symbol(), d["batch"],
+                                 d["seq_len"]),
+        cache_len=d["seq_len"], temperature=0.0, kv_dtype="",
+        paged=True, page_tokens=4, prefill_chunk=4)
+    server = DecodeServer(pred, max_prefill=12, slots=d["batch"],
+                          max_new_tokens=3, spec_k=_SPEC_K)
+    prefix = rng.randint(0, d["vocab"], size=(6,))
+    for n in (3, 5, 2, 4):          # shared prefix, mixed tails
+        server.submit(np.concatenate(
+            [prefix, rng.randint(0, d["vocab"], size=(n,))]))
+    results = server.run()
+    stats = server.stats()
+    if len(results) != 4 or server.spec_steps == 0 \
+            or stats.get("prefix_cache_hit_rate", 0) <= 0:
+        raise MXNetError(
+            "paged serve drive did not exercise the paged programs "
+            "(results=%d, spec_steps=%d, hit_rate=%s)"
+            % (len(results), server.spec_steps,
+               stats.get("prefix_cache_hit_rate")))
+    # a fresh batch state at the same sizing lowers the SAME traces
+    state = pred.paged_batch_state(d["batch"])
+    return (pred.decode_artifact(state, name="paged_decode_step"),
+            pred.verify_artifact(state, _SPEC_K,
+                                 name="paged_verify_step"))
 
 
 def _gqa_artifacts():
     """gqa_decode_step: the paged decode program under a GROUPED-QUERY
     layout (num_kv_heads < num_heads), driven by a real grouped paged
-    serve with the fused kernel armed.
+    serve.
 
     The grouped config (G = heads/kv_heads = 4 here) allocates pools
     H_kv heads wide — the cache-bytes meta carries the grouped promise
     (``num_kv_heads``/``attn_dims``/``cache_kv_dims``), so the
     cache-bytes pass's ``mha-under-gqa`` tripwire proves the pool really
     shrank by G and a dropped num_kv_heads is a red lint run."""
-    from mxnet_tpu import config as _config
     from mxnet_tpu.decode import DecodePredictor, DecodeServer
     from mxnet_tpu.models import attention_lm
 
-    import jax
-
-    knobs = {"MXNET_PALLAS_DECODE": "1"}
-    if jax.default_backend() != "tpu":
-        knobs["MXNET_PALLAS_INTERPRET"] = "1"
-    with _config.overrides(**knobs):
-        d = _LM
-        rng = np.random.RandomState(5)
-        sym = attention_lm.get_symbol(
-            vocab_size=d["vocab"], seq_len=d["seq_len"],
-            num_layers=d["layers"], embed=d["embed"], heads=d["heads"],
-            ffn_hidden=d["ffn"], num_kv_heads=1)
-        pred = DecodePredictor(
-            sym, _lm_params(sym, d["batch"], d["seq_len"]),
-            cache_len=d["seq_len"], temperature=0.0, kv_dtype="",
-            paged=True, page_tokens=4, prefill_chunk=4)
-        server = DecodeServer(pred, max_prefill=12, slots=d["batch"],
-                              max_new_tokens=3)
-        prefix = rng.randint(0, d["vocab"], size=(6,))
-        for n in (3, 5, 2, 4):          # shared prefix, mixed tails
-            server.submit(np.concatenate(
-                [prefix, rng.randint(0, d["vocab"], size=(n,))]))
-        results = server.run()
-        if len(results) != 4:
-            raise MXNetError(
-                "grouped paged serve drive did not complete "
-                "(results=%d)" % (len(results),))
-        state = pred.paged_batch_state(d["batch"])
-        art = pred.decode_artifact(state, name="gqa_decode_step")
-        if not art.meta.get("num_kv_heads"):
-            raise MXNetError(
-                "gqa_decode_step artifact carries no grouped-K/V meta; "
-                "the mha-under-gqa tripwire would be vacuous")
-        return (art,)
+    d = _LM
+    rng = np.random.RandomState(5)
+    sym = attention_lm.get_symbol(
+        vocab_size=d["vocab"], seq_len=d["seq_len"],
+        num_layers=d["layers"], embed=d["embed"], heads=d["heads"],
+        ffn_hidden=d["ffn"], num_kv_heads=1)
+    pred = DecodePredictor(
+        sym, _lm_params(sym, d["batch"], d["seq_len"]),
+        cache_len=d["seq_len"], temperature=0.0, kv_dtype="",
+        paged=True, page_tokens=4, prefill_chunk=4)
+    server = DecodeServer(pred, max_prefill=12, slots=d["batch"],
+                          max_new_tokens=3)
+    prefix = rng.randint(0, d["vocab"], size=(6,))
+    for n in (3, 5, 2, 4):          # shared prefix, mixed tails
+        server.submit(np.concatenate(
+            [prefix, rng.randint(0, d["vocab"], size=(n,))]))
+    results = server.run()
+    if len(results) != 4:
+        raise MXNetError(
+            "grouped paged serve drive did not complete "
+            "(results=%d)" % (len(results),))
+    state = pred.paged_batch_state(d["batch"])
+    art = pred.decode_artifact(state, name="gqa_decode_step")
+    if not art.meta.get("num_kv_heads"):
+        raise MXNetError(
+            "gqa_decode_step artifact carries no grouped-K/V meta; "
+            "the mha-under-gqa tripwire would be vacuous")
+    return (art,)
 
 
 def _ckpt_train_step_artifact():

@@ -79,7 +79,7 @@ def refresh(name=None):
 def overrides(**knobs):
     """Temporarily pin declared flags through the environment.
 
-    ``with config.overrides(MXNET_PALLAS_DECODE="1"):`` sets each env
+    ``with config.overrides(MXNET_PALLAS_UPDATE="1"):`` sets each env
     var (``None`` unsets it), refreshes the registry cache so the new
     values are live inside the block, and restores BOTH the environment
     and the cache on exit — the save/set/refresh/restore dance that
@@ -138,20 +138,6 @@ register("MXNET_ENGINE_TYPE", str, "",
          "debugging (reference src/engine/engine.cc:13-39).")
 register("MXNET_PROFILER_AUTOSTART", bool, False,
          "Start the profiler at import time (reference env_var.md:71-79).")
-register("MXNET_PALLAS_DECODE", bool, False,
-         "Use the fused Pallas flash-decoding kernels "
-         "(ops/pallas_decode.py) for decode/verify attention over KV "
-         "caches: the page-table gather, int8/fp8 dequantization and the "
-         "length-masked softmax run in ONE HBM pass over the pool "
-         "(PagedAttention's in-kernel gather), with a split-K grid axis "
-         "parallelizing over cache length (Flash-Decoding) so small-batch "
-         "decode fills the chip.  Applies to paged pools AND dense ring "
-         "buffers (identity page table).  Engages on TPU, or anywhere "
-         "under MXNET_PALLAS_INTERPRET; unsupported shapes (or a "
-         "mesh-sharded cache — Pallas is opaque to GSPMD) fall back to "
-         "the three-pass paged_gather+sdpa_decode einsum path, which the "
-         "mxlint flop-dtype tripwire reports on the canonical paged "
-         "programs so the fallback is never silent.")
 register("MXNET_PALLAS_UPDATE", bool, False,
          "Use the fused multi-tensor Pallas optimizer-update kernel "
          "(ops/pallas_update.py) inside the compiled train step: the "

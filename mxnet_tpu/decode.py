@@ -99,6 +99,7 @@ slot is reused with no clearing program.
 """
 from __future__ import annotations
 
+import functools
 import time
 from collections import Counter, deque
 from typing import NamedTuple
@@ -511,6 +512,8 @@ class DecodePredictor:
                              "chunk": 0, "fork": 0, "commit": 0,
                              "extract": 0, "install": 0}
         self._probing = False
+        # {rows a slot: the attention paths a program's trace took}
+        self._decode_paths = {}
         if self._paged:
             from .programs.aot import AotDispatch
 
@@ -714,12 +717,11 @@ class DecodePredictor:
         from .ops import attention as _attn
 
         out = []
-        armed = _attn.decode_kernel_mode()[0]
         for layout, node in zip(self._layouts, self._cache_nodes):
             if not self._paged or layout.kind != "full":
                 continue
             cap, pt = layout.capacity, self._page_tokens
-            plan = None if armed else _attn.live_block_plan(
+            plan = _attn.live_block_plan(
                 (slots, 1), (slots, cap // pt), pt,
                 mesh_active=self._mesh is not None,
                 window=int(node.parsed_attrs().get("window", 0) or 0))
@@ -1067,6 +1069,11 @@ class DecodePredictor:
         b, t = tokens.shape[0], tokens.shape[1]
         new_caches = []
         self._counts = counts = {}
+        # which of paged_attend's / cache_attend's paths this walk's
+        # attention nodes took, recorded at trace time by the rows a slot
+        # of the program that called: artifact meta says from it whether
+        # the program holds the decode row's kernel
+        self._decode_paths[t] = paths = set()
         ci = qi = 0
         values = {}
         base_key = jax.random.PRNGKey(0)
@@ -1221,7 +1228,6 @@ class DecodePredictor:
                                 num_heads=heads, scale=scale,
                                 num_kv_heads=kv_heads, active=active, **at)
                             outs = [out]
-                            self._decode_path = "einsum"
                             counts.setdefault("sparse_blocks_chosen",
                                               []).append(chosen)
                             counts.setdefault("sparse_blocks_live",
@@ -1261,12 +1267,7 @@ class DecodePredictor:
                                                        mesh_active=mesh_on,
                                                        num_kv_heads=kv_heads,
                                                        **extra)]
-                        # PATH_TAKEN, recorded at trace time: which decode-
-                        # attention path this predictor's programs actually
-                        # lowered — refines artifact meta so a shape-gated
-                        # fallback ("einsum-gated") never false-trips the
-                        # mxlint pallas-fallback error
-                        self._decode_path = _attn.DECODE_PATH["last"]
+                        paths.add(_attn.DECODE_PATH["last"])
                         new_caches.append((kc, vc))
                 elif opname in self._state_ops:
                     op = self._state_ops[opname]
@@ -2737,8 +2738,6 @@ class DecodePredictor:
             for c in (kc, vc):
                 dtypes.add(str((c.data if isinstance(c, QuantKV)
                                 else c).dtype))
-        from .ops.attention import decode_kernel_mode
-
         meta = {"cache_bytes": self.cache_bytes(state),
                 "kv_dtype": str(self._kv_dtype)
                 if self._kv_dtype is not None else None,
@@ -2746,18 +2745,7 @@ class DecodePredictor:
                 "cache_layout": "paged" if self._paged else "dense",
                 "kv_paged": bool(self._paged or (
                     self._paged_from_env
-                    and _config.get("MXNET_KV_PAGED"))),
-                # the artifact-level PATH_TAKEN tripwire: when the fused
-                # flash-decoding kernel is configured to engage (and no
-                # mesh shards the cache away from it), the flop-dtype
-                # pass demands a pallas_call in the program — a silent
-                # einsum fallback becomes a lint error, not a perf loss.
-                # _refine_pallas_meta withdraws the promise post-trace
-                # when the shape gate VISIBLY refused the kernel
-                # ("einsum-gated" — e.g. head dims off the Mosaic tile
-                # on TPU), so only silent fallbacks trip the error
-                "pallas_decode": bool(decode_kernel_mode()[0]
-                                      and self._mesh is None)}
+                    and _config.get("MXNET_KV_PAGED")))}
         if self._grouped_kv_heads is not None:
             # grouped-K/V promise + the widths actually allocated: the
             # cache-bytes pass errors when a cache/pool plane comes out
@@ -2800,16 +2788,17 @@ class DecodePredictor:
             meta["replicated_degrades"] = degrades
         return meta
 
-    def _refine_pallas_meta(self, art):
-        """Withdraw the artifact's fused-kernel promise when the dispatch
-        visibly shape-gated it.  ``artifact_from_jit``'s trace (or the
-        serving trace it reuses) just ran ``paged_attend``/
-        ``cache_attend``, which recorded the taken path in
-        ``self._decode_path``; a gated fallback is legitimate — the
-        flop-dtype tripwire targets SILENT einsum regressions only."""
-        if art.meta.get("pallas_decode") and \
-                getattr(self, "_decode_path", None) == "einsum-gated":
-            art.meta["pallas_decode"] = False
+    def _refine_decode_meta(self, art, rows=1):
+        """Say in the artifact's meta which paths the attention nodes of
+        the program with ``rows`` query rows a slot took when it was traced
+        (``ops.attention.DECODE_PATH``, one of ``decode-kernel`` / ``walk`` /
+        ``whole`` a node), and promise the decode row's Pallas kernel where
+        one took it: the flop-dtype pass then demands a ``pallas_call`` in
+        the program, so a kernel the rule chose and the lowering lost is a
+        lint error."""
+        paths = self._decode_paths.get(int(rows), ())
+        art.meta["attn_paths"] = sorted(paths)
+        art.meta["pallas_decode"] = "decode-kernel" in paths
         return art
 
     def decode_artifact(self, state, key=None, name="decode_step"):
@@ -2829,12 +2818,13 @@ class DecodePredictor:
         donated = len(jtu.tree_leaves(astate)) if self._donate else 0
         if self._paged:
             tables, active = self._paged_probe_args(state)
-            args = (env, astate, _aval(tables), _aval(active), akey)
+            args = (env, astate, jtu.tree_map(_aval, tables), _aval(active),
+                    akey)
         else:
             args = (env, astate, akey)
         return probe_artifact(
             self, self._decode_fn, args, name,
-            refine=self._refine_pallas_meta, donated_leaves=donated,
+            refine=self._refine_decode_meta, donated_leaves=donated,
             mesh_shape=dict(self._mesh.shape)
             if self._mesh is not None else None,
             trace_count=self.trace_counts["decode"], expected_traces=1,
@@ -2866,13 +2856,15 @@ class DecodePredictor:
         donated = len(jtu.tree_leaves(astate)) if self._donate else 0
         if self._paged:
             tables, active = self._paged_probe_args(state)
-            args = (env, astate, _aval(tables), _aval(active), atoks,
-                    aq, akey)
+            args = (env, astate, jtu.tree_map(_aval, tables), _aval(active),
+                    atoks, aq, akey)
         else:
             args = (env, astate, atoks, aq, akey)
         return probe_artifact(
             self, self._verify_fn, args, name,
-            refine=self._refine_pallas_meta, donated_leaves=donated,
+            refine=functools.partial(self._refine_decode_meta,
+                                     rows=int(k) + 1),
+            donated_leaves=donated,
             mesh_shape=dict(self._mesh.shape)
             if self._mesh is not None else None,
             trace_count=self.trace_counts["verify"],

@@ -825,18 +825,6 @@ def paged_copy(pool, src, dst):
     return jtu.tree_map(lambda plane: plane.at[dst].set(plane[src]), pool)
 
 
-def _kernel_pools(k_pool, v_pool):
-    """A node's pools as :mod:`~mxnet_tpu.ops.pallas_decode` reads them,
-    each with a (P, page_tokens, H) scale plane of its own: the shared
-    plane's rows are split at the kernel's door."""
-    if not isinstance(k_pool, QuantKV):
-        return k_pool, v_pool
-    p, pt = k_pool.data.shape[:2]
-    scales = k_pool.scale.reshape(p, pt, 2, -1)
-    return (QuantKV(k_pool.data, scales[:, :, 0]),
-            QuantKV(v_pool.data, scales[:, :, 1]))
-
-
 # Which path the last dot_product_attention dispatch traced: "flash",
 # "einsum" or "ring".  Written at trace time (dispatch happens under jit
 # tracing), so tests can assert the kernel path actually ran instead of
@@ -855,8 +843,8 @@ PATH_TAKEN = {"last": None}
 FLASH_MIN_T = {64: 512, 128: 512}
 
 
-def _note_path(path):
-    PATH_TAKEN["last"] = path
+def _note_path(path, taken=None):
+    (PATH_TAKEN if taken is None else taken)["last"] = path
     from .. import obs as _obs
 
     _obs.registry.counter(
@@ -890,15 +878,13 @@ def flash_selected(q_shape, k_shape, causal, num_heads, num_kv_heads,
 
 
 # Same marker for the DECODE-side dispatch (paged_attend / cache_attend):
-# "pallas" when the fused flash-decoding kernel traced, "einsum" for the
-# gather+dequant+attend fallback (knob off or mesh-sharded cache), and
-# "einsum-gated" when the kernel was ARMED but the shape gate
-# (pallas_decode.supported) refused — a legitimate, visible fallback
-# (e.g. head dims off the Mosaic tile on TPU).  mxnet_tpu.decode records
-# it per program so artifact meta promises the kernel only when the
-# dispatch actually took it; the mxlint flop-dtype pass then turns a
-# promised-but-missing pallas_call into a lint error (the artifact-level
-# tripwire), without false-flagging gated shapes.
+# "decode-kernel" when the decode row's Pallas kernel traced
+# (decode_kernel_selected), "walk" for the loop over the live blocks
+# (_attend_live_blocks) and "whole" where the view is gathered and attended
+# whole (a window ring, a view of one block, a sharded pool, a dense ring).
+# Each such dispatch also counts in mx_attn_dispatch_total{path=...}.
+# mxnet_tpu.decode records it per program, so that an artifact's meta says
+# which path its attention took.
 DECODE_PATH = {"last": None}
 
 
@@ -913,17 +899,6 @@ def _kernel_backend():
     on_tpu = jax.default_backend() == "tpu"
     interpret = bool(_config.get("MXNET_PALLAS_INTERPRET")) and not on_tpu
     return on_tpu or interpret, interpret
-
-
-def decode_kernel_mode():
-    """``(engage, interpret)`` for the fused decode kernel under the
-    current config and backend: engaged when ``MXNET_PALLAS_DECODE`` is
-    set AND the backend can run it (:func:`_kernel_backend`)."""
-    from .. import config as _config
-
-    if not _config.get("MXNET_PALLAS_DECODE"):
-        return False, False
-    return _kernel_backend()
 
 
 def _extras(window, sink, value_scale, layer):
@@ -984,9 +959,35 @@ def live_block_plan(q_shape, table_shape, page_tokens, mesh_active=False,
                              LIVE_STEP_SCORES // (block * tq), blocks // 16))
 
 
+def decode_kernel_selected(q_shape, k_pool, v_pool, table_shape, num_heads,
+                           num_kv_heads, mesh_active=False, window=0):
+    """``(take, interpret)``: whether :func:`paged_attend` hands the live
+    blocks of this call to the decode row's Pallas kernel
+    (:mod:`~mxnet_tpu.ops.pallas_decode`), decided from what the call
+    shows, as :func:`flash_selected` decides for training.
+
+    All must hold: one query row a slot (more rows are matrix products
+    already, and take the walk); a :func:`live_block_plan` (so no window
+    node, no mesh, a view of more than a block); a backend that runs
+    Pallas (:func:`_kernel_backend`); and shapes the kernel tiles
+    (``pallas_decode.tiles``: whole lane tiles of keys, values and scale
+    rows, a step's buffers within fast memory).  ``take`` is the call's
+    ``Tiles`` where it is taken, None where it is not."""
+    from . import pallas_decode as _pd
+
+    runs, interpret = _kernel_backend()
+    plan = live_block_plan(q_shape, table_shape, _plane(k_pool).shape[1],
+                           mesh_active=mesh_active, window=window)
+    if q_shape[1] != 1 or plan is None or not runs:
+        return None, False
+    return _pd.tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads,
+                     plan[0]), interpret
+
+
 def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
                         scale, num_kv_heads, block, group, sink=None,
-                        value_scale=1.0, layer="attn", chosen=None):
+                        value_scale=1.0, layer="attn", chosen=None,
+                        kernel=None):
     """:func:`paged_gather` + :func:`_sdpa_cache` over the blocks the slots
     have reached, and no others.
 
@@ -1008,7 +1009,15 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
     the walk: query row ``i`` of kv group ``g`` attends position ``p`` only
     where ``mask[b, g, i, p // width]`` (``width`` divides ``block``).  The
     walk still visits every live block; what a row did not choose is masked
-    out of its softmax."""
+    out of its softmax.
+
+    ``kernel`` = ``(Tiles, interpret)`` (:func:`decode_kernel_selected`: one
+    query row, nothing ``chosen``) puts ONE Pallas kernel over the list in
+    the loop's place (``pallas_decode.attend_blocks``): a step a live row of
+    the list, the block's pages copied from the pools into fast memory by
+    the kernel itself, no gathered view written, dead rows neither visited
+    nor read.  The list before it and the combine after it are the loop's
+    own."""
     import jax
     import jax.numpy as jnp
 
@@ -1036,6 +1045,27 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         pages = pages.reshape(b, nb, ppb)[slot, blk]          # (rows, ppb)
         steps = -(-ends[-1] // group)
     hdv = _plane(v_pool).shape[2] // (int(num_kv_heads) or num_heads)
+    if kernel is not None:
+        from . import pallas_decode as _pd
+
+        with _scope(layer, "scores"):
+            j = jnp.arange(nb, dtype=jnp.int32)[None, :]
+            parts = _pd.attend_blocks(
+                q, k_pool, v_pool, pages, slot,
+                jnp.clip(jnp.minimum(total, cap)[slot] - blk * block, 0,
+                         block),
+                ends[-1], kernel[0],
+                scale or 1.0 / np.sqrt(q.shape[2] // num_heads),
+                interpret=kernel[1])
+            # a slot's dead blocks read its first and weigh nothing
+            where = jnp.where(j < reached[:, None], first[:, None] + j,
+                              first[:, None])
+            live = (j < reached[:, None])[:, :, None, None]
+            m, den, acc = (x[where][:, :, None] for x in parts)
+            m = jnp.where(live, m, jnp.finfo(jnp.float32).min)
+            den = jnp.where(live, den, 0.0)
+            acc = jnp.where(live[..., None], acc, 0.0)
+        return _combine_blocks(m, den, acc, sink, value_scale, v_pool, layer)
     # B slots' blocks are kept apart until the loop has ended, one row a
     # block (row ``rows`` is never written: a slot's dead blocks read it).
     # One slot's blocks fold into one running row as the loop goes: a
@@ -1099,6 +1129,17 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
             j = jnp.arange(nb, dtype=jnp.int32)[None, :]
             where = jnp.where(j < reached[:, None], first[:, None] + j, rows)
             m, den, acc = (buf[where] for buf in parts)  # (B, nb, tq, H[, e])
+    return _combine_blocks(m, den, acc, sink, value_scale, v_pool, layer)
+
+
+def _combine_blocks(m, den, acc, sink, value_scale, v_pool, layer):
+    """One softmax a slot from its blocks' shares ``m``, ``den`` (B, nb, tq,
+    H) and ``acc`` (B, nb, tq, H, hdv); the sink and the value scale join
+    here.  -> (B, tq, H * hdv)."""
+    import jax.numpy as jnp
+
+    b, _, tq, num_heads, hdv = acc.shape
+    with _scope(layer, "scores"):
         top = jnp.max(m, axis=1)
         if sink is not None:
             sink = jnp.asarray(sink, jnp.float32).reshape(1, 1, num_heads)
@@ -1119,58 +1160,48 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
                  scale=None, mesh_active=False, num_kv_heads=0, window=0,
                  sink=None, value_scale=1.0, layer="attn"):
     """Decode/verify attention over shared page pools — the ONE entry the
-    decode programs call.
+    decode programs call.  Which of three paths a call takes follows from
+    what the call shows; each counts in ``mx_attn_dispatch_total{path}``.
 
-    With ``MXNET_PALLAS_DECODE`` armed and the shapes supported, this is
-    the fused Pallas flash-decoding kernel
-    (:mod:`~mxnet_tpu.ops.pallas_decode`): the page-table gather, the
-    int8/fp8 dequant and the length-masked softmax run in ONE HBM pass
-    over the pool, split-K parallel over cache length.  Otherwise (knob
-    off, unsupported shape, or a mesh-sharded pool — Pallas is opaque to
-    GSPMD) it falls back to the two-pass einsum path: gather, then the
-    products of :func:`sdpa_decode`/:func:`sdpa_verify` in the pool's
-    storage dtype (an int8/fp8 view is never dequantized into a float
-    copy), whose numerics the kernel matches within documented tolerances
-    (docs/inference.md).  A node with a window, a sink or a value scale
-    takes the einsum path.
+    ``walk``: the view is attended block by block, only the blocks the
+    slots have reached (:func:`_attend_live_blocks`, by
+    :func:`live_block_plan`): one program, ``total_len`` as data, no view
+    of the whole table; a loop gathers a few blocks' pages and takes the
+    products of :func:`sdpa_decode`/:func:`sdpa_verify` over them in the
+    pool's storage dtype (an int8/fp8 view is never dequantized into a
+    float copy).
 
-    The einsum path gathers and attends only the blocks the slots have
-    reached (:func:`_attend_live_blocks`, by :func:`live_block_plan`): one
-    program, ``total_len`` as data, no view of the whole table.  Where the
-    plan is None — a view of one block, a window node, a sharded pool —
-    it gathers the whole view (:func:`paged_gather`) and attends it as a
-    dense ring is attended, the same jaxpr as ever and bit-parity with a
-    dense ring; the walk agrees with that within the tolerance of
-    reordered float32 sums."""
-    engage, interp = decode_kernel_mode()
-    extra = _extras(window, sink, value_scale, layer)
-    if engage and not mesh_active and not extra:
-        from . import pallas_decode as _pd
+    ``decode-kernel``: one query row a slot over shapes the kernel tiles
+    (:func:`decode_kernel_selected`) hands the same list of blocks to ONE
+    Pallas kernel that copies each live block's pages from the pools into
+    fast memory itself and takes both products there
+    (:mod:`~mxnet_tpu.ops.pallas_decode`): every live byte is read once
+    and no gathered view is written.  The arithmetic is the walk's in
+    another order (docs/inference.md).
 
-        k_kern, v_kern = _kernel_pools(k_pool, v_pool)
-        if _pd.supported(q.shape, k_kern, v_kern, table.shape, num_heads,
-                         interpret=interp, num_kv_heads=num_kv_heads):
-            DECODE_PATH["last"] = "pallas"
-            fn = _pd.flash_sdpa_decode if q.shape[1] == 1 \
-                else _pd.flash_sdpa_verify
-            return fn(q, k_kern, v_kern, table, total_len,
-                      num_heads=num_heads, scale=scale, interpret=interp,
-                      num_kv_heads=num_kv_heads)
-        DECODE_PATH["last"] = "einsum-gated"
-    else:
-        DECODE_PATH["last"] = "einsum"
+    ``whole``: where the plan is None — a view of one block, a window
+    node, a sharded pool — the whole view is gathered
+    (:func:`paged_gather`) and attended as a dense ring is, the same jaxpr
+    as ever and bit-parity with a dense ring; the other two agree with
+    that within the tolerance of reordered float32 sums."""
     plan = live_block_plan(q.shape, table.shape, _plane(k_pool).shape[1],
                            mesh_active=mesh_active, window=window)
     if plan is not None:
-        return _attend_live_blocks(q, k_pool, v_pool, table, total_len,
-                                   num_heads, scale, num_kv_heads, *plan,
-                                   sink=sink, value_scale=value_scale,
-                                   layer=layer)
+        tiles, interpret = decode_kernel_selected(
+            q.shape, k_pool, v_pool, table.shape, num_heads, num_kv_heads,
+            mesh_active=mesh_active, window=window)
+        _note_path("walk" if tiles is None else "decode-kernel", DECODE_PATH)
+        return _attend_live_blocks(
+            q, k_pool, v_pool, table, total_len, num_heads, scale,
+            num_kv_heads, *plan, sink=sink, value_scale=value_scale,
+            layer=layer,
+            kernel=None if tiles is None else (tiles, interpret))
+    _note_path("whole", DECODE_PATH)
     with _scope(layer, "kv_gather"):
         k_view, v_view = paged_gather_kv(k_pool, v_pool, table)
     return _sdpa_cache(q, k_view, v_view, total_len, num_heads, scale,
                        num_kv_heads=num_kv_heads, mesh_active=mesh_active,
-                       **extra)
+                       **_extras(window, sink, value_scale, layer))
 
 
 # ---------------------------------------------------------------------------
@@ -1567,30 +1598,13 @@ def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
                  mesh_active=False, num_kv_heads=0, window=0, sink=None,
                  value_scale=1.0, layer="attn"):
     """Decode/verify attention over dense (B, C, E) ring buffers — the
-    non-paged twin of :func:`paged_attend`.  The fused path is the SAME
-    kernel through an identity page table
-    (:func:`~mxnet_tpu.ops.pallas_decode.dense_ring_attend`), so the
-    plain KV-cached serving path gets split-K decode attention too;
-    fallback is :func:`sdpa_decode`/:func:`sdpa_verify` unchanged."""
-    engage, interp = decode_kernel_mode()
-    extra = _extras(window, sink, value_scale, layer)
-    if engage and not mesh_active and not extra:
-        from . import pallas_decode as _pd
-
-        if _pd.supported_dense(q.shape, k_cache, v_cache, num_heads,
-                               interpret=interp,
-                               num_kv_heads=num_kv_heads):
-            DECODE_PATH["last"] = "pallas"
-            return _pd.dense_ring_attend(q, k_cache, v_cache, total_len,
-                                         num_heads=num_heads, scale=scale,
-                                         interpret=interp,
-                                         num_kv_heads=num_kv_heads)
-        DECODE_PATH["last"] = "einsum-gated"
-    else:
-        DECODE_PATH["last"] = "einsum"
+    non-paged twin of :func:`paged_attend`: :func:`sdpa_decode` /
+    :func:`sdpa_verify` over the whole ring (``whole`` in
+    ``mx_attn_dispatch_total``)."""
+    _note_path("whole", DECODE_PATH)
     return _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
                        num_kv_heads=num_kv_heads, mesh_active=mesh_active,
-                       **extra)
+                       **_extras(window, sink, value_scale, layer))
 
 
 _KV_LAYOUT_WARNED = {"done": False}

@@ -1,125 +1,56 @@
-"""Pallas flash-decoding kernels over paged KV pools (TPU) — gather +
-dequant + attention fused into ONE HBM pass.
+"""The decode row's kernel over paged KV pools (TPU): a slot's live blocks
+are read from the pools once, inside the kernel that multiplies them.
 
-The serving path's einsum formulation walks the largest tensor in the
-system twice per generated token: ``ops.attention.paged_gather``
-materializes a full (B, M*pt, E) dense-ring view of the shared page pool
-in HBM, and ``sdpa_decode`` streams that view again for the score/value
-matmuls (an int8/fp8 view as it is stored; no f32 copy is built).
-Decode attention is bandwidth-bound on exactly those bytes, so the two
-passes ARE the step time.
+``ops.attention._attend_live_blocks`` attends a view block by block, only
+the blocks a slot's length has reached, and combines a slot's blocks by one
+log-sum-exp.  Its loop gathers a block's pages into a copy and takes the two
+products over the copy: every live byte is read, written and read again.
+:func:`attend_blocks` is that loop as ONE ``pallas_call`` that walks the
+live rows of the padded list of blocks, a prefix of it.  The list's page ids
+ride in as scalar prefetch; the pools stay in HBM as they lie and a step
+copies its block's pages — whole pages, every head — and their scale rows
+into fast memory itself, the next block's copies in flight while this
+block's arithmetic runs.  No gathered view is written.  (A page's scale row
+is copied with the seven rows that share its ``(8, 128)`` tile: Mosaic cuts
+a tiled plane at whole tiles.  A plane stored a page a TILE would be read
+once too; that is the pools' storage, not this kernel's.)
 
-These kernels implement the two fixes the literature names, together:
+The arithmetic is ``_sdpa_cache``'s row form in another order.  A narrow
+plane (int8, fp8, bfloat16) is exact in bfloat16; the float operand (the
+query row, then the probabilities) goes as its three bfloat16 pieces,
+stacked as ROWS of one product: the query rows of all heads are laid
+block-diagonally over kv-heads, ``(3 * H, E_k) x (block, E_k)^T`` gives the
+logits of every head with positions on the lanes, ``(3 * H, block) x (block,
+E_v)`` the values, and the diagonal blocks of that result are folded out in
+fast memory.  Accumulation is float32; the key scales multiply the float32
+logits and the value scales the probabilities.  A float32 pool takes the
+same two products in float32 at ``Precision.HIGHEST``.  In a page's scale row
+a token's floats lie ``(K | V, head)``; a select and a lane fold put them
+``(block, 2 * H_kv)`` and one transposition turns positions onto the lanes.
 
-* **PagedAttention** (Kwon et al., SOSP 2023): the page-table gather
-  moves *inside* the kernel.  The (B, M) table rides in as a
-  scalar-prefetch argument (``pltpu.PrefetchScalarGridSpec``) and every
-  pool BlockSpec's index map reads it — ``(table[b, m], 0, h)`` — so each
-  grid step DMAs one page's one head-slice straight from the pool.  No
-  gathered view, no dequantized copy: int8/fp8 pages dequantize in VMEM
-  (per-(token, head) scales, a (P, page_tokens, H) plane a pool: the
-  paged pools' shared plane of rows is split into two such at this
-  kernel's door, ``ops.attention._kernel_pools``) on their way into the
-  score matmul.
-* **Flash-Decoding** (Dao et al., 2023): the grid parallelizes over the
-  CACHE-LENGTH axis, not just (batch, head).  At decode (tq=1) with
-  batch = serving slots, a (B, H) grid strands the chip when B*H is
-  small; a split-K axis of S splits walks M/S pages each, maintaining
-  the running (max, sum, acc) flash softmax per split, and a small
-  cross-split logsumexp combine (host-side jnp over (B, H, S, tq)-shaped
-  partials — tiny) reduces them exactly.  The (b, h, s) grid prefix is
-  marked ``parallel`` toward Mosaic (each instance owns its scratch
-  lifetime) so it fans across cores; only the within-split page walk
-  ``ms`` is ``arbitrary`` (sequential softmax accumulation).
+What a step writes is the block's share of the softmax, ``(max, sum, acc)``
+not yet normalized, as the walk's loop kept it: the combine, the sink and
+the value scale stay with the caller.  A dead row of the padded list is not
+visited: it copies nothing and writes nothing, and the combine reads none.
 
-Three entry points share one kernel core:
-
-* :func:`flash_sdpa_decode` — tq == 1, the decode hot path;
-* :func:`flash_sdpa_verify` — tq == k+1, the speculative verify window
-  (and the chunked-prefill window: any tq with per-query length masks);
-* :func:`dense_ring_attend` — the non-paged ring buffers take the same
-  kernel through an identity page table: a (B, C, E) cache reshapes
-  (free, row-major split) into a (B*Mb, bs, E) pool and
-  ``table[b, m] = b*Mb + m``.
-
-All are length-masked and wrap-aware exactly like
-``ops.attention._sdpa_cache``: query i of a window whose total appended
-length is ``total`` sees view slots v < min(total - (tq-1) + i, C), so a
-wrapped ring (total > C) attends all C live slots.  Numerics follow the
-einsum path (f32 logits, f32 softmax accumulation); streaming
-accumulation reorders the sums, so parity is tolerance-tested
-(documented in docs/inference.md), not bit-asserted.
-
-Dispatch lives in ``ops.attention.paged_attend`` / ``cache_attend``,
-gated by ``MXNET_PALLAS_DECODE`` with shape fallback to the einsum path;
-``interpret=True`` runs the same kernels on CPU (the tier-1 parity
-suite, tests/test_pallas_decode.py).
+:func:`tiles` is the shape rule: tile sizes follow from ``E``, ``H``,
+``H_kv`` and the block; ``ops.attention.decode_kernel_selected`` adds what
+the call shows (one query row, a live-block plan, a backend that runs
+Pallas).  ``interpret=True`` runs the same kernel on the CPU
+(tests/test_pallas_decode.py).
 """
 from __future__ import annotations
 
 import functools
+import math
+from typing import NamedTuple
 
 import numpy as np
 
-# Split-K sizing: at most MAX_SPLITS splits over the view's M pages (the
-# largest power of two <= min(M, MAX_SPLITS) dividing M).  More splits =
-# more cross-core parallelism on the cache-length axis but more combine
-# partials; 8 covers a v5e megacore with headroom.
-MAX_SPLITS = 8
-# Residual lane width for the per-split (max, sum) partials — matches the
-# (rows, lanes) layout pallas_attention.py uses for its logsumexp
-# residuals, so no kernel ever writes a 1-lane vector.
 LANES = 128
-# TPU (non-interpret) gates: Mosaic wants the lane (last) dim a multiple
-# of 128 and the sublane dim a multiple of 8; interpret mode has no tile
-# constraints and takes any positive shape.
-_TPU_LANE = 128
-_TPU_SUBLANE = 8
-
-
-def _num_splits(m, cap=None, groups=1):
-    """Largest power-of-two split count <= min(m, cap) that divides m
-    (1 when m is odd — the split axis degrades gracefully).  ``cap``
-    defaults to the tuning cache's ``max_splits`` for this view width
-    (the :data:`MAX_SPLITS` constant when cold and no sweep armed)."""
-    if cap is None:
-        cap = _tuned_split_cap(m, groups=groups)
-    s = 1
-    while s * 2 <= min(m, cap) and m % (s * 2) == 0:
-        s *= 2
-    return s
-
-
-_STALE_GROUP_CHECKED = set()
-
-
-def _tuned_split_cap(m, groups=1):
-    from . import tuning
-
-    # split width is a parallelism knob, not a dtype-layout one: one
-    # decision per view width serves every pool dtype
-    if groups <= 1:
-        return int(tuning.resolve("pallas_decode",
-                                  tuning.shape_class_for(m=m),
-                                  "any").get("max_splits", MAX_SPLITS))
-    # grouped K/V shapes get their own content-addressed tune key (the
-    # kv-head group class rides in the shape class) so a GQA sweep never
-    # collides with an MHA winner for the same view width
-    sc = tuning.shape_class_for(m=m, g=groups)
-    if sc not in _STALE_GROUP_CHECKED:
-        _STALE_GROUP_CHECKED.add(sc)
-        mha_sc = tuning.shape_class_for(m=m)
-        if (tuning.get("pallas_decode", sc, "any", version=1) is None
-                and tuning.get("pallas_decode", mha_sc, "any",
-                               version=1) is not None):
-            import warnings
-
-            warnings.warn(
-                "tuning cache holds an MHA-keyed pallas_decode record for "
-                "m=%d but the shape is grouped (G=%d); the MHA winner "
-                "does not apply — treating as a miss" % (m, groups))
-    return int(tuning.resolve("pallas_decode", sc,
-                              "any").get("max_splits", MAX_SPLITS))
+# what a step's buffers and temporaries may take of fast memory (a v5e
+# core has 128 MiB; Mosaic's default scoped limit is 16)
+_VMEM_BUDGET = 40 << 20
 
 
 def _is_quant(pool):
@@ -128,421 +59,402 @@ def _is_quant(pool):
     return isinstance(pool, QuantKV)
 
 
-def supported(q_shape, k_pool, v_pool, table_shape, num_heads,
-              interpret=False, num_kv_heads=0):
-    """Whether the fused kernel handles this paged-decode shape.
-
-    Correctness constraints always: heads divide both embed dims and the
-    (quantized) scale planes carry exactly the K/V head count.  Grouped
-    configs (``num_kv_heads < num_heads``) require the pools to be
-    physically H_kv heads wide — the kernel maps q-head h to pool slice
-    ``h // G``.  On a real TPU (``interpret=False``) the Mosaic tile
-    constraints add: per-head dims and page_tokens aligned to the
-    (8, 128) tile.  Anything else falls back to the einsum path — same
-    numerics, three HBM passes.
-    """
-    kd = k_pool.data if _is_quant(k_pool) else k_pool
-    vd = v_pool.data if _is_quant(v_pool) else v_pool
-    b, tq, e = q_shape
-    kvh = int(num_kv_heads) or int(num_heads)
-    if num_heads <= 0 or kvh <= 0 or num_heads % kvh:
-        return False
-    if e % num_heads or vd.shape[2] % kvh:
-        return False
-    if kd.shape[2] != kvh * (e // num_heads):
-        return False
-    if _is_quant(k_pool) and k_pool.scale.shape[-1] != kvh:
-        return False
-    if _is_quant(v_pool) and v_pool.scale.shape[-1] != kvh:
-        return False
-    pt = kd.shape[1]
-    if pt <= 0 or table_shape[1] <= 0:
-        return False
-    if not interpret:
-        hd_k = e // num_heads
-        hd_v = vd.shape[2] // kvh
-        if hd_k % _TPU_LANE or hd_v % _TPU_LANE:
-            return False
-        if pt % _TPU_SUBLANE:
-            return False
-    return True
+def _plane(pool):
+    return pool.data if _is_quant(pool) else pool
 
 
-def _kernel(table_ref, lens_ref, q_ref, k_ref, v_ref, ks_ref, vs_ref,
-            acc_ref, m_ref, l_ref, m_scr, l_scr, acc_scr, *, scale, tq,
-            page_tokens, pages_per_split, view_pages, quant, group):
-    """One (b, h, s, ms) grid step: fold page ``s*pages_per_split + ms``
-    of slot b's view into the running flash softmax for head h.
+class Tiles(NamedTuple):
+    """The static sizes of one :func:`attend_blocks` call."""
 
-    ``ks_ref``/``vs_ref`` are the per-(token, head) scale pages of a
-    quantized pool (None otherwise) — dequantization happens HERE, on
-    the (pt, hd) tile in VMEM, never in HBM.  A scale page arrives with
-    ALL its kv heads — Mosaic refuses a 1-wide lane block of the
-    (P, pt, H_kv) plane — and this head's column is picked by a one-hot
-    reduction (``group`` q-heads share kv-head ``h // group``).  At the
-    split's last page
-    the UNNORMALIZED partial (acc, max, sum) is written out; the caller
-    combines splits with a logsumexp reduction.
-    """
+    heads: int       # H
+    kv_heads: int    # H_kv
+    rows: int        # H rounded up to the bfloat16 sublane tile
+    pieces: int      # bfloat16 pieces of the float operand (1: float32)
+    hd: int          # key head width
+    hdv: int         # value head width
+    qw: int          # lanes of a query row as handed in: lcm(hd, 128)
+    ow: int          # lanes of the folded PV result: max(hdv, 128)
+    pt: int          # positions a page
+    ppb: int         # pages a block
+    quant: bool
+    vmem: int        # bytes of fast memory a step may take
+
+
+def tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads, block):
+    """The :class:`Tiles` of a decode row over these pools by blocks of
+    ``block`` positions, or None where the kernel does not tile the
+    shapes: the walk then serves them."""
+    import jax.numpy as jnp
+
+    kd, vd = _plane(k_pool), _plane(v_pool)
+    quant = _is_quant(k_pool)
+    if quant != _is_quant(v_pool) or kd.dtype != vd.dtype \
+            or kd.ndim != 3 or vd.shape[:2] != kd.shape[:2]:
+        return None
+    e = q_shape[2]
+    h = int(num_heads)
+    kvh = int(num_kv_heads) or h
+    pt, ek, ev = kd.shape[1], kd.shape[2], vd.shape[2]
+    if h <= 0 or kvh <= 0 or h % kvh or e % h or ev % kvh \
+            or ek != kvh * (e // h) or block % pt or h > LANES:
+        return None
+    hd, hdv = e // h, ev // kvh
+    qw = hd * LANES // math.gcd(hd, LANES)
+    # whole pages of whole sublane tiles; planes of whole lane tiles; a
+    # query row tiled to whole lane tiles; value heads that fold into one
+    if pt % 8 or ek % qw or ev % LANES \
+            or (hdv % LANES and LANES % hdv) or hdv < 8:
+        return None
+    if quant:
+        # a page's scale row: whole lane tiles, a token's 2 * H_kv floats
+        # dividing one
+        w = 2 * kvh
+        if k_pool.scale.shape[1] != pt * w or (pt * w) % LANES \
+                or LANES % w:
+            return None
+    item = jnp.dtype(kd.dtype).itemsize
+    rows = -(-h // 16) * 16
+    pieces = 1 if item > 2 else 3       # a float32 pool: one piece
+    # two buffers a plane, and the planes once more as the products read
+    # them (float32 on the way to bfloat16)
+    vmem = block * (ek + ev) * (2 * item + 6) \
+        + (block // pt * (2 * 8 + pt) * pt * 2 * kvh * 4 if quant else 0) \
+        + pieces * rows * (ek + ev + 4 * block) * 4 \
+        + q_shape[0] * pieces * rows * qw * 4
+    if vmem > _VMEM_BUDGET:
+        return None
+    return Tiles(h, kvh, rows, pieces, hd, hdv, qw, max(hdv, LANES), pt,
+                 block // pt, quant, vmem)
+
+
+def _split3(x):
+    """float32 -> its three bfloat16 pieces, largest first: their sum is
+    ``x`` to the last bit float32 holds."""
+    import jax.numpy as jnp
+
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _div(x, n):
+    """``x // n`` and ``x % n`` (:func:`_rem`) of non-negative int32 by a
+    Python int: the truncating forms, without ``//``'s sign fix-ups."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
 
-    b = pl.program_id(0)
-    kv_head = pl.program_id(1) // group
-    ms = pl.program_id(3)
-    nms = pl.num_programs(3)
-    s = pl.program_id(2)
-
-    @pl.when(ms == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, -jnp.inf)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    m_view = s * pages_per_split + ms          # view page index in [0, M)
-    total = lens_ref[b]
-    cap = view_pages * page_tokens             # C, the ring capacity
-    visible = jnp.minimum(total, cap)          # live view slots
-
-    def _update():
-        q = q_ref[0, 0].astype(jnp.float32)                 # (tq, hd_k)
-        k = k_ref[0].astype(jnp.float32)                    # (pt, hd_k)
-        v = v_ref[0].astype(jnp.float32)                    # (pt, hd_v)
-        if quant:
-            def head_scale(ref):                            # -> (pt, 1)
-                page = ref[0]                               # (pt, H_kv)
-                col = jax.lax.broadcasted_iota(jnp.int32, page.shape, 1)
-                return jnp.sum(jnp.where(col == kv_head, page, 0.0),
-                               axis=1, keepdims=True)
-
-            k = k * head_scale(ks_ref)
-            v = v * head_scale(vs_ref)
-        logits = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * jnp.float32(scale)
-        # view slot v = m_view*pt + j; query i sees v < min(total-(tq-1)+i, C)
-        vpos = m_view * page_tokens + jax.lax.broadcasted_iota(
-            jnp.int32, (tq, page_tokens), 1)
-        limit = jnp.minimum(
-            total - (tq - 1) + jax.lax.broadcasted_iota(
-                jnp.int32, (tq, page_tokens), 0), cap)
-        logits = jnp.where(vpos < limit, logits, -jnp.inf)
-
-        m_prev = m_scr[:, :1]                               # (tq, 1)
-        m_new = jnp.maximum(m_prev, jnp.max(logits, axis=1, keepdims=True))
-        m_safe = jnp.where(m_new == -jnp.inf, 0.0, m_new)
-        p = jnp.exp(logits - m_safe)
-        p = jnp.where(logits == -jnp.inf, 0.0, p)
-        corr = jnp.where(m_prev == -jnp.inf, 0.0, jnp.exp(m_prev - m_safe))
-        l_scr[:] = l_scr[:] * corr + jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), l_scr.shape)
-        acc_scr[:] = acc_scr[:] * corr + jax.lax.dot_general(
-            p, v, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
-        m_scr[:] = jnp.broadcast_to(m_new, m_scr.shape)
-
-    # pages wholly past the live window contribute nothing — skip their
-    # compute entirely (their DMA still lands, the index map ran)
-    @pl.when(m_view * page_tokens < visible)
-    def _masked_update():
-        _update()
-
-    @pl.when(ms == nms - 1)
-    def _finish():
-        acc_ref[0, 0, 0] = acc_scr[:]
-        m_ref[0, 0, 0] = m_scr[:]
-        l_ref[0, 0, 0] = l_scr[:]
+    return jax.lax.div(x, jnp.int32(n))
 
 
-def _paged_flash_call(q, k_pool, v_pool, table, lens, num_heads, scale,
-                      interpret, split_cap=None, num_kv_heads=0):
-    """Launch the kernel and combine split partials; returns (B, tq, Ev)
-    in the V pool's compute dtype (f32 for quantized pools, matching the
-    einsum path's dequantized output).
+def _rem(x, n):
+    import jax
+    import jax.numpy as jnp
 
-    Grouped pools (``num_kv_heads < num_heads``) keep the (b, h, s, ms)
-    q-head grid; the pool/scale BlockSpec index maps gather ONE kv-head
-    slice per G q-heads (``hi // G`` — the group id), so the pool is
-    never widened to H_q."""
+    return jax.lax.rem(x, jnp.int32(n))
+
+
+def _fold_lanes(x, width):
+    """(n, 128) whose lanes hold one nonzero group of ``width``: every
+    group becomes the sum of all (the nonzero one)."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    shift = LANES // 2
+    while shift >= width:
+        x = x + pltpu.roll(x, shift, 1)
+        shift //= 2
+    return x
+
+
+def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
+            *rest, t, scale):
+    """One invocation walks the live rows of the list, a prefix of it:
+    ``pages_ref`` (rows * ppb,) the blocks' page ids, ``slot_ref`` and
+    ``valid_ref`` (rows,) each block's slot and the positions of the block
+    that slot has reached, ``live_ref`` (1,) the live rows.  ``q_ref`` (B,
+    pieces * rows, qw): a slot's query row a head, by pieces, tiled to
+    whole lane tiles.  The pools and the two outputs stay in HBM; row
+    ``r``'s copies fly while row ``r - 1`` is multiplied, and its share
+    leaves while row ``r + 1`` is."""
     import jax
     import jax.numpy as jnp
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
-    quant = _is_quant(k_pool)
-    kd = k_pool.data if quant else k_pool
-    vd = v_pool.data if quant else v_pool
-    b, tq, e = q.shape
-    h = num_heads
-    kvh = int(num_kv_heads) or int(h)
-    g = h // kvh
-    hd_k = e // h
-    hd_v = vd.shape[2] // kvh
-    pt = kd.shape[1]
-    m = table.shape[1]
-    s = _num_splits(m, split_cap, groups=g)
-    ms = m // s
-    scale = float(scale or 1.0 / np.sqrt(hd_k))
-
-    qh = q.reshape(b, tq, h, hd_k).transpose(0, 2, 1, 3)  # (B, H, tq, hd)
-    table = jnp.asarray(table, jnp.int32)
-    lens = jnp.broadcast_to(jnp.asarray(lens, jnp.int32).reshape(-1), (b,))
-
-    kernel = functools.partial(
-        _kernel, scale=scale, tq=tq, page_tokens=pt, pages_per_split=ms,
-        view_pages=m, quant=quant, group=g)
-
-    # index maps: every pool block is one page's one head-slice, located
-    # through the scalar-prefetched table — the in-kernel gather
-    def _q_map(bi, hi, si, mi, tr, lr):
-        return (bi, hi, 0, 0)
-
-    if g == 1:
-        def _page_map(bi, hi, si, mi, tr, lr):
-            return (tr[bi, si * ms + mi], 0, hi)
+    if t.quant:
+        s_hbm, acc_hbm, stat_hbm, kbuf, vbuf, sbuf, accbuf, statbuf, sems, \
+            osems = rest
     else:
-        # pool blocks keyed by GROUP id: q-heads hi in [gi*G, (gi+1)*G)
-        # all DMA kv-head slice gi = hi // G of the physically-grouped pool
-        def _page_map(bi, hi, si, mi, tr, lr):
-            return (tr[bi, si * ms + mi], 0, hi // g)
+        acc_hbm, stat_hbm, kbuf, vbuf, accbuf, statbuf, sems, osems = rest
+        s_hbm = sbuf = None
+    live = live_ref[0]
+    block = t.ppb * t.pt
+    g = t.heads // t.kv_heads
+    fill = jnp.finfo(jnp.float32).min
+    narrow = t.pieces == 3
+    mm = jnp.bfloat16 if narrow else jnp.float32
+    prec = None if narrow else jax.lax.Precision.HIGHEST
+    rows3 = t.pieces * t.rows
+    ek, ev = kbuf.shape[-1], vbuf.shape[-1]
 
-    def _out_map(bi, hi, si, mi, tr, lr):
-        return (bi, hi, si, 0, 0)
+    def pages_of(row, buf, go, unrolled=False):
+        """``go`` (start or wait) each copy of row ``row``'s pages into
+        buffer ``buf``.  A loop where it may be: a descriptor costs
+        milliseconds to trace and to lower, in every session's set-up, and
+        a kernel that unrolled all three of its uses took 5 s of a 24-s
+        one.  ``unrolled`` for the starts that run beside the arithmetic:
+        issued by a loop they delay it (2 % of the OPT cell's tokens/s;
+        my chip runs, PR 48)."""
+        def page(i, _):
+            at = pages_ref[row * t.ppb + i]
+            go(pltpu.make_async_copy(k_hbm.at[at], kbuf.at[buf, i],
+                                     sems.at[buf, 0]))
+            go(pltpu.make_async_copy(v_hbm.at[at], vbuf.at[buf, i],
+                                     sems.at[buf, 1]))
+            if t.quant:
+                # a page's row with the seven that share its sublane
+                # tile: a tiled plane is cut at whole tiles
+                go(pltpu.make_async_copy(
+                    s_hbm.at[pl.ds(pl.multiple_of(_div(at, 8) * 8, 8), 8)],
+                    sbuf.at[buf, i], sems.at[buf, 2]))
 
-    in_specs = [
-        pl.BlockSpec((1, 1, tq, hd_k), _q_map),
-        pl.BlockSpec((1, pt, hd_k), _page_map),
-        pl.BlockSpec((1, pt, hd_v), _page_map),
-    ]
-    args = [qh, kd, vd]
-    if quant:
-        def _scale_map(bi, hi, si, mi, tr, lr):
-            return (tr[bi, si * ms + mi], 0, 0)
+        if unrolled:
+            for i in range(t.ppb):
+                page(i, None)
+        else:
+            jax.lax.fori_loop(0, t.ppb, page, None)
 
-        in_specs += [pl.BlockSpec((1, pt, kvh), _scale_map)] * 2
-        args += [k_pool.scale, v_pool.scale]
-    else:
-        # keep ONE kernel signature: unquantized pools ride a zero-cost
-        # dummy scale page (never read — quant=False skips it)
-        dummy = jnp.zeros((1, pt, 1), jnp.float32)
-        in_specs += [pl.BlockSpec((1, pt, 1),
-                                  lambda bi, hi, si, mi, tr, lr: (0, 0, 0))] \
-            * 2
-        args += [dummy, dummy]
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b, h, s, ms),
-        in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, 1, 1, tq, hd_v), _out_map),
-            pl.BlockSpec((1, 1, 1, tq, LANES), _out_map),
-            pl.BlockSpec((1, 1, 1, tq, LANES), _out_map),
-        ],
-        scratch_shapes=[
-            pltpu.VMEM((tq, LANES), jnp.float32),   # running max
-            pltpu.VMEM((tq, LANES), jnp.float32),   # running sum
-            pltpu.VMEM((tq, hd_v), jnp.float32),    # output accumulator
-        ],
-    )
-    # (b, h, s) are independent — each owns its scratch lifetime via the
-    # ms==0 init — so Mosaic may fan them across cores (the split-K
-    # parallelism that fills the chip at batch=slots); only ms, the
-    # running-softmax accumulation over a split's pages, is sequential.
-    # Without this, all four grid dims default to 'arbitrary' and the
-    # whole grid serializes on one core.
-    acc, m_p, l_p = pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((b, h, s, tq, hd_v), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, s, tq, LANES), jnp.float32),
-            jax.ShapeDtypeStruct((b, h, s, tq, LANES), jnp.float32),
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary")),
-        interpret=interpret,
-    )(table, lens, *args)
+    def shares(row, buf):
+        return (pltpu.make_async_copy(accbuf.at[buf], acc_hbm.at[row],
+                                      osems.at[buf, 0]),
+                pltpu.make_async_copy(statbuf.at[buf], stat_hbm.at[row],
+                                      osems.at[buf, 1]))
 
-    # cross-split logsumexp combine (Flash-Decoding's reduction): tiny
-    # (B, H, S, tq)-shaped partials, exact in f32
-    m_p = m_p[..., 0]                                   # (B, H, S, tq)
-    l_p = l_p[..., 0]
-    m_star = jnp.max(m_p, axis=2, keepdims=True)
-    m_star = jnp.where(m_star == -jnp.inf, 0.0, m_star)
-    alpha = jnp.where(m_p == -jnp.inf, 0.0, jnp.exp(m_p - m_star))
-    l_tot = jnp.sum(alpha * l_p, axis=2)                # (B, H, tq)
-    acc = jnp.sum(alpha[..., None] * acc, axis=2)       # (B, H, tq, hd_v)
-    denom = jnp.where(l_tot == 0.0, 1.0, l_tot)
-    out = acc / denom[..., None]
-    out = out.transpose(0, 2, 1, 3).reshape(b, tq, h * hd_v)
-    out_dtype = jnp.float32 if quant else vd.dtype
-    return out.astype(out_dtype)
+    def plane(ref, buf):
+        x = ref[buf]                            # (ppb, pt, E)
+        if x.dtype != mm:
+            x = x.astype(jnp.float32)
+        return x.reshape(block, x.shape[-1]).astype(mm)
 
+    # which kv-head a lane of the query's lane tile lies in, which a row's
+    # head reads: the same for every row of the list
+    q_head = _div(_rem(jax.lax.broadcasted_iota(
+        jnp.int32, (rows3, t.qw), 0), t.rows), g)
+    q_lane = _div(jax.lax.broadcasted_iota(jnp.int32, (rows3, t.qw), 1),
+                  t.hd)
 
-def flash_sdpa_decode(q, k_pool, v_pool, table, total_len, num_heads=1,
-                      scale=None, interpret=False, split_cap=None,
-                      num_kv_heads=0):
-    """Fused paged decode attention: (B, 1, E) queries over (P, pt, E_kv)
-    pools through (B, M) page tables -> (B, 1, Ev).
+    def attend(r, _):
+        buf = _rem(r, 2)
 
-    ``total_len`` counts tokens appended INCLUDING the query position
-    (the ``sdpa_decode`` contract); once the view ring has wrapped
-    (total > M*pt) every slot is live.  Pools may be
-    :class:`~mxnet_tpu.ops.attention.QuantKV` — dequantized per
-    (token, kv-head) in VMEM.  One HBM pass over the live pool pages;
-    grouped pools (``num_kv_heads``) are walked once per kv head group.
-    """
-    return _paged_flash_call(q, k_pool, v_pool, table, total_len,
-                             num_heads, scale, interpret,
-                             split_cap=split_cap,
-                             num_kv_heads=num_kv_heads)
+        @pl.when(r + 1 < live)
+        def _next():
+            pages_of(r + 1, 1 - buf, start, unrolled=True)
 
+        pages_of(r, buf, wait)
+        # the query rows, block-diagonal over kv-heads: a lane tile at a
+        # time, head n's row kept in the columns of kv-head n // g
+        qq = q_ref[slot_ref[r]]
+        qbd = jnp.concatenate(
+            [jnp.where(q_lane + c * (t.qw // t.hd) == q_head, qq,
+                       jnp.zeros_like(qq))
+             for c in range(ek // t.qw)], axis=1)
+        s = jax.lax.dot_general(
+            qbd, plane(kbuf, buf), (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)     # (pieces * rows, block)
+        logits = s[:t.rows]
+        for i in range(1, t.pieces):
+            logits = logits + s[i * t.rows:(i + 1) * t.rows]
+        logits = logits * jnp.float32(scale)
 
-def flash_sdpa_verify(q, k_pool, v_pool, table, total_len, num_heads=1,
-                      scale=None, interpret=False, split_cap=None,
-                      num_kv_heads=0):
-    """Fused paged multi-position cache attention — the speculative
-    verify window (tq = k+1) and the chunked-prefill window (tq = chunk
-    width) share it.  Query i masks to view slots
-    v < min(total - (tq-1) + i, C), exactly ``sdpa_verify``'s rule, so
-    each output row equals what a sequential decode chain would produce.
-    """
-    return _paged_flash_call(q, k_pool, v_pool, table, total_len,
-                             num_heads, scale, interpret,
-                             split_cap=split_cap,
-                             num_kv_heads=num_kv_heads)
+        vs = None
+        if t.quant:
+            # (ppb, pt * W) -> (block, W) -> (W, block): a page's row to
+            # each of its tokens, a token's own stretch kept, the stretches
+            # folded onto one lane tile
+            w = 2 * t.kv_heads
+            width = sbuf.shape[-1]
+            per = jnp.concatenate(
+                [jnp.broadcast_to(
+                    sbuf[buf, i, pl.ds(_rem(pages_ref[r * t.ppb + i], 8), 1), :],
+                    (t.pt, width)) for i in range(t.ppb)], axis=0)
+            tok = _rem(jax.lax.broadcasted_iota(
+                jnp.int32, (block, width), 0), t.pt)
+            at = _div(jax.lax.broadcasted_iota(
+                jnp.int32, (block, width), 1), w)
+            own = jnp.where(tok == at, per, 0.0)
+            one = own[:, :LANES]
+            for c in range(1, width // LANES):
+                one = one + own[:, c * LANES:(c + 1) * LANES]
+            turned = _fold_lanes(one, w).T          # (128, block)
+            if g == 1 and t.kv_heads == t.rows:
+                ks, vs = turned[:t.rows], turned[t.rows:2 * t.rows]
+            else:
+                of = _div(jax.lax.broadcasted_iota(
+                    jnp.int32, (t.rows, block), 0), g)
+                ks = jnp.zeros((t.rows, block), jnp.float32)
+                vs = jnp.zeros((t.rows, block), jnp.float32)
+                for j in range(t.kv_heads):
+                    ks = jnp.where(of == j, turned[j:j + 1], ks)
+                    vs = jnp.where(
+                        of == j,
+                        turned[t.kv_heads + j:t.kv_heads + j + 1], vs)
+            logits = logits * ks
 
+        pos = jax.lax.broadcasted_iota(jnp.int32, (t.rows, block), 1)
+        logits = jnp.where(pos < valid_ref[r], logits, fill)
+        m = jnp.max(logits, axis=1, keepdims=True)
+        p = jnp.exp(logits - m)
+        den = jnp.sum(p, axis=1, keepdims=True)
+        if vs is not None:
+            p = p * vs
+        p3 = jnp.concatenate(_split3(p), axis=0) if narrow else p
+        full = jax.lax.dot_general(
+            p3, plane(vbuf, buf), (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)     # (pieces * rows, E_v)
+        out = full[:t.rows]
+        for i in range(1, t.pieces):
+            out = out + full[i * t.rows:(i + 1) * t.rows]
+        # head n's values are the columns of kv-head n // g
+        of = _div(jax.lax.broadcasted_iota(jnp.int32, (t.rows, ev), 0), g)
+        col = _div(jax.lax.broadcasted_iota(jnp.int32, (t.rows, ev), 1),
+                   t.hdv)
+        out = jnp.where(of == col, out, 0.0)
+        acc = out[:, :t.ow]
+        for c in range(1, ev // t.ow):
+            acc = acc + out[:, c * t.ow:(c + 1) * t.ow]
+        if t.hdv < LANES:
+            acc = _fold_lanes(acc, t.hdv)
+        # the maxima and the sums, a head a lane: rows 0 and 1 of a tile
+        lane = jax.lax.broadcasted_iota(jnp.int32, (t.rows, LANES), 1)
+        stats = jnp.where(lane == 0, m, jnp.where(lane == 1, den, 0.0))
+        stats = jnp.concatenate(
+            [stats, jnp.zeros((LANES - t.rows, LANES), jnp.float32)], axis=0)
 
-def _dense_block(c, pt_pref=128):
-    """Page size for the dense-ring identity view: the largest
-    power-of-two <= min(c, pt_pref) dividing c."""
-    bs = min(pt_pref, c)
-    while c % bs:
-        bs //= 2
-    return bs
+        @pl.when(r >= 2)
+        def _sent():                    # row r - 2 has left these buffers
+            for c in shares(r, buf):
+                c.wait()
 
+        accbuf[buf] = acc
+        statbuf[buf] = stats.T[:8]
+        for c in shares(r, buf):
+            c.start()
 
-class _Shape:
-    """Shape/dtype carrier so the paged ``supported`` gate can vet a
-    dense ring's pool view without reshaping real arrays."""
+    @pl.when(live > 0)
+    def _first():
+        pages_of(0, 0, start)
 
-    __slots__ = ("shape", "dtype")
-
-    def __init__(self, shape, dtype):
-        self.shape = tuple(shape)
-        self.dtype = dtype
-
-
-def supported_dense(q_shape, k_cache, v_cache, num_heads, interpret=False,
-                    num_kv_heads=0):
-    """Whether the dense-ring variant handles these cache shapes: the
-    (B, C, E) ring must tile into identity pages the paged gate accepts."""
-    from .attention import QuantKV
-
-    kd = k_cache.data if _is_quant(k_cache) else k_cache
-    c = kd.shape[1]
-    bs = _dense_block(c)
-    if bs < 1:
-        return False
-    mb = c // bs
-
-    def as_pool(cache):
-        if _is_quant(cache):
-            return QuantKV(as_pool(cache.data), as_pool(cache.scale))
-        return _Shape((cache.shape[0] * mb, bs, cache.shape[2]),
-                      cache.dtype)
-
-    return supported(q_shape, as_pool(k_cache), as_pool(v_cache),
-                     (q_shape[0], mb), num_heads, interpret=interpret,
-                     num_kv_heads=num_kv_heads)
+    jax.lax.fori_loop(0, live, attend, None)
+    for back in (1, 2):
+        @pl.when(live >= back)
+        def _drain():
+            for c in shares(0, _rem(live - back, 2)):
+                c.wait()
 
 
-def dense_ring_attend(q, k_cache, v_cache, total_len, num_heads=1,
-                      scale=None, interpret=False, num_kv_heads=0):
-    """The dense-ring variant: run the SAME fused kernel over a non-paged
-    (B, C, E) ring buffer through an identity page table.
+def attend_blocks(q, k_pool, v_pool, pages, slot, valid, live, t, scale,
+                  interpret=False):
+    """The listed blocks' shares of each slot's softmax: ``(m (rows, H),
+    den (rows, H), acc (rows, H, hdv))`` float32, block ``r`` of the list
+    at row ``r``.  Dead rows are not visited and not written: what they
+    hold is to be read by nobody.
 
-    The ring reshapes (free: a row-major split of C into Mb pages of bs
-    tokens) into a (B*Mb, bs, E) pool and ``table[b, m] = b*Mb + m``;
-    split-K then parallelizes the plain KV-cached decode path over cache
-    length too.  Length masks/wrap behave exactly like ``_sdpa_cache``.
-    """
+    ``q`` (B, 1, E); the pools as the paged ops store them; ``pages``
+    (rows, ppb) page ids, ``slot`` (rows,) the slot of each block,
+    ``valid`` (rows,) how many of a block's positions its slot has
+    reached, ``live`` the number of live rows, a prefix of the list;
+    ``t`` the call's :class:`Tiles`.
+
+    The kernel's launch is traced as ONE function a (shapes, ``t``,
+    ``scale``): the layers of a decode program share a kernel shape, and a
+    program of 24 nodes traces and lowers the kernel once, not 24 times
+    (0.6 s a node on the host, in every session's set-up, warm or cold).
+    The query is cut into its pieces out here, so that a first layer's
+    bfloat16 row and a later layer's float32 one are one shape to it."""
     import jax.numpy as jnp
 
-    from .attention import QuantKV
-
-    kd = k_cache.data if _is_quant(k_cache) else k_cache
-    b, c = kd.shape[0], kd.shape[1]
-    bs = _dense_block(c)
-    mb = c // bs
-
-    def as_pool(cache):
-        if _is_quant(cache):
-            return QuantKV(as_pool(cache.data), as_pool(cache.scale))
-        return cache.reshape(b * mb, bs, cache.shape[2])
-
-    table = (jnp.arange(b, dtype=jnp.int32)[:, None] * mb
-             + jnp.arange(mb, dtype=jnp.int32)[None, :])
-    return _paged_flash_call(q, as_pool(k_cache), as_pool(v_cache), table,
-                             total_len, num_heads, scale, interpret,
-                             num_kv_heads=num_kv_heads)
+    b = q.shape[0]
+    qh = q.astype(jnp.float32).reshape(b, t.heads, t.hd)
+    qh = jnp.pad(qh, ((0, 0), (0, t.rows - t.heads), (0, 0)))
+    if t.pieces == 3:
+        qh = jnp.concatenate(_split3(qh), axis=1)
+    qh = jnp.tile(qh, (1, 1, t.qw // t.hd))        # (B, pieces * rows, qw)
+    return _jitted()(qh, k_pool, v_pool, pages, slot, valid, live, t=t,
+                     scale=float(scale), interpret=bool(interpret))
 
 
-# ---------------------------------------------------------------------------
-# tunable space (ops/tuning.py): split-K width per view-width class
-# ---------------------------------------------------------------------------
+@functools.lru_cache(maxsize=None)
+def _jitted():
+    import jax
 
-def _tuning_candidates(shape_class, interpret):
-    if interpret:
-        # 2-candidate toy space for the tier-1 CPU sweep
-        return [{"max_splits": 2}, {"max_splits": 4}]
-    return [{"max_splits": c} for c in (1, 2, 4, 8, 16)]
+    return jax.jit(_attend_blocks, static_argnames=("t", "scale",
+                                                    "interpret"))
 
 
-def _tuning_runner(params, shape_class, dtype, interpret):
+def _attend_blocks(qh, k_pool, v_pool, pages, slot, valid, live, *, t, scale,
+                   interpret):
     import jax
     import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
-    from . import tuning
+    rows = pages.shape[0]
+    kd, vd = _plane(k_pool), _plane(v_pool)
 
-    m = tuning.parse_shape_class(shape_class).get("m", 8)
-    cap = params["max_splits"]
-    if cap > m:
-        raise tuning.SpaceError("max_splits %d exceeds view width m=%d"
-                                % (cap, m))
-    dt = jnp.float32 if dtype == "any" else jnp.dtype(dtype)
-    pt, e, b = 16, 128, 4
-    rng = jax.random.PRNGKey(0)
-    kp = jax.random.normal(rng, (b * m + 1, pt, e), dt)
-    vp = jax.random.normal(jax.random.fold_in(rng, 1), (b * m + 1, pt, e),
-                           dt)
-    q = jax.random.normal(jax.random.fold_in(rng, 2), (b, 1, e), dt)
-    table = (jnp.arange(b * m, dtype=jnp.int32).reshape(b, m) + 1)
-    lens = jnp.full((b,), m * pt, jnp.int32)
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    in_specs = [pl.BlockSpec(memory_space=pltpu.VMEM), hbm, hbm]
+    args = [qh, kd, vd]
+    scratch = [pltpu.VMEM((2, t.ppb) + kd.shape[1:], kd.dtype),
+               pltpu.VMEM((2, t.ppb) + vd.shape[1:], vd.dtype)]
+    if t.quant:
+        in_specs.append(hbm)
+        plane = k_pool.scale
+        if interpret and plane.shape[0] % 8:
+            # a step copies the whole sublane tile a page's row lies in.
+            # The chip's plane is stored by whole tiles; the interpreter's
+            # ends at its last row
+            plane = jnp.pad(plane, ((0, -plane.shape[0] % 8), (0, 0)))
+        args.append(plane)
+        scratch.append(pltpu.VMEM((2, t.ppb, 8, k_pool.scale.shape[1]),
+                                  jnp.float32))
+    scratch += [pltpu.VMEM((2, t.rows, t.ow), jnp.float32),
+                pltpu.VMEM((2, 8, LANES), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 3)),
+                pltpu.SemaphoreType.DMA((2, 2))]
+    # Mosaic has no 64-bit integers: the kernel is traced with 32-bit
+    # defaults whatever ``jax_enable_x64`` says (the tests set it)
+    with jax.enable_x64(False):
+        acc, stats = pl.pallas_call(
+            functools.partial(_kernel, t=t, scale=float(scale)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(1,),
+                in_specs=in_specs,
+                out_specs=[hbm, hbm],
+                scratch_shapes=scratch),
+            out_shape=[jax.ShapeDtypeStruct((rows, t.rows, t.ow), jnp.float32),
+                       jax.ShapeDtypeStruct((rows, 8, LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=int(min(100 << 20, max(32 << 20, 2 * t.vmem)))),
+            name="decode_live_blocks",
+            interpret=interpret,
+        )(pages.reshape(-1).astype(jnp.int32), slot.astype(jnp.int32),
+          valid.astype(jnp.int32), jnp.reshape(live, (1,)).astype(jnp.int32),
+          *args)
+    return (stats[:, 0, :t.heads], stats[:, 1, :t.heads],
+            acc[:, :t.heads, :t.hdv])
 
-    @jax.jit
-    def probe(q, kp, vp, table, lens):
-        # explicit split_cap: the sweep must not re-enter resolve()
-        return flash_sdpa_decode(q, kp, vp, table, lens, num_heads=1,
-                                 interpret=interpret, split_cap=cap)
 
-    def run():
-        jax.block_until_ready(probe(q, kp, vp, table, lens))
-
-    return run
-
-
-def _register_space():
-    from . import tuning
-
-    tuning.register_space(
-        "pallas_decode", version=1,
-        defaults={"max_splits": MAX_SPLITS},
-        constants=("MAX_SPLITS",),
-        candidates=_tuning_candidates, runner=_tuning_runner)
-
-
-_register_space()
+def block_bytes(t, k_pool, v_pool):
+    """Bytes one live block of the list is in the pools: what a step must
+    read (``benchmarks/bench_decode_kernel.py`` prices a call by it)."""
+    kd, vd = _plane(k_pool), _plane(v_pool)
+    per = (kd.shape[2] + vd.shape[2]) * np.dtype(kd.dtype).itemsize
+    if t.quant:
+        per += 2 * t.kv_heads * 4
+    return t.ppb * t.pt * per

@@ -73,7 +73,7 @@ BLOCK = BLOCK_ROWS * LANES
 
 # which update path the last fused-step build took ("pallas" | "xla") —
 # path-selection tripwire, same pattern as ops.attention.PATH_TAKEN /
-# ops.pallas_decode's DECODE_PATH
+# DECODE_PATH
 UPDATE_PATH = {"last": None}
 
 _SUPPORTED_DTYPES = ("float32", "bfloat16")
@@ -81,8 +81,8 @@ _SUPPORTED_DTYPES = ("float32", "bfloat16")
 
 def enabled():
     """``(armed, interpret)``: the kernel engages on TPU natively, or
-    anywhere under ``MXNET_PALLAS_INTERPRET`` (the tier-1 CPU harness) —
-    the same gate rule as ``MXNET_PALLAS_DECODE``."""
+    anywhere under ``MXNET_PALLAS_INTERPRET`` (the tier-1 CPU harness):
+    ``ops.attention._kernel_backend``'s rule."""
     import jax
 
     from .. import config as _config
@@ -418,8 +418,7 @@ def _bucket_call(kind, nslots, has_wc, w, g, slots, wc, cdtype, lrb, wdb,
         input_output_aliases=aliases,
         # every (16, 128) block is an independent segment of the update
         # — no cross-block reduction — so the grid axis fans out across
-        # megacores (the same marking pallas_decode gives its
-        # independent axes; 'arbitrary' would serialize the whole slab)
+        # megacores ('arbitrary' would serialize the whole slab)
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
         interpret=interpret,

@@ -108,7 +108,7 @@ def register_space(op, version, defaults, constants, candidates, runner):
 def spaces():
     """{op: space} of every registered tunable space (imports the
     kernel modules so their registrations ran)."""
-    from . import pallas_attention, pallas_decode, pallas_update  # noqa
+    from . import pallas_attention, pallas_update  # noqa
 
     return dict(_SPACES)
 

@@ -965,7 +965,7 @@ class CompiledTrainStep:
                                  for k, v in mesh_shape.items()},
                         "leaves": leaves}
         # the artifact-level PATH_TAKEN tripwire, same contract as
-        # decode's meta['pallas_decode']: a plan means the config
+        # decode's meta['pallas_decode']: a plan means the build
         # PROMISED the fused multi-tensor update kernel, and the
         # flop-dtype pass errors if no pallas_call lowered into the
         # program (a silent fallback to the per-parameter XLA chain)

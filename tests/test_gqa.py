@@ -508,30 +508,6 @@ def test_grouped_tuning_key_warns_on_stale_mha_record(tmp_path,
         _config.refresh("MXNET_PROGRAM_CACHE")
 
 
-def test_grouped_decode_tuning_key_warns_on_stale_mha_record(tmp_path,
-                                                             monkeypatch):
-    from mxnet_tpu import config as _config
-    from mxnet_tpu.ops import pallas_decode as pd
-    from mxnet_tpu.ops import tuning
-
-    monkeypatch.setenv("MXNET_PROGRAM_CACHE", str(tmp_path))
-    _config.refresh("MXNET_PROGRAM_CACHE")
-    try:
-        m = 4096
-        tuning.put("pallas_decode", tuning.shape_class_for(m=m), "any",
-                   {"split_cap": 8}, version=1)
-        pd._STALE_GROUP_CHECKED.discard(
-            tuning.shape_class_for(m=m, g=4))
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            pd._tuned_split_cap(m, groups=4)
-        assert any("MHA" in str(x.message) for x in w), \
-            [str(x.message) for x in w]
-    finally:
-        monkeypatch.delenv("MXNET_PROGRAM_CACHE")
-        _config.refresh("MXNET_PROGRAM_CACHE")
-
-
 def test_cache_bytes_pass_mha_under_gqa():
     """A pool/cache plane at the full q width under a grouped config is
     the dropped-layout regression the pass must error on."""

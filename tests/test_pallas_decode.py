@@ -1,385 +1,476 @@
-"""Fused Pallas flash-decoding kernels (ops/pallas_decode.py) and their
-dispatch/pricing/lint wiring.
+"""The decode row's Pallas kernel over paged KV pools (ops/pallas_decode.py),
+the rule that chooses it and the wiring that reports it.
 
-The ISSUE-11 acceptance surface, all in interpret mode on the CPU
-harness (the same kernels Mosaic compiles on TPU):
+All in interpret mode on the CPU harness (the same kernel Mosaic compiles on
+a TPU), plus compiles of the kernel at the serving cells' shapes for a
+described v5e:
 
-* kernel parity vs the three-pass einsum path (``paged_gather`` +
-  ``sdpa_decode``/``sdpa_verify``) on padded lens, ring wrap, shared /
-  recycled pages, int8 and fp8 pools, and k+1 verify windows;
-* the dense-ring variant (identity page table) vs ``sdpa_decode``;
-* dispatch gating: ``MXNET_PALLAS_DECODE`` + supported shapes take the
-  kernel (``DECODE_PATH``), unsupported shapes / meshes / knob-off fall
-  back to einsum — and the fallback is priced+linted, never silent;
-* the paged speculative server is token-identical kernel-on vs
-  kernel-off;
-* ``program_cost`` prices the einsum path's materialized gather view
-  (``gather_bytes``) so the fused path's attention bytes visibly drop;
-* the flop-dtype pass's ``pallas-fallback`` artifact tripwire.
+* parity of ``paged_attend``'s kernel path with ``_sdpa_cache`` over the
+  whole gathered view, at toy sizes with each admitted node's proportions
+  (H = H_kv with heads of 64; heads of 128 over 4 KV heads; K and V of
+  unequal width with a sink and a value scale) over float32, bfloat16, int8
+  and fp8 pools, with slots at length 0, 1, exactly one block, one past it
+  and a wrapped ring;
+* dead rows of the padded list reach nothing; the int8 case against
+  ``dequantize_kv`` + dense attention;
+* ``decode_kernel_selected`` as a table over the five serving
+  configurations' nodes (read from ``chipbench/configs``), and what it
+  refuses; ``mx_attn_dispatch_total{path}`` after tracing a toy graph with
+  one full and one window node;
+* the paged server token-identical with the kernel and with the walk;
+  ``program_cost`` prices the walk's gather and not the kernel's; the
+  flop-dtype pass's ``pallas-fallback`` artifact tripwire.
+
+Tolerance: rtol 1e-4 / atol 1e-5, what docs/inference.md states for
+reordered float32 sums (``tests/test_paged_live_blocks.py`` holds the walk
+to the same).
 """
+import os
+import sys
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from mxnet_tpu import config
+from mxnet_tpu import config, obs
 from mxnet_tpu.ops import attention as attn
 from mxnet_tpu.ops import pallas_decode as pd
 
-VOCAB, T, EMBED, HEADS = 17, 16, 8, 2
-B = 2
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "benchmarks"))
+import bench_decode_kernel as probe  # noqa: E402
+
+RTOL, ATOL = 1e-4, 1e-5
+PT, M = 16, 40                  # a view of 640 positions: 2.5 blocks of 256
+# the admitted nodes' proportions at toy sizes: (H, H_kv, hd, hdv, sink,
+# value scale)
+NODES = {
+    "mha_heads_of_64": (4, 4, 64, 64, False, 1.0),          # opt-1.3b
+    "heads_of_128_over_4": (8, 4, 128, 128, False, 1.0),    # falcon-h1-34b
+    "unequal_sink_scale": (8, 4, 192, 128, True, 0.707),    # mimo-v2.5
+}
+# empty, one position, exactly a block, one past it, a wrapped ring
+LENS = (0, 1, 256, 257, M * PT + 9)
 
 
 @pytest.fixture
-def kernel_on():
-    """Arm the fused decode kernel (interpret mode — CPU harness)."""
-    with config.overrides(MXNET_PALLAS_DECODE="1",
-                          MXNET_PALLAS_INTERPRET="1"):
+def interpret():
+    """A backend that runs Pallas through the interpreter."""
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
         yield
 
 
-def _pools(rng, pages, pt, e, dtype=None, heads=HEADS):
-    k = jnp.asarray(rng.randn(pages, pt, e).astype(np.float32))
-    v = jnp.asarray(rng.randn(pages, pt, e).astype(np.float32))
-    if dtype is None:
-        return k, v
-    # quantize through the production path so scales match exactly
-    return attn.quantize_pools(k, v, dtype, heads)
+def _case(node, dtype, seed=0, lens=LENS, qdtype=jnp.float32):
+    h, kvh, hd, hdv, sink, value_scale = NODES[node]
+    b = len(lens)
+    rng = np.random.RandomState(seed)
+    k = jnp.asarray(rng.randn(1 + b * M, PT, kvh * hd).astype(np.float32))
+    v = jnp.asarray(rng.randn(1 + b * M, PT, kvh * hdv).astype(np.float32))
+    if dtype in ("int8", "float8_e4m3fn"):
+        kp, vp = attn.quantize_pools(k, v, dtype, kvh)
+    else:
+        kp, vp = k.astype(dtype), v.astype(dtype)
+    table = jnp.asarray(1 + rng.permutation(b * M).reshape(b, M), jnp.int32)
+    q = jnp.asarray(rng.randn(b, 1, h * hd).astype(np.float32)).astype(qdtype)
+    kw = dict(num_heads=h, num_kv_heads=kvh, value_scale=value_scale,
+              sink=jnp.asarray(rng.randn(h).astype(np.float32))
+              if sink else None)
+    return (q, kp, vp, table, jnp.asarray(lens, jnp.int32)), kw
 
 
-def _einsum_paged(q, kp, vp, table, lens, heads):
-    return attn._sdpa_cache(q, *attn.paged_gather_kv(kp, vp, table), lens,
-                            heads, None)
+def _whole(args, kw):
+    q, kp, vp, table, total = args
+    return attn._sdpa_cache(q, *attn.paged_gather_kv(kp, vp, table), total,
+                            kw["num_heads"], None,
+                            num_kv_heads=kw["num_kv_heads"], sink=kw["sink"],
+                            value_scale=kw["value_scale"])
 
 
-# ---------------------------------------------------------------------------
-# kernel parity vs the einsum path
-# ---------------------------------------------------------------------------
-def test_paged_decode_parity_padded_full_wrapped():
-    """tq=1 over paged pools: padded short rows, an exactly-full ring and
-    a wrapped ring (page recycle: every view slot live) all match the
-    gather+attend einsum path; the table deliberately SHARES pages across
-    slots (prefix sharing) and repeats one page inside a slot."""
-    rng = np.random.RandomState(0)
-    m, pt = 4, 4
-    kp, vp = _pools(rng, 1 + B * m, pt, EMBED)
-    table = np.array([[1, 2, 3, 4], [2, 5, 6, 5]], np.int32)  # shared + dup
-    lens = jnp.asarray([5, m * pt + 7], dtype=jnp.int32)      # padded, wrap
-    q = jnp.asarray(rng.randn(B, 1, EMBED).astype(np.float32))
-
-    out = pd.flash_sdpa_decode(q, kp, vp, jnp.asarray(table), lens,
-                               num_heads=HEADS, interpret=True)
-    ref = _einsum_paged(q, kp, vp, jnp.asarray(table), lens, HEADS)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-6)
-
-    full = jnp.asarray([m * pt, m * pt], dtype=jnp.int32)
-    out2 = pd.flash_sdpa_decode(q, kp, vp, jnp.asarray(table), full,
-                                num_heads=HEADS, interpret=True)
-    ref2 = _einsum_paged(q, kp, vp, jnp.asarray(table), full, HEADS)
-    np.testing.assert_allclose(np.asarray(out2), np.asarray(ref2),
-                               rtol=1e-5, atol=1e-6)
-
-
-def test_paged_verify_parity_k_plus_1_window():
-    """tq=k+1 (the speculative verify window): each query row masks to
-    its own prefix exactly like ``sdpa_verify`` over the gathered view."""
-    rng = np.random.RandomState(1)
-    m, pt, k = 4, 4, 3
-    kp, vp = _pools(rng, 1 + B * m, pt, EMBED)
-    table = jnp.asarray(rng.randint(0, 1 + B * m, size=(B, m)), jnp.int32)
-    q = jnp.asarray(rng.randn(B, k + 1, EMBED).astype(np.float32))
-    for lens in ([k + 2, 9], [m * pt, 7]):
-        lens = jnp.asarray(lens, dtype=jnp.int32)
-        out = pd.flash_sdpa_verify(q, kp, vp, table, lens,
-                                   num_heads=HEADS, interpret=True)
-        ref = _einsum_paged(q, kp, vp, table, lens, HEADS)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-6)
-
-
-@pytest.mark.parametrize("dtype", ["int8", "float8_e4m3fn"])
-def test_quantized_pool_parity_in_kernel_dequant(dtype):
-    """int8 / fp8 pools dequantize per (token, head) INSIDE the kernel and
-    match the einsum path (which dequantizes the gathered view in HBM)
-    within streaming-accumulation tolerance."""
-    rng = np.random.RandomState(2)
-    m, pt = 4, 8
-    kp, vp = _pools(rng, 1 + B * m, pt, EMBED, dtype=dtype)
-    table = jnp.asarray(rng.randint(0, 1 + B * m, size=(B, m)), jnp.int32)
-    lens = jnp.asarray([6, m * pt + 3], dtype=jnp.int32)
-    for tq in (1, 3):
-        q = jnp.asarray(rng.randn(B, tq, EMBED).astype(np.float32))
-        fn = pd.flash_sdpa_decode if tq == 1 else pd.flash_sdpa_verify
-        # the kernel reads a scale plane a pool: the door splits the rows
-        out = fn(q, *attn._kernel_pools(kp, vp), table, lens,
-                 num_heads=HEADS, interpret=True)
-        ref = _einsum_paged(q, kp, vp, table, lens, HEADS)
-        assert np.asarray(out).dtype == np.float32
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-4, atol=1e-5)
-
-
-def test_dense_ring_identity_table_parity():
-    """The non-paged ring buffers ride the SAME kernel through an
-    identity page table — parity with ``sdpa_decode`` incl. wrap."""
-    rng = np.random.RandomState(3)
-    c = 24  # not a power of two: _dense_block must still tile it
-    kc = jnp.asarray(rng.randn(B, c, EMBED).astype(np.float32))
-    vc = jnp.asarray(rng.randn(B, c, EMBED).astype(np.float32))
-    q = jnp.asarray(rng.randn(B, 1, EMBED).astype(np.float32))
-    for lens in ([4, c], [c + 9, c + 1]):
-        lens = jnp.asarray(lens, dtype=jnp.int32)
-        out = pd.dense_ring_attend(q, kc, vc, lens, num_heads=HEADS,
-                                   interpret=True)
-        ref = attn.sdpa_decode(q, kc, vc, lens, num_heads=HEADS)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   rtol=1e-5, atol=1e-6)
-
-
-def test_split_k_sizing():
-    """The split axis takes the largest dividing power of two <= 8 and
-    degrades to 1 on odd page counts."""
-    assert pd._num_splits(8) == 8
-    assert pd._num_splits(6) == 2
-    assert pd._num_splits(12) == 4
-    assert pd._num_splits(7) == 1
-    assert pd._num_splits(1) == 1
+def _walk(args, kw):
+    """``paged_attend`` on a backend shown no Pallas."""
+    out = attn.paged_attend(*args, **kw)
+    assert attn.DECODE_PATH["last"] == "walk"
+    return out
 
 
 # ---------------------------------------------------------------------------
-# dispatch gating
+# parity
 # ---------------------------------------------------------------------------
-def test_dispatch_takes_kernel_and_falls_back(kernel_on):
-    """``paged_attend`` takes the kernel when armed and supported
-    (DECODE_PATH='pallas', same numbers as einsum), and falls back —
-    visibly — for unsupported heads, under a mesh, and with the knob
-    off."""
-    rng = np.random.RandomState(4)
-    m, pt = 4, 4
-    kp, vp = _pools(rng, 1 + B * m, pt, EMBED)
-    table = jnp.asarray(rng.randint(0, 1 + B * m, size=(B, m)), jnp.int32)
-    lens = jnp.asarray([5, 9], dtype=jnp.int32)
-    q = jnp.asarray(rng.randn(B, 1, EMBED).astype(np.float32))
-
-    out = attn.paged_attend(q, kp, vp, table, lens, num_heads=HEADS)
-    assert attn.DECODE_PATH["last"] == "pallas"
-    ref = _einsum_paged(q, kp, vp, table, lens, HEADS)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-5, atol=1e-6)
-
-    # shapes the gate refuses (heads not dividing E, empty tables) never
-    # reach the kernel
-    assert not pd.supported(q.shape, kp, vp, table.shape, 3,
-                            interpret=True)
-    assert not pd.supported(q.shape, kp, vp, (B, 0), HEADS,
-                            interpret=True)
-
-    # a mesh-sharded pool is opaque to Pallas: fallback
-    attn.paged_attend(q, kp, vp, table, lens, num_heads=HEADS,
-                      mesh_active=True)
-    assert attn.DECODE_PATH["last"] == "einsum"
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16", "int8",
+                                   "float8_e4m3fn"])
+@pytest.mark.parametrize("node", sorted(NODES))
+def test_kernel_parity_with_the_whole_view(node, dtype, interpret):
+    """The kernel path of ``paged_attend`` against ``_sdpa_cache`` over the
+    whole gathered view, every slot at another length; the empty slot (whose
+    answer is an average of unwritten pages, of the first block's alone on
+    the block paths) against the walk."""
+    args, kw = _case(node, dtype,
+                     qdtype=jnp.bfloat16 if dtype == "bfloat16"
+                     else jnp.float32)
+    got = attn.paged_attend(*args, **kw)
+    assert attn.DECODE_PATH["last"] == "decode-kernel"
+    ref = _whole(args, kw)
+    assert got.shape == ref.shape and got.dtype == ref.dtype
+    # a bfloat16 pool's output is rounded to bfloat16 on both sides
+    tol = dict(rtol=2e-2, atol=2e-2) if dtype == "bfloat16" \
+        else dict(rtol=RTOL, atol=ATOL)
+    np.testing.assert_allclose(np.asarray(got[1:], np.float32),
+                               np.asarray(ref[1:], np.float32), **tol)
+    with config.overrides(MXNET_PALLAS_INTERPRET="0"):
+        walk = _walk(args, kw)
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(walk, np.float32), **tol)
 
 
-def test_dispatch_marks_shape_gated_fallback(kernel_on, monkeypatch):
-    """An ARMED dispatch whose shape gate refuses records the distinct
-    'einsum-gated' marker (vs plain 'einsum' for knob-off/mesh) — the
-    artifact meta uses it to withdraw the kernel promise, so a
-    legitimate gated fallback (e.g. head dims off the Mosaic tile on
-    TPU) is never a pallas-fallback lint error."""
-    rng = np.random.RandomState(9)
-    m, pt = 4, 4
-    kp, vp = _pools(rng, 1 + B * m, pt, EMBED)
-    table = jnp.asarray(rng.randint(0, 1 + B * m, size=(B, m)), jnp.int32)
-    lens = jnp.asarray([5, 9], dtype=jnp.int32)
-    q = jnp.asarray(rng.randn(B, 1, EMBED).astype(np.float32))
+def test_int8_against_dequantized_dense_attention(interpret):
+    """The int8 kernel path against attention over the DEQUANTIZED float
+    buffers (``dequantize_kv``, then plain float32 einsums and a softmax):
+    the pools are attended as stored and the result is that of attending
+    what they stand for."""
+    args, kw = _case("heads_of_128_over_4", "int8", seed=3,
+                     lens=(5, 200, 256, 300, 640))
+    q, kp, vp, table, total = args
+    h, kvh = kw["num_heads"], kw["num_kv_heads"]
+    got = attn.paged_attend(*args, **kw)
+    assert attn.DECODE_PATH["last"] == "decode-kernel"
+    kq, vq = attn.paged_gather_kv(kp, vp, table)
+    k, v = (np.asarray(attn.dequantize_kv(c)) for c in (kq, vq))
+    b, c, _ = k.shape
+    qh = np.asarray(q, np.float32).reshape(b, kvh, h // kvh, -1)
+    logits = np.einsum("bhgd,bkhd->bhgk", qh, k.reshape(b, c, kvh, -1)) \
+        / np.sqrt(qh.shape[-1])
+    seen = np.arange(c)[None, :] < np.minimum(np.asarray(total), c)[:, None]
+    logits = np.where(seen[:, None, None], logits, -np.inf)
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    ref = np.einsum("bhgk,bkhe->bhge", p, v.reshape(b, c, kvh, -1))
+    np.testing.assert_allclose(np.asarray(got), ref.reshape(b, 1, -1),
+                               rtol=RTOL, atol=ATOL)
 
-    monkeypatch.setattr(pd, "supported", lambda *a, **k: False)
-    out = attn.paged_attend(q, kp, vp, table, lens, num_heads=HEADS)
-    assert attn.DECODE_PATH["last"] == "einsum-gated"
-    ref = _einsum_paged(q, kp, vp, table, lens, HEADS)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=1e-6, atol=0)
 
-    monkeypatch.setattr(pd, "supported_dense", lambda *a, **k: False)
-    kc = jnp.asarray(rng.randn(B, 8, EMBED).astype(np.float32))
-    attn.cache_attend(q, kc, kc, jnp.asarray([3, 3], dtype=jnp.int32),
-                      num_heads=HEADS)
-    assert attn.DECODE_PATH["last"] == "einsum-gated"
+def test_dead_rows_of_the_padded_list_reach_nothing(interpret):
+    """``attend_blocks`` visits the live prefix of the list alone: what the
+    dead rows name (here: pages that do not exist) is never copied, the
+    live rows' shares are ``_sdpa_cache``'s over the same blocks, and
+    ``_attend_live_blocks`` over a list padded further is the same
+    result."""
+    args, kw = _case("mha_heads_of_64", "int8", seed=4, lens=(300, 1, 40))
+    q, kp, vp, table, total = args
+    h = kw["num_heads"]
+    block, ppb = 256, 256 // PT
+    t = pd.tiles(q.shape, kp, vp, h, h, block)
+    # slot 0 has two live blocks, slots 1 and 2 one: four live rows of 12
+    slot = jnp.asarray([0, 0, 1, 2] + [2] * 8, jnp.int32)
+    blk = np.asarray([0, 1, 0, 0] + [0] * 8)
+    pages = np.full((12, ppb), 10 ** 6, np.int32)       # no such page
+    for r in range(4):
+        pages[r] = np.asarray(table)[int(slot[r]),
+                                     blk[r] * ppb:(blk[r] + 1) * ppb]
+    valid = jnp.clip(total[slot] - blk * block, 0, block)
+    m, den, acc = pd.attend_blocks(q, kp, vp, jnp.asarray(pages), slot,
+                                   valid, jnp.int32(4), t,
+                                   1.0 / np.sqrt(64), interpret=True)
+    k_blk, v_blk = attn.paged_gather_kv(kp, vp, jnp.asarray(pages[:4]))
+    want = attn._sdpa_cache(q[slot[:4]], k_blk, v_blk, total[slot[:4]], h,
+                            None, block=(jnp.asarray(blk[:4] * block),
+                                         M * PT))
+    for got, ref in zip((m, den, acc), want):
+        np.testing.assert_allclose(np.asarray(got[:4]),
+                                   np.asarray(ref[:, 0]), rtol=RTOL,
+                                   atol=ATOL)
+    outs = [attn._attend_live_blocks(q, kp, vp, table, total, h, None, h,
+                                     block, group, kernel=(t, True))
+            for group in (1, 7)]
+    np.testing.assert_array_equal(*(np.asarray(o) for o in outs))
+    np.testing.assert_allclose(np.asarray(outs[0]),
+                               np.asarray(_whole(args, kw)), rtol=RTOL,
+                               atol=ATOL)
 
 
-def test_gated_fallback_withdraws_artifact_promise(kernel_on, monkeypatch):
-    """A predictor whose decode programs were shape-gated away from the
-    kernel must NOT carry meta['pallas_decode'] — the flop-dtype
-    tripwire targets silent regressions, not visible gate refusals."""
-    from mxnet_tpu.analysis import run_passes
-    from mxnet_tpu.analysis.passes import FlopDtypePass
+# ---------------------------------------------------------------------------
+# the rule
+# ---------------------------------------------------------------------------
+# what each attention node of a serving cell takes in the decode step and in
+# a prefill chunk, in graph order (runs of equal nodes written once)
+RULE = {
+    "opt_serve_backlog": [("decode-kernel", "walk")] * 24,
+    "falconh1_serve_chat": [("decode-kernel", "walk")] * 6,
+    # full, four window rings, full, a window ring
+    "mimo_serve_longshort": [("decode-kernel", "walk")]
+    + [("whole", "whole")] * 4 + [("decode-kernel", "walk"),
+                                  ("whole", "whole")],
+    # two rows a slot (a draft beside the committed token): the walk
+    "exaone_serve_reason": [("whole", "whole")] * 3 + [("walk", "walk"),
+                                                       ("whole", "whole"),
+                                                       ("walk", "walk")],
+    # sparse selection attends its own lists
+    "sala_serve_longctx": [("sparse", "sparse")] * 3,
+}
+
+
+@pytest.mark.parametrize("cell", sorted(RULE))
+def test_rule_over_the_serving_configurations(cell, interpret):
+    """``decode_kernel_selected`` over the decode-row and chunk shapes of
+    every attention node of the five serving configurations, the shapes
+    read from the files under ``chipbench/configs`` and
+    ``chipbench/traffic``."""
+    nodes = probe.serving_nodes(cell)
+    got = [(probe.decode_path(n), probe.decode_path(n, tq=n["chunk"]))
+           for n in nodes]
+    assert got == RULE[cell], list(zip((n["name"] for n in nodes), got))
+    # a backend that runs no Pallas takes the kernel nowhere
+    with config.overrides(MXNET_PALLAS_INTERPRET="0"):
+        assert "decode-kernel" not in {probe.decode_path(n) for n in nodes}
+
+
+def _selected(q_shape=(4, 1, 256), ek=256, ev=256, kvh=4, heads=4, pages=M,
+              dtype=jnp.int8, mesh_active=False, window=0):
+    k = jax.ShapeDtypeStruct((1 + 4 * pages, PT, ek), dtype)
+    v = jax.ShapeDtypeStruct((1 + 4 * pages, PT, ev), dtype)
+    if jnp.dtype(dtype).itemsize == 1:
+        k = attn.QuantKV(k, jax.ShapeDtypeStruct(
+            (k.shape[0], PT * 2 * kvh), jnp.float32))
+        v = attn.QuantKV(v, None)
+    return attn.decode_kernel_selected(q_shape, k, v, (4, pages), heads, kvh,
+                                       mesh_active=mesh_active,
+                                       window=window)[0]
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("two query rows a slot", dict(q_shape=(4, 2, 256))),
+    ("a prefill chunk", dict(q_shape=(1, 64, 256))),
+    ("a window node", dict(window=128)),
+    ("a mesh shards the pools", dict(mesh_active=True)),
+    ("a view of one block", dict(pages=16)),
+    ("heads of 80: no whole lane tiles", dict(q_shape=(4, 1, 320), ek=320,
+                                              ev=320)),
+    ("2 KV heads: a scale row of 64 lanes", dict(kvh=2, ek=128, ev=128)),
+    ("160 heads", dict(q_shape=(4, 1, 160 * 64), heads=160, kvh=4)),
+])
+def test_rule_refuses(why, kw, interpret):
+    assert _selected() is not None
+    assert _selected(dtype=jnp.float32) is not None
+    assert _selected(**kw) is None, why
+
+
+def test_rule_needs_a_backend_that_runs_pallas():
+    assert _selected() is None                  # the CPU, no interpreter
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        t = _selected()
+        assert (t.heads, t.kv_heads, t.rows, t.pieces, t.ppb) == \
+            (4, 4, 16, 3, 16)
+        assert _selected(dtype=jnp.float32).pieces == 1
+
+
+# ---------------------------------------------------------------------------
+# a toy graph with one full and one window node, served
+# ---------------------------------------------------------------------------
+VOCAB, SLOTS, CACHE = 64, 2, 512
+
+
+def _toy_lm(seed=5):
+    from mxnet_tpu.models import decoder_lm
+
+    sym = decoder_lm.get_symbol(
+        vocab_size=VOCAB, hidden_size=64, num_layers=2, num_attention_heads=4,
+        head_dim=64, num_key_value_heads=4, swa_num_key_value_heads=4,
+        hybrid_layer_pattern=(0, 1), sliding_window=8, intermediate_size=64)
+    rng = np.random.RandomState(seed)
+    arg_shapes, _, _ = sym.infer_shape(data=(1, 64), softmax_label=(1, 64))
+    params = {n: (1.0 + 0.1 * rng.randn(*s) if len(s) == 1
+                  else rng.normal(0, 0.08, s)).astype(np.float32)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in ("data", "softmax_label")}
+    return sym, params
+
+
+def _predictor(kv_dtype="int8"):
     from mxnet_tpu.decode import DecodePredictor
-    from mxnet_tpu.models import attention_lm
 
-    monkeypatch.setattr(pd, "supported", lambda *a, **k: False)
-    sym = attention_lm.get_symbol(VOCAB, T, num_layers=1, embed=EMBED,
-                                  heads=HEADS, ffn_hidden=16)
-    rng = np.random.RandomState(10)
-    arg_shapes, _, _ = sym.infer_shape(data=(B, T), softmax_label=(B, T))
-    params = {n: rng.normal(0, 0.5, s).astype(np.float32)
-              for n, s in zip(sym.list_arguments(), arg_shapes)
-              if n not in ("data", "softmax_label")}
-    pred = DecodePredictor(sym, params, cache_len=T, temperature=0.0,
-                           paged=True, page_tokens=4)
-    art = pred.decode_artifact(pred.paged_batch_state(B))
-    assert art.meta["pallas_decode"] is False
-    rep = run_passes([art], passes=[FlopDtypePass()])
-    assert not any(f.code == "pallas-fallback" for f in rep.findings)
+    sym, params = _toy_lm()
+    return DecodePredictor(sym, params, cache_len=CACHE, temperature=0.0,
+                           paged=True, page_tokens=PT, prefill_chunk=64,
+                           kv_dtype=kv_dtype)
 
 
-def test_dispatch_off_by_default():
-    assert not attn.decode_kernel_mode()[0]
-    rng = np.random.RandomState(5)
-    kc = jnp.asarray(rng.randn(B, 8, EMBED).astype(np.float32))
-    attn.cache_attend(jnp.ones((B, 1, EMBED), jnp.float32), kc, kc,
-                      jnp.asarray([3, 3], dtype=jnp.int32),
-                      num_heads=HEADS)
-    assert attn.DECODE_PATH["last"] == "einsum"
+def _dispatched():
+    counter = obs.registry.counter("mx_attn_dispatch_total",
+                                   labels=("path",))
+    return {path: counter.labels(path=path).get()
+            for path in ("decode-kernel", "walk", "whole")}
 
 
-# ---------------------------------------------------------------------------
-# end-to-end: the paged speculative server, kernel on vs off
-# ---------------------------------------------------------------------------
-def _serve_tokens(rng_seed, arm):
-    from mxnet_tpu.decode import DecodePredictor, DecodeServer
-    from mxnet_tpu.models import attention_lm
+def _serve(pred):
+    from mxnet_tpu.decode import DecodeServer
 
-    sym = attention_lm.get_symbol(VOCAB, T, num_layers=2, embed=EMBED,
-                                  heads=HEADS, ffn_hidden=16)
-    rng = np.random.RandomState(rng_seed)
-    arg_shapes, _, _ = sym.infer_shape(data=(B, T), softmax_label=(B, T))
-    params = {n: rng.normal(0, 0.5, s).astype(np.float32)
-              for n, s in zip(sym.list_arguments(), arg_shapes)
-              if n not in ("data", "softmax_label")}
-    pred = DecodePredictor(sym, params, cache_len=T, temperature=0.0,
-                           paged=True, page_tokens=4, prefill_chunk=4)
-    server = DecodeServer(pred, max_prefill=10, slots=B,
-                          max_new_tokens=4, spec_k=2)
-    prefix = rng.randint(0, VOCAB, size=(4,))
-    ids = [server.submit(np.concatenate(
-        [prefix, rng.randint(0, VOCAB, size=(n,))])) for n in (2, 4, 3)]
+    server = DecodeServer(pred, max_prefill=320, slots=SLOTS,
+                          max_new_tokens=3)
+    rng = np.random.RandomState(9)
+    ids = [server.submit(rng.randint(0, VOCAB, size=(n,)))
+           for n in (300, 70, 260)]
     results = server.run()
-    assert attn.DECODE_PATH["last"] == ("pallas" if arm else "einsum")
-    return [np.asarray(results[i]) for i in ids]
+    return [np.asarray(results[i]) for i in ids], pred
 
 
-def test_paged_spec_serve_token_identical_kernel_on_off():
-    """The acceptance line: the paged speculative server emits EXACTLY
-    the same tokens with the fused kernel on and off (greedy serve,
-    shared prefix, chunked prefill, spec verify, retirement)."""
-    off = _serve_tokens(11, arm=False)
-    with config.overrides(MXNET_PALLAS_DECODE="1",
-                          MXNET_PALLAS_INTERPRET="1"):
-        on = _serve_tokens(11, arm=True)
-    assert len(on) == len(off)
+def test_dispatch_counter_and_token_identity(interpret):
+    """Tracing the toy's programs counts, in
+    ``mx_attn_dispatch_total{path}``, the full node's decode row as
+    ``decode-kernel``, its chunk as ``walk`` and the window node's ring as
+    ``whole`` in both; and the server emits exactly the walk's tokens."""
+    before = _dispatched()
+    on, pred = _serve(_predictor())
+    after = _dispatched()
+    took = {p: after[p] - before[p] for p in after}
+    # one decode program and one chunk program, two nodes each
+    assert took == {"decode-kernel": 1, "walk": 1, "whole": 2}, took
+    assert pred._decode_paths[1] == {"decode-kernel", "whole"}
+    assert pred._decode_paths[64] == {"walk", "whole"}
+    with config.overrides(MXNET_PALLAS_INTERPRET="0"):
+        off, pred = _serve(_predictor())
+    assert pred._decode_paths[1] == {"walk", "whole"}
     for i, (a, b) in enumerate(zip(on, off)):
         assert np.array_equal(a, b), \
-            "request %d diverged: kernel-on %s vs kernel-off %s" % (i, a, b)
+            "request %d diverged: kernel %s vs walk %s" % (i, a, b)
 
 
-# ---------------------------------------------------------------------------
-# pricing: the einsum path's gather view is no longer invisible
-# ---------------------------------------------------------------------------
-def test_gather_stats_price_paged_view():
-    from mxnet_tpu.analysis.hlo_parse import stablehlo_gather_stats
-
-    rng = np.random.RandomState(6)
-    kp, _ = _pools(rng, 9, 4, EMBED)
-    table = jnp.zeros((B, 4), jnp.int32)
-    low = jax.jit(attn.paged_gather).lower(kp, table).as_text()
-    stats = stablehlo_gather_stats(low)
-    view_bytes = B * 4 * 4 * EMBED * 4
-    assert stats["count"] >= 1
-    assert stats["bytes"] >= 2 * view_bytes  # write + re-read floor
-
-
-def test_program_cost_attn_bytes_drop_with_kernel():
-    """program_cost over the real paged decode-step program: the fused
-    path's priced attention bytes (pool pass + gathers) are <= 0.5x the
-    einsum path's — the mfu_table row the ISSUE-11 acceptance pins."""
-    from mxnet_tpu.analysis.cost import program_cost
-    from mxnet_tpu.decode import DecodePredictor
-    from mxnet_tpu.models import attention_lm
-
-    sym = attention_lm.get_symbol(VOCAB, T, num_layers=1, embed=EMBED,
-                                  heads=HEADS, ffn_hidden=16)
-    rng = np.random.RandomState(7)
-    arg_shapes, _, _ = sym.infer_shape(data=(B, T), softmax_label=(B, T))
-    params = {n: rng.normal(0, 0.5, s).astype(np.float32)
-              for n, s in zip(sym.list_arguments(), arg_shapes)
-              if n not in ("data", "softmax_label")}
-
-    def price(arm):
-        val = "1" if arm else None
-        with config.overrides(MXNET_PALLAS_DECODE=val,
-                              MXNET_PALLAS_INTERPRET=val):
-            pred = DecodePredictor(sym, params, cache_len=T, paged=True,
-                                   page_tokens=4)
-            state = pred.paged_batch_state(B)
-            tables, active = pred._paged_probe_args(state)
-            pred._probing = True
-            try:
-                cost = program_cost(
-                    pred._decode_fn,
-                    (pred._env, state, tables, active,
-                     jax.random.PRNGKey(0)))
-            finally:
-                pred._probing = False
-            return pred.pool_bytes() + cost["gather_bytes"], cost
-
-    attn_einsum, ce = price(False)
-    attn_fused, cf = price(True)
-    assert ce["gather_bytes"] > cf["gather_bytes"]
-    assert attn_fused <= 0.5 * attn_einsum, \
-        "fused attention bytes %d not <= 0.5x einsum %d" \
-        % (attn_fused, attn_einsum)
-    assert cf["bytes"] < ce["bytes"]
-
-
-# ---------------------------------------------------------------------------
-# the artifact-level lint tripwire
-# ---------------------------------------------------------------------------
-def test_flop_pass_pallas_tripwire(kernel_on):
-    """A decode artifact built under MXNET_PALLAS_DECODE carries the
-    promise; the flop-dtype pass blesses a program with a pallas_call and
-    errors on one that silently fell back to einsum."""
+def test_artifact_meta_and_flop_pass_tripwire(interpret):
+    """A decode artifact whose trace took the kernel says so
+    (``attn_paths``, ``pallas_decode``); the flop-dtype pass blesses a
+    program with a ``pallas_call`` and errors on one that promised the
+    kernel and lowered without it.  On a backend that runs no Pallas the
+    same predictor promises nothing."""
     from mxnet_tpu.analysis import run_passes
     from mxnet_tpu.analysis.artifact import ProgramArtifact
     from mxnet_tpu.analysis.passes import FlopDtypePass
-    from mxnet_tpu.decode import DecodePredictor
-    from mxnet_tpu.models import attention_lm
 
-    sym = attention_lm.get_symbol(VOCAB, T, num_layers=1, embed=EMBED,
-                                  heads=HEADS, ffn_hidden=16)
-    rng = np.random.RandomState(8)
-    arg_shapes, _, _ = sym.infer_shape(data=(B, T), softmax_label=(B, T))
-    params = {n: rng.normal(0, 0.5, s).astype(np.float32)
-              for n, s in zip(sym.list_arguments(), arg_shapes)
-              if n not in ("data", "softmax_label")}
-    pred = DecodePredictor(sym, params, cache_len=T, temperature=0.0,
-                           paged=True, page_tokens=4)
-    state = pred.paged_batch_state(B)
-    art = pred.decode_artifact(state)
+    pred = _predictor()
+    art = pred.decode_artifact(pred.paged_batch_state(SLOTS))
+    assert art.meta["attn_paths"] == ["decode-kernel", "whole"]
     assert art.meta["pallas_decode"] is True
     assert "pallas_call" in art.jaxpr_text
     rep = run_passes([art], passes=[FlopDtypePass()])
     assert any(f.code == "pallas-decode" for f in rep.findings)
     assert not any(f.code == "pallas-fallback" for f in rep.findings)
 
-    # a program that PROMISED the kernel but lowered einsum: lint error
     fallback = ProgramArtifact(
         name="paged_decode_step", jaxpr_text="no kernels here",
         stablehlo_text="", compiled_text="HloModule stub\n",
         meta={"pallas_decode": True})
     rep = run_passes([fallback], passes=[FlopDtypePass()])
     assert any(f.code == "pallas-fallback" for f in rep.errors)
+
+    with config.overrides(MXNET_PALLAS_INTERPRET="0"):
+        pred = _predictor()
+        art = pred.decode_artifact(pred.paged_batch_state(SLOTS))
+    assert art.meta["attn_paths"] == ["walk", "whole"]
+    assert art.meta["pallas_decode"] is False
+    assert "pallas_call" not in art.jaxpr_text
+    rep = run_passes([art], passes=[FlopDtypePass()])
+    assert not any(f.code.startswith("pallas") for f in rep.findings)
+
+
+# ---------------------------------------------------------------------------
+# pricing: the walk's gathered blocks are priced, the kernel has none
+# ---------------------------------------------------------------------------
+def test_gather_stats_price_paged_view():
+    from mxnet_tpu.analysis.hlo_parse import stablehlo_gather_stats
+
+    kp = jnp.zeros((9, 4, 8), jnp.float32)
+    table = jnp.zeros((2, 4), jnp.int32)
+    low = jax.jit(attn.paged_gather).lower(kp, table).as_text()
+    stats = stablehlo_gather_stats(low)
+    view_bytes = 2 * 4 * 4 * 8 * 4
+    assert stats["count"] >= 1
+    assert stats["bytes"] >= 2 * view_bytes  # write + re-read floor
+
+
+def test_program_cost_gather_bytes_drop_with_kernel():
+    """``program_cost`` over the toy's paged decode-step program: the
+    walk's program is priced the pages it gathers (one step of its loop),
+    the kernel's program none of the full node's."""
+    from mxnet_tpu.analysis.cost import program_cost
+
+    def price(kernel):
+        with config.overrides(MXNET_PALLAS_INTERPRET="1" if kernel else "0"):
+            pred = _predictor()
+            state = pred.paged_batch_state(SLOTS)
+            tables, active = pred._paged_probe_args(state)
+            pred._probing = True
+            try:
+                return program_cost(
+                    pred._decode_fn, (pred._env, state, tables, active,
+                                      jax.random.PRNGKey(0)))
+            finally:
+                pred._probing = False
+
+    walk, kernel = price(False), price(True)
+    # a step of the walk gathers one block of 256 positions of K and V, a
+    # byte each, for its two slots' share: the kernel's program lacks them
+    assert walk["gather_bytes"] - kernel["gather_bytes"] >= 2 * 2 * 256 * 256
+
+
+# ---------------------------------------------------------------------------
+# the kernel at the cells' shapes, compiled for a described v5e
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:          # no TPU compiler on this machine
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("cell", ["opt_serve_backlog", "falconh1_serve_chat",
+                                  "mimo_serve_longshort"])
+def test_kernel_compiles_for_the_chip_at_the_cells_shapes(cell, one_chip,
+                                                          monkeypatch):
+    """The decode row of each cell's first full node through
+    ``paged_attend``, compiled by the chip's own compiler: Mosaic takes the
+    kernel at the published widths, and the program holds no loop and no
+    array of a gathered block's shape."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
+    node = next(n for n in probe.serving_nodes(cell)
+                if probe.decode_path(n) == "decode-kernel")
+    b, m = node["slots"], node["cap"] // node["pt"]
+    aval = lambda s: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        s)
+    kp, vp = probe.abstract_pools(node)
+    args = (jax.ShapeDtypeStruct((b, 1, node["e"]), jnp.bfloat16),
+            kp, vp, jax.ShapeDtypeStruct((b, m), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32))
+
+    def attend(q, kp, vp, table, total):
+        return attn.paged_attend(q, kp, vp, table, total,
+                                 num_heads=node["heads"],
+                                 num_kv_heads=node["kv_heads"],
+                                 value_scale=node["value_scale"])
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(attend).lower(*aval(args)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert attn.DECODE_PATH["last"] == "decode-kernel"
+    assert "tpu_custom_call" in text
+    assert " while(" not in text
+    block = attn.live_block_plan((b, 1), (b, m), node["pt"])[0]
+    assert not re.search(r"s8\[\d+,%d,%d\]" % (block, node["ek"]), text)
 
 
 # ---------------------------------------------------------------------------
