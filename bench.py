@@ -227,29 +227,6 @@ def main():
 
     mfu_rows = obs.mfu_table()
     print(obs.render_mfu_table(mfu_rows), file=sys.stderr)
-    # optimizer-phase HBM bytes per step, priced for BOTH update paths
-    # (ops.pallas_update.priced_update_cost_for_step) — the tentpole's
-    # "HBM diet" claim as a published, asserted number.  The fused
-    # multi-tensor kernel must read+write the param/grad/slot traffic at
-    # most once; the per-parameter chain's engine-op floor is ~5 round
-    # trips — anything above 0.5x means the kernel stopped fusing.
-    opt_bytes = None
-    if mod._fused_step is not None:
-        from mxnet_tpu.ops.pallas_update import (UPDATE_PATH,
-                                                 priced_update_cost_for_step)
-
-        opt_bytes = priced_update_cost_for_step(mod._fused_step)
-        if opt_bytes is not None:
-            opt_bytes["path"] = UPDATE_PATH["last"]
-            # the halving claim is a bf16-headline claim: without the
-            # cast/recast phases a pure-f32 chain floors at 5/9 of the
-            # per-param bytes even when the kernel fuses perfectly
-            if dtype == "bfloat16":
-                assert opt_bytes["fused_bytes"] <= \
-                    0.5 * opt_bytes["per_param_bytes"], \
-                    "fused optimizer update must halve the per-parameter " \
-                    "path's priced HBM bytes at the headline config: %r" \
-                    % opt_bytes
     metric = "resnet50_train_imgs_per_sec_bs%d" % batch_size
     if use_recordio:
         metric = "resnet50_recordio_train_imgs_per_sec_bs%d" % batch_size
@@ -258,7 +235,6 @@ def main():
         round(img_s / BASELINE_IMG_S, 3),
         input_stall_fraction=round(stats["input_stall_fraction"], 4),
         host_syncs_per_step=round(stats["host_syncs_per_step"], 4),
-        opt_update_bytes=opt_bytes,
         mfu_table=mfu_rows, **device))
 
 
@@ -334,16 +310,6 @@ def smoke():
                   prompt_len=8, max_new_tokens=5)
     mfu_rows = obs.mfu_table()
     print(obs.render_mfu_table(mfu_rows), file=sys.stderr)
-    # publish (no assert here — the non-smoke headline asserts) the
-    # priced optimizer-phase bytes per path, same field as main()
-    opt_bytes = None
-    if mod._fused_step is not None:
-        from mxnet_tpu.ops.pallas_update import (UPDATE_PATH,
-                                                 priced_update_cost_for_step)
-
-        opt_bytes = priced_update_cost_for_step(mod._fused_step)
-        if opt_bytes is not None:
-            opt_bytes["path"] = UPDATE_PATH["last"]
     print(json.dumps({"loop_stats": {k: stats[k] for k in
                                      ("steps", "host_wait_s", "input_wait_s",
                                       "metric_d2h", "metric_syncs",
@@ -362,7 +328,6 @@ def smoke():
         ckpt_writes=ckpt_writes,
         ckpt_steps_during_write=steps_during_write,
         recoveries=stats["recoveries"],
-        opt_update_bytes=opt_bytes,
         mfu_table=mfu_rows))
 
 

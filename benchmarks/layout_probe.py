@@ -18,12 +18,7 @@ native row-major; the knob is then best left empty.
 
 Output contract: ONE bench.contract_line json per probed layout on
 stdout (winner flagged with ``"winner": true``); the human-readable
-table goes to stderr.  The ``--kv`` winner is also INGESTED into the
-persistent tuning cache (:mod:`mxnet_tpu.ops.tuning`, op
-``"kv_layout"``), where :func:`mxnet_tpu.ops.attention.apply_kv_layout`
-consults it whenever ``MXNET_KV_LAYOUT`` is unset — probe once on the
-bench chip, every later process on the same device generation places
-its pools with the winning layout.
+table goes to stderr.
 """
 import functools
 import os
@@ -160,12 +155,9 @@ def bench_kv(iters=30):
     the einsum path both stream).  The SAME jitted program runs for every
     candidate; only the pool's device layout changes, so the delta IS the
     layout.  Prints the winner as an ``export MXNET_KV_LAYOUT=...`` line
-    (empty = native wins or the backend refuses overrides), emits one
-    contract_line json per candidate on stdout, and ingests the winner
-    into the persistent tuning cache (op ``"kv_layout"``) so
-    ``apply_kv_layout`` finds it with the knob unset."""
+    (empty = native wins or the backend refuses overrides) and emits one
+    contract_line json per candidate on stdout."""
     from mxnet_tpu.ops import attention as attn
-    from mxnet_tpu.ops import tuning
 
     b, t_cache, e, heads, pt = 8, 2048, 1024, 8, 16
     m = t_cache // pt
@@ -215,17 +207,6 @@ def bench_kv(iters=30):
         print("winner: %s" % best, file=sys.stderr)
         print("export MXNET_KV_LAYOUT=%s"
               % ("" if best == "native" else best), file=sys.stderr)
-        # ingest: apply_kv_layout consults this entry whenever the knob
-        # is unset, keyed by pool rank + dtype on this device generation
-        key = tuning.put(
-            "kv_layout", tuning.shape_class_for(rank=kp.ndim),
-            kp.dtype.name,
-            {"kv_layout": "" if best == "native" else best},
-            version=1,
-            extra={"probed": [{"layout": n, "ms": round(d * 1e3, 4)}
-                              for d, n, _ in results]})
-        print("tuning cache: kv_layout winner persisted (%s)" % key,
-              file=sys.stderr)
 
 
 if __name__ == "__main__":

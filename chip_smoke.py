@@ -11,7 +11,7 @@ ONE process on the TPU, at the full width of the models the repo benchmarks
 * ``serve``     a 2-layer width-1024 ``attention_lm`` behind ``DecodeServer``
                 over a paged int8 KV pool, checked against the dense
                 f32-cache predictor on the same chip;
-* ``kernels``   each of the three Pallas families compiled by Mosaic once at a
+* ``kernels``   each of the two Pallas families compiled by Mosaic once at a
                 shape the phases above use, against its XLA reference;
 * ``multichip`` (>= 4 chips) data-parallel ResNet-50 and one ring-attention
                 LM step over a 4-way 'seq' mesh.
@@ -405,86 +405,7 @@ def kernel_decode(ctx, sizes):
     return {"outcome": "compiled", "shape": shape, "rel_err": _sig(err)}
 
 
-def kernel_update(ctx, sizes):
-    """The fused multi-tensor SGD-momentum update over the train phase's
-    parameter tree vs the per-parameter XLA chain."""
-    import jax
-    import jax.numpy as jnp
-
-    import mxnet_tpu as mx
-    from mxnet_tpu.models import resnet
-    from mxnet_tpu.ops import pallas_update
-
-    s, interp = sizes["train"], sizes["kernels"]["interpret"]
-    net = resnet.get_symbol(num_classes=s["classes"], num_layers=s["layers"],
-                            image_shape=tuple(s["image"]))
-    arg_shapes, _, _ = net.infer_shape(
-        data=(s["batch"],) + tuple(s["image"]),
-        softmax_label=(s["batch"],))
-    shapes = {n: sh for n, sh in zip(net.list_arguments(), arg_shapes)
-              if n not in ("data", "softmax_label")}
-    names = sorted(shapes)
-    rng = np.random.RandomState(3)
-
-    def tree(scale, dtype=jnp.float32):
-        return {n: jax.device_put(jnp.asarray(
-            rng.normal(0, scale, shapes[n]), dtype), ctx.jax_device)
-            for n in names}
-
-    w, g, m = tree(0.05), tree(1.0), tree(0.01)
-    opt = mx.optimizer.create("sgd", learning_rate=0.1, momentum=0.9,
-                              wd=1e-4, rescale_grad=1.0 / s["batch"])
-    plan = pallas_update.plan_for(opt, w, names, jnp.bfloat16,
-                                  interpret=interp)
-    assert plan is not None, "no fused-update plan for SGD-momentum"
-    _, opt_apply = opt.fused_kernel()
-    lrs, wds, rescale, clip = opt.fused_hyper(list(range(len(names))))
-    extra = opt.fused_extra()
-    lrb, wdb = plan.lr_wd_blocks(dict(zip(names, lrs)),
-                                 dict(zip(names, wds)))
-    hyp = jnp.concatenate([jnp.asarray([rescale, clip], jnp.float32),
-                           jnp.asarray(extra, jnp.float32)])
-
-    @jax.jit
-    def fused(w_, g_, m_):
-        ws = plan.pack(w_)
-        new_w, new_m, new_wc = plan.apply(
-            ws, plan.pack(g_, dtype_of_bucket=plan.grad_dtype),
-            plan.pack_slots({n: (m_[n],) for n in names}),
-            plan.cast_slabs(ws), lrb, wdb, hyp)
-        wc = {}
-        for bk in plan.buckets:
-            wc.update(plan.unpack(bk, new_wc[bk]))
-        return (plan.unpack_all(new_w),
-                {n: v[0] for n, v in plan.unpack_slots(new_m).items()}, wc)
-
-    @jax.jit
-    def per_param(w_, g_, m_):
-        out = {}
-        for i, n in enumerate(names):
-            out[n] = opt_apply(w_[n], g_[n], (m_[n],), lrs[i], wds[i],
-                               rescale, clip, extra)
-        return ({n: v[0] for n, v in out.items()},
-                {n: v[1][0] for n, v in out.items()},
-                {n: v[0].astype(jnp.bfloat16) for n, v in out.items()})
-
-    got, ref = fused(w, g, m), per_param(w, g, m)
-    worst, exact = 0.0, True
-    for a, b in zip(got, ref):
-        for n in names:
-            worst = max(worst, _rel_err(a[n], b[n]))
-            exact &= bool(np.array_equal(np.asarray(a[n], np.float32),
-                                         np.asarray(b[n], np.float32)))
-    # the same f32 chain in the same order; only fused multiply-add
-    # contraction (and one bf16 ulp on the recast) may differ
-    assert worst <= 1e-2 / 2 ** 7, worst
-    return {"outcome": "compiled", "params": len(names),
-            "elements": int(sum(np.prod(shapes[n]) for n in names)),
-            "rel_err": _sig(worst), "bit_identical": exact}
-
-
-KERNELS = {"flash_attention": kernel_flash, "paged_decode": kernel_decode,
-           "fused_update": kernel_update}
+KERNELS = {"flash_attention": kernel_flash, "paged_decode": kernel_decode}
 
 
 def kernels(ctx, sizes):

@@ -43,8 +43,7 @@ from .framework import (Finding, Pass, Report, SEVERITIES, default_passes,
                         run_passes)
 from .passes import (CacheBytesPass, CollectiveBudgetPass, DonationPass,
                      DriftPass, FlopDtypePass, HostSyncPass, RetracePass,
-                     ShardingCoveragePass, TunerCoveragePass,
-                     record_snapshot, snapshot_hash)
+                     ShardingCoveragePass, record_snapshot, snapshot_hash)
 from .retrace import RetraceAuditor, arg_signature, signature_diff
 from .schedule import ScheduleModel, SchedulePass, parse_schedule
 
@@ -53,7 +52,7 @@ __all__ = [
     "Finding", "FlopDtypePass", "HostSyncPass", "Pass", "ProgramArtifact",
     "Report", "RetraceAuditor", "RetracePass", "SEVERITIES",
     "ScheduleModel", "SchedulePass", "ShardingCoveragePass",
-    "TunerCoveragePass", "arg_signature", "artifact_cost",
+    "arg_signature", "artifact_cost",
     "artifact_from_jit", "aval_bytes", "default_passes", "load_budgets",
     "load_snapshot", "parse_schedule", "program_cost", "record_snapshot",
     "resolve_budgets_path", "run_passes", "signature_diff",

@@ -184,17 +184,15 @@ def _is_suppressed(finding, triples):
 
 
 def default_passes():
-    """Fresh instances of the ten shipped passes, in run order."""
+    """Fresh instances of the nine shipped passes, in run order."""
     from .passes import (CacheBytesPass, CollectiveBudgetPass, DonationPass,
                          DriftPass, FlopDtypePass, HostSyncPass,
-                         RetracePass, ShardingCoveragePass,
-                         TunerCoveragePass)
+                         RetracePass, ShardingCoveragePass)
     from .schedule import SchedulePass
 
     return [DonationPass(), CollectiveBudgetPass(), RetracePass(),
             HostSyncPass(), FlopDtypePass(), CacheBytesPass(),
-            TunerCoveragePass(), SchedulePass(), ShardingCoveragePass(),
-            DriftPass()]
+            SchedulePass(), ShardingCoveragePass(), DriftPass()]
 
 
 _SURFACE_ATTR = {"jaxpr": "jaxpr_text", "stablehlo": "stablehlo_text",

@@ -25,11 +25,6 @@ the 8-virtual-device CPU mesh instead of silently regressing a headline:
   per-slot rings), and a dense-ring allocation under ``MXNET_KV_PAGED=1``
   is an error — the config promises paged memory management the program
   no longer performs.
-* :class:`TunerCoveragePass` — every Pallas kernel module's block/split
-  constants must be registered with the autotuner
-  (:mod:`mxnet_tpu.ops.tuning`): a new hardcoded ``BLOCK_*`` that never
-  joined its module's tunable space is a shape the tuning cache can
-  never improve — exactly the silent plateau ISSUE-16 closes.
 * :class:`ShardingCoveragePass` — partition-rule coverage over the bound
   param tree (``meta['sharding_coverage']``): every leaf resolves to a
   rule match or an *intentional* replicate; the placement degrade paths
@@ -54,7 +49,7 @@ from .hlo_parse import (collective_stats, dot_flops_report,
 
 __all__ = ["DonationPass", "CollectiveBudgetPass", "RetracePass",
            "HostSyncPass", "FlopDtypePass", "CacheBytesPass",
-           "TunerCoveragePass", "ShardingCoveragePass", "DriftPass",
+           "ShardingCoveragePass", "DriftPass",
            "record_snapshot", "snapshot_hash"]
 
 
@@ -349,13 +344,6 @@ class FlopDtypePass(Pass):
          "(DECODE_PATH decode-kernel) but no pallas_call lowered into "
          "this program: the live blocks are attended some other way "
          "(dispatch regression)"),
-        ("pallas_update", "MXNET_PALLAS_UPDATE", "pallas-update",
-         "fused multi-tensor Pallas optimizer-update kernel present "
-         "(MXNET_PALLAS_UPDATE honored)",
-         "MXNET_PALLAS_UPDATE promises the fused multi-tensor "
-         "optimizer-update kernel but no pallas_call lowered into this "
-         "program — the update silently fell back to the per-parameter "
-         "XLA chain (plan gate or dispatch regression)"),
     )
 
     def run(self, artifact, context):
@@ -527,108 +515,6 @@ class CacheBytesPass(Pass):
                    layout or "dense"),
                 code="within-budget", measured=cache_bytes,
                 budget=ceiling, kv_dtype=kv_dtype, layout=layout))
-        return findings
-
-
-# the tunable-constant surface the tuner-coverage audit matches: block
-# shapes (BLOCK*) and split counts (*SPLIT/SPLITS).  MIN_* floors are
-# support gates (below them the kernels fall back to einsum), and LANES
-# is the TPU register lane width — neither is a tunable, so neither
-# needs a tuning-space registration.
-_TUNABLE_CONST_RE = r"^(BLOCK[A-Z_0-9]*|[A-Z_0-9]*SPLITS?)$"
-
-
-class TunerCoveragePass(Pass):
-    """Every Pallas module's block/split constants registered with the
-    autotuner.
-
-    Static source audit, not an artifact property: each
-    ``ops/pallas_*.py`` module is AST-scanned for module-level ALL_CAPS
-    ``BLOCK*``/``*SPLITS`` assignments, and each found name must appear
-    in that module's registered tuning space
-    (``tuning.spaces()[module].constants``).  A constant outside the
-    space is a block shape ``MXNET_PALLAS_TUNE`` can never sweep — the
-    hardcoded-plateau regression this pass exists to catch — and reads
-    as an error.  The scan is repo-global, so it runs ONCE per drive
-    (findings land on the first artifact; later artifacts skip with an
-    info row).
-    """
-
-    name = "tuner-coverage"
-    requires = ()
-
-    def __init__(self):
-        self._ran = False
-
-    def _scan(self):
-        import ast
-        import glob
-        import os
-        import re
-
-        ops_dir = os.path.join(os.path.dirname(__file__), "..", "ops")
-        pat = re.compile(_TUNABLE_CONST_RE)
-        found = {}
-        for path in sorted(glob.glob(os.path.join(ops_dir, "pallas_*.py"))):
-            mod = os.path.splitext(os.path.basename(path))[0]
-            with open(path) as f:
-                tree = ast.parse(f.read(), filename=path)
-            names = []
-            for node in tree.body:
-                targets = []
-                if isinstance(node, ast.Assign):
-                    targets = node.targets
-                elif isinstance(node, ast.AnnAssign):
-                    targets = [node.target]
-                for tgt in targets:
-                    if isinstance(tgt, ast.Name) and pat.match(tgt.id) \
-                            and not tgt.id.startswith("MIN_"):
-                        names.append(tgt.id)
-            found[mod] = names
-        return found
-
-    def run(self, artifact, context):
-        if self._ran:
-            return [self.finding(
-                artifact, "info", "tuner coverage audited once per drive",
-                code="already-ran")]
-        self._ran = True
-        from ..ops import tuning
-
-        spaces = tuning.spaces()
-        findings = []
-        total = 0
-        for mod, names in self._scan().items():
-            if not names:
-                continue
-            space = spaces.get(mod)
-            registered = set(space.constants) if space is not None else set()
-            missing = [n for n in names if n not in registered]
-            total += len(names)
-            if space is None:
-                findings.append(self.finding(
-                    artifact, "error",
-                    "ops/%s.py hardcodes block constants %s but registers "
-                    "no tuning space at all — MXNET_PALLAS_TUNE cannot "
-                    "sweep this kernel (ops/tuning.register_space)"
-                    % (mod, names), code="no-space", module=mod,
-                    constants=names))
-            elif missing:
-                findings.append(self.finding(
-                    artifact, "error",
-                    "ops/%s.py block constants %s are not governed by the "
-                    "module's registered tuning space (constants=%s) — "
-                    "the autotuner can never improve them"
-                    % (mod, missing, sorted(registered)),
-                    code="unregistered-constant", module=mod,
-                    missing=missing, registered=sorted(registered)))
-        if not findings:
-            findings.append(self.finding(
-                artifact, "info",
-                "%d block/split constants across %d Pallas modules all "
-                "registered with the autotuner"
-                % (total, len([1 for n in self._scan().values() if n])),
-                code="covered", constants=total))
         return findings
 
 

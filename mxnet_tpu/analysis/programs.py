@@ -483,32 +483,15 @@ def _moe_train_step_artifact():
 # canonical program is one register_canonical call
 # ---------------------------------------------------------------------------
 def _train_eval_builder(want):
-    # the canonical train_step is audited WITH the fused multi-tensor
-    # Pallas optimizer update armed (interpret off-TPU), so the
-    # flop-dtype pass's pallas-fallback tripwire proves the kernel
-    # lowered — the same arming story as the paged decode programs
-    from .. import config as _config
-
-    import jax as _jax
-
-    knobs = {"MXNET_PALLAS_UPDATE": "1"}
-    if _jax.default_backend() != "tpu":
-        knobs["MXNET_PALLAS_INTERPRET"] = "1"
     out = []
-    with _config.overrides(**knobs):
-        mod, batch = _mlp_module()
-        if "train_step" in want:
-            # the eval program needs only the bound group; driving (and
-            # compiling) the fused step is the train artifact's cost
-            step = _drive_fused(mod, batch)
-            if step._plan is None:
-                raise MXNetError(
-                    "MXNET_PALLAS_UPDATE armed but the canonical "
-                    "MLP step built no update plan (SGD-momentum "
-                    "f32 masters must be in scope)")
-            out.append(("train_step", step.artifact(name="train_step")))
-        if "eval_step" in want:
-            out.append(("eval_step", _eval_artifact(mod, batch)))
+    mod, batch = _mlp_module()
+    if "train_step" in want:
+        # the eval program needs only the bound group; driving (and
+        # compiling) the fused step is the train artifact's cost
+        step = _drive_fused(mod, batch)
+        out.append(("train_step", step.artifact(name="train_step")))
+    if "eval_step" in want:
+        out.append(("eval_step", _eval_artifact(mod, batch)))
     return out
 
 

@@ -15,8 +15,8 @@ __all__ = ["CHECKOUT", "JAX_CACHE", "PROGRAM_CACHE", "arm_compile_cache"]
 CHECKOUT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 # jax's persistent compilation cache (arm_compile_cache)
 JAX_CACHE = os.path.join(CHECKOUT, ".jax_cache")
-# AOT-serialized executables and tuned block shapes (programs.aot,
-# ops.tuning) when MXNET_PROGRAM_CACHE does not place them elsewhere
+# AOT-serialized executables (programs.aot) when MXNET_PROGRAM_CACHE does
+# not place them elsewhere
 PROGRAM_CACHE = os.path.join(CHECKOUT, ".mxnet_programs")
 
 

@@ -79,7 +79,7 @@ def refresh(name=None):
 def overrides(**knobs):
     """Temporarily pin declared flags through the environment.
 
-    ``with config.overrides(MXNET_PALLAS_UPDATE="1"):`` sets each env
+    ``with config.overrides(MXNET_PALLAS_INTERPRET="1"):`` sets each env
     var (``None`` unsets it), refreshes the registry cache so the new
     values are live inside the block, and restores BOTH the environment
     and the cache on exit — the save/set/refresh/restore dance that
@@ -138,33 +138,6 @@ register("MXNET_ENGINE_TYPE", str, "",
          "debugging (reference src/engine/engine.cc:13-39).")
 register("MXNET_PROFILER_AUTOSTART", bool, False,
          "Start the profiler at import time (reference env_var.md:71-79).")
-register("MXNET_PALLAS_UPDATE", bool, False,
-         "Use the fused multi-tensor Pallas optimizer-update kernel "
-         "(ops/pallas_update.py) inside the compiled train step: the "
-         "donated param/grad/slot trees flatten into dtype-homogeneous "
-         "flat slabs (multi-tensor apply) and ONE Pallas pass per slab "
-         "does grad rescale + clip + bf16->f32 promotion + the "
-         "SGD-momentum/Adam moment update (at the true update count t) "
-         "+ the compute-dtype recast — replacing the per-parameter XLA "
-         "update fusions, whose cast/rescale/clip/update/recast chain "
-         "round-trips every param, grad and slot through HBM "
-         "separately.  Engages on TPU, or anywhere under "
-         "MXNET_PALLAS_INTERPRET; unsupported optimizers (anything but "
-         "SGD/Adam), non-float32/bfloat16 params, mesh-sharded masters "
-         "and the eager opt_owner fall back to the existing per-param "
-         "path unchanged (the mxlint flop-dtype pass's pallas-fallback "
-         "tripwire covers the promise on canonical programs).")
-register("MXNET_PALLAS_TUNE", bool, False,
-         "Autotune Pallas kernel block shapes on the live device "
-         "(ops/tuning.py): each kernel module's registered candidate "
-         "space is swept layout_probe-style (timed probes), and the "
-         "winner is persisted in the content-addressed tuning cache "
-         "(the MXNET_PROGRAM_CACHE directory) keyed by (device "
-         "generation, op, shape-class, dtype) — a later process "
-         "resolves the same key from disk with zero probes.  Off "
-         "(default) = the modules' hardcoded constants, which remain "
-         "the interpret/CPU-mode defaults; cached winners are still "
-         "READ when present.")
 register("MXNET_MOE_DISPATCH", str, "sort",
          "Capacity-slot assignment algorithm for the sparse MoE "
          "dispatch (ops/moe.py): 'sort' (default) ranks the (token, "

@@ -363,13 +363,6 @@ class ProgramAccounting:
                 # compiled) executable carry their provenance — the
                 # cold-start story made visible per program
                 row["aot"] = cost["aot"]
-            if cost.get("update_path"):
-                # the opt_update row: which update path is armed, plus
-                # both paths' priced bytes so the fused-vs-per-param
-                # comparison travels with the table
-                for k in ("update_path", "per_param_bytes",
-                          "fused_bytes"):
-                    row[k] = cost.get(k)
             if "error" in cost:
                 row["error"] = cost["error"]
             if wall > 0 and calls > 0:

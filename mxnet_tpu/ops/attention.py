@@ -1617,11 +1617,8 @@ def apply_kv_layout(buf, device=None):
     --kv`` (which times decode attention under each candidate pool layout
     on the bench chip, per the ROADMAP's wire-the-probe clause).
 
-    Empty knob (default): the persistent tuning cache is consulted for
-    a ``--kv`` winner this probe ingested on this device generation
-    (op ``"kv_layout"``, keyed by pool rank + dtype); a cached native
-    winner or a cache miss is a plain ``device_put`` to ``device`` (or
-    the buffer as-is when no device is given).  A backend that refuses
+    Empty knob (default): a plain ``device_put`` to ``device`` (or the
+    buffer as-is when no device is given).  A backend that refuses
     the requested layout falls back to its native one with a one-time
     warning, so the knob is safe to leave set in mixed fleets."""
     import jax
@@ -1629,16 +1626,6 @@ def apply_kv_layout(buf, device=None):
     from .. import config as _config
 
     spec = str(_config.get("MXNET_KV_LAYOUT")).strip()
-    if not spec:
-        try:
-            from . import tuning
-
-            hit = tuning.get("kv_layout",
-                             tuning.shape_class_for(rank=buf.ndim),
-                             buf.dtype.name, version=1)
-            spec = str((hit or {}).get("kv_layout", "")).strip()
-        except Exception:
-            spec = ""
     if not spec:
         return jax.device_put(buf, device) if device is not None else buf
     from jax.experimental.layout import Format, Layout

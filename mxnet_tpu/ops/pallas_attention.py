@@ -43,12 +43,11 @@ import functools
 
 import numpy as np
 
-# Block-size defaults, taken where the tuning cache (ops/tuning.py) holds no
-# winner for the (generation, shape-class, dtype) — a fresh checkout holds
-# none, .mxnet_programs/ is not in git.  A block is one TILE of scores; the
-# kernels loop over tiles inside a grid step that holds MAJOR_ROWS rows of
-# K/V (forward) or of Q/dO (backward).  Measured on TPU v5 lite, jax 0.9.0,
-# bf16 causal, 8192 tokens, forward / backward in ms at (B, T) =
+# Block sizes: constants, swept by hand on the chip.  A block is one TILE of
+# scores; the kernels loop over tiles inside a grid step that holds
+# MAJOR_ROWS rows of K/V (forward) or of Q/dO (backward).  Measured on TPU
+# v5 lite, jax 0.9.0, bf16 causal, 8192 tokens, forward / backward in ms at
+# (B, T) =
 # (4, 2048) | (8, 1024) | (16, 512)
 # (benchmarks/bench_flash_attention.py --crossover, PR 31):
 #   32 heads of 64:  2.245 / 3.514 | 1.792 / 2.577 | 1.487 / 2.030
@@ -90,47 +89,6 @@ def _pick_block(pref, t):
     while b >= MIN_BLOCK and t % b:
         b //= 2
     return b if b >= MIN_BLOCK and t % b == 0 else 0
-
-
-# grouped shape classes whose stale-MHA-record check already ran (the
-# warned-miss fires once per shape class per process, not per trace)
-_STALE_GROUP_CHECKED = set()
-
-
-def _tuned(t, d, dtype, groups=1):
-    """Tuning-cache block resolution for this shape class ({"block_q",
-    "block_k", "block_q_bwd", "block_k_bwd"}; the module constants when
-    cold and no sweep armed).
-
-    The kv-head group factor is part of the content-addressed key
-    (``g<G>`` joins the shape class) — a grouped kernel's winning blocks
-    see G× narrower K/V streams than the MHA kernel's at the same (t, d),
-    so GQA shapes must never collide with MHA winners.  A persisted
-    MHA-keyed record encountered for a grouped shape reads as a WARNED
-    miss, never as a hit."""
-    import jax.numpy as jnp
-
-    from . import tuning
-
-    name = jnp.dtype(dtype).name
-    if groups <= 1:
-        return tuning.resolve("pallas_attention",
-                              tuning.shape_class_for(t=t, d=d), name)
-    sc = tuning.shape_class_for(t=t, d=d, g=groups)
-    if sc not in _STALE_GROUP_CHECKED:
-        _STALE_GROUP_CHECKED.add(sc)
-        if tuning.get("pallas_attention", sc, name, version=1) is None \
-                and tuning.get("pallas_attention",
-                               tuning.shape_class_for(t=t, d=d), name,
-                               version=1) is not None:
-            import warnings
-
-            warnings.warn(
-                "tuning cache holds an MHA-keyed pallas_attention record "
-                "for t=%d d=%d but the shape is grouped (G=%d); the MHA "
-                "winner does not apply — treating as a miss" %
-                (t, d, groups))
-    return tuning.resolve("pallas_attention", sc, name)
 
 
 def _out_sds(shape, dtype, *inputs):
@@ -303,12 +261,8 @@ def _fwd_rows(q, k, v, scale, causal, interpret, with_lse, block_q=None,
         raise ValueError(
             "flash_attention fwd: folded K/V batch %d * groups=%d != "
             "folded Q batch %d" % (k.shape[0], g, bh))
-    if block_q is None or block_k is None:
-        cfg = _tuned(t, d, q.dtype, groups=g)
-        block_q = block_q or cfg.get("block_q", BLOCK_Q)
-        block_k = block_k or cfg.get("block_k", BLOCK_K)
-    bq = _pick_block(block_q, t)
-    bk = _pick_block(block_k, t)
+    bq = _pick_block(block_q or BLOCK_Q, t)
+    bk = _pick_block(block_k or BLOCK_K, t)
     if not bq or not bk:
         raise ValueError("flash_attention fwd blocks degenerate for T=%d "
                          "(callers must gate on supported())" % t)
@@ -586,15 +540,10 @@ def _bwd_call(q, k, v, o, lse, do, scale, causal, interpret, block_q=None,
     # one kernel where a head's dQ fits VMEM and K/V are not grouped (a kv
     # head's dK/dV would gather G q-heads' dQ scratches); else two
     fused = g == 1 and t * d * (4 + 2 * q.dtype.itemsize) <= FUSED_BWD_BYTES
-    if block_q is None or block_k is None:
-        cfg = _tuned(t, d, q.dtype, groups=g)
-        block_q = block_q or cfg.get("block_q_bwd", BLOCK_Q_BWD)
-        # the two kernels pay per key block as the forward does, and take
-        # its key block (PR 26 measured 1024 for both)
-        block_k = block_k or (cfg.get("block_k_bwd", BLOCK_K_BWD) if fused
-                              else cfg.get("block_k", BLOCK_K))
-    bq = _pick_block(block_q, t)
-    bk = _pick_block(block_k, t)
+    bq = _pick_block(block_q or BLOCK_Q_BWD, t)
+    # the two kernels pay per key block as the forward does, and take
+    # its key block (PR 26 measured 1024 for both)
+    bk = _pick_block(block_k or (BLOCK_K_BWD if fused else BLOCK_K), t)
     if not bq or not bk:
         raise ValueError("flash_attention bwd blocks degenerate for T=%d "
                          "(callers must gate on supported())" % t)
@@ -845,74 +794,3 @@ def sdpa_flash(q, k, v, num_heads, causal, scale, interpret=False,
                           interpret=bool(interpret), groups=g)
     return out.reshape(b, num_heads, t, hd).transpose(0, 2, 1, 3) \
         .reshape(b, t, e)
-
-
-# ---------------------------------------------------------------------------
-# tunable space (ops/tuning.py): fwd/bwd Q/K blocks per shape class
-# ---------------------------------------------------------------------------
-
-def _tuning_candidates(shape_class, interpret):
-    if interpret:
-        # 2-candidate toy space: tier-1 exercises the sweep machinery on
-        # CPU without a grid search
-        return [{"block_q": 128, "block_k": 128},
-                {"block_q": 128, "block_k": 256}]
-    out = []
-    for bq in (256, 512, 1024):
-        for bk in (512, 1024):
-            for bqb, bkb in ((256, 512), (512, 512), (512, 1024)):
-                out.append({"block_q": bq, "block_k": bk,
-                            "block_q_bwd": bqb, "block_k_bwd": bkb})
-    return out
-
-
-def _tuning_runner(params, shape_class, dtype, interpret):
-    import jax
-    import jax.numpy as jnp
-
-    from . import tuning
-
-    dims = tuning.parse_shape_class(shape_class)
-    t, d = dims["t"], dims["d"]
-    for key in ("block_q", "block_k", "block_q_bwd", "block_k_bwd"):
-        if not _pick_block(params[key], t):
-            raise tuning.SpaceError("%s=%d degenerates for T=%d"
-                                    % (key, params[key], t))
-    dt = jnp.dtype(dtype)
-    rng = jax.random.PRNGKey(0)
-    bh = 4
-    q = jax.random.normal(rng, (bh, t, d), dt)
-    k = jax.random.normal(jax.random.fold_in(rng, 1), (bh, t, d), dt)
-    v = jax.random.normal(jax.random.fold_in(rng, 2), (bh, t, d), dt)
-    do = jnp.ones((bh, t, d), dt)
-    scale = 1.0 / float(np.sqrt(d))
-
-    bq, bk = params["block_q"], params["block_k"]
-    bqb, bkb = params["block_q_bwd"], params["block_k_bwd"]
-
-    @jax.jit
-    def probe(q, k, v, do):
-        o, lse = _fwd_call(q, k, v, scale, True, interpret, with_lse=True,
-                           block_q=bq, block_k=bk)
-        grads = _bwd_call(q, k, v, o, lse, do, scale, True, interpret,
-                          block_q=bqb, block_k=bkb)
-        return (o,) + tuple(grads)
-
-    def run():
-        jax.block_until_ready(probe(q, k, v, do))
-
-    return run
-
-
-def _register_space():
-    from . import tuning
-
-    tuning.register_space(
-        "pallas_attention", version=1,
-        defaults={"block_q": BLOCK_Q, "block_k": BLOCK_K,
-                  "block_q_bwd": BLOCK_Q_BWD, "block_k_bwd": BLOCK_K_BWD},
-        constants=("BLOCK_Q", "BLOCK_K", "BLOCK_Q_BWD", "BLOCK_K_BWD"),
-        candidates=_tuning_candidates, runner=_tuning_runner)
-
-
-_register_space()

@@ -316,6 +316,10 @@ class NAG(SGD):
 
         return make_slots, apply
 
+    # SGD's whole-model kernel is plain momentum; the eager path takes
+    # ``update`` below, one parameter at a time
+    update_multi = Optimizer.update_multi
+
     def update(self, index, weight, grad, state):
         self._update_count(index)
         lr = self._get_lr(index)

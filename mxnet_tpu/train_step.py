@@ -53,46 +53,14 @@ def _weak_prober(step, method="roofline_static"):
     return prober
 
 
-def _weak_update_prober(step):
-    """The ``opt_update`` roofline row's static prober: the optimizer
-    phase's priced HBM bytes on the path this step ACTUALLY runs
-    (``ops.pallas_update.priced_update_cost_for_step``) — so arming
-    MXNET_PALLAS_UPDATE visibly moves the row.  FLOPs are zero by
-    construction (the phase is pure traffic); both paths' bytes ride
-    along so the table's consumer can show the comparison.  Weakly
-    bound, same lifetime rule as :func:`_weak_prober`."""
-    import weakref
-
-    ref = weakref.ref(step)
-
-    def prober():
-        live = ref()
-        if live is None:
-            return None
-        from .ops.pallas_update import priced_update_cost_for_step
-
-        priced = priced_update_cost_for_step(live)
-        if priced is None:
-            return None
-        armed = live._plan is not None
-        return {"flops": 0,
-                "bytes": priced["fused_bytes" if armed
-                                else "per_param_bytes"],
-                "update_path": "pallas" if armed else "xla",
-                "per_param_bytes": priced["per_param_bytes"],
-                "fused_bytes": priced["fused_bytes"]}
-
-    return prober
-
-
 def _register_step_spec(step):
     """Register a step's :class:`~mxnet_tpu.programs.spec.ProgramSpec`
     with the process-wide program registry — name, donation map, lazy
     abstract args and the retrace counters, registered ONCE per step
     (the registry holds it weakly; the step owns it).  Works for both
-    :class:`CompiledTrainStep` (whose donation block widens under an
-    armed fused-update plan) and :class:`CompiledEvalStep` (donated
-    accumulator state only)."""
+    :class:`CompiledTrainStep` (params, slots, aux and metric state
+    donated) and :class:`CompiledEvalStep` (donated accumulator state
+    only)."""
     import weakref
 
     from .programs import registry as _registry
@@ -109,18 +77,10 @@ def _register_step_spec(step):
             return live._abstract_args(live._group)
         return live._last_args
 
-    # the donation map is fixed at registration: a fused-update plan
-    # only arms in __init__, and registration happens at first run()
-    if is_train:
-        donate = (0, 1, 2, 3, 4) if step._plan is not None \
-            else (0, 1, 2, 3)
-    else:
-        donate = (2,)
-
     spec = ProgramSpec(
         step.telemetry_name, step._fn, owner=step,
         abstract_args=abstract,
-        donate_argnums=donate,
+        donate_argnums=(0, 1, 2, 3) if is_train else (2,),
         compute_dtype=lambda: (str(ref()._cdtype)
                                if ref() is not None and is_train
                                and ref()._cdtype is not None else None),
@@ -346,12 +306,6 @@ class CompiledTrainStep:
     def __init__(self, exec_group, optimizer, compute_dtype=None):
         import jax.numpy as jnp
 
-        # the fused-update plan state must exist before the params/slots
-        # properties are first touched below
-        self._plan = None
-        self._w_slabs = None
-        self._slot_slabs = None
-        self._wcast = {}
         kernel = optimizer.fused_kernel()
         if kernel is None:
             raise MXNetError("optimizer %s has no fused kernel"
@@ -394,30 +348,6 @@ class CompiledTrainStep:
                        for n in self._param_names}
         self.aux = {n: jnp.copy(exe.aux_dict[n].data) for n in self._aux_names}
         self.reset_slots()
-        # fused multi-tensor Pallas optimizer update (MXNET_PALLAS_UPDATE,
-        # ops/pallas_update.py): when the plan builds — SGD/Adam, f32/bf16
-        # trainables, no mesh — the trainable master params and optimizer
-        # slots live PERMANENTLY as dtype-homogeneous slabs (plus the
-        # compute-dtype `_wcast` recast slabs), donated end to end through
-        # the step program: the forward reads slab views, the kernel
-        # updates the slabs in place, and nothing repacks per step — the
-        # whole point of the HBM diet.  The ``params``/``slots``
-        # properties keep the per-name dict surface for everything
-        # outside the hot path (checkpointing, probes, benches), packing
-        # on assignment and unpacking on read.  plan None = the
-        # per-parameter XLA path, unchanged.
-        from .ops import pallas_update as _pallas_update
-
-        armed, interpret = _pallas_update.enabled()
-        plan = None
-        if armed:
-            plan = _pallas_update.plan_for(
-                optimizer, self._params, self._grad_names, self._cdtype,
-                mesh=exec_group._mesh, interpret=interpret)
-        _pallas_update.UPDATE_PATH["last"] = \
-            "pallas" if plan is not None else "xla"
-        if plan is not None:
-            self._arm_plan(plan)
         # compiled programs keyed by executor identity (the value holds a
         # strong ref to the executor so a GC'd id can't alias a new one);
         # a reshape rebuilds group.exec_, so the stale program is skipped
@@ -452,61 +382,6 @@ class CompiledTrainStep:
         self.step_stale = False   # executor buffers newer than the store
         self.exec_stale = False   # store newer than executor buffers
         self.opt_owner = "eager"  # who holds live optimizer slots
-
-    # ------------------------------------------------------------------
-    # master-state surface: per-name dicts outside, slabs inside (plan)
-    # ------------------------------------------------------------------
-    @property
-    def params(self):
-        """Master params as a per-name dict.  Under an armed fused-update
-        plan the trainables are VIEWS unpacked from the persistent slabs
-        (fresh dict per read — mutate via assignment, not item writes);
-        otherwise the plain backing dict."""
-        if self._plan is None:
-            return self._params
-        out = dict(self._params)          # fixed (no-grad) params
-        out.update(self._plan.unpack_all(self._w_slabs))
-        return out
-
-    @params.setter
-    def params(self, value):
-        if self._plan is None:
-            self._params = value
-            return
-        planned = self._plan.names()
-        self._params = {n: v for n, v in value.items() if n not in planned}
-        self._w_slabs = self._plan.pack({n: value[n] for n in planned})
-        self._wcast = self._plan.cast_slabs(self._w_slabs)
-
-    @property
-    def slots(self):
-        """Optimizer slots as {name: tuple} — under an armed plan,
-        unpacked views of the persistent slot slabs."""
-        if self._plan is None:
-            return self._slots
-        return self._plan.unpack_slots(self._slot_slabs)
-
-    @slots.setter
-    def slots(self, value):
-        if self._plan is None:
-            self._slots = value
-            return
-        self._slot_slabs = self._plan.pack_slots(value)
-
-    def _arm_plan(self, plan):
-        """Move the trainable masters + slots into the plan's persistent
-        slabs (and build the compute-dtype recast slabs).  One-time pack
-        at arm time; after this the step program reads and donates the
-        slabs directly and nothing repacks per step."""
-        params, slots = self._params, self._slots
-        self._plan = plan
-        planned = plan.names()
-        self._params = {n: v for n, v in params.items()
-                        if n not in planned}
-        self._w_slabs = plan.pack({n: params[n] for n in planned})
-        self._slot_slabs = plan.pack_slots(slots)
-        self._wcast = plan.cast_slabs(self._w_slabs)
-        self._slots = {}
 
     def compatible(self, group):
         """Whether a (bucket) executor group can train through this store.
@@ -596,7 +471,6 @@ class CompiledTrainStep:
         opt_apply = self._opt_apply
         label_names = self._label_names
         macc = self._metric_acc
-        plan = self._plan
 
         def cast(v):
             if cdtype is not None and jnp.issubdtype(v.dtype, jnp.floating):
@@ -609,63 +483,6 @@ class CompiledTrainStep:
                 return v.astype(cdtype if cdtype is not None
                                 else jnp.float32)
             return v
-
-        if plan is not None:
-            # the persistent-slab step: masters and slots arrive AS the
-            # donated slabs and leave as the kernel's outputs — nothing
-            # packs or unpacks per step.  The forward reads views sliced
-            # straight out of the compute slab (wc buckets) or the master
-            # slab (master dtype == compute dtype) — slices feed their
-            # consumers without materializing.  The ONLY per-step
-            # assembly is the gradient slab, and its pack fuses into the
-            # backward's own output writes (the convert-before-reshape /
-            # excess-precision story in ops/pallas_update.py).
-            def step(w_slabs, slot_slabs, aux, wcast, mstate, fixed,
-                     data, lrb, wdb, rescale, clip, extra, rng):
-                if not self._probing:
-                    self.trace_count += 1
-                views = {}
-                for bk in plan.buckets:
-                    src = wcast[bk] if plan.has_wc(bk) else w_slabs[bk]
-                    views.update(plan.unpack(bk, src))
-                castp = {n: cast(v) for n, v in fixed.items()}
-                castp.update(views)
-                datac = {n: (cast(v) if n in data_names else v)
-                         for n, v in data.items()}
-
-                def fwd(gvals):
-                    env = dict(castp)
-                    env.update(zip(grad_names, gvals))
-                    env.update(datac)
-                    outs, new_aux = exe._run_graph(env, aux, rng, True)
-                    return outs, [new_aux[n] for n in aux_names]
-
-                gvals = [castp[n] for n in grad_names]
-                outs, vjp_fn, new_aux_vals = jax.vjp(fwd, gvals,
-                                                     has_aux=True)
-                cts = [jnp.ones_like(o) for o in outs]
-                (grads,) = vjp_fn(cts)
-
-                with _scope("optimizer"):
-                    g_slabs = plan.pack(dict(zip(grad_names, grads)),
-                                        dtype_of_bucket=plan.grad_dtype)
-                    hyp = jnp.concatenate([
-                        jnp.reshape(rescale, (1,)).astype(jnp.float32),
-                        jnp.reshape(clip, (1,)).astype(jnp.float32),
-                        extra.astype(jnp.float32)])
-                    new_w, new_slot_slabs, new_wcast = plan.apply(
-                        w_slabs, g_slabs, slot_slabs, wcast, lrb, wdb, hyp)
-                new_aux = {n: v.astype(aux[n].dtype)
-                           for n, v in zip(aux_names, new_aux_vals)}
-                if macc is not None:
-                    labels = [data[n] for n in label_names]
-                    with _scope("metric"):
-                        mstate = macc.update(mstate, labels, list(outs))
-                return (new_w, new_slot_slabs, new_aux, new_wcast, outs,
-                        mstate)
-
-            self.programs_built += 1
-            return jax.jit(step, donate_argnums=(0, 1, 2, 3, 4))
 
         def step(params, slots, aux, mstate, data, lrs, wds, rescale, clip,
                  extra, rng):
@@ -740,16 +557,6 @@ class CompiledTrainStep:
                 self.telemetry_name, _weak_prober(self, "compiled_hlo"),
                 owner=self)
             self._program_spec = _register_step_spec(self)
-            # the optimizer phase's own row: zero wall of its own (its
-            # dispatch is inside train_step), but its priced bytes make
-            # the fused-vs-per-param HBM diet visible per program.  Keyed
-            # by this step's telemetry name (canonical step keeps the
-            # bare contract name) so benches with several live train
-            # steps don't overwrite each other's row
-            row = "opt_update" if self.telemetry_name == "train_step" \
-                else "%s:opt_update" % self.telemetry_name
-            _obs.programs.register_static(row,
-                                          _weak_update_prober(self))
         with _obs.program_span(self.telemetry_name):
             return self._run_impl(data_batch, group)
 
@@ -774,7 +581,6 @@ class CompiledTrainStep:
 
         lrs, wds, rescale, clip = self._optimizer.fused_hyper(self._grad_indices)
         extra = self._optimizer.fused_extra()
-        plan = self._plan
         # keep hyper-params resident on device across steps: with a constant
         # schedule this is one transfer total instead of one per step
         cached = self._hyper_cache
@@ -788,17 +594,9 @@ class CompiledTrainStep:
 
             where = group._rep_sharding if group._mesh is not None \
                 else group.contexts[0].jax_device
-            if plan is not None:
-                # the fused kernel consumes per-BLOCK lr/wd scalar-
-                # prefetch arrays instead of per-param vectors
-                lrb, wdb = plan.lr_wd_blocks(
-                    dict(zip(self._grad_names, lrs)),
-                    dict(zip(self._grad_names, wds)))
-                host = (lrb, wdb, rescale, clip, extra)
-            else:
-                host = (lrs, wds, rescale, clip, extra)
             hyper_dev = jax.tree_util.tree_map(
-                lambda v: jax.device_put(v, where), host)
+                lambda v: jax.device_put(v, where),
+                (lrs, wds, rescale, clip, extra))
             self._hyper_cache = (lrs, wds, rescale, clip, extra, hyper_dev)
         rng = _rnd.split_key()
         acc = self._metric_acc
@@ -806,13 +604,6 @@ class CompiledTrainStep:
 
         def dispatch(fn, donated_mstate):
             h0, h1, h2, h3, h4 = hyper_dev
-            if plan is not None:
-                # the persistent slabs ARE the donated state; the per-name
-                # dict surface never enters the hot path.  ``_params``
-                # holds only the fixed (no-grad) forward inputs here.
-                return fn(self._w_slabs, self._slot_slabs, self.aux,
-                          self._wcast, donated_mstate, self._params, data,
-                          h0, h1, h2, h3, h4, rng)
             return fn(self.params, self.slots, self.aux, donated_mstate,
                       data, h0, h1, h2, h3, h4, rng)
 
@@ -838,12 +629,8 @@ class CompiledTrainStep:
                 self.detach_metric()
                 acc, mstate = None, ()
                 fn = self._entry_for(group)
-        if plan is not None:
-            (self._w_slabs, self._slot_slabs, self.aux, self._wcast, outs,
-             mstate) = dispatch(fn, mstate)
-        else:
-            self.params, self.slots, self.aux, outs, mstate = \
-                dispatch(fn, mstate)
+        self.params, self.slots, self.aux, outs, mstate = \
+            dispatch(fn, mstate)
         if acc is not None:
             acc.commit(mstate)
         self.num_steps += 1
@@ -863,19 +650,9 @@ class CompiledTrainStep:
         if self._hyper_cache is None:
             return None  # never run: no hyper avals to rebuild
 
-        if self._plan is not None:
-            # the slab signature: avals of the persistent donated slabs
-            # plus the fixed (no-grad) forward inputs
-            params = {bk: _aval(v) for bk, v in self._w_slabs.items()}
-            slots = {bk: tuple(_aval(s) for s in v)
-                     for bk, v in self._slot_slabs.items()}
-            fixed = {n: _aval(v) for n, v in self._params.items()}
-            wcast = {bk: _aval(v) for bk, v in self._wcast.items()}
-        else:
-            params = {n: _aval(v) for n, v in self.params.items()}
-            slots = {n: tuple(_aval(s) for s in v)
-                     for n, v in self.slots.items()}
-            fixed = wcast = None
+        params = {n: _aval(v) for n, v in self.params.items()}
+        slots = {n: tuple(_aval(s) for s in v)
+                 for n, v in self.slots.items()}
         aux = {n: _aval(v) for n, v in self.aux.items()}
         exe = group.exec_
         label_names = [n for n in group.label_names if n in exe.arg_dict]
@@ -890,9 +667,6 @@ class CompiledTrainStep:
                                               sharding=sharding)
         import jax.tree_util as jtu
 
-        # under the fused-update plan the hyper device tree is
-        # (lrb_dict, wdb_dict, rescale, clip, extra) — per-bucket
-        # per-block arrays instead of per-param vectors
         hyper = jtu.tree_map(_aval, self._hyper_cache[5])
 
         # metric accumulator avals carry NO sharding: after a drain the
@@ -909,9 +683,6 @@ class CompiledTrainStep:
         # global RNG (split_key() here would shift every later step's
         # randomness and break bit-reproducibility around the probe)
         rng = _aval(_rnd._key())
-        if self._plan is not None:
-            return (params, slots, aux, wcast, mstate, fixed, data) + \
-                tuple(hyper) + (rng,)
         return (params, slots, aux, mstate, data) + tuple(hyper) + (rng,)
 
     def compiled_hlo(self, group=None):
@@ -949,10 +720,8 @@ class CompiledTrainStep:
         if args is None:
             return None
         fn = self._entry_for(group)
-        # donated = the leading donate_argnums block: (params, slots, aux,
-        # mstate), plus the persistent compute slabs when the fused
-        # Pallas update plan is armed
-        ndon = 5 if self._plan is not None else 4
+        # donated = the leading donate_argnums block
+        # (params, slots, aux, mstate)
         mesh_shape = dict(group._mesh.shape) if group._mesh is not None \
             else None
         # sharding-coverage lint surface: the per-param placement records
@@ -964,20 +733,14 @@ class CompiledTrainStep:
             coverage = {"mesh": {str(k): int(v)
                                  for k, v in mesh_shape.items()},
                         "leaves": leaves}
-        # the artifact-level PATH_TAKEN tripwire, same contract as
-        # decode's meta['pallas_decode']: a plan means the build
-        # PROMISED the fused multi-tensor update kernel, and the
-        # flop-dtype pass errors if no pallas_call lowered into the
-        # program (a silent fallback to the per-parameter XLA chain)
         return probe_artifact(
             self, fn, args, name,
-            donated_leaves=len(jtu.tree_leaves(args[:ndon])),
+            donated_leaves=len(jtu.tree_leaves(args[:4])),
             compute_dtype=str(self._cdtype) if self._cdtype is not None
             else None,
             mesh_shape=mesh_shape, trace_count=self.trace_count,
             expected_traces=self.programs_built,
             num_steps=self.num_steps,
-            pallas_update=self._plan is not None,
             sharding_coverage=coverage)
 
     def roofline_static(self, group=None):
@@ -1015,10 +778,9 @@ class CompiledTrainStep:
         import jax.numpy as jnp
 
         exe = self._exec
-        params = self.params   # one slab unpack, not one per name
         for n in self._param_names:
             exe.arg_dict[n]._set_data(
-                jnp.copy(params[n]).astype(exe.arg_dict[n].data.dtype))
+                jnp.copy(self.params[n]).astype(exe.arg_dict[n].data.dtype))
         for n in self._aux_names:
             exe.aux_dict[n]._set_data(
                 jnp.copy(self.aux[n]).astype(exe.aux_dict[n].data.dtype))
@@ -1028,12 +790,8 @@ class CompiledTrainStep:
         import jax.numpy as jnp
 
         exe = self._exec
-        # whole-dict assignment: under an armed fused-update plan the
-        # params setter re-packs the slabs and rebuilds the compute-dtype
-        # recast slabs (pure cast(master) caches, so restore paths stay
-        # bit-identical to an uninterrupted run)
-        self.params = {n: jnp.copy(exe.arg_dict[n].data)
-                       for n in self._param_names}
+        for n in self._param_names:
+            self.params[n] = jnp.copy(exe.arg_dict[n].data)
         for n in self._aux_names:
             self.aux[n] = jnp.copy(exe.aux_dict[n].data)
 
@@ -1051,16 +809,11 @@ class CompiledTrainStep:
 
         host = pickle.loads(payload)
         index_names = {i: n for i, n in enumerate(self._group.param_names)}
-        # mutate a snapshot, then assign whole — under an armed plan the
-        # ``slots`` getter unpacks a FRESH dict, so item writes on it
-        # would be lost; the setter re-packs the slot slabs
-        slots = dict(self.slots)
         for key, state in host.items():
             name = index_names.get(key, key) if isinstance(key, int) else key
-            if name not in slots:
+            if name not in self.slots:
                 continue
-            slots[name] = self._state_to_slots(state, jnp)
-        self.slots = slots
+            self.slots[name] = self._state_to_slots(state, jnp)
 
     @staticmethod
     def _state_to_slots(state, jnp):
@@ -1079,8 +832,7 @@ class CompiledTrainStep:
         """Synthesize fresh (zero-moment) optimizer slots for the CURRENT
         params — a slot-less checkpoint restored into a training module
         must not keep the moments of the weights it replaced."""
-        params = self.params   # one slab unpack, not one per name
-        self.slots = {n: self._make_slots(params[n])
+        self.slots = {n: self._make_slots(self.params[n])
                       for n in self._grad_names}
 
     def import_updater_states(self, states, param_names):
@@ -1089,13 +841,10 @@ class CompiledTrainStep:
         import jax.numpy as jnp
 
         index_names = {i: n for i, n in enumerate(param_names)}
-        # snapshot-then-assign: see set_states
-        slots = dict(self.slots)
         for key, state in states.items():
             name = index_names.get(key, key) if isinstance(key, int) else key
-            if name in slots:
-                slots[name] = self._state_to_slots(state, jnp)
-        self.slots = slots
+            if name in self.slots:
+                self.slots[name] = self._state_to_slots(state, jnp)
 
     def export_updater_states(self, updater, param_names, ctx):
         """Hand the fused slots to an eager Updater (fused -> eager switch:
@@ -1104,10 +853,9 @@ class CompiledTrainStep:
 
         from . import ndarray as _nd
 
-        slots = self.slots   # one slab unpack, not one per name
         for idx, name in enumerate(param_names):
-            if name not in slots:
+            if name not in self.slots:
                 continue
             arrays = [_nd.NDArray(jnp.copy(s), ctx)
-                      for s in slots[name]]
+                      for s in self.slots[name]]
             updater.states[idx] = self._optimizer.pack_state(arrays)

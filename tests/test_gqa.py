@@ -6,8 +6,7 @@ hop slices, flash-kernel blocks, decode caches and page pools — carries
 query head h reads kv head ``h // G``, and the G=1 configuration is
 bit-identical to the ungrouped code (the grouped machinery must vanish
 when there is nothing to group).  Satellite coverage rides along: the
-named head-divisibility ``ValueError``s, the grouped tuning-key class
-with its stale-MHA-record warning, the ``mha-under-gqa`` cache-bytes
+named head-divisibility ``ValueError``s, the ``mha-under-gqa`` cache-bytes
 finding, the swap-restore layout guard, and the ``gqa_decode_step``
 canonical program registration.
 """
@@ -469,45 +468,8 @@ def test_attention_lm_g1_graph_json_identical():
 
 
 # ---------------------------------------------------------------------------
-# satellites: tuning keys, cache-bytes finding, swap guard, TP pspec,
-# canonical program
+# satellites: cache-bytes finding, swap guard, TP pspec, canonical program
 # ---------------------------------------------------------------------------
-def test_grouped_tuning_key_warns_on_stale_mha_record(tmp_path,
-                                                      monkeypatch):
-    from mxnet_tpu import config as _config
-    from mxnet_tpu.ops import tuning
-
-    monkeypatch.setenv("MXNET_PROGRAM_CACHE", str(tmp_path))
-    _config.refresh("MXNET_PROGRAM_CACHE")
-    try:
-        t, d = 8192, 256
-        mha_sc = tuning.shape_class_for(t=t, d=d)
-        gsc = tuning.shape_class_for(t=t, d=d, g=4)
-        assert gsc != mha_sc and "g4" in gsc
-        # a persisted MHA winner at the same (t, d)
-        tuning.put("pallas_attention", mha_sc, "float32",
-                   {"block_q": 256}, version=1)
-        pa._STALE_GROUP_CHECKED.discard(gsc)
-        with warnings.catch_warnings(record=True) as w:
-            warnings.simplefilter("always")
-            params = pa._tuned(t, d, np.float32, groups=4)
-        assert any("MHA" in str(x.message) and "G=4" in str(x.message)
-                   for x in w), [str(x.message) for x in w]
-        # the stale winner is a MISS: no grouped record was created and
-        # the kernel got a full params dict (the registered defaults)
-        assert "block_q" in params and "block_k" in params
-        assert tuning.get("pallas_attention", gsc, "float32",
-                          version=1) is None
-        # warned once per shape class, not once per trace
-        with warnings.catch_warnings(record=True) as w2:
-            warnings.simplefilter("always")
-            pa._tuned(t, d, np.float32, groups=4)
-        assert not [x for x in w2 if "MHA" in str(x.message)]
-    finally:
-        monkeypatch.delenv("MXNET_PROGRAM_CACHE")
-        _config.refresh("MXNET_PROGRAM_CACHE")
-
-
 def test_cache_bytes_pass_mha_under_gqa():
     """A pool/cache plane at the full q width under a grouped config is
     the dropped-layout regression the pass must error on."""

@@ -1,5 +1,7 @@
 """Pallas flash-attention kernel tests (interpret mode on CPU — the same
 kernel Mosaic compiles on a real TPU)."""
+import os
+
 import numpy as np
 import pytest
 
@@ -165,15 +167,10 @@ def test_flash_multiblock_grid_fwd_bwd(causal, case, monkeypatch):
     import jax
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops import tuning
-
     t, hd, heads, kv_heads, bq, bk, major = _GRID_CASES[case]
-    # cold-start blocks are the space's defaults (the module constants as
-    # registered); the memo holds what an earlier test resolved
-    monkeypatch.setattr(tuning.spaces()["pallas_attention"], "defaults",
-                        {"block_q": bq, "block_k": bk, "block_q_bwd": bq,
-                         "block_k_bwd": bk})
-    monkeypatch.setattr(tuning, "_MEMO", {})
+    for name, block in (("BLOCK_Q", bq), ("BLOCK_K", bk),
+                        ("BLOCK_Q_BWD", bq), ("BLOCK_K_BWD", bk)):
+        monkeypatch.setattr(pa, name, block)
     if major:
         monkeypatch.setattr(pa, "MAJOR_ROWS", major)
     if case == "split":
@@ -501,3 +498,50 @@ def test_odd_t_pick_block_degenerates_to_einsum_fallback():
         assert_almost_equal(np.asarray(a), np.asarray(b),
                             rtol=1e-4, atol=1e-5)
 
+
+
+def test_flash_and_kv_layout_leave_the_program_cache_alone(monkeypatch,
+                                                           tmp_path):
+    """Block sizes are the module's constants and a pool's layout is
+    ``MXNET_KV_LAYOUT``'s: a flash call (forward and backward) and
+    ``apply_kv_layout`` look for, open, list and create nothing under
+    ``cache_dirs.PROGRAM_CACHE``, wherever ``MXNET_PROGRAM_CACHE`` puts
+    it."""
+    import builtins
+
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu import cache_dirs, config
+    from mxnet_tpu.ops import attention as attn
+
+    moved = str(tmp_path / "programs")
+    watched = (cache_dirs.PROGRAM_CACHE, moved)
+    touched = []
+
+    def watch(module, name):
+        real = getattr(module, name)
+
+        def spy(path, *args, **kwargs):
+            if isinstance(path, (str, bytes, os.PathLike)) \
+                    and os.fsdecode(path).startswith(watched):
+                touched.append((name, os.fsdecode(path)))
+            return real(path, *args, **kwargs)
+
+        monkeypatch.setattr(module, name, spy)
+
+    # os.path.exists is an os.stat
+    for module, name in ((builtins, "open"), (os, "stat"), (os, "listdir"),
+                         (os, "scandir"), (os, "mkdir")):
+        watch(module, name)
+    with config.overrides(MXNET_PROGRAM_CACHE=moved):
+        rng = np.random.RandomState(2)
+        q, k, v = [jnp.asarray(x) for x in _qkv(rng, 2, 128, 64)]
+        jax.block_until_ready(jax.grad(
+            lambda *a: jnp.sum(pa.flash_attention(
+                *a, scale=0.125, causal=True, interpret=True)),
+            argnums=(0, 1, 2))(q, k, v))
+        pool = jnp.zeros((4, 8, 16), jnp.int8)
+        assert attn.apply_kv_layout(pool) is pool
+    assert touched == []
+    assert not os.path.exists(moved)

@@ -12,27 +12,40 @@ from mxnet_tpu import symbol as sym
 from mxnet_tpu.io import DataBatch
 
 
-def _make_module(fused, optimizer="sgd", compute_dtype=None, seed=7):
+def _make_module(fused, optimizer="sgd", compute_dtype=None, seed=7,
+                 optimizer_params=None, fixed=None, bf16_head=False):
+    """``bf16_head``: fc2's weight and bias are bfloat16 masters beside
+    fc1's float32 ones."""
     from mxnet_tpu import config
 
     data = mx.sym.Variable("data")
     fc1 = mx.sym.FullyConnected(data, name="fc1", num_hidden=16)
     act = mx.sym.Activation(fc1, name="relu1", act_type="relu")
-    fc2 = mx.sym.FullyConnected(act, name="fc2", num_hidden=4)
+    if bf16_head:
+        fc2 = mx.sym.FullyConnected(
+            mx.sym.Cast(act, dtype="bfloat16"), name="fc2", num_hidden=4,
+            weight=mx.sym.Variable("fc2_weight", dtype="bfloat16"),
+            bias=mx.sym.Variable("fc2_bias", dtype="bfloat16"))
+        fc2 = mx.sym.Cast(fc2, dtype="float32")
+    else:
+        fc2 = mx.sym.FullyConnected(act, name="fc2", num_hidden=4)
     net = mx.sym.SoftmaxOutput(fc2, name="softmax")
 
-    mod = mx.mod.Module(net, context=mx.cpu(), compute_dtype=compute_dtype)
+    mod = mx.mod.Module(net, context=mx.cpu(), compute_dtype=compute_dtype,
+                        fixed_param_names=fixed)
     mod.bind(data_shapes=[("data", (8, 10))], label_shapes=[("softmax_label", (8,))])
     mx.random.seed(seed)
     mod.init_params(mx.initializer.Uniform(0.1))
     import os
 
+    if optimizer_params is None:
+        optimizer_params = {"learning_rate": 0.1, "momentum": 0.9,
+                            "wd": 1e-4} \
+            if optimizer == "sgd" else {"learning_rate": 0.01}
     os.environ["MXNET_FUSED_TRAIN_STEP"] = "1" if fused else "0"
     config.refresh("MXNET_FUSED_TRAIN_STEP")
     mod.init_optimizer(optimizer=optimizer,
-                       optimizer_params={"learning_rate": 0.1, "momentum": 0.9,
-                                         "wd": 1e-4}
-                       if optimizer == "sgd" else {"learning_rate": 0.01})
+                       optimizer_params=dict(optimizer_params))
     os.environ["MXNET_FUSED_TRAIN_STEP"] = "1"
     config.refresh("MXNET_FUSED_TRAIN_STEP")
     return mod
@@ -48,24 +61,103 @@ def _batches(n, seed=3):
     return out
 
 
-@pytest.mark.parametrize("optimizer", ["sgd", "adam"])
-def test_fused_matches_eager(optimizer):
-    fused = _make_module(True, optimizer)
-    eager = _make_module(False, optimizer)
+# every optimizer with a ``fused_kernel()``, in each form that changes its
+# slots or its math
+_KERNELS = {
+    "sgd": ("sgd", {"learning_rate": 0.1, "momentum": 0.9}),
+    "sgd-plain": ("sgd", {"learning_rate": 0.1}),
+    "nag": ("nag", {"learning_rate": 0.1, "momentum": 0.9}),
+    "ccsgd": ("ccsgd", {"learning_rate": 0.1, "momentum": 0.9}),
+    "adam": ("adam", {"learning_rate": 0.01}),
+    "adagrad": ("adagrad", {"learning_rate": 0.05}),
+    "rmsprop": ("rmsprop", {"learning_rate": 0.01}),
+    "rmsprop-centered": ("rmsprop", {"learning_rate": 0.01,
+                                     "centered": True}),
+}
+
+
+def _kernel_pair(kernel, extra=None, **kwargs):
+    """The fused and the eager module of one ``_KERNELS`` entry."""
+    optimizer, params = _KERNELS[kernel]
+    params = dict(params, **(extra or {}))
+    fused = _make_module(True, optimizer, optimizer_params=params, **kwargs)
+    eager = _make_module(False, optimizer, optimizer_params=params, **kwargs)
     assert fused._fused_step is not None
     assert eager._fused_step is None
+    return fused, eager
 
-    for batch in _batches(5):
+
+def _step_both(fused, eager, batches):
+    for batch in batches:
         fused.forward_backward(batch)
         fused.update()
         eager.forward_backward(batch)
         eager.update()
 
-    fargs, fauxs = fused.get_params()
-    eargs, eauxs = eager.get_params()
+
+def _assert_same_params(fused, eager, rtol=1e-5, atol=1e-6):
+    fargs, eargs = fused.get_params()[0], eager.get_params()[0]
     for name in fargs:
-        np.testing.assert_allclose(fargs[name].asnumpy(), eargs[name].asnumpy(),
-                                   rtol=1e-5, atol=1e-6, err_msg=name)
+        np.testing.assert_allclose(
+            fargs[name].asnumpy().astype(np.float32),
+            eargs[name].asnumpy().astype(np.float32),
+            rtol=rtol, atol=atol, err_msg=name)
+
+
+@pytest.mark.parametrize("decay_and_clip", [False, True])
+@pytest.mark.parametrize("kernel", list(_KERNELS))
+def test_fused_matches_eager(kernel, decay_and_clip):
+    # the clip is low enough to bite: the rescaled gradients reach ~0.4
+    extra = {"wd": 1e-4, "clip_gradient": 0.05} if decay_and_clip else None
+    fused, eager = _kernel_pair(kernel, extra)
+    _step_both(fused, eager, _batches(5))
+    _assert_same_params(fused, eager)
+
+
+@pytest.mark.parametrize("kernel", ["sgd", "sgd-plain", "adam"])
+def test_fused_matches_eager_on_mixed_dtype_masters(kernel):
+    """A tree of float32 and bfloat16 trainables: every master and every
+    slot keeps its own type through the step (the eager SGD update
+    promotes a bfloat16 weight to float32, so the two agree to a bfloat16
+    rounding, not to the bit)."""
+    fused, eager = _kernel_pair(kernel, bf16_head=True)
+    step = fused._fused_step
+    dtypes = {n: v.dtype for n, v in step.params.items()}
+    assert {str(d) for d in dtypes.values()} == {"float32", "bfloat16"}
+    _step_both(fused, eager, _batches(4))
+    for n, v in step.params.items():
+        assert v.dtype == dtypes[n], n
+        assert all(s.dtype == dtypes[n] for s in step.slots[n]), n
+    _assert_same_params(fused, eager, rtol=0, atol=2e-3)
+
+
+def test_fused_matches_eager_with_fixed_params():
+    """A fixed parameter rides through the step as a forward input: it has
+    no slot, stays as initialised, and the others train as on the eager
+    path."""
+    fused, eager = _kernel_pair("sgd", fixed=["fc1_bias"])
+    assert "fc1_bias" not in fused._fused_step.slots
+    before = fused.get_params()[0]["fc1_bias"].asnumpy().copy()
+    _step_both(fused, eager, _batches(4))
+    np.testing.assert_array_equal(
+        fused.get_params()[0]["fc1_bias"].asnumpy(), before)
+    _assert_same_params(fused, eager)
+
+
+def test_set_params_between_steps_is_read_by_the_next_step():
+    """Masters replaced from outside the step (``set_params``) are what the
+    next step trains from; the slots carry over."""
+    fused, eager = _kernel_pair("sgd")
+    batches = _batches(3)
+    _step_both(fused, eager, batches[:2])
+    new_args, new_aux = _make_module(True, seed=99).get_params()
+    fused.set_params(new_args, new_aux)
+    eager.set_params(new_args, new_aux)
+    _step_both(fused, eager, batches[2:])
+    _assert_same_params(fused, eager)
+    # and not from the masters the first two steps left
+    moved = fused.get_params()[0]["fc1_weight"].asnumpy()
+    assert np.abs(moved - new_args["fc1_weight"].asnumpy()).max() < 0.05
 
 
 def test_fused_outputs_feed_metric():
@@ -112,8 +204,11 @@ def test_bf16_compute_trains():
     assert last < first  # loss decreased under bf16 compute
 
 
-def test_fused_optimizer_state_roundtrip(tmp_path):
-    mod = _make_module(True)
+@pytest.mark.parametrize("kernel",
+                         ["sgd", "nag", "adam", "adagrad", "rmsprop"])
+def test_fused_optimizer_state_roundtrip(tmp_path, kernel):
+    mod = _make_module(True, _KERNELS[kernel][0],
+                       optimizer_params=_KERNELS[kernel][1])
     for batch in _batches(3):
         mod.forward_backward(batch)
         mod.update()
@@ -151,11 +246,12 @@ def test_rescale_clip_are_runtime_scalars():
         assert np.max(np.abs(p2[n] - p1[n])) < 1.0
 
 
-def test_fused_to_eager_handoff_preserves_momentum():
+@pytest.mark.parametrize("kernel",
+                         ["sgd", "nag", "adam", "adagrad", "rmsprop"])
+def test_fused_to_eager_handoff_preserves_momentum(kernel):
     # install_monitor mid-training drops to the eager path; momentum must
     # carry over so the trajectory matches a pure-eager run
-    fused = _make_module(True)
-    eager = _make_module(False)
+    fused, eager = _kernel_pair(kernel)
     batches = _batches(6)
     for b in batches[:3]:
         fused.forward_backward(b)

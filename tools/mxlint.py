@@ -13,7 +13,7 @@ real top-2 capacity-routed MoE LM step whose explicit all-to-all
 dispatch the collective pass budgets), snapshots each as a
 :class:`~mxnet_tpu.analysis.artifact.ProgramArtifact` (jaxpr + lowered
 StableHLO + compiled HLO + donation/retrace/dtype/cache metadata), and
-runs the ten analysis passes against the committed budget file:
+runs the nine analysis passes against the committed budget file:
 
 ==================  =====================================================
 pass                invariant it pins
@@ -25,8 +25,6 @@ host-sync           no host-callback primitives / host-transfer HLO ops
 flop-dtype          dot_flops coverage; no f32 dots in bf16 programs
 cache-bytes         decode KV-cache bytes <= ceiling; quantized configs
                     store narrow data planes
-tuner-coverage      Pallas block/split constants registered with the
-                    autotuner (no dead hand-tuned shapes)
 schedule            async -start/-done pairs matched; compute shadows
                     above the per-program ``overlap`` floors
 sharding-coverage   every bound param resolves to a rule match or an

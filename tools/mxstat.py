@@ -19,7 +19,7 @@ Usage:
 * ``tools/mxstat.py --diff A.json B.json`` — headline / MFU / bytes
   deltas between two bench JSON contracts (``BENCH_r*.json``): the
   headline metric's value, the aggregate byte-ish extras
-  (``opt_update_bytes``, ``all_to_all_bytes``, ``dispatch_bytes``),
+  (``all_to_all_bytes``, ``dispatch_bytes``),
   the fleet headline fields (``bench_fleet.py``: ``p95_ttft_ms``,
   ``router_cache_hit_rate``, ``vs_round_robin``, migrated/swapped page
   counts, and the ``--cold-start`` contract's ``cold_start_s`` /
@@ -143,7 +143,7 @@ _EXTRA_SUFFIXES = (".ratio", ".count", "_ms", "_rate", "_pages",
 
 def _flatten_bytes_extras(obj, prefix=""):
     """The byte-ish / fleet-headline scalar extras of a contract line,
-    flattened: opt_update_bytes.fused_bytes, dispatch_bytes.sort.bytes,
+    flattened: all_to_all_bytes, dispatch_bytes.sort.bytes,
     p95_ttft_ms, router_cache_hit_rate, migrated_pages, ..."""
     out = {}
     for key, val in sorted((obj or {}).items()):
@@ -283,9 +283,7 @@ def smoke():
     with tempfile.TemporaryDirectory(prefix="mxstat_diff_") as tmp:
         a_line = {"metric": "resnet50_train_imgs_per_sec_bs256",
                   "value": 2442.6, "unit": "img/s", "vs_baseline": 13.45,
-                  "opt_update_bytes": {"per_param_bytes": 1200,
-                                       "fused_bytes": 1200,
-                                       "ratio": 1.0},
+                  "dispatch_bytes": {"sort": {"bytes": 1200}},
                   "schedule_pairs": 6, "schedule_serialized": 0,
                   "drift_checked": 13, "drifted": 0,
                   "mfu_table": [{"program": "train_step", "calls": 10,
@@ -293,9 +291,7 @@ def smoke():
                                  "bytes": 1000, "mfu": 0.15}]}
         b_line = {"metric": "resnet50_train_imgs_per_sec_bs256",
                   "value": 2520.9, "unit": "img/s", "vs_baseline": 13.89,
-                  "opt_update_bytes": {"per_param_bytes": 1200,
-                                       "fused_bytes": 540,
-                                       "ratio": 0.45},
+                  "dispatch_bytes": {"sort": {"bytes": 540}},
                   "schedule_pairs": 4, "schedule_serialized": 2,
                   "drift_checked": 13, "drifted": 1,
                   "mfu_table": [{"program": "train_step", "calls": 10,
@@ -312,7 +308,7 @@ def smoke():
         text = buf.getvalue()
         checks["diff_exit"] = rc == 0
         checks["diff_headline"] = "+78.3" in text and "+3.21%" in text
-        checks["diff_bytes"] = "opt_update_bytes.fused_bytes" in text \
+        checks["diff_bytes"] = "dispatch_bytes.sort.bytes" in text \
             and "-660" in text and "-55.00%" in text
         checks["diff_programs"] = "train_step.bytes" in text \
             and "-200" in text
