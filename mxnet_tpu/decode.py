@@ -85,12 +85,16 @@ rotated) — ``models.attention_lm``, ``models.decoder_lm`` and the benchmark
 LMs qualify.
 
 **Cache groups.**  Each stateful node has a cache layout read off it at
-bind time (:class:`CacheLayout`), of one of three kinds: a "full" attention
+bind time (:class:`CacheLayout`), of one of four kinds: a "full" attention
 node holds ``cache_len`` positions a slot, a paged "window" node a ring of
 ``window + prefill_chunk`` positions rounded up to a page, a "state" node
-(a recurrent op) one fixed row a slot and no positions at all.  Nodes of
-one kind and capacity form a group (``serve.CacheGroup``): the paged kinds
-with their own page count, page tables and allocator, the state group with
+(a recurrent op) one fixed row a slot and no positions at all, and a
+"latent" node (``ops.attention.LATENT_OP``) ``cache_len`` positions as ONE
+plane with no head axis, ``kv_lora_rank + qk_rope_head_dim`` values a
+position and a page a row, in the full group's pages and tables (pages
+are pages: fork, copy-on-write, extract and install move the plane as they
+move keys and values).  Nodes of one kind and capacity form a group
+(``serve.CacheGroup``): the paged kinds with their own page count, page tables and allocator, the state group with
 one row a slot whose "table" is the row's index.  A graph of one kind builds
 one group and the tables, programs and page counts it built before groups
 existed.  A state row has no scratch page: the decode step masks its write
@@ -122,6 +126,9 @@ _KV_DTYPES = {
     "f8e4m3fn": "float8_e4m3fn",
     "float8_e5m2": "float8_e5m2", "f8e5m2": "float8_e5m2",
 }
+# spellings of "the pools in the serving type": what an empty string says,
+# by a name a traffic file can state
+_KV_UNQUANTIZED = ("bfloat16", "bf16")
 
 def _pad_window(tokens, width):
     """``tokens`` left-aligned in a zero-padded (1, width) float32 window —
@@ -197,14 +204,18 @@ def _per_group(items):
     return items if len(items) > 1 else items[0]
 
 
-def _pool_pair(kc, vc, make, *index):
-    """A node's two page pools from its probed K and V avals:
-    ``make(aval, is_scale=False, is_index=False)`` builds one plane.
-    Quantized pools keep one scale plane between them, beside the K data
-    (``ops.attention.QuantKV``).  A node with sparse selection brings a
-    third aval, its ``index``: one more plane, a row a page."""
+def _node_pools(leaves, make):
+    """A node's page pools from its probed avals ``leaves``: ``make(aval,
+    is_scale=False, is_index=False)`` builds one plane.  An attention node
+    brings its K and V avals: quantized pools keep one scale plane between
+    them, beside the K data (``ops.attention.QuantKV``); a node with sparse
+    selection brings a third aval, its index: one more plane, a row a page.
+    A latent node brings one aval, its one plane."""
     from .ops.attention import QuantKV
 
+    if len(leaves) == 1:
+        return (make(leaves[0]),)
+    kc, vc, *index = leaves
     more = tuple(make(a, is_index=True) for a in index)
     if isinstance(kc, QuantKV):
         return (QuantKV(make(kc.data), make(kc.scale, is_scale=True)),
@@ -238,9 +249,10 @@ def state_ops():
 
 class CacheLayout(NamedTuple):
     """What one stateful node keeps a slot, read off the node at bind
-    time: ``kind`` ("full" | "window" | "state"), its KV heads, and the
-    positions it holds (``capacity``; a "state" node has neither: it keeps
-    one row, whatever the sequence's length); the key and value widths are
+    time: ``kind`` ("full" | "window" | "state" | "latent"), its KV heads,
+    and the positions it holds (``capacity``; a "state" node has neither: it
+    keeps one row, whatever the sequence's length; a "latent" node has no
+    heads: one plane, a row a position); the key and value widths are
     the pools' trailing dims, for a state node the conv tail's and the
     state's own (``DecodePredictor.cache_layouts`` adds them once the shapes
     are probed)."""
@@ -311,7 +323,8 @@ class DecodePredictor:
         KV-cache storage dtype: 'int8', 'float8_e4m3fn' or 'float8_e5m2'
         (per-(token, head) scales, quantize-on-append / dequantize-in-
         kernel).  ``None`` (default) reads ``MXNET_KV_DTYPE``; empty
-        string = full-precision caches.
+        string, or 'bfloat16' by name = full-precision caches: the pools
+        in the type the graph computes in.
     paged : bool, optional
         Store the caches as fixed-size pages in one shared pool per
         attention node with per-slot page tables (traced data — see the
@@ -356,6 +369,8 @@ class DecodePredictor:
         if kv_dtype is None:
             kv_dtype = _config.get("MXNET_KV_DTYPE")
         kv_dtype = (kv_dtype or "").strip().lower()
+        if kv_dtype in _KV_UNQUANTIZED:
+            kv_dtype = ""
         if kv_dtype:
             canonical = _KV_DTYPES.get(kv_dtype)
             if canonical is None:
@@ -396,23 +411,39 @@ class DecodePredictor:
                              "inputs: %s)" % (data_name, free))
         # the stateful nodes, in graph order: DecodeState.caches, the
         # layouts and the groups are indexed by position in this list
+        from .ops.attention import LATENT_OP
+
         self._state_ops = state_ops()
         self._cache_nodes = [n for n in symbol._topo()
                              if not n.is_variable and (
-                                 n.op.name == "dot_product_attention"
+                                 n.op.name in ("dot_product_attention",
+                                               LATENT_OP)
                                  or n.op.name in self._state_ops)]
         self._attn_nodes = [n for n in self._cache_nodes
                             if n.op.name == "dot_product_attention"]
+        latent = [n for n in self._cache_nodes if n.op.name == LATENT_OP]
         # a multi-token-prediction block: the nodes that read the variable
         # MTP_DATA (the token AFTER each position) run after the main head
         # has said what that token is (:meth:`_run`'s ``between``)
         self._late = self._nodes_after(self.MTP_DATA) \
             if self.MTP_DATA in free and len(symbol._outputs) > 1 else None
         self._heads = self._head_regions()
-        if not self._attn_nodes:
+        if not self._attn_nodes and not latent:
             raise MXNetError("symbol has no dot_product_attention node; "
                              "nothing to cache — use Predictor")
-        if len(self._cache_nodes) > len(self._attn_nodes):
+        if latent and self._kv_dtype is not None:
+            raise MXNetError(
+                "a graph with %s nodes keeps its latent plane in the serving "
+                "type (kv_dtype %r asked): a quantized pool's scales are a "
+                "row a (position, KV head) (ops.attention.QuantKV), and a "
+                "latent row has no heads" % (LATENT_OP, str(self._kv_dtype)))
+        if latent and mesh is not None:
+            raise MXNetError(
+                "a graph with %s nodes is served on one device: "
+                "parallel.tp_rules.kv_pool_pspec shards a pool's trailing "
+                "dimension by head groups, and a latent row has none"
+                % LATENT_OP)
+        if len(self._cache_nodes) > len(self._attn_nodes) + len(latent):
             recurrent = "/".join(sorted({
                 n.op.name for n in self._cache_nodes
                 if n.op.name in self._state_ops}))
@@ -675,6 +706,8 @@ class DecodePredictor:
         ring has to hold the chunk and the window before its first
         query.  Every other attention node, a window node of a dense
         predictor included, keeps ``cache_len`` (its mask does the rest).
+        A latent node keeps ``cache_len`` rows in one plane: it joins the
+        group of that capacity, the full nodes' where the graph has them.
         A state node keeps no positions (capacity 0): its group comes
         last."""
         from .ops import attention as _attn
@@ -685,6 +718,10 @@ class DecodePredictor:
             "window" if cap < self._cache_len else "full"
         self._layouts = []
         for n in self._cache_nodes:
+            if n.op.name == _attn.LATENT_OP:
+                self._layouts.append(CacheLayout("latent", 0,
+                                                 self._cache_len))
+                continue
             if n.op.name != "dot_product_attention":
                 self._layouts.append(CacheLayout("state", 0, 0))
                 continue
@@ -709,7 +746,8 @@ class DecodePredictor:
 
     def attn_walk(self, slots):
         """``[(capacity, block)]``, one entry per attention node that keeps
-        the whole context in pages: the width of the blocks the decode
+        the whole context in pages (as keys and values, or as latent rows:
+        one walk serves both): the width of the blocks the decode
         step over ``slots`` slots walks its view by
         (``ops.attention.live_block_plan``, from the shapes the step
         itself shows it), or the capacity where the view is gathered
@@ -718,7 +756,7 @@ class DecodePredictor:
 
         out = []
         for layout, node in zip(self._layouts, self._cache_nodes):
-            if not self._paged or layout.kind != "full":
+            if not self._paged or layout.kind not in ("full", "latent"):
                 continue
             cap, pt = layout.capacity, self._page_tokens
             plan = _attn.live_block_plan(
@@ -825,9 +863,13 @@ class DecodePredictor:
         positions, or ``pages`` state rows.  The scale plane a node's two
         quantized pools share (``is_scale``) is a row a page, (pages,
         page_tokens * 2 * H_kv): ``ops.attention.QuantKV``; so is the index
-        of a node with sparse selection (``is_index``), (pages, H_kv * D)."""
+        of a node with sparse selection (``is_index``), (pages, H_kv * D),
+        and a latent node's one plane, (pages, page_tokens * (rank + rope))."""
         if self._layouts[ai].kind == "state":
             return (pages,) + tuple(aval.shape[1:])
+        if self._layouts[ai].kind == "latent":
+            # a page a row: ops.attention, the latent section's header
+            return (pages, self._page_tokens * aval.shape[2])
         if is_index:
             return (pages, aval.shape[2])
         if is_scale:
@@ -1142,7 +1184,8 @@ class DecodePredictor:
             if late and id(node) in late:
                 if between is None and caches is not None:
                     if not node.is_variable and (
-                            node.op.name == "dot_product_attention"
+                            node.op.name in ("dot_product_attention",
+                                             _attn.LATENT_OP)
                             or node.op.name in self._state_ops):
                         new_caches.append(caches[ci])
                         ci += 1
@@ -1269,6 +1312,24 @@ class DecodePredictor:
                                                        **extra)]
                         paths.add(_attn.DECODE_PATH["last"])
                         new_caches.append((kc, vc))
+                elif opname == _attn.LATENT_OP:
+                    # one plane of rows a position; which of the op's forms
+                    # a call takes follows from its own shapes
+                    ai = ci
+                    ci += 1
+                    if caches is None:
+                        out, rows = _attn.latent_mix(attrs, *ins, pos0=pos0)
+                        carried = (self._fill_cache(rows),)
+                    else:
+                        tbl = tables[self._group_of[ai]] \
+                            if isinstance(tables, tuple) else tables
+                        out, plane = _attn.latent_mix(
+                            attrs, *ins, cache=caches[ai][0], table=tbl,
+                            pos0=pos0, active=active, valid=valid,
+                            mesh_active=self._mesh is not None)
+                        carried = (plane,)
+                    outs = [out]
+                    new_caches.append(carried)
                 elif opname in self._state_ops:
                     op = self._state_ops[opname]
                     ai = ci
@@ -1898,7 +1959,7 @@ class DecodePredictor:
                                                is_index),
                               aval.dtype), is_scale=is_scale or is_index)
 
-            pools.append(_pool_pair(*leaves[:2], pool_of, *leaves[2:]))
+            pools.append(_node_pools(leaves, pool_of))
         self._paged_lens = np.zeros(slots, np.int64)
         state = DecodeState(tuple(pools), jnp.zeros((slots,), jnp.int32),
                             jnp.zeros((slots, 1), jnp.int32))
@@ -2012,7 +2073,7 @@ class DecodePredictor:
                 pools.append(
                     tuple(make(a) for a in leaves)
                     if self._layouts[ai].kind == "state"
-                    else _pool_pair(*leaves[:2], make, *leaves[2:]))
+                    else _node_pools(leaves, make))
             return tuple(pools)
 
         caches = build(lambda ai, a, is_scale=False, is_index=False:
@@ -2734,8 +2795,8 @@ class DecodePredictor:
         kv = [pair for l, pair in zip(self._layouts, state.caches)
               if l.kind != "state"]
         dtypes = set()
-        for kc, vc, *_ in kv:
-            for c in (kc, vc):
+        for planes in kv:
+            for c in planes[:2]:
                 dtypes.add(str((c.data if isinstance(c, QuantKV)
                                 else c).dtype))
         meta = {"cache_bytes": self.cache_bytes(state),
@@ -2754,8 +2815,8 @@ class DecodePredictor:
             meta["num_kv_heads"] = int(self._grouped_kv_heads)
             meta["attn_dims"] = [dict(d) for d in self._attn_dims]
             widths = set()
-            for kc, vc, *_ in kv:
-                for c in (kc, vc):
+            for planes in kv:
+                for c in planes[:2]:
                     widths.add(int((c.data if isinstance(c, QuantKV)
                                     else c).shape[2]))
             meta["cache_kv_dims"] = sorted(widths)
@@ -3270,6 +3331,14 @@ class DecodeServer:
             "sparse selection in a decode step: those a row attended "
             "(chosen) and those its context holds (live)",
             labels=("kind",))
+        self._m_latent_rows = _obs.registry.counter(
+            "mx_attn_latent_rows_total",
+            "(query slot, cached position, LatentAttention node) triples a "
+            "dispatch attended, by the form it took: a decode step's live "
+            "rows (absorbed), a prefill chunk's context (expanded)",
+            labels=("form",))
+        self._latent_nodes = sum(
+            l.kind == "latent" for l in getattr(predictor, "_layouts", ()))
         nodes_of = getattr(predictor, "state_nodes", lambda counts: 0)
         self._ssm_nodes = nodes_of("ssm_rows")
         self._linattn_nodes = nodes_of("linattn_rows")
@@ -3411,6 +3480,20 @@ class DecodeServer:
         self._m_attn_blocks.labels(kind="live").inc(live)
         self._m_attn_blocks.labels(kind="view").inc(view)
         note.update(attn_blocks_live=live, attn_blocks_view=view)
+
+    def _note_latent_rows(self, rows, lens, note=None):
+        """Count the cached positions the latent nodes of one dispatch
+        attend, from the host's own lengths: ``rows`` query rows a slot
+        say which form it took (``ops.attention.latent_form``), ``lens`` the
+        positions each of its slots holds once its rows are appended.  A
+        decode step's count also goes into ``note`` as ``latent_rows``.
+        Called where the graph has such nodes (``_latent_nodes``)."""
+        from .ops.attention import latent_form
+
+        n = self._latent_nodes * int(np.sum(lens))
+        self._m_latent_rows.labels(form=latent_form(rows)).inc(n)
+        if note is not None:
+            note["latent_rows"] = n
 
     def _note_accept(self, proposed, accepted):
         """One slot's speculative window accounted."""
@@ -4230,6 +4313,8 @@ class DecodeServer:
                 if moe:
                     cur["moe"].append(("chunk", moe[0]))
                 self._m_ssm_chunk_tokens.inc(int(n) * self._ssm_nodes)
+                if self._latent_nodes:
+                    self._note_latent_rows(self._chunk_w, p["pos"] + n)
                 ps["state"] = state = DecodeState(
                     caches, state.lens, state.tok, draft=state.draft,
                     draft_probs=state.draft_probs)
@@ -4344,6 +4429,10 @@ class DecodeServer:
             for rec in active.values():
                 rec["unread"] += 1      # at least: what leave_due counts on
             self._note_attn_blocks(slot_lens, act_mask, cur["note"])
+            if self._latent_nodes:
+                self._note_latent_rows(
+                    1 + self._mtp, np.where(act_mask > 0, slot_lens + 1, 0),
+                    cur["note"])
             self._note_step(spec=self._mtp)
             slot_lens += (1 + self._mtp) * act_mask.astype(np.int64)
             leave_due()

@@ -61,7 +61,8 @@ model of the family is a configuration file and no code:
 
 * ``layer_types[l]`` (``"sliding_attention"`` | ``"full_attention"``) and
   ``mlp_layer_types[l]`` (``"dense"`` | ``"sparse"``): the same two choices
-  under the names newer configurations give them; ``rope_parameters``
+  under the names newer configurations give them (``first_k_dense_replace``
+  k likewise: layers k .. take the experts); ``rope_parameters``
   (``{"rope_theta": ...}``) likewise for ``rope_theta``;
 * ``attn_qk_norm``: every q and k head is normed by an RMSNorm with one
   learned gain of ``head_dim`` a layer (``_q_norm`` / ``_k_norm``), before
@@ -81,6 +82,21 @@ model of the family is a configuration file and no code:
   Embedding and head are the main model's own matrices.  The symbol is
   then a group of two outputs, the main softmax first.
 
+* ``kv_lora_rank`` > 0: every attention is multi-head latent attention
+  (``ops.attention.LATENT_OP``; DeepSeek-V2's keys).  The query goes through
+  a rank of ``q_lora_rank`` with an RMSNorm between (``_q_a``, ``_q_a_norm``,
+  ``_q_b``; a ``q_lora_rank`` of 0, one matrix, is refused until a
+  configuration brings it) to heads of ``qk_nope_head_dim +
+  qk_rope_head_dim``; ``_kv_a`` gives the latent of ``kv_lora_rank``, normed
+  (``_kv_a_norm``), and ONE rotary key part of ``qk_rope_head_dim`` for all
+  heads; the node holds the up-projection to every head's ``qk_nope_head_dim``
+  key dims and ``v_head_dim`` values (``_latt_kv_b_weight``).  Its rotation
+  reads ``rope_parameters`` whole: ``rope_type`` "yarn" (``factor``,
+  ``beta_fast``, ``beta_slow``, ``original_max_position_embeddings``,
+  ``mscale``, ``mscale_all_dim``) and ``llama_4_scaling_beta`` (the query x 1
+  + beta ln(1 + floor(p / original_max)));  ``rope_interleave``: rotated
+  pairs are adjacent dims (false is refused likewise).
+
 ``hybrid_layer_pattern`` and ``moe_layer_freq`` default to zeros: full
 attention and a dense MLP in every layer.  Entries ``first_layer ..
 first_layer + num_layers - 1`` of the per-layer lists are built.
@@ -98,6 +114,7 @@ _SPARSE = {LAYER_ATTR: "attn_sparse"}
 _MLP = {LAYER_ATTR: "linear"}
 _HEAD = {LAYER_ATTR: "head_loss"}
 _MTP = {LAYER_ATTR: "mtp"}
+_LATENT = {LAYER_ATTR: "attn_latent"}
 
 
 def rotary_dims(head_dim, partial_rotary_factor):
@@ -154,6 +171,55 @@ def attention(data, name, window, hidden, heads, kv_heads, head_dim,
                 flatten=False, name=name + "_gate"), act_type="sigmoid")
     return sym.FullyConnected(att, num_hidden=hidden, no_bias=True,
                               flatten=False, name=name + "_attout")
+
+
+def latent_rope_attrs(rope_parameters, rope_theta):
+    """A published ``rope_parameters`` as the latent-attention node's
+    attributes: the base, YaRN's keys where ``rope_type`` says yarn, and the
+    query temperature's beta."""
+    rp = dict(rope_parameters or {})
+    out = {"rope_theta": float(rp.get("rope_theta", rope_theta))}
+    span = int(rp.get("original_max_position_embeddings", 0) or 0)
+    if rp.get("rope_type", rp.get("type")) == "yarn":
+        out.update(rope_type="yarn", rope_factor=float(rp["factor"]),
+                   beta_fast=float(rp.get("beta_fast", 32)),
+                   beta_slow=float(rp.get("beta_slow", 1)),
+                   mscale=float(rp.get("mscale", 1)),
+                   mscale_all_dim=float(rp.get("mscale_all_dim", 0)))
+    if float(rp.get("llama_4_scaling_beta", 0) or 0):
+        out["query_scaling_beta"] = float(rp["llama_4_scaling_beta"])
+    if span:
+        out["original_max_position_embeddings"] = span
+    return out
+
+
+def latent_attention(data, name, hidden, heads, q_rank, kv_rank, nope, rope,
+                     v_head_dim, eps, interleave, rope_attrs, layer=None):
+    """Multi-head latent attention: ``hidden`` -> the low-rank query and the
+    latent with its rotary key part -> ``ops.attention.LATENT_OP`` ->
+    ``hidden``."""
+    fc = lambda x, width, part: sym.FullyConnected(
+        x, num_hidden=width, no_bias=True, flatten=False, name=name + part)
+    if not q_rank or not interleave:
+        raise ValueError(
+            "latent attention with q_lora_rank %r, rope_interleave %r: a "
+            "query of one matrix and half-split rotary pairs are not built "
+            "(no configuration here brings them)" % (q_rank, interleave))
+    q = fc(sym.RMSNorm(fc(data, q_rank, "_q_a"), eps=eps,
+                       name=name + "_q_a_norm"),
+           heads * (nope + rope), "_q_b")
+    kv = fc(data, kv_rank + rope, "_kv_a")
+    with AttrScope(**(layer or _LATENT)):
+        latent = sym.slice_axis(kv, axis=2, begin=0, end=kv_rank)
+        key_rope = sym.slice_axis(kv, axis=2, begin=kv_rank,
+                                  end=kv_rank + rope)
+    latent = sym.RMSNorm(latent, eps=eps, name=name + "_kv_a_norm")
+    with AttrScope(**(layer or _LATENT)):
+        att = sym.LatentAttention(
+            q, latent, key_rope, num_heads=heads, qk_nope_head_dim=nope,
+            qk_rope_head_dim=rope, v_head_dim=v_head_dim,
+            kv_lora_rank=kv_rank, name=name + "_latt", **rope_attrs)
+    return fc(att, hidden, "_attout")
 
 
 def lightning_mixer(data, name, hidden, heads, head_dim, theta, eps,
@@ -242,7 +308,9 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                rope_parameters=None, attn_qk_norm=False,
                full_attn_use_rope=True, n_shared_experts=0,
                routed_scaling_factor=1.0, num_nextn_predict_layers=0,
-               **kwargs):
+               q_lora_rank=0, kv_lora_rank=0, qk_nope_head_dim=0,
+               qk_rope_head_dim=0, rope_interleave=True,
+               first_k_dense_replace=None, **kwargs):
     """data (B, T) int tokens -> softmax over the vocabulary at every
     position (``softmax_label`` (B, T) next tokens, pad = -1 ignored)."""
     heads = int(num_attention_heads)
@@ -257,6 +325,9 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
     if mlp_layer_types:
         moe_layer_freq = tuple(int(kind == "sparse")
                                for kind in mlp_layer_types)
+    if first_k_dense_replace is not None and int(n_routed_experts):
+        moe_layer_freq = tuple(int(i >= int(first_k_dense_replace))
+                               for i in range(len(zeros)))
     if rope_parameters:
         rope_theta = dict(rope_parameters).get("rope_theta", rope_theta)
     if int(num_nextn_predict_layers) not in (0, 1):
@@ -283,6 +354,13 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
             first_held=int(first_held), name=name + "_moe", **moe_more)
 
     def attend(normed, name, windowed, selects=False, layer=None):
+        if int(kv_lora_rank or 0):
+            return latent_attention(
+                normed, name, hidden_size, heads, int(q_lora_rank or 0),
+                int(kv_lora_rank), int(qk_nope_head_dim),
+                int(qk_rope_head_dim), v_head_dim, layernorm_epsilon,
+                rope_interleave,
+                latent_rope_attrs(rope_parameters, rope_theta), layer=layer)
         return attention(
             normed, name, window=int(sliding_window) if windowed else 0,
             hidden=hidden_size, heads=heads,

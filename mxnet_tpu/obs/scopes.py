@@ -38,6 +38,7 @@ LAYER_OF_OP = {
     "RMSNorm": "norm",
     "SelectiveSSM": "ssm",
     "LightningAttention": "linattn",
+    "LatentAttention": "attn_latent",
 }
 # every value a scope's <layer> may take: the table's, "other" for op
 # kinds it does not list, and the two fixed scopes of the train step
@@ -60,7 +61,12 @@ SUBSCOPES = ("kv_append", "kv_gather", "kv_dequant", "scores", "rope",
              # sequence ("chunk"; "step" and "gate_norm" as above).  Sparse
              # selection: the index's rows written, and the scoring, pooling
              # and top-k that choose a row's blocks
-             "chunk", "index_append", "select")
+             "chunk", "index_append", "select",
+             # latent attention: the cached rows turned back into per-head
+             # keys and values ("expand", the chunk's form), and the key
+             # up-projection folded into the query with the value one after
+             # the weighted sum ("absorb", the decode row's)
+             "expand", "absorb")
 # an instruction no mx.<layer> scope reaches (compiler-made copies,
 # casts between the step's phases)
 UNSCOPED = "unscoped"

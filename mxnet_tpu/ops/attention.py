@@ -987,7 +987,8 @@ def decode_kernel_selected(q_shape, k_pool, v_pool, table_shape, num_heads,
 def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
                         scale, num_kv_heads, block, group, sink=None,
                         value_scale=1.0, layer="attn", chosen=None,
-                        kernel=None):
+                        kernel=None, gather=None, hdv=None,
+                        page_tokens=None):
     """:func:`paged_gather` + :func:`_sdpa_cache` over the blocks the slots
     have reached, and no others.
 
@@ -1017,12 +1018,19 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
     the list, the block's pages copied from the pools into fast memory by
     the kernel itself, no gathered view written, dead rows neither visited
     nor read.  The list before it and the combine after it are the loop's
-    own."""
+    own.
+
+    ``gather(ids (rows, pages a block))`` -> ``(k_blk, v_blk)`` stands in
+    for :func:`paged_gather_kv` where a node's pages are not keys and values
+    as the products read them (a latent plane, :func:`latent_attend`): what
+    it returns is attended as a block's keys and values are, ``hdv`` wide a
+    head, and ``page_tokens`` says how many positions a page of such a plane
+    holds."""
     import jax
     import jax.numpy as jnp
 
     b, tq, _ = q.shape
-    pt = _plane(k_pool).shape[1]
+    pt = page_tokens or _plane(k_pool).shape[1]
     ppb = block // pt
     cap = table.shape[1] * pt
     nb = -(-table.shape[1] // ppb)
@@ -1044,7 +1052,7 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         blk = jnp.clip(flat - first[slot], 0, nb - 1)
         pages = pages.reshape(b, nb, ppb)[slot, blk]          # (rows, ppb)
         steps = -(-ends[-1] // group)
-    hdv = _plane(v_pool).shape[2] // (int(num_kv_heads) or num_heads)
+    hdv = hdv or _plane(v_pool).shape[2] // (int(num_kv_heads) or num_heads)
     if kernel is not None:
         from . import pallas_decode as _pd
 
@@ -1094,7 +1102,8 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         take = lambda x: jax.lax.dynamic_slice_in_dim(x, at, group)
         rows_of, ids = take(slot), take(pages)
         with _scope(layer, "kv_gather"):
-            k_blk, v_blk = paged_gather_kv(k_pool, v_pool, ids)
+            k_blk, v_blk = paged_gather_kv(k_pool, v_pool, ids) \
+                if gather is None else gather(ids)
         allow = {}
         if chosen is not None:
             mask, width = chosen
@@ -1607,6 +1616,378 @@ def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
                        **_extras(window, sink, value_scale, layer))
 
 
+# ---------------------------------------------------------------------------
+# Multi-head latent attention (DeepSeek-V2, section 2.1): a position is cached
+# as ONE row shared by all heads, ``[c ; rot(k_rope)]``: the normed latent of
+# ``kv_lora_rank`` values from which every head's key (without positions) and
+# value are linear maps (``W_kvb``, (H * (nope + v), rank)), and one rotated
+# key part of ``qk_rope_head_dim``.  A head's score is ``scale * (q_nope .
+# W_k c + q_rope . k_rope)``.  :func:`latent_mix` is the one mathematics in
+# three forms, as ``ops.ssm.mix`` and ``ops.linattn.mix`` have theirs:
+#
+# * a whole sequence from nothing (``cache`` None): the rows are expanded
+#   into per-head keys and values and attended causally; the rows are
+#   returned for the caller to keep;
+# * rows against the cache, EXPANDED (a prefill chunk): the cached rows of
+#   each live block are turned back into per-head keys and values inside the
+#   walk over the live blocks (never a view of the whole table), and
+#   attended as any keys and values are;
+# * rows against the cache, ABSORBED (a decode row): ``W_k`` is folded into
+#   the query (``q_abs = W_k^T q_nope``, rank wide) and ``W_v`` applied after
+#   the weighted sum, so the pages are read as they lie: one "key" of rank +
+#   rope and one "value" (its first rank values) a position for all heads.
+#
+# Paged, the plane is (P, page_tokens * (rank + rope)), a page a row, as a
+# quantized pool's scale plane is: a row of 320 values is no whole number of
+# 128 lanes, and XLA:TPU lays a (P, page_tokens, 320) array out pages-minor
+# to save the padding, then converts the whole pool to rows-minor and back
+# around every scatter and gather (seen in the optimized HLO of the decode
+# step compiled for a described v5e, PR 50: two copies of 0.85 GB a layer a
+# tick).  A page's row of page_tokens * 320 is whole lanes at page_tokens 16;
+# rows are written by :func:`_append_scales`' read-select-write of the few
+# pages a call touches, and what a gather brings is reshaped to positions, a
+# copy of the gathered block and never of the pool.
+#
+# Which of the two cached forms a call takes follows from its rows a slot
+# (:data:`LATENT_EXPAND_ROWS`): a (query row, cached position) pair costs
+# ``2 (rank + rope) + 2 rank`` FLOP a head absorbed against ``2 (nope + rope)
+# + 2 v`` expanded, and expanding costs ``2 rank (nope + v)`` a head a
+# position once a call.
+# ---------------------------------------------------------------------------
+
+LATENT_OP = "LatentAttention"
+# rows a slot from which the cached rows are expanded: with r = rank, e =
+# rope, n = nope, v: absorbed t (4 r + 2 e) = expanded t (2 n + 2 e + 2 v) +
+# 2 r (n + v) at t = r (n + v) / (2 r - n - v), 154 at 256 / 64 / 128; the
+# expanded products are also the better fed (a head's own contraction of n +
+# e against t rows, where the absorbed one keeps H x t rows against one key),
+# so the switch is taken somewhat below.  RECKONED from those counts, NOT
+# MEASURED: the one cell that runs the op calls it with 1 row and with 2048,
+# and no chip reading lies near the flip
+LATENT_EXPAND_ROWS = 96
+
+
+class LatentSpec(NamedTuple):
+    """The sizes and constants of a :data:`LATENT_OP` node, read off its
+    attributes (:func:`latent_spec`)."""
+
+    heads: int
+    nope: int
+    rope: int
+    v: int
+    rank: int
+    inv_freq: tuple     # turn a position of pair (2j, 2j + 1), rope / 2
+    trig_scale: float   # cos and sin are multiplied by this
+    scale: float        # the softmax's
+    temp_beta: float    # the query x (1 + beta ln(1 + floor(p / temp_span)))
+    temp_span: int
+    layer: str
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's attention factor ``0.1 mscale ln(factor) + 1`` (1 at factor
+    <= 1)."""
+    return 1.0 if factor <= 1 else 0.1 * float(mscale) * np.log(factor) + 1.0
+
+
+def yarn_ramp(dim, theta, beta_fast, beta_slow, original_max):
+    """``(lo, hi)``: the pairs between which YaRN blends interpolated and
+    original frequencies: ``lo = floor(corr(beta_fast))``, ``hi =
+    ceil(corr(beta_slow))``, ``corr(n) = dim ln(original_max / (2 pi n)) /
+    (2 ln theta)``, held to [0, dim - 1]."""
+    corr = lambda n: dim * np.log(original_max / (2 * np.pi * n)) \
+        / (2 * np.log(theta))
+    return (max(int(np.floor(corr(beta_fast))), 0),
+            min(int(np.ceil(corr(beta_slow))), dim - 1))
+
+
+def rope_frequencies(dim, theta, rope_type="default", factor=1.0,
+                     beta_fast=32.0, beta_slow=1.0, original_max=0):
+    """The ``dim / 2`` inverse frequencies of a rotation (float64 numpy):
+    ``theta^(-2j / dim)``, and under ``rope_type`` "yarn" the blend ``(1 -
+    g_j) theta_j / factor + g_j theta_j`` with ``g_j = 1 - clip((j - lo) /
+    (hi - lo), 0, 1)`` over :func:`yarn_ramp`'s ``lo`` and ``hi``."""
+    j = np.arange(dim // 2, dtype=np.float64)
+    inv = float(theta) ** (-2.0 * j / dim)
+    if rope_type != "yarn":
+        return inv
+    lo, hi = yarn_ramp(dim, theta, beta_fast, beta_slow, original_max)
+    keep = 1.0 - np.clip((j - lo) / max(hi - lo, 1e-3), 0.0, 1.0)
+    return (1.0 - keep) * inv / float(factor) + keep * inv
+
+
+def latent_spec(attrs):
+    """The :class:`LatentSpec` of a node's attributes."""
+    g = lambda k, d: attrs.get(k, d)
+    nope, rope_dim = int(attrs["qk_nope_head_dim"]), \
+        int(attrs["qk_rope_head_dim"])
+    yarn = g("rope_type", "default") == "yarn"
+    factor = float(g("rope_factor", 1.0)) if yarn else 1.0
+    inv = rope_frequencies(
+        rope_dim, float(g("rope_theta", 10000.0)), g("rope_type", "default"),
+        factor, float(g("beta_fast", 32.0)), float(g("beta_slow", 1.0)),
+        int(g("original_max_position_embeddings", 0)))
+    all_dim = float(g("mscale_all_dim", 0.0))
+    trig = yarn_mscale(factor, float(g("mscale", 1.0))) \
+        / yarn_mscale(factor, all_dim) if yarn else 1.0
+    scale = float(nope + rope_dim) ** -0.5
+    if yarn and all_dim:
+        scale *= yarn_mscale(factor, all_dim) ** 2
+    return LatentSpec(
+        int(attrs["num_heads"]), nope, rope_dim, int(attrs["v_head_dim"]),
+        int(attrs["kv_lora_rank"]), tuple(float(x) for x in inv),
+        float(trig), float(scale),
+        float(g("query_scaling_beta", 0.0)),
+        int(g("original_max_position_embeddings", 0)),
+        attrs.get("__layer__") or "attn_latent")
+
+
+def latent_rotate(x, positions, heads, spec):
+    """``x`` (B, t, heads * rope) turned at ``positions`` (B, t) by the
+    node's frequencies, dims (2j, 2j + 1) a pair.  Float32 inside, ``x``'s
+    type out."""
+    import jax.numpy as jnp
+
+    b, t, _ = x.shape
+    with _scope(spec.layer, "rope"):
+        ang = jnp.asarray(positions, jnp.float32)[:, :, None, None] \
+            * jnp.asarray(spec.inv_freq, jnp.float32)
+        cos = jnp.cos(ang) * spec.trig_scale
+        sin = jnp.sin(ang) * spec.trig_scale
+        xh = x.reshape(b, t, heads, spec.rope).astype(jnp.float32)
+        x1, x2 = xh[..., 0::2], xh[..., 1::2]
+        out = jnp.stack([x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1)
+        return out.reshape(b, t, heads * spec.rope).astype(x.dtype)
+
+
+def latent_query_scale(positions, spec):
+    """The query's temperature at ``positions``: ``1 + beta ln(1 + floor(p
+    / span))``, float32; None where the node has none."""
+    import jax.numpy as jnp
+
+    if not spec.temp_beta or not spec.temp_span:
+        return None
+    steps = jnp.floor_divide(jnp.asarray(positions, jnp.int32),
+                             spec.temp_span).astype(jnp.float32)
+    return 1.0 + spec.temp_beta * jnp.log1p(steps)
+
+
+def _latent_weights(w_kvb, spec):
+    """``W_kvb`` (H * (nope + v), rank) as ``(W_k (H, nope, rank), W_v (H,
+    v, rank))``."""
+    w = w_kvb.reshape(spec.heads, spec.nope + spec.v, spec.rank)
+    return w[:, :spec.nope], w[:, spec.nope:]
+
+
+def latent_expand(rows, w_kvb, spec):
+    """Cached rows (N, C, rank + rope) -> every head's keys (N, C, H *
+    (nope + rope)) and values (N, C, H * v): the latent through ``W_kvb``,
+    the one rotated part beside every head's own."""
+    import jax.numpy as jnp
+
+    n, c, _ = rows.shape
+    h = spec.heads
+    with _scope(spec.layer, "expand"):
+        kv = jnp.einsum("ncr,hdr->nchd", rows[..., :spec.rank],
+                        w_kvb.reshape(h, spec.nope + spec.v, spec.rank)
+                        .astype(rows.dtype))
+        k = jnp.concatenate(
+            [kv[..., :spec.nope], jnp.broadcast_to(
+                rows[:, :, None, spec.rank:], (n, c, h, spec.rope))], axis=-1)
+        return (k.reshape(n, c, h * (spec.nope + spec.rope)),
+                kv[..., spec.nope:].reshape(n, c, h * spec.v))
+
+
+def _note_latent(form):
+    from .. import obs as _obs
+
+    _obs.registry.counter(
+        "mx_attn_latent_dispatch_total",
+        "LatentAttention nodes traced against a cache, by the form they took",
+        labels=("form",)).labels(form=form).inc()
+
+
+def latent_form(rows):
+    """``"expanded"`` or ``"absorbed"``: the form a call with ``rows`` query
+    rows a slot takes against the cache."""
+    return "expanded" if int(rows) >= LATENT_EXPAND_ROWS else "absorbed"
+
+
+def latent_pages(plane, ids, width):
+    """The pages ``ids`` (N, M) of a latent plane (P, page_tokens * width)
+    as rows a position: (N, M * page_tokens, width)."""
+    return plane[ids].reshape(ids.shape[0], -1, width)
+
+
+def latent_attend(q_nope, q_rope, cache, table, total_len, w_kvb, spec,
+                  mesh_active=False):
+    """(B, t, H, nope) and (B, t, H, rope) queries, already rotated, against
+    the cached rows: ``cache`` a pool (P, page_tokens * (rank + rope)) read
+    through ``table`` (B, M), or a dense ring (B, C, rank + rope) where
+    ``table`` is None.  ``total_len`` counts the rows appended, the queries'
+    own included.  -> (B, t, H * v), in the form the rows a slot choose
+    (:func:`latent_form`)."""
+    import jax.numpy as jnp
+
+    b, t, h, _ = q_nope.shape
+    form = latent_form(t)
+    _note_latent(form)
+    w_k, w_v = _latent_weights(w_kvb, spec)
+    if form == "expanded":
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        kvh, hdv = h, spec.v
+        blocks = lambda rows: latent_expand(rows, w_kvb, spec)
+    else:
+        with _scope(spec.layer, "absorb"):
+            q_abs = jnp.einsum("bthd,hdr->bthr", q_nope,
+                               w_k.astype(q_nope.dtype))
+        q = jnp.concatenate([q_abs, q_rope], axis=-1)
+        kvh, hdv = 1, spec.rank
+        blocks = lambda rows: (rows, rows[..., :spec.rank])
+    q = q.reshape(b, t, -1)
+    width = spec.rank + spec.rope
+    pt = None if table is None else cache.shape[1] // width
+    plan = None if table is None else live_block_plan(
+        q.shape, table.shape, pt, mesh_active=mesh_active)
+    if plan is not None:
+        out = _attend_live_blocks(
+            q, cache, cache, table, total_len, h, spec.scale, kvh, *plan,
+            layer=spec.layer, hdv=hdv, page_tokens=pt,
+            gather=lambda ids: blocks(latent_pages(cache, ids, width)))
+    else:
+        with _scope(spec.layer, "kv_gather"):
+            k, v = blocks(cache if table is None
+                          else latent_pages(cache, table, width))
+        out = _sdpa_cache(q, k, v, total_len, h, spec.scale,
+                          num_kv_heads=kvh, mesh_active=mesh_active,
+                          layer=spec.layer)
+    if form == "absorbed":
+        with _scope(spec.layer, "absorb"):
+            out = jnp.einsum("bthr,her->bthe",
+                             out.reshape(b, t, h, spec.rank),
+                             w_v.astype(out.dtype)).reshape(b, t, h * spec.v)
+    return out
+
+
+def latent_mix(attrs, q, c, k_rope, w_kvb, cache=None, table=None, pos0=None,
+               active=None, valid=None, mesh_active=False):
+    """``(out (B, t, H * v), rows or cache)``: latent attention over the
+    projected streams in one of the section's three forms.  ``q`` (B, t, H *
+    (nope + rope)) is the up-projected query, ``c`` (B, t, rank) the normed
+    latent, ``k_rope`` (B, t, rope) the shared key part before its rotation,
+    ``w_kvb`` (H * (nope + v), rank).  ``pos0`` (B,) is the first position
+    (0 where None).  ``cache`` None: the sequence is attended by itself and
+    its rows ``[c ; rot(k_rope)]`` (B, t, rank + rope) come back.  Else the
+    rows are appended to ``cache`` first (a pool (P, page_tokens * (rank +
+    rope)) through ``table``, its writes masked by ``active`` and ``valid``
+    as :func:`paged_append` masks them; a dense ring where ``table`` is None) and the rows attend what it
+    then holds; the cache comes back."""
+    import jax.numpy as jnp
+
+    spec = latent_spec(attrs)
+    b, t, _ = q.shape
+    h = spec.heads
+    if q.shape[2] != h * (spec.nope + spec.rope) or c.shape[2] != spec.rank \
+            or k_rope.shape[2] != spec.rope:
+        raise ValueError(
+            "%s: q %s, latent %s, key_rope %s are not (B, T, %d x %d), (B, "
+            "T, %d), (B, T, %d)" % (LATENT_OP, q.shape, c.shape,
+                                    k_rope.shape, h, spec.nope + spec.rope,
+                                    spec.rank, spec.rope))
+    start = jnp.zeros((b,), jnp.int32) if pos0 is None else \
+        jnp.broadcast_to(jnp.asarray(pos0, jnp.int32).reshape(-1), (b,))
+    positions = start[:, None] + jnp.arange(t, dtype=jnp.int32)[None, :]
+    qh = q.reshape(b, t, h, spec.nope + spec.rope)
+    q_nope = qh[..., :spec.nope]
+    q_rope = latent_rotate(qh[..., spec.nope:].reshape(b, t, -1), positions,
+                           h, spec).reshape(b, t, h, spec.rope)
+    temp = latent_query_scale(positions, spec)
+    if temp is not None:
+        with _scope(spec.layer, "rope"):
+            warm = lambda x: (x.astype(jnp.float32)
+                              * temp[:, :, None, None]).astype(x.dtype)
+            q_nope, q_rope = warm(q_nope), warm(q_rope)
+    rows = jnp.concatenate(
+        [c, latent_rotate(k_rope, positions, 1, spec)], axis=-1)
+    if cache is None:
+        k, v = latent_expand(rows, w_kvb, spec)
+        out = _sdpa_extra(
+            jnp.concatenate([q_nope, q_rope], axis=-1).reshape(b, t, -1), k,
+            v, h, True, spec.scale, h, 0, None, 1.0, spec.layer)
+        return out, rows
+    if table is None:
+        cache = cache_append(cache, rows, start, layer=spec.layer)
+    else:
+        with _scope(spec.layer, "kv_append"):
+            cache = _append_scales(
+                cache, table, *_latest(rows, start, table.shape[1]
+                                       * cache.shape[1] // rows.shape[2])[:2],
+                active, valid)
+    return latent_attend(q_nope, q_rope, cache, table, start + t, w_kvb,
+                         spec, mesh_active=mesh_active), cache
+
+
+def _latent_arguments(attrs):
+    return ["query", "latent", "key_rope", "kv_b_weight"]
+
+
+def _latent_shape(attrs, in_shapes, aux_shapes):
+    h = int(attrs["num_heads"])
+    nope, rope_dim = int(attrs["qk_nope_head_dim"]), \
+        int(attrs["qk_rope_head_dim"])
+    v, rank = int(attrs["v_head_dim"]), int(attrs["kv_lora_rank"])
+    lead = tuple(in_shapes[0][:-1])
+    return ([lead + (h * (nope + rope_dim),), lead + (rank,),
+             lead + (rope_dim,), (h * (nope + v), rank)],
+            [lead + (h * v,)], [])
+
+
+def _register_latent():
+    def fcompute(attrs, inputs, aux, octx):
+        return [latent_mix(attrs, *inputs)[0]], list(aux)
+
+    register_op(OpDef(
+        LATENT_OP, fcompute,
+        schema=ParamSchema(
+            Param("num_heads", int, required=True),
+            Param("qk_nope_head_dim", int, required=True,
+                  doc="dims of a head's query and key that take no position"),
+            Param("qk_rope_head_dim", int, required=True,
+                  doc="rotated dims: a head's own in the query, ONE vector a "
+                      "position shared by all heads in the key"),
+            Param("v_head_dim", int, required=True),
+            Param("kv_lora_rank", int, required=True,
+                  doc="width of the cached latent"),
+            Param("rope_theta", float, default=10000.0),
+            Param("rope_type", str, default="default",
+                  doc="'yarn': frequencies blended between theta_j / "
+                      "rope_factor and theta_j (rope_frequencies)"),
+            Param("rope_factor", float, default=1.0),
+            Param("beta_fast", float, default=32.0),
+            Param("beta_slow", float, default=1.0),
+            Param("original_max_position_embeddings", int, default=0,
+                  doc="the window YaRN extends, and the span of the query "
+                      "temperature's steps"),
+            Param("mscale", float, default=1.0),
+            Param("mscale_all_dim", float, default=0.0,
+                  doc="not 0 under yarn: the softmax scale x m(factor, "
+                      "this)^2, and cos / sin x m(factor, mscale) / "
+                      "m(factor, this)"),
+            Param("query_scaling_beta", float, default=0.0,
+                  doc="the query after rotation x (1 + beta ln(1 + floor(p "
+                      "/ original_max_position_embeddings))); 0 = none"),
+        ),
+        num_inputs=4, arguments=_latent_arguments,
+        infer_shape=_latent_shape,
+        doc="Multi-head latent attention over a projected query (B, T, H * "
+            "(nope + rope)), the normed latent (B, T, rank), the shared "
+            "rotary key part (B, T, rope) and the up-projection W_kvb (H * "
+            "(nope + v), rank): causal, returns (B, T, H * v).  Stateful in "
+            "serving: DecodePredictor keeps one row of rank + rope a "
+            "position (a 'latent' cache layout) and attends it expanded "
+            "(chunks) or absorbed (decode rows)."))
+
+
 _KV_LAYOUT_WARNED = {"done": False}
 
 
@@ -1695,6 +2076,8 @@ def _attn_shape(attrs, in_shapes, aux_shapes):
 
 
 def register_all():
+    _register_latent()
+
     def _compute_full(attrs, inputs, aux, octx):
         q, k, v, *sink = inputs
         heads = attrs.get("num_heads", 1)

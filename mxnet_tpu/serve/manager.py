@@ -29,7 +29,11 @@ and needs no bookkeeping of its own: the planes of the decode layer's pools
 share the page ids this manager hands out, so the row of an int8 pool's
 scale plane and the row of a sparse-selection node's index of compressed
 keys (``ops.attention.paged_append_index``: one compressed key a KV head a
-page) are allocated when the page is mapped and free when it is freed.  A
+page) are allocated when the page is mapped and free when it is freed.  So
+is a latent node's whole pool (``ops.attention.LATENT_OP``: ONE plane, a
+page a row, no head axis): it is a node of the group whose capacity it has,
+the full nodes' own, and forks, prefix sharing, extract and install move
+its rows with the page, so :func:`why_not` has nothing to refuse for it.  A
 recycled page's stale rows are never read: the slot's length says which
 windows are complete, and a complete window's row has been rewritten.
 

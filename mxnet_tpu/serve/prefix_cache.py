@@ -2,15 +2,19 @@
 
 Matching prompts share K/V pages instead of re-prefilling them: the cache
 maps the tokens of each page-aligned prompt prefix to the page already
-holding its K/V.  Keys are the literal token tuples (collision-free; the
-chains are short at serving scale and the page granularity keeps the dict
-small) in two granularities:
+holding its K/V.  A key is ``(parent, the page's literal tokens)``, where
+``parent`` is the number the entry of the chain's previous page was given (0:
+the empty chain): collision-free, and one page long whatever the chain's
+length.  (Until PR 50 a key was the whole chain's token tuple: publishing a
+prompt of 64k tokens built 4096 tuples of up to 64k tokens each and hashed
+them thrice, 1 to 20 s of host time with the device idle and a gigabyte of
+host memory a prompt.)  Two granularities:
 
-* **full-page entries** — key = ``tokens[:i*page_tokens]`` for each full
-  page a finished prefill produced; a new prompt matches the longest
+* **full-page entries** — one for each full page a finished prefill
+  produced, chained through ``parent``; a new prompt matches the longest
   chain of full pages it starts with;
-* **partial-page entries** — key = (full-page prefix, the final partial
-  page's tokens); they let a prompt whose divergence point is mid-page
+* **partial-page entries** — key = (the full-page prefix's number, the final
+  partial page's tokens); they let a prompt whose divergence point is mid-page
   still share the page holding the common tokens.  The sharer maps the
   page read-only — its first append into it copy-on-write forks it
   (refcount > 1, see ``manager.PagedKVManager.ensure``), which is also
@@ -65,16 +69,21 @@ class PrefixCache:
     def __init__(self, page_tokens, allocator):
         self._pt = int(page_tokens)
         self._alloc = allocator
-        # key -> page id; full keys are token tuples, partial keys are
-        # (full-prefix tuple, partial-tokens tuple).  One OrderedDict so
-        # eviction is a single LRU walk.
+        # key -> page id; a key is (parent node, the page's token tuple):
+        # a full page's content has page_tokens tokens, a partial entry's
+        # fewer.  One OrderedDict so eviction is a single LRU walk.
         self._entries = OrderedDict()
-        # full-prefix tuple -> {content tuple: key}: every stored
-        # continuation of a full-page chain — partial entries (content =
-        # the partial tokens) AND the final page of one-page-deeper full
-        # chains (content = that page's pt tokens).  The radix frontier:
-        # match() takes the longest common token prefix of the remaining
-        # prompt against these contents.
+        # key -> (node, digest, hasher): a full-page entry's own node
+        # number (what its continuations name as parent), the chain_hash of
+        # the whole chain up to and including it, and the running hash it
+        # was read from (a continuation extends a copy); a partial entry's
+        # (None, the chain_hash of its full-page prefix, None)
+        self._meta = {}
+        self._next_node = 1
+        # parent node -> {content tuple: key}: every stored continuation of
+        # a full-page chain — partial entries AND the next full page.  The
+        # radix frontier: match() takes the longest common token prefix of
+        # the remaining prompt against these contents.
         self._children = {}
         # page id -> set of keys holding it (wrap recycling invalidates
         # a page's entries through this reverse map)
@@ -102,10 +111,30 @@ class PrefixCache:
 
     @staticmethod
     def _tokens(prompt):
-        return tuple(int(t) for t in np.asarray(prompt).reshape(-1))
+        return np.ascontiguousarray(np.asarray(prompt, np.int64).reshape(-1))
 
     def _touch(self, key):
         self._entries.move_to_end(key)
+
+    def _walk(self, toks):
+        """``(keys, parent)``: the keys of the longest chain of cached full
+        pages ``toks`` starts with, and the node of the last (0: none)."""
+        keys, parent = [], 0
+        if (0, tuple(toks[:self._pt].tolist())) not in self._entries:
+            return keys, parent     # the common miss: nothing converted
+        for content in self._full_pages(toks):
+            key = (parent, tuple(content))
+            if key not in self._entries:
+                break
+            keys.append(key)
+            parent = self._meta[key][0]
+        return keys, parent
+
+    def _full_pages(self, toks):
+        """The full pages of ``toks`` as lists of tokens (one conversion for
+        the whole prompt, not one a page)."""
+        n = len(toks) // self._pt
+        return toks[:n * self._pt].reshape(n, self._pt).tolist()
 
     # ------------------------------------------------------------------
     def match(self, prompt):
@@ -121,17 +150,11 @@ class PrefixCache:
         cap = max(len(toks) - 1, 0)
         self.lookups += 1
         self.lookup_tokens += max(cap, 0)
-        pages = []
-        n_full = 0
-        while (n_full + 1) * self._pt <= len(toks):
-            key = toks[:(n_full + 1) * self._pt]
-            page = self._entries.get(key)
-            if page is None:
-                break
+        keys, parent = self._walk(toks)
+        for key in keys:
             self._touch(key)
-            pages.append(page)
-            n_full += 1
-        matched = n_full * self._pt
+        pages = [self._entries[key] for key in keys]
+        matched = len(keys) * self._pt
         # radix extension at the frontier: the longest common TOKEN
         # prefix between the remaining prompt and any stored
         # continuation of the matched chain — a partial entry, or the
@@ -139,10 +162,9 @@ class PrefixCache:
         # the walk above already ruled out).  Divergence mid-page still
         # shares the page up to the divergence point; the length mask
         # hides the tail and the first write there forks (COW).
-        rest = toks[matched:]
+        rest = toks[matched:matched + self._pt].tolist()
         best_lcp, best_key, best_content = 0, None, None
-        for content, key in self._children.get(toks[:matched],
-                                               {}).items():
+        for content, key in self._children.get(parent, {}).items():
             lcp = 0
             for a, b in zip(content, rest):
                 if a != b:
@@ -169,6 +191,14 @@ class PrefixCache:
         return matched, pages
 
     # ------------------------------------------------------------------
+    def _add(self, key, page, meta):
+        self._alloc.incref(page)
+        self._entries[key] = page
+        self._meta[key] = meta
+        self._by_page.setdefault(page, set()).add(key)
+        self._children.setdefault(key[0], {})[key[1]] = key
+        self._content_version += 1
+
     def insert(self, prompt, prompt_len, pages):
         """Publish a finished prefill's prompt pages.
 
@@ -178,32 +208,29 @@ class PrefixCache:
         (first-in wins — the duplicate page stays slot-owned only).
         """
         toks = self._tokens(prompt)[:int(prompt_len)]
-        n_full = len(toks) // self._pt
-        for i in range(n_full):
-            key = toks[:(i + 1) * self._pt]
-            if key in self._entries:
-                self._touch(key)
-                continue
-            page = pages[i]
-            self._alloc.incref(page)
-            self._entries[key] = page
-            self._by_page.setdefault(page, set()).add(key)
-            self._children.setdefault(toks[:i * self._pt],
-                                      {})[key[i * self._pt:]] = key
-            self._content_version += 1
-        tail = toks[n_full * self._pt:]
-        if tail and n_full < len(pages):
-            full_key = toks[:n_full * self._pt]
-            key = (full_key, tail)
+        width = self._pt * toks.itemsize
+        raw = toks.tobytes()
+        parent, digest = 0, chain_hash(())
+        running = hashlib.blake2b(digest_size=8)
+        n_full = 0
+        for n_full, content in enumerate(self._full_pages(toks), 1):
+            key = (parent, tuple(content))
             if key in self._entries:
                 self._touch(key)
             else:
-                page = pages[n_full]
-                self._alloc.incref(page)
-                self._entries[key] = page
-                self._by_page.setdefault(page, set()).add(key)
-                self._children.setdefault(full_key, {})[tail] = key
-                self._content_version += 1
+                running = running.copy()
+                running.update(raw[(n_full - 1) * width:n_full * width])
+                self._add(key, pages[n_full - 1], (
+                    self._next_node, running.hexdigest(), running))
+                self._next_node += 1
+            parent, digest, running = self._meta[key]
+        tail = tuple(toks[n_full * self._pt:].tolist())
+        if tail and n_full < len(pages):
+            key = (parent, tail)
+            if key in self._entries:
+                self._touch(key)
+            else:
+                self._add(key, pages[n_full], (None, digest, None))
 
     # ------------------------------------------------------------------
     def evict(self, need_pages):
@@ -222,48 +249,57 @@ class PrefixCache:
                 continue
             if self._alloc.refcount(page) > 1:
                 continue                        # live holder beyond us
-            self._drop(key)
-            if self._alloc.decref(page):
-                freed += 1
+            freed += self._drop(key)[1]
             if freed >= need_pages:
                 break
         return freed
 
     def release_page(self, page):
-        """Invalidate every entry holding ``page`` and drop the cache's
-        refs — the wrap-recycle path: a slot is about to overwrite the
-        page in place, so its cached content is dead.  Returns the number
-        of entries dropped."""
-        keys = list(self._by_page.get(page, ()))
-        for key in keys:
-            self._drop(key)
-            self._alloc.decref(page)
-        return len(keys)
+        """Invalidate every entry holding ``page``, and what continued them,
+        and drop the cache's refs — the wrap-recycle path: a slot is about
+        to overwrite the page in place, so its cached content is dead.
+        Returns the number of entries dropped."""
+        return sum(self._drop(key)[0]
+                   for key in list(self._by_page.get(page, ()))
+                   if key in self._entries)
 
     def _drop(self, key):
-        self._content_version += 1
-        page = self._entries.pop(key)
-        held = self._by_page.get(page)
-        if held is not None:
+        """Forget ``key`` AND every entry that continued it, giving back the
+        cache's reference to each one's page: ``(entries dropped, pages
+        freed)``.  A chain is walked from its first page through the numbers
+        its entries were given, and a page published again gets a new one, so
+        what continued a dropped page could never be matched again: left in
+        place it would hold its pages, and its digests in :meth:`summary`,
+        until the LRU walk reached each (until PR 50 a key was the whole
+        chain and publishing the head again linked them back).  Pages a live
+        slot still maps lose the cache's reference and stay the slot's."""
+        dropped = freed = 0
+        pending = [key]
+        while pending:
+            key = pending.pop()
+            page = self._entries.pop(key)
+            node = self._meta.pop(key)[0]
+            held = self._by_page[page]
             held.discard(key)
             if not held:
                 del self._by_page[page]
-        if len(key) == 2 and isinstance(key[0], tuple) \
-                and isinstance(key[1], tuple):
-            parent, content = key[0], key[1]
-        else:
-            parent, content = key[:len(key) - self._pt], key[-self._pt:]
-        kids = self._children.get(parent)
-        if kids is not None:
-            kids.pop(content, None)
-            if not kids:
-                del self._children[parent]
+            kids = self._children.get(key[0])
+            if kids is not None:    # a continuation's went with its parent
+                kids.pop(key[1], None)
+                if not kids:
+                    del self._children[key[0]]
+            pending.extend(self._children.pop(node, {}).values())
+            dropped += 1
+            freed += bool(self._alloc.decref(page))
+        self._content_version += 1
+        return dropped, freed
 
     def clear(self):
         """Decref every cached page and empty the cache."""
         for key, page in list(self._entries.items()):
             self._alloc.decref(page)
         self._entries.clear()
+        self._meta.clear()
         self._children.clear()
         self._by_page.clear()
         self._content_version += 1
@@ -284,13 +320,12 @@ class PrefixCache:
             return self._summary_cache[1]
         full, partial = [], []
         for key in self._entries:
-            if len(key) == 2 and isinstance(key[0], tuple) \
-                    and isinstance(key[1], tuple):
-                partial.append({"prefix": chain_hash(key[0]),
-                                "len": len(key[1]),
+            node, digest, _ = self._meta[key]
+            if node is None:
+                partial.append({"prefix": digest, "len": len(key[1]),
                                 "hash": chain_hash(key[1])})
             else:
-                full.append(chain_hash(key))
+                full.append(digest)
         out = {"page_tokens": self._pt, "full": full, "partial": partial}
         self._summary_cache = (self._content_version, out)
         return out

@@ -643,6 +643,68 @@ def _gated(x, wr, b, wg, wu, wd, k, first=0, held=0):
                      first_held=first).asnumpy()
 
 
+def _np_softmax_shared(x, wr, wg, wu, wd, shared, k, first=0, held=None):
+    """Softmax over all the experts, the k largest, their weights
+    renormalised to sum 1, no selection bias, beside one always-on gated
+    MLP ``shared`` (gate, up, down); with ``first`` / ``held`` what a chip
+    holding experts [first, first + held) computes: its experts' part and
+    the shared expert whole."""
+    e = wr.shape[1]
+    held = e if held is None else held
+    z = x @ wr
+    s = np.exp(z - z.max(-1, keepdims=True))
+    s /= s.sum(-1, keepdims=True)
+    silu = lambda g: g / (1.0 + np.exp(-g))
+    y = (silu(x @ shared[0]) * (x @ shared[1])) @ shared[2]
+    for i in range(x.shape[0]):
+        chosen = np.argsort(-s[i], kind="stable")[:k]
+        w = s[i, chosen] / (s[i, chosen].sum() + 1e-20)
+        for c, wc in zip(chosen, w):
+            if first <= c < first + held:
+                y[i] += wc * ((silu(x[i] @ wg[c]) * (x[i] @ wu[c])) @ wd[c])
+    return y
+
+
+def _softmax_shared(x, wr, wg, wu, wd, shared, k, first=0, held=0):
+    e = wr.shape[1]
+    sl = slice(first, first + (held or e))
+    return nd.MoEFFN(nd.array(x), nd.array(wr), nd.array(wg[sl]),
+                     nd.array(wu[sl]), nd.array(wd[sl]),
+                     *(nd.array(m) for m in shared),
+                     num_experts=e, hidden_size=wg.shape[2], gated=True,
+                     score_func="softmax", score_bias=False, norm_topk=True,
+                     n_shared_experts=1, num_experts_per_tok=k,
+                     num_held=held, first_held=first).asnumpy()
+
+
+@pytest.mark.parametrize("held", [4, 8])
+def test_gated_shares_add_up_softmax_beside_a_shared_expert(held):
+    """Softmax routing, greedy top-k, no bias, a shared expert on every chip:
+    each share is what the numpy layer says of it, and the shares, with the
+    shared expert counted once, add up to the uncut layer."""
+    rng = np.random.RandomState(11)
+    n, d, e, h, k = 24, 8, 16, 6, 4
+    x = rng.normal(size=(n, d)).astype(np.float32)
+    wr, _, wg, wu, wd = _gated_weights(rng, d, e, h)
+    shared = (rng.normal(0, 0.5, (d, h)).astype(np.float32),
+              rng.normal(0, 0.5, (d, h)).astype(np.float32),
+              rng.normal(0, 0.5, (h, d)).astype(np.float32))
+    w = (wr, wg, wu, wd, shared)
+    whole = _np_softmax_shared(x, *w, k=k)
+    alone = _np_softmax_shared(x, *w, k=k, first=0, held=0)   # the shared
+    firsts = range(0, e, held)
+    parts = [_softmax_shared(x, *w, k=k, first=f, held=held)
+             for f in firsts]
+    for f, part in zip(firsts, parts):
+        assert_almost_equal(part, _np_softmax_shared(x, *w, k=k, first=f,
+                                                     held=held),
+                            rtol=1e-4, atol=1e-5)
+    assert_almost_equal(sum(parts) - (len(parts) - 1) * alone, whole,
+                        rtol=1e-4, atol=1e-5)
+    assert_almost_equal(_softmax_shared(x, *w, k=k), whole, rtol=1e-4,
+                        atol=1e-5)
+
+
 @pytest.mark.parametrize("held", [4, 8])
 def test_gated_shares_add_up(held):
     """The outputs of the 4 (or 2) shares of a 16-expert layer sum to the

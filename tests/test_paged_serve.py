@@ -685,6 +685,56 @@ def test_allocator_and_prefix_cache_units():
     assert freed == 2 and alloc2.used_pages == 0
 
 
+def test_prefix_cache_drops_what_continued_an_evicted_page():
+    """A chain is walked from its first page, so what continued a dropped
+    page is dropped with it: its pages come back and its digests leave
+    ``summary()`` at once, and the same prompt published again is a chain
+    of its own pages, matched whole."""
+    from mxnet_tpu.serve import chain_hash
+
+    alloc = PageAllocator(16)
+    cache = PrefixCache(4, alloc)
+    toks = np.arange(100, 114)             # 3 full pages + a 2-token tail
+    first = [alloc.alloc() for _ in range(4)]
+    cache.insert(toks, 14, first)
+    other = np.arange(200, 208)            # a second chain, published later
+    second = [alloc.alloc() for _ in range(2)]
+    cache.insert(other, 8, second)
+    for p in first + second:
+        alloc.decref(p)                    # the slots retire
+    assert cache.pages_held == 6 and alloc.used_pages == 6
+    # the oldest entry is the first chain's first page: one page asked for,
+    # the whole chain goes (nothing could reach the rest), the other stays
+    assert cache.evict(1) == 4
+    assert cache.pages_held == 2 and alloc.used_pages == 2
+    assert cache.match(toks) == (0, [])
+    assert cache.match(other) == (7, second)
+    summ = cache.summary()
+    assert summ["full"] == [chain_hash(other[:4]), chain_hash(other)]
+    assert summ["partial"] == []
+    # published again from other pages: matched whole, page for page
+    again = [alloc.alloc() for _ in range(4)]
+    cache.insert(toks, 14, again)
+    assert cache.match(toks) == (13, again)
+    assert cache.pages_held == 6
+    summ = cache.summary()
+    assert [chain_hash(toks[:4 * k]) in summ["full"] for k in (1, 2, 3)] \
+        == [True] * 3
+    assert summ["partial"] == [{"prefix": chain_hash(toks[:12]), "len": 2,
+                                "hash": chain_hash(toks[12:])}]
+    # a page recycled in the middle takes what continued it, not what led
+    # to it; the slot's own references keep the pages
+    assert cache.release_page(again[1]) == 3
+    assert cache.match(toks) == (4, again[:1])
+    assert cache.pages_held == 3 and alloc.used_pages == 6
+    assert len(cache.summary()["full"]) == 3
+    for p in again:
+        alloc.decref(p)
+    assert alloc.used_pages == 3
+    cache.clear()
+    assert alloc.used_pages == 0 and not cache._children and not cache._meta
+
+
 def test_cache_bytes_pass_understands_paged_layouts():
     """mxlint satellite: the cache-bytes pass budgets pool bytes and
     errors on a dense-ring allocation under MXNET_KV_PAGED=1."""
