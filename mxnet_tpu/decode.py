@@ -91,7 +91,9 @@ node holds ``cache_len`` positions a slot, a paged "window" node a ring of
 (a recurrent op) one fixed row a slot and no positions at all, and a
 "latent" node (``ops.attention.LATENT_OP``) ``cache_len`` positions as ONE
 plane with no head axis, ``kv_lora_rank + qk_rope_head_dim`` values a
-position and a page a row, in the full group's pages and tables (pages
+position, a page's positions one after the other in rows of whole lanes
+(``ops.pallas_decode.latent_plane_shape``), in the full group's pages and
+tables (pages
 are pages: fork, copy-on-write, extract and install move the plane as they
 move keys and values).  Nodes of one kind and capacity form a group
 (``serve.CacheGroup``): the paged kinds with their own page count, page tables and allocator, the state group with
@@ -863,13 +865,19 @@ class DecodePredictor:
         positions, or ``pages`` state rows.  The scale plane a node's two
         quantized pools share (``is_scale``) is a row a page, (pages,
         page_tokens * 2 * H_kv): ``ops.attention.QuantKV``; so is the index
-        of a node with sparse selection (``is_index``), (pages, H_kv * D),
-        and a latent node's one plane, (pages, page_tokens * (rank + rope))."""
+        of a node with sparse selection (``is_index``), (pages, H_kv * D).
+        A latent node's one plane holds page_tokens * (rank + rope) values
+        a page (``ops.pallas_decode.latent_plane_shape``)."""
         if self._layouts[ai].kind == "state":
             return (pages,) + tuple(aval.shape[1:])
         if self._layouts[ai].kind == "latent":
-            # a page a row: ops.attention, the latent section's header
-            return (pages, self._page_tokens * aval.shape[2])
+            # a page's positions one after the other, in rows of whole lanes
+            # where the width gives them: ops.attention, the latent
+            # section's header
+            from .ops.pallas_decode import latent_plane_shape
+
+            return latent_plane_shape(pages, self._page_tokens,
+                                      aval.shape[2])
         if is_index:
             return (pages, aval.shape[2])
         if is_scale:
@@ -1327,6 +1335,7 @@ class DecodePredictor:
                             attrs, *ins, cache=caches[ai][0], table=tbl,
                             pos0=pos0, active=active, valid=valid,
                             mesh_active=self._mesh is not None)
+                        paths.add(_attn.DECODE_PATH["last"])
                         carried = (plane,)
                     outs = [out]
                     new_caches.append(carried)
@@ -2859,7 +2868,8 @@ class DecodePredictor:
         lint error."""
         paths = self._decode_paths.get(int(rows), ())
         art.meta["attn_paths"] = sorted(paths)
-        art.meta["pallas_decode"] = "decode-kernel" in paths
+        art.meta["pallas_decode"] = bool(
+            {"decode-kernel", "absorbed-kernel"} & set(paths))
         return art
 
     def decode_artifact(self, state, key=None, name="decode_step"):

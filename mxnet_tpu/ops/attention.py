@@ -636,7 +636,10 @@ def _append_scales(plane, table, new, start, active, valid):
     """Write ``new`` (B, t, W) per-token scales into a scale plane (P,
     page_tokens * W), a page a row, at ring positions [start, start + t)
     of each slot's table; ``active`` and ``valid`` as in
-    :func:`paged_append`.
+    :func:`paged_append`.  A latent plane's pages may be cut into rows of a
+    few positions (``pallas_decode.latent_plane_shape``): a page's values
+    lie in the same order, and it is read and written as a page all the
+    same.
 
     XLA:TPU scatters whole rows as one native scatter and expands a
     window narrower than the row into a loop of one update a token (12 ms
@@ -651,7 +654,7 @@ def _append_scales(plane, table, new, start, active, valid):
 
     b, t, w = new.shape
     m = table.shape[1]
-    pt = plane.shape[1] // w
+    pt = _page_positions(plane, w)
     n = min((t + pt - 2) // pt + 1, m)        # pages t tokens can touch
     first = (start // pt)[:, None] + jnp.arange(n, dtype=jnp.int32)[None, :]
     page = jnp.take_along_axis(table.astype(jnp.int32), first % m, axis=1)
@@ -672,7 +675,14 @@ def _append_scales(plane, table, new, start, active, valid):
                               jnp.clip(tok, 0, t - 1)[:, :, None], axis=1)
     rows = jnp.where(ok[:, :, None], got,       # (B, n * pt, W)
                      plane[page].reshape(got.shape))
-    return plane.at[page.reshape(-1)].set(rows.reshape(-1, pt * w))
+    return plane.at[page.reshape(-1)].set(
+        rows.reshape((-1,) + plane.shape[1:]))
+
+
+def _page_positions(plane, width):
+    """Positions a page of a plane of ``width`` values a position holds,
+    however the page's values are cut into rows."""
+    return int(np.prod(plane.shape[1:])) // width
 
 
 def paged_gather(pool, table):
@@ -1012,9 +1022,11 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
     walk still visits every live block; what a row did not choose is masked
     out of its softmax.
 
-    ``kernel`` = ``(Tiles, interpret)`` (:func:`decode_kernel_selected`: one
-    query row, nothing ``chosen``) puts ONE Pallas kernel over the list in
-    the loop's place (``pallas_decode.attend_blocks``): a step a live row of
+    ``kernel`` = ``(tiles, interpret)`` (:func:`decode_kernel_selected`, or
+    :func:`latent_kernel_selected` over a latent plane: one query row,
+    nothing ``chosen``) puts ONE Pallas kernel over the list in the loop's
+    place (``tiles.attend``: ``pallas_decode.attend_blocks``, or
+    ``attend_latent_blocks`` with ``block`` its own): a step a live row of
     the list, the block's pages copied from the pools into fast memory by
     the kernel itself, no gathered view written, dead rows neither visited
     nor read.  The list before it and the combine after it are the loop's
@@ -1054,16 +1066,13 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         steps = -(-ends[-1] // group)
     hdv = hdv or _plane(v_pool).shape[2] // (int(num_kv_heads) or num_heads)
     if kernel is not None:
-        from . import pallas_decode as _pd
-
         with _scope(layer, "scores"):
             j = jnp.arange(nb, dtype=jnp.int32)[None, :]
-            parts = _pd.attend_blocks(
+            parts = kernel[0].attend(
                 q, k_pool, v_pool, pages, slot,
                 jnp.clip(jnp.minimum(total, cap)[slot] - blk * block, 0,
                          block),
-                ends[-1], kernel[0],
-                scale or 1.0 / np.sqrt(q.shape[2] // num_heads),
+                ends[-1], scale or 1.0 / np.sqrt(q.shape[2] // num_heads),
                 interpret=kernel[1])
             # a slot's dead blocks read its first and weigh nothing
             where = jnp.where(j < reached[:, None], first[:, None] + j,
@@ -1637,16 +1646,31 @@ def cache_attend(q, k_cache, v_cache, total_len, num_heads=1, scale=None,
 #   the weighted sum, so the pages are read as they lie: one "key" of rank +
 #   rope and one "value" (its first rank values) a position for all heads.
 #
-# Paged, the plane is (P, page_tokens * (rank + rope)), a page a row, as a
-# quantized pool's scale plane is: a row of 320 values is no whole number of
+# Paged, a page of the plane holds its positions' values one after the other,
+# and never as (page_tokens, 320): a row of 320 values is no whole number of
 # 128 lanes, and XLA:TPU lays a (P, page_tokens, 320) array out pages-minor
 # to save the padding, then converts the whole pool to rows-minor and back
 # around every scatter and gather (seen in the optimized HLO of the decode
 # step compiled for a described v5e, PR 50: two copies of 0.85 GB a layer a
-# tick).  A page's row of page_tokens * 320 is whole lanes at page_tokens 16;
-# rows are written by :func:`_append_scales`' read-select-write of the few
-# pages a call touches, and what a gather brings is reshaped to positions, a
-# copy of the gathered block and never of the pool.
+# tick).  The page is cut into rows of the fewest positions that make whole
+# lanes, (P, page_tokens / 2, 2 * 320) at 320 values and pages of 16
+# (``pallas_decode.latent_plane_shape``): a page is then whole tiles of HBM,
+# eight rows of five, contiguous, and the decode row's kernel copies it
+# alone.  (Stored a page a ROW, (P, page_tokens * 320), as a quantized pool's
+# scale plane is, a tile of HBM holds eight PAGES' rows, two interleaved word
+# by word: no copy brings one page, Mosaic refuses the cut, and XLA's own
+# gather of such rows moved 217 GB/s, PR 50.  Widths and pages that give no
+# rows of whole lanes keep a page a row.)  Rows are written by
+# :func:`_append_scales`' read-select-write of the few pages a call touches,
+# and what a gather brings is reshaped to positions, a copy of the gathered
+# block and never of the pool.
+#
+# An absorbed call of ONE row a slot over such a plane takes a third path
+# (:func:`latent_kernel_selected`): the list of live blocks the walk builds
+# goes to one Pallas kernel that copies each live block's pages into fast
+# memory itself and takes both folded products there as the pages lie
+# (``pallas_decode.attend_latent_blocks``): no gathered view, no re-layout, a
+# live byte read once.
 #
 # Which of the two cached forms a call takes follows from its rows a slot
 # (:data:`LATENT_EXPAND_ROWS`): a (query row, cached position) pair costs
@@ -1799,8 +1823,13 @@ def latent_expand(rows, w_kvb, spec):
 
 
 def _note_latent(form):
+    """Count a node traced against a cache under the form it took
+    (``expanded``, ``absorbed``, or ``absorbed-kernel`` where the absorbed
+    row went to the Pallas kernel), and leave it in :data:`DECODE_PATH` for
+    the program's record, as :func:`paged_attend` leaves its path."""
     from .. import obs as _obs
 
+    DECODE_PATH["last"] = form
     _obs.registry.counter(
         "mx_attn_latent_dispatch_total",
         "LatentAttention nodes traced against a cache, by the form they took",
@@ -1814,24 +1843,71 @@ def latent_form(rows):
 
 
 def latent_pages(plane, ids, width):
-    """The pages ``ids`` (N, M) of a latent plane (P, page_tokens * width)
-    as rows a position: (N, M * page_tokens, width)."""
+    """The pages ``ids`` (N, M) of a latent plane (a page's positions one
+    after the other, in rows of any length) as rows a position: (N, M *
+    page_tokens, width)."""
     return plane[ids].reshape(ids.shape[0], -1, width)
+
+
+def latent_kernel_selected(q_shape, plane, table_shape, spec,
+                           mesh_active=False):
+    """``(take, interpret)``: whether :func:`latent_attend` hands the live
+    blocks of this call to the absorbed row's Pallas kernel
+    (``pallas_decode.attend_latent_blocks``), decided from what the call
+    shows, as :func:`decode_kernel_selected` decides for
+    :func:`paged_attend`.
+
+    All must hold: the absorbed form with ONE query row a slot (``q_shape``
+    is (B, 1, H * (rank + rope)); rows 2-95 are matrix products already and
+    take the walk); a paged plane (``table_shape`` not None: a dense ring is
+    attended whole); a :func:`live_block_plan` (no mesh, a view of more than
+    a block); a backend that runs Pallas (:func:`_kernel_backend`); and
+    shapes the kernel tiles (``pallas_decode.latent_tiles``: a plane stored
+    in rows of whole lane tiles and pages of whole sublane tiles, a rank of
+    whole lane tiles, a step's buffers within fast memory).  ``take`` is the
+    call's ``LatentTiles`` where it is taken (its ``block`` the positions a
+    step, the kernel's own rule), None where it is not."""
+    from . import pallas_decode as _pd
+
+    runs, interpret = _kernel_backend()
+    if q_shape[1] != 1 or table_shape is None or not runs \
+            or live_block_plan(
+                q_shape, table_shape,
+                _page_positions(plane, spec.rank + spec.rope),
+                mesh_active=mesh_active) is None:
+        return None, False
+    return _pd.latent_tiles(q_shape, plane, table_shape, spec.heads,
+                            spec.rank, spec.rank + spec.rope), interpret
 
 
 def latent_attend(q_nope, q_rope, cache, table, total_len, w_kvb, spec,
                   mesh_active=False):
     """(B, t, H, nope) and (B, t, H, rope) queries, already rotated, against
-    the cached rows: ``cache`` a pool (P, page_tokens * (rank + rope)) read
-    through ``table`` (B, M), or a dense ring (B, C, rank + rope) where
-    ``table`` is None.  ``total_len`` counts the rows appended, the queries'
-    own included.  -> (B, t, H * v), in the form the rows a slot choose
-    (:func:`latent_form`)."""
+    the cached rows: ``cache`` a pool of pages of ``page_tokens * (rank +
+    rope)`` values (``pallas_decode.latent_plane_shape``) read through
+    ``table`` (B, M), or a dense ring (B, C, rank + rope) where ``table`` is
+    None.  ``total_len`` counts the rows appended, the queries' own included.
+    -> (B, t, H * v), in the form the rows a slot choose (:func:`latent_form`).
+
+    The absorbed form has two paths over a table, chosen from what the call
+    shows and counted in ``mx_attn_latent_dispatch_total{form}``: the walk
+    over the live blocks (``absorbed``: a step gathers its blocks' pages and
+    re-lays them out to positions), and, for one row a slot over shapes the
+    kernel tiles (:func:`latent_kernel_selected`), ONE Pallas kernel over the
+    same list that copies each live block's pages as they lie and multiplies
+    them there (``absorbed-kernel``).  The arithmetic is the walk's (the
+    serving type's operands, float32 sums, probabilities rounded to the
+    plane's type) in another order; the logits are not rounded on the way."""
     import jax.numpy as jnp
 
     b, t, h, _ = q_nope.shape
     form = latent_form(t)
-    _note_latent(form)
+    width = spec.rank + spec.rope
+    tiles, interpret = (None, False) if form == "expanded" else \
+        latent_kernel_selected(
+            (b, t, h * width), cache, None if table is None else table.shape,
+            spec, mesh_active=mesh_active)
+    _note_latent(form if tiles is None else "absorbed-kernel")
     w_k, w_v = _latent_weights(w_kvb, spec)
     if form == "expanded":
         q = jnp.concatenate([q_nope, q_rope], axis=-1)
@@ -1845,14 +1921,17 @@ def latent_attend(q_nope, q_rope, cache, table, total_len, w_kvb, spec,
         kvh, hdv = 1, spec.rank
         blocks = lambda rows: (rows, rows[..., :spec.rank])
     q = q.reshape(b, t, -1)
-    width = spec.rank + spec.rope
-    pt = None if table is None else cache.shape[1] // width
-    plan = None if table is None else live_block_plan(
-        q.shape, table.shape, pt, mesh_active=mesh_active)
+    pt = None if table is None else _page_positions(cache, width)
+    if tiles is not None:
+        # the kernel's own step, a row of the list a block
+        plan, kernel = (tiles.block, 1), (tiles, interpret)
+    else:
+        plan, kernel = None if table is None else live_block_plan(
+            q.shape, table.shape, pt, mesh_active=mesh_active), None
     if plan is not None:
         out = _attend_live_blocks(
             q, cache, cache, table, total_len, h, spec.scale, kvh, *plan,
-            layer=spec.layer, hdv=hdv, page_tokens=pt,
+            layer=spec.layer, hdv=hdv, page_tokens=pt, kernel=kernel,
             gather=lambda ids: blocks(latent_pages(cache, ids, width)))
     else:
         with _scope(spec.layer, "kv_gather"):
@@ -1920,8 +1999,9 @@ def latent_mix(attrs, q, c, k_rope, w_kvb, cache=None, table=None, pos0=None,
     else:
         with _scope(spec.layer, "kv_append"):
             cache = _append_scales(
-                cache, table, *_latest(rows, start, table.shape[1]
-                                       * cache.shape[1] // rows.shape[2])[:2],
+                cache, table, *_latest(
+                    rows, start, table.shape[1]
+                    * _page_positions(cache, rows.shape[2]))[:2],
                 active, valid)
     return latent_attend(q_nope, q_rope, cache, table, start + t, w_kvb,
                          spec, mesh_active=mesh_active), cache

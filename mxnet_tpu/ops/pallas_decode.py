@@ -38,6 +38,17 @@ visited: it copies nothing and writes nothing, and the combine reads none.
 the call shows (one query row, a live-block plan, a backend that runs
 Pallas).  ``interpret=True`` runs the same kernel on the CPU
 (tests/test_pallas_decode.py).
+
+A second kernel, :func:`attend_latent_blocks`, does the same for the absorbed
+decode row of latent attention (``ops.attention.latent_attend``): ONE headless
+plane of ``rank + rope`` values a position, stored a page eight rows of two
+positions (:func:`latent_plane_shape`), multiplied as it lies against query
+rows laid block-diagonally over a row's positions.  Its shape rule is
+:func:`latent_tiles`, its step :data:`LATENT_STEP_TOKENS`, its chooser
+``ops.attention.latent_kernel_selected``; it shares the list and the combine
+with the kernel above and none of its body (the section's header below says
+why the plane is stored so, and in which order a block's positions lie inside
+the kernel).
 """
 from __future__ import annotations
 
@@ -78,6 +89,14 @@ class Tiles(NamedTuple):
     ppb: int         # pages a block
     quant: bool
     vmem: int        # bytes of fast memory a step may take
+
+    def attend(self, q, k_pool, v_pool, pages, slot, valid, live, scale,
+               interpret=False):
+        """The listed blocks' shares by this shape's kernel: what
+        ``ops.attention._attend_live_blocks`` calls, whichever kernel the
+        call's rule chose."""
+        return attend_blocks(q, k_pool, v_pool, pages, slot, valid, live,
+                             self, scale, interpret=interpret)
 
 
 def tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads, block):
@@ -458,3 +477,286 @@ def block_bytes(t, k_pool, v_pool):
     if t.quant:
         per += 2 * t.kv_heads * 4
     return t.ppb * t.pt * per
+
+
+# ---------------------------------------------------------------------------
+# The absorbed decode row of latent attention (``ops.attention.latent_attend``
+# with one query row a slot): ONE headless plane of ``rank + rope`` values a
+# position, read as the keys whole and as the values by its first ``rank``.
+# A second kernel with a shape rule of its own: it shares the list of live
+# blocks before it and the combine after it with :func:`attend_blocks`, and
+# none of :func:`_kernel`'s body (no scale rows, no KV heads to keep apart,
+# one plane for both products).
+#
+# The plane is stored ``(P, page_tokens / k, k * width)``: a page's positions
+# ``k`` a row, the fewest that make a row whole lane tiles (2 at a width of
+# 320: a row holds positions ``2 j`` and ``2 j + 1`` side by side, 640 lanes),
+# the rows of a page one sublane tile or more, so that a page is whole tiles
+# of HBM, contiguous, and one copy brings it.  (A plane stored a page a row,
+# ``(P, page_tokens * width)``, cannot be read a page at a time: its tiles
+# hold eight pages' rows each, two rows interleaved word by word, and Mosaic
+# cuts a tiled plane at whole tiles.)  The copied block ``(block / k, k *
+# width)`` is multiplied as it lies: the query rows are laid block-diagonally
+# over a row's ``k`` positions, ``(k * H, k * width)``, so one product gives
+# the logits of position ``k j + i`` of the block in row group ``i`` and
+# column ``j``; the probabilities go back the same way, ``(k * H, block / k)
+# x (block / k, k * width)``, and the diagonal blocks' first ``rank`` columns
+# are summed.  Inside the kernel a block's positions so lie ``(position in
+# its row, row)``; the length mask follows the same order, and the softmax
+# does not care.  Nothing is re-laid out, in HBM or in fast memory.
+# ---------------------------------------------------------------------------
+
+# Positions a step of the latent kernel, by the view's capacity (the widest
+# capacity listed that the view reaches), as LIVE_BLOCK_TOKENS is the walk's.
+# Measured kernel alone on the chip (TPU v5 lite, jax 0.9.0, the one cell's
+# shapes: 20 slots of 66,560 positions, 32 heads over 256 + 64 values in
+# bfloat16; benchmarks/probe_latent_decode.py, PR 51; device ms a call with
+# every slot at 16k / 32k / 64k positions, the walk over the same plane
+# first):
+#   1.39 / 2.42 / 4.48 -> step 512 1.18 / 2.12 / 3.96, 1024 0.90 / 1.64 /
+#   3.12, 2048 0.78 / 1.43 / 2.76
+# A block is loaded into the matrix unit as the stationary operand of both
+# products whatever the step (the queries are 64 rows), so a step's fixed
+# cost (its shares out, its copies' waits, the combine's rows) is what a
+# longer step saves; a slot's last step is half idle on average, 3 % of the
+# cell's mean context at 2048.  Views under 8192 positions keep 512.
+LATENT_STEP_TOKENS = {0: 512, 8192: 2048}
+
+
+class LatentTiles(NamedTuple):
+    """The static sizes of one :func:`attend_latent_blocks` call."""
+
+    heads: int       # H
+    rows: int        # H rounded up to the float32 sublane tile
+    width: int       # values a position: rank + rope
+    rank: int        # the first of them are the values
+    per: int         # k: positions a row of a page
+    pr: int          # rows a page
+    ppb: int         # pages a block
+    exact: bool      # a float32 plane: products at Precision.HIGHEST
+    vmem: int        # bytes of fast memory a step may take
+
+    @property
+    def block(self):
+        """Positions a step: what the list of live blocks is cut by."""
+        return self.ppb * self.pr * self.per
+
+    def attend(self, q, plane, _values, pages, slot, valid, live, scale,
+               interpret=False):
+        """As ``Tiles.attend``: the plane is keys and values both."""
+        return attend_latent_blocks(q, plane, pages, slot, valid, live, self,
+                                    scale, interpret=interpret)
+
+
+def latent_plane_shape(pages, page_tokens, width):
+    """The shape a latent plane of ``pages`` pages is stored in: ``(P,
+    page_tokens / k, k * width)`` where some ``k`` positions a row make rows
+    of whole lane tiles and pages of whole sublane tiles (what
+    :func:`attend_latent_blocks` copies a page at a time), else a page a
+    row, ``(P, page_tokens * width)``."""
+    k = LANES // math.gcd(width, LANES)
+    if page_tokens % k == 0 and (page_tokens // k) % 8 == 0:
+        return (pages, page_tokens // k, k * width)
+    return (pages, page_tokens * width)
+
+
+def latent_tiles(q_shape, plane, table_shape, heads, rank, width):
+    """The :class:`LatentTiles` of an absorbed decode row over this plane
+    and table, or None where the kernel does not tile the shapes: the walk
+    then serves them."""
+    import jax.numpy as jnp
+
+    if plane.ndim != 3 or q_shape[2] != heads * width:
+        return None
+    pr, lanes = plane.shape[1], plane.shape[2]
+    per = lanes // width
+    item = jnp.dtype(plane.dtype).itemsize
+    # pages of whole tiles as the plane's type packs them in HBM (8 rows of
+    # 128 lanes), values of whole lane tiles, a head a lane of the shares'
+    # tile
+    if lanes != per * width or lanes % LANES or pr % 8 or rank % LANES \
+            or rank > width or item not in (2, 4) or heads > LANES:
+        return None
+    pt = pr * per
+    cap = table_shape[1] * pt
+    step = LATENT_STEP_TOKENS[max(c for c in LATENT_STEP_TOKENS if c <= cap)]
+    if step % pt or cap <= step:
+        return None
+    rows = -(-heads // 8) * 8
+    # two buffers of a block, the block once more as the products read it,
+    # the logits and the probabilities, the folded result
+    vmem = step * width * (2 * item + 4) \
+        + per * rows * (3 * step // per + 2 * lanes) * 4 \
+        + q_shape[0] * per * rows * lanes * item
+    if vmem > _VMEM_BUDGET:
+        return None
+    return LatentTiles(heads, rows, width, rank, per, pr, step // pt,
+                       item == 4, vmem)
+
+
+def _latent_kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, hbm,
+                   acc_hbm, stat_hbm, buf, accbuf, statbuf, sems, osems, *,
+                   t, scale):
+    """One invocation walks the live rows of the list as :func:`_kernel`
+    does.  ``q_ref`` (B, per * rows, per * width): a slot's query rows,
+    block-diagonal over the positions of a page's row.  ``buf`` (2, ppb *
+    pr, per * width): a block's pages as they are stored, one under the
+    other."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    live = live_ref[0]
+    n = t.ppb * t.pr                            # rows of a block
+    fill = jnp.finfo(jnp.float32).min
+    prec = jax.lax.Precision.HIGHEST if t.exact else None
+
+    def pages_of(row, b, go, unrolled=False):
+        def page(i, _):
+            go(pltpu.make_async_copy(
+                hbm.at[pages_ref[row * t.ppb + i]],
+                buf.at[b, pl.ds(pl.multiple_of(i * t.pr, t.pr), t.pr)],
+                sems.at[b]))
+
+        if unrolled:
+            for i in range(t.ppb):
+                page(i, None)
+        else:
+            jax.lax.fori_loop(0, t.ppb, page, None)
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+
+    def shares(row, b):
+        return (pltpu.make_async_copy(accbuf.at[b], acc_hbm.at[row],
+                                      osems.at[b, 0]),
+                pltpu.make_async_copy(statbuf.at[b], stat_hbm.at[row],
+                                      osems.at[b, 1]))
+
+    # position of the block that column j of row group i is: per * j + i
+    pos = jax.lax.broadcasted_iota(jnp.int32, (t.per * t.rows, n), 1) * t.per \
+        + _div(jax.lax.broadcasted_iota(jnp.int32, (t.per * t.rows, n), 0),
+               t.rows)
+
+    def attend(r, _):
+        b = _rem(r, 2)
+
+        @pl.when(r + 1 < live)
+        def _next():
+            pages_of(r + 1, 1 - b, start, unrolled=True)
+
+        pages_of(r, b, wait)
+        x = buf[b]                                  # (n, per * width)
+        s = jax.lax.dot_general(
+            q_ref[slot_ref[r]], x, (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)     # (per * rows, n)
+        logits = jnp.where(pos < valid_ref[r], s * jnp.float32(scale), fill)
+        m = jnp.max(logits, axis=1, keepdims=True)
+        top = m[:t.rows]
+        for i in range(1, t.per):
+            top = jnp.maximum(top, m[i * t.rows:(i + 1) * t.rows])
+        p = jnp.exp(logits - jnp.concatenate([top] * t.per, axis=0))
+        sums = jnp.sum(p, axis=1, keepdims=True)
+        full = jax.lax.dot_general(
+            p.astype(x.dtype), x, (((1,), (0,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32)     # (per * rows, per * width)
+        # row group i's values are the first ``rank`` columns of position i
+        acc, den = full[:t.rows, :t.rank], sums[:t.rows]
+        for i in range(1, t.per):
+            acc = acc + full[i * t.rows:(i + 1) * t.rows,
+                             i * t.width:i * t.width + t.rank]
+            den = den + sums[i * t.rows:(i + 1) * t.rows]
+        # the maxima and the sums, a head a lane: rows 0 and 1 of a tile
+        lane = jax.lax.broadcasted_iota(jnp.int32, (t.rows, LANES), 1)
+        stats = jnp.where(lane == 0, top, jnp.where(lane == 1, den, 0.0))
+        stats = jnp.concatenate(
+            [stats, jnp.zeros((LANES - t.rows, LANES), jnp.float32)], axis=0)
+
+        @pl.when(r >= 2)
+        def _sent():                    # row r - 2 has left these buffers
+            for c in shares(r, b):
+                c.wait()
+
+        accbuf[b] = acc
+        statbuf[b] = stats.T[:8]
+        for c in shares(r, b):
+            c.start()
+
+    @pl.when(live > 0)
+    def _first():
+        pages_of(0, 0, start)
+
+    jax.lax.fori_loop(0, live, attend, None)
+    for back in (1, 2):
+        @pl.when(live >= back)
+        def _drain():
+            for c in shares(0, _rem(live - back, 2)):
+                c.wait()
+
+
+def attend_latent_blocks(q, plane, pages, slot, valid, live, t, scale,
+                         interpret=False):
+    """The listed blocks' shares of each slot's softmax over a latent plane,
+    as :func:`attend_blocks` gives them: ``(m (rows, H), den (rows, H), acc
+    (rows, H, rank))`` float32.  ``q`` (B, 1, H * width) the absorbed query
+    rows in the plane's type; ``plane`` as :func:`latent_plane_shape` stores
+    it; the list as :func:`attend_blocks` takes it, blocks of ``t.block``
+    positions."""
+    import jax.numpy as jnp
+
+    b = q.shape[0]
+    qh = jnp.pad(q.reshape(b, t.heads, t.width).astype(plane.dtype),
+                 ((0, 0), (0, t.rows - t.heads), (0, 0)))
+    # (B, per * rows, per * width): row group i reads position i of a row
+    qbd = jnp.einsum("ij,bhw->bihjw", jnp.eye(t.per, dtype=qh.dtype),
+                     qh).reshape(b, t.per * t.rows, t.per * t.width)
+    return _jitted_latent()(qbd, plane, pages, slot, valid, live, t=t,
+                            scale=float(scale), interpret=bool(interpret))
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_latent():
+    import jax
+
+    return jax.jit(_attend_latent_blocks,
+                   static_argnames=("t", "scale", "interpret"))
+
+
+def _attend_latent_blocks(qbd, plane, pages, slot, valid, live, *, t, scale,
+                          interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rows = pages.shape[0]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    with jax.enable_x64(False):
+        acc, stats = pl.pallas_call(
+            functools.partial(_latent_kernel, t=t, scale=float(scale)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4,
+                grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), hbm],
+                out_specs=[hbm, hbm],
+                scratch_shapes=[
+                    pltpu.VMEM((2, t.ppb * t.pr, t.per * t.width),
+                               plane.dtype),
+                    pltpu.VMEM((2, t.rows, t.rank), jnp.float32),
+                    pltpu.VMEM((2, 8, LANES), jnp.float32),
+                    pltpu.SemaphoreType.DMA((2,)),
+                    pltpu.SemaphoreType.DMA((2, 2))]),
+            out_shape=[jax.ShapeDtypeStruct((rows, t.rows, t.rank),
+                                            jnp.float32),
+                       jax.ShapeDtypeStruct((rows, 8, LANES), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=int(min(100 << 20, max(32 << 20, 2 * t.vmem)))),
+            name="latent_live_blocks",
+            interpret=interpret,
+        )(pages.reshape(-1).astype(jnp.int32), slot.astype(jnp.int32),
+          valid.astype(jnp.int32), jnp.reshape(live, (1,)).astype(jnp.int32),
+          qbd, plane)
+    return (stats[:, 0, :t.heads], stats[:, 1, :t.heads],
+            acc[:, :t.heads])

@@ -9,6 +9,13 @@ pages of a latent group forked, extracted and installed, what the predictor
 refuses by name, and the graphs of the five other serving configurations as
 they were.
 
+The absorbed decode row's Pallas kernel (``ops.pallas_decode``, PR 51) runs
+here through the interpreter (``MXNET_PALLAS_INTERPRET``) over ``TILED``, the
+toy at a latent width the kernel tiles (rank 128 + rope 64: pages of 8 rows
+of 384 lanes): the served rows against the reference, the absorbed form
+against the expanded, and every planted fault, through the kernel as through
+the walk.
+
 Tolerance.  ``ATOL`` 1e-4 on log-probabilities: system and reference both
 compute in float32 and differ in the order of their sums (5e-7 measured).
 The least of the planted faults moves them by more than ten times that.
@@ -25,6 +32,7 @@ import pytest
 import mxnet_tpu as mx
 from chipbench import correct, harness, manifest, weights
 from chipbench.reference import mistral4 as ref
+from mxnet_tpu import config
 from mxnet_tpu.base import MXNetError
 from mxnet_tpu.decode import DecodePredictor, DecodeServer
 from mxnet_tpu.ops import attention as attn
@@ -42,6 +50,13 @@ TOY = dict(vocab_size=96, hidden_size=64, num_attention_heads=4, head_dim=16,
            num_experts_per_tok=4, held_n_routed_experts=4,
            first_held_expert=4, serve_num_hidden_layers=2,
            max_position_embeddings=1024)
+
+
+# the toy at a latent width whose pages are whole tiles: what the absorbed
+# row's kernel takes (a cache of 1024 is two of its steps of 512)
+TILED = dict(kv_lora_rank=128, qk_rope_head_dim=64, qk_head_dim=72,
+             head_dim=72)
+PATHS = ("walk", "kernel")
 
 
 def _probe():
@@ -114,14 +129,35 @@ def served(pred, toks, slots=3):
     return jnp.stack(got)
 
 
-@pytest.fixture(scope="module")
-def toy():
-    cfg = toy_config()
+def _toy(**over):
+    cfg = toy_config(**over)
     sym, params = build(cfg)
     toks = np.random.default_rng(0).integers(0, cfg["vocab_size"],
                                              size=(1, T))
     want = ref.forward(params, cfg, toks)[0]
     return cfg, sym, params, toks, want
+
+
+@pytest.fixture(scope="module")
+def toy():
+    return _toy()
+
+
+@pytest.fixture(scope="module")
+def tiled():
+    return _toy(**TILED)
+
+
+@pytest.fixture
+def on_path(request):
+    """``(toy, form)`` of a path of the absorbed decode row: the walk over
+    the toy's plane (a page a row, which no kernel tiles), or the kernel
+    over the tiled toy's on a backend that interprets Pallas."""
+    if request.param == "walk":
+        yield request.getfixturevalue("toy"), "absorbed"
+        return
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        yield request.getfixturevalue("tiled"), "absorbed-kernel"
 
 
 # ---------------------------------------------------------------------------
@@ -187,13 +223,17 @@ def test_the_whole_sequence_is_the_references(toy):
     assert correct.compare_logp(got, want, ATOL)["ok"]
 
 
-@pytest.mark.parametrize("chunk,forms", [
-    (100, {"expanded", "absorbed"}),    # uneven: a chunk straddles pages
-    (40, {"absorbed"}),                 # chunks below the switch: absorbed
-])
-def test_chunks_then_decode_rows_through_a_paged_latent_pool(toy, chunk,
+@pytest.mark.parametrize("on_path,chunk,forms", [
+    ("walk", 100, {"expanded"}),        # uneven: a chunk straddles pages
+    ("walk", 40, set()),                # chunks below the switch: absorbed
+    ("kernel", 100, {"expanded"}),
+    # chunks of 40 rows are absorbed and walked; the decode row's kernel
+    ("kernel", 40, {"absorbed"}),
+], indirect=["on_path"])
+def test_chunks_then_decode_rows_through_a_paged_latent_pool(on_path, chunk,
                                                              forms):
-    _, sym, params, toks, want = toy
+    (cfg, sym, params, toks, want), row_form = on_path
+    width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
     before = harness.program_counters()
     pred = predictor(sym, params, chunk=chunk)
     got = served(pred, toks)
@@ -201,41 +241,66 @@ def test_chunks_then_decode_rows_through_a_paged_latent_pool(toy, chunk,
     assert check["ok"], check
     took = harness.program_counters(since=before)
     assert {k.split("form=")[1].rstrip("}") for k in took
-            if k.startswith("mx_attn_latent_dispatch_total")} == forms
+            if k.startswith("mx_attn_latent_dispatch_total")} \
+        == forms | {row_form}
+    # the program's record says the same, a set a rows-a-slot
+    assert pred._decode_paths[1] == {row_form}
+    assert pred._decode_paths[chunk] == {attn.latent_form(chunk)}
     layouts = pred.cache_layouts()
     assert [l.kind for l in layouts] == ["latent"] * 2
-    assert all((l.kv_heads, l.capacity, l.key_width) == (0, CACHE, 24)
+    assert all((l.kv_heads, l.capacity, l.key_width) == (0, CACHE, width)
                for l in layouts)
     assert [g.kind for g in pred._groups] == ["full"]
-    # a page a row: 193 pages of 16 positions x 24 values, two nodes, float32
-    assert pred.pool_bytes() == 2 * 193 * 16 * 24 * 4
+    # 193 pages of 16 positions x width values, two nodes, float32; a page a
+    # row at 24 values, a page 8 rows of two positions at 192
+    assert pred.pool_bytes() == 2 * 193 * 16 * width * 4
+    state = pred.paged_batch_state(3)
+    assert {x.shape for node in state.caches for x in node} \
+        == {(193, PAGE * 24) if width == 24 else (193, 8, 2 * width)}
     assert pred.attn_walk(3) == [(CACHE, 256)] * 2
 
 
-def test_absorbed_is_expanded_to_float32_rounding(monkeypatch):
+@pytest.mark.parametrize("path,rank,rope,cases", [
+    # the walk over the live blocks (a pool of 1024 positions a slot) and
+    # the view gathered whole (one block)
+    ("walk", 16, 8, ((64, 5), (64, 130), (8, 3))),
+    # one row a slot over pages of whole tiles: the kernel, two steps and
+    # one and a quarter
+    ("kernel", 128, 64, ((64, 1), (40, 1))),
+])
+def test_absorbed_is_expanded_to_float32_rounding(monkeypatch, path, rank,
+                                                  rope, cases):
     """Both cached forms over the same pool, table and queries (the flip
-    moved under and over the call's rows): the walk over the live blocks (a
-    pool of 1024 positions a slot) and the view gathered whole (one
-    block)."""
+    moved under and over the call's rows), the absorbed one by the walk and
+    by the decode row's kernel."""
+    from mxnet_tpu.ops.pallas_decode import latent_plane_shape
+
     spec = attn.latent_spec(dict(
-        num_heads=4, qk_nope_head_dim=8, qk_rope_head_dim=8, v_head_dim=16,
-        kv_lora_rank=16))
+        num_heads=4, qk_nope_head_dim=8, qk_rope_head_dim=rope,
+        v_head_dim=16, kv_lora_rank=rank))
+    width = rank + rope
     rng = np.random.default_rng(1)
     f32 = lambda *shape: jnp.asarray(rng.standard_normal(shape), jnp.float32)
-    w_kvb = f32(4 * 24, 16) * 0.3
-    for pages_a_slot, t in ((64, 5), (64, 130), (8, 3)):
+    w_kvb = f32(4 * 24, rank) * 0.3
+    took = {"walk": "absorbed", "kernel": "absorbed-kernel"}[path]
+    for pages_a_slot, t in cases:
         b = 2
-        plane = f32(1 + b * pages_a_slot, PAGE * 24)
+        plane = f32(*latent_plane_shape(1 + b * pages_a_slot, PAGE, width))
+        assert plane.ndim == {"walk": 2, "kernel": 3}[path]
         table = jnp.asarray(1 + np.arange(b * pages_a_slot).reshape(
             b, pages_a_slot), jnp.int32)
         total = jnp.asarray([pages_a_slot * PAGE - 7, 40 + t], jnp.int32)
-        q_nope, q_rope = f32(b, t, 4, 8), f32(b, t, 4, 8)
+        q_nope, q_rope = f32(b, t, 4, 8), f32(b, t, 4, rope)
         out = {}
         for form, flip in (("absorbed", t + 1), ("expanded", 1)):
             monkeypatch.setattr(attn, "LATENT_EXPAND_ROWS", flip)
             assert attn.latent_form(t) == form
-            out[form] = attn.latent_attend(q_nope, q_rope, plane, table,
-                                           total, w_kvb, spec)
+            with config.overrides(
+                    MXNET_PALLAS_INTERPRET=str(int(path == "kernel"))):
+                out[form] = attn.latent_attend(q_nope, q_rope, plane, table,
+                                               total, w_kvb, spec)
+            assert attn.DECODE_PATH["last"] == \
+                (took if form == "absorbed" else "expanded")
         monkeypatch.undo()
         assert out["absorbed"].shape == (b, t, 4 * 16)
         np.testing.assert_allclose(out["absorbed"], out["expanded"],
@@ -247,17 +312,21 @@ def test_absorbed_is_expanded_to_float32_rounding(monkeypatch):
 # ---------------------------------------------------------------------------
 # every planted fault fails the tolerance
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("on_path", PATHS, indirect=True)
 @pytest.mark.parametrize("fault", probe.FAULTS)
-def test_a_planted_fault_fails(toy, fault):
+def test_a_planted_fault_fails(on_path, fault):
     """Each fault the probe plants in the serving programs on the chip, here:
     chunks and decode rows under it are not the reference's (ten times the
-    tolerance at the least)."""
-    cfg, _, params, toks, want = toy
+    tolerance at the least), whether the decode rows take the walk or the
+    kernel."""
+    (cfg, _, params, toks, want), row_form = on_path
     with probe.planted(fault):
         sym = harness.build_symbol(cfg)
         served_params = probe.coarse(params) if fault == "fp8_weights" \
             else params
-        got = served(predictor(sym, served_params), toks)
+        pred = predictor(sym, served_params)
+        got = served(pred, toks)
+    assert pred._decode_paths[1] == {row_form}
     check = correct.compare_logp(got, want[PROMPT - 1:T - 1], ATOL)
     assert check["max_abs_dlogp"] > 10 * ATOL, (fault, check)
     # and the module is as it was
@@ -269,10 +338,15 @@ def test_a_planted_fault_fails(toy, fault):
 # ---------------------------------------------------------------------------
 # pages are pages
 # ---------------------------------------------------------------------------
-def test_pages_extract_and_install_on_a_latent_group(toy):
+@pytest.mark.parametrize("on_path", PATHS, indirect=True)
+def test_pages_extract_and_install_on_a_latent_group(on_path):
     """One slot's pages out of one predictor's pools and into another row of
-    another's: the decode rows that follow are the same."""
-    _, sym, params, toks, want = toy
+    another's: the decode rows that follow are the same, whether a page is
+    stored a row (the toy) or eight rows of two positions (the tiled toy,
+    whose decode rows take the kernel)."""
+    (cfg, sym, params, toks, want), _ = on_path
+    width = cfg["kv_lora_rank"] + cfg["qk_rope_head_dim"]
+    page = (PAGE * 24,) if width == 24 else (8, 2 * width)
     a, b = predictor(sym, params), predictor(sym, params)
     t = np.zeros((2, PROMPT), np.float32)
     t[0] = toks[0, :PROMPT]
@@ -284,7 +358,7 @@ def test_pages_extract_and_install_on_a_latent_group(toy):
     row = a._manager.tables[0]
     data = a.extract_pages(state_a.caches, row)
     assert [tuple(x.shape for x in node) for node in data] \
-        == [((CACHE // PAGE, PAGE * 24),)] * 2
+        == [((CACHE // PAGE,) + page,)] * 2
     # row 1 of b takes the pages, under ids of b's own (the restore path of
     # serve.swap: the same gate, fresh pages at the same ring positions)
     mgr = b._manager

@@ -19,7 +19,11 @@ described v5e:
   one full and one window node;
 * the paged server token-identical with the kernel and with the walk;
   ``program_cost`` prices the walk's gather and not the kernel's; the
-  flop-dtype pass's ``pallas-fallback`` artifact tripwire.
+  flop-dtype pass's ``pallas-fallback`` artifact tripwire;
+* the absorbed row's kernel over a latent plane (``attend_latent_blocks``,
+  PR 51) at the published widths (32 heads, rank 256, rope 64, pages of 16)
+  against the walk, its dead rows, its rule (``latent_kernel_selected``) and
+  its compile at ``mistral4_serve_longdoc``'s shapes.
 
 Tolerance: rtol 1e-4 / atol 1e-5, what docs/inference.md states for
 reordered float32 sums (``tests/test_paged_live_blocks.py`` holds the walk
@@ -471,6 +475,225 @@ def test_kernel_compiles_for_the_chip_at_the_cells_shapes(cell, one_chip,
     assert " while(" not in text
     block = attn.live_block_plan((b, 1), (b, m), node["pt"])[0]
     assert not re.search(r"s8\[\d+,%d,%d\]" % (block, node["ek"]), text)
+
+
+# ---------------------------------------------------------------------------
+# the absorbed row of latent attention: a kernel of its own over one plane
+# ---------------------------------------------------------------------------
+# the published widths of mistral-small-4-119b's latent node
+LATENT = dict(num_heads=32, qk_nope_head_dim=64, qk_rope_head_dim=64,
+              v_head_dim=128, kv_lora_rank=256)
+LM = 72                         # 1152 positions a slot: 2.25 steps of 512
+# an empty slot, one position, a length that is no multiple of a page, a
+# step exactly, one past it, a last block that is not whole, a slot at its
+# capacity, a ring that has wrapped
+LATENT_LENS = (0, 1, 37, 512, 513, 1100, LM * PT, LM * PT + 9)
+
+
+def _latent_case(dtype, seed=0, lens=LATENT_LENS, pages=LM, **over):
+    spec = attn.latent_spec(dict(LATENT, **over))
+    width = spec.rank + spec.rope
+    b = len(lens)
+    rng = np.random.default_rng(seed)
+    draw = lambda *shape: jnp.asarray(rng.standard_normal(shape), dtype)
+    plane = draw(*pd.latent_plane_shape(1 + b * pages, PT, width))
+    table = jnp.asarray(1 + rng.permutation(b * pages).reshape(b, pages),
+                        jnp.int32)
+    w_kvb = 0.06 * draw(spec.heads * (spec.nope + spec.v), spec.rank)
+    return (draw(b, 1, spec.heads, spec.nope),
+            draw(b, 1, spec.heads, spec.rope), plane, table,
+            jnp.asarray(lens, jnp.int32), w_kvb.astype(dtype), spec)
+
+
+def _latent_walk(args):
+    with config.overrides(MXNET_PALLAS_INTERPRET="0"):
+        out = attn.latent_attend(*args)
+    assert attn.DECODE_PATH["last"] == "absorbed"
+    return out
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_latent_kernel_parity_with_the_walk(dtype, interpret):
+    """``latent_attend``'s kernel path against the walk over the same plane
+    at the published widths, every slot at another length.  The empty
+    slot's answer is an average of unwritten pages, of its first block's
+    alone on either path, and the two paths' blocks differ: it is finite."""
+    args = _latent_case(dtype)
+    assert args[2].shape == (1 + len(LATENT_LENS) * LM, 8, 640)
+    before = obs.registry.counter(
+        "mx_attn_latent_dispatch_total", labels=("form",)).labels(
+            form="absorbed-kernel").get()
+    got = attn.latent_attend(*args)
+    assert attn.DECODE_PATH["last"] == "absorbed-kernel"
+    assert obs.registry.counter(
+        "mx_attn_latent_dispatch_total", labels=("form",)).labels(
+            form="absorbed-kernel").get() == before + 1
+    ref = _latent_walk(args)
+    assert got.shape == ref.shape == (len(LATENT_LENS), 1, 32 * 128)
+    assert got.dtype == ref.dtype
+    # the walk rounds its logits and its output to a bfloat16 plane's type
+    tol = dict(rtol=3e-2, atol=3e-2) if dtype == "bfloat16" \
+        else dict(rtol=RTOL, atol=ATOL)
+    np.testing.assert_allclose(np.asarray(got[1:], np.float32),
+                               np.asarray(ref[1:], np.float32), **tol)
+    assert np.all(np.isfinite(np.asarray(got[0], np.float32)))
+
+
+def test_latent_kernel_against_the_plane_stored_a_page_a_row(interpret):
+    """The same pages' values stored a page a row (as the plane was until
+    PR 51, and is where a width gives no rows of whole lanes): the rule
+    refuses it, the walk reads the same positions, the results agree."""
+    args = _latent_case("float32", seed=2, lens=(700, 1152, 3))
+    plane = args[2]
+    a_row = plane.reshape(plane.shape[0], -1)
+    got = attn.latent_attend(*args)
+    assert attn.DECODE_PATH["last"] == "absorbed-kernel"
+    ref = attn.latent_attend(*args[:2], a_row, *args[3:])
+    assert attn.DECODE_PATH["last"] == "absorbed"
+    np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=RTOL,
+                               atol=ATOL)
+
+
+def test_latent_dead_rows_of_the_padded_list_reach_nothing(interpret):
+    """``attend_latent_blocks`` visits the live prefix of the list alone:
+    what the dead rows name (pages that do not exist) is never copied, the
+    live rows' shares are ``_sdpa_cache``'s over the same blocks re-laid out
+    to positions, and ``_attend_live_blocks`` over a list padded further is
+    the same result."""
+    qn, qr, plane, table, total, w_kvb, spec = _latent_case(
+        "float32", seed=4, lens=(600, 1, 40))
+    width, h = spec.rank + spec.rope, spec.heads
+    t = attn.latent_kernel_selected((3, 1, h * width), plane, table.shape,
+                                    spec)[0]
+    assert (t.block, t.ppb, t.per, t.pr, t.rows, t.exact) \
+        == (512, 32, 2, 8, 32, True)
+    w_k, _ = attn._latent_weights(w_kvb, spec)
+    q = jnp.concatenate([jnp.einsum("bthd,hdr->bthr", qn, w_k), qr],
+                        axis=-1).reshape(3, 1, -1)
+    # slot 0 has two live blocks, slots 1 and 2 one: four live rows of 9
+    slot = jnp.asarray([0, 0, 1, 2] + [2] * 5, jnp.int32)
+    blk = np.asarray([0, 1, 0, 0] + [0] * 5)
+    pages = np.full((9, t.ppb), 10 ** 6, np.int32)      # no such page
+    padded = np.pad(np.asarray(table), ((0, 0), (0, 3 * t.ppb - LM)))
+    for r in range(4):
+        pages[r] = padded[int(slot[r]), blk[r] * t.ppb:(blk[r] + 1) * t.ppb]
+    valid = jnp.clip(total[slot] - blk * t.block, 0, t.block)
+    m, den, acc = pd.attend_latent_blocks(
+        q, plane, jnp.asarray(pages), slot, valid, jnp.int32(4), t,
+        spec.scale, interpret=True)
+    rows = attn.latent_pages(plane, jnp.asarray(pages[:4]), width)
+    want = attn._sdpa_cache(q[slot[:4]], rows, rows[..., :spec.rank],
+                            total[slot[:4]], h, spec.scale, num_kv_heads=1,
+                            block=(jnp.asarray(blk[:4] * t.block), LM * PT))
+    for got, ref in zip((m, den, acc), want):
+        np.testing.assert_allclose(np.asarray(got[:4]),
+                                   np.asarray(ref[:, 0]), rtol=RTOL,
+                                   atol=ATOL)
+    outs = [attn._attend_live_blocks(
+        q, plane, plane, table, total, h, spec.scale, 1, t.block, group,
+        hdv=spec.rank, page_tokens=PT, kernel=(t, True), layer=spec.layer)
+        for group in (1, 7)]
+    np.testing.assert_array_equal(*(np.asarray(o) for o in outs))
+
+
+def _latent_selected(rows=1, slots=20, pages=4160, mesh_active=False,
+                     ring=False, a_row=False, dtype=jnp.bfloat16, **over):
+    spec = attn.latent_spec(dict(LATENT, **over))
+    width = spec.rank + spec.rope
+    shape = pd.latent_plane_shape(1 + slots * pages, PT, width)
+    if a_row:
+        shape = (shape[0], PT * width)
+    plane = jax.ShapeDtypeStruct(
+        (slots, pages * PT, width) if ring else shape, dtype)
+    return attn.latent_kernel_selected(
+        (slots, rows, spec.heads * width), plane,
+        None if ring else (slots, pages), spec, mesh_active=mesh_active)[0]
+
+
+@pytest.mark.parametrize("why,kw", [
+    ("two query rows a slot", dict(rows=2)),
+    ("a mesh shards the executor", dict(mesh_active=True)),
+    ("a dense ring", dict(ring=True)),
+    ("a view of one step", dict(pages=32)),
+    ("a plane stored a page a row", dict(a_row=True)),
+    ("288 values a position: pages of four rows",
+     dict(qk_rope_head_dim=32)),
+    ("a rank of 192: values of no whole lane tiles",
+     dict(kv_lora_rank=192, qk_rope_head_dim=128)),
+    ("an int8 plane", dict(dtype=jnp.int8)),
+])
+def test_latent_rule_refuses(why, kw, interpret):
+    assert _latent_selected() is not None
+    assert _latent_selected(dtype=jnp.float32).exact
+    assert _latent_selected(**kw) is None, why
+
+
+def test_latent_rule_at_the_cells_shapes():
+    """``latent_kernel_selected`` over ``mistral4_serve_longdoc``'s decode
+    row, its shapes read from the cell's files: taken where the backend
+    runs Pallas, by steps of the kernel's own rule; refused for the cell's
+    prefill chunk, which is the expanded form."""
+    import probe_latent_decode as latent_probe
+
+    spec, slots, m, pt = latent_probe.cell_shapes()
+    assert (spec.heads, spec.rank, spec.rope, slots, m, pt) \
+        == (32, 256, 64, 20, 4160, 16)
+    assert pd.latent_plane_shape(slots * m + 1, pt, 320) == (83201, 8, 640)
+    assert _latent_selected() is None           # the CPU, no interpreter
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        t = _latent_selected()
+        assert (t.heads, t.rows, t.width, t.rank, t.per, t.pr, t.exact) \
+            == (32, 32, 320, 256, 2, 8, False)
+        # steps of 2048 from a view of 8192 on, of 512 below
+        assert t.block == pd.LATENT_STEP_TOKENS[8192] == 2048
+        assert _latent_selected(pages=256).block == 512
+        assert t.vmem < pd._VMEM_BUDGET
+        assert _latent_selected(rows=2048, slots=1) is None
+    # the walk's own plan for the same call is what it was
+    assert attn.live_block_plan((20, 1, 32 * 320), (20, 4160), 16) \
+        == (512, 16)
+
+
+def test_latent_kernel_compiles_for_the_chip_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """The cell's absorbed decode row through ``latent_attend``, compiled by
+    the chip's own compiler: Mosaic takes the kernel at the published
+    widths over the plane as it is stored, and the program holds no loop,
+    no copy of the pool and no array of a gathered block's shape."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    import probe_latent_decode as latent_probe
+
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
+    spec, b, m, pt = latent_probe.cell_shapes()
+    width = spec.rank + spec.rope
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    args = (sds((b, 1, spec.heads, spec.nope), jnp.bfloat16),
+            sds((b, 1, spec.heads, spec.rope), jnp.bfloat16),
+            sds(pd.latent_plane_shape(b * m + 1, pt, width), jnp.bfloat16),
+            sds((b, m), jnp.int32), sds((b,), jnp.int32),
+            sds((spec.heads * (spec.nope + spec.v), spec.rank),
+                jnp.bfloat16))
+
+    def attend(*a):
+        return attn.latent_attend(*a, spec)
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(attend).lower(*args).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert attn.DECODE_PATH["last"] == "absorbed-kernel"
+    assert text.count("tpu_custom_call") == 1
+    assert " while(" not in text
+    assert not re.search(r"bf16\[83201,8,640\]\S* copy\(", text)
+    assert not re.search(r"bf16\[\d+,512,320\]", text)
 
 
 # ---------------------------------------------------------------------------
