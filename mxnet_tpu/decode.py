@@ -545,8 +545,11 @@ class DecodePredictor:
                              "chunk": 0, "fork": 0, "commit": 0,
                              "extract": 0, "install": 0}
         self._probing = False
-        # {rows a slot: the attention paths a program's trace took}
+        # {rows a slot: the attention paths a program's trace took}, and
+        # the form each of its gated expert layers' routed product took, in
+        # the walk's order (ops.moe.MOE_PATH)
         self._decode_paths = {}
+        self._moe_forms = {}
         if self._paged:
             from .programs.aot import AotDispatch
 
@@ -1115,6 +1118,7 @@ class DecodePredictor:
 
         from .obs.scopes import node_scope as _node_scope
         from .ops import attention as _attn
+        from .ops import moe as _moe
 
         b, t = tokens.shape[0], tokens.shape[1]
         new_caches = []
@@ -1124,6 +1128,7 @@ class DecodePredictor:
         # of the program that called: artifact meta says from it whether
         # the program holds the decode row's kernel
         self._decode_paths[t] = paths = set()
+        self._moe_forms[t] = forms = []
         ci = qi = 0
         values = {}
         base_key = jax.random.PRNGKey(0)
@@ -1394,6 +1399,8 @@ class DecodePredictor:
                         ins = list(ins)
                         ins[big_i] = jnp.take(big[0], idx, axis=0)
                     outs = plain(seq, node, attrs, ins, aux_ins)
+                    if opname == "MoEFFN" and attrs.get("gated"):
+                        forms.append(_moe.MOE_PATH["last"])
             for i, o in enumerate(outs):
                 values[(id(node), i)] = o
         if late and between is not None:
@@ -2868,6 +2875,7 @@ class DecodePredictor:
         lint error."""
         paths = self._decode_paths.get(int(rows), ())
         art.meta["attn_paths"] = sorted(paths)
+        art.meta["moe_forms"] = list(self._moe_forms.get(int(rows), ()))
         art.meta["pallas_decode"] = bool(
             {"decode-kernel", "absorbed-kernel"} & set(paths))
         return art

@@ -48,6 +48,8 @@ plumbing (set ``aux_loss_coeff=0`` to disable).
 """
 from __future__ import annotations
 
+import math
+
 import numpy as np
 
 from ..attrs import Param, ParamSchema
@@ -165,7 +167,163 @@ def _scores(xt, wr, bias, k, score_func, norm_topk):
     return choice, weight
 
 
-def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None):
+# The smallest call, in rows, whose routed product takes the grouped form
+# (:func:`grouped_selected`), and the grouped product's tiles: rows of the
+# buffer a step, and the most of the contraction and of the columns (a step
+# takes the largest divisor of its width that is no larger, so no tile is
+# partial: 6144 goes by 2048).  Measured kernel alone on the chip (TPU v5
+# lite, jax 0.9.0, bfloat16, 16 held experts; benchmarks/probe_moe_grouped.py,
+# PR 52; ms a layer, routing and sum back inside on both sides; uniform
+# routing / every row to two held experts; reading the 16 experts once takes
+# 0.98, exaone's 1.47):
+#   rows             64           256          512          1024         2048
+#   4096 x 2048, top 4 of 128 (mistral4)
+#     dense          1.16         1.36         2.42         5.28         10.83
+#     grouped        1.01 / 0.23  1.19 / 0.34  1.34 / 0.54  1.57 / 1.02  2.53 / 2.31
+#   the same, top 8 of 256 (mimo)
+#     dense          1.19         1.38         2.42         5.27         10.99
+#     grouped        0.94 / 0.23  1.31 / 0.39  1.63 / 0.78  2.32 / 1.70  3.68 / 3.41
+#   6144 x 2048, top 8 of 128 (exaone)
+#     dense          1.76         2.02         4.06         7.85         16.26
+#     grouped        1.79 / 0.30  2.01 / 0.62  2.47 / 1.25  3.92 / 3.04  6.90 / 6.57
+# Under 512 rows the two forms lie within 0.25 ms at uniform routing (both
+# read every held expert once and that read is the time); from 512 on the
+# dense form is bound by its held x n rows of products and the grouped form
+# is 1.5 x ahead at the least.  Tiles at 2048 rows of mistral4's shape,
+# uniform / skewed: (128, 1024, 512) 2.80 / 3.48, (128, 2048, 512) 2.71 /
+# 3.04, (128, 2048, 1024) 2.70 / 3.03, (128, 4096, 512) 2.53 / 2.30, (256,
+# 2048, 512) 2.64 / 2.35 (but 1.48 at 512 rows, where 128 reads 1.34: a tile
+# that straddles two small groups is multiplied once a group); 64 rows a tile
+# 3.66 / 5.09 and (512, 1024, 512) 4.36 / 2.85 before that.  In megablox
+# gmm's place jax.lax.ragged_dot read 2.80 / 0.60 at 512 rows and 4.53 / 2.97
+# at 2048 (it visits only groups with rows, and loses where every group has
+# some): not kept.
+GROUPED_MIN_ROWS = 512
+GROUPED_TILES = (128, 4096, 512)
+LANES = 128
+
+
+def grouped_selected(n, k, held, d, h, mesh_active=False):
+    """``(take, interpret)``: whether a gated layer's call of ``n`` rows,
+    top ``k`` over ``held`` held experts of ``d`` x ``h``, takes the grouped
+    form of the routed product, decided from what the call shows, as
+    ``attention.flash_selected`` decides for a training node.
+
+    All must hold: a backend that runs Pallas (``attention._kernel_backend``:
+    the product is megablox's kernel); no mesh shards the executor (the
+    kernel is opaque to GSPMD); widths of whole lane tiles; and ``n`` has
+    reached :data:`GROUPED_MIN_ROWS`.  Anything else takes the dense form.
+    ``k`` and ``held`` size the grouped form's buffer and do not move the
+    rule: at every top-k and share measured the forms cross between 256 and
+    512 rows."""
+    from .attention import _kernel_backend
+
+    runs, interpret = _kernel_backend()
+    if mesh_active or not runs or d % LANES or h % LANES:
+        return False, False
+    return n >= GROUPED_MIN_ROWS, interpret
+
+
+def _note_form(form):
+    """Count a gated node traced under the form its routed product took,
+    and leave it in :data:`MOE_PATH` for the program's record."""
+    from .. import obs as _obs
+
+    MOE_PATH["last"] = form
+    _obs.registry.counter(
+        "mx_moe_dispatch_total",
+        "gated MoEFFN nodes traced, by the form their routed product took",
+        labels=("form",)).labels(form=form).inc()
+
+
+def _experts_dense(xt, wg, wu, wd, here, weight, layer):
+    """Every held expert over every row; the unchosen pairs weigh zero."""
+    import jax
+    import jax.numpy as jnp
+
+    from ..obs.scopes import scope as _scope
+
+    with _scope(layer, "experts"):
+        g = jnp.einsum("nd,edh->enh", xt, wg)
+        u = jnp.einsum("nd,edh->enh", xt, wu)
+        y = jnp.einsum("enh,ehd->end", jax.nn.silu(g) * u, wd,
+                       preferred_element_type=jnp.float32)
+    with _scope(layer, "combine"):
+        w = (here * weight[:, :, None]).sum(1)            # (n, held)
+        return jnp.einsum("end,ne->nd", y, w)
+
+
+def _experts_grouped(xt, wg, wu, wd, here, weight, layer, interpret):
+    """Only the held (row, expert) pairs: sorted by held expert, their rows
+    gathered into a buffer of the worst case (every row choosing ``min(k,
+    held)`` held experts), three grouped products whose work follows the
+    groups' sizes, and each pair's output, times its weight, summed back into
+    its row.  The sizes are data: one trace serves every routing and no pair
+    is ever dropped.  Rows of the buffer past the last group are never
+    computed; what lies there is selected away, not multiplied by zero.  The
+    form has no gradient (what lies past the last group would reach it) and
+    refuses one by name."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    from ..obs.scopes import scope as _scope
+
+    n, k, held = here.shape
+    tm, tk, tn = GROUPED_TILES
+    pairs = n * k
+    rows = -(-n * min(k, held) // tm) * tm
+
+    @jax.custom_vjp
+    def run(xt, wg, wu, wd, here, weight):
+        with _scope(layer, "route"):
+            # the pairs choice by choice, a choice's rows together: what is
+            # gathered back for a choice is then (n, d) as it lies
+            mine = here.any(-1).T                         # (k, n)
+            # a pair's group is its held expert; pairs held elsewhere sort
+            # behind the last group
+            group = jnp.where(mine, jnp.argmax(here, -1).T, held)
+            order = jnp.argsort(group.reshape(-1),
+                                stable=True).astype(jnp.int32)
+            sizes = here.sum((0, 1), dtype=jnp.int32)     # (held,)
+            # the buffer's row of each pair, and the row each one holds
+            slot = jnp.zeros((pairs,), jnp.int32).at[order].set(
+                jnp.arange(pairs, dtype=jnp.int32))
+            source = jnp.pad(order, (0, max(0, rows - pairs)))[:rows] % n
+
+        def product(lhs, rhs, out_type):
+            # Mosaic has no 64-bit integers: traced with 32-bit defaults
+            # whatever ``jax_enable_x64`` says (the tests set it)
+            with jax.enable_x64(False):
+                return gmm(lhs, rhs, sizes, preferred_element_type=out_type,
+                           tiling=(tm, math.gcd(tk, lhs.shape[1]),
+                                   math.gcd(tn, rhs.shape[2])),
+                           interpret=interpret)
+
+        with _scope(layer, "experts"):
+            xs = jnp.take(xt, source, axis=0, mode="clip")    # (rows, d)
+            g = product(xs, wg, xt.dtype)
+            u = product(xs, wu, xt.dtype)
+            y = product(jax.nn.silu(g) * u, wd, jnp.float32)
+        with _scope(layer, "combine"):
+            picked = jnp.take(y, jnp.minimum(slot, rows - 1), axis=0,
+                              mode="clip").reshape(k, n, -1)
+            return jnp.where(mine[:, :, None],
+                             picked * weight.T[:, :, None], 0.0).sum(0)
+
+    def refuse(*_):
+        raise NotImplementedError(
+            "MoEFFN: the grouped form of the gated layer's routed product "
+            "(held_grouped) has no gradient; differentiate the dense form "
+            "(fewer than %d rows a call, or a backend that runs no kernel)"
+            % GROUPED_MIN_ROWS)
+
+    run.defvjp(refuse, refuse)
+    return run(xt, wg, wu, wd, here, weight)
+
+
+def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None,
+               mesh_active=False):
     """The gated layer at this chip's share: what the held experts add to
     each token's output, and nothing in place of the others.  ``shared``
     (gate, up, down; ``n_shared_experts``) is an always-on gated MLP that
@@ -173,19 +331,20 @@ def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None):
     so where shares are added up it is counted in one of them.  The routed
     weights are scaled by ``routed_scaling_factor`` after normalising.
 
-    Every held expert runs over every token and the unchosen pairs weigh
-    zero: held x n rows of work whatever the routing, so no token is ever
-    dropped, nothing retraces as the routing changes and there is no index
-    traffic.  An expert's product is bound by reading its weights until it
-    has some 240 rows (TPU v5 lite: 197 TFLOP/s over 819 GB/s), so at a
-    serving call's sizes the unchosen rows cost nothing.  Measured on the
-    chip beside a sort of the held pairs into grouped products (PR 35; 16
-    held experts of 4096 x 2048, top 8 of 256, bfloat16; ms a layer; reading
-    the 16 experts takes 0.98):
-      64 tokens:  this 1.20, jax.lax.ragged_dot 2.52, megablox gmm 1.39
-      512 tokens: this 2.43, ragged_dot 3.30, gmm 2.27
-    A grouped form (k x n rows against held x n) is worth adding when a
-    cell's calls are large enough for the chip to show it winning."""
+    The routed product has two forms with one result, and the call's shapes
+    pick one (:func:`grouped_selected`; ``mesh_active``: a mesh shards the
+    executor).  Dense (``held_dense``): every held expert runs over every
+    token and the unchosen pairs weigh zero, held x n rows of work whatever
+    the routing, no index traffic; an expert's product is bound by reading
+    its weights until it has some 240 rows (TPU v5 lite: 197 TFLOP/s over 819
+    GB/s), so in a call of few rows (a decode tick) the unchosen rows cost
+    nothing.  Grouped (``held_grouped``, :func:`_experts_grouped`): only the
+    held (row, expert) pairs, sorted by expert into a buffer of the worst
+    case, through grouped products whose work follows the groups' sizes.  In
+    both no token is ever dropped and nothing retraces as the routing
+    changes.  The readings that put the crossover at 512 rows are beside
+    :data:`GROUPED_MIN_ROWS` (a chunk of 2048 rows at 16 of 128 experts held,
+    top 4: dense 10.83 ms a layer, grouped 2.53)."""
     import jax
     import jax.numpy as jnp
 
@@ -212,15 +371,16 @@ def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None):
         # which held expert each (token, choice) pair goes to, if any
         here = choice[:, :, None] \
             == (first + jnp.arange(held))[None, None, :]  # (n, k, held)
-    MOE_PATH["last"] = "held_dense"
-    with _scope(layer, "experts"):
-        g = jnp.einsum("nd,edh->enh", xt, wg)
-        u = jnp.einsum("nd,edh->enh", xt, wu)
-        y = jnp.einsum("enh,ehd->end", jax.nn.silu(g) * u, wd,
-                       preferred_element_type=jnp.float32)
-    with _scope(layer, "combine"):
-        w = (here * weight[:, :, None]).sum(1)            # (n, held)
-        out = jnp.einsum("end,ne->nd", y, w)
+    grouped, interpret = grouped_selected(
+        xt.shape[0], choice.shape[1], held, wg.shape[1], wg.shape[2],
+        mesh_active)
+    if grouped:
+        _note_form("held_grouped")
+        out = _experts_grouped(xt, wg, wu, wd, here, weight, layer,
+                               interpret)
+    else:
+        _note_form("held_dense")
+        out = _experts_dense(xt, wg, wu, wd, here, weight, layer)
     if shared is not None:
         sg, su, sd = shared
         with _scope(layer, "shared"):
@@ -703,7 +863,8 @@ def register_all():
             bias = rest.pop(0) if attrs.get("score_bias") else None
             shared = tuple(rest[3:]) if _shared_width(attrs) else None
             return [_moe_share(x, wr, bias, *rest[:3], attrs, k,
-                               shared=shared)], []
+                               shared=shared,
+                               mesh_active=octx.mesh_active)], []
         if attrs.get("score_bias") or attrs.get("num_held") \
                 or attrs.get("score_func", "softmax") != "softmax":
             raise ValueError("MoEFFN: score_func, score_bias and num_held "

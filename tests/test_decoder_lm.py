@@ -188,6 +188,43 @@ def test_chunked_prefill_and_decode_past_the_rings_wrap(toy, kv_dtype, atol):
     assert stats["full"]["used_pages"] == -(-(T - 1) // PAGE)
 
 
+def test_a_chunk_takes_the_grouped_experts_and_a_decode_row_the_dense(
+        monkeypatch):
+    """The routed product's form follows a program's rows: with the crossover
+    at the chunk's width (and Pallas interpreted) the chunk program's six
+    expert layers take the grouped form, the decode program's the dense, each
+    program's record says so, and the distributions are the reference's."""
+    from mxnet_tpu import config
+    from mxnet_tpu.ops import moe
+
+    # the grouped form wants widths of whole lane tiles
+    cfg = toy_config(hidden_size=128, moe_intermediate_size=128)
+    sym, params = build(cfg)
+    toks = np.random.default_rng(1).integers(0, cfg["vocab_size"],
+                                             size=(1, T))
+    monkeypatch.setattr(moe, "GROUPED_MIN_ROWS", CHUNK)
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        pred = DecodePredictor(
+            sym, {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()},
+            cache_len=64, ctx=mx.cpu(), paged=True, page_tokens=PAGE,
+            prefill_chunk=CHUNK)
+        state, probs = pred.prefill(toks[:, :PROMPT].astype(np.float32),
+                                    np.array([PROMPT]))
+        got = [probs[0]]
+        for i in range(PROMPT, PROMPT + 3):
+            state = state._replace(
+                tok=jnp.asarray(toks[:, i:i + 1], jnp.int32))
+            state, probs = pred.step(state)
+            got.append(probs[0])
+        art = pred.decode_artifact(state)
+    assert pred._moe_forms[CHUNK] == ["held_grouped"] * 6
+    assert pred._moe_forms[1] == ["held_dense"] * 6
+    assert art.meta["moe_forms"] == ["held_dense"] * 6
+    want = ref.forward(params, cfg, toks)[0, PROMPT - 1:PROMPT + 3]
+    out = correct.compare_logp(jnp.stack(got), want, FLOAT_ATOL)
+    assert out["ok"] and out["positions"] == 4, out
+
+
 def test_server_matches_generate_and_counts_moe_rows(toy):
     """The serving loop over both groups gives each request the tokens of
     its own ``generate``; the MoE counters count (token, choice) pairs."""

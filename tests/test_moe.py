@@ -868,3 +868,237 @@ def test_gated_symbol_names_and_shapes():
     assert arg_shapes[1:] == [(8, 16), (16,), (4, 8, 5), (4, 8, 5),
                               (4, 5, 8)]
     assert out_shapes == [(2, 3, 8)]
+
+
+# ---------------------------------------------------------------------------
+# the gated layer's grouped form: only the held (row, expert) pairs, sorted by
+# expert, through megablox's grouped product (interpreted here), chosen by
+# ``grouped_selected`` from the call's rows
+# ---------------------------------------------------------------------------
+
+@pytest.fixture
+def grouped_form(monkeypatch):
+    """Every call inside takes the grouped form: Pallas interpreted and the
+    crossover lowered to one row.  The imperative path keeps a trace by the
+    op's attributes, so its cache is emptied on the way in and out."""
+    from mxnet_tpu import config, registry
+    from mxnet_tpu.ops import moe
+
+    monkeypatch.setattr(moe, "GROUPED_MIN_ROWS", 1)
+    registry._jitted.cache_clear()
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        yield moe
+    registry._jitted.cache_clear()
+
+
+def _lane_weights(rng, e=16, d=128, h=128):
+    """Weights of whole lane tiles (the grouped form's rule), scaled so that
+    an output is of order one."""
+    wr, b, wg, wu, wd = _gated_weights(rng, d, e, h)
+    shared = tuple(0.1 * rng.normal(size=s).astype(np.float32)
+                   for s in ((d, h), (d, h), (h, d)))
+    return (0.2 * wr, b, 0.2 * wg, 0.2 * wu, 0.2 * wd), shared
+
+
+def _layer(kind, x, w, shared, k, first=0, held=0):
+    """(the op's output, numpy's) for a ``sigmoid_bias`` or a
+    ``softmax_shared`` layer at a share."""
+    wr, b, wg, wu, wd = w
+    if kind == "sigmoid_bias":
+        return (_gated(x, *w, k=k, first=first, held=held),
+                _np_gated(x, *w, k=k, first=first, held=held or None))
+    return (_softmax_shared(x, wr, wg, wu, wd, shared, k=k, first=first,
+                            held=held),
+            _np_softmax_shared(x, wr, wg, wu, wd, shared, k=k, first=first,
+                               held=held or None))
+
+
+@pytest.mark.parametrize("kind", ["sigmoid_bias", "softmax_shared"])
+@pytest.mark.parametrize("held,first", [(4, 4), (8, 8), (4, 12)])
+def test_grouped_form_is_the_dense_form_and_the_numpy_layer(
+        kind, held, first, grouped_form, monkeypatch):
+    rng = np.random.RandomState(21)
+    w, shared = _lane_weights(rng)
+    x = rng.normal(size=(40, 128)).astype(np.float32)
+    got, want = _layer(kind, x, w, shared, 4, first, held)
+    assert grouped_form.MOE_PATH["last"] == "held_grouped"
+    assert np.abs(want).max() > 0.1
+    assert_almost_equal(got, want, rtol=1e-4, atol=1e-5)
+    # the dense form of the same call: a backend that runs no kernel
+    from mxnet_tpu import registry
+    from mxnet_tpu.ops import attention as attn
+
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (False, False))
+    registry._jitted.cache_clear()
+    dense, _ = _layer(kind, x, w, shared, 4, first, held)
+    assert grouped_form.MOE_PATH["last"] == "held_dense"
+    assert_almost_equal(got, dense, rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("kind", ["sigmoid_bias", "softmax_shared"])
+@pytest.mark.parametrize("held", [4, 8])
+def test_grouped_shares_add_up(kind, held, grouped_form):
+    """The grouped shares of a 16-expert layer sum to the uncut layer (the
+    shared expert, which every share holds, counted once)."""
+    rng = np.random.RandomState(22)
+    w, shared = _lane_weights(rng)
+    x = rng.normal(size=(24, 128)).astype(np.float32)
+    firsts = range(0, 16, held)
+    parts = [_layer(kind, x, w, shared, 4, f, held)[0] for f in firsts]
+    assert grouped_form.MOE_PATH["last"] == "held_grouped"
+    whole, want = _layer(kind, x, w, shared, 4)
+    # what every share holds beside its experts: the shared expert, or nothing
+    alone = _np_softmax_shared(x, w[0], *w[2:], shared, k=4, first=0,
+                               held=0) if kind == "softmax_shared" else 0.0
+    assert_almost_equal(sum(parts) - (len(parts) - 1) * alone, want,
+                        rtol=1e-4, atol=1e-5)
+    assert_almost_equal(whole, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("chosen,first,filled", [
+    ((5, 6), 4, 1.0),       # both held: every row of the buffer is a pair
+    ((5, 9), 4, 0.5), ((5, 9), 8, 0.5)])
+def test_grouped_routing_is_dropless_under_a_skewed_router(
+        chosen, first, filled, grouped_form):
+    """Every token to the same two experts: two groups of 96 rows each, or
+    one; with both held the pairs are the buffer's worst case, 192 rows of a
+    buffer of two tiles, and every one is computed."""
+    rng = np.random.RandomState(23)
+    n, k = 96, 2
+    w, _ = _lane_weights(rng)
+    wr, b = np.zeros_like(w[0]), np.zeros_like(w[1])
+    b[chosen[0]], b[chosen[1]] = 5.0, 4.0
+    w = (wr, b) + w[2:]
+    x = np.abs(rng.normal(size=(n, 128))).astype(np.float32)
+    out = _gated(x, *w, k=k, first=first, held=4)
+    assert grouped_form.MOE_PATH["last"] == "held_grouped"
+    want = _np_gated(x, *w, k=k, first=first, held=4)
+    held_pairs = sum(first <= c < first + 4 for c in chosen) * n
+    assert held_pairs == filled * n * k
+    assert np.abs(want).max(axis=1).min() > 0       # every token has a part
+    assert_almost_equal(out, want, rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("kind", ["sigmoid_bias", "softmax_shared"])
+def test_grouped_share_that_holds_none_of_the_chosen(kind, grouped_form):
+    """No pair is held: every group is empty, no tile of the buffer is ever
+    computed, and what lies there is selected away: zeros beside the shared
+    expert, not what an unwritten buffer held."""
+    rng = np.random.RandomState(24)
+    w, shared = _lane_weights(rng)
+    wr = np.zeros_like(w[0])
+    b = np.zeros_like(w[1])
+    b[:4] = 9.0                     # a bias moves a sigmoid layer's choice
+    x = np.abs(rng.normal(size=(24, 128))).astype(np.float32)
+    if kind == "softmax_shared":
+        wr[:, :4] = 1.0             # a softmax layer's choice is its scores'
+    got, want = _layer(kind, x, (wr, b) + w[2:], shared, 4, first=8, held=4)
+    assert grouped_form.MOE_PATH["last"] == "held_grouped"
+    assert np.isfinite(got).all()
+    if kind == "sigmoid_bias":
+        assert not got.any() and not want.any()
+    else:
+        assert np.abs(want).max() > 0.01
+        assert_almost_equal(got, want, rtol=1e-4, atol=1e-5)
+
+
+def test_grouped_row_count_is_static_and_the_counts_are_the_dense_forms(
+        grouped_form, monkeypatch):
+    """One trace of the grouped form serves four routings (the groups' sizes
+    are data), each counted in ``mx_moe_dispatch_total{form}``; what
+    ``collecting()`` counts, padding rows left out, is what the dense form
+    counts of the same call."""
+    import jax.numpy as jnp
+
+    from mxnet_tpu import obs
+    from mxnet_tpu.ops import attention as attn
+
+    moe = grouped_form
+    rng = np.random.RandomState(25)
+    n, k = 12, 4
+    w, _ = _lane_weights(rng)
+    attrs = dict(num_experts=16, hidden_size=128, gated=True,
+                 score_func="sigmoid", score_bias=True,
+                 num_experts_per_tok=k, num_held=4, first_held=8)
+    count = lambda form: obs.registry.counter(
+        "mx_moe_dispatch_total", labels=("form",)).labels(form=form).get()
+    before = count("held_grouped"), count("held_dense")
+    traces, dense_traces = [], []
+    run = _counting_run(attrs, w, slice(8, 12), traces)
+    real = (np.arange(n) < 7).astype(np.int32)
+    seen, got = set(), []
+    for seed in range(4):
+        x = np.random.RandomState(seed).normal(size=(n, 128)) \
+            .astype(np.float32)
+        bias = np.roll(w[1], seed)
+        out, rows = run(jnp.asarray(x), jnp.asarray(bias), jnp.asarray(real))
+        assert_almost_equal(
+            np.asarray(out),
+            _np_gated(x, w[0], bias, *w[2:], k=k, first=8, held=4),
+            rtol=1e-4, atol=1e-5)
+        held_rows, elsewhere, visits = (int(v) for v in np.asarray(rows))
+        assert held_rows + elsewhere == 7 * k and 0 <= visits <= 4
+        seen.add(held_rows)
+        got.append((x, bias, np.asarray(rows)))
+    assert len(traces) == 1 and moe.MOE_PATH["last"] == "held_grouped"
+    assert len(seen) > 1            # the routing did change
+    assert count("held_grouped") == before[0] + 1
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (False, False))
+    dense = _counting_run(attrs, w, slice(8, 12), dense_traces)
+    for x, bias, rows in got:
+        _, want = dense(jnp.asarray(x), jnp.asarray(bias), jnp.asarray(real))
+        assert list(rows) == list(np.asarray(want))
+    assert moe.MOE_PATH["last"] == "held_dense"
+    assert count("held_dense") == before[1] + 1
+
+
+def test_grouped_form_refuses_a_gradient_by_name(grouped_form):
+    """What lies past the last group would reach a gradient: the grouped
+    form has none, and says so; it is never silently the dense form's."""
+    import jax
+    import jax.numpy as jnp
+
+    from mxnet_tpu.registry import OpContext, get_op
+
+    rng = np.random.RandomState(26)
+    w, _ = _lane_weights(rng)
+    op = get_op("MoEFFN")
+    attrs = op.parse_attrs(dict(
+        num_experts=16, hidden_size=128, gated=True, score_func="sigmoid",
+        score_bias=True, num_experts_per_tok=4, num_held=4, first_held=4))
+    ins = [jnp.asarray(v) for v in
+           (w[0], w[1], w[2][4:8], w[3][4:8], w[4][4:8])]
+    loss = lambda x: op.fcompute(attrs, [x] + ins, [], OpContext())[0][0].sum()
+    x = jnp.asarray(rng.normal(size=(8, 128)).astype(np.float32))
+    assert np.isfinite(float(loss(x)))
+    with pytest.raises(NotImplementedError, match="held_grouped"):
+        jax.grad(loss)(x)
+
+
+# (k, held, d, h) of the three serving cells' gated layers
+_CELL_LAYERS = {"mistral4": (4, 16, 4096, 2048), "mimo": (8, 16, 4096, 2048),
+                "exaone": (8, 16, 6144, 2048)}
+
+
+@pytest.mark.parametrize("cell", sorted(_CELL_LAYERS))
+@pytest.mark.parametrize("rows,grouped", [(20, False), (64, False),
+                                          (96, False), (256, False),
+                                          (512, True), (2048, True)])
+def test_chooser_follows_the_calls_rows(cell, rows, grouped, monkeypatch):
+    """Decode ticks (20-96 rows) keep the dense form, chunks of 512 (mimo,
+    exaone) and of 2048 (mistral4) take the grouped one, where the probe put
+    it ahead by 1.4 x or more; nothing takes it under a mesh, on a backend
+    that runs no kernel, or at widths that are not whole lane tiles."""
+    from mxnet_tpu.ops import attention as attn
+    from mxnet_tpu.ops import moe
+
+    k, held, d, h = _CELL_LAYERS[cell]
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
+    assert moe.grouped_selected(rows, k, held, d, h) == (grouped, False)
+    assert moe.grouped_selected(rows, k, held, d, h, mesh_active=True) \
+        == (False, False)
+    assert moe.grouped_selected(rows, k, held, d - 64, h) == (False, False)
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, True))
+    assert moe.grouped_selected(rows, k, held, d, h) == (grouped, True)
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (False, False))
+    assert moe.grouped_selected(rows, k, held, d, h) == (False, False)
