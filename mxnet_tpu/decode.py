@@ -75,8 +75,9 @@ and values a position; with ``sparse_topk`` an index of compressed keys
 beside them, a row a page) and the recurrent ops :func:`state_ops` lists,
 each a statement of what it keeps a sequence and how a chunk and a step
 update it (``SelectiveSSM``, ``ops.ssm``: a conv tail and a state;
-``LightningAttention``, ``ops.linattn``: one matrix state a head; no
-positions in either but those the op's own rotation reads).  Positions enter
+``LightningAttention``, ``ops.linattn``: one matrix state a head;
+``KimiDeltaAttention``, ``ops.kda``: a conv tail and a matrix state a head;
+no positions in any but those the op's own rotation reads).  Positions enter
 either
 as a learned positional table added via a ``broadcast_*`` op against a
 ``(1, S, E)`` variable, or inside the attention node (``rotary_dim``: q and
@@ -243,10 +244,11 @@ class StateOp(NamedTuple):
 def state_ops():
     """``{op name: StateOp}``: the recurrent ops the decode walk carries a
     state for, each in a "state" cache layout."""
-    from .ops import linattn as _linattn, ssm as _ssm
+    from .ops import kda as _kda, linattn as _linattn, ssm as _ssm
 
     return {_ssm.OP_NAME: StateOp(_ssm.mix, "ssm_rows"),
-            _linattn.OP_NAME: StateOp(_linattn.mix, "linattn_rows")}
+            _linattn.OP_NAME: StateOp(_linattn.mix, "linattn_rows"),
+            _kda.OP_NAME: StateOp(_kda.mix, "kda_rows")}
 
 
 class CacheLayout(NamedTuple):
@@ -287,8 +289,9 @@ class DecodeState(NamedTuple):
     ssm: object = None  # int32: (slot, SelectiveSSM node) rows whose state
                         # the paged step advanced; None as ``moe`` is
     counts: object = None   # {name: int32} of what else the paged step's
-                            # nodes counted (linattn_rows, sparse_blocks_
-                            # chosen / _live); None as ``moe`` is
+                            # nodes counted (linattn_rows, kda_rows,
+                            # sparse_blocks_chosen / _live); None as ``moe``
+                            # is
     draft: object = None    # (B, 1) int32: the token the graph's own
                             # prediction block drafted for the position after
                             # ``tok``; None (no leaf) unless the state is a
@@ -797,7 +800,8 @@ class DecodePredictor:
 
     def state_nodes(self, counts):
         """How many of the graph's recurrent nodes are of the op that
-        counts its rows as ``counts`` ("ssm_rows", "linattn_rows")."""
+        counts its rows as ``counts`` ("ssm_rows", "linattn_rows",
+        "kda_rows")."""
         return sum(n.op.name in self._state_ops
                    and self._state_ops[n.op.name].counts == counts
                    for n in self._cache_nodes)
@@ -3343,6 +3347,14 @@ class DecodeServer:
             "mx_linattn_state_bytes",
             "bytes of the state cache group's LightningAttention rows: "
             "every slot's (H, D, D) float32 states")
+        self._m_kda_rows = _obs.registry.counter(
+            "mx_kda_rows_total",
+            "(slot, KimiDeltaAttention node) rows whose matrix state a "
+            "decode step advanced (idle and mid-prefill slots left out)")
+        self._m_kda_state_bytes = _obs.registry.gauge(
+            "mx_kda_state_bytes",
+            "bytes of the state cache group's KimiDeltaAttention rows: "
+            "every slot's convolution tails and (H, D, D) float32 states")
         self._m_sparse_blocks = _obs.registry.counter(
             "mx_attn_sparse_blocks_total",
             "(slot, KV group, node) blocks of the attention nodes with "
@@ -3360,6 +3372,7 @@ class DecodeServer:
         nodes_of = getattr(predictor, "state_nodes", lambda counts: 0)
         self._ssm_nodes = nodes_of("ssm_rows")
         self._linattn_nodes = nodes_of("linattn_rows")
+        self._kda_nodes = nodes_of("kda_rows")
         # --- fleet/preemption state (paged loop) ---
         # fair admission: after this many consecutive pool-gate-blocked
         # iterations the lowest-priority slot is preempted (swap-out) so
@@ -3469,11 +3482,13 @@ class DecodeServer:
                             moe_expert_visits=visits)
 
     def _note_counts(self, note):
-        """Mirror into the registry what the decode step's lightning and
-        sparse nodes counted (``note`` holds them by name, as the step's
+        """Mirror into the registry what the decode step's lightning, delta
+        and sparse nodes counted (``note`` holds them by name, as the step's
         ``serve.readback`` span shows them)."""
         if "linattn_rows" in note:
             self._m_linattn_rows.inc(note["linattn_rows"])
+        if "kda_rows" in note:
+            self._m_kda_rows.inc(note["kda_rows"])
         for kind in ("chosen", "live"):
             if "sparse_blocks_" + kind in note:
                 self._m_sparse_blocks.labels(kind=kind).inc(
@@ -3875,6 +3890,15 @@ class DecodeServer:
             "unread": None,     # what the last tick queued and nobody has
                                 # read yet (_tick's `cur`); _settle reads it
         }
+        if getattr(pred._manager, "prefix_cache", None) is not None:
+            # the copy-on-write fork is the one program of the loop that a
+            # fill does not run: it waits for the first write into a page a
+            # prefix shares, thousands of ticks on, and the tick that needs
+            # it first then stands still while it compiles.  Page 0 onto
+            # itself now: the same program, nothing moved
+            state = self._ps["state"]
+            self._ps["state"] = state._replace(
+                caches=pred._run_forks(state.caches, [(0, 0)]))
         for g in pred._manager.groups:      # a pool's size: once a session
             self._m_pages_total.labels(group=g.name).set(g.pool_pages)
         if self._ssm_nodes:
@@ -3883,6 +3907,9 @@ class DecodeServer:
         if self._linattn_nodes:
             self._m_linattn_state_bytes.set(
                 slots * pred.state_row_bytes("linattn_rows"))
+        if self._kda_nodes:
+            self._m_kda_state_bytes.set(
+                slots * pred.state_row_bytes("kda_rows"))
         return self._ps
 
     def serve_reset(self):

@@ -97,6 +97,17 @@ model of the family is a configuration file and no code:
   + beta ln(1 + floor(p / original_max)));  ``rope_interleave``: rotated
   pairs are adjacent dims (false is refused likewise).
 
+* ``linear_attn_config`` (``num_heads``, ``head_dim``,
+  ``short_conv_kernel_size``) with ``gqa_layers``: every layer NOT listed in
+  ``gqa_layers`` is Kimi delta attention (``ops.kda``: a delta rule over a
+  matrix state a head with a decay a channel, a convolution on q, k and v;
+  ``kda_allow_neg_eigval`` true: beta in (0, 2); ``kda_use_full_proj`` false:
+  the decay's and the output gate's paths are low-rank pairs of rank
+  ``head_dim``; the other value of either is refused by name), the listed ones the attention ``hybrid_layer_pattern``
+  says, with an elementwise sigmoid gate on its output where
+  ``use_gqa_gate``.  The feed-forward part of such a layer is chosen as any
+  other's (``moe_layer_freq`` and its other names);
+
 ``hybrid_layer_pattern`` and ``moe_layer_freq`` default to zeros: full
 attention and a dense MLP in every layer.  Entries ``first_layer ..
 first_layer + num_layers - 1`` of the per-layer lists are built.
@@ -241,6 +252,33 @@ def lightning_mixer(data, name, hidden, heads, head_dim, theta, eps,
                               flatten=False, name=name + "_lin_out")
 
 
+def kda_mixer(data, name, hidden, heads, head_dim, conv, eps, neg_eigval,
+              full_proj=False):
+    """Kimi delta attention: ``hidden`` -> q, k, v, the decay's and the
+    gate's low-rank pairs and beta -> ``ops.kda`` -> ``hidden``."""
+    if full_proj:
+        raise ValueError(
+            "kda_use_full_proj true: the decay's and the gate's paths are "
+            "built as low-rank pairs of rank head_dim (no configuration "
+            "here brings full ones)")
+    if not neg_eigval:
+        raise ValueError(
+            "kda_allow_neg_eigval false: ops.kda takes beta = 2 sigmoid(.), "
+            "in (0, 2) (no configuration here keeps it in (0, 1))")
+    width = heads * head_dim
+    fc = lambda x, n, part: sym.FullyConnected(
+        x, num_hidden=n, no_bias=True, flatten=False,
+        name="%s_kda_%s" % (name, part))
+    pair = lambda part: fc(fc(data, head_dim, part + "_a"), width,
+                           part + "_b")
+    mixed = sym.KimiDeltaAttention(
+        fc(data, width, "q"), fc(data, width, "k"), fc(data, width, "v"),
+        pair("f"), fc(data, heads, "beta"), pair("g"), num_heads=heads,
+        head_dim=head_dim, conv_kernel=int(conv), eps=float(eps),
+        name=name + "_kda")
+    return fc(mixed, hidden, "out")
+
+
 def gated_mlp(data, name, hidden, width, multipliers=(1.0, 1.0)):
     gate = sym.FullyConnected(data, num_hidden=width, no_bias=True,
                               flatten=False, name=name + "_ffn_gate")
@@ -310,7 +348,10 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                routed_scaling_factor=1.0, num_nextn_predict_layers=0,
                q_lora_rank=0, kv_lora_rank=0, qk_nope_head_dim=0,
                qk_rope_head_dim=0, rope_interleave=True,
-               first_k_dense_replace=None, **kwargs):
+               first_k_dense_replace=None, linear_attn_config=None,
+               gqa_layers=None, use_gqa_gate=False,
+               kda_allow_neg_eigval=False, kda_use_full_proj=False,
+               **kwargs):
     """data (B, T) int tokens -> softmax over the vocabulary at every
     position (``softmax_label`` (B, T) next tokens, pad = -1 ignored)."""
     heads = int(num_attention_heads)
@@ -376,10 +417,15 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                       else add_full_attention_sink_bias),
             key_multiplier=key_multiplier,
             sparse=sparse_config if selects else None,
-            gate=bool(selects and attn_use_output_gate),
+            gate=bool(attn_use_output_gate if selects else use_gqa_gate),
             qk_norm_eps=float(layernorm_epsilon) if attn_qk_norm else 0.0,
             layer=layer)
     mixer_types = mixer_types or ("",) * len(zeros)
+    if linear_attn_config:
+        delta = dict(linear_attn_config)
+        attends = tuple(gqa_layers or ())
+        mixer_types = tuple(kind if i in attends else "kda"
+                            for i, kind in enumerate(mixer_types))
     # MiniCPM's muP: every residual branch, and the logits
     depth = float(scale_depth) / total ** 0.5 if float(scale_depth) else 1.0
     if int(dim_model_base):
@@ -406,6 +452,7 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
         windowed = bool(hybrid_layer_pattern[i])
         normed = sym.RMSNorm(net, eps=layernorm_epsilon,
                              name=name + "_att_norm")
+        selects = mixer_types[i] == "minicpm4"
         if mixer_types[i] == "lightning-attn":
             net = net + times(lightning_mixer(
                 normed, name, hidden_size, int(lightning_nh),
@@ -414,25 +461,26 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                 rotary=lightning_use_rope, qk_norm=qk_norm,
                 output_norm=use_output_norm, output_gate=use_output_gate),
                 depth)
-            net = net + times(gated_mlp(
-                sym.RMSNorm(net, eps=layernorm_epsilon,
-                            name=name + "_ffn_norm"),
-                name, hidden_size, int(intermediate_size), mlp_multipliers),
+        elif mixer_types[i] == "kda":
+            net = net + times(kda_mixer(
+                normed, name, hidden_size, int(delta["num_heads"]),
+                int(delta["head_dim"]),
+                int(delta.get("short_conv_kernel_size", 4)),
+                layernorm_epsilon, kda_allow_neg_eigval, kda_use_full_proj),
                 depth)
-            continue
-        selects = mixer_types[i] == "minicpm4"
-        if mamba_d_ssm:
-            # the parallel block: both mixers read the one normed input
-            net = net + times(ssm_mixer(
-                times(normed, ssm_in_multiplier), name, hidden_size,
-                int(mamba_n_heads), int(mamba_d_head), int(mamba_d_state),
-                int(mamba_n_groups), int(mamba_d_conv),
-                int(mamba_chunk_size), layernorm_epsilon,
-                bool(mamba_proj_bias), ssm_state_dtype, ssm_multipliers),
-                ssm_out_multiplier)
-        net = net + times(attend(
-            times(normed, attention_in_multiplier), name, windowed, selects),
-            attention_out_multiplier * depth)
+        else:
+            if mamba_d_ssm:
+                # the parallel block: both mixers read the one normed input
+                net = net + times(ssm_mixer(
+                    times(normed, ssm_in_multiplier), name, hidden_size,
+                    int(mamba_n_heads), int(mamba_d_head),
+                    int(mamba_d_state), int(mamba_n_groups),
+                    int(mamba_d_conv), int(mamba_chunk_size),
+                    layernorm_epsilon, bool(mamba_proj_bias),
+                    ssm_state_dtype, ssm_multipliers), ssm_out_multiplier)
+            net = net + times(attend(
+                times(normed, attention_in_multiplier), name, windowed,
+                selects), attention_out_multiplier * depth)
         normed = sym.RMSNorm(net, eps=layernorm_epsilon,
                              name=name + "_ffn_norm")
         if moe_layer_freq[i]:

@@ -39,6 +39,7 @@ LAYER_OF_OP = {
     "SelectiveSSM": "ssm",
     "LightningAttention": "linattn",
     "LatentAttention": "attn_latent",
+    "KimiDeltaAttention": "kda",
 }
 # every value a scope's <layer> may take: the table's, "other" for op
 # kinds it does not list, and the two fixed scopes of the train step
@@ -66,7 +67,10 @@ SUBSCOPES = ("kv_append", "kv_gather", "kv_dequant", "scores", "rope",
              # keys and values ("expand", the chunk's form), and the key
              # up-projection folded into the query with the value one after
              # the weighted sum ("absorb", the decode row's)
-             "expand", "absorb")
+             "expand", "absorb",
+             # Kimi delta attention: the triangular solve a block of its
+             # chunked form ("conv", "chunk", "step", "gate_norm" as above)
+             "solve")
 # an instruction no mx.<layer> scope reaches (compiler-made copies,
 # casts between the step's phases)
 UNSCOPED = "unscoped"

@@ -66,8 +66,8 @@ def state_avals(attrs, rows, dtype):
 
 def _conv(xbc, tail, w, bias, nvalid):
     """Causal depthwise convolution of ``xbc`` (B, T, C) behind ``tail``
-    (B, K - 1, C), then silu; and the new tail: the ``K - 1`` rows that end
-    at each row's last real token."""
+    (B, K - 1, C) (``bias`` None: without one), then silu; and the new tail:
+    the ``K - 1`` rows that end at each row's last real token."""
     import jax
     import jax.numpy as jnp
 
@@ -75,7 +75,9 @@ def _conv(xbc, tail, w, bias, nvalid):
     window = jnp.concatenate([tail.astype(xbc.dtype), xbc], axis=1)
     w32 = w.astype(jnp.float32)
     out = sum(window[:, i:i + t].astype(jnp.float32) * w32[:, i]
-              for i in range(k)) + bias.astype(jnp.float32)
+              for i in range(k))
+    if bias is not None:
+        out = out + bias.astype(jnp.float32)
     if nvalid is None:
         new_tail = window[:, t:]
     else:

@@ -326,3 +326,92 @@ def test_what_a_ring_cannot_carry_is_refused_by_name(toy):
     out = server.run()
     assert pred._manager is None or pred._manager.prefix_cache is None
     assert np.array_equal(out[0], out[1])
+
+
+# ---------------------------------------------------------------------------
+# delta layers behind a layer of gated attention (``solar_open2``'s keys)
+# ---------------------------------------------------------------------------
+DELTA_TOY = dict(
+    vocab_size=96, hidden_size=64, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=16,
+    linear_attn_config=dict(short_conv_kernel_size=4, head_dim=16,
+                            num_heads=4, num_kv_heads=None),
+    intermediate_size=128, moe_intermediate_size=32, n_routed_experts=16,
+    num_experts_per_tok=4, held_n_routed_experts=4, first_held_expert=4,
+    serve_num_hidden_layers=5, max_position_embeddings=64)
+
+
+def delta_config(**over):
+    """``solar-open2-250b`` cut to the toy's sizes: five layers, so that the
+    period (attention at 0 and 4, delta layers between) closes once."""
+    cfg = manifest.load_json(manifest.ROOT,
+                             "chipbench/configs/solar-open2-250b.json")
+    wider = {"_weight$": dict(std=0.08),
+             "_kda_dt_bias$": dict(low=-3.0, high=0.0)}
+    init = [dict(r, **wider.get(r["match"], {})) for r in cfg["init"]]
+    return dict(cfg, init=init, **dict(DELTA_TOY, **over))
+
+
+@pytest.fixture(scope="module")
+def delta_toy():
+    cfg = delta_config()
+    sym, params = build(cfg)
+    toks = np.random.default_rng(0).integers(0, cfg["vocab_size"],
+                                             size=(1, T))
+    return cfg, sym, params, toks, system_probs(sym, params, toks)
+
+
+def test_the_mixer_and_the_feed_forward_part_are_chosen_apart(delta_toy):
+    """``gqa_layers`` / ``linear_attn_config`` choose a layer's mixer,
+    ``first_k_dense_replace`` its feed-forward part: routed experts stand
+    behind the recurrent layers as behind the attention."""
+    cfg, sym, params, _, _ = delta_toy
+    kinds = {}
+    for node in sym._topo():
+        if not node.is_variable and node.op.name in (
+                "dot_product_attention", "KimiDeltaAttention", "MoEFFN"):
+            kinds.setdefault(node.name.split("_")[0], []).append(
+                node.op.name)
+    assert kinds == dict(
+        {"layer%d" % l: ["KimiDeltaAttention", "MoEFFN"] for l in (1, 2, 3)},
+        layer0=["dot_product_attention", "MoEFFN"],
+        layer4=["dot_product_attention", "MoEFFN"])
+    att = next(n for n in sym._topo() if n.name == "layer0_att")
+    assert int(att.parsed_attrs().get("rotary_dim", 0)) == 0    # use_rope
+    assert params["layer0_gate_weight"].shape == (4 * 16, 64)   # elementwise
+    assert params["layer0_k_weight"].shape == (2 * 16, 64)
+    assert params["layer1_kda_f_a_weight"].shape == (16, 64)    # rank D
+    assert params["layer1_kda_g_b_weight"].shape == (64, 16)
+    assert params["layer1_kda_beta_weight"].shape == (4, 64)
+    assert params["layer1_kda_conv_weight"].shape == (3 * 64, 4)
+    assert params["layer1_moe_expert_gate_weight"].shape == (4, 64, 32)
+    assert params["layer1_moe_shared_up_weight"].shape == (64, 32)
+    assert not [n for n in params if "_ffn_gate" in n or "layer1_q_" in n]
+    with pytest.raises(ValueError, match="kda_use_full_proj"):
+        harness.build_symbol(dict(cfg, kda_use_full_proj=True))
+    with pytest.raises(ValueError, match="kda_allow_neg_eigval"):
+        harness.build_symbol(dict(cfg, kda_allow_neg_eigval=False))
+    # the other families' configurations name none of it
+    assert "use_gqa_gate" not in toy_config()["symbol_args"]
+
+
+@pytest.mark.parametrize("dropped", [None, "gqa_gate", "neg_eigval",
+                                     "experts_behind_delta"])
+def test_delta_layers_match_their_reference(delta_toy, dropped):
+    from chipbench.reference import solar_open2
+
+    cfg, _, params, toks, probs = delta_toy
+    faulty = {"gqa_gate": dict(use_gqa_gate=False),
+              "neg_eigval": dict(kda_allow_neg_eigval=False),
+              # the reference with layers 1-3 taken for attention layers
+              # would need other weights: drop their experts' share instead
+              "experts_behind_delta": dict(n_shared_experts=0)}.get(
+                  dropped, {})
+    out = correct.compare_logp(
+        probs, solar_open2.forward(params, dict(cfg, **faulty), toks)[0],
+        FLOAT_ATOL)
+    assert out["positions"] == T
+    if dropped is None:
+        assert out["ok"], out
+    else:
+        assert out["max_abs_dlogp"] > 10 * FLOAT_ATOL, out

@@ -755,6 +755,7 @@ TESTED_ELSEWHERE = {
     "MoEFFN": "test_moe.py", "_contrib_MoEFFN": "test_moe.py",
     "SelectiveSSM": "test_ssm.py",
     "LightningAttention": "test_linattn.py",
+    "KimiDeltaAttention": "test_kda.py",
     "LatentAttention": "test_latent_attention.py",
     "count_sketch": "test_spatial_contrib.py",
     "_contrib_count_sketch": "test_spatial_contrib.py",

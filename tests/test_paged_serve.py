@@ -210,6 +210,33 @@ def test_paged_server_shared_prefix_matches_dense():
                         "paged_decode_step"))
 
 
+def test_the_fork_program_is_ready_when_a_session_opens():
+    """The copy-on-write fork is the one program of the loop that a fill
+    does not run; a session that shares prefixes runs it once as it opens
+    (page 0 onto itself), so that the first shared page written, however
+    late, compiles nothing: every page as it was, one trace before the
+    first request and the same one after forks have run."""
+    import jax
+
+    sym, params = _lm_and_params()
+    pred = DecodePredictor(sym, params, cache_len=T, paged=True,
+                           page_tokens=4, prefill_chunk=5)
+    srv = DecodeServer(pred, max_prefill=14, slots=2, max_new_tokens=4)
+    fresh = pred.paged_batch_state(2).caches
+    ps = srv.serve_open()
+    assert pred.trace_counts["fork"] == 1
+    for a, b in zip(jax.tree_util.tree_leaves(fresh),
+                    jax.tree_util.tree_leaves(ps["state"].caches)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    rng = np.random.RandomState(5)
+    prefix = rng.randint(0, VOCAB, (6,))    # ends inside a page: forks
+    for n in (3, 2, 4, 3):
+        srv.submit(np.concatenate([prefix, rng.randint(0, VOCAB, (n,))]))
+    assert len(srv.run()) == 4
+    assert pred._manager.stats()["cow_forks"] > 0
+    assert pred.trace_counts["fork"] == 1
+
+
 def test_paged_server_speculative_matches_generate():
     """Speculative verify over page tables (quantized pools): the paged
     spec server returns exactly what per-prompt dense generation returns,
@@ -890,3 +917,84 @@ def test_paged_int8_pools_under_a_mesh_replicate_the_scale_plane():
         p_state, p_probs = plain.step(p_state)
         np.testing.assert_allclose(np.asarray(s_probs), np.asarray(p_probs),
                                    rtol=1e-4, atol=1e-5)
+
+
+# ---------------------------------------------------------------------------
+# a state group of two-leaf rows, a full group of int8 pages and held experts
+# in one graph (``solar_open2``'s keys at a toy size)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def delta_graph():
+    from test_decoder_lm import build, delta_config, system_probs
+
+    cfg = delta_config(serve_num_hidden_layers=4)
+    sym, params = build(cfg)
+    return cfg, sym, params, system_probs
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+def test_delta_rows_beside_pages_and_held_experts_serve_as_one_pass(
+        delta_graph, kv_dtype):
+    """Five requests through two slots (chunks of 8, then decode steps, each
+    slot reused: a chunk at position 0 voids the state and the tail the last
+    request left): every request's tokens are its own ``generate``'s, and
+    each is the arg max of ONE whole forward pass over the sequence that was
+    served, at every decoded position."""
+    from mxnet_tpu import obs
+    from mxnet_tpu.base import MXNetError
+
+    cfg, sym, params, system_probs = delta_graph
+    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
+    make = lambda: DecodePredictor(
+        sym, nd, cache_len=64, ctx=mx.cpu(), paged=True, page_tokens=4,
+        kv_dtype=kv_dtype, prefill_chunk=8)
+    pred = make()
+    assert [g.kind for g in pred._groups] == ["full", "state"]
+    assert [l.kind for l in pred.cache_layouts()] == ["full"] + ["state"] * 3
+    # a row: 3 delta layers x (3 positions of 3 x 64 channels, 4 x 16 x 16)
+    row = 3 * (3 * 192 * 4 + 4 * 16 * 16 * 4)
+    assert pred.state_row_bytes() == pred.state_row_bytes("kda_rows") == row
+    assert pred.state_nodes("kda_rows") == 3
+    assert pred.state_nodes("linattn_rows") == pred.state_nodes("ssm_rows") \
+        == 0
+    server = DecodeServer(pred, max_prefill=32, slots=2, spec_k=0)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 26, 9, 30)]
+    noted = lambda: [e["args"] for e in obs.timeline.events()
+                     if e["name"] == "serve.readback" and e.get("args")]
+    seen = len(noted())
+    rids = [server.submit(p, max_new_tokens=10) for p in prompts]
+    results = server.run()
+    alone = make()
+    for rid, p in zip(rids, prompts):
+        want = alone.generate(p[None].astype(np.float32), p.size,
+                              max_new_tokens=10)[0]
+        assert np.array_equal(results[rid], want), rid
+        if kv_dtype:
+            continue    # int8 keys move a near-tie; float pools are exact
+        seq = np.concatenate([p, results[rid][:-1]])[None]
+        probs = np.asarray(system_probs(sym, params, seq))
+        assert np.array_equal(probs[p.size - 1:].argmax(-1), results[rid])
+    rows = [a["kda_rows"] for a in noted()[seen:] if "kda_rows" in a]
+    assert rows and set(rows) <= {3, 6}
+    assert sum(rows) == 3 * len(prompts) * (10 - 1)
+    snap = obs.registry.snapshot()
+    assert snap["mx_kda_state_bytes"]["series"][0]["value"] == 2 * row
+    assert snap["mx_kda_rows_total"]["series"][0]["value"] >= sum(rows)
+    # what a state group refuses today stays refused by name
+    assert not server._swap_armed
+    with pytest.raises(MXNetError, match="'state' cache group.*not in pages"):
+        server.inject(object())
+    with pytest.raises(MXNetError, match="'state' cache group.*rejected "
+                                         "draft has already advanced"):
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=2)
+    state, _ = pred.prefill(prompts[0][None].astype(np.float32),
+                            np.array([5]))
+    with pytest.raises(MXNetError, match="KimiDeltaAttention.*rejected "
+                                         "draft"):
+        pred.verify_step(state, np.zeros((1, 3), np.int32))
+    from mxnet_tpu.parallel.mesh import MeshConfig, build_mesh
+    with pytest.raises(MXNetError, match="KimiDeltaAttention.*one device"):
+        DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
+                        page_tokens=4, prefill_chunk=8,
+                        mesh=build_mesh(MeshConfig(model=2)))
