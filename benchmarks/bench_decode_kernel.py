@@ -72,7 +72,8 @@ def serving_nodes(cell):
             name=n.name, heads=int(a["num_heads"]),
             kv_heads=int(a.get("num_kv_heads", 0) or a["num_heads"]),
             e=q[2], ek=k[2], ev=v[2], window=window,
-            sparse=sparse_spec(a) is not None, sink=bool(a.get("sink")),
+            sparse=sparse_spec(a) is not None, spec=sparse_spec(a),
+            sink=bool(a.get("sink")),
             value_scale=float(a.get("value_scale", 1.0) or 1.0),
             slots=int(traffic["slots"]), cap=cap, pt=pt, chunk=chunk,
             rows=1 + int(traffic.get("spec_k", 0)),
@@ -104,19 +105,32 @@ def decode_path(node, tq=None, mesh_active=False):
     Pallas: what ``mx_attn_dispatch_total{path}`` counts."""
     from mxnet_tpu.ops import attention as attn
 
-    if node["sparse"]:
-        return "sparse"
     tq = tq or node["rows"]
+    if node["sparse"] and tq == 1:
+        return "sparse"         # a decode row attends the list it chose
     b = node["slots"] if tq == node["rows"] else 1
     shape = (b, tq, node["e"])
     table = (b, node["cap"] // node["pt"])
-    if attn.live_block_plan(shape, table, node["pt"], mesh_active=mesh_active,
-                            window=node["window"]) is None:
+    plan = attn.live_block_plan(shape, table, node["pt"],
+                                mesh_active=mesh_active,
+                                window=node["window"])
+    if plan is None or (node["sparse"] and plan[0] % node["spec"].block):
         return "whole"
-    take, _ = attn.decode_kernel_selected(
-        shape, *abstract_pools(node), table, node["heads"], node["kv_heads"],
-        mesh_active=mesh_active, window=node["window"])
-    return "walk" if take is None else "decode-kernel"
+    shown = (shape, *abstract_pools(node), table, node["heads"],
+             node["kv_heads"])
+    take = None
+    if not node["sparse"]:
+        take, _ = attn.decode_kernel_selected(
+            *shown, mesh_active=mesh_active, window=node["window"])
+    if take is not None:
+        return "decode-kernel"
+    # a chunk of a sparse node lays its selection over the same walk
+    chosen = None if not node["sparse"] else (
+        (1, node["kv_heads"], tq, -(-node["cap"] // node["spec"].block)),
+        node["spec"].block)
+    take, _ = attn.chunk_kernel_selected(
+        *shown, mesh_active=mesh_active, window=node["window"], chosen=chosen)
+    return "walk" if take is None else "chunk-kernel"
 
 
 def case(node, share, seed=0):

@@ -321,10 +321,11 @@ class FlopDtypePass(Pass):
     legalization (XLA:CPU rewrites bf16 dots through f32) happens later
     and is out of scope.
 
-    Pallas-decode tripwire: a decode/verify artifact whose trace took the
-    decode row's kernel at some attention node
+    Pallas-decode tripwire: a decode/verify artifact whose trace took a
+    Pallas kernel over the live blocks at some attention node
     (``ops.attention.DECODE_PATH`` read ``decode-kernel``, chosen by
-    ``decode_kernel_selected`` from the call's shapes) carries
+    ``decode_kernel_selected`` from the call's shapes, or ``chunk-kernel``,
+    by ``chunk_kernel_selected``) carries
     ``meta['pallas_decode']``: the dispatch PROMISED
     ``ops/pallas_decode.py``'s kernel, the live blocks' pages read once.
     The promise is checked at the artifact level: the traced jaxpr must

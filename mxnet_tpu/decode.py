@@ -1292,6 +1292,11 @@ class DecodePredictor:
                                               []).append(chosen)
                             counts.setdefault("sparse_blocks_live",
                                               []).append(live)
+                            if t > 1:
+                                # a chunk's rows go the walk's way or the
+                                # chunk kernel's; a decode row attends the
+                                # list it chose, no path of those
+                                paths.add(_attn.DECODE_PATH["last"])
                             new_caches.append((kc, vc, index))
                     elif caches is None:
                         outs = [_attn.sdpa(q, k, v, num_heads=heads,
@@ -2872,16 +2877,16 @@ class DecodePredictor:
     def _refine_decode_meta(self, art, rows=1):
         """Say in the artifact's meta which paths the attention nodes of
         the program with ``rows`` query rows a slot took when it was traced
-        (``ops.attention.DECODE_PATH``, one of ``decode-kernel`` / ``walk`` /
-        ``whole`` a node), and promise the decode row's Pallas kernel where
-        one took it: the flop-dtype pass then demands a ``pallas_call`` in
-        the program, so a kernel the rule chose and the lowering lost is a
-        lint error."""
+        (``ops.attention.DECODE_PATH``, one of ``decode-kernel`` /
+        ``chunk-kernel`` / ``walk`` / ``whole`` a node), and promise a
+        Pallas kernel over the live blocks where a node took one: the
+        flop-dtype pass then demands a ``pallas_call`` in the program, so a
+        kernel the rule chose and the lowering lost is a lint error."""
         paths = self._decode_paths.get(int(rows), ())
         art.meta["attn_paths"] = sorted(paths)
         art.meta["moe_forms"] = list(self._moe_forms.get(int(rows), ()))
         art.meta["pallas_decode"] = bool(
-            {"decode-kernel", "absorbed-kernel"} & set(paths))
+            {"decode-kernel", "chunk-kernel", "absorbed-kernel"} & set(paths))
         return art
 
     def decode_artifact(self, state, key=None, name="decode_step"):

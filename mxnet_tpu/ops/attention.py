@@ -889,9 +889,11 @@ def flash_selected(q_shape, k_shape, causal, num_heads, num_kv_heads,
 
 # Same marker for the DECODE-side dispatch (paged_attend / cache_attend):
 # "decode-kernel" when the decode row's Pallas kernel traced
-# (decode_kernel_selected), "walk" for the loop over the live blocks
-# (_attend_live_blocks) and "whole" where the view is gathered and attended
-# whole (a window ring, a view of one block, a sharded pool, a dense ring).
+# (decode_kernel_selected), "chunk-kernel" when a prefill chunk's did
+# (chunk_kernel_selected; paged_attend_sparse's chunk branch too), "walk" for
+# the loop over the live blocks (_attend_live_blocks) and "whole" where the
+# view is gathered and attended whole (a window ring, a view of one block, a
+# sharded pool, a dense ring).
 # Each such dispatch also counts in mx_attn_dispatch_total{path=...}.
 # mxnet_tpu.decode records it per program, so that an artifact's meta says
 # which path its attention took.
@@ -994,11 +996,68 @@ def decode_kernel_selected(q_shape, k_pool, v_pool, table_shape, num_heads,
                      plan[0]), interpret
 
 
+# Query rows of a one-slot call from which its live blocks go to the chunk's
+# Pallas kernel (``pallas_decode.attend_chunk_blocks``) in the walk's place:
+# the fewest at which the kernel was ahead of the walk at every shape and
+# context measured.  Kernel alone on the chip (TPU v5 lite, jax 0.9.0, int8
+# pools, float32 queries, heads of 128; benchmarks/probe_chunk_kernel.py,
+# PR 54; ms a layer, walk -> kernel, by rows x context; a call of 0.2 ms is
+# the dispatch's own length on either path):
+#   32 heads over 2 KV heads, 64 of n blocks chosen a row (minicpm-sala):
+#     2048 x 16k / 32k / 64k  18.83 -> 4.33, 37.46 -> 8.46, 74.72 -> 16.75
+#     (nothing chosen: 18.58 -> 4.22, 37.00 -> 8.27, 73.84 -> 16.39)
+#     1024 x 1k / 4k 0.33 -> 0.31, 0.92 -> 0.70;  512 x 1k / 4k 0.25 -> 0.23,
+#     0.46 -> 0.42;  256 x 1k / 4k 0.21 -> 0.22, 0.28 -> 0.32
+#   64 heads over 8 (solar-open2): 2048 x 8k / 16k 22.63 -> 4.91, 44.75 ->
+#     9.41;  1024 x 1k / 4k 1.36 -> 0.53, 5.02 -> 1.37;  512 x 1k / 4k 0.29
+#     -> 0.32, 0.88 -> 0.75;  256 x 1k / 4k 0.21 -> 0.24, 0.50 -> 0.40
+#   64 heads over 8, blocks of 256 (k-exaone): 512 x 2k / 6k 0.63 -> 0.68,
+#     1.71 -> 1.72
+#   20 heads over 4 (falcon-h1): 1024 x 1k / 2k 0.24 -> 0.22, 0.40 -> 0.31;
+#     512 x 1k / 2k 0.22 -> 0.24, 0.28 -> 0.25;  256 x 1k 0.22 -> 0.20-0.23
+# At 512 rows the two trade places by the context (0.88-1.17 x): a step of
+# the walk still holds its scores where the products leave them
+# (LIVE_STEP_SCORES); from 1024 rows on it writes them out and the kernel is
+# 1.05-4.7 x ahead.  So the chunks of 2048 take the kernel and those of 256
+# and 512 (falcon-h1, k-exaone) keep the walk.
+CHUNK_MIN_ROWS = 1024
+
+
+def chunk_kernel_selected(q_shape, k_pool, v_pool, table_shape, num_heads,
+                          num_kv_heads, mesh_active=False, window=0,
+                          chosen=None):
+    """``(take, interpret)``: whether a call of one slot and many query rows
+    (a prefill chunk) hands its live blocks to the chunk's Pallas kernel
+    (``pallas_decode.attend_chunk_blocks``) in the walk's place, decided
+    from what the call shows, as :func:`decode_kernel_selected` decides for
+    the decode row.
+
+    All must hold: one slot with at least :data:`CHUNK_MIN_ROWS` query rows;
+    a :func:`live_block_plan` (so no window node, no mesh, a view of more
+    than a block); a backend that runs Pallas; and shapes the kernel tiles
+    (``pallas_decode.chunk_tiles``: heads of whole lane tiles, a tile of the
+    rows with every head's running state within fast memory).  ``chosen`` =
+    ``(mask shape, width)`` of a selection laid over the walk
+    (:func:`paged_attend_sparse`).  A call that brings its own ``gather``
+    (latent attention's expanded chunk) does not ask.  ``take`` is the
+    call's ``ChunkTiles`` where it is taken, None where it is not."""
+    from . import pallas_decode as _pd
+
+    runs, interpret = _kernel_backend()
+    plan = live_block_plan(q_shape, table_shape, _plane(k_pool).shape[1],
+                           mesh_active=mesh_active, window=window)
+    if q_shape[0] != 1 or q_shape[1] < CHUNK_MIN_ROWS or plan is None \
+            or not runs:
+        return None, False
+    return _pd.chunk_tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads,
+                           plan[0], chosen=chosen), interpret
+
+
 def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
                         scale, num_kv_heads, block, group, sink=None,
                         value_scale=1.0, layer="attn", chosen=None,
                         kernel=None, gather=None, hdv=None,
-                        page_tokens=None):
+                        page_tokens=None, chunk=None):
     """:func:`paged_gather` + :func:`_sdpa_cache` over the blocks the slots
     have reached, and no others.
 
@@ -1031,6 +1090,13 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
     the kernel itself, no gathered view written, dead rows neither visited
     nor read.  The list before it and the combine after it are the loop's
     own.
+
+    ``chunk`` = ``(tiles, interpret)`` (:func:`chunk_kernel_selected`: ONE
+    slot, many query rows, no ``gather``) puts the chunk's Pallas kernel
+    over the slot's blocks in the loop's place
+    (``pallas_decode.attend_chunk_blocks``): what it returns is the loop's
+    running row, a selection ``chosen`` goes in with it, and the combine
+    after it is the loop's own.
 
     ``gather(ids (rows, pages a block))`` -> ``(k_blk, v_blk)`` stands in
     for :func:`paged_gather_kv` where a node's pages are not keys and values
@@ -1065,6 +1131,16 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         pages = pages.reshape(b, nb, ppb)[slot, blk]          # (rows, ppb)
         steps = -(-ends[-1] // group)
     hdv = hdv or _plane(v_pool).shape[2] // (int(num_kv_heads) or num_heads)
+    if chunk is not None:
+        from . import pallas_decode as _pd
+
+        with _scope(layer, "scores"):
+            m, den, acc = _pd.attend_chunk_blocks(
+                q, k_pool, v_pool, pages[:nb], total[0], cap, chunk[0],
+                scale or 1.0 / np.sqrt(q.shape[2] // num_heads),
+                chosen=chosen, interpret=chunk[1])
+        return _combine_blocks(m[:, None], den[:, None], acc[:, None], sink,
+                               value_scale, v_pool, layer)
     if kernel is not None:
         with _scope(layer, "scores"):
             j = jnp.arange(nb, dtype=jnp.int32)[None, :]
@@ -1178,7 +1254,7 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
                  scale=None, mesh_active=False, num_kv_heads=0, window=0,
                  sink=None, value_scale=1.0, layer="attn"):
     """Decode/verify attention over shared page pools — the ONE entry the
-    decode programs call.  Which of three paths a call takes follows from
+    decode programs call.  Which of four paths a call takes follows from
     what the call shows; each counts in ``mx_attn_dispatch_total{path}``.
 
     ``walk``: the view is attended block by block, only the blocks the
@@ -1197,23 +1273,46 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
     and no gathered view is written.  The arithmetic is the walk's in
     another order (docs/inference.md).
 
+    ``chunk-kernel``: ONE slot with many query rows (a prefill chunk) over
+    shapes the chunk's kernel tiles (:func:`chunk_kernel_selected`: at
+    least :data:`CHUNK_MIN_ROWS` rows, heads of whole lane tiles) hands the
+    slot's blocks to ONE Pallas kernel that takes a tile of the rows
+    against every block under the tile's causal limit, the pages copied as
+    the decode row's kernel copies them, and keeps the logits, the mask,
+    the exponentials and the probabilities in fast memory: the walk's
+    ``tq > 1`` arithmetic (one bfloat16 pass a product, float32 sums) with
+    only the order of the softmax's sums changed.  What stays on the walk:
+    a backend without Pallas, a few rows of many slots (a verify window, a
+    self-drafting tick), heads of 64 or keys of 192, and latent
+    attention's expanded chunk (its own ``gather``).
+
     ``whole``: where the plan is None — a view of one block, a window
     node, a sharded pool — the whole view is gathered
     (:func:`paged_gather`) and attended as a dense ring is, the same jaxpr
     as ever and bit-parity with a dense ring; the other two agree with
-    that within the tolerance of reordered float32 sums."""
+    that within the tolerance of reordered float32 sums (the chunk's
+    kernel within that of probabilities rounded to bfloat16 about another
+    maximum, as the walk rounds them about a block's)."""
     plan = live_block_plan(q.shape, table.shape, _plane(k_pool).shape[1],
                            mesh_active=mesh_active, window=window)
     if plan is not None:
+        shown = (q.shape, k_pool, v_pool, table.shape, num_heads,
+                 num_kv_heads)
+        path, kernel, chunk = "walk", None, None
         tiles, interpret = decode_kernel_selected(
-            q.shape, k_pool, v_pool, table.shape, num_heads, num_kv_heads,
-            mesh_active=mesh_active, window=window)
-        _note_path("walk" if tiles is None else "decode-kernel", DECODE_PATH)
+            *shown, mesh_active=mesh_active, window=window)
+        if tiles is not None:
+            path, kernel = "decode-kernel", (tiles, interpret)
+        else:
+            tiles, interpret = chunk_kernel_selected(
+                *shown, mesh_active=mesh_active, window=window)
+            if tiles is not None:
+                path, chunk = "chunk-kernel", (tiles, interpret)
+        _note_path(path, DECODE_PATH)
         return _attend_live_blocks(
             q, k_pool, v_pool, table, total_len, num_heads, scale,
             num_kv_heads, *plan, sink=sink, value_scale=value_scale,
-            layer=layer,
-            kernel=None if tiles is None else (tiles, interpret))
+            layer=layer, kernel=kernel, chunk=chunk)
     _note_path("whole", DECODE_PATH)
     with _scope(layer, "kv_gather"):
         k_view, v_view = paged_gather_kv(k_pool, v_pool, table)
@@ -1555,8 +1654,14 @@ def paged_attend_sparse(q, k_pool, v_pool, index, table, total_len, spec,
     (:attr:`SparseSpec.list_width`), one program for both.
 
     More rows (a chunk): each row has its own list, and this first form
-    lays it as a mask over the walk of every live block
-    (:func:`_attend_live_blocks`): the same mathematics, at dense cost."""
+    lays it as a mask over every live block (:func:`_attend_live_blocks`):
+    the same mathematics, at dense cost.  One slot's chunk over shapes the
+    chunk's kernel tiles (:func:`chunk_kernel_selected`, the mask's shape
+    shown to it) takes the blocks and the mask to that kernel
+    (``chunk-kernel`` in ``mx_attn_dispatch_total{path}``), which widens a
+    row's choice to positions in fast memory and visits every live block
+    as the walk does; anything else keeps the walk's loop (``walk``), or,
+    without a plan, the whole gathered view (``whole``)."""
     import jax
     import jax.numpy as jnp
 
@@ -1598,10 +1703,16 @@ def paged_attend_sparse(q, k_pool, v_pool, index, table, total_len, spec,
         chosen = jnp.sum(jnp.where(on[:, None, None, None], mask, False))
     plan = live_block_plan(q.shape, table.shape, pt, mesh_active=mesh_active)
     if plan is not None and plan[0] % spec.block == 0:
-        out = _attend_live_blocks(q, k_pool, v_pool, table, total, num_heads,
-                                  scale, kvh, *plan, layer=layer,
-                                  chosen=(mask, spec.block))
+        tiles, interpret = chunk_kernel_selected(
+            q.shape, k_pool, v_pool, table.shape, num_heads, kvh,
+            mesh_active=mesh_active, chosen=(mask.shape, spec.block))
+        _note_path("walk" if tiles is None else "chunk-kernel", DECODE_PATH)
+        out = _attend_live_blocks(
+            q, k_pool, v_pool, table, total, num_heads, scale, kvh, *plan,
+            layer=layer, chosen=(mask, spec.block),
+            chunk=None if tiles is None else (tiles, interpret))
     else:
+        _note_path("whole", DECODE_PATH)
         with _scope(layer, "kv_gather"):
             k_view, v_view = paged_gather_kv(k_pool, v_pool, table)
         allow = jnp.repeat(mask, spec.block, axis=3)[..., :m * pt]

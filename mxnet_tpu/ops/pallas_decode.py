@@ -760,3 +760,373 @@ def _attend_latent_blocks(qbd, plane, pages, slot, valid, live, *, t, scale,
           qbd, plane)
     return (stats[:, 0, :t.heads], stats[:, 1, :t.heads],
             acc[:, :t.heads])
+
+
+# ---------------------------------------------------------------------------
+# A prefill chunk: ONE slot, many query rows (``ops.attention.
+# _attend_live_blocks`` with ``b == 1``, the walk's ``running`` row).  The
+# walk takes a block a step: it gathers the block's pages into a copy, and
+# between its two products writes and reads again, through HBM, the float32
+# logits, the mask repeated to positions, the exponentials and the
+# probabilities of ``heads x rows x block`` entries.  :func:`attend_chunk_
+# blocks` is that walk with the scores kept in fast memory: a grid step a
+# tile of query rows, a loop inside it over the slot's blocks up to the
+# tile's causal limit, the pages copied as :func:`_kernel` copies them (the
+# next block's in flight), the running ``(max, sum, acc)`` of every head of
+# the tile in fast memory across the blocks and written once.  A third kernel
+# with a shape rule of its own (:func:`chunk_tiles`): it shares the pools'
+# storage and the page copies with :func:`_kernel` and the combine after it
+# with the walk; its products are matrix products of a tile's rows a head
+# (one bfloat16 pass, float32 accumulation: ``_sdpa_cache``'s ``tq > 1``
+# form), not the row form's three pieces, and a quantized pool's scale rows
+# come to it already turned to positions (:func:`attend_chunk_blocks`).
+#
+# A selection (``chosen`` of the walk: which blocks of ``width`` positions a
+# row of a KV group attends) rides in as int8, ``(H_kv, lane tiles of
+# blocks, rows, 128)``: a step reads the lane tile its block's columns lie in
+# and widens it to positions by one small product against a 0/1 matrix built
+# from iotas, once a KV group, for all the group's heads.
+# ---------------------------------------------------------------------------
+
+# Query rows a tile, the largest first that divides the chunk and fits:
+# the fixed cost of a visit to a block (its 64 page copies, its planes'
+# casts, a group's mask) is shared by a tile's rows.  Measured kernel alone
+# at MiniCPM-SALA's 32 heads, 2048 rows x 16k / 32k / 64k (my chip runs, PR
+# 54): tiles of 128 rows 7.55 / 15.07 / 30.11 ms (the copies of 4 KB a page
+# no longer hide behind the arithmetic), 256 4.76 / 9.34 / 18.53, 512 4.33 /
+# 8.46 / 16.75.
+CHUNK_TILE_ROWS = (512, 256, 128, 64, 32)
+
+
+class ChunkTiles(NamedTuple):
+    """The static sizes of one :func:`attend_chunk_blocks` call."""
+
+    heads: int       # H
+    kv_heads: int    # H_kv
+    hd: int          # key head width
+    hdv: int         # value head width
+    pt: int          # positions a page
+    ppb: int         # pages a block
+    rows: int        # query rows a tile
+    per: int         # selection blocks a block (0: nothing chosen)
+    quant: bool
+    vmem: int        # bytes of fast memory a step may take
+
+
+def chunk_tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads, block,
+                chosen=None):
+    """The :class:`ChunkTiles` of one slot's ``q_shape[1]`` query rows over
+    these pools by blocks of ``block`` positions, or None where the kernel
+    does not tile the shapes: the walk then serves them.  ``chosen`` =
+    ``(mask shape, width)`` of a selection laid over the walk."""
+    import jax.numpy as jnp
+
+    kd, vd = _plane(k_pool), _plane(v_pool)
+    quant = _is_quant(k_pool)
+    if quant != _is_quant(v_pool) or kd.dtype != vd.dtype \
+            or kd.ndim != 3 or vd.shape[:2] != kd.shape[:2]:
+        return None
+    item = jnp.dtype(kd.dtype).itemsize
+    tq, e = q_shape[1], q_shape[2]
+    h = int(num_heads)
+    kvh = int(num_kv_heads) or h
+    pt, ek, ev = kd.shape[1], kd.shape[2], vd.shape[2]
+    if q_shape[0] != 1 or h <= 0 or kvh <= 0 or h % kvh or e % h \
+            or ev % kvh or ek != kvh * (e // h) or block % pt or item > 2:
+        return None
+    hd, hdv = e // h, ev // kvh
+    # whole pages of whole sublane tiles, whole lane tiles a head: a head's
+    # keys and values are cut out of a block at lane tiles
+    if pt % 8 or hd % LANES or hdv % LANES or block % LANES:
+        return None
+    if quant and k_pool.scale.shape[1] != pt * 2 * kvh:
+        return None
+    per = groups = 0
+    if chosen is not None:
+        shape, wide = chosen
+        if len(shape) != 4 or shape[0] != 1 or shape[1] != kvh \
+                or shape[2] != tq or block % wide \
+                or LANES % (block // wide):
+            return None
+        per, groups = block // wide, -(-shape[3] // LANES)
+    for rows in CHUNK_TILE_ROWS:
+        if tq % rows:
+            continue
+        # the tile's running state and its queries (one buffer each), the
+        # block's two buffers a plane and the planes once more as the
+        # products read them, its scale rows, a group's mask, the scores of
+        # a head five times over
+        vmem = h * rows * (hdv * 4 + 2 * LANES * 4 + hd * 2 + 2 * 8 * 4) \
+            + 2 * kvh * groups * rows * LANES \
+            + block * (ek + ev) * (2 * item + 6) \
+            + (2 * _scale_rows(kvh) * block * 4 if quant else 0) \
+            + 5 * rows * block * 4
+        if vmem <= _VMEM_BUDGET:
+            return ChunkTiles(h, kvh, hd, hdv, pt, block // pt, rows, per,
+                              quant, vmem)
+    return None
+
+
+def _scale_rows(kvh):
+    """Rows of a block's turned scales: K's heads, V's heads, whole sublane
+    tiles."""
+    return -(-2 * kvh // 8) * 8
+
+
+def _chunk_kernel(pages_ref, total_ref, q_ref, *rest, t, scale, cap, tq):
+    """One invocation is a tile of ``t.rows`` query rows against the slot's
+    blocks up to the tile's causal limit.  ``pages_ref`` (blocks * ppb,) the
+    slot's page ids in the table's order, ``total_ref`` (1,) the slot's
+    length through the chunk's last row.  ``q_ref`` (H, rows, hd) bfloat16;
+    ``mask_ref`` (H_kv, lane tiles, rows, 128) int8 where something is
+    chosen.  The pools stay in HBM.  ``acc_ref`` (H, rows, hdv) is the
+    tile's accumulator and its output; ``stat_ref`` (H, 8, rows) takes the
+    maxima in row 0 and the sums in row 1 when the tile's last block is
+    done."""
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    rest = list(rest)
+    mask_ref = rest.pop(0) if t.per else None
+    k_hbm, v_hbm = rest.pop(0), rest.pop(0)
+    s_hbm = rest.pop(0) if t.quant else None
+    acc_ref, stat_ref, kbuf, vbuf = (rest.pop(0) for _ in range(4))
+    sbuf = rest.pop(0) if t.quant else None
+    m_scr, l_scr, sems = rest
+    rows, block = t.rows, t.ppb * t.pt
+    g = t.heads // t.kv_heads
+    nb = pages_ref.shape[0] // t.ppb
+    fill = jnp.finfo(jnp.float32).min
+    mm = q_ref.dtype
+
+    # row r of the tile sees the positions under total - (tq - 1) + r, and
+    # none at or above the view's capacity: the walk's limit
+    low = total_ref[0] - (tq - 1) + pl.program_id(0) * rows
+    limit = jnp.minimum(
+        low + jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), cap)
+    # the blocks that hold a position some row of the tile sees
+    visit = jnp.clip(_div(jnp.minimum(low + rows - 1, cap) + block - 1,
+                          block), 1, nb)
+
+    def pages_of(b, buf, go, unrolled=False):
+        def page(i, _):
+            at = pages_ref[b * t.ppb + i]
+            go(pltpu.make_async_copy(k_hbm.at[at], kbuf.at[buf, i],
+                                     sems.at[buf, 0]))
+            go(pltpu.make_async_copy(v_hbm.at[at], vbuf.at[buf, i],
+                                     sems.at[buf, 1]))
+
+        if unrolled:
+            for i in range(t.ppb):
+                page(i, None)
+        else:
+            jax.lax.fori_loop(0, t.ppb, page, None)
+        if t.quant:
+            go(pltpu.make_async_copy(s_hbm.at[b], sbuf.at[buf],
+                                     sems.at[buf, 2]))
+
+    start = lambda c: c.start()
+    wait = lambda c: c.wait()
+
+    def plane(ref, buf):
+        x = ref[buf]                            # (ppb, pt, E)
+        if x.dtype != mm:
+            x = x.astype(jnp.float32)
+        return x.reshape(block, x.shape[-1]).astype(mm)
+
+    m_scr[...] = jnp.full(m_scr.shape, fill, jnp.float32)
+    l_scr[...] = jnp.zeros(l_scr.shape, jnp.float32)
+    acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+    pages_of(0, 0, start)
+
+    def attend(b, _):
+        buf = _rem(b, 2)
+
+        @pl.when(b + 1 < visit)
+        def _next():
+            pages_of(b + 1, 1 - buf, start, unrolled=True)
+
+        pages_of(b, buf, wait)
+        keys, values = plane(kbuf, buf), plane(vbuf, buf)
+        turned = sbuf[buf] if t.quant else None
+        pos = b * block + jax.lax.broadcasted_iota(
+            jnp.int32, (rows, block), 1)
+        under = pos < limit
+        if t.per:
+            # the block's columns of the selection: ``per`` lanes of one
+            # lane tile, widened to positions by a 0/1 matrix
+            lane = jax.lax.broadcasted_iota(jnp.int32, (LANES, block), 0)
+            col = _div(jax.lax.broadcasted_iota(
+                jnp.int32, (LANES, block), 1), block // t.per)
+            widen = jnp.where(lane == _rem(b * t.per, LANES) + col, 1.0,
+                              0.0).astype(mm)
+            tile_of = _div(b * t.per, LANES)
+        for j in range(t.kv_heads):
+            seen = under
+            if t.per:
+                picked = mask_ref[j, tile_of].astype(jnp.float32).astype(mm)
+                seen = under & (jax.lax.dot_general(
+                    picked, widen, (((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32) > 0.5)
+            kj = keys[:, j * t.hd:(j + 1) * t.hd]
+            vj = values[:, j * t.hdv:(j + 1) * t.hdv]
+            ks = jnp.float32(scale)
+            vs = None
+            if t.quant:
+                ks = turned[j:j + 1] * jnp.float32(scale)
+                vs = turned[t.kv_heads + j:t.kv_heads + j + 1]
+
+            def head(i, _, seen=seen, kj=kj, vj=vj, ks=ks, vs=vs, j=j):
+                h = j * g + i
+                s = jax.lax.dot_general(
+                    q_ref[h], kj, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32)     # (rows, block)
+                s = jnp.where(seen, s * ks, fill)
+                m0 = m_scr[h]                               # (rows, 128)
+                m1 = jnp.maximum(m0, jnp.max(s, axis=1, keepdims=True))
+                shrink = jnp.exp(m0 - m1)
+                p = jnp.exp(s - jnp.concatenate([m1] * (block // LANES),
+                                                axis=1))
+                l_scr[h] = shrink * l_scr[h] \
+                    + jnp.sum(p, axis=1, keepdims=True)
+                m_scr[h] = m1
+                if vs is not None:
+                    p = p * vs
+                acc_ref[h] = acc_ref[h] * jnp.concatenate(
+                    [shrink] * (t.hdv // LANES), axis=1) \
+                    + jax.lax.dot_general(
+                        p.astype(mm), vj, (((1,), (0,)), ((), ())),
+                        preferred_element_type=jnp.float32)
+
+            jax.lax.fori_loop(0, g, head, None)
+
+    jax.lax.fori_loop(0, visit, attend, None)
+
+    # the maxima and the sums leave a row a lane: rows 0 and 1 of a tile
+    first = jax.lax.broadcasted_iota(jnp.int32, (8, rows), 0) == 0
+
+    def leave(h, _):
+        stat_ref[h] = jnp.where(first, m_scr[h].T[:8], l_scr[h].T[:8])
+
+    jax.lax.fori_loop(0, t.heads, leave, None)
+
+
+def attend_chunk_blocks(q, k_pool, v_pool, pages, total, cap, t, scale,
+                        chosen=None, interpret=False):
+    """One slot's query rows over its blocks: the walk's running row, ``(m
+    (1, tq, H), den (1, tq, H), acc (1, tq, H, hdv))`` float32, not yet
+    normalized.
+
+    ``q`` (1, tq, E), row ``i`` at position ``total - tq + i``; the pools as
+    the paged ops store them; ``pages`` (blocks, ppb) the slot's page ids in
+    the table's order (a last block past the table's end reads the scratch
+    page); ``total`` the slot's length through the chunk's last row and
+    ``cap`` the view's capacity: row ``i`` attends the positions under
+    ``min(total - (tq - 1) + i, cap)``.  ``chosen`` = ``(mask (1, H_kv, tq,
+    n) bool, width)`` as the walk takes it; ``t`` the call's
+    :class:`ChunkTiles`."""
+    import jax.numpy as jnp
+
+    tq = q.shape[1]
+    qh = jnp.swapaxes(q[0].reshape(tq, t.heads, t.hd), 0, 1)  # (H, tq, hd)
+    if not interpret:
+        # the products read their operands in the query's type, as the
+        # walk's einsums do; a float32 one is ONE bfloat16 pass on the chip
+        # (XLA's default precision), so the cast is made once, out here.
+        # The interpreter's products are the CPU's: float32 as they lie
+        qh = qh.astype(jnp.bfloat16)
+    mask = None
+    if chosen is not None:
+        mask = chosen[0][0].astype(jnp.int8)                # (H_kv, tq, n)
+        n = mask.shape[2]
+        mask = jnp.pad(mask, ((0, 0), (0, 0), (0, -n % LANES)))
+        mask = jnp.swapaxes(mask.reshape(t.kv_heads, tq, -1, LANES), 1, 2)
+    turned = None
+    if t.quant:
+        # the slot's scale rows, turned once a call: (blocks, K's heads then
+        # V's, positions of a block).  Not copied page by page inside the
+        # kernel as the decode row's are: a plane of 2 KV heads is 64 floats
+        # a page, half a lane tile, and Mosaic cuts a tiled plane at whole
+        # tiles; and a turn made in the kernel is made again by every tile
+        # of rows that visits the block
+        w = 2 * t.kv_heads
+        turned = jnp.swapaxes(k_pool.scale[pages.reshape(-1)].reshape(
+            pages.shape[0], t.ppb * t.pt, w), 1, 2)
+        turned = jnp.pad(turned, ((0, 0), (0, _scale_rows(t.kv_heads) - w),
+                                  (0, 0)))
+    acc, stats = _jitted_chunk()(
+        qh, mask, _plane(k_pool), _plane(v_pool), turned, pages, total, t=t,
+        scale=float(scale), cap=int(cap), interpret=bool(interpret))
+    return (jnp.swapaxes(stats[:, 0], 0, 1)[None],
+            jnp.swapaxes(stats[:, 1], 0, 1)[None],
+            jnp.swapaxes(acc, 0, 1)[None])
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_chunk():
+    import jax
+
+    return jax.jit(_attend_chunk_blocks,
+                   static_argnames=("t", "scale", "cap", "interpret"))
+
+
+def _attend_chunk_blocks(qh, mask, kd, vd, turned, pages, total, *, t, scale,
+                         cap, interpret):
+    import jax
+    import jax.numpy as jnp
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    tq = qh.shape[1]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    # one buffer a tile's queries and values: a tile is long (every block
+    # under its limit, every head), so the copies at its ends need not hide
+    # behind arithmetic, and a second buffer of each would halve the tile
+    once = pl.Buffered(1)
+    in_specs = [pl.BlockSpec((t.heads, t.rows, t.hd),
+                             lambda i, *_: (0, i, 0), pipeline_mode=once)]
+    args = [qh]
+    if mask is not None:
+        in_specs.append(pl.BlockSpec(
+            (t.kv_heads, mask.shape[1], t.rows, LANES),
+            lambda i, *_: (0, 0, i, 0)))
+        args.append(mask)
+    in_specs += [hbm, hbm]
+    args += [kd, vd]
+    scratch = [pltpu.VMEM((2, t.ppb) + kd.shape[1:], kd.dtype),
+               pltpu.VMEM((2, t.ppb) + vd.shape[1:], vd.dtype)]
+    if t.quant:
+        in_specs.append(hbm)
+        args.append(turned)
+        scratch.append(pltpu.VMEM((2,) + turned.shape[1:], jnp.float32))
+    scratch += [pltpu.VMEM((t.heads, t.rows, LANES), jnp.float32),
+                pltpu.VMEM((t.heads, t.rows, LANES), jnp.float32),
+                pltpu.SemaphoreType.DMA((2, 3))]
+    with jax.enable_x64(False):
+        acc, stats = pl.pallas_call(
+            functools.partial(_chunk_kernel, t=t, scale=float(scale),
+                              cap=int(cap), tq=tq),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=2,
+                grid=(tq // t.rows,),
+                in_specs=in_specs,
+                out_specs=[
+                    pl.BlockSpec((t.heads, t.rows, t.hdv),
+                                 lambda i, *_: (0, i, 0), pipeline_mode=once),
+                    pl.BlockSpec((t.heads, 8, t.rows),
+                                 lambda i, *_: (0, 0, i))],
+                scratch_shapes=scratch),
+            out_shape=[
+                jax.ShapeDtypeStruct((t.heads, tq, t.hdv), jnp.float32),
+                jax.ShapeDtypeStruct((t.heads, 8, tq), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=int(min(100 << 20,
+                                         max(32 << 20, 2 * t.vmem)))),
+            name="chunk_live_blocks",
+            interpret=interpret,
+        )(pages.reshape(-1).astype(jnp.int32),
+          jnp.reshape(total, (1,)).astype(jnp.int32), *args)
+    return acc, stats

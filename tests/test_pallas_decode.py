@@ -199,27 +199,33 @@ def test_dead_rows_of_the_padded_list_reach_nothing(interpret):
 # what each attention node of a serving cell takes in the decode step and in
 # a prefill chunk, in graph order (runs of equal nodes written once)
 RULE = {
+    # heads of 64: no whole lane tiles for the chunk's kernel
     "opt_serve_backlog": [("decode-kernel", "walk")] * 24,
+    # chunks of 256 rows: under the chunk kernel's constant
     "falconh1_serve_chat": [("decode-kernel", "walk")] * 6,
-    # full, four window rings, full, a window ring
+    # full (keys of 192: the chunk keeps the walk), four window rings, full,
+    # a window ring
     "mimo_serve_longshort": [("decode-kernel", "walk")]
     + [("whole", "whole")] * 4 + [("decode-kernel", "walk"),
                                   ("whole", "whole")],
-    # two rows a slot (a draft beside the committed token): the walk
+    # two rows a slot (a draft beside the committed token): the walk; its
+    # chunks of 512 rows too
     "exaone_serve_reason": [("whole", "whole")] * 3 + [("walk", "walk"),
                                                        ("whole", "whole"),
                                                        ("walk", "walk")],
-    # sparse selection attends its own lists
-    "sala_serve_longctx": [("sparse", "sparse")] * 3,
+    # a decode row attends the list it chose; a chunk of 2048 rows lays its
+    # selection over the live blocks inside the chunk's kernel
+    "sala_serve_longctx": [("sparse", "chunk-kernel")] * 3,
+    "solar2_serve_agent": [("decode-kernel", "chunk-kernel")],
 }
 
 
 @pytest.mark.parametrize("cell", sorted(RULE))
 def test_rule_over_the_serving_configurations(cell, interpret):
-    """``decode_kernel_selected`` over the decode-row and chunk shapes of
-    every attention node of the five serving configurations, the shapes
-    read from the files under ``chipbench/configs`` and
-    ``chipbench/traffic``."""
+    """``decode_kernel_selected`` and ``chunk_kernel_selected`` over the
+    decode-row and chunk shapes of every attention node of six serving
+    configurations, the shapes read from the files under
+    ``chipbench/configs`` and ``chipbench/traffic``."""
     nodes = probe.serving_nodes(cell)
     got = [(probe.decode_path(n), probe.decode_path(n, tq=n["chunk"]))
            for n in nodes]
@@ -227,6 +233,8 @@ def test_rule_over_the_serving_configurations(cell, interpret):
     # a backend that runs no Pallas takes the kernel nowhere
     with config.overrides(MXNET_PALLAS_INTERPRET="0"):
         assert "decode-kernel" not in {probe.decode_path(n) for n in nodes}
+        assert "chunk-kernel" not in {
+            probe.decode_path(n, tq=n["chunk"]) for n in nodes}
 
 
 def _selected(q_shape=(4, 1, 256), ek=256, ev=256, kvh=4, heads=4, pages=M,
@@ -475,6 +483,66 @@ def test_kernel_compiles_for_the_chip_at_the_cells_shapes(cell, one_chip,
     assert " while(" not in text
     block = attn.live_block_plan((b, 1), (b, m), node["pt"])[0]
     assert not re.search(r"s8\[\d+,%d,%d\]" % (block, node["ek"]), text)
+
+
+@pytest.mark.parametrize("cell", ["sala_serve_longctx", "solar2_serve_agent",
+                                  "falconh1_serve_chat",
+                                  "exaone_serve_reason"])
+def test_chunk_kernel_compiles_for_the_chip_at_the_cells_shapes(
+        cell, one_chip, monkeypatch):
+    """A prefill chunk of each cell's first full node through
+    ``_attend_live_blocks`` (MiniCPM-SALA's with its selection's mask laid
+    over it), compiled by the chip's own compiler: Mosaic takes the chunk's
+    kernel at the published widths (``tests/test_pallas_chunk.py`` holds its
+    results against the walk's), and the program holds no loop and no array
+    of a gathered block's shape.  Falcon-H1's chunks of 256 rows and
+    K-EXAONE's of 512 lie under the rule's constant, lowered here: a cell
+    that cut the same nodes' prompts into chunks of 1024 would take the
+    kernel at these widths (blocks of 256, 20 heads over 4)."""
+    import re
+
+    from jax.experimental.compilation_cache import compilation_cache
+
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
+    monkeypatch.setattr(attn, "CHUNK_MIN_ROWS", 256)
+    node = next(n for n in probe.serving_nodes(cell)
+                if probe.decode_path(n, tq=n["chunk"]) == "chunk-kernel")
+    tq, m = node["chunk"], node["cap"] // node["pt"]
+    h, kvh = node["heads"], node["kv_heads"]
+    aval = lambda s: jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=one_chip),
+        s)
+    kp, vp = probe.abstract_pools(node)
+    args = [jax.ShapeDtypeStruct((1, tq, node["e"]), jnp.float32), kp, vp,
+            jax.ShapeDtypeStruct((1, m), jnp.int32),
+            jax.ShapeDtypeStruct((1,), jnp.int32)]
+    width = node["spec"].block if node["sparse"] else 0
+    if width:
+        args.append(jax.ShapeDtypeStruct(
+            (1, kvh, tq, -(-node["cap"] // width)), jnp.bool_))
+    plan = attn.live_block_plan((1, tq), (1, m), node["pt"])
+    tiles, _ = attn.chunk_kernel_selected(
+        args[0].shape, kp, vp, (1, m), h, kvh,
+        chosen=(args[5].shape, width) if width else None)
+    assert tiles is not None and tiles.rows >= 128
+
+    def attend(q, kp, vp, table, total, mask=None):
+        return attn._attend_live_blocks(
+            q, kp, vp, table, total, h, None, kvh, *plan,
+            chosen=None if mask is None else (mask, width),
+            chunk=(tiles, False))
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        text = jax.jit(attend).lower(*aval(args)).compile().as_text()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+    assert "tpu_custom_call" in text
+    assert " while(" not in text
+    assert not re.search(r"s8\[\d+,%d,%d\]" % (plan[0], node["ek"]), text)
 
 
 # ---------------------------------------------------------------------------
