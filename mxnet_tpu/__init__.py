@@ -14,6 +14,15 @@ Typical usage matches the reference:
     mod = mx.mod.Module(net, ...)
     mod.fit(train_iter, ...)
 """
+import time as _time
+
+_t0 = _time.perf_counter_ns()
+# the process accounts for its own start (obs/startup.py): this file from
+# here to its last line is phase `import.self`, `import jax` apart from it
+from .obs import startup as _startup  # noqa: E402
+
+_importing = _startup.begin_import(_t0)
+
 from . import base
 from .base import MXNetError, AttrScope, NameManager
 from .context import Context, cpu, gpu, tpu, current_context, num_devices
@@ -68,3 +77,6 @@ from . import contrib
 from . import test_utils
 
 __version__ = "0.1.0"
+
+_importing.__exit__(None, None, None)
+del _time, _t0, _startup, _importing

@@ -346,6 +346,7 @@ class DecodePredictor:
         Arm copy-on-write prefix sharing in paged mode (default on).
     """
 
+    @_obs.phased("build.predictor")
     def __init__(self, symbol, params, cache_len, ctx=None, mesh=None,
                  temperature=0.0, top_k=0, data_name="data", kv_dtype=None,
                  paged=None, page_tokens=None, pool_pages=None,
@@ -657,7 +658,8 @@ class DecodePredictor:
 
         if getattr(self, "_vocab", None) is None:
             env = {n: aval_of(v) for n, v in self._env.items()}
-            with probing(self):
+            with _obs.phase("build.shape_probe", program="shape_probe"), \
+                    probing(self):
                 self._vocab = int(jax.eval_shape(
                     lambda e: self._run(e, jnp.zeros((1, 1), jnp.float32),
                                         None, 0)[0], env).shape[-1])
@@ -1880,6 +1882,7 @@ class DecodePredictor:
                 caches, jnp.asarray(row, jnp.int32).reshape(-1),
                 jtu.tree_map(jnp.asarray, data))
 
+    @_obs.phased("build.shape_probe", program="shape_probe")
     def _probe_cache_shapes(self):
         """Per-attention-node cache avals — (1, C, E) K/V (or QuantKV)
         from an abstract prefill at (1, 1), the shape source for building
@@ -3202,6 +3205,7 @@ class DecodeServer:
     any other host.
     """
 
+    @_obs.phased("build.server")
     def __init__(self, predictor, max_prefill, slots=None, eos_id=None,
                  max_new_tokens=None, seed=0, spec_k=None, proposer=None,
                  draft=None, metrics_port=None, host=None):
@@ -3845,10 +3849,14 @@ class DecodeServer:
         live; :meth:`serve_reset` closes it (compiled programs are
         per-predictor and survive — a reopened session retraces
         nothing)."""
-        import jax
-
         if self._ps is not None:
             return self._ps
+        with _obs.phase("build.serve_open"):
+            return self._open_session()
+
+    def _open_session(self):
+        import jax
+
         pred = self._pred
         slots = self._slots
         # AOT cold start (MXNET_AOT): before the first request, load
@@ -3868,9 +3876,10 @@ class DecodeServer:
             prop = self._proposer
             probs_prop = getattr(prop, "predictor", None) is not None \
                 and not prop.predictor._greedy
-            self.aot_report = pred.prepare_programs(
-                slots, chunk_w=self._chunk_w,
-                spec_k=0 if probs_prop else self._spec_k)
+            with _obs.phase("build.serve_open.aot"):
+                self.aot_report = pred.prepare_programs(
+                    slots, chunk_w=self._chunk_w,
+                    spec_k=0 if probs_prop else self._spec_k)
         elif _aot.enabled():
             import logging
 
@@ -4235,7 +4244,7 @@ class DecodeServer:
         ps = self.serve_open()
         ps["tick"] += 1
         args = {"tick": ps["tick"]}
-        with _obs.span("serve.tick", cat="serve", args=args):
+        with _obs.top_span("serve.tick", cat="serve", args=args):
             args["read"] = self._tick(ps)
         self._m_ticks.labels(read=args["read"]).inc()
 

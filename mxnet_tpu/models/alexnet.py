@@ -1,7 +1,9 @@
 """AlexNet (reference: example/image-classification/symbol_alexnet.py)."""
+from .. import obs as _obs
 from .. import symbol as sym
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=1000, **kwargs):
     input_data = sym.Variable(name="data")
     # stage 1

@@ -12,6 +12,7 @@ from __future__ import annotations
 
 import numpy as np
 
+from .. import obs as _obs
 from .. import symbol as sym
 from ..base import AttrScope
 from ..obs.scopes import LAYER_ATTR
@@ -89,6 +90,7 @@ def block(data, embed, heads, ffn_hidden, name, moe_experts=0,
     return data + ffn
 
 
+@_obs.phased("build.symbol")
 def get_symbol(vocab_size, seq_len, num_layers=2, embed=128, heads=4,
                ffn_hidden=512, moe_experts=0, moe_capacity_factor=0.0,
                moe_top_k=1, num_kv_heads=0, **kwargs):

@@ -114,6 +114,7 @@ first_layer + num_layers - 1`` of the per-layer lists are built.
 """
 from __future__ import annotations
 
+from .. import obs as _obs
 from .. import symbol as sym
 from ..base import AttrScope
 from ..obs.scopes import LAYER_ATTR
@@ -318,6 +319,7 @@ def ssm_mixer(data, name, hidden, heads, head_dim, state, groups, conv,
                               name=name + "_ssm_out")
 
 
+@_obs.phased("build.symbol")
 def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                head_dim, hybrid_layer_pattern=None, moe_layer_freq=None,
                intermediate_size=0, num_key_value_heads=0, v_head_dim=0,

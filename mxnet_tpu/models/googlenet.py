@@ -7,6 +7,7 @@ the paper's Table 1 configuration; auxiliary classifier heads are omitted
 """
 from __future__ import annotations
 
+from .. import obs as _obs
 from .. import symbol as sym
 
 
@@ -45,6 +46,7 @@ _BLOCKS = [
 ]
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=1000, **kwargs):
     data = sym.Variable("data")
     net = _conv(data, 64, (7, 7), stride=(2, 2), pad=(3, 3), name="stem1")

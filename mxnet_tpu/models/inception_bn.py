@@ -1,4 +1,5 @@
 """Inception-BN (reference: example/image-classification/symbol_inception-bn.py)."""
+from .. import obs as _obs
 from .. import symbol as sym
 
 BN_EPS = 0.001
@@ -51,6 +52,7 @@ def InceptionFactoryB(data, num_3x3red, num_3x3, num_d3x3red, num_d3x3, name):
     return sym.Concat(c3x3, cd3x3, pooling, name="ch_concat_%s_chconcat" % name)
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=1000, **kwargs):
     data = sym.Variable("data")
     # stage 1

@@ -7,6 +7,7 @@ reductions, BN after every conv.  299x299 input.
 """
 from __future__ import annotations
 
+from .. import obs as _obs
 from .. import symbol as sym
 
 BN_EPS = 2e-5
@@ -87,6 +88,7 @@ def _block_c(data, name):
     return sym.Concat(t1, t2a, t2b, t3a, t3b, t4, name=name)
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=1000, **kwargs):
     data = sym.Variable("data")
     # stem: 299 -> 35

@@ -1,7 +1,9 @@
 """LeNet (reference: example/image-classification/symbol_lenet.py)."""
+from .. import obs as _obs
 from .. import symbol as sym
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=10, **kwargs):
     data = sym.Variable("data")
     # first conv

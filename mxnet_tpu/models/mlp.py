@@ -1,7 +1,9 @@
 """MLP (reference: example/image-classification/symbol_mlp.py)."""
+from .. import obs as _obs
 from .. import symbol as sym
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=10, **kwargs):
     data = sym.Variable("data")
     data = sym.Flatten(data)

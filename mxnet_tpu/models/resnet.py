@@ -6,6 +6,7 @@ match the reference's 2e-5 / 0.9.
 """
 from __future__ import annotations
 
+from .. import obs as _obs
 from .. import symbol as sym
 
 BN_EPS = 2e-5
@@ -102,6 +103,7 @@ def resnet(units, num_stages, filter_list, num_classes, image_shape,
     return sym.SoftmaxOutput(fc1, name="softmax")
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=1000, num_layers=50, image_shape=(3, 224, 224),
                **kwargs):
     """Layer-count → configuration table (reference: symbol_resnet.py:118-151)."""

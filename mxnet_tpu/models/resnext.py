@@ -8,6 +8,7 @@ tiles it as a block-diagonal matmul, no per-group loop.
 """
 from __future__ import annotations
 
+from .. import obs as _obs
 from .. import symbol as sym
 
 BN_EPS = 2e-5
@@ -43,6 +44,7 @@ def resnext_unit(data, num_filter, stride, dim_match, cardinality,
     return sym.Activation(b3 + shortcut, act_type="relu")
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=1000, num_layers=50, cardinality=32,
                bottleneck_width=4, image_shape=(3, 224, 224), **kwargs):
     units = {50: [3, 4, 6, 3], 101: [3, 4, 23, 3],

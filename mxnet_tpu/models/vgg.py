@@ -1,7 +1,9 @@
 """VGG (reference: example/image-classification/symbol_vgg.py)."""
+from .. import obs as _obs
 from .. import symbol as sym
 
 
+@_obs.phased("build.symbol")
 def get_symbol(num_classes=1000, **kwargs):
     data = sym.Variable(name="data")
     # group 1

@@ -246,8 +246,8 @@ class BaseModule:
                 # one fit_step span per iteration; its children are
                 # input_wait, the step's own train_step program span,
                 # metric_update, host_wait and batch_end_callback
-                with _obs.span("fit_step", cat="loop",
-                               args={"step": nbatch}):
+                with _obs.top_span("fit_step", cat="loop",
+                                   args={"step": nbatch}):
                     t0 = time.perf_counter()
                     with _obs.mirror("input_wait"):
                         try:

@@ -12,6 +12,7 @@ import logging
 
 from .. import context as ctx_mod
 from .. import ndarray as nd
+from .. import obs as _obs
 from .. import optimizer as opt_mod
 from ..base import MXNetError
 from ..model import save_checkpoint, load_checkpoint, _create_kvstore
@@ -139,6 +140,7 @@ class Module(BaseModule):
             self._sync_params_from_devices()
         return (self._arg_params, self._aux_params)
 
+    @_obs.phased("build.init_params")
     def init_params(self, initializer=None, arg_params=None, aux_params=None,
                     allow_missing=False, force_init=False):
         if self.params_initialized and not force_init:
@@ -210,6 +212,7 @@ class Module(BaseModule):
         self.params_initialized = True
 
     # ------------------------------------------------------------------
+    @_obs.phased("build.bind")
     def bind(self, data_shapes, label_shapes=None, for_training=True,
              inputs_need_grad=False, force_rebind=False, shared_module=None,
              grad_req="write"):
@@ -301,6 +304,7 @@ class Module(BaseModule):
                             force_init=True)
 
     # ------------------------------------------------------------------
+    @_obs.phased("build.init_optimizer")
     def init_optimizer(self, kvstore="local", optimizer="sgd",
                        optimizer_params=(("learning_rate", 0.01),),
                        force_init=False):

@@ -14,6 +14,10 @@ Three layers (docs/observability.md), shared process-wide singletons:
   each program's scope map (:mod:`~mxnet_tpu.obs.scopes`: which layer
   every instruction of its optimized HLO belongs to).
 
+The process's own start and every compile are accounted for by
+:mod:`~mxnet_tpu.obs.startup` (``obs.phase`` / ``obs.top_span``, the one
+``jax.monitoring`` listener).
+
 ``profiler`` (the historical module) is a thin compatibility facade over
 these; new code records here directly.  Instrumentation is HOST-side
 only: nothing in this package runs inside a traced program (the layer
@@ -37,9 +41,9 @@ __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricsServer",
     "PEAK_FLOPS", "PeriodicExporter", "ProgramAccounting", "TraceTimeline",
     "auto_peak", "enabled", "mfu_table", "mirror", "peak_flops_for",
-    "percentile",
+    "percentile", "phase", "phased",
     "program_span", "programs", "registry", "render_mfu_table",
-    "serve_metrics", "span", "timeline",
+    "serve_metrics", "span", "timeline", "top_span",
 ]
 
 from .. import config as _config
@@ -84,13 +88,16 @@ class _NullCtx:
 
 _NULL = _NullCtx()
 
+from .startup import _tls, phase, phased, top_span  # noqa: E402
+
 
 class _ProgramSpan:
     """Times one compiled-program dispatch: feeds the roofline
     accounting AND drops a span on the timeline (cat="program"), off
-    one clock reading at each end."""
+    one clock reading at each end.  While it is open the thread's compile
+    stages are booked under its name (:mod:`~mxnet_tpu.obs.startup`)."""
 
-    __slots__ = ("_name", "_t0", "_ann")
+    __slots__ = ("_name", "_t0", "_ann", "_outer")
 
     def __init__(self, name):
         self._name = name
@@ -98,11 +105,14 @@ class _ProgramSpan:
 
     def __enter__(self):
         self._ann.__enter__()
+        self._outer = _tls.program
+        _tls.program = self._name
         self._t0 = time.perf_counter_ns()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
+        _tls.program = self._outer
         programs.note(self._name, (t1 - self._t0) * 1e-9)
         timeline.add_span_ns(self._name, self._t0, t1, cat="program")
         self._ann.__exit__(*exc)

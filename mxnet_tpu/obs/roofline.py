@@ -85,23 +85,12 @@ def auto_peak():
         return None
 
 
-# backend compiles this process has made (a persistent-cache read counts:
-# it loads a program too), counted by one listener for the map readers
-_compiles = {"seen": 0, "listening": False}
-
-
-def _on_compile(event, _seconds, **_):
-    if event == "/jax/core/compile/backend_compile_duration":
-        _compiles["seen"] += 1
-
-
 def _backend_compiles():
-    if not _compiles["listening"]:
-        import jax
+    """Backend compiles this process has made (a persistent-cache read
+    counts: it loads a program too), from the process's one listener."""
+    from .startup import backend_compiles
 
-        _compiles["listening"] = True
-        jax.monitoring.register_event_duration_secs_listener(_on_compile)
-    return _compiles["seen"]
+    return backend_compiles()
 
 
 def _what(row):

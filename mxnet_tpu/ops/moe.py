@@ -265,6 +265,10 @@ def _experts_grouped(xt, wg, wu, wd, here, weight, layer, interpret):
     refuses one by name."""
     import jax
     import jax.numpy as jnp
+
+    from ..obs.startup import pallas
+
+    pallas("jax.experimental.pallas.ops.tpu.megablox.gmm")
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     from ..obs.scopes import scope as _scope

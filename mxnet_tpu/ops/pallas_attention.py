@@ -43,6 +43,8 @@ import functools
 
 import numpy as np
 
+from ..obs.startup import pallas as _pallas
+
 # Block sizes: constants, swept by hand on the chip.  A block is one TILE of
 # scores; the kernels loop over tiles inside a grid step that holds
 # MAJOR_ROWS rows of K/V (forward) or of Q/dO (backward).  Measured on TPU
@@ -157,7 +159,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest, scale, causal, block_q,
     update on and nothing guards against -inf."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
+    pl = _pallas()[0]
 
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
@@ -280,8 +282,7 @@ def _major(block, t):
 def _fwd_kernel(q, k, v, *, scale, causal, interpret, with_lse, bq, bk,
                 major, g):
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     bh, t, d = q.shape
     grid = (bh, t // bq, t // major)
@@ -341,7 +342,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, dq_ref,
     in a (T, D) float32 scratch written out on the head's last step."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
+    pl = _pallas()[0]
 
     major = q_ref.shape[1]
     ntiles = major // block_q
@@ -443,7 +444,7 @@ def _bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref, dq_ref,
                    dq_scr, *, scale, causal, block_q, block_k):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
+    pl = _pallas()[0]
 
     i = pl.program_id(1)
     j = pl.program_id(2)
@@ -484,7 +485,7 @@ def _bwd_dkv_kernel_grouped(q_ref, k_ref, v_ref, do_ref, lse_ref, dta_ref,
     contributions before the single write-back — dK/dV land at the GROUPED
     width, no q-width gradient is ever materialized."""
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
+    pl = _pallas()[0]
 
     j = pl.program_id(1)   # key block (outer)
     i = pl.program_id(2)   # query block (accumulated)
@@ -558,8 +559,7 @@ def _bwd_kernels(q, k, v, o, lse, do, *, scale, causal, interpret, bq, bk,
     """``lse`` is (BH, T).  ``major`` > 0: the fused kernel over Q/dO blocks
     of that many rows; 0: the dQ kernel and the dK/dV kernel."""
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     bh, t, d = q.shape
     bh_kv = k.shape[0]

@@ -58,6 +58,8 @@ from typing import NamedTuple
 
 import numpy as np
 
+from ..obs.startup import pallas as _pallas
+
 LANES = 128
 # what a step's buffers and temporaries may take of fast memory (a v5e
 # core has 128 MiB; Mosaic's default scoped limit is 16)
@@ -177,7 +179,7 @@ def _rem(x, n):
 def _fold_lanes(x, width):
     """(n, 128) whose lanes hold one nonzero group of ``width``: every
     group becomes the sum of all (the nonzero one)."""
-    from jax.experimental.pallas import tpu as pltpu
+    pltpu = _pallas()[1]
 
     shift = LANES // 2
     while shift >= width:
@@ -198,8 +200,7 @@ def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
     leaves while row ``r + 1`` is."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     if t.quant:
         s_hbm, acc_hbm, stat_hbm, kbuf, vbuf, sbuf, accbuf, statbuf, sems, \
@@ -418,8 +419,7 @@ def _attend_blocks(qh, k_pool, v_pool, pages, slot, valid, live, *, t, scale,
                    interpret):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     rows = pages.shape[0]
     kd, vd = _plane(k_pool), _plane(v_pool)
@@ -604,8 +604,7 @@ def _latent_kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, hbm,
     other."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     live = live_ref[0]
     n = t.ppb * t.pr                            # rows of a block
@@ -727,8 +726,7 @@ def _attend_latent_blocks(qbd, plane, pages, slot, valid, live, *, t, scale,
                           interpret):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     rows = pages.shape[0]
     hbm = pl.BlockSpec(memory_space=pl.ANY)
@@ -885,8 +883,7 @@ def _chunk_kernel(pages_ref, total_ref, q_ref, *rest, t, scale, cap, tq):
     done."""
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     rest = list(rest)
     mask_ref = rest.pop(0) if t.per else None
@@ -1076,8 +1073,7 @@ def _attend_chunk_blocks(qh, mask, kd, vd, turned, pages, total, *, t, scale,
                          cap, interpret):
     import jax
     import jax.numpy as jnp
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
+    pl, pltpu = _pallas()
 
     tq = qh.shape[1]
     hbm = pl.BlockSpec(memory_space=pl.ANY)
