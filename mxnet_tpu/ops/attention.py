@@ -382,7 +382,9 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
     normalized probabilities before the PV product.  The float (B, C, E)
     copy :func:`dequantize_kv` returns is never built; the result is that
     of attending the dequantized buffers densely, which is what the
-    parity tests pin.  K and V go each by their own type, and a
+    parity tests pin, and with more than one query row it leaves in the
+    query's dtype (the stream's: :func:`_out_dtype`; one row leaves
+    float32).  K and V go each by their own type, and a
     plain-array cache takes the plain einsums.
 
     One query row (tq == 1, the decode step) over a stored plane takes
@@ -543,6 +545,8 @@ def _sdpa_cache(q, k_cache, v_cache, total_len, num_heads, scale,
             .astype(jnp.float32)
     if value_scale != 1.0:
         out = out * jnp.asarray(value_scale, out.dtype)
+    if v_scale is not None and tq > 1:
+        out = out.astype(q.dtype)       # _out_dtype: the stream's
     return out.reshape(b, tq, num_heads * (ev // kvh))
 
 
@@ -1131,6 +1135,7 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         pages = pages.reshape(b, nb, ppb)[slot, blk]          # (rows, ppb)
         steps = -(-ends[-1] // group)
     hdv = hdv or _plane(v_pool).shape[2] // (int(num_kv_heads) or num_heads)
+    out_dtype = _out_dtype(q, v_pool)
     if chunk is not None:
         from . import pallas_decode as _pd
 
@@ -1140,7 +1145,7 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
                 scale or 1.0 / np.sqrt(q.shape[2] // num_heads),
                 chosen=chosen, interpret=chunk[1])
         return _combine_blocks(m[:, None], den[:, None], acc[:, None], sink,
-                               value_scale, v_pool, layer)
+                               value_scale, out_dtype, layer)
     if kernel is not None:
         with _scope(layer, "scores"):
             j = jnp.arange(nb, dtype=jnp.int32)[None, :]
@@ -1158,7 +1163,8 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
             m = jnp.where(live, m, jnp.finfo(jnp.float32).min)
             den = jnp.where(live, den, 0.0)
             acc = jnp.where(live[..., None], acc, 0.0)
-        return _combine_blocks(m, den, acc, sink, value_scale, v_pool, layer)
+        return _combine_blocks(m, den, acc, sink, value_scale, out_dtype,
+                               layer)
     # B slots' blocks are kept apart until the loop has ended, one row a
     # block (row ``rows`` is never written: a slot's dead blocks read it).
     # One slot's blocks fold into one running row as the loop goes: a
@@ -1223,13 +1229,37 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
             j = jnp.arange(nb, dtype=jnp.int32)[None, :]
             where = jnp.where(j < reached[:, None], first[:, None] + j, rows)
             m, den, acc = (buf[where] for buf in parts)  # (B, nb, tq, H[, e])
-    return _combine_blocks(m, den, acc, sink, value_scale, v_pool, layer)
+    return _combine_blocks(m, den, acc, sink, value_scale, out_dtype, layer)
 
 
-def _combine_blocks(m, den, acc, sink, value_scale, v_pool, layer):
+def _out_dtype(q, v_pool):
+    """The type a cached attention's output leaves in: a float pool's own;
+    over a quantized pool, which has no float type to return to, that of
+    the queries (the stream's) where a slot brings more than one row, and
+    the float32 of the sums where it brings one.  The running maximum, sum
+    and accumulator inside are float32 either way.
+
+    Rows of many positions (a prefill chunk, a verify window, a drafting
+    tick's pair) are where a float32 stream costs: every product behind the
+    attention writes and reads float32 activations (6.2 ms of a 127-ms
+    chunk in ``sala_serve_longctx``, 5.7 of 84.4 in ``solar2_serve_agent``).
+    A decode row's products stream weights past 24-96 rows, which weigh
+    nothing, and with a bfloat16 stream ``opt_serve_backlog``'s tick read
+    8.48 ms where the float32 one reads 7.91 (my chip runs, PR 56: the same
+    attention, ``linear`` 1.9 -> 2.5 ms a tick; PERF.md section 7 asks
+    why): the row keeps the type it had."""
+    import jax.numpy as jnp
+
+    if not isinstance(v_pool, QuantKV):
+        return _plane(v_pool).dtype
+    return q.dtype if q.shape[1] > 1 else jnp.float32
+
+
+def _combine_blocks(m, den, acc, sink, value_scale, out_dtype, layer):
     """One softmax a slot from its blocks' shares ``m``, ``den`` (B, nb, tq,
-    H) and ``acc`` (B, nb, tq, H, hdv); the sink and the value scale join
-    here.  -> (B, tq, H * hdv)."""
+    H) and ``acc`` (B, nb, tq, H, hdv), all float32; the sink and the value
+    scale join here, and the result is cast once, after both, to
+    ``out_dtype`` (:func:`_out_dtype`).  -> (B, tq, H * hdv)."""
     import jax.numpy as jnp
 
     b, _, tq, num_heads, hdv = acc.shape
@@ -1245,9 +1275,7 @@ def _combine_blocks(m, den, acc, sink, value_scale, v_pool, layer):
         out = jnp.sum(w[..., None] * acc, axis=1) / den[..., None]
     if value_scale != 1.0:
         out = out * jnp.asarray(value_scale, out.dtype)
-    if not isinstance(v_pool, QuantKV):
-        out = out.astype(v_pool.dtype)
-    return out.reshape(b, tq, num_heads * hdv)
+    return out.astype(out_dtype).reshape(b, tq, num_heads * hdv)
 
 
 def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import obs
+from mxnet_tpu import config, obs
 from mxnet_tpu.decode import DecodePredictor, DecodeServer
 from mxnet_tpu.models import attention_lm
 from mxnet_tpu.ops import attention as attn
@@ -221,3 +221,126 @@ def test_a_table_of_one_block_counts_as_attended_whole():
              if e["name"] == "serve.readback" and e.get("args")]
     assert ticks and all(a["attn_blocks_live"] == a["attn_blocks_view"]
                          == 2 * 3 for a in ticks)
+
+
+# ---------------------------------------------------------------------------
+# the type the output leaves in: over a quantized pool the queries' (the
+# stream's) where a slot brings more than one row and float32 where it brings
+# one; the pool's over a float one; on every path
+# ---------------------------------------------------------------------------
+KPT, KM = 16, 40                # the kernels' view: 640 positions, 2.5 blocks
+SPARSE = attn.SparseSpec(topk=2, block=64, kernel=32, stride=KPT,
+                         init_blocks=1, window=64, dense_len=128)
+# path -> (slots, query rows, pages a slot, keywords, Pallas shown,
+#          the path counted)
+OUT_PATHS = {
+    "walk": (3, 1, KM, {}, False, "walk"),
+    "walk_chunk": (1, 32, KM, {}, False, "walk"),
+    "walk_verify": (3, 4, KM, {}, False, "walk"),
+    "decode-kernel": (3, 1, KM, {}, True, "decode-kernel"),
+    "chunk-kernel": (1, 32, KM, {}, True, "chunk-kernel"),
+    "whole": (3, 1, 16, {}, False, "whole"),
+    "whole_verify": (3, 4, 16, {}, False, "whole"),
+    "window_ring": (3, 1, KM, {"window": 128}, False, "whole"),
+    "window_ring_chunk": (1, 32, KM, {"window": 128}, False, "whole"),
+    "sink_and_value_scale": (
+        3, 1, KM, {"value_scale": 0.5, "sink": np.zeros((4,), np.float32)},
+        False, "walk"),
+    "sparse_row": (3, 1, KM, {"sparse": True}, False, None),
+    "sparse_walk": (1, 32, KM, {"sparse": True}, False, "walk"),
+    "sparse_chunk-kernel": (1, 32, KM, {"sparse": True}, True,
+                            "chunk-kernel"),
+    "sparse_whole": (1, 8, 16, {"sparse": True}, False, "whole"),
+}
+
+
+def _out_aval(path, pool, qdtype, monkeypatch):
+    slots, tq, pages, kw, pallas, counted = OUT_PATHS[path]
+    kw = dict(kw)
+    heads, hd = 4, 128      # a page's scale row fills a lane tile
+    sds = jax.ShapeDtypeStruct
+    plane = sds((1 + slots * pages, KPT, heads * hd),
+                jnp.int8 if pool == "int8" else jnp.dtype(pool))
+    if pool == "int8":
+        kp = attn.QuantKV(plane, sds((plane.shape[0], KPT * 2 * heads),
+                                     jnp.float32))
+        vp = attn.QuantKV(plane, None)
+    else:
+        kp = vp = plane
+    q = sds((slots, tq, heads * hd), qdtype)
+    table = sds((slots, pages), jnp.int32)
+    total = sds((slots,), jnp.int32)
+    monkeypatch.setattr(attn, "CHUNK_MIN_ROWS", 32)
+    attn.DECODE_PATH["last"] = None
+    with config.overrides(MXNET_PALLAS_INTERPRET="1" if pallas else "0"):
+        if kw.pop("sparse", False):
+            index = sds((plane.shape[0], heads * hd), jnp.bfloat16)
+            out = jax.eval_shape(
+                lambda q, kp, vp, index, table, total:
+                attn.paged_attend_sparse(q, kp, vp, index, table, total,
+                                         SPARSE, num_heads=heads)[0],
+                q, kp, vp, index, table, total)
+        else:
+            out = jax.eval_shape(
+                lambda q, kp, vp, table, total: attn.paged_attend(
+                    q, kp, vp, table, total, num_heads=heads, **kw),
+                q, kp, vp, table, total)
+    assert attn.DECODE_PATH["last"] == counted, path
+    assert out.shape == q.shape
+    return out
+
+
+@pytest.mark.parametrize("qdtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("path", sorted(OUT_PATHS))
+def test_over_int8_pages_the_output_leaves_in_the_queries_type(
+        path, qdtype, monkeypatch):
+    """A quantized pool has no float type to hand back, so every path
+    returns rows of many positions (a chunk, a verify window) in the type
+    of the queries it was given: a bfloat16 stream stays bfloat16 through
+    the attention, and float32 queries get what they got.  One row a slot
+    (the decode step) leaves in the float32 of its sums, whatever the
+    queries' type (``_out_dtype`` says why)."""
+    rows = OUT_PATHS[path][1]
+    assert _out_aval(path, "int8", qdtype, monkeypatch).dtype \
+        == (qdtype if rows > 1 else "float32")
+
+
+@pytest.mark.parametrize("path", ["walk", "chunk-kernel", "whole",
+                                  "window_ring", "decode-kernel"])
+def test_over_float_pages_the_output_leaves_in_the_pools_type(
+        path, monkeypatch):
+    assert _out_aval(path, "bfloat16", "bfloat16", monkeypatch).dtype \
+        == jnp.bfloat16
+    if path in ("walk", "whole", "window_ring"):
+        # the einsum paths take float32 queries over a bfloat16 pool too
+        assert _out_aval(path, "bfloat16", "float32", monkeypatch).dtype \
+            == jnp.bfloat16
+
+
+@pytest.mark.parametrize("ring", ["dense_ring", "paged_walk", "paged_whole"])
+def test_the_sums_inside_are_float32_and_the_cast_comes_last(ring):
+    """A verify window of bfloat16 queries over int8 keys and values: the
+    answer is the one float32 queries of the same values get (the planes
+    are exact in bfloat16, the sums float32 on both sides), rounded once."""
+    rng = np.random.RandomState(11)
+    heads, hd, b = 4, 8, 3
+    kp, vp = _pools(rng, 1 + b * PAGES, heads, hd, hd, "int8")
+    table = jnp.asarray(
+        1 + rng.permutation(b * PAGES).reshape(b, PAGES), jnp.int32)
+    total = jnp.asarray([5, CAP - 3, CAP], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, 4, heads * hd)), jnp.bfloat16)
+    if ring == "dense_ring":
+        kc, vc = attn.paged_gather_kv(kp, vp, table)
+        call = lambda q: attn.cache_attend(q, kc, vc, total, num_heads=heads)
+    elif ring == "paged_whole":
+        call = lambda q: attn.paged_attend(q, kp, vp, table, total,
+                                           num_heads=heads)
+    else:
+        call = lambda q: attn._attend_live_blocks(
+            q, kp, vp, table, total, heads, None, heads, BLOCK, 3)
+    got, want = call(q), call(q.astype(jnp.float32))
+    assert got.dtype == jnp.bfloat16 and want.dtype == jnp.float32
+    np.testing.assert_allclose(
+        np.asarray(got, np.float32),
+        np.asarray(want.astype(jnp.bfloat16), np.float32),
+        rtol=2e-2, atol=2e-2)
