@@ -86,7 +86,7 @@ def abstract_pools(node, pages=None):
     import jax
     import jax.numpy as jnp
 
-    from mxnet_tpu.ops.attention import QuantKV
+    from mxnet_tpu.ops.attention import QuantKV, scale_group
 
     p = pages or node["slots"] * (node["cap"] // node["pt"]) + 1
     dt = jnp.dtype(node["kv_dtype"])
@@ -95,7 +95,7 @@ def abstract_pools(node, pages=None):
     if dt.itemsize > 1:
         return k, v
     return (QuantKV(k, jax.ShapeDtypeStruct(
-        (p, node["pt"] * 2 * node["kv_heads"]), jnp.float32)),
+        (p, node["pt"] * scale_group(node["kv_heads"])), jnp.float32)),
         QuantKV(v, None))
 
 

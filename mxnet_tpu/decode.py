@@ -76,7 +76,8 @@ beside them, a row a page) and the recurrent ops :func:`state_ops` lists,
 each a statement of what it keeps a sequence and how a chunk and a step
 update it (``SelectiveSSM``, ``ops.ssm``: a conv tail and a state;
 ``LightningAttention``, ``ops.linattn``: one matrix state a head;
-``KimiDeltaAttention``, ``ops.kda``: a conv tail and a matrix state a head;
+``KimiDeltaAttention``, ``ops.kda``, and ``GatedDeltaNet``, ``ops.gdn``: a
+conv tail and a matrix state a head, square or Dk x Dv;
 no positions in any but those the op's own rotation reads).  Positions enter
 either
 as a learned positional table added via a ``broadcast_*`` op against a
@@ -235,20 +236,47 @@ class StateOp(NamedTuple):
     in place: ``active``), ``state`` a tuple of (rows, ...) leaves whose
     shapes the shape probe reads off a (1, 1) sequence, and ``counts`` the
     name under which the rows a decode step advanced are counted (a leaf of
-    :class:`DecodeState`, an argument of the ``serve.readback`` span)."""
+    :class:`DecodeState`, an argument of the ``serve.readback`` span).
+    ``advanced`` and ``held`` say, in the words of the serving loop's two
+    metrics of the op (``mx_<stem>_rows_total`` and ``mx_<stem>_state_bytes``,
+    ``<stem>`` = ``counts`` without its ``_rows``), what a row of it is and
+    what the state group holds of it."""
 
     mix: object
     counts: str
+    advanced: str
+    held: str
+
+    def metric(self, what):
+        return "mx_%s_%s" % (self.counts[:-len("_rows")], what)
 
 
 def state_ops():
     """``{op name: StateOp}``: the recurrent ops the decode walk carries a
     state for, each in a "state" cache layout."""
-    from .ops import kda as _kda, linattn as _linattn, ssm as _ssm
+    from .ops import gdn as _gdn, kda as _kda, linattn as _linattn, \
+        ssm as _ssm
 
-    return {_ssm.OP_NAME: StateOp(_ssm.mix, "ssm_rows"),
-            _linattn.OP_NAME: StateOp(_linattn.mix, "linattn_rows"),
-            _kda.OP_NAME: StateOp(_kda.mix, "kda_rows")}
+    rows = "(slot, %s node) rows whose %s state a decode step advanced " \
+        "(idle and mid-prefill slots left out)"
+    return {
+        _ssm.OP_NAME: StateOp(
+            _ssm.mix, "ssm_rows", rows % (_ssm.OP_NAME, "recurrent"),
+            "bytes of the state cache group: every slot's conv tails and "
+            "recurrent states"),
+        _linattn.OP_NAME: StateOp(
+            _linattn.mix, "linattn_rows",
+            rows % (_linattn.OP_NAME, "matrix"),
+            "bytes of the state cache group's LightningAttention rows: "
+            "every slot's (H, D, D) float32 states"),
+        _kda.OP_NAME: StateOp(
+            _kda.mix, "kda_rows", rows % (_kda.OP_NAME, "matrix"),
+            "bytes of the state cache group's KimiDeltaAttention rows: "
+            "every slot's convolution tails and (H, D, D) float32 states"),
+        _gdn.OP_NAME: StateOp(
+            _gdn.mix, "gdn_rows", rows % (_gdn.OP_NAME, "matrix"),
+            "bytes of the state cache group's GatedDeltaNet rows: every "
+            "slot's convolution tails and (H, Dk, Dv) float32 states")}
 
 
 class CacheLayout(NamedTuple):
@@ -290,8 +318,8 @@ class DecodeState(NamedTuple):
                         # the paged step advanced; None as ``moe`` is
     counts: object = None   # {name: int32} of what else the paged step's
                             # nodes counted (linattn_rows, kda_rows,
-                            # sparse_blocks_chosen / _live); None as ``moe``
-                            # is
+                            # gdn_rows, sparse_blocks_chosen / _live); None
+                            # as ``moe`` is
     draft: object = None    # (B, 1) int32: the token the graph's own
                             # prediction block drafted for the position after
                             # ``tok``; None (no leaf) unless the state is a
@@ -803,7 +831,7 @@ class DecodePredictor:
     def state_nodes(self, counts):
         """How many of the graph's recurrent nodes are of the op that
         counts its rows as ``counts`` ("ssm_rows", "linattn_rows",
-        "kda_rows")."""
+        "kda_rows", "gdn_rows")."""
         return sum(n.op.name in self._state_ops
                    and self._state_ops[n.op.name].counts == counts
                    for n in self._cache_nodes)
@@ -873,7 +901,9 @@ class DecodePredictor:
         probed batch-1 ``aval``: ``pages`` pages of ``page_tokens``
         positions, or ``pages`` state rows.  The scale plane a node's two
         quantized pools share (``is_scale``) is a row a page, (pages,
-        page_tokens * 2 * H_kv): ``ops.attention.QuantKV``; so is the index
+        page_tokens * 2 * H_kv; a token's stretch padded where 2 * H_kv
+        does not divide a lane tile: ``ops.attention.scale_group``):
+        ``ops.attention.QuantKV``; so is the index
         of a node with sparse selection (``is_index``), (pages, H_kv * D).
         A latent node's one plane holds page_tokens * (rank + rope) values
         a page (``ops.pallas_decode.latent_plane_shape``)."""
@@ -890,7 +920,9 @@ class DecodePredictor:
         if is_index:
             return (pages, aval.shape[2])
         if is_scale:
-            return (pages, self._page_tokens * 2 * aval.shape[2])
+            from .ops.attention import scale_group
+
+            return (pages, self._page_tokens * scale_group(aval.shape[2]))
         return (pages, self._page_tokens, aval.shape[2])
 
     # ------------------------------------------------------------------
@@ -1283,7 +1315,7 @@ class DecodePredictor:
                                 active=active, valid=valid, **at)
                             index = _attn.paged_append_index(
                                 index, kc, tbl, pos0, t, spec, active=active,
-                                valid=valid, **at)
+                                valid=valid, num_kv_heads=kv_heads, **at)
                             out, (chosen, live) = _attn.paged_attend_sparse(
                                 q, kc, vc, index, tbl, jnp.asarray(
                                     pos0, jnp.int32).reshape(-1) + t, spec,
@@ -3336,34 +3368,21 @@ class DecodeServer:
             "mx_moe_calls_total",
             "runs of a program with gated MoE layers",
             labels=("program",))
-        self._m_ssm_rows = _obs.registry.counter(
-            "mx_ssm_rows_total",
-            "(slot, SelectiveSSM node) rows whose recurrent state a decode "
-            "step advanced (idle and mid-prefill slots left out)")
         self._m_ssm_chunk_tokens = _obs.registry.counter(
             "mx_ssm_chunk_tokens_total",
             "(prompt token, SelectiveSSM node) pairs the chunk program "
             "scanned (a chunk's padding left out)")
-        self._m_ssm_state_bytes = _obs.registry.gauge(
-            "mx_ssm_state_bytes",
-            "bytes of the state cache group: every slot's conv tails and "
-            "recurrent states")
-        self._m_linattn_rows = _obs.registry.counter(
-            "mx_linattn_rows_total",
-            "(slot, LightningAttention node) rows whose matrix state a "
-            "decode step advanced (idle and mid-prefill slots left out)")
-        self._m_linattn_state_bytes = _obs.registry.gauge(
-            "mx_linattn_state_bytes",
-            "bytes of the state cache group's LightningAttention rows: "
-            "every slot's (H, D, D) float32 states")
-        self._m_kda_rows = _obs.registry.counter(
-            "mx_kda_rows_total",
-            "(slot, KimiDeltaAttention node) rows whose matrix state a "
-            "decode step advanced (idle and mid-prefill slots left out)")
-        self._m_kda_state_bytes = _obs.registry.gauge(
-            "mx_kda_state_bytes",
-            "bytes of the state cache group's KimiDeltaAttention rows: "
-            "every slot's convolution tails and (H, D, D) float32 states")
+        # each recurrent op's rows counter and state-bytes gauge, and how
+        # many nodes of it the graph has: {counts: (counter, gauge, nodes)}
+        nodes_of = getattr(predictor, "state_nodes", lambda counts: 0)
+        self._m_state = {
+            op.counts: (_obs.registry.counter(op.metric("rows_total"),
+                                              op.advanced),
+                        _obs.registry.gauge(op.metric("state_bytes"),
+                                            op.held),
+                        nodes_of(op.counts))
+            for op in state_ops().values()}
+        self._ssm_nodes = self._m_state["ssm_rows"][2]
         self._m_sparse_blocks = _obs.registry.counter(
             "mx_attn_sparse_blocks_total",
             "(slot, KV group, node) blocks of the attention nodes with "
@@ -3378,10 +3397,6 @@ class DecodeServer:
             labels=("form",))
         self._latent_nodes = sum(
             l.kind == "latent" for l in getattr(predictor, "_layouts", ()))
-        nodes_of = getattr(predictor, "state_nodes", lambda counts: 0)
-        self._ssm_nodes = nodes_of("ssm_rows")
-        self._linattn_nodes = nodes_of("linattn_rows")
-        self._kda_nodes = nodes_of("kda_rows")
         # --- fleet/preemption state (paged loop) ---
         # fair admission: after this many consecutive pool-gate-blocked
         # iterations the lowest-priority slot is preempted (swap-out) so
@@ -3491,13 +3506,12 @@ class DecodeServer:
                             moe_expert_visits=visits)
 
     def _note_counts(self, note):
-        """Mirror into the registry what the decode step's lightning, delta
-        and sparse nodes counted (``note`` holds them by name, as the step's
+        """Mirror into the registry what the decode step's recurrent and
+        sparse nodes counted (``note`` holds them by name, as the step's
         ``serve.readback`` span shows them)."""
-        if "linattn_rows" in note:
-            self._m_linattn_rows.inc(note["linattn_rows"])
-        if "kda_rows" in note:
-            self._m_kda_rows.inc(note["kda_rows"])
+        for counts, (rows, _, _) in self._m_state.items():
+            if counts in note:
+                rows.inc(note[counts])
         for kind in ("chosen", "live"):
             if "sparse_blocks_" + kind in note:
                 self._m_sparse_blocks.labels(kind=kind).inc(
@@ -3915,15 +3929,9 @@ class DecodeServer:
                 caches=pred._run_forks(state.caches, [(0, 0)]))
         for g in pred._manager.groups:      # a pool's size: once a session
             self._m_pages_total.labels(group=g.name).set(g.pool_pages)
-        if self._ssm_nodes:
-            self._m_ssm_state_bytes.set(
-                slots * pred.state_row_bytes("ssm_rows"))
-        if self._linattn_nodes:
-            self._m_linattn_state_bytes.set(
-                slots * pred.state_row_bytes("linattn_rows"))
-        if self._kda_nodes:
-            self._m_kda_state_bytes.set(
-                slots * pred.state_row_bytes("kda_rows"))
+        for counts, (_, held, nodes) in self._m_state.items():
+            if nodes:
+                held.set(slots * pred.state_row_bytes(counts))
         return self._ps
 
     def serve_reset(self):
@@ -4534,7 +4542,7 @@ class DecodeServer:
             self._note_counts(note)
             if fl["ssm"] is not None:
                 note["ssm_rows"] = int(got.pop())
-                self._m_ssm_rows.inc(note["ssm_rows"])
+                self._m_state["ssm_rows"][0].inc(note["ssm_rows"])
             accepts = got.pop() if fl["accepts"] is not None else None
             toks = got.pop() if fl["toks"] is not None else None
             if accepts is not None:

@@ -108,6 +108,25 @@ model of the family is a configuration file and no code:
   ``use_gqa_gate``.  The feed-forward part of such a layer is chosen as any
   other's (``moe_layer_freq`` and its other names);
 
+* ``linear_key_head_dim`` > 0 (with ``linear_num_key_heads``,
+  ``linear_num_value_heads``, ``linear_value_head_dim``,
+  ``linear_conv_kernel_dim``): the layers ``layer_types`` calls
+  ``"linear_attention"`` are Gated DeltaNet (``ops.gdn``: the delta rule
+  over a ``linear_key_head_dim`` x ``linear_value_head_dim`` matrix state a
+  head with ONE decay a head, a convolution on q, k and v, a silu gate from a
+  full matrix; ``linear_allow_neg_eigval`` true: beta in (0, 2); false, and
+  value heads that outnumber the key heads, are refused by name), those it
+  calls ``"full_attention"`` the attention ``hybrid_layer_pattern`` says.  A
+  ``rope_parameters`` whose ``rope_theta`` is null gives no base, so no
+  angle: the full-attention layers take no positions, as under
+  ``full_attn_use_rope`` false;
+* ``norm_after``: OLMo 2's reordered norm, ``x + RMSNorm(f(x))`` in place
+  of ``x + f(RMSNorm(x))``, under the same node names (``_att_norm`` /
+  ``_ffn_norm``); refused beside the parallel block;
+* ``attn_qk_norm`` ``"projection"``: q and k are normed over the WHOLE
+  projection, before the heads are cut, a gain of ``heads x head_dim`` each
+  (OLMo 2), where ``True`` norms a head;
+
 ``hybrid_layer_pattern`` and ``moe_layer_freq`` default to zeros: full
 attention and a dense MLP in every layer.  Entries ``first_layer ..
 first_layer + num_layers - 1`` of the per-layer lists are built.
@@ -153,12 +172,17 @@ def sparse_attrs(sparse_config):
 def attention(data, name, window, hidden, heads, kv_heads, head_dim,
               v_head_dim, rotary_dim, theta, value_scale, sink,
               key_multiplier=1.0, sparse=None, gate=False, qk_norm_eps=0.0,
-              layer=None):
+              layer=None, qk_norm_whole=False):
     q = sym.FullyConnected(data, num_hidden=heads * head_dim, no_bias=True,
                            flatten=False, name=name + "_q")
     k = sym.FullyConnected(data, num_hidden=kv_heads * head_dim,
                            no_bias=True, flatten=False, name=name + "_k")
-    if qk_norm_eps:
+    if qk_norm_eps and qk_norm_whole:
+        # over the whole projection, before the heads are cut
+        q, k = (sym.RMSNorm(x, eps=qk_norm_eps,
+                            name="%s_%s_norm" % (name, part))
+                for x, part in ((q, "q"), (k, "k")))
+    elif qk_norm_eps:
         q, k = (sym.Reshape(sym.RMSNorm(
             sym.Reshape(x, shape=(0, 0, -1, head_dim)), eps=qk_norm_eps,
             name="%s_%s_norm" % (name, part)), shape=(0, 0, -1))
@@ -280,6 +304,32 @@ def kda_mixer(data, name, hidden, heads, head_dim, conv, eps, neg_eigval,
     return fc(mixed, hidden, "out")
 
 
+def gdn_mixer(data, name, hidden, heads, value_heads, key_dim, value_dim,
+              conv, eps, neg_eigval):
+    """Gated DeltaNet: ``hidden`` -> q, k, v, the decay and beta a head and
+    the gate -> ``ops.gdn`` -> ``hidden``."""
+    if int(value_heads or heads) != heads:
+        raise ValueError(
+            "linear_num_value_heads %r != linear_num_key_heads %d: ops.gdn "
+            "gives every key head one value head (no configuration here "
+            "groups them)" % (value_heads, heads))
+    if not neg_eigval:
+        raise ValueError(
+            "linear_allow_neg_eigval false: ops.gdn takes beta = 2 "
+            "sigmoid(.), in (0, 2) (no configuration here keeps it in (0, "
+            "1))")
+    fc = lambda x, n, part: sym.FullyConnected(
+        x, num_hidden=n, no_bias=True, flatten=False,
+        name="%s_gdn_%s" % (name, part))
+    mixed = sym.GatedDeltaNet(
+        fc(data, heads * key_dim, "q"), fc(data, heads * key_dim, "k"),
+        fc(data, heads * value_dim, "v"), fc(data, heads, "a"),
+        fc(data, heads, "b"), fc(data, heads * value_dim, "g"),
+        num_heads=heads, key_head_dim=key_dim, value_head_dim=value_dim,
+        conv_kernel=int(conv), eps=float(eps), name=name + "_gdn")
+    return fc(mixed, hidden, "out")
+
+
 def gated_mlp(data, name, hidden, width, multipliers=(1.0, 1.0)):
     gate = sym.FullyConnected(data, num_hidden=width, no_bias=True,
                               flatten=False, name=name + "_ffn_gate")
@@ -353,7 +403,10 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                first_k_dense_replace=None, linear_attn_config=None,
                gqa_layers=None, use_gqa_gate=False,
                kda_allow_neg_eigval=False, kda_use_full_proj=False,
-               **kwargs):
+               linear_num_key_heads=0, linear_num_value_heads=0,
+               linear_key_head_dim=0, linear_value_head_dim=0,
+               linear_conv_kernel_dim=4, linear_allow_neg_eigval=False,
+               norm_after=False, **kwargs):
     """data (B, T) int tokens -> softmax over the vocabulary at every
     position (``softmax_label`` (B, T) next tokens, pad = -1 ignored)."""
     heads = int(num_attention_heads)
@@ -372,7 +425,11 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
         moe_layer_freq = tuple(int(i >= int(first_k_dense_replace))
                                for i in range(len(zeros)))
     if rope_parameters:
-        rope_theta = dict(rope_parameters).get("rope_theta", rope_theta)
+        theta = dict(rope_parameters).get("rope_theta", rope_theta)
+        if theta is None:
+            full_attn_use_rope = False      # no base: no angle
+        else:
+            rope_theta = theta
     if int(num_nextn_predict_layers) not in (0, 1):
         raise ValueError("num_nextn_predict_layers %r: one multi-token-"
                          "prediction block is built, or none"
@@ -421,8 +478,12 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
             sparse=sparse_config if selects else None,
             gate=bool(attn_use_output_gate if selects else use_gqa_gate),
             qk_norm_eps=float(layernorm_epsilon) if attn_qk_norm else 0.0,
-            layer=layer)
+            layer=layer, qk_norm_whole=attn_qk_norm == "projection")
     mixer_types = mixer_types or ("",) * len(zeros)
+    if int(linear_key_head_dim or 0):
+        mixer_types = tuple(
+            "gdn" if kind == "linear_attention" else mixer
+            for kind, mixer in zip(layer_types or (), mixer_types))
     if linear_attn_config:
         delta = dict(linear_attn_config)
         attends = tuple(gqa_layers or ())
@@ -449,14 +510,22 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
     net = times(sym.Embedding(data, input_dim=vocab_size,
                               output_dim=hidden_size, name="embed",
                               **embed_w), embedding_multiplier)
+    if norm_after and mamba_d_ssm:
+        raise ValueError("norm_after beside the parallel block (mamba_d_ssm "
+                         "> 0): one norm cannot follow two summed mixers "
+                         "(no configuration here brings both)")
+    norm = lambda x, name: sym.RMSNorm(x, eps=layernorm_epsilon, name=name)
+    # where a sublayer's norm stands: before it (pre-norm), or after it and
+    # before the residual sum (OLMo 2)
+    before = (lambda x, name: x) if norm_after else norm
+    after = norm if norm_after else (lambda x, name: x)
     for i in range(first, first + int(num_layers)):
         name = "layer%d" % i
         windowed = bool(hybrid_layer_pattern[i])
-        normed = sym.RMSNorm(net, eps=layernorm_epsilon,
-                             name=name + "_att_norm")
+        normed = before(net, name + "_att_norm")
         selects = mixer_types[i] == "minicpm4"
         if mixer_types[i] == "lightning-attn":
-            net = net + times(lightning_mixer(
+            mixed = times(lightning_mixer(
                 normed, name, hidden_size, int(lightning_nh),
                 int(lightning_head_dim), rope_theta, layernorm_epsilon,
                 1.0 - i / max(total - 1, 1) + 1e-5,
@@ -464,12 +533,18 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                 output_norm=use_output_norm, output_gate=use_output_gate),
                 depth)
         elif mixer_types[i] == "kda":
-            net = net + times(kda_mixer(
+            mixed = times(kda_mixer(
                 normed, name, hidden_size, int(delta["num_heads"]),
                 int(delta["head_dim"]),
                 int(delta.get("short_conv_kernel_size", 4)),
                 layernorm_epsilon, kda_allow_neg_eigval, kda_use_full_proj),
                 depth)
+        elif mixer_types[i] == "gdn":
+            mixed = times(gdn_mixer(
+                normed, name, hidden_size, int(linear_num_key_heads),
+                int(linear_num_value_heads), int(linear_key_head_dim),
+                int(linear_value_head_dim), int(linear_conv_kernel_dim),
+                layernorm_epsilon, linear_allow_neg_eigval), depth)
         else:
             if mamba_d_ssm:
                 # the parallel block: both mixers read the one normed input
@@ -480,17 +555,17 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                     int(mamba_d_conv), int(mamba_chunk_size),
                     layernorm_epsilon, bool(mamba_proj_bias),
                     ssm_state_dtype, ssm_multipliers), ssm_out_multiplier)
-            net = net + times(attend(
+            mixed = times(attend(
                 times(normed, attention_in_multiplier), name, windowed,
                 selects), attention_out_multiplier * depth)
-        normed = sym.RMSNorm(net, eps=layernorm_epsilon,
-                             name=name + "_ffn_norm")
+        net = net + after(mixed, name + "_att_norm")
+        normed = before(net, name + "_ffn_norm")
         if moe_layer_freq[i]:
             ffn = experts(normed, name)
         else:
             ffn = gated_mlp(normed, name, hidden_size,
                             int(intermediate_size), mlp_multipliers)
-        net = net + times(ffn, depth)
+        net = net + after(times(ffn, depth), name + "_ffn_norm")
     if not int(num_nextn_predict_layers):
         net = sym.RMSNorm(net, eps=layernorm_epsilon, name="final_norm")
         with AttrScope(**_HEAD):

@@ -40,6 +40,7 @@ LAYER_OF_OP = {
     "LightningAttention": "linattn",
     "LatentAttention": "attn_latent",
     "KimiDeltaAttention": "kda",
+    "GatedDeltaNet": "gdn",
 }
 # every value a scope's <layer> may take: the table's, "other" for op
 # kinds it does not list, and the two fixed scopes of the train step

@@ -7,7 +7,7 @@ optimizer updates.  Contrib (detection / CTC / fft) and RNN register from
 their own modules as they land.
 """
 from . import (elemwise, tensor, nn, sample, optimizer_ops, rnn_op, spatial,
-               contrib_ops, attention, moe, ssm, linattn, kda)
+               contrib_ops, attention, moe, ssm, linattn, kda, gdn)
 
 _registered = False
 
@@ -30,6 +30,7 @@ def register_all():
     ssm.register_all()
     linattn.register_all()
     kda.register_all()
+    gdn.register_all()
 
 
 register_all()

@@ -683,6 +683,17 @@ def _append_scales(plane, table, new, start, active, valid):
         rows.reshape((-1,) + plane.shape[1:]))
 
 
+def scale_group(num_kv_heads):
+    """Floats a token's scales take of its page's row in a pool's scale
+    plane: K's ``H_kv`` then V's, and zeros up to the next width that divides
+    a lane tile where ``2 * H_kv`` does not (30 heads: 60 -> 64, 16 B a token
+    a layer), so that a page's row is whole lane tiles and a token's stretch
+    never straddles one (``pallas_decode.tiles``).  A count whose double
+    divides 128 keeps its ``2 * H_kv``."""
+    w = 2 * int(num_kv_heads)
+    return w if w >= 128 or 128 % w == 0 else 1 << (w - 1).bit_length()
+
+
 def _page_positions(plane, width):
     """Positions a page of a plane of ``width`` values a position holds,
     however the page's values are cut into rows."""
@@ -706,7 +717,7 @@ def paged_gather(pool, table):
     return pages.reshape(b, m * pool.shape[1], pool.shape[2])
 
 
-def paged_gather_kv(k_pool, v_pool, table):
+def paged_gather_kv(k_pool, v_pool, table, num_kv_heads=0):
     """:func:`paged_gather` of a node's K and V pools: ``(k_view,
     v_view)``, each what a dense ring of the table's capacity holds.
 
@@ -714,9 +725,11 @@ def paged_gather_kv(k_pool, v_pool, table):
     plane, ``k_pool.scale`` (P, page_tokens * 2 * H), a page a row: whole
     rows are taken by ONE gather for the two, and only what was gathered
     is reshaped and split into the (B, M*page_tokens, H) planes of two
-    dense rings.  The transposition that puts positions on the lanes for
-    the products (:func:`_sdpa_cache`) is paid on the view, a few hundred
-    kilobytes, and the pool is read as it lies."""
+    dense rings.  Where a token's stretch is padded (:func:`scale_group`),
+    ``num_kv_heads`` says how many of its floats are K's and V's.  The
+    transposition that puts positions on the lanes for the products
+    (:func:`_sdpa_cache`) is paid on the view, a few hundred kilobytes,
+    and the pool is read as it lies."""
     import jax.numpy as jnp
 
     if not isinstance(k_pool, QuantKV):
@@ -729,10 +742,10 @@ def paged_gather_kv(k_pool, v_pool, table):
     # (Split first, (B, C, 2, H), and turned a pool: tiles of 2 x 128 with
     # H lanes filled, 2.0 ms of an OPT tick more; my chip runs, PR 42)
     both = jnp.swapaxes(rows.reshape(k_view.shape[:2] + (-1,)), 1, 2)
-    h = both.shape[1] // 2
+    h = int(num_kv_heads) or both.shape[1] // 2
     return (QuantKV(k_view, jnp.swapaxes(both[:, :h], 1, 2)),
             QuantKV(paged_gather(v_pool.data, table),
-                    jnp.swapaxes(both[:, h:], 1, 2)))
+                    jnp.swapaxes(both[:, h:2 * h], 1, 2)))
 
 
 def paged_append(pool, table, new, start_pos, active=None, valid=None,
@@ -807,9 +820,11 @@ def paged_append_kv(k_pool, v_pool, table, k, v, start_pos, num_heads=1,
                          valid=valid, layer=layer)
             for pool, q in ((k_pool, qk), (v_pool, qv)))
         pt = k_pool.data.shape[1]
-        scales, start, _ = _latest(
-            jnp.concatenate([qk.scale, qv.scale], axis=-1), start_pos,
-            table.shape[1] * pt)
+        scales = jnp.concatenate([qk.scale, qv.scale], axis=-1)
+        spare = k_pool.scale.shape[1] // pt - scales.shape[-1]
+        if spare:       # a padded stretch: scale_group
+            scales = jnp.pad(scales, ((0, 0), (0, 0), (0, spare)))
+        scales, start, _ = _latest(scales, start_pos, table.shape[1] * pt)
         return (QuantKV(k_data, _append_scales(
             k_pool.scale, table, scales, start, active, valid)),
             QuantKV(v_data, None))
@@ -824,6 +839,9 @@ def quantize_pools(k, v, dtype, num_heads=1):
     qk = quantize_kv(k, dtype, num_heads)
     qv = quantize_kv(v, dtype, num_heads)
     scales = jnp.concatenate([qk.scale, qv.scale], axis=-1)
+    spare = scale_group(num_heads) - scales.shape[-1]
+    if spare:
+        scales = jnp.pad(scales, ((0, 0), (0, 0), (0, spare)))
     return (QuantKV(qk.data, scales.reshape(k.shape[0], -1)),
             QuantKV(qv.data, None))
 
@@ -1193,7 +1211,8 @@ def _attend_live_blocks(q, k_pool, v_pool, table, total_len, num_heads,
         take = lambda x: jax.lax.dynamic_slice_in_dim(x, at, group)
         rows_of, ids = take(slot), take(pages)
         with _scope(layer, "kv_gather"):
-            k_blk, v_blk = paged_gather_kv(k_pool, v_pool, ids) \
+            k_blk, v_blk = paged_gather_kv(
+                k_pool, v_pool, ids, int(num_kv_heads) or num_heads) \
                 if gather is None else gather(ids)
         allow = {}
         if chosen is not None:
@@ -1343,7 +1362,8 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
             layer=layer, kernel=kernel, chunk=chunk)
     _note_path("whole", DECODE_PATH)
     with _scope(layer, "kv_gather"):
-        k_view, v_view = paged_gather_kv(k_pool, v_pool, table)
+        k_view, v_view = paged_gather_kv(
+            k_pool, v_pool, table, int(num_kv_heads) or num_heads)
     return _sdpa_cache(q, k_view, v_view, total_len, num_heads, scale,
                        num_kv_heads=num_kv_heads, mesh_active=mesh_active,
                        **_extras(window, sink, value_scale, layer))
@@ -1583,7 +1603,8 @@ def sdpa_sparse(q, k, v, spec, num_heads=1, scale=None, num_kv_heads=0,
 
 
 def paged_append_index(index, k_pool, table, start_pos, t, spec,
-                       active=None, valid=None, layer="attn_sparse"):
+                       active=None, valid=None, layer="attn_sparse",
+                       num_kv_heads=0):
     """Write the index rows of the windows that the ``t`` positions just
     appended at ``start_pos`` completed: window ``j`` (positions ``stride *
     j .. stride * j + kernel - 1``, pages ``j`` and ``j + 1`` of the slot's
@@ -1618,8 +1639,12 @@ def paged_append_index(index, k_pool, table, start_pos, t, spec,
                      0, m - 1), axis=1)                    # (B, nw + 1)
         keys = data[ids].astype(jnp.float32)       # (B, nw + 1, pt, E)
         if isinstance(k_pool, QuantKV):
-            kvh = k_pool.scale.shape[1] // (2 * pt)
-            scales = k_pool.scale[ids].reshape(b, nw + 1, pt, 2, kvh)[..., 0, :]
+            w = k_pool.scale.shape[1] // pt
+            kvh = int(num_kv_heads) or w // 2
+            scales = k_pool.scale[ids]
+            scales = scales.reshape(b, nw + 1, pt, 2, kvh)[..., 0, :] \
+                if w == 2 * kvh \
+                else scales.reshape(b, nw + 1, pt, w)[..., :kvh]
             keys = (keys.reshape(b, nw + 1, pt, kvh, -1)
                     * scales[..., None]).reshape(keys.shape)
         halves = jnp.sum(keys, axis=2)
@@ -1648,7 +1673,7 @@ def _attend_block_list(q, k_pool, v_pool, table, blocks, valid, n, spec,
             jnp.clip(pages.reshape(b, -1), 0, table.shape[1] - 1), axis=1)
         ids = jnp.where(jnp.repeat(valid.reshape(b, -1), per, axis=1),
                         ids, 0)
-        k_view, v_view = paged_gather_kv(k_pool, v_pool, ids)
+        k_view, v_view = paged_gather_kv(k_pool, v_pool, ids, kvh)
     c = kvh * width * spec.block
     pos = (blocks[..., None] * spec.block
            + jnp.arange(spec.block, dtype=jnp.int32)).reshape(b, kvh, -1)
@@ -1742,7 +1767,7 @@ def paged_attend_sparse(q, k_pool, v_pool, index, table, total_len, spec,
     else:
         _note_path("whole", DECODE_PATH)
         with _scope(layer, "kv_gather"):
-            k_view, v_view = paged_gather_kv(k_pool, v_pool, table)
+            k_view, v_view = paged_gather_kv(k_pool, v_pool, table, kvh)
         allow = jnp.repeat(mask, spec.block, axis=3)[..., :m * pt]
         out = _sdpa_cache(q, k_view, v_view, total, num_heads, scale,
                           num_kv_heads=kvh, mesh_active=mesh_active,

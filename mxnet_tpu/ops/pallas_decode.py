@@ -127,11 +127,12 @@ def tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads, block):
             or (hdv % LANES and LANES % hdv) or hdv < 8:
         return None
     if quant:
-        # a page's scale row: whole lane tiles, a token's 2 * H_kv floats
-        # dividing one
-        w = 2 * kvh
-        if k_pool.scale.shape[1] != pt * w or (pt * w) % LANES \
-                or LANES % w:
+        # a page's scale row: whole lane tiles, a token's stretch (K's
+        # H_kv floats, V's, and what ops.attention.scale_group pads them
+        # with) dividing one
+        w = k_pool.scale.shape[1] // pt
+        if k_pool.scale.shape[1] != pt * w or w < 2 * kvh \
+                or (pt * w) % LANES or LANES % w:
             return None
     item = jnp.dtype(kd.dtype).itemsize
     rows = -(-h // 16) * 16
@@ -139,7 +140,7 @@ def tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads, block):
     # two buffers a plane, and the planes once more as the products read
     # them (float32 on the way to bfloat16)
     vmem = block * (ek + ev) * (2 * item + 6) \
-        + (block // pt * (2 * 8 + pt) * pt * 2 * kvh * 4 if quant else 0) \
+        + (block // pt * (2 * 8 + pt) * pt * w * 4 if quant else 0) \
         + pieces * rows * (ek + ev + 4 * block) * 4 \
         + q_shape[0] * pieces * rows * qw * 4
     if vmem > _VMEM_BUDGET:
@@ -295,8 +296,8 @@ def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
             # (ppb, pt * W) -> (block, W) -> (W, block): a page's row to
             # each of its tokens, a token's own stretch kept, the stretches
             # folded onto one lane tile
-            w = 2 * t.kv_heads
             width = sbuf.shape[-1]
+            w = width // t.pt
             per = jnp.concatenate(
                 [jnp.broadcast_to(
                     sbuf[buf, i, pl.ds(_rem(pages_ref[r * t.ppb + i], 8), 1), :],
@@ -837,7 +838,8 @@ def chunk_tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads, block,
     # keys and values are cut out of a block at lane tiles
     if pt % 8 or hd % LANES or hdv % LANES or block % LANES:
         return None
-    if quant and k_pool.scale.shape[1] != pt * 2 * kvh:
+    if quant and (k_pool.scale.shape[1] % pt
+                  or k_pool.scale.shape[1] // pt < 2 * kvh):
         return None
     per = groups = 0
     if chosen is not None:
@@ -1050,7 +1052,9 @@ def attend_chunk_blocks(q, k_pool, v_pool, pages, total, cap, t, scale,
         # of rows that visits the block
         w = 2 * t.kv_heads
         turned = jnp.swapaxes(k_pool.scale[pages.reshape(-1)].reshape(
-            pages.shape[0], t.ppb * t.pt, w), 1, 2)
+            pages.shape[0], t.ppb * t.pt, -1), 1, 2)
+        if turned.shape[1] != w:    # a padded stretch: scale_group
+            turned = turned[:, :w]
         turned = jnp.pad(turned, ((0, 0), (0, _scale_rows(t.kv_heads) - w),
                                   (0, 0)))
     acc, stats = _jitted_chunk()(

@@ -352,6 +352,27 @@ def delta_config(**over):
     return dict(cfg, init=init, **dict(DELTA_TOY, **over))
 
 
+GDN_TOY = dict(
+    vocab_size=96, hidden_size=64, num_attention_heads=3,
+    num_key_value_heads=3, head_dim=16, linear_num_key_heads=3,
+    linear_num_value_heads=3, linear_key_head_dim=8, linear_value_head_dim=16,
+    intermediate_size=128, serve_num_hidden_layers=4,
+    max_position_embeddings=64)
+
+
+def gdn_config(**over):
+    """``olmo-hybrid-7b`` cut to the toy's sizes: one period (three Gated
+    DeltaNet layers, then attention of three KV heads: an int8 pool's scale
+    row is padded, 6 floats a token to 8)."""
+    cfg = manifest.load_json(manifest.ROOT,
+                             "chipbench/configs/olmo-hybrid-7b.json")
+    wider = {"_weight$": dict(std=0.08), "_gdn_a_weight$": dict(std=0.05),
+             "_gdn_b_weight$": dict(std=0.2),
+             "_gdn_dt_bias$": dict(low=-3.0, high=0.0)}
+    init = [dict(r, **wider.get(r["match"], {})) for r in cfg["init"]]
+    return dict(cfg, init=init, **dict(GDN_TOY, **over))
+
+
 @pytest.fixture(scope="module")
 def delta_toy():
     cfg = delta_config()
@@ -415,3 +436,127 @@ def test_delta_layers_match_their_reference(delta_toy, dropped):
         assert out["ok"], out
     else:
         assert out["max_abs_dlogp"] > 10 * FLOAT_ATOL, out
+
+
+# ---------------------------------------------------------------------------
+# OLMo's block: the norm after the sublayer, q and k normed over the whole
+# projection, Gated DeltaNet chosen by ``layer_types`` (PR 57)
+# ---------------------------------------------------------------------------
+PARENT_NODES = [
+    ("Embedding", "embed"), ("RMSNorm", "layer0_att_norm"),
+    ("FullyConnected", "layer0_q"), ("Reshape", "reshape0"),
+    ("RMSNorm", "layer0_q_norm"), ("Reshape", "reshape1"),
+    ("FullyConnected", "layer0_k"), ("Reshape", "reshape2"),
+    ("RMSNorm", "layer0_k_norm"), ("Reshape", "reshape3"),
+    ("FullyConnected", "layer0_v"), ("dot_product_attention", "layer0_att"),
+    ("FullyConnected", "layer0_attout"), ("_plus", "plus0"),
+    ("RMSNorm", "layer0_ffn_norm"), ("FullyConnected", "layer0_ffn_gate"),
+    ("Activation", "activation0"), ("FullyConnected", "layer0_ffn_up"),
+    ("_mul", "mul0"), ("FullyConnected", "layer0_ffn_down"),
+    ("_plus", "plus1"), ("RMSNorm", "final_norm"), ("Reshape", "reshape4"),
+    ("FullyConnected", "head"), ("Reshape", "reshape5"),
+    ("SoftmaxOutput", "softmax")]
+OLMO = dict(vocab_size=32, hidden_size=16, num_layers=1,
+            num_attention_heads=2, head_dim=8, intermediate_size=24)
+
+
+def _nodes(sym):
+    return [(n.op.name, n.name) for n in sym._topo() if not n.is_variable]
+
+
+def test_a_graph_with_defaults_has_the_parents_node_list():
+    """The new arguments at their defaults change nothing: the nodes of a
+    one-layer graph with a per-head q/k norm, in order and by name, as the
+    tree before PR 57 built them (``benchmarks/runs/pr57_hashes.py`` holds
+    the accepted cells' serving programs to the parent's text)."""
+    from mxnet_tpu.base import NameManager
+    from mxnet_tpu.models import decoder_lm
+
+    with NameManager():
+        sym = decoder_lm.get_symbol(attn_qk_norm=True, **OLMO)
+    assert _nodes(sym) == PARENT_NODES
+    with NameManager():
+        after = decoder_lm.get_symbol(attn_qk_norm="projection",
+                                      norm_after=True, **OLMO)
+    # the same names, the norms after their sublayers, no reshape round a
+    # q/k norm
+    assert [n for _, n in _nodes(after) if n.endswith("_norm")] == [
+        "layer0_q_norm", "layer0_k_norm", "layer0_att_norm",
+        "layer0_ffn_norm", "final_norm"]
+    shapes = dict(zip(after.list_arguments(), after.infer_shape(
+        data=(1, 4), softmax_label=(1, 4))[0]))
+    assert shapes["layer0_q_norm_gamma"] == (16,)
+    with pytest.raises(ValueError, match="norm_after beside the parallel"):
+        decoder_lm.get_symbol(norm_after=True, mamba_d_ssm=8, mamba_n_heads=2,
+                              mamba_d_head=4, mamba_d_state=4, **OLMO)
+
+
+def test_norm_after_and_the_whole_projection_norm_by_hand():
+    """``x + RMS(f(x))`` and q, k normed over the whole projection against
+    the equations in plain ``jax.numpy``, no rotation (``rope_theta``
+    null)."""
+    from mxnet_tpu.models import decoder_lm
+
+    sym = decoder_lm.get_symbol(
+        attn_qk_norm="projection", norm_after=True,
+        rope_parameters={"rope_theta": None}, layernorm_epsilon=1e-6, **OLMO)
+    t = 6
+    args = sym.list_arguments()
+    shapes = dict(zip(args, sym.infer_shape(data=(1, t),
+                                            softmax_label=(1, t))[0]))
+    rng = np.random.default_rng(3)
+    p = {n: jnp.asarray((1.0 + 0.2 * rng.standard_normal(s)) if len(s) == 1
+                        else 0.4 * rng.standard_normal(s), jnp.float32)
+         for n, s in shapes.items() if n not in ("data", "softmax_label")}
+    toks = rng.integers(0, 32, size=(1, t))
+    got = system_probs(sym, p, toks)
+    rms = lambda x, g: x * jax.lax.rsqrt(
+        jnp.mean(x * x, -1, keepdims=True) + 1e-6) * g
+    fc = lambda x, n: x @ p[n + "_weight"].T
+    x = p["embed_weight"][toks]                             # (1, t, 16)
+    q = rms(fc(x, "layer0_q"), p["layer0_q_norm_gamma"]).reshape(1, t, 2, 8)
+    k = rms(fc(x, "layer0_k"), p["layer0_k_norm_gamma"]).reshape(1, t, 2, 8)
+    v = fc(x, "layer0_v").reshape(1, t, 2, 8)
+    s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / np.sqrt(8.0)
+    s = jnp.where(jnp.tril(jnp.ones((t, t), bool)), s, -jnp.inf)
+    att = jnp.einsum("bhqk,bkhd->bqhd", jax.nn.softmax(s, -1), v)
+    h = x + rms(fc(att.reshape(1, t, 16), "layer0_attout"),
+                p["layer0_att_norm_gamma"])
+    mlp = fc(jax.nn.silu(fc(h, "layer0_ffn_gate")) * fc(h, "layer0_ffn_up"),
+             "layer0_ffn_down")
+    h = h + rms(mlp, p["layer0_ffn_norm_gamma"])
+    want = jax.nn.softmax(fc(rms(h, p["final_norm_gamma"]), "head"), -1)
+    assert float(jnp.max(jnp.abs(got - want[0]))) < 1e-5
+    # and not the pre-norm block over the same weights
+    pre = decoder_lm.get_symbol(
+        attn_qk_norm="projection", rope_parameters={"rope_theta": None},
+        layernorm_epsilon=1e-6, **OLMO)
+    assert float(jnp.max(jnp.abs(system_probs(pre, p, toks) - want[0]))) \
+        > 1e-2
+
+
+def test_layer_types_choose_gated_deltanet_where_the_linear_keys_are_given():
+    from mxnet_tpu.models import decoder_lm
+
+    kinds = ("linear_attention", "full_attention", "sliding_attention")
+    lin = dict(linear_num_key_heads=2, linear_num_value_heads=2,
+               linear_key_head_dim=4, linear_value_head_dim=8,
+               linear_allow_neg_eigval=True)
+    sym = decoder_lm.get_symbol(layer_types=kinds, sliding_window=4,
+                                **dict(OLMO, num_layers=3), **lin)
+    ops = [op for op, _ in _nodes(sym)]
+    assert ops.count("GatedDeltaNet") == 1 \
+        and ops.count("dot_product_attention") == 2
+    att = [n.parsed_attrs() for n in sym._topo() if not n.is_variable
+           and n.op.name == "dot_product_attention"]
+    assert [int(a.get("window", 0) or 0) for a in att] == [0, 4]
+    # without the linear keys, layer_types keeps its meaning: attention
+    plain = decoder_lm.get_symbol(layer_types=kinds, sliding_window=4,
+                                  **dict(OLMO, num_layers=3))
+    assert "GatedDeltaNet" not in [op for op, _ in _nodes(plain)]
+    for bad, match in ((dict(linear_allow_neg_eigval=False), "neg_eigval"),
+                       (dict(linear_num_value_heads=4), "value_heads")):
+        with pytest.raises(ValueError, match=match):
+            decoder_lm.get_symbol(layer_types=kinds, sliding_window=4,
+                                  **dict(OLMO, num_layers=3),
+                                  **dict(lin, **bad))

@@ -756,6 +756,7 @@ TESTED_ELSEWHERE = {
     "SelectiveSSM": "test_ssm.py",
     "LightningAttention": "test_linattn.py",
     "KimiDeltaAttention": "test_kda.py",
+    "GatedDeltaNet": "test_gdn.py",
     "LatentAttention": "test_latent_attention.py",
     "count_sketch": "test_spatial_contrib.py",
     "_contrib_count_sketch": "test_spatial_contrib.py",

@@ -998,3 +998,96 @@ def test_delta_rows_beside_pages_and_held_experts_serve_as_one_pass(
         DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
                         page_tokens=4, prefill_chunk=8,
                         mesh=build_mesh(MeshConfig(model=2)))
+
+
+# ---------------------------------------------------------------------------
+# a state group of two-leaf rows whose widths differ (a 2 x 24 + 48-channel
+# tail, 8 x 16 matrices) beside int8 pages of THREE KV heads, whose scale row
+# is padded (``olmo_hybrid``'s keys at a toy size)
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def gdn_graph():
+    from test_decoder_lm import build, gdn_config, system_probs
+
+    cfg = gdn_config()
+    sym, params = build(cfg)
+    return cfg, sym, params, system_probs
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+def test_gated_deltanet_rows_beside_padded_int8_pages_serve_as_one_pass(
+        gdn_graph, kv_dtype):
+    """Five requests through two slots (chunks of 8, then decode steps, each
+    slot reused): every request's tokens are its own ``generate``'s, and over
+    float pools the arg max of ONE whole forward pass over the sequence that
+    was served; the rows are counted under the op's own name, and what a
+    state group refuses stays refused by name."""
+    from mxnet_tpu import obs
+    from mxnet_tpu.base import MXNetError
+    from mxnet_tpu.ops.attention import QuantKV
+
+    cfg, sym, params, system_probs = gdn_graph
+    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
+    make = lambda: DecodePredictor(
+        sym, nd, cache_len=64, ctx=mx.cpu(), paged=True, page_tokens=4,
+        kv_dtype=kv_dtype, prefill_chunk=8)
+    pred = make()
+    assert [l.kind for l in pred.cache_layouts()] == ["state"] * 3 + ["full"]
+    # a row: 3 delta layers x (3 positions of 96 channels, 3 x 8 x 16)
+    row = 3 * (3 * 96 * 4 + 3 * 8 * 16 * 4)
+    assert pred.state_row_bytes() == pred.state_row_bytes("gdn_rows") == row
+    assert pred.state_nodes("gdn_rows") == 3
+    assert pred.state_nodes("kda_rows") == 0
+    if kv_dtype:
+        kc, vc = pred.paged_batch_state(2).caches[3]
+        assert isinstance(kc, QuantKV) and vc.scale is None
+        assert kc.scale.shape == (kc.data.shape[0], 4 * 8)     # 6 -> 8
+    server = DecodeServer(pred, max_prefill=32, slots=2, spec_k=0)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 26, 9, 30)]
+    noted = lambda: [e["args"] for e in obs.timeline.events()
+                     if e["name"] == "serve.readback" and e.get("args")]
+    seen = len(noted())
+    rids = [server.submit(p, max_new_tokens=10) for p in prompts]
+    results = server.run()
+    alone = make()
+    for rid, p in zip(rids, prompts):
+        want = alone.generate(p[None].astype(np.float32), p.size,
+                              max_new_tokens=10)[0]
+        assert np.array_equal(results[rid], want), rid
+        if kv_dtype:
+            continue    # int8 keys move a near-tie; float pools are exact
+        seq = np.concatenate([p, results[rid][:-1]])[None]
+        probs = np.asarray(system_probs(sym, params, seq))
+        assert np.array_equal(probs[p.size - 1:].argmax(-1), results[rid])
+    rows = [a["gdn_rows"] for a in noted()[seen:] if "gdn_rows" in a]
+    assert rows and set(rows) <= {3, 6}
+    assert sum(rows) == 3 * len(prompts) * (10 - 1)
+    snap = obs.registry.snapshot()
+    assert snap["mx_gdn_state_bytes"]["series"][0]["value"] == 2 * row
+    assert snap["mx_gdn_rows_total"]["series"][0]["value"] >= sum(rows)
+    # the names and help texts of the three older ops' metrics as they were
+    for name, text in (
+            ("mx_ssm_rows_total", "(slot, SelectiveSSM node) rows whose "
+             "recurrent state a decode step advanced (idle and mid-prefill "
+             "slots left out)"),
+            ("mx_linattn_state_bytes", "bytes of the state cache group's "
+             "LightningAttention rows: every slot's (H, D, D) float32 "
+             "states"),
+            ("mx_kda_rows_total", "(slot, KimiDeltaAttention node) rows "
+             "whose matrix state a decode step advanced (idle and "
+             "mid-prefill slots left out)")):
+        assert snap[name]["help"] == text
+    assert not server._swap_armed
+    with pytest.raises(MXNetError, match="'state' cache group.*rejected "
+                                         "draft has already advanced"):
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=2)
+    state, _ = pred.prefill(prompts[0][None].astype(np.float32),
+                            np.array([5]))
+    with pytest.raises(MXNetError, match="GatedDeltaNet.*rejected draft"):
+        pred.verify_step(state, np.zeros((1, 3), np.int32))
+    from mxnet_tpu.parallel.mesh import MeshConfig, build_mesh
+    with pytest.raises(MXNetError, match="GatedDeltaNet.*one device"):
+        DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
+                        page_tokens=4, prefill_chunk=8,
+                        mesh=build_mesh(MeshConfig(model=2)))

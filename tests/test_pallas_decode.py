@@ -54,6 +54,8 @@ NODES = {
     "mha_heads_of_64": (4, 4, 64, 64, False, 1.0),          # opt-1.3b
     "heads_of_128_over_4": (8, 4, 128, 128, False, 1.0),    # falcon-h1-34b
     "unequal_sink_scale": (8, 4, 192, 128, True, 0.707),    # mimo-v2.5
+    # 30 KV heads: a token's 60 scales padded to 64 (attn.scale_group)
+    "mha_30_heads": (30, 30, 64, 64, False, 1.0),           # olmo-hybrid-7b
 }
 # empty, one position, exactly a block, one past it, a wrapped ring
 LENS = (0, 1, 256, 257, M * PT + 9)
@@ -86,8 +88,8 @@ def _case(node, dtype, seed=0, lens=LENS, qdtype=jnp.float32):
 
 def _whole(args, kw):
     q, kp, vp, table, total = args
-    return attn._sdpa_cache(q, *attn.paged_gather_kv(kp, vp, table), total,
-                            kw["num_heads"], None,
+    return attn._sdpa_cache(q, *attn.paged_gather_kv(
+        kp, vp, table, kw["num_kv_heads"]), total, kw["num_heads"], None,
                             num_kv_heads=kw["num_kv_heads"], sink=kw["sink"],
                             value_scale=kw["value_scale"])
 
@@ -217,6 +219,9 @@ RULE = {
     # selection over the live blocks inside the chunk's kernel
     "sala_serve_longctx": [("sparse", "chunk-kernel")] * 3,
     "solar2_serve_agent": [("decode-kernel", "chunk-kernel")],
+    # 30 KV heads over a padded scale row; chunks of 512 rows: under the
+    # chunk kernel's constant
+    "olmoh_serve_rollouts": [("decode-kernel", "walk")] * 2,
 }
 
 
@@ -265,6 +270,29 @@ def test_rule_refuses(why, kw, interpret):
     assert _selected() is not None
     assert _selected(dtype=jnp.float32) is not None
     assert _selected(**kw) is None, why
+
+
+def test_a_scale_row_is_padded_only_where_its_heads_do_not_divide_a_tile(
+        interpret):
+    """A token's stretch of its page's scale row: ``2 * H_kv`` floats where
+    that divides 128 (every accepted configuration's pools keep their plane's
+    shape), the next width that does where it does not; ``tiles`` takes the
+    padded plane and refuses the bare one."""
+    assert [attn.scale_group(h) for h in (1, 2, 4, 8, 32, 64, 96)] \
+        == [2, 4, 8, 16, 64, 128, 192]
+    assert [attn.scale_group(h) for h in (3, 6, 30, 40)] == [8, 16, 64, 128]
+    rng = np.random.RandomState(1)
+    for kvh, width in ((8, 16), (32, 64), (30, 64)):
+        k = jnp.asarray(rng.randn(3, PT, kvh * 64).astype(np.float32))
+        kp, vp = attn.quantize_pools(k, k, "int8", kvh)
+        assert kp.scale.shape == (3, PT * width) and vp.scale is None
+        assert pd.tiles((2, 1, kvh * 64), kp, vp, kvh, kvh, 256) is not None
+        # K's heads, V's heads, then zeros
+        row = np.asarray(kp.scale).reshape(3, PT, width)
+        assert (row[..., :2 * kvh] > 0).all() and (row[..., 2 * kvh:] == 0).all()
+    bare = attn.QuantKV(kp.data, kp.scale.reshape(3, PT, 64)[..., :60]
+                        .reshape(3, PT * 60))
+    assert pd.tiles((2, 1, 30 * 64), bare, vp, 30, 30, 256) is None
 
 
 def test_rule_needs_a_backend_that_runs_pallas():
@@ -441,7 +469,8 @@ def one_chip():
 
 
 @pytest.mark.parametrize("cell", ["opt_serve_backlog", "falconh1_serve_chat",
-                                  "mimo_serve_longshort"])
+                                  "mimo_serve_longshort",
+                                  "olmoh_serve_rollouts"])
 def test_kernel_compiles_for_the_chip_at_the_cells_shapes(cell, one_chip,
                                                           monkeypatch):
     """The decode row of each cell's first full node through
