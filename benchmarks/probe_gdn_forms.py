@@ -2,13 +2,14 @@
 chunk form (one 512-token chunk of a carried state, 30 heads of 96 x 192) reads
 against the recurrence a token at a time in float32 at ``HIGHEST``, at each
 precision its products could take (``ops.gdn.PRECISION`` and the two below
-it), what each takes, and the step form (96 rows in place).  One process, the
-chip's: it refuses to start without one and names the device in every line;
-nothing of the benchmark calls this.
+it), what each takes, and the step form (96 rows in place) in both of its
+forms, the kernel alone swept over its head block (``probe_delta_step.py``
+prints the step's lines alone).  One process, the chip's: it refuses to start
+without one and names the device in every line; nothing of the benchmark
+calls this.
 
     chiprun -- python3 benchmarks/probe_gdn_forms.py
 """
-import json
 import os
 import sys
 import time
@@ -18,6 +19,7 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
+import probe_delta_step
 from chipbench.reference import olmo_hybrid as ref
 from mxnet_tpu.ops import gdn
 
@@ -25,6 +27,10 @@ H, DK, DV, K, T, SLOTS = 30, 96, 192, 4, 512, 96
 KW, VW = H * DK, H * DV
 ATTRS = dict(num_heads=H, key_head_dim=DK, value_head_dim=DV, conv_kernel=K,
              eps=1e-6)
+# what probe_delta_step.step_and_sweep reads: the op, its heads, one decay a
+# head, and the head blocks swept
+MIX, HEADS, PER_HEAD = gdn.mix, (H, DK, DV), True
+BLOCKS = (1, 2, 3, 5, 6, 10, 15, 30)
 
 
 def inputs(key, b, t, dtype):
@@ -68,14 +74,7 @@ def timed(fn, *args, n=5):
 
 
 def main():
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        raise SystemExit("probe_gdn_forms times the op on the chip: "
-                         "jax.devices()[0] is %s (%s), not a TPU"
-                         % (dev.platform, dev.device_kind))
-    say = lambda **kw: print(json.dumps(dict(
-        kw, device={"platform": dev.platform, "kind": dev.device_kind})),
-        flush=True)
+    say = probe_delta_step.chip_or_exit("probe_gdn_forms")
     key = jax.random.PRNGKey(57)
     assert gdn.PRECISION == "highest"
     for dtype in ("float32", "bfloat16"):
@@ -104,24 +103,8 @@ def main():
                     (s_got - s_want) ** 2))),
                 state_diff_max=float(jnp.max(jnp.abs(s_got - s_want))))
         gdn.PRECISION = "highest"
-    # the step: 96 rows in place, the state donated
-    streams, weights = inputs(key, SLOTS, 1, "bfloat16")
-    state = (jnp.zeros((SLOTS, K - 1, 2 * KW + VW), jnp.bfloat16),
-             jnp.tile(s_want, (SLOTS, 1, 1, 1)))
-    on = jnp.ones((SLOTS,), jnp.int32)
-    step = jax.jit(lambda s, w, st: gdn.mix(ATTRS, *s, *w, state=st,
-                                            active=on)[:2],
-                   donate_argnums=(2,))
-    out, state = step(streams, weights, state)
-    jax.block_until_ready(state)
-    began = time.perf_counter()
-    for _ in range(20):
-        out, state = step(streams, weights, state)
-    jax.block_until_ready(state)
-    ms = 1e3 * (time.perf_counter() - began) / 20
-    moved = SLOTS * 2 * (H * DK * DV * 4 + 3 * (2 * KW + VW) * 2)
-    say(form="step", rows=SLOTS, ms=ms, state_step_bytes=moved,
-        hbm_util_pct=100 * moved / (ms / 1e3) / 819e9)
+    probe_delta_step.step_and_sweep(say, sys.modules[__name__], key,
+                                    s_want)
 
 
 if __name__ == "__main__":

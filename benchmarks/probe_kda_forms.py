@@ -1,13 +1,14 @@
 """``ops.kda`` alone at ``solar2_serve_agent``'s shapes on the chip: what the
 chunk form (one 2048-token chunk of a carried state, 64 heads of 128) and the
 step form (96 rows in place) read against the recurrence a token at a time in
-float32 at ``HIGHEST``, and what each takes.  One process, the chip's: it
-refuses to start without one and names the device in every line; nothing of
-the benchmark calls this.
+float32 at ``HIGHEST``, and what each takes; the step in both of its forms,
+the kernel alone swept over its head block (``probe_delta_step.py`` prints
+the step's lines alone).  One process, the chip's: it refuses to start
+without one and names the device in every line; nothing of the benchmark
+calls this.
 
     chiprun -- python3 benchmarks/probe_kda_forms.py
 """
-import json
 import os
 import sys
 import time
@@ -17,11 +18,16 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 import jax
 import jax.numpy as jnp
 
+import probe_delta_step
 from chipbench.reference import solar_open2 as ref
 from mxnet_tpu.ops import kda
 
 H, D, K, T, SLOTS = 64, 128, 4, 2048, 96
 ATTRS = dict(num_heads=H, head_dim=D, conv_kernel=K, eps=1e-5)
+# what probe_delta_step.step_and_sweep reads: the op, its heads, a decay a
+# channel, and the head blocks swept
+MIX, HEADS, PER_HEAD = kda.mix, (H, D, D), False
+BLOCKS = (1, 2, 4, 8, 16, 32, 64)
 
 
 def inputs(key, b, t, dtype):
@@ -67,14 +73,7 @@ def timed(fn, *args, n=5):
 
 
 def main():
-    dev = jax.devices()[0]
-    if dev.platform != "tpu":
-        raise SystemExit("probe_kda_forms times the op on the chip: "
-                         "jax.devices()[0] is %s (%s), not a TPU"
-                         % (dev.platform, dev.device_kind))
-    say = lambda **kw: print(json.dumps(dict(
-        kw, device={"platform": dev.platform, "kind": dev.device_kind})),
-        flush=True)
+    say = probe_delta_step.chip_or_exit("probe_kda_forms")
     key = jax.random.PRNGKey(53)
     for dtype in ("float32", "bfloat16"):
         streams, weights = inputs(key, 1, T, dtype)
@@ -96,24 +95,8 @@ def main():
             diff_max=float(jnp.max(jnp.abs(d))),
             state_rms=float(jnp.sqrt(jnp.mean(s_want ** 2))),
             state_diff_max=float(jnp.max(jnp.abs(s_got - s_want))))
-    # the step: 96 rows in place, the state donated
-    streams, weights = inputs(key, SLOTS, 1, "bfloat16")
-    state = (jnp.zeros((SLOTS, K - 1, 3 * H * D), jnp.bfloat16),
-             jnp.tile(s_want, (SLOTS, 1, 1, 1)))
-    on = jnp.ones((SLOTS,), jnp.int32)
-    step = jax.jit(lambda s, w, st: kda.mix(ATTRS, *s, *w, state=st,
-                                            active=on)[:2],
-                   donate_argnums=(2,))
-    out, state = step(streams, weights, state)
-    jax.block_until_ready(state)
-    began = time.perf_counter()
-    for _ in range(20):
-        out, state = step(streams, weights, state)
-    jax.block_until_ready(state)
-    ms = 1e3 * (time.perf_counter() - began) / 20
-    moved = SLOTS * 2 * (H * D * D * 4 + 3 * 3 * H * D * 2)
-    say(form="step", rows=SLOTS, ms=ms, state_step_bytes=moved,
-        hbm_util_pct=100 * moved / (ms / 1e3) / 819e9)
+    probe_delta_step.step_and_sweep(say, sys.modules[__name__], key,
+                                    s_want)
 
 
 if __name__ == "__main__":

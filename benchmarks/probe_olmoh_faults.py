@@ -71,7 +71,7 @@ from chipbench.drivers import serve_ticks, serve_ticks_by_leaf
 from mxnet_tpu.ops import gdn
 
 from probe_mistral4_faults import _coarse, sound_tree_after
-from probe_solar2_faults import _step_corrected_first
+from probe_solar2_faults import _step_corrected_first, masked
 
 CELL = "olmoh_serve_rollouts"
 PATCHED = ("beta_not_doubled", "no_decay", "no_l2_norm", "gate_sigmoid",
@@ -101,7 +101,7 @@ def planted(which):
     """``ops.gdn`` with one fault while a variant's predictor is built (it
     reads ``gdn.mix`` through ``decode.state_ops()`` then) and its programs
     trace."""
-    saved = {n: getattr(gdn, n) for n in ("mix", "_unit", "_step",
+    saved = {n: getattr(gdn, n) for n in ("mix", "_unit", "_delta_step",
                                           "_chunked", "BETA_SCALE",
                                           "PRECISION")}
     mix = saved["mix"]
@@ -133,7 +133,7 @@ def planted(which):
     elif which == "gate_sigmoid":
         gdn.mix = gate_sigmoid
     elif which == "corrected_before_decay":
-        gdn._step = _step_corrected_first
+        gdn._delta_step = masked(_step_corrected_first)
         gdn._chunked = _chunk_corrected_first
     elif which == "no_l2_norm":
         gdn._unit = lambda x, eps: x

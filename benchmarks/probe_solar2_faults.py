@@ -78,6 +78,18 @@ def _step_corrected_first(q, k, v, g, beta, s):
     return jnp.sum(q[..., :, None] * s, axis=-2), s
 
 
+def masked(step):
+    """``kda.step``'s call over an elementwise ``step``: how a planted step
+    runs on any backend (the sound one is a kernel where Pallas runs)."""
+    def entry(q, k, v, g, beta, s, active=None, op=None, mesh_active=False):
+        o, new = step(q, k, v, g, beta, s)
+        if active is not None:
+            on = jnp.asarray(active).reshape(-1).astype(bool)
+            new = jnp.where(on[:, None, None, None], new, s)
+        return o, new
+    return entry
+
+
 def _chunk_corrected_first(q, k, v, g, beta, s0, layer="kda"):
     """A chunk a token at a time by :func:`_step_corrected_first` (g = 0 and
     beta = 0 past ``nvalid`` are the identity in either order)."""
@@ -95,7 +107,7 @@ def planted(which):
     """``ops.kda`` with one fault while a variant's predictor is built (it
     reads ``kda.mix`` through ``decode.state_ops()`` then) and its programs
     trace."""
-    saved = {n: getattr(kda, n) for n in ("mix", "_unit", "_step",
+    saved = {n: getattr(kda, n) for n in ("mix", "_unit", "step",
                                           "_chunked", "BETA_SCALE")}
     mix = saved["mix"]
 
@@ -116,7 +128,7 @@ def planted(which):
     elif which == "tail_not_carried":
         kda.mix = tail_not_carried
     elif which == "corrected_before_decay":
-        kda._step = _step_corrected_first
+        kda.step = masked(_step_corrected_first)
         kda._chunked = _chunk_corrected_first
     elif which == "no_l2_norm":
         kda._unit = lambda x, eps: x

@@ -577,11 +577,13 @@ class DecodePredictor:
                              "chunk": 0, "fork": 0, "commit": 0,
                              "extract": 0, "install": 0}
         self._probing = False
-        # {rows a slot: the attention paths a program's trace took}, and
-        # the form each of its gated expert layers' routed product took, in
-        # the walk's order (ops.moe.MOE_PATH)
+        # {rows a slot: the attention paths a program's trace took}, the
+        # form each of its gated expert layers' routed product took, in the
+        # walk's order (ops.moe.MOE_PATH), and the form of each delta layer's
+        # decode step (ops.kda.STEP_PATH)
         self._decode_paths = {}
         self._moe_forms = {}
+        self._delta_steps = {}
         if self._paged:
             from .programs.aot import AotDispatch
 
@@ -1156,6 +1158,7 @@ class DecodePredictor:
 
         from .obs.scopes import node_scope as _node_scope
         from .ops import attention as _attn
+        from .ops import kda as _kda
         from .ops import moe as _moe
 
         b, t = tokens.shape[0], tokens.shape[1]
@@ -1167,6 +1170,7 @@ class DecodePredictor:
         # the program holds the decode row's kernel
         self._decode_paths[t] = paths = set()
         self._moe_forms[t] = forms = []
+        self._delta_steps[t] = steps = []
         ci = qi = 0
         values = {}
         base_key = jax.random.PRNGKey(0)
@@ -1406,9 +1410,12 @@ class DecodePredictor:
                     elif t == 1 and active is not None:
                         # a decode step: every slot's row in place, the
                         # write masked (no scratch row to send junk to)
+                        _kda.STEP_PATH["last"] = None
                         out, carried, rows = op.mix(
                             attrs, *ins, state=caches[ai], pos0=pos0,
                             active=active)
+                        if _kda.STEP_PATH["last"]:
+                            steps.append(_kda.STEP_PATH["last"])
                     else:
                         raise MXNetError(
                             "decode: node %r (%s) carries a recurrent state "
@@ -2920,6 +2927,7 @@ class DecodePredictor:
         paths = self._decode_paths.get(int(rows), ())
         art.meta["attn_paths"] = sorted(paths)
         art.meta["moe_forms"] = list(self._moe_forms.get(int(rows), ()))
+        art.meta["delta_steps"] = list(self._delta_steps.get(int(rows), ()))
         art.meta["pallas_decode"] = bool(
             {"decode-kernel", "chunk-kernel", "absorbed-kernel"} & set(paths))
         return art

@@ -24,7 +24,7 @@ plain ``K K^T`` times a (C, C) matrix of decays.  Two leaves of state a
 sequence: the last ``K - 1`` rows of [query | key | value] before the
 convolution ((K - 1, 2 H Dk + H Dv), the stream's type) and the matrices
 ((H, Dk, Dv) float32).  :func:`mix` is the one mathematics in the three forms
-``ops.kda.mix`` has, over that module's own ``_unit`` and ``_step`` (a step
+``ops.kda.mix`` has, over that module's own ``_unit`` and ``step`` (a step
 is blind to Dk != Dv and takes the head's decay broadcast over the key dim)
 and ``ops.ssm._conv``:
 
@@ -41,8 +41,9 @@ and ``ops.ssm._conv``:
   ``pos0 == 0`` starts from a zero state and a zero tail whatever the carried
   arrays hold;
 * one token a row (``T == 1`` over a carried state): the decode step,
-  elementwise.  A row whose ``active`` is 0 comes out bit-for-bit as it went
-  in.
+  ``ops.kda.step`` (one Pallas kernel where the backend runs one,
+  elementwise elsewhere).  A row whose ``active`` is 0 comes out bit-for-bit
+  as it went in.
 
 The recurrence is computed in float32 whatever the streams' type, and the
 chunk form's products at :data:`PRECISION` (highest): on the TPU a float32
@@ -56,7 +57,7 @@ from __future__ import annotations
 from ..attrs import Param, ParamSchema
 from ..obs.scopes import scope as _scope
 from ..registry import OpDef, register_op
-from .kda import BETA_SCALE, L2_EPS, _step, _unit
+from .kda import BETA_SCALE, L2_EPS, _unit, step as _delta_step
 from .ssm import _conv
 
 OP_NAME = "GatedDeltaNet"
@@ -184,13 +185,13 @@ def mix(attrs, q, k, v, decay, beta, gate, conv_w, a_log, dt_bias,
     if step:
         with _scope(layer, "step"):
             # the head's one decay over its key dims: (B, H, 1) broadcasts
-            o, new_s = _step(qh[:, 0], kh[:, 0], vh[:, 0], g[:, 0, :, None],
-                             bt[:, 0], s)
+            o, new_s = _delta_step(
+                qh[:, 0], kh[:, 0], vh[:, 0], g[:, 0, :, None], bt[:, 0], s,
+                active, OP_NAME)
             o = o[:, None]
             if active is not None:
                 on = jnp.asarray(active).reshape(-1).astype(bool)
                 new_tail = jnp.where(on[:, None, None], new_tail, state[0])
-                new_s = jnp.where(on[:, None, None, None], new_s, state[1])
                 rows = jnp.sum(on, dtype=jnp.int32)
     else:
         with _scope(layer, "chunk"):

@@ -36,9 +36,11 @@ last ``K - 1`` rows of [query | key | value] before the convolution
   Positions past ``nvalid`` are the identity (g = 0, beta = 0, the tail not
   advanced), and a chunk at ``pos0 == 0`` starts from a zero state and a
   zero tail whatever the carried arrays hold;
-* one token a row (``T == 1`` over a carried state): the decode step,
-  elementwise.  A row whose ``active`` is 0 comes out bit-for-bit as it went
-  in.
+* one token a row (``T == 1`` over a carried state): the decode step
+  (:func:`step`: one Pallas kernel that moves a matrix through HBM once each
+  way, ``ops.pallas_delta``, where the backend runs one; elementwise,
+  :func:`_step`, elsewhere).  A row whose ``active`` is 0 comes out
+  bit-for-bit as it went in.
 
 The recurrence is computed in float32 whatever the streams' type.
 """
@@ -184,6 +186,66 @@ def _step(q, k, v, g, beta, s):
     return o, s + k[..., :, None] * nu[..., None, :]
 
 
+# Which form the last decode step traced took, "kernel" or "elementwise":
+# written at trace time as ``ops.attention.DECODE_PATH`` is.  Each such
+# dispatch also counts in mx_delta_step_dispatch_total{op, path}, and
+# mxnet_tpu.decode records it per program, so that an artifact's meta says
+# which step it holds.
+STEP_PATH = {"last": None}
+
+
+def step_kernel_selected(s_shape, mesh_active=False):
+    """``(take, interpret)``: whether a decode step over a (B, H, Dk, Dv)
+    state runs ``ops.pallas_delta``'s kernel, decided from what the call
+    shows, as ``attention.decode_kernel_selected`` decides for a decode row.
+
+    All must hold: a backend that runs Pallas (``attention._kernel_backend``);
+    no mesh shards the executor (the kernel is opaque to GSPMD; ``mix`` never
+    says one does: ``DecodePredictor`` serves a graph with a delta node on one
+    device); and a state
+    ``pallas_delta.supported`` tiles, stored as the kernel reads it.  Anything
+    else takes :func:`_step`."""
+    from . import pallas_delta as _pd
+    from .attention import _kernel_backend
+
+    runs, interpret = _kernel_backend()
+    if mesh_active or not runs \
+            or not _pd.supported(*s_shape[1:], rows=s_shape[0]):
+        return False, False
+    return True, interpret
+
+
+def step(q, k, v, g, beta, s, active=None, op=OP_NAME, mesh_active=False):
+    """The decode step of the delta rule over B rows, the write masked:
+    ``(o (B, H, Dv) float32, S_new)``, a row whose ``active`` (B,) is 0 keeping
+    its ``s`` bit for bit.  ``q`` (scaled), ``k`` (B, H, Dk), ``v`` (B, H,
+    Dv), ``g`` (B, H, Dk), a log-decay a channel, or (B, H, 1), one a head,
+    ``beta`` (B, H), ``s`` (B, H, Dk, Dv) float32.  One algorithm in two
+    forms (:func:`step_kernel_selected`); ``op`` names the caller in the
+    counter."""
+    import jax.numpy as jnp
+
+    from .. import obs as _obs
+
+    take, interpret = step_kernel_selected(s.shape, mesh_active)
+    STEP_PATH["last"] = path = "kernel" if take else "elementwise"
+    _obs.registry.counter(
+        "mx_delta_step_dispatch_total",
+        "decode steps of the delta rule traced, by the op that asked and "
+        "the form the step took",
+        labels=("op", "path")).labels(op=op, path=path).inc()
+    if take:
+        from . import pallas_delta as _pd
+
+        return _pd.delta_step(q, k, v, g, beta, s, active,
+                              interpret=interpret)
+    o, new_s = _step(q, k, v, g, beta, s)
+    if active is not None:
+        on = jnp.asarray(active).reshape(-1).astype(bool)
+        new_s = jnp.where(on[:, None, None, None], new_s, s)
+    return o, new_s
+
+
 def mix(attrs, q, k, v, decay, beta, gate, conv_w, a_log, dt_bias,
         out_gamma, state=None, pos0=None, nvalid=None, active=None):
     """``(out (B, T, H * D), (conv tail, S), rows)``: the mixer over the
@@ -210,7 +272,7 @@ def mix(attrs, q, k, v, decay, beta, gate, conv_w, a_log, dt_bias,
         s = jnp.zeros((b, h, d, d), jnp.float32)
     else:
         tail, s = state[0], state[1].astype(jnp.float32)
-    step = t == 1 and nvalid is None and state is not None
+    one = t == 1 and nvalid is None and state is not None
     if nvalid is not None:
         nvalid = jnp.asarray(nvalid, jnp.int32).reshape(-1)
         if pos0 is not None:
@@ -235,15 +297,14 @@ def mix(attrs, q, k, v, decay, beta, gate, conv_w, a_log, dt_bias,
         # kept in the type it is carried in, whatever the streams' type
         new_tail = new_tail.astype(state[0].dtype)
     rows = jnp.int32(b)
-    if step:
+    if one:
         with _scope(layer, "step"):
-            o, new_s = _step(qh[:, 0], kh[:, 0], vh[:, 0], g[:, 0],
-                             bt[:, 0], s)
+            o, new_s = step(qh[:, 0], kh[:, 0], vh[:, 0], g[:, 0], bt[:, 0],
+                            s, active, OP_NAME)
             o = o[:, None]
             if active is not None:
                 on = jnp.asarray(active).reshape(-1).astype(bool)
                 new_tail = jnp.where(on[:, None, None], new_tail, state[0])
-                new_s = jnp.where(on[:, None, None, None], new_s, state[1])
                 rows = jnp.sum(on, dtype=jnp.int32)
     else:
         with _scope(layer, "chunk"):

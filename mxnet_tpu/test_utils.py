@@ -549,3 +549,38 @@ def check_reading_behind(make_server, prompts, caps, with_eos):
     first = serve(eos_id, serve_reading_first)
     for a, b in zip(behind, first):
         np.testing.assert_array_equal(a, b)
+
+
+def delta_toy_lm(kind, dk=None, dv=None, seed=5, vocab=64):
+    """``(symbol, {name: float32 array})``: a toy ``models.decoder_lm`` with
+    three delta layers before one attention layer, 2 delta heads of ``dk`` x
+    ``dv``, widths ``ops.pallas_delta`` tiles by default: ``"kda"`` Kimi delta
+    attention (``solar_open2``'s keys; 64 x 64, ``dv`` = ``dk``), ``"gdn"``
+    Gated DeltaNet (``olmo_hybrid``'s; 8 x 64).  For tests of the decode
+    step's dispatch and of the decode program that holds its kernel."""
+    from .models import decoder_lm
+
+    if kind == "kda":
+        keys = dict(linear_attn_config=dict(
+            short_conv_kernel_size=4, head_dim=int(dk or 64), num_heads=2,
+            num_kv_heads=None), gqa_layers=(3,), kda_allow_neg_eigval=True)
+    elif kind == "gdn":
+        keys = dict(layer_types=("linear_attention",) * 3
+                    + ("full_attention",), linear_num_key_heads=2,
+                    linear_num_value_heads=2,
+                    linear_key_head_dim=int(dk or 8),
+                    linear_value_head_dim=int(dv or 64),
+                    linear_allow_neg_eigval=True)
+    else:
+        raise ValueError("delta_toy_lm: kind %r is neither 'kda' nor 'gdn'"
+                         % (kind,))
+    sym = decoder_lm.get_symbol(
+        vocab_size=vocab, hidden_size=64, num_layers=4,
+        num_attention_heads=2, head_dim=16, intermediate_size=64, **keys)
+    rng = np.random.RandomState(seed)
+    arg_shapes, _, _ = sym.infer_shape(data=(1, 16), softmax_label=(1, 16))
+    params = {n: (1.0 + 0.1 * rng.randn(*s) if len(s) == 1
+                  else rng.normal(0, 0.08, s)).astype(np.float32)
+              for n, s in zip(sym.list_arguments(), arg_shapes)
+              if n not in ("data", "softmax_label")}
+    return sym, params

@@ -17,6 +17,7 @@ import numpy as np
 import pytest
 
 import mxnet_tpu as mx
+from mxnet_tpu import config, obs
 from mxnet_tpu.ops import gdn, kda
 from mxnet_tpu.registry import get_op
 
@@ -226,22 +227,76 @@ def test_a_chunk_at_position_zero_starts_from_nothing(d):
     assert close(later[0], clean[0]) and not close(later[1], clean[1], 1e-2)
 
 
-def test_steps_continue_a_chunk(d):
+@pytest.fixture(params=["elementwise", "kernel"])
+def step_form(request, d):
+    """The form the decode step takes: as the CPU takes it, or with the
+    interpreter running ``ops.pallas_delta``'s kernel where it tiles the
+    heads (96 x 192; 8 x 8 stays elementwise)."""
+    if request.param == "elementwise":
+        yield request.param
+        return
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        yield "kernel" if d.dk == 96 else "elementwise"
+
+
+def test_steps_continue_a_chunk(d, step_form):
     xs, w = streams(d, 2, 50), weights(d)
     want, s = plain(d, xs, w)
     _, state, _ = gdn.mix(d.attrs, *cut(xs, 0, 37), *w)
     got, state = by_token(d, xs, w, state, 37, 50)
+    assert kda.STEP_PATH["last"] == step_form
     assert close(got, want[:, 37:]) and close(state[1], s)
 
 
-def test_an_inactive_row_comes_out_of_a_step_as_it_went_in(d):
+def test_an_inactive_row_comes_out_of_a_step_as_it_went_in(d, step_form):
     xs, w, state = streams(d, 3, 1), weights(d), carried(d, 3)
     _, new, rows = gdn.mix(d.attrs, *xs, *w, state=state,
                            active=jnp.asarray([1, 0, 1], jnp.int32))
+    assert kda.STEP_PATH["last"] == step_form
     assert int(rows) == 2
     for leaf, old in zip(new, state):
         assert np.array_equal(np.asarray(leaf[1]), np.asarray(old[1]))
         assert not np.array_equal(np.asarray(leaf[0]), np.asarray(old[0]))
+
+
+def test_the_step_is_dispatched_by_backend_mesh_and_shape(d):
+    """``mx_delta_step_dispatch_total{op, path}``: the CPU as it is and a
+    sharded executor count ``elementwise``, the interpreter counts ``kernel``
+    where the heads tile, and a decode program's recorded steps say what its
+    three Gated DeltaNet layers took."""
+    from mxnet_tpu.decode import DecodePredictor
+    from mxnet_tpu.test_utils import delta_toy_lm
+
+    counter = obs.registry.counter("mx_delta_step_dispatch_total",
+                                   labels=("op", "path"))
+    counts = lambda: {p: counter.labels(op=gdn.OP_NAME, path=p).get()
+                      for p in ("kernel", "elementwise")}
+    xs, w, state = streams(d, 2, 1), weights(d), carried(d, 2)
+
+    def took(interpret, step=None):
+        before = counts()
+        with config.overrides(MXNET_PALLAS_INTERPRET=interpret):
+            (step or (lambda: gdn.mix(d.attrs, *xs, *w, state=state,
+                                      active=jnp.ones(2, jnp.int32))))()
+        return {p: n - before[p] for p, n in counts().items()}
+
+    tiled = d.dk == 96
+    assert took("0") == {"kernel": 0, "elementwise": 1}
+    assert took("1") == {"kernel": int(tiled), "elementwise": int(not tiled)}
+    sharded = lambda: kda.step(*(jnp.zeros(s) for s in (
+        (2, H, d.dk), (2, H, d.dk), (2, H, d.dv), (2, H, 1), (2, H),
+        (2, H, d.dk, d.dv))), op=gdn.OP_NAME, mesh_active=True)
+    assert took("1", sharded) == {"kernel": 0, "elementwise": 1}
+    if not tiled:
+        return
+    for interpret, path in (("1", "kernel"), ("0", "elementwise")):
+        with config.overrides(MXNET_PALLAS_INTERPRET=interpret):
+            pred = DecodePredictor(
+                *delta_toy_lm("gdn"), cache_len=64, temperature=0.0,
+                paged=True, page_tokens=4, prefill_chunk=8)
+            art = pred.decode_artifact(pred.paged_batch_state(2))
+        assert art.meta["delta_steps"] == [path] * 3
+        assert ("pallas_call" in art.jaxpr_text) == (path == "kernel")
 
 
 @pytest.mark.parametrize("form", ["sequence", "chunks", "steps"])

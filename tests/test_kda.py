@@ -7,12 +7,15 @@ Tolerance 2e-5 on outputs of order 1: everything is float32 on the CPU, the
 forms differ in the order of their sums (the chunked form solves a triangular
 system a block where the recurrence corrects the state token by token).
 """
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 import mxnet_tpu as mx
+from mxnet_tpu import config, obs
 from mxnet_tpu.ops import kda
 from mxnet_tpu.registry import get_op
 
@@ -209,22 +212,91 @@ def test_a_chunk_at_position_zero_starts_from_nothing():
     assert close(later[0], clean[0]) and not close(later[1], clean[1], 1e-2)
 
 
-def test_steps_continue_a_chunk():
+@pytest.fixture(params=["elementwise", "kernel"])
+def step_form(request, monkeypatch):
+    """The decode step's two forms: as the CPU takes it at this file's 8 x 8
+    heads, and ``ops.pallas_delta``'s kernel through the interpreter at heads
+    of 64 x 64, a width it tiles."""
+    if request.param == "elementwise":
+        yield request.param
+        return
+    me = sys.modules[__name__]
+    monkeypatch.setattr(me, "D", 64)
+    monkeypatch.setattr(me, "W", H * 64)
+    monkeypatch.setattr(me, "ATTRS", dict(ATTRS, head_dim=64))
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        yield request.param
+
+
+def test_steps_continue_a_chunk(step_form):
     xs, w = streams(2, 50), weights()
     want, s = plain(xs, w)
     _, state, _ = kda.mix(ATTRS, *cut(xs, 0, 37), *w)
     got, state = by_token(xs, w, state, 37, 50)
+    assert kda.STEP_PATH["last"] == step_form
     assert close(got, want[:, 37:]) and close(state[1], s)
 
 
-def test_an_inactive_row_comes_out_of_a_step_as_it_went_in():
+def test_an_inactive_row_comes_out_of_a_step_as_it_went_in(step_form):
     xs, w, state = streams(3, 1), weights(), carried(3)
     _, new, rows = kda.mix(ATTRS, *xs, *w, state=state,
                            active=jnp.asarray([1, 0, 1], jnp.int32))
+    assert kda.STEP_PATH["last"] == step_form
     assert int(rows) == 2
     for leaf, old in zip(new, state):
         assert np.array_equal(np.asarray(leaf[1]), np.asarray(old[1]))
         assert not np.array_equal(np.asarray(leaf[0]), np.asarray(old[0]))
+
+
+def _step_counts(op):
+    counter = obs.registry.counter("mx_delta_step_dispatch_total",
+                                   labels=("op", "path"))
+    return {path: counter.labels(op=op, path=path).get()
+            for path in ("kernel", "elementwise")}
+
+
+def test_the_step_is_dispatched_by_backend_mesh_and_shape(monkeypatch):
+    """``mx_delta_step_dispatch_total{op, path}``: the CPU as it is and a
+    sharded executor count ``elementwise``, the interpreter counts
+    ``kernel`` (8 x 8 heads ``elementwise`` there too), and a decode
+    program's recorded steps say what its three delta layers took."""
+    from mxnet_tpu.decode import DecodePredictor
+    from mxnet_tpu.test_utils import delta_toy_lm
+
+    me = sys.modules[__name__]
+    xs8, w8, state8 = streams(2, 1), weights(), carried(2)
+    monkeypatch.setattr(me, "D", 64)
+    monkeypatch.setattr(me, "W", H * 64)
+    attrs = dict(ATTRS, head_dim=64)
+    xs, w, state = streams(2, 1), weights(), carried(2)
+    on = jnp.ones(2, jnp.int32)
+
+    def took(interpret, attrs, xs, w, state):
+        before = _step_counts(kda.OP_NAME)
+        with config.overrides(MXNET_PALLAS_INTERPRET=interpret):
+            kda.mix(attrs, *xs, *w, state=state, active=on)
+        after = _step_counts(kda.OP_NAME)
+        return {p: after[p] - before[p] for p in after}
+
+    assert took("0", attrs, xs, w, state) == {"kernel": 0, "elementwise": 1}
+    assert took("1", attrs, xs, w, state) == {"kernel": 1, "elementwise": 0}
+    before = _step_counts(kda.OP_NAME)
+    with config.overrides(MXNET_PALLAS_INTERPRET="1"):
+        kda.step(*(jnp.zeros(s) for s in (
+            (2, H, 64), (2, H, 64), (2, H, 64), (2, H, 64), (2, H),
+            (2, H, 64, 64))), mesh_active=True)
+    assert _step_counts(kda.OP_NAME)["elementwise"] \
+        == before["elementwise"] + 1
+    assert took("1", ATTRS, xs8, w8, state8) \
+        == {"kernel": 0, "elementwise": 1}
+    for interpret, path in (("1", "kernel"), ("0", "elementwise")):
+        with config.overrides(MXNET_PALLAS_INTERPRET=interpret):
+            pred = DecodePredictor(
+                *delta_toy_lm("kda"), cache_len=64, temperature=0.0,
+                paged=True, page_tokens=4, prefill_chunk=8)
+            art = pred.decode_artifact(pred.paged_batch_state(2))
+        assert art.meta["delta_steps"] == [path] * 3
+        assert ("pallas_call" in art.jaxpr_text) == (path == "kernel")
 
 
 @pytest.mark.parametrize("form", ["sequence", "chunks", "steps"])

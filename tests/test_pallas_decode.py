@@ -468,6 +468,22 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+def _compiled_for_the_chip(fn, args, **jit):
+    """``jax.jit(fn, **jit)`` compiled over ``args`` (avals on the described
+    chip) with the persistent compilation cache off: what is compiled for a
+    chip that is not attached cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    cached = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jax.jit(fn, **jit).lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", cached)
+        compilation_cache.reset_cache()
+
+
 @pytest.mark.parametrize("cell", ["opt_serve_backlog", "falconh1_serve_chat",
                                   "mimo_serve_longshort",
                                   "olmoh_serve_rollouts"])
@@ -478,8 +494,6 @@ def test_kernel_compiles_for_the_chip_at_the_cells_shapes(cell, one_chip,
     kernel at the published widths, and the program holds no loop and no
     array of a gathered block's shape."""
     import re
-
-    from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
     node = next(n for n in probe.serving_nodes(cell)
@@ -499,14 +513,7 @@ def test_kernel_compiles_for_the_chip_at_the_cells_shapes(cell, one_chip,
                                  num_kv_heads=node["kv_heads"],
                                  value_scale=node["value_scale"])
 
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(attend).lower(*aval(args)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cached)
-        compilation_cache.reset_cache()
+    text = _compiled_for_the_chip(attend, aval(args)).as_text()
     assert attn.DECODE_PATH["last"] == "decode-kernel"
     assert "tpu_custom_call" in text
     assert " while(" not in text
@@ -529,8 +536,6 @@ def test_chunk_kernel_compiles_for_the_chip_at_the_cells_shapes(
     that cut the same nodes' prompts into chunks of 1024 would take the
     kernel at these widths (blocks of 256, 20 heads over 4)."""
     import re
-
-    from jax.experimental.compilation_cache import compilation_cache
 
     monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
     monkeypatch.setattr(attn, "CHUNK_MIN_ROWS", 256)
@@ -561,14 +566,7 @@ def test_chunk_kernel_compiles_for_the_chip_at_the_cells_shapes(
             chosen=None if mask is None else (mask, width),
             chunk=(tiles, False))
 
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(attend).lower(*aval(args)).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cached)
-        compilation_cache.reset_cache()
+    text = _compiled_for_the_chip(attend, aval(args)).as_text()
     assert "tpu_custom_call" in text
     assert " while(" not in text
     assert not re.search(r"s8\[\d+,%d,%d\]" % (plan[0], node["ek"]), text)
@@ -759,8 +757,6 @@ def test_latent_kernel_compiles_for_the_chip_at_the_cells_shapes(
     no copy of the pool and no array of a gathered block's shape."""
     import re
 
-    from jax.experimental.compilation_cache import compilation_cache
-
     import probe_latent_decode as latent_probe
 
     monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
@@ -778,19 +774,131 @@ def test_latent_kernel_compiles_for_the_chip_at_the_cells_shapes(
     def attend(*a):
         return attn.latent_attend(*a, spec)
 
-    cached = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    try:
-        text = jax.jit(attend).lower(*args).compile().as_text()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", cached)
-        compilation_cache.reset_cache()
+    text = _compiled_for_the_chip(attend, args).as_text()
     assert attn.DECODE_PATH["last"] == "absorbed-kernel"
     assert text.count("tpu_custom_call") == 1
     assert " while(" not in text
     assert not re.search(r"bf16\[83201,8,640\]\S* copy\(", text)
     assert not re.search(r"bf16\[\d+,512,320\]", text)
+
+
+# ---------------------------------------------------------------------------
+# the delta rule's decode step (ops/pallas_delta.py; its results against the
+# elementwise step are tests/test_delta_step_kernel.py's)
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("cell,h,dk,dv,per_head", [
+    ("solar2_serve_agent", 64, 128, 128, False),
+    ("olmoh_serve_rollouts", 30, 96, 192, True)])
+def test_delta_step_compiles_for_the_chip_at_the_cells_shapes(
+        cell, h, dk, dv, per_head, one_chip):
+    """A delta layer's 96 rows through ``delta_step``, the state donated,
+    compiled by the chip's own compiler: Mosaic takes the kernel at the
+    published widths with the rule's head block, the state's buffer is the
+    result's, and nothing copies it."""
+    import re
+
+    from mxnet_tpu.ops import pallas_delta as pdl
+
+    b = 96
+    sds = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32,
+                                              sharding=one_chip)
+    args = (sds(b, h, dk), sds(b, h, dk), sds(b, h, dv),
+            sds(b, h, 1 if per_head else dk), sds(b, h), sds(b, h, dk, dv),
+            jax.ShapeDtypeStruct((b,), jnp.int32, sharding=one_chip))
+    assert pdl.supported(h, dk, dv)
+    compiled = _compiled_for_the_chip(pdl.delta_step, args,
+                                      donate_argnums=(5,))
+    text = compiled.as_text()
+    assert text.count("custom_call_target=\"tpu_custom_call\"") == 1
+    assert "delta_step" in text
+    assert not re.search(r"f32\[%d,%d,%d,%d\]\S* copy\(" % (b, h, dk, dv),
+                         text)
+    # the donated state, as the chip stores it (whole (8, 128) tiles)
+    assert compiled.memory_analysis().alias_size_in_bytes \
+        == b * h * pdl._head_bytes(dk, dv)
+
+
+@pytest.mark.parametrize("shape", [
+    (96, 30, 96, 192), (192, 30, 96, 192), (64, 30, 96, 192),
+    (96, 64, 128, 128), (128, 30, 96, 128),
+    # another dim wastes less of its lane tiles than the values' 192
+    (128, 30, 96, 192), (120, 30, 96, 192), (384, 30, 96, 192),
+    (96, 128, 96, 192), (96, 30, 128, 192)], ids=lambda s: "x".join(
+        map(str, s)))
+def test_the_shape_rule_knows_how_the_chip_stores_a_state(shape, one_chip):
+    """``pallas_delta.supported(..., rows=)`` against the compiler itself:
+    the layout XLA:TPU gives a donated (rows, H, Dk, Dv) float32 operand of
+    the elementwise step (which asks for none) is value dim minor exactly
+    where the rule admits the kernel."""
+    import re
+
+    from mxnet_tpu.ops import kda
+    from mxnet_tpu.ops import pallas_delta as pdl
+
+    b, h, dk, dv = shape
+    sds = lambda *s: jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+    text = _compiled_for_the_chip(
+        kda._step, (sds(b, h, dk), sds(b, h, dk), sds(b, h, dv),
+                    sds(b, h, 1), sds(b, h), sds(*shape)),
+        donate_argnums=(5,)).as_text()
+    stored, = set(re.findall(
+        r"f32\[%d,%d,%d,%d\]\{([\d,]+):T\(8,128\)\} parameter\(" % shape,
+        text[text.index("ENTRY"):]))
+    assert pdl.supported(h, dk, dv)
+    assert (stored == "3,2,1,0") == pdl.supported(h, dk, dv, rows=b), stored
+
+
+@pytest.mark.parametrize("kind,heads", [("kda", (2, 128, 128)),
+                                        ("gdn", (2, 96, 256))])
+def test_the_decode_program_holds_one_delta_step_a_layer(kind, heads,
+                                                         one_chip,
+                                                         monkeypatch):
+    """The paged decode program of a toy with three delta layers (Solar-
+    Open2's keys, Olmo-Hybrid's; two heads of whole lane tiles),
+    compiled for the chip with the rule's question about the backend
+    answered for it: one ``delta_step`` custom call a delta layer, each
+    matrix-state leaf read by that call alone and its buffer the call's
+    result (and the program's: the leaf is donated), no copy of a leaf's
+    shape.  1024 slots: a leaf the compiler can fit into the chip's 128 MiB
+    of fast memory it moves there whole before the call, a toy's artefact."""
+    import re
+
+    import mxnet_tpu as mx
+    from mxnet_tpu.decode import DecodePredictor
+    from mxnet_tpu.programs import spec as pspec
+    from mxnet_tpu.test_utils import delta_toy_lm
+
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
+    slots = 1024
+    pred = DecodePredictor(*delta_toy_lm(kind, *heads[1:]), cache_len=64,
+                           ctx=mx.cpu(),
+                           temperature=0.0, paged=True, page_tokens=4,
+                           prefill_chunk=8)
+    avals = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip),
+        pred.serving_avals(slots, chunk_w=8)["decode"])
+    with pspec.probing(pred):
+        compiled = _compiled_for_the_chip(pred._paged_decode_impl, avals,
+                                          donate_argnums=(1,))
+    text = compiled.as_text()
+    assert pred._delta_steps[1] == ["kernel"] * 3
+    calls = [line for line in text.splitlines()
+             if "custom_call_target=\"tpu_custom_call\"" in line
+             and "delta_step" in line]
+    assert len(calls) == 3
+    leaf = r"f32\[%d,%d,%d,%d\]" % ((slots,) + heads)
+    entry = text[text.index("ENTRY"):]
+    leaves = re.findall(r"(%%\S+) = %s\S* parameter\(" % leaf, entry)
+    assert len(leaves) == 3
+    for name in leaves:
+        readers = [line for line in entry.splitlines()
+                   if re.search(r"[(, ]%s[,)]" % re.escape(name), line)]
+        assert len(readers) == 1 and readers[0] in calls, readers
+        # the state in is operand 3 of the call, its result 1
+        assert "output_to_operand_aliasing={{1}: (3, {})}" in readers[0]
+    assert not re.search(r"%s\S* copy\(" % leaf, text)
+    state_bytes = 3 * slots * heads[0] * heads[1] * heads[2] * 4
+    assert compiled.memory_analysis().alias_size_in_bytes >= state_bytes
 
 
 # ---------------------------------------------------------------------------
