@@ -98,12 +98,13 @@ def main():
 
     def dense(xt, wg, wu, wd, choice, weight):
         here = choice[:, :, None] == jnp.arange(wg.shape[0])[None, None, :]
-        return moe._experts_dense(xt, wg, wu, wd, here, weight, "moe")
+        return moe._experts_dense(xt, (wg, wu, wd), moe.BODIES["swiglu"],
+                                  here, weight, "moe")
 
     def grouped(xt, wg, wu, wd, choice, weight):
         here = choice[:, :, None] == jnp.arange(wg.shape[0])[None, None, :]
-        return moe._experts_grouped(xt, wg, wu, wd, here, weight, "moe",
-                                    False)
+        return moe._experts_grouped(xt, (wg, wu, wd), moe.BODIES["swiglu"],
+                                    here, weight, "moe", False)
 
     def line(**kw):
         print(json.dumps(dict(kw, device=dev.device_kind)), flush=True)

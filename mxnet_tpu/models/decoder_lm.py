@@ -127,6 +127,18 @@ model of the family is a configuration file and no code:
   projection, before the heads are cut, a gain of ``heads x head_dim`` each
   (OLMo 2), where ``True`` norms a head;
 
+* ``hybrid_override_pattern`` (Nemotron-H: one letter a layer): every layer
+  is ONE sublayer under one norm (``_norm``), ``x + f(RMSNorm(x))``: ``M`` the
+  state-space mixer alone (the ``mamba_*`` sizes above; ``mamba_d_ssm`` 0:
+  there is no parallel block), ``E`` the routed experts, ``*`` the attention
+  ``hybrid_layer_pattern`` says, ``-`` a dense MLP of ``intermediate_size``.
+  Any other letter is refused by name;
+* ``mlp_hidden_act`` ``"relu2"``: every expert, the shared one and the dense
+  MLP are ``relu(x W_u)^2 W_d``, two matrices and no gate (``"silu"``: the
+  gated MLP of three); ``moe_shared_expert_intermediate_size``: the shared
+  expert's width where it is not ``n_shared_experts x
+  moe_intermediate_size``.
+
 ``hybrid_layer_pattern`` and ``moe_layer_freq`` default to zeros: full
 attention and a dense MLP in every layer.  Entries ``first_layer ..
 first_layer + num_layers - 1`` of the per-layer lists are built.
@@ -343,6 +355,16 @@ def gated_mlp(data, name, hidden, width, multipliers=(1.0, 1.0)):
                  multipliers[1])
 
 
+def relu2_mlp(data, name, hidden, width):
+    """``relu(x W_u)^2 W_d``: two matrices, no gate."""
+    up = sym.FullyConnected(data, num_hidden=width, no_bias=True,
+                            flatten=False, name=name + "_ffn_up")
+    with AttrScope(**_MLP):
+        h = sym.square(sym.Activation(up, act_type="relu"))
+    return sym.FullyConnected(h, num_hidden=hidden, no_bias=True,
+                              flatten=False, name=name + "_ffn_down")
+
+
 def ssm_mixer(data, name, hidden, heads, head_dim, state, groups, conv,
               chunk, eps, proj_bias, state_dtype, multipliers):
     """The state-space mixer: ``hidden`` -> [z | x | B | C | dt] ->
@@ -406,7 +428,9 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
                linear_num_key_heads=0, linear_num_value_heads=0,
                linear_key_head_dim=0, linear_value_head_dim=0,
                linear_conv_kernel_dim=4, linear_allow_neg_eigval=False,
-               norm_after=False, **kwargs):
+               norm_after=False, hybrid_override_pattern=None,
+               mlp_hidden_act="silu", moe_shared_expert_intermediate_size=0,
+               **kwargs):
     """data (B, T) int tokens -> softmax over the vocabulary at every
     position (``softmax_label`` (B, T) next tokens, pad = -1 ignored)."""
     heads = int(num_attention_heads)
@@ -443,6 +467,15 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
         moe_more["n_shared_experts"] = int(n_shared_experts)
     if float(routed_scaling_factor or 1.0) != 1.0:
         moe_more["routed_scaling_factor"] = float(routed_scaling_factor)
+    if mlp_hidden_act not in ("silu", "relu2"):
+        raise ValueError("mlp_hidden_act %r: the feed-forward parts are "
+                         "built gated (silu) or as relu(x W_u)^2 W_d (relu2)"
+                         % (mlp_hidden_act,))
+    if mlp_hidden_act == "relu2":
+        moe_more["expert_act"] = "relu2"
+    if int(moe_shared_expert_intermediate_size or 0):
+        moe_more["shared_hidden_size"] = int(
+            moe_shared_expert_intermediate_size)
 
     def experts(normed, name):
         return sym.MoEFFN(
@@ -479,6 +512,32 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
             gate=bool(attn_use_output_gate if selects else use_gqa_gate),
             qk_norm_eps=float(layernorm_epsilon) if attn_qk_norm else 0.0,
             layer=layer, qk_norm_whole=attn_qk_norm == "projection")
+
+    def dense(normed, name):
+        if mlp_hidden_act == "relu2":
+            return relu2_mlp(normed, name, hidden_size,
+                             int(intermediate_size))
+        return gated_mlp(normed, name, hidden_size, int(intermediate_size),
+                         mlp_multipliers)
+
+    def state_space(normed, name):
+        return ssm_mixer(
+            normed, name, hidden_size, int(mamba_n_heads), int(mamba_d_head),
+            int(mamba_d_state), int(mamba_n_groups), int(mamba_d_conv),
+            int(mamba_chunk_size), layernorm_epsilon, bool(mamba_proj_bias),
+            ssm_state_dtype, ssm_multipliers)
+
+    # Nemotron-H's letters: the one sublayer a layer is
+    alone = {"M": state_space, "E": experts, "-": dense,
+             "*": lambda normed, name: attend(normed, name, windowed=False)}
+    pattern = str(hybrid_override_pattern or "")
+    if pattern and (set(pattern) - set(alone) or mamba_d_ssm or norm_after
+                    or len(pattern) < len(zeros)):
+        raise ValueError(
+            "hybrid_override_pattern %r: %d letters of M, E, * and - are "
+            "read, one sublayer a layer under its own norm before it (not "
+            "beside mamba_d_ssm's parallel block or norm_after)"
+            % (pattern, len(zeros)))
     mixer_types = mixer_types or ("",) * len(zeros)
     if int(linear_key_head_dim or 0):
         mixer_types = tuple(
@@ -498,7 +557,7 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
             * int(mamba_d_head):
         raise ValueError("mamba_d_ssm %d != mamba_n_heads %d x mamba_d_head "
                          "%d" % (mamba_d_ssm, mamba_n_heads, mamba_d_head))
-    if mamba_d_ssm and not mamba_conv_bias:
+    if (mamba_d_ssm or "M" in pattern) and not mamba_conv_bias:
         raise ValueError("mamba_conv_bias false: the mixer's convolution is "
                          "built with its bias (ops.ssm)")
     data = sym.Variable("data")
@@ -521,6 +580,9 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
     after = norm if norm_after else (lambda x, name: x)
     for i in range(first, first + int(num_layers)):
         name = "layer%d" % i
+        if pattern:
+            net = net + alone[pattern[i]](norm(net, name + "_norm"), name)
+            continue
         windowed = bool(hybrid_layer_pattern[i])
         normed = before(net, name + "_att_norm")
         selects = mixer_types[i] == "minicpm4"
@@ -548,23 +610,15 @@ def get_symbol(vocab_size, hidden_size, num_layers, num_attention_heads,
         else:
             if mamba_d_ssm:
                 # the parallel block: both mixers read the one normed input
-                net = net + times(ssm_mixer(
-                    times(normed, ssm_in_multiplier), name, hidden_size,
-                    int(mamba_n_heads), int(mamba_d_head),
-                    int(mamba_d_state), int(mamba_n_groups),
-                    int(mamba_d_conv), int(mamba_chunk_size),
-                    layernorm_epsilon, bool(mamba_proj_bias),
-                    ssm_state_dtype, ssm_multipliers), ssm_out_multiplier)
+                net = net + times(state_space(
+                    times(normed, ssm_in_multiplier), name),
+                    ssm_out_multiplier)
             mixed = times(attend(
                 times(normed, attention_in_multiplier), name, windowed,
                 selects), attention_out_multiplier * depth)
         net = net + after(mixed, name + "_att_norm")
         normed = before(net, name + "_ffn_norm")
-        if moe_layer_freq[i]:
-            ffn = experts(normed, name)
-        else:
-            ffn = gated_mlp(normed, name, hidden_size,
-                            int(intermediate_size), mlp_multipliers)
+        ffn = (experts if moe_layer_freq[i] else dense)(normed, name)
         net = net + after(times(ffn, depth), name + "_ffn_norm")
     if not int(num_nextn_predict_layers):
         net = sym.RMSNorm(net, eps=layernorm_epsilon, name="final_norm")

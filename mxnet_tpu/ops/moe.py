@@ -73,11 +73,59 @@ def _held(attrs):
 
 
 def _shared_width(attrs):
-    """Width of the always-on gated MLP beside the routed experts:
-    ``n_shared_experts`` experts of the routed experts' width, as one MLP
-    (0 = none)."""
-    return int(attrs.get("n_shared_experts", 0) or 0) \
-        * int(attrs["hidden_size"])
+    """Width of the always-on MLP beside the routed experts (0 = none):
+    ``shared_hidden_size`` where the node states it, else
+    ``n_shared_experts`` experts of the routed experts' width, as one MLP."""
+    if not int(attrs.get("n_shared_experts", 0) or 0):
+        return 0
+    return int(attrs.get("shared_hidden_size", 0) or 0) \
+        or int(attrs["n_shared_experts"]) * int(attrs["hidden_size"])
+
+
+def _silu(g):
+    import jax
+
+    return jax.nn.silu(g)
+
+
+def _relu2(u):
+    import jax.numpy as jnp
+
+    return jnp.square(jnp.maximum(u, 0))
+
+
+# an expert's body at a chip's share (``expert_act``): the names of its
+# matrices, the last the way back; the activation of the first one's product
+# (a further matrix in multiplies it: `_hidden`); and whether the matrices in
+# are stored as the one back is, (held, h, d), a hidden unit a row.
+# ``swiglu`` is ``(silu(x W_g) * (x W_u)) W_d``; ``relu2`` is ``relu(x
+# W_u)^2 W_d``, two matrices, both a unit a row: then only the model's width
+# is ever a minor dimension, and an expert width that is no whole number of
+# lane tiles (1856 = 14.5 x 128) is stored without padding and read by the
+# grouped product as it lies (as (held, d, 1856) the chip keeps it with d
+# minor, and every call of the kernel pays a transposing copy of the stack)
+BODIES = {"swiglu": (("gate", "up", "down"), _silu, False),
+          "relu2": (("up", "down"), _relu2, True)}
+
+
+def _hidden(act, products):
+    """What the matrix back takes: ``act`` of the first product in, times
+    each further one, drawn from ``products`` as it is needed."""
+    products = iter(products)
+    h = act(next(products))
+    for p in products:
+        h = h * p
+    return h
+
+
+def _body(attrs):
+    """``(parts, act, unit_rows)`` of the node's ``expert_act``
+    (:data:`BODIES`)."""
+    act = attrs.get("expert_act") or "swiglu"
+    if act not in BODIES:
+        raise ValueError("MoEFFN: expert_act must be one of %s; got %r"
+                         % (sorted(BODIES), act))
+    return BODIES[act]
 
 
 def _moe_shape(attrs, in_shapes, aux_shapes):
@@ -87,12 +135,15 @@ def _moe_shape(attrs, in_shapes, aux_shapes):
     d = x[-1]
     if attrs.get("gated"):
         held = _held(attrs)
+        parts, _, unit_rows = _body(attrs)
+        ins = len(parts) - 1
         bias = [(e,)] if attrs.get("score_bias") else []
-        want = [tuple(x), (d, e)] + bias + [(held, d, h), (held, d, h),
-                                            (held, h, d)]
+        want = [tuple(x), (d, e)] + bias \
+            + [(held, h, d) if unit_rows else (held, d, h)] * ins \
+            + [(held, h, d)]
         hs = _shared_width(attrs)
         if hs:
-            want += [(d, hs), (d, hs), (hs, d)]
+            want += [(hs, d) if unit_rows else (d, hs)] * ins + [(hs, d)]
         return want, [tuple(x)], []
     want = [tuple(x), (d, e), (e, d, h), (e, h), (e, h, d), (e, d)]
     return want, [tuple(x)], []
@@ -100,20 +151,21 @@ def _moe_shape(attrs, in_shapes, aux_shapes):
 
 def _moe_arguments(attrs):
     if attrs.get("gated"):
+        parts = _body(attrs)[0]
         return ["data", "gate_weight"] \
             + (["gate_bias"] if attrs.get("score_bias") else []) \
-            + ["expert_gate_weight", "expert_up_weight",
-               "expert_down_weight"] \
-            + (["shared_gate_weight", "shared_up_weight",
-                "shared_down_weight"] if _shared_width(attrs) else [])
+            + ["expert_%s_weight" % part for part in parts] \
+            + (["shared_%s_weight" % part for part in parts]
+               if _shared_width(attrs) else [])
     return ["data", "gate_weight", "expert1_weight", "expert1_bias",
             "expert2_weight", "expert2_bias"]
 
 
 # ---------------------------------------------------------------------------
 # the gated layer at one chip's share (serving): sigmoid or softmax scores
-# over all E experts, top-k with a selection-only bias, SwiGLU experts
-# without biases, of which this chip holds [first, first + held)
+# over all E experts, top-k with a selection-only bias, experts without
+# biases (SwiGLU, or the node's ``expert_act``), of which this chip holds
+# [first, first + held)
 # ---------------------------------------------------------------------------
 
 # where a caller collects what the gated layers it traces counted: a list
@@ -201,9 +253,33 @@ def _scores(xt, wr, bias, k, score_func, norm_topk):
 GROUPED_MIN_ROWS = 512
 GROUPED_TILES = (128, 4096, 512)
 LANES = 128
+# rows of a bfloat16 matrix's sublane tile (a float32 one's is 8)
+SUBLANES = 16
+# under this the largest common divisor is no tile to work in: the width
+# has few factors of two (2688 = 21 x 128, 1856 = 29 x 64) and `_tile` looks
+# further
+MIN_TILE = 512
 
 
-def grouped_selected(n, k, held, d, h, mesh_active=False):
+def _tile(cap, width):
+    """A step's tile of one ``width`` of a grouped product, ``cap`` the most
+    it may be.  The largest divisor the two share where that is a tile worth
+    having (every width of whole powers of two: 4096, 2048, 6144 = 3 x
+    2048); else the width whole where the cap holds it (a block that is the
+    whole dimension needs no alignment), else its largest divisor of whole
+    lane tiles under the cap (2688 under 512: 384), else the cap itself, the
+    last tile partial (1856 under 512: three tiles and 320 columns)."""
+    t = math.gcd(cap, width)
+    if t >= min(MIN_TILE, cap):
+        return t
+    if width <= cap:
+        return width
+    whole = [w for w in range(cap - cap % LANES, 0, -LANES)
+             if width % w == 0]
+    return whole[0] if whole else cap
+
+
+def grouped_selected(n, k, held, d, h, mesh_active=False, unit_rows=False):
     """``(take, interpret)``: whether a gated layer's call of ``n`` rows,
     top ``k`` over ``held`` held experts of ``d`` x ``h``, takes the grouped
     form of the routed product, decided from what the call shows, as
@@ -211,7 +287,9 @@ def grouped_selected(n, k, held, d, h, mesh_active=False):
 
     All must hold: a backend that runs Pallas (``attention._kernel_backend``:
     the product is megablox's kernel); no mesh shards the executor (the
-    kernel is opaque to GSPMD); widths of whole lane tiles; and ``n`` has
+    kernel is opaque to GSPMD); widths of whole lane tiles (``unit_rows``,
+    every stack (held, h, d): the expert's width is nowhere minor and needs
+    whole sublane tiles only); and ``n`` has
     reached :data:`GROUPED_MIN_ROWS`.  Anything else takes the dense form.
     ``k`` and ``held`` size the grouped form's buffer and do not move the
     rule: at every top-k and share measured the forms cross between 256 and
@@ -219,7 +297,8 @@ def grouped_selected(n, k, held, d, h, mesh_active=False):
     from .attention import _kernel_backend
 
     runs, interpret = _kernel_backend()
-    if mesh_active or not runs or d % LANES or h % LANES:
+    if mesh_active or not runs or d % LANES \
+            or h % (SUBLANES if unit_rows else LANES):
         return False, False
     return n >= GROUPED_MIN_ROWS, interpret
 
@@ -236,28 +315,31 @@ def _note_form(form):
         labels=("form",)).labels(form=form).inc()
 
 
-def _experts_dense(xt, wg, wu, wd, here, weight, layer):
-    """Every held expert over every row; the unchosen pairs weigh zero."""
-    import jax
+def _experts_dense(xt, ws, body, here, weight, layer):
+    """Every held expert over every row; the unchosen pairs weigh zero.
+    ``ws``: an expert's matrices in, then the one back; ``body`` the node's
+    entry of :data:`BODIES`."""
     import jax.numpy as jnp
 
     from ..obs.scopes import scope as _scope
 
+    _, act, unit_rows = body
+    into = "nd,ehd->enh" if unit_rows else "nd,edh->enh"
     with _scope(layer, "experts"):
-        g = jnp.einsum("nd,edh->enh", xt, wg)
-        u = jnp.einsum("nd,edh->enh", xt, wu)
-        y = jnp.einsum("enh,ehd->end", jax.nn.silu(g) * u, wd,
+        ins = [jnp.einsum(into, xt, w) for w in ws[:-1]]
+        y = jnp.einsum("enh,ehd->end", _hidden(act, ins), ws[-1],
                        preferred_element_type=jnp.float32)
     with _scope(layer, "combine"):
         w = (here * weight[:, :, None]).sum(1)            # (n, held)
         return jnp.einsum("end,ne->nd", y, w)
 
 
-def _experts_grouped(xt, wg, wu, wd, here, weight, layer, interpret):
+def _experts_grouped(xt, ws, body, here, weight, layer, interpret):
     """Only the held (row, expert) pairs: sorted by held expert, their rows
     gathered into a buffer of the worst case (every row choosing ``min(k,
-    held)`` held experts), three grouped products whose work follows the
-    groups' sizes, and each pair's output, times its weight, summed back into
+    held)`` held experts), grouped products whose work follows the groups'
+    sizes (``ws`` and ``body`` as :func:`_experts_dense` takes them), and each
+    pair's output, times its weight, summed back into
     its row.  The sizes are data: one trace serves every routing and no pair
     is ever dropped.  Rows of the buffer past the last group are never
     computed; what lies there is selected away, not multiplied by zero.  The
@@ -273,13 +355,14 @@ def _experts_grouped(xt, wg, wu, wd, here, weight, layer, interpret):
 
     from ..obs.scopes import scope as _scope
 
+    _, act, unit_rows = body
     n, k, held = here.shape
     tm, tk, tn = GROUPED_TILES
     pairs = n * k
     rows = -(-n * min(k, held) // tm) * tm
 
     @jax.custom_vjp
-    def run(xt, wg, wu, wd, here, weight):
+    def run(xt, ws, here, weight):
         with _scope(layer, "route"):
             # the pairs choice by choice, a choice's rows together: what is
             # gathered back for a choice is then (n, d) as it lies
@@ -295,20 +378,20 @@ def _experts_grouped(xt, wg, wu, wd, here, weight, layer, interpret):
                 jnp.arange(pairs, dtype=jnp.int32))
             source = jnp.pad(order, (0, max(0, rows - pairs)))[:rows] % n
 
-        def product(lhs, rhs, out_type):
+        def product(lhs, rhs, out_type, rows_out=False):
             # Mosaic has no 64-bit integers: traced with 32-bit defaults
             # whatever ``jax_enable_x64`` says (the tests set it)
             with jax.enable_x64(False):
                 return gmm(lhs, rhs, sizes, preferred_element_type=out_type,
-                           tiling=(tm, math.gcd(tk, lhs.shape[1]),
-                                   math.gcd(tn, rhs.shape[2])),
-                           interpret=interpret)
+                           tiling=(tm, _tile(tk, lhs.shape[1]),
+                                   _tile(tn, rhs.shape[1 if rows_out
+                                                       else 2])),
+                           transpose_rhs=rows_out, interpret=interpret)
 
         with _scope(layer, "experts"):
             xs = jnp.take(xt, source, axis=0, mode="clip")    # (rows, d)
-            g = product(xs, wg, xt.dtype)
-            u = product(xs, wu, xt.dtype)
-            y = product(jax.nn.silu(g) * u, wd, jnp.float32)
+            ins = [product(xs, w, xt.dtype, unit_rows) for w in ws[:-1]]
+            y = product(_hidden(act, ins), ws[-1], jnp.float32)
         with _scope(layer, "combine"):
             picked = jnp.take(y, jnp.minimum(slot, rows - 1), axis=0,
                               mode="clip").reshape(k, n, -1)
@@ -323,14 +406,15 @@ def _experts_grouped(xt, wg, wu, wd, here, weight, layer, interpret):
             % GROUPED_MIN_ROWS)
 
     run.defvjp(refuse, refuse)
-    return run(xt, wg, wu, wd, here, weight)
+    return run(xt, tuple(ws), here, weight)
 
 
-def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None,
-               mesh_active=False):
+def _moe_share(x, wr, bias, ws, attrs, k, shared=None, mesh_active=False):
     """The gated layer at this chip's share: what the held experts add to
-    each token's output, and nothing in place of the others.  ``shared``
-    (gate, up, down; ``n_shared_experts``) is an always-on gated MLP that
+    each token's output, and nothing in place of the others.  ``ws`` are the
+    held experts' weight stacks and ``shared`` (``n_shared_experts``) the
+    matrices of an always-on MLP of the same body (``expert_act``,
+    :data:`BODIES`) that
     every chip holds whole and adds to its own tokens: it is in every share,
     so where shares are added up it is counted in one of them.  The routed
     weights are scaled by ``routed_scaling_factor`` after normalising.
@@ -348,8 +432,8 @@ def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None,
     both no token is ever dropped and nothing retraces as the routing
     changes.  The readings that put the crossover at 512 rows are beside
     :data:`GROUPED_MIN_ROWS` (a chunk of 2048 rows at 16 of 128 experts held,
-    top 4: dense 10.83 ms a layer, grouped 2.53)."""
-    import jax
+    top 4: dense 10.83 ms a layer, grouped 2.53).  A body other than the
+    gated one is counted under its own label (``held_dense_relu2``)."""
     import jax.numpy as jnp
 
     from ..obs.scopes import LAYER_ATTR, scope as _scope
@@ -360,10 +444,12 @@ def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None,
     first = int(attrs.get("first_held", 0) or 0)
     held = _held(attrs)
     e = int(attrs["num_experts"])
-    if first < 0 or first + held > e or wg.shape[0] != held:
+    body = _body(attrs)
+    _, act, unit_rows = body
+    if first < 0 or first + held > e or ws[-1].shape[0] != held:
         raise ValueError("MoEFFN: experts [%d, %d) of a stack of %d are "
                          "not among the layer's %d"
-                         % (first, first + held, wg.shape[0], e))
+                         % (first, first + held, ws[-1].shape[0], e))
     xt = x.reshape(-1, x.shape[-1])
     with _scope(layer, "route"):
         choice, weight = _scores(xt, wr, bias, min(k, e),
@@ -376,21 +462,22 @@ def _moe_share(x, wr, bias, wg, wu, wd, attrs, k, shared=None,
         here = choice[:, :, None] \
             == (first + jnp.arange(held))[None, None, :]  # (n, k, held)
     grouped, interpret = grouped_selected(
-        xt.shape[0], choice.shape[1], held, wg.shape[1], wg.shape[2],
-        mesh_active)
+        xt.shape[0], choice.shape[1], held, ws[-1].shape[2],
+        ws[-1].shape[1], mesh_active, unit_rows)
+    # the gated body keeps the labels it had; another adds its name
+    name = attrs.get("expert_act") or "swiglu"
+    label = "" if name == "swiglu" else "_" + name
     if grouped:
-        _note_form("held_grouped")
-        out = _experts_grouped(xt, wg, wu, wd, here, weight, layer,
-                               interpret)
+        _note_form("held_grouped" + label)
+        out = _experts_grouped(xt, ws, body, here, weight, layer, interpret)
     else:
-        _note_form("held_dense")
-        out = _experts_dense(xt, wg, wu, wd, here, weight, layer)
+        _note_form("held_dense" + label)
+        out = _experts_dense(xt, ws, body, here, weight, layer)
     if shared is not None:
-        sg, su, sd = shared
         with _scope(layer, "shared"):
-            out = out + jnp.dot(
-                jax.nn.silu(jnp.dot(xt, sg)) * jnp.dot(xt, su), sd,
-                preferred_element_type=jnp.float32)
+            ins = (jnp.dot(xt, w.T if unit_rows else w) for w in shared[:-1])
+            out = out + jnp.dot(_hidden(act, ins), shared[-1],
+                                preferred_element_type=jnp.float32)
     if _STATS["sink"] is not None:
         with _scope(layer, "route"):
             pairs = here.any(-1)                          # (n, k)
@@ -865,8 +952,9 @@ def register_all():
         if attrs.get("gated"):
             x, wr, *rest = inputs
             bias = rest.pop(0) if attrs.get("score_bias") else None
-            shared = tuple(rest[3:]) if _shared_width(attrs) else None
-            return [_moe_share(x, wr, bias, *rest[:3], attrs, k,
+            n = len(_body(attrs)[0])
+            shared = tuple(rest[n:]) if _shared_width(attrs) else None
+            return [_moe_share(x, wr, bias, tuple(rest[:n]), attrs, k,
                                shared=shared,
                                mesh_active=octx.mesh_active)], []
         if attrs.get("score_bias") or attrs.get("num_held") \
@@ -913,9 +1001,15 @@ def register_all():
                       "MXNET_MOE_DROPLESS=1 forces 'dropless'.  The gated "
                       "layer has no capacity and never drops"),
             Param("gated", bool, default=False,
-                  doc="SwiGLU experts without biases (three weight stacks "
-                      "gate/up/down), routed without capacity or drops; "
-                      "the attributes below belong to it"),
+                  doc="the layer at one chip's share: experts without "
+                      "biases, routed without capacity or drops; the "
+                      "attributes below belong to it"),
+            Param("expert_act", str, default="swiglu",
+                  doc="an expert's body (and the shared MLP's): 'swiglu' = "
+                      "silu(x W_g) * (x W_u) then W_d, three weight stacks "
+                      "gate/up/down of (held, d, h), (held, d, h), (held, "
+                      "h, d); 'relu2' = relu(x W_u)^2 then W_d, two stacks "
+                      "up/down, both (held, h, d), a hidden unit a row"),
             Param("score_func", str, default="softmax",
                   doc="'softmax' or 'sigmoid' over the router's outputs"),
             Param("score_bias", bool, default=False,
@@ -930,16 +1024,18 @@ def register_all():
             Param("first_held", int, default=0,
                   doc="the first expert held"),
             Param("n_shared_experts", int, default=0,
-                  doc="always-on gated experts beside the routed ones, "
+                  doc="always-on experts beside the routed ones, "
                       "held as one MLP of n_shared_experts x hidden_size "
-                      "(shared_gate/up/down_weight, (d, w) and (w, d)) and "
-                      "added in every share"),
+                      "(shared_gate/up/down_weight, (d, w) and (w, d); "
+                      "under 'relu2' shared_up/down_weight, both (w, d)) "
+                      "and added in every share"),
+            Param("shared_hidden_size", int, default=0,
+                  doc="the shared MLP's width where it is not "
+                      "n_shared_experts x hidden_size; 0 = that"),
             Param("routed_scaling_factor", float, default=1.0,
                   doc="the routed weights x this, after norm_topk"),
         ),
-        num_inputs=lambda a: (5 + bool(a.get("score_bias"))
-                              + 3 * bool(a.get("n_shared_experts")))
-        if a.get("gated") else 6,
+        num_inputs=lambda a: len(_moe_arguments(a)),
         arguments=_moe_arguments,
         infer_shape=_moe_shape,
         mesh_axes={"expert1_weight": "expert", "expert1_bias": "expert",

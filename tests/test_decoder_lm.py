@@ -560,3 +560,103 @@ def test_layer_types_choose_gated_deltanet_where_the_linear_keys_are_given():
             decoder_lm.get_symbol(layer_types=kinds, sliding_window=4,
                                   **dict(OLMO, num_layers=3),
                                   **dict(lin, **bad))
+
+
+# ---------------------------------------------------------------------------
+# Nemotron-H's letters: every layer ONE sublayer under its own norm
+# (``nemotron-3-nano-30b``'s keys at a toy size)
+# ---------------------------------------------------------------------------
+LETTER_TOY = dict(
+    vocab_size=96, hidden_size=64, num_attention_heads=4,
+    num_key_value_heads=2, head_dim=16, intermediate_size=40,
+    moe_intermediate_size=48, moe_shared_expert_intermediate_size=80,
+    n_routed_experts=8, num_experts_per_tok=3, held_n_routed_experts=4,
+    first_held_expert=4, mamba_num_heads=4, mamba_head_dim=8,
+    ssm_state_size=16, n_groups=2, chunk_size=8,
+    hybrid_override_pattern="ME*-EM", num_hidden_layers=6,
+    serve_num_hidden_layers=6, max_position_embeddings=64)
+
+
+def letter_config(**over):
+    """``nemotron-3-nano-30b`` cut to the toy's sizes: a layer of each
+    letter, stateless layers (``E``, ``-``) between stateful ones (``M``,
+    ``*``)."""
+    cfg = manifest.load_json(manifest.ROOT,
+                             "chipbench/configs/nemotron-3-nano-30b.json")
+    wider = {"_(q|k)_weight$": 0.16, "_ssm_in_weight$": 0.16}
+    init = [dict(r, std=wider.get(r["match"], 0.08))
+            if r["match"].endswith("_weight$") and r["dist"] == "normal"
+            and "conv" not in r["match"] and "embed" not in r["match"]
+            else r for r in cfg["init"]]
+    return dict(cfg, init=init, **dict(LETTER_TOY, **over))
+
+
+def test_every_layer_is_the_one_sublayer_its_letter_names():
+    """``hybrid_override_pattern`` builds ``x + f(RMSNorm(x))`` with one
+    ``f`` a layer: the mixer alone (no attention node beside it), the
+    two-matrix relu^2 experts with a shared MLP of its own width, attention
+    of 4 / 2 heads without rotation, a dense relu^2 MLP; and the whole agrees
+    with the plain reference."""
+    from chipbench.reference import nemotron_h
+
+    cfg = letter_config()
+    sym, params = build(cfg)
+    kinds = {}
+    for node in sym._topo():
+        if not node.is_variable and node.op.name in (
+                "dot_product_attention", "SelectiveSSM", "MoEFFN",
+                "RMSNorm"):
+            kinds.setdefault(node.name.split("_")[0], []).append(
+                node.op.name)
+    assert [kinds["layer%d" % l] for l in range(6)] == [
+        ["RMSNorm", "SelectiveSSM"], ["RMSNorm", "MoEFFN"],
+        ["RMSNorm", "dot_product_attention"], ["RMSNorm"],
+        ["RMSNorm", "MoEFFN"], ["RMSNorm", "SelectiveSSM"]]
+    assert "square" in [n.op.name for n in sym._topo() if not n.is_variable]
+    assert [n for n in params if n.endswith("_norm_gamma")
+            and not n.startswith(("final", "layer0_ssm", "layer5_ssm"))] \
+        == ["layer%d_norm_gamma" % l for l in range(6)]
+    att = next(n for n in sym._topo() if n.name == "layer2_att")
+    assert int(att.parsed_attrs().get("rotary_dim", 0)) == 0
+    moe_node = next(n for n in sym._topo() if n.name == "layer1_moe")
+    attrs = moe_node.parsed_attrs()
+    assert attrs["expert_act"] == "relu2" and attrs["shared_hidden_size"] == 80
+    assert float(attrs["routed_scaling_factor"]) == 2.5
+    assert params["layer1_moe_expert_up_weight"].shape == (4, 48, 64)
+    assert params["layer1_moe_shared_down_weight"].shape == (80, 64)
+    assert params["layer3_ffn_up_weight"].shape == (40, 64)
+    assert params["layer0_ssm_in_weight"].shape == (2 * 32 + 2 * 32 + 4, 64)
+    assert not [n for n in params if "_ffn_gate" in n or "_att_norm" in n
+                or "_ffn_norm" in n or "moe_expert_gate" in n]
+    toks = np.random.default_rng(0).integers(0, 96, size=(1, T))
+    probs = system_probs(sym, params, toks)
+    want = jax.nn.log_softmax(nemotron_h.forward(params, cfg, toks)[0], -1)
+    assert float(jnp.max(jnp.abs(jnp.log(probs) - want))) < 2e-5
+    # the rotation the file's rope_theta would give is another model
+    turned = system_probs(harness.build_symbol(
+        dict(cfg, attn_use_rope=True)), params, toks)
+    assert float(jnp.max(jnp.abs(jnp.log(turned) - want))) > 1e-3
+
+
+def test_what_the_letters_cannot_say_is_refused_by_name():
+    from mxnet_tpu.models import decoder_lm
+
+    cfg = letter_config()
+    with pytest.raises(ValueError, match="hybrid_override_pattern 'MXM"):
+        harness.build_symbol(dict(cfg, hybrid_override_pattern="MXM*EM"))
+    with pytest.raises(ValueError, match="6 letters"):
+        harness.build_symbol(dict(cfg, hybrid_override_pattern="ME*"))
+    with pytest.raises(ValueError, match="mlp_hidden_act 'gelu'"):
+        harness.build_symbol(dict(cfg, mlp_hidden_act="gelu"))
+    with pytest.raises(ValueError, match="mamba_conv_bias"):
+        harness.build_symbol(dict(cfg, use_conv_bias=False))
+    with pytest.raises(ValueError, match="parallel block"):
+        decoder_lm.get_symbol(hybrid_override_pattern="M", mamba_d_ssm=8,
+                              mamba_n_heads=2, mamba_d_head=4,
+                              mamba_d_state=4, **OLMO)
+    # the other families' configurations name none of it, and silu keeps
+    # the gated MLP of three matrices
+    assert "hybrid_override_pattern" not in toy_config()["symbol_args"]
+    with_gate = harness.build_symbol(dict(
+        cfg, mlp_hidden_act="silu", hybrid_override_pattern="M-M-M-"))
+    assert "layer1_ffn_gate_weight" in with_gate.list_arguments()

@@ -1102,3 +1102,150 @@ def test_chooser_follows_the_calls_rows(cell, rows, grouped, monkeypatch):
     assert moe.grouped_selected(rows, k, held, d, h) == (grouped, True)
     monkeypatch.setattr(attn, "_kernel_backend", lambda: (False, False))
     assert moe.grouped_selected(rows, k, held, d, h) == (False, False)
+
+
+# ---------------------------------------------------------------------------
+# an expert body of two matrices (``expert_act="relu2"``: relu(x W_u)^2 W_d,
+# both stacks a hidden unit a row) and a shared MLP of a width of its own:
+# the share, the router and the two forms of the routed product stay
+# ---------------------------------------------------------------------------
+
+def _relu2_weights(rng, d, e, h, hs):
+    """Router, selection bias, W_u and W_d (e, h, d), the shared pair
+    (hs, d); scaled so that an output is of order one."""
+    return (0.2 * rng.normal(0, 0.5, (d, e)).astype(np.float32),
+            rng.normal(0, 0.3, (e,)).astype(np.float32),
+            rng.normal(0, 0.1, (e, h, d)).astype(np.float32),
+            rng.normal(0, 0.1, (e, h, d)).astype(np.float32),
+            rng.normal(0, 0.1, (hs, d)).astype(np.float32),
+            rng.normal(0, 0.1, (hs, d)).astype(np.float32))
+
+
+def _np_relu2(x, wr, b, wu, wd, su, sd, k, factor, first=0, held=None):
+    """The layer by a dense einsum: sigmoid scores over all the experts, the
+    k largest of score + bias, their weights renormalised and times
+    ``factor``; the experts [first, first + held) and the shared MLP."""
+    e = wr.shape[1]
+    held = e if held is None else held
+    s = 1.0 / (1.0 + np.exp(-(x @ wr)))
+    chosen = np.argsort(-(s + b), axis=1)[:, :k]
+    w = np.take_along_axis(s, chosen, 1)
+    w = factor * w / (w.sum(1, keepdims=True) + 1e-20)
+    dense = np.zeros((x.shape[0], e), np.float32)
+    np.put_along_axis(dense, chosen, w, 1)
+    dense = dense[:, first:first + held]
+    act = np.maximum(np.einsum("nd,ehd->enh", x, wu[first:first + held]),
+                     0) ** 2
+    y = np.einsum("enh,ehd,ne->nd", act, wd[first:first + held], dense)
+    return y + np.maximum(x @ su.T, 0) ** 2 @ sd
+
+
+def _relu2(x, wr, b, wu, wd, su, sd, k, factor, first=0, held=0):
+    e = wr.shape[1]
+    sl = slice(first, first + (held or e))
+    return nd.MoEFFN(
+        nd.array(x), nd.array(wr), nd.array(b), nd.array(wu[sl]),
+        nd.array(wd[sl]), nd.array(su), nd.array(sd), num_experts=e,
+        hidden_size=wu.shape[1], gated=True, expert_act="relu2",
+        score_func="sigmoid", score_bias=True, num_experts_per_tok=k,
+        routed_scaling_factor=factor, n_shared_experts=1,
+        shared_hidden_size=su.shape[0], num_held=held,
+        first_held=first).asnumpy()
+
+
+@pytest.mark.parametrize("held,first", [(0, 0), (4, 4), (8, 8)])
+def test_relu2_share_is_the_dense_einsum_in_both_forms(held, first,
+                                                       grouped_form,
+                                                       monkeypatch):
+    """Two matrices an expert, no gate, a shared MLP 80 wide beside experts
+    48 wide (1.5 sublane tiles of 32: the grouped product takes the width
+    whole), the weights times 2.5: the grouped form (Pallas interpreted), the
+    dense form and numpy agree at every share, each form under its own
+    label."""
+    from mxnet_tpu import registry
+    from mxnet_tpu.ops import attention as attn
+
+    rng = np.random.RandomState(31)
+    w = _relu2_weights(rng, 128, 16, 48, 80)
+    x = rng.normal(size=(40, 128)).astype(np.float32)
+    want = _np_relu2(x, *w, k=3, factor=2.5, first=first, held=held or None)
+    got = _relu2(x, *w, k=3, factor=2.5, first=first, held=held)
+    assert grouped_form.MOE_PATH["last"] == "held_grouped_relu2"
+    assert np.abs(want).max() > 0.1
+    assert_almost_equal(got, want, rtol=1e-4, atol=1e-5)
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (False, False))
+    registry._jitted.cache_clear()
+    dense = _relu2(x, *w, k=3, factor=2.5, first=first, held=held)
+    assert grouped_form.MOE_PATH["last"] == "held_dense_relu2"
+    assert_almost_equal(dense, want, rtol=1e-4, atol=1e-5)
+    # the square is in it, and so is the factor
+    assert np.abs(_np_relu2(x, *w, k=3, factor=1.0, first=first,
+                            held=held or None) - want).max() > 1e-2
+
+
+def test_relu2_symbol_names_and_shapes():
+    """Two stacks and no gate, both a hidden unit a row; the shared MLP at
+    its own width, or n_shared_experts x hidden_size where none is given;
+    the gated body's names as they were."""
+    s = sym.MoEFFN(sym.Variable("data"), num_experts=16, hidden_size=5,
+                   gated=True, expert_act="relu2", score_bias=True,
+                   num_held=4, first_held=4, n_shared_experts=1,
+                   shared_hidden_size=7, name="moe")
+    assert s.list_arguments() == [
+        "data", "moe_gate_weight", "moe_gate_bias", "moe_expert_up_weight",
+        "moe_expert_down_weight", "moe_shared_up_weight",
+        "moe_shared_down_weight"]
+    arg_shapes, out_shapes, _ = s.infer_shape(data=(2, 3, 8))
+    assert arg_shapes[1:] == [(8, 16), (16,), (4, 5, 8), (4, 5, 8), (7, 8),
+                              (7, 8)]
+    assert out_shapes == [(2, 3, 8)]
+    s = sym.MoEFFN(sym.Variable("data"), num_experts=16, hidden_size=5,
+                   gated=True, n_shared_experts=2, name="moe")
+    arg_shapes, _, _ = s.infer_shape(data=(2, 3, 8))
+    assert s.list_arguments()[2:] == [
+        "moe_expert_gate_weight", "moe_expert_up_weight",
+        "moe_expert_down_weight", "moe_shared_gate_weight",
+        "moe_shared_up_weight", "moe_shared_down_weight"]
+    assert arg_shapes[2:] == [(16, 8, 5), (16, 8, 5), (16, 5, 8), (8, 10),
+                              (8, 10), (10, 8)]
+    with pytest.raises(Exception, match="expert_act"):
+        sym.MoEFFN(sym.Variable("data"), num_experts=4, hidden_size=5,
+                   gated=True, expert_act="gelu",
+                   name="moe").infer_shape(data=(2, 3, 8))
+
+
+@pytest.mark.parametrize("cap,width,tile", [
+    # whole powers of two, and 3 x 2048: the largest common divisor, as ever
+    (4096, 4096, 4096), (4096, 2048, 2048), (4096, 6144, 2048),
+    (512, 2048, 512), (512, 4096, 512), (512, 6144, 512), (4096, 128, 128),
+    (512, 128, 128), (512, 256, 256),
+    # 2688 = 21 x 128: whole under 4096, its divisor 384 under 512
+    (4096, 2688, 2688), (512, 2688, 384),
+    # 1856 = 29 x 64: whole under 4096, 512 with a partial last tile
+    (4096, 1856, 1856), (512, 1856, 512), (512, 1920, 384)])
+def test_a_grouped_products_tile_of_a_width(cap, width, tile):
+    from mxnet_tpu.ops import moe
+
+    assert moe._tile(cap, width) == tile
+
+
+def test_chooser_takes_a_unit_row_width_of_whole_sublane_tiles(monkeypatch):
+    """Nemotron's 2688 x 1856 at 64 held, top 6: a chunk of 2048 rows takes
+    the grouped form where every stack is a hidden unit a row, a decode tick
+    of 64 rows the dense one; as (held, d, h) stacks the lane rule stands as
+    it was, and the model's width is held to whole lane tiles either way."""
+    from mxnet_tpu.ops import attention as attn
+    from mxnet_tpu.ops import moe
+
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
+    assert moe.grouped_selected(2048, 6, 64, 2688, 1856, unit_rows=True) \
+        == (True, False)
+    assert moe.grouped_selected(64, 6, 64, 2688, 1856, unit_rows=True) \
+        == (False, False)
+    assert moe.grouped_selected(2048, 6, 64, 2688, 1856) == (False, False)
+    assert moe.grouped_selected(2048, 6, 64, 2688, 1848, unit_rows=True) \
+        == (False, False)
+    assert moe.grouped_selected(2048, 6, 64, 2688 - 64, 1856,
+                                unit_rows=True) == (False, False)
+    assert moe.grouped_selected(2048, 6, 64, 2688, 1856, mesh_active=True,
+                                unit_rows=True) == (False, False)

@@ -1091,3 +1091,83 @@ def test_gated_deltanet_rows_beside_padded_int8_pages_serve_as_one_pass(
         DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
                         page_tokens=4, prefill_chunk=8,
                         mesh=build_mesh(MeshConfig(model=2)))
+
+
+# ---------------------------------------------------------------------------
+# a graph whose every layer is ONE sublayer (``nemotron_h``'s letters at a toy
+# size): stateless layers (experts, a dense MLP) lie BETWEEN the stateful
+# ones, whose rows (a conv tail and a state) and int8 pages of two KV heads a
+# slot carries
+# ---------------------------------------------------------------------------
+@pytest.fixture(scope="module")
+def letter_graph():
+    from test_decoder_lm import build, letter_config, system_probs
+
+    cfg = letter_config()
+    sym, params = build(cfg)
+    return cfg, sym, params, system_probs
+
+
+@pytest.mark.parametrize("kv_dtype", ["", "int8"])
+def test_stateless_layers_between_stateful_ones_serve_as_one_pass(
+        letter_graph, kv_dtype):
+    """Five requests through two slots (admitted, prefilled in chunks of 8,
+    decoded, retired, each slot handed to the next request with no clearing
+    program): every request's tokens are its own ``generate``'s, and over
+    float pools the arg max of ONE whole forward pass over the sequence that
+    was served; the tick's counters carry the mixers' rows and the held
+    experts' pairs; a state row cannot be swapped out and in, so preemption
+    stays disarmed, and what a state group refuses stays refused by name."""
+    from mxnet_tpu import obs
+    from mxnet_tpu.base import MXNetError
+
+    cfg, sym, params, system_probs = letter_graph
+    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
+    make = lambda: DecodePredictor(
+        sym, nd, cache_len=64, ctx=mx.cpu(), paged=True, page_tokens=4,
+        kv_dtype=kv_dtype, prefill_chunk=8)
+    pred = make()
+    # ME*-EM: two state rows and one node of pages; E and - keep nothing
+    assert [l.kind for l in pred.cache_layouts()] == ["state", "full",
+                                                      "state"]
+    # a row: 2 mixer layers x (3 positions of 96 channels, 4 x 8 x 16)
+    row = 2 * (3 * 96 * 4 + 4 * 8 * 16 * 4)
+    assert pred.state_row_bytes() == pred.state_row_bytes("ssm_rows") == row
+    assert pred.state_nodes("ssm_rows") == 2
+    server = DecodeServer(pred, max_prefill=32, slots=2, spec_k=0)
+    rng = np.random.default_rng(3)
+    prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 26, 9, 30)]
+    noted = lambda: [e["args"] for e in obs.timeline.events()
+                     if e["name"] == "serve.readback" and e.get("args")]
+    seen = len(noted())
+    rids = [server.submit(p, max_new_tokens=10) for p in prompts]
+    results = server.run()
+    alone = make()
+    for rid, p in zip(rids, prompts):
+        want = alone.generate(p[None].astype(np.float32), p.size,
+                              max_new_tokens=10)[0]
+        assert np.array_equal(results[rid], want), rid
+        if kv_dtype:
+            continue    # int8 keys move a near-tie; float pools are exact
+        seq = np.concatenate([p, results[rid][:-1]])[None]
+        probs = np.asarray(system_probs(sym, params, seq))
+        assert np.array_equal(probs[p.size - 1:].argmax(-1), results[rid])
+    notes = [a for a in noted()[seen:] if "ssm_rows" in a]
+    assert notes and {a["ssm_rows"] for a in notes} <= {2, 4}
+    assert sum(a["ssm_rows"] for a in notes) == 2 * len(prompts) * (10 - 1)
+    # two E layers, three of eight experts a token, four held: a tick's held
+    # pairs and the held experts they touched, beside the rows
+    assert all({"moe_rows_held", "moe_rows_elsewhere", "moe_expert_visits"}
+               <= set(a) for a in notes)
+    assert all(a["moe_rows_held"] + a["moe_rows_elsewhere"]
+               == 3 * a["ssm_rows"] for a in notes)
+    assert max(a["moe_expert_visits"] for a in notes) <= 8
+    assert not server._swap_armed
+    with pytest.raises(MXNetError, match="'state' cache group.*rejected "
+                                         "draft has already advanced"):
+        DecodeServer(pred, max_prefill=32, slots=2, spec_k=2)
+    from mxnet_tpu.parallel.mesh import MeshConfig, build_mesh
+    with pytest.raises(MXNetError, match="SelectiveSSM.*one device"):
+        DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
+                        page_tokens=4, prefill_chunk=8,
+                        mesh=build_mesh(MeshConfig(model=2)))
