@@ -34,7 +34,8 @@ import numpy as np
 
 SERVING_CELLS = ("opt_serve_backlog", "falconh1_serve_chat",
                  "mimo_serve_longshort", "exaone_serve_reason",
-                 "sala_serve_longctx")
+                 "sala_serve_longctx", "solar2_serve_agent",
+                 "olmoh_serve_rollouts")
 _FREE = ("data", "softmax_label", "mtp_data", "mtp_label")
 
 
@@ -147,8 +148,8 @@ def case(node, share, seed=0):
     data = [jnp.asarray(rng.randint(-127, 128, (b * m + 1, pt, w), np.int8))
             for w in (node["ek"], node["ev"])]
     pools = [attn.QuantKV(data[0], jnp.asarray(rng.uniform(
-        0.005, 0.02, (b * m + 1, pt * 2 * node["kv_heads"])), jnp.float32)),
-        attn.QuantKV(data[1], None)]
+        0.005, 0.02, (b * m + 1, pt * attn.scale_group(node["kv_heads"]))),
+        jnp.float32)), attn.QuantKV(data[1], None)]
     table = jnp.asarray(rng.permutation(b * m).reshape(b, m) + 1, jnp.int32)
     q = jnp.asarray(rng.normal(size=(b, 1, node["e"])), jnp.bfloat16)
     mean = share * node["cap"]
@@ -232,6 +233,8 @@ def main():
                 attn._kernel_backend = backend
             print(json.dumps({
                 "phase": "decode_kernel", "cell": cell, "node": node["name"],
+                # (a tree from before PR 60 has the one body)
+                "body": getattr(tiles, "body", "whole"),
                 "slots": node["slots"], "view": node["cap"], "block": block,
                 "live_share": round(share, 3), "live_blocks": live,
                 "must_read_mb": round(must / 1e6, 2),

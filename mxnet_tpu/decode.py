@@ -578,10 +578,12 @@ class DecodePredictor:
                              "extract": 0, "install": 0}
         self._probing = False
         # {rows a slot: the attention paths a program's trace took}, the
-        # form each of its gated expert layers' routed product took, in the
-        # walk's order (ops.moe.MOE_PATH), and the form of each delta layer's
+        # Tiles of each node whose decode row took the kernel, the form each
+        # of its gated expert layers' routed product took, in the walk's
+        # order (ops.moe.MOE_PATH), and the form of each delta layer's
         # decode step (ops.kda.STEP_PATH)
         self._decode_paths = {}
+        self._decode_tiles = {}
         self._moe_forms = {}
         self._delta_steps = {}
         if self._paged:
@@ -1169,6 +1171,7 @@ class DecodePredictor:
         # of the program that called: artifact meta says from it whether
         # the program holds the decode row's kernel
         self._decode_paths[t] = paths = set()
+        self._decode_tiles[t] = kernels = []
         self._moe_forms[t] = forms = []
         self._delta_steps[t] = steps = []
         ci = qi = 0
@@ -1371,6 +1374,8 @@ class DecodePredictor:
                                                        num_kv_heads=kv_heads,
                                                        **extra)]
                         paths.add(_attn.DECODE_PATH["last"])
+                        if _attn.DECODE_PATH["last"] == "decode-kernel":
+                            kernels.append(_attn.DECODE_PATH["tiles"])
                         new_caches.append((kc, vc))
                 elif opname == _attn.LATENT_OP:
                     # one plane of rows a position; which of the op's forms
@@ -2926,6 +2931,10 @@ class DecodePredictor:
         kernel the rule chose and the lowering lost is a lint error."""
         paths = self._decode_paths.get(int(rows), ())
         art.meta["attn_paths"] = sorted(paths)
+        # which products the decode row's kernel takes, a node that took
+        # it: "grouped" / "whole" (pallas_decode.Tiles.body)
+        art.meta["decode_bodies"] = [
+            t.body for t in self._decode_tiles.get(int(rows), ())]
         art.meta["moe_forms"] = list(self._moe_forms.get(int(rows), ()))
         art.meta["delta_steps"] = list(self._delta_steps.get(int(rows), ()))
         art.meta["pallas_decode"] = bool(

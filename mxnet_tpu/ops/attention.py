@@ -885,6 +885,18 @@ def _note_path(path, taken=None):
         labels=("path",)).labels(path=path).inc()
 
 
+def _note_body(tiles):
+    """Leave a "decode-kernel" dispatch's ``Tiles`` in :data:`DECODE_PATH`
+    and count its body."""
+    DECODE_PATH["tiles"] = tiles
+    from .. import obs as _obs
+
+    _obs.registry.counter(
+        "mx_attn_decode_body_total",
+        "decode-kernel dispatches traced, by the body the kernel took",
+        labels=("body",)).labels(body=tiles.body).inc()
+
+
 def flash_selected(q_shape, k_shape, causal, num_heads, num_kv_heads,
                    mesh_active, plain=True):
     """``(take, interpret)``: whether ``dot_product_attention`` runs the
@@ -918,8 +930,12 @@ def flash_selected(q_shape, k_shape, causal, num_heads, num_kv_heads,
 # sharded pool, a dense ring).
 # Each such dispatch also counts in mx_attn_dispatch_total{path=...}.
 # mxnet_tpu.decode records it per program, so that an artifact's meta says
-# which path its attention took.
-DECODE_PATH = {"last": None}
+# which path its attention took.  "tiles" is the last "decode-kernel"
+# dispatch's ``pallas_decode.Tiles``: its ``body`` says whether the kernel
+# takes its products a group of KV heads ("grouped", where H > H_kv) or one
+# product over them all ("whole"); mx_attn_decode_body_total{body=...}
+# counts it.
+DECODE_PATH = {"last": None, "tiles": None}
 
 
 def _kernel_backend():
@@ -1350,6 +1366,7 @@ def paged_attend(q, k_pool, v_pool, table, total_len, num_heads=1,
             *shown, mesh_active=mesh_active, window=window)
         if tiles is not None:
             path, kernel = "decode-kernel", (tiles, interpret)
+            _note_body(tiles)
         else:
             tiles, interpret = chunk_kernel_selected(
                 *shown, mesh_active=mesh_active, window=window)

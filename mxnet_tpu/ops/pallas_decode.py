@@ -18,15 +18,40 @@ once too; that is the pools' storage, not this kernel's.)
 The arithmetic is ``_sdpa_cache``'s row form in another order.  A narrow
 plane (int8, fp8, bfloat16) is exact in bfloat16; the float operand (the
 query row, then the probabilities) goes as its three bfloat16 pieces,
-stacked as ROWS of one product: the query rows of all heads are laid
-block-diagonally over kv-heads, ``(3 * H, E_k) x (block, E_k)^T`` gives the
-logits of every head with positions on the lanes, ``(3 * H, block) x (block,
-E_v)`` the values, and the diagonal blocks of that result are folded out in
-fast memory.  Accumulation is float32; the key scales multiply the float32
-logits and the value scales the probabilities.  A float32 pool takes the
-same two products in float32 at ``Precision.HIGHEST``.  In a page's scale row
-a token's floats lie ``(K | V, head)``; a select and a lane fold put them
-``(block, 2 * H_kv)`` and one transposition turns positions onto the lanes.
+stacked as ROWS of one product.  Accumulation is float32; the key scales
+multiply the float32 logits and the value scales the probabilities.  A
+float32 pool takes the same two products in float32 at
+``Precision.HIGHEST``.  In a page's scale row a token's floats lie ``(K | V,
+head)``; a select and a lane fold put them ``(block, 2 * H_kv)`` and one
+transposition turns positions onto the lanes.
+
+Which rows meet which columns follows from ``H / H_kv`` and the head widths
+(``Tiles.group``, the KV heads a product spans; ``Tiles.body``):
+
+* ``H = H_kv`` (``"whole"``): the query rows of all heads are laid
+  block-diagonally over the KV heads, ``(3 * H, E_k) x (block, E_k)^T`` gives
+  the logits of every head with positions on the lanes, ``(3 * H, block) x
+  (block, E_v)`` the values, and the diagonal blocks of that result are
+  folded out in fast memory.  A head's row against its own head's columns is
+  all there is to do, and one product does it.
+* ``H > H_kv`` (``"grouped"``): that layout would take every row against
+  every KV head's columns, ``H_kv`` times the arithmetic and all but one
+  part of it against zeros (64 heads over 8: ``(192, 1024) x (1024, 512)``
+  a block where ``8 x (24, 128) x (128, 512)`` is the work).  A step takes
+  its two products a GROUP of KV heads at a time, with the query rows of
+  that group's heads only: the group is the fewest KV heads whose key
+  columns and whose value columns are whole lane tiles (1 at heads of 128,
+  2 at keys of 192: a product reads its columns out of the block at lane
+  tiles), block-diagonal inside the group where it holds more than one.  A
+  group's rows are its ``group * H / H_kv`` heads a piece, every piece from
+  a float32 sublane tile on (``Tiles.stride``; the pieces' sums are cut out
+  of the float32 product at whole tiles), ``Tiles.prows`` rows a product
+  (64 over 8: 3 x 8 -> 32; 20 over 4: 3 x 8 -> 32; 64 over 4 with keys of
+  192: 3 x 32 = 96).  A group's scales are its own rows of the turned tile,
+  broadcast over its query rows.  The shares leave group by group, a
+  group's heads from a multiple of ``stride`` on, and :func:`attend_blocks`
+  closes the gaps.  Where the groups' rows would not fit the one lane tile
+  of heads the shares leave in, the one product stays.
 
 What a step writes is the block's share of the softmax, ``(max, sum, acc)``
 not yet normalized, as the walk's loop kept it: the combine, the sink and
@@ -81,16 +106,42 @@ class Tiles(NamedTuple):
 
     heads: int       # H
     kv_heads: int    # H_kv
-    rows: int        # H rounded up to the bfloat16 sublane tile
+    rows: int        # rows of a block's shares: H rounded up to the bfloat16
+                     # sublane tile; grouped, ``stride`` rows a group
     pieces: int      # bfloat16 pieces of the float operand (1: float32)
     hd: int          # key head width
     hdv: int         # value head width
-    qw: int          # lanes of a query row as handed in: lcm(hd, 128)
+    qw: int          # lanes of a query row as handed in: lcm(hd, 128);
+                     # grouped, a group's key columns, group * hd
     ow: int          # lanes of the folded PV result: max(hdv, 128)
     pt: int          # positions a page
     ppb: int         # pages a block
     quant: bool
     vmem: int        # bytes of fast memory a step may take
+    group: int       # KV heads a product: H_kv where one product spans all
+
+    @property
+    def body(self):
+        """``"grouped"``: a step takes its two products a group of KV heads,
+        with that group's query rows; ``"whole"``: one product, block-
+        diagonal over every KV head (all there is to do where H = H_kv)."""
+        return "grouped" if self.group < self.kv_heads else "whole"
+
+    @property
+    def gheads(self):
+        """Query heads a product: those of its ``group`` KV heads."""
+        return self.group * self.heads // self.kv_heads
+
+    @property
+    def stride(self):
+        """Grouped: rows a piece of a group's float operand takes
+        (:func:`_group_rows`)."""
+        return _group_rows(self.gheads, self.pieces)[0]
+
+    @property
+    def prows(self):
+        """Grouped: rows a product (:func:`_group_rows`)."""
+        return _group_rows(self.gheads, self.pieces)[1]
 
     def attend(self, q, k_pool, v_pool, pages, slot, valid, live, scale,
                interpret=False):
@@ -137,16 +188,51 @@ def tiles(q_shape, k_pool, v_pool, num_heads, num_kv_heads, block):
     item = jnp.dtype(kd.dtype).itemsize
     rows = -(-h // 16) * 16
     pieces = 1 if item > 2 else 3       # a float32 pool: one piece
+    group = _kv_heads_a_product(h, kvh, hd, hdv)
+    kw, vw, prods = ek, ev, pieces * rows
+    if group < kvh:
+        # a product a group: that group's columns, its heads' rows
+        stride, prows = _group_rows(group * (h // kvh), pieces)
+        rows, qw = kvh // group * stride, group * hd
+        kw, vw, prods = qw, group * hdv, kvh // group * prows
     # two buffers a plane, and the planes once more as the products read
     # them (float32 on the way to bfloat16)
     vmem = block * (ek + ev) * (2 * item + 6) \
         + (block // pt * (2 * 8 + pt) * pt * w * 4 if quant else 0) \
-        + pieces * rows * (ek + ev + 4 * block) * 4 \
-        + q_shape[0] * pieces * rows * qw * 4
+        + prods * (kw + vw + 4 * block) * 4 \
+        + q_shape[0] * prods * qw * 4
     if vmem > _VMEM_BUDGET:
         return None
     return Tiles(h, kvh, rows, pieces, hd, hdv, qw, max(hdv, LANES), pt,
-                 block // pt, quant, vmem)
+                 block // pt, quant, vmem, group)
+
+
+def _kv_heads_a_product(h, kvh, hd, hdv):
+    """KV heads a product of the kernel spans.  Where H = H_kv every one: a
+    head's row against its own head's columns is the block-diagonal product
+    whole.  Where several query heads share a KV head, the fewest whose key
+    columns and whose value columns are whole lane tiles (a product reads its
+    columns out of the block at lane tiles), if the groups' rows, each
+    group's rounded up to a sublane tile, still fit the shares' one lane
+    tile of heads; else every one."""
+    g = h // kvh
+    if g > 1:
+        for n in range(1, kvh):
+            if kvh % n == 0 and n * hd % LANES == 0 and n * hdv % LANES == 0 \
+                    and kvh // n * _group_rows(n * g, 1)[0] <= LANES:
+                return n
+    return kvh
+
+
+def _group_rows(heads, pieces):
+    """``(stride, rows)`` of a group's product over ``heads`` query heads:
+    a piece of the float operand takes ``stride`` rows, the heads rounded up
+    to the float32 sublane tile (the pieces' sums are cut out of the float32
+    product at whole tiles), and the product ``pieces * stride`` rows
+    rounded up to the operand's sublane tile."""
+    stride = -(-heads // 8) * 8
+    tile = 16 if pieces == 3 else 8
+    return stride, -(-pieces * stride // tile) * tile
 
 
 def _split3(x):
@@ -196,7 +282,8 @@ def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
     ``valid_ref`` (rows,) each block's slot and the positions of the block
     that slot has reached, ``live_ref`` (1,) the live rows.  ``q_ref`` (B,
     pieces * rows, qw): a slot's query row a head, by pieces, tiled to
-    whole lane tiles.  The pools and the two outputs stay in HBM; row
+    whole lane tiles; grouped, (B, groups, prows, qw): :func:`_grouped_rows`.
+    The pools and the two outputs stay in HBM; row
     ``r``'s copies fly while row ``r - 1`` is multiplied, and its share
     leaves while row ``r + 1`` is."""
     import jax
@@ -217,7 +304,7 @@ def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
     mm = jnp.bfloat16 if narrow else jnp.float32
     prec = None if narrow else jax.lax.Precision.HIGHEST
     rows3 = t.pieces * t.rows
-    ek, ev = kbuf.shape[-1], vbuf.shape[-1]
+    ek = kbuf.shape[-1]
 
     def pages_of(row, buf, go, unrolled=False):
         """``go`` (start or wait) each copy of row ``row``'s pages into
@@ -255,27 +342,67 @@ def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
                 pltpu.make_async_copy(statbuf.at[buf], stat_hbm.at[row],
                                       osems.at[buf, 1]))
 
-    def plane(ref, buf):
-        x = ref[buf]                            # (ppb, pt, E)
+    def plane(ref, buf, lo=0, width=None):
+        x = ref[buf] if width is None \
+            else ref[buf, :, :, lo:lo + width]  # (ppb, pt, E)
         if x.dtype != mm:
             x = x.astype(jnp.float32)
         return x.reshape(block, x.shape[-1]).astype(mm)
 
-    # which kv-head a lane of the query's lane tile lies in, which a row's
-    # head reads: the same for every row of the list
-    q_head = _div(_rem(jax.lax.broadcasted_iota(
-        jnp.int32, (rows3, t.qw), 0), t.rows), g)
-    q_lane = _div(jax.lax.broadcasted_iota(jnp.int32, (rows3, t.qw), 1),
-                  t.hd)
+    def turned_scales(r, buf):
+        """(ppb, pt * W) -> (block, W) -> (W, block): a page's row to each
+        of its tokens, a token's own stretch kept, the stretches folded onto
+        one lane tile, positions turned onto the lanes: K's heads' scales
+        in rows ``[0, H_kv)``, V's in ``[H_kv, 2 * H_kv)``."""
+        width = sbuf.shape[-1]
+        w = width // t.pt
+        per = jnp.concatenate(
+            [jnp.broadcast_to(
+                sbuf[buf, i, pl.ds(_rem(pages_ref[r * t.ppb + i], 8), 1), :],
+                (t.pt, width)) for i in range(t.ppb)], axis=0)
+        tok = _rem(jax.lax.broadcasted_iota(
+            jnp.int32, (block, width), 0), t.pt)
+        at = _div(jax.lax.broadcasted_iota(
+            jnp.int32, (block, width), 1), w)
+        own = jnp.where(tok == at, per, 0.0)
+        one = own[:, :LANES]
+        for c in range(1, width // LANES):
+            one = one + own[:, c * LANES:(c + 1) * LANES]
+        return _fold_lanes(one, w).T                # (128, block)
 
-    def attend(r, _):
-        buf = _rem(r, 2)
+    def folded(out):
+        """(rows, width) values of a product over KV heads side by side,
+        head ``n`` of the rows' reading kv-head ``n // g``'s columns ->
+        (rows, ow): each row's own head's columns, the rest masked out and
+        the heads folded onto one."""
+        of = _div(jax.lax.broadcasted_iota(jnp.int32, out.shape, 0), g)
+        col = _div(jax.lax.broadcasted_iota(jnp.int32, out.shape, 1), t.hdv)
+        out = jnp.where(of == col, out, 0.0)
+        acc = out[:, :t.ow]
+        for c in range(1, out.shape[1] // t.ow):
+            acc = acc + out[:, c * t.ow:(c + 1) * t.ow]
+        if t.hdv < LANES:
+            acc = _fold_lanes(acc, t.hdv)
+        return acc
 
-        @pl.when(r + 1 < live)
-        def _next():
-            pages_of(r + 1, 1 - buf, start, unrolled=True)
+    def share_stats(m, den):
+        """The maxima and the sums, a head a lane once turned: columns 0
+        and 1 of a lane tile."""
+        lane = jax.lax.broadcasted_iota(jnp.int32, (m.shape[0], LANES), 1)
+        return jnp.where(lane == 0, m, jnp.where(lane == 1, den, 0.0))
 
-        pages_of(r, buf, wait)
+    if t.body == "whole":
+        # which kv-head a lane of the query's lane tile lies in, which a
+        # row's head reads: the same for every row of the list
+        q_head = _div(_rem(jax.lax.broadcasted_iota(
+            jnp.int32, (rows3, t.qw), 0), t.rows), g)
+        q_lane = _div(jax.lax.broadcasted_iota(jnp.int32, (rows3, t.qw), 1),
+                      t.hd)
+
+    def whole(r, buf):
+        """Row ``r``'s shares by ONE product a plane, the query rows
+        block-diagonal over every KV head: ``(acc (rows, ow), stats (rows,
+        128))``."""
         # the query rows, block-diagonal over kv-heads: a lane tile at a
         # time, head n's row kept in the columns of kv-head n // g
         qq = q_ref[slot_ref[r]]
@@ -293,24 +420,7 @@ def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
 
         vs = None
         if t.quant:
-            # (ppb, pt * W) -> (block, W) -> (W, block): a page's row to
-            # each of its tokens, a token's own stretch kept, the stretches
-            # folded onto one lane tile
-            width = sbuf.shape[-1]
-            w = width // t.pt
-            per = jnp.concatenate(
-                [jnp.broadcast_to(
-                    sbuf[buf, i, pl.ds(_rem(pages_ref[r * t.ppb + i], 8), 1), :],
-                    (t.pt, width)) for i in range(t.ppb)], axis=0)
-            tok = _rem(jax.lax.broadcasted_iota(
-                jnp.int32, (block, width), 0), t.pt)
-            at = _div(jax.lax.broadcasted_iota(
-                jnp.int32, (block, width), 1), w)
-            own = jnp.where(tok == at, per, 0.0)
-            one = own[:, :LANES]
-            for c in range(1, width // LANES):
-                one = one + own[:, c * LANES:(c + 1) * LANES]
-            turned = _fold_lanes(one, w).T          # (128, block)
+            turned = turned_scales(r, buf)
             if g == 1 and t.kv_heads == t.rows:
                 ks, vs = turned[:t.rows], turned[t.rows:2 * t.rows]
             else:
@@ -340,18 +450,89 @@ def _kernel(pages_ref, slot_ref, valid_ref, live_ref, q_ref, k_hbm, v_hbm,
         for i in range(1, t.pieces):
             out = out + full[i * t.rows:(i + 1) * t.rows]
         # head n's values are the columns of kv-head n // g
-        of = _div(jax.lax.broadcasted_iota(jnp.int32, (t.rows, ev), 0), g)
-        col = _div(jax.lax.broadcasted_iota(jnp.int32, (t.rows, ev), 1),
-                   t.hdv)
-        out = jnp.where(of == col, out, 0.0)
-        acc = out[:, :t.ow]
-        for c in range(1, ev // t.ow):
-            acc = acc + out[:, c * t.ow:(c + 1) * t.ow]
-        if t.hdv < LANES:
-            acc = _fold_lanes(acc, t.hdv)
-        # the maxima and the sums, a head a lane: rows 0 and 1 of a tile
-        lane = jax.lax.broadcasted_iota(jnp.int32, (t.rows, LANES), 1)
-        stats = jnp.where(lane == 0, m, jnp.where(lane == 1, den, 0.0))
+        return folded(out), share_stats(m, den)
+
+    def grouped(r, buf):
+        """Row ``r``'s shares, the products a group of ``t.group`` KV heads
+        at a time: the group's query rows (``q_ref[slot, j]``: its heads a
+        piece, the pieces ``t.stride`` rows apart, block-diagonal inside the
+        group where it holds more than one KV head) against the group's key
+        columns, the probabilities against its value columns.  The groups'
+        products stand side by side in the program and ONE softmax runs
+        between them over every group's rows: a chain a group (product,
+        reduction, exponential, product) is a group's latency eight times
+        over.  -> ``(acc (rows, ow), stats (rows, 128))``, group ``j``'s
+        heads from row ``j * t.stride`` on."""
+        n, stride = t.group, t.stride
+        groups = range(t.kv_heads // n)
+        spare = t.prows - t.pieces * stride
+        kw, vw = n * t.hd, n * t.hdv
+        qs = q_ref.at[slot_ref[r]]
+        turned = turned_scales(r, buf) if t.quant else None
+
+        def scales(first):
+            """A group's rows of ``turned`` from ``first`` on, each over the
+            query rows of its KV head."""
+            rows = turned[first:first + 1]
+            if n > 1:
+                of = _div(jax.lax.broadcasted_iota(
+                    jnp.int32, (stride, block), 0), g)
+            for i in range(1, n):
+                rows = jnp.where(of == i, turned[first + i:first + i + 1],
+                                 rows)
+            return rows
+
+        def pieces_summed(x):
+            out = x[:stride]
+            for i in range(1, t.pieces):
+                out = out + x[i * stride:(i + 1) * stride]
+            return out
+
+        def of_group(x, j):
+            return x[j * stride:(j + 1) * stride]
+
+        logits = [pieces_summed(jax.lax.dot_general(
+            qs[j], plane(kbuf, buf, j * kw, kw), (((1,), (1,)), ((), ())),
+            precision=prec, preferred_element_type=jnp.float32))
+            for j in groups]                            # (stride, block) each
+        if t.quant:
+            logits = [x * scales(j * n) for j, x in zip(groups, logits)]
+        logits = jnp.concatenate(logits, axis=0) * jnp.float32(scale)
+        pos = jax.lax.broadcasted_iota(jnp.int32, (t.rows, block), 1)
+        logits = jnp.where(pos < valid_ref[r], logits, fill)
+        m = jnp.max(logits, axis=1, keepdims=True)
+        p = jnp.exp(logits - m)
+        den = jnp.sum(p, axis=1, keepdims=True)
+        if t.quant:
+            p = jnp.concatenate(
+                [of_group(p, j) * scales(t.kv_heads + j * n) for j in groups],
+                axis=0)
+        # a group's pieces a sublane tile of float32 apart, as its query's
+        # are: a bfloat16 piece is itself in float32 and back
+        pieces = [x.astype(jnp.float32) for x in _split3(p)] if narrow \
+            else [p]
+        zeros = [jnp.zeros((spare, block), jnp.float32)] * (spare > 0)
+        accs = []
+        for j in groups:
+            full = jax.lax.dot_general(
+                jnp.concatenate([of_group(x, j) for x in pieces] + zeros,
+                                axis=0).astype(mm),
+                plane(vbuf, buf, j * vw, vw), (((1,), (0,)), ((), ())),
+                precision=prec,
+                preferred_element_type=jnp.float32)     # (prows, vw)
+            out = pieces_summed(full)
+            accs.append(folded(out) if n > 1 else out)
+        return jnp.concatenate(accs, axis=0), share_stats(m, den)
+
+    def attend(r, _):
+        buf = _rem(r, 2)
+
+        @pl.when(r + 1 < live)
+        def _next():
+            pages_of(r + 1, 1 - buf, start, unrolled=True)
+
+        pages_of(r, buf, wait)
+        acc, stats = (whole if t.body == "whole" else grouped)(r, buf)
         stats = jnp.concatenate(
             [stats, jnp.zeros((LANES - t.rows, LANES), jnp.float32)], axis=0)
 
@@ -400,12 +581,36 @@ def attend_blocks(q, k_pool, v_pool, pages, slot, valid, live, t, scale,
 
     b = q.shape[0]
     qh = q.astype(jnp.float32).reshape(b, t.heads, t.hd)
-    qh = jnp.pad(qh, ((0, 0), (0, t.rows - t.heads), (0, 0)))
-    if t.pieces == 3:
-        qh = jnp.concatenate(_split3(qh), axis=1)
-    qh = jnp.tile(qh, (1, 1, t.qw // t.hd))        # (B, pieces * rows, qw)
+    if t.body == "grouped":
+        qh = _grouped_rows(qh, t)           # (B, groups, prows, qw)
+    else:
+        qh = jnp.pad(qh, ((0, 0), (0, t.rows - t.heads), (0, 0)))
+        if t.pieces == 3:
+            qh = jnp.concatenate(_split3(qh), axis=1)
+        qh = jnp.tile(qh, (1, 1, t.qw // t.hd))    # (B, pieces * rows, qw)
     return _jitted()(qh, k_pool, v_pool, pages, slot, valid, live, t=t,
                      scale=float(scale), interpret=bool(interpret))
+
+
+def _grouped_rows(qh, t):
+    """(B, H, hd) float32 query rows -> (B, H_kv / group, prows, group *
+    hd): a group's heads a piece, the pieces ``t.stride`` rows apart, a
+    head's row in the columns of its own KV head of the group and zeros in
+    the others'."""
+    import jax.numpy as jnp
+
+    b, n = qh.shape[0], t.group
+    groups, ng = t.kv_heads // n, t.gheads
+    qh = jnp.pad(qh.reshape(b, groups, ng, t.hd),
+                 ((0, 0), (0, 0), (0, t.stride - ng), (0, 0)))
+    if t.pieces == 3:
+        qh = jnp.concatenate(_split3(qh), axis=2)
+    qh = jnp.pad(qh, ((0, 0), (0, 0), (0, t.prows - qh.shape[2]), (0, 0)))
+    if n > 1:
+        of = jnp.arange(t.prows) % t.stride // (ng // n)
+        qh = jnp.where((of[:, None] == jnp.arange(n)[None, :])[:, :, None],
+                       qh[:, :, :, None, :], jnp.zeros((), qh.dtype))
+    return qh.reshape(b, groups, t.prows, t.qw)
 
 
 @functools.lru_cache(maxsize=None)
@@ -466,6 +671,10 @@ def _attend_blocks(qh, k_pool, v_pool, pages, slot, valid, live, *, t, scale,
         )(pages.reshape(-1).astype(jnp.int32), slot.astype(jnp.int32),
           valid.astype(jnp.int32), jnp.reshape(live, (1,)).astype(jnp.int32),
           *args)
+    if t.body == "grouped" and t.gheads < t.stride:
+        # a group's heads lie from a multiple of ``stride`` on
+        at = np.arange(t.rows).reshape(-1, t.stride)[:, :t.gheads].reshape(-1)
+        stats, acc = stats[:, :, at], acc[:, at]
     return (stats[:, 0, :t.heads], stats[:, 1, :t.heads],
             acc[:, :t.heads, :t.hdv])
 

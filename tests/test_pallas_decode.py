@@ -7,16 +7,20 @@ described v5e:
 
 * parity of ``paged_attend``'s kernel path with ``_sdpa_cache`` over the
   whole gathered view, at toy sizes with each admitted node's proportions
-  (H = H_kv with heads of 64; heads of 128 over 4 KV heads; K and V of
-  unequal width with a sink and a value scale) over float32, bfloat16, int8
+  (H = H_kv with heads of 64, 4 and 30 of them: one product over every KV
+  head; heads of 128 over 4 and over 8 KV heads, two, five and eight query
+  heads a KV head: a product a KV head; K and V of unequal width with a sink
+  and a value scale: a product two KV heads) over float32, bfloat16, int8
   and fp8 pools, with slots at length 0, 1, exactly one block, one past it
   and a wrapped ring;
 * dead rows of the padded list reach nothing; the int8 case against
   ``dequantize_kv`` + dense attention;
-* ``decode_kernel_selected`` as a table over the five serving
-  configurations' nodes (read from ``chipbench/configs``), and what it
-  refuses; ``mx_attn_dispatch_total{path}`` after tracing a toy graph with
-  one full and one window node;
+* ``decode_kernel_selected`` as a table over the eight serving
+  configurations' nodes (read from ``chipbench/configs``), which products
+  the kernel takes at each (``Tiles.body``), and what the rule refuses;
+  ``mx_attn_dispatch_total{path}`` and ``mx_attn_decode_body_total{body}``
+  after tracing a toy graph with one full and one window node, four query
+  heads over four KV heads and eight;
 * the paged server token-identical with the kernel and with the walk;
   ``program_cost`` prices the walk's gather and not the kernel's; the
   flop-dtype pass's ``pallas-fallback`` artifact tripwire;
@@ -56,6 +60,10 @@ NODES = {
     "unequal_sink_scale": (8, 4, 192, 128, True, 0.707),    # mimo-v2.5
     # 30 KV heads: a token's 60 scales padded to 64 (attn.scale_group)
     "mha_30_heads": (30, 30, 64, 64, False, 1.0),           # olmo-hybrid-7b
+    # eight query heads a KV head: a product a KV head, 24 rows of 32
+    "heads_of_128_over_8": (64, 8, 128, 128, False, 1.0),   # solar-open2-250b
+    # five: a group's piece is 5 rows of a sublane tile's 8
+    "five_heads_a_kv_head": (20, 4, 128, 128, False, 1.0),  # falcon-h1-34b
 }
 # empty, one position, exactly a block, one past it, a wrapped ring
 LENS = (0, 1, 256, 257, M * PT + 9)
@@ -222,6 +230,19 @@ RULE = {
     # 30 KV heads over a padded scale row; chunks of 512 rows: under the
     # chunk kernel's constant
     "olmoh_serve_rollouts": [("decode-kernel", "walk")] * 2,
+    # 2 KV heads: a page's scale row is 64 lanes at pages of 16 positions,
+    # half a lane tile (``tiles`` refuses it; 16 query heads a KV head would
+    # tile); chunks of 2048 rows over heads of 128
+    "nemotron3_serve_agent": [("walk", "chunk-kernel")] * 2,
+}
+# the products the decode row's kernel takes at each cell's first node that
+# takes it: (body, KV heads a product, rows a product)
+BODY = {
+    "opt_serve_backlog": ("whole", 32, 96),             # H = H_kv
+    "olmoh_serve_rollouts": ("whole", 30, 96),
+    "falconh1_serve_chat": ("grouped", 1, 32),          # 5 heads a KV head
+    "mimo_serve_longshort": ("grouped", 2, 96),         # keys of 192: 384 lanes
+    "solar2_serve_agent": ("grouped", 1, 32),           # 8 heads a KV head
 }
 
 
@@ -240,6 +261,29 @@ def test_rule_over_the_serving_configurations(cell, interpret):
         assert "decode-kernel" not in {probe.decode_path(n) for n in nodes}
         assert "chunk-kernel" not in {
             probe.decode_path(n, tq=n["chunk"]) for n in nodes}
+
+
+@pytest.mark.parametrize("cell", sorted(BODY))
+def test_body_of_the_kernel_at_the_serving_configurations(cell, interpret):
+    """Which products ``tiles`` gives the decode row's kernel at a cell's
+    shapes: a group of KV heads at a time where several query heads share
+    one, the fewest whose columns are whole lane tiles; one product over
+    them all where H = H_kv."""
+    node = next(n for n in probe.serving_nodes(cell)
+                if probe.decode_path(n) == "decode-kernel")
+    b, m = node["slots"], node["cap"] // node["pt"]
+    t, _ = attn.decode_kernel_selected(
+        (b, 1, node["e"]), *probe.abstract_pools(node), (b, m),
+        node["heads"], node["kv_heads"])
+    rows = t.prows if t.body == "grouped" else t.pieces * t.rows
+    assert (t.body, t.group, rows) == BODY[cell]
+    g = node["heads"] // node["kv_heads"]
+    assert (t.body == "grouped") == (g > 1)
+    # a group's columns are whole lane tiles, its shares fit one tile of heads
+    assert t.group * t.hd % 128 == 0 and t.group * t.hdv % 128 == 0
+    if t.body == "grouped":
+        assert t.rows == node["kv_heads"] // t.group * t.stride <= 128
+        assert t.stride >= t.group * g and t.qw == t.group * t.hd
 
 
 def _selected(q_shape=(4, 1, 256), ek=256, ev=256, kvh=4, heads=4, pages=M,
@@ -310,11 +354,12 @@ def test_rule_needs_a_backend_that_runs_pallas():
 VOCAB, SLOTS, CACHE = 64, 2, 512
 
 
-def _toy_lm(seed=5):
+def _toy_lm(seed=5, heads=4):
     from mxnet_tpu.models import decoder_lm
 
     sym = decoder_lm.get_symbol(
-        vocab_size=VOCAB, hidden_size=64, num_layers=2, num_attention_heads=4,
+        vocab_size=VOCAB, hidden_size=64, num_layers=2,
+        num_attention_heads=heads,
         head_dim=64, num_key_value_heads=4, swa_num_key_value_heads=4,
         hybrid_layer_pattern=(0, 1), sliding_window=8, intermediate_size=64)
     rng = np.random.RandomState(seed)
@@ -326,10 +371,10 @@ def _toy_lm(seed=5):
     return sym, params
 
 
-def _predictor(kv_dtype="int8"):
+def _predictor(kv_dtype="int8", heads=4):
     from mxnet_tpu.decode import DecodePredictor
 
-    sym, params = _toy_lm()
+    sym, params = _toy_lm(heads=heads)
     return DecodePredictor(sym, params, cache_len=CACHE, temperature=0.0,
                            paged=True, page_tokens=PT, prefill_chunk=64,
                            kv_dtype=kv_dtype)
@@ -340,6 +385,13 @@ def _dispatched():
                                    labels=("path",))
     return {path: counter.labels(path=path).get()
             for path in ("decode-kernel", "walk", "whole")}
+
+
+def _bodies():
+    counter = obs.registry.counter("mx_attn_decode_body_total",
+                                   labels=("body",))
+    return {body: counter.labels(body=body).get()
+            for body in ("grouped", "whole")}
 
 
 def _serve(pred):
@@ -354,22 +406,32 @@ def _serve(pred):
     return [np.asarray(results[i]) for i in ids], pred
 
 
-def test_dispatch_counter_and_token_identity(interpret):
+@pytest.mark.parametrize("heads,body", [(4, "whole"), (8, "grouped")])
+def test_dispatch_counter_and_token_identity(heads, body, interpret):
     """Tracing the toy's programs counts, in
     ``mx_attn_dispatch_total{path}``, the full node's decode row as
     ``decode-kernel``, its chunk as ``walk`` and the window node's ring as
-    ``whole`` in both; and the server emits exactly the walk's tokens."""
-    before = _dispatched()
-    on, pred = _serve(_predictor())
+    ``whole`` in both, and in ``mx_attn_decode_body_total{body}`` which
+    products that kernel takes (four heads over four KV heads: one over them
+    all; eight: two KV heads of 64 a product); and the server emits exactly
+    the walk's tokens."""
+    before, bodies = _dispatched(), _bodies()
+    on, pred = _serve(_predictor(heads=heads))
     after = _dispatched()
     took = {p: after[p] - before[p] for p in after}
     # one decode program and one chunk program, two nodes each
     assert took == {"decode-kernel": 1, "walk": 1, "whole": 2}, took
+    assert {b: n - bodies[b] for b, n in _bodies().items() if n > bodies[b]} \
+        == {body: 1}
     assert pred._decode_paths[1] == {"decode-kernel", "whole"}
     assert pred._decode_paths[64] == {"walk", "whole"}
+    assert [(t.body, t.group) for t in pred._decode_tiles[1]] \
+        == [(body, 4 if body == "whole" else 2)]
+    assert pred._decode_tiles[64] == []
     with config.overrides(MXNET_PALLAS_INTERPRET="0"):
-        off, pred = _serve(_predictor())
+        off, pred = _serve(_predictor(heads=heads))
     assert pred._decode_paths[1] == {"walk", "whole"}
+    assert pred._decode_tiles[1] == []
     for i, (a, b) in enumerate(zip(on, off)):
         assert np.array_equal(a, b), \
             "request %d diverged: kernel %s vs walk %s" % (i, a, b)
@@ -388,6 +450,7 @@ def test_artifact_meta_and_flop_pass_tripwire(interpret):
     pred = _predictor()
     art = pred.decode_artifact(pred.paged_batch_state(SLOTS))
     assert art.meta["attn_paths"] == ["decode-kernel", "whole"]
+    assert art.meta["decode_bodies"] == ["whole"]
     assert art.meta["pallas_decode"] is True
     assert "pallas_call" in art.jaxpr_text
     rep = run_passes([art], passes=[FlopDtypePass()])
@@ -405,6 +468,7 @@ def test_artifact_meta_and_flop_pass_tripwire(interpret):
         pred = _predictor()
         art = pred.decode_artifact(pred.paged_batch_state(SLOTS))
     assert art.meta["attn_paths"] == ["walk", "whole"]
+    assert art.meta["decode_bodies"] == []
     assert art.meta["pallas_decode"] is False
     assert "pallas_call" not in art.jaxpr_text
     rep = run_passes([art], passes=[FlopDtypePass()])
@@ -486,7 +550,8 @@ def _compiled_for_the_chip(fn, args, **jit):
 
 @pytest.mark.parametrize("cell", ["opt_serve_backlog", "falconh1_serve_chat",
                                   "mimo_serve_longshort",
-                                  "olmoh_serve_rollouts"])
+                                  "olmoh_serve_rollouts",
+                                  "solar2_serve_agent"])
 def test_kernel_compiles_for_the_chip_at_the_cells_shapes(cell, one_chip,
                                                           monkeypatch):
     """The decode row of each cell's first full node through
