@@ -45,7 +45,7 @@ attention-LM generating tokens through ``mxnet_tpu.decode`` —
   (``analysis.cost.program_cost``'s gather_bytes term).  Published as
   ``decode_attn_bytes_per_token`` (+ per-path variants and the ratio) and
   ``pallas_decode_enabled``; non-smoke asserts the kernel's path prices
-  <= 0.5x the walk's bytes at T=2048 — the mfu_table traffic win.  (At
+  <= 0.5x the walk's bytes at T=2048 — the priced traffic win.  (At
   the --smoke dims the rule keeps the whole view: both prices are one.)
 
 * **gqa** — grouped-query attention (``num_kv_heads = heads/G``,
@@ -75,7 +75,7 @@ from the same static analyzer the mxlint cache-bytes pass uses
 ``tokens_per_sec_per_gb`` — quantization's win shows up in the JSON
 contract even where compute, not bandwidth, bounds the harness.
 
-Mirrors bench.py's contract: ONE json line on stdout —
+The benches' contract: ONE json line on stdout —
 ``{"metric": "decode_tokens_per_sec_t<T>", "value", "unit",
 "vs_baseline", ...}`` — where ``vs_baseline`` is the decode rate over the
 naive recompute rate on the same chip (the acceptance headline: >= 5x at

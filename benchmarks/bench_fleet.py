@@ -42,10 +42,11 @@ Deterministic halves (asserted at EVERY dims, smoke included):
 * the preemption drill really swapped (``swap_outs >= 1``, both
   configs).
 
-Headline (bench.py contract, one JSON line on stdout):
+Headline (the benches' contract, one JSON line on stdout):
 ``fleet_tokens_per_sec_h<N>`` with ``vs_round_robin`` (= vs_baseline),
 ``p95_ttft_ms``, ``router_cache_hit_rate``, migrated/swapped page
-counts and the per-program ``mfu_table``.  Non-smoke asserts
+counts and the ``programs`` whose dispatches left a span.  Non-smoke
+asserts
 ``vs_round_robin >= 1.5`` — the wall-clock win of not prefilling every
 tenant's prefix on every host.  Wall-clock ratios at smoke dims are
 REPORTED only (shared-machine noise); the deterministic halves above
@@ -376,7 +377,8 @@ def main():
         "tenant_affinity": bool(affinity),
         "token_identical": True,
         "zero_retraces": True,
-        "mfu_table": obs.mfu_table(),
+        "programs": sorted({e["name"] for e in obs.timeline.events()
+                            if e["cat"] == "program"}),
     }))
 
 
@@ -396,7 +398,7 @@ def cold_start_main():
     import shutil
     import tempfile
 
-    from mxnet_tpu import config as _config, obs
+    from mxnet_tpu import config as _config
     from mxnet_tpu.decode import DecodeServer
     from mxnet_tpu.programs import aot as _aot
 
@@ -519,7 +521,6 @@ def cold_start_main():
         "token_identical": bool(token_identical),
         "zero_retraces": bool(zero_retraces),
         "hosts": n_hosts,
-        "mfu_table": obs.mfu_table(),
     }))
 
 

@@ -1,8 +1,7 @@
 #!/usr/bin/env python
 """Benchmark: long-context attention-LM training throughput across meshes.
 
-The long-context headline the ResNet bench (`bench.py`) never covered:
-a causal attention LM at T=8192, full training step (forward + backward +
+A causal attention LM at T=8192, full training step (forward + backward +
 fused optimizer update, ONE donated XLA program), measured on the three
 canonical mesh shapes of the ring×TP composition story:
 
@@ -25,13 +24,13 @@ collective traffic from compiled HLO (``parallel.hlo_stats``): total
 bytes plus the async-pair "overlappable" bytes (nonzero on backends
 that split collectives into start/done, i.e. TPU).
 
-Mirrors bench.py's contract: ONE json line on stdout —
+The benches' contract: ONE json line on stdout —
 ``{"metric": "attention_lm_tokens_per_sec_t<T>", "value", "unit",
-"mfu", "vs_baseline", "vs_serial"}`` — where the value is the ring×TP
+"vs_baseline", "vs_serial"}`` — where the value is the ring×TP
 mesh rate under the overlapped schedule, ``vs_baseline`` is its speedup
 over the TP-only GSPMD einsum plan on the same chips, and ``vs_serial``
 its speedup over its own serial schedule.  Per-(mesh, schedule) detail
-(tokens/s, sustained TFLOP/s, MFU, traced attention path, collective
+(tokens/s, sustained TFLOP/s, traced attention path, collective
 bytes) goes to stderr, one json per run.
 
 Env knobs: BENCH_T, BENCH_BATCH, BENCH_EMBED, BENCH_HEADS, BENCH_VOCAB,
@@ -66,8 +65,6 @@ if os.environ.get("JAX_PLATFORMS", "") == "cpu" and \
                                ).strip()
 
 import numpy as np
-
-import bench as _bench  # PEAK_FLOPS table + device-kind matching
 
 
 def _flops_per_token(t, e, vocab, causal=True):
@@ -163,7 +160,7 @@ def main():
     ctx_fn = mx.tpu if on_tpu else mx.cpu
     contexts = [ctx_fn(i) for i in range(n_dev)]
     train_flops_per_token = 3 * _flops_per_token(t, e, vocab)
-    peak, kind = _bench._peak_for(jax.devices()[0])
+    kind = jax.devices()[0].device_kind
 
     def measure(cfg):
         mod = mx.mod.Module(build_lm(), context=contexts, mesh_config=cfg,
@@ -202,10 +199,8 @@ def main():
 
         tok_s = b * t * n_iters / dt
         tflops = tok_s * train_flops_per_token / 1e12
-        mfu = tflops * 1e12 / (peak * n_dev) if peak else None
         row = {"tokens_per_sec": round(tok_s, 1),
                "sustained_tflops": round(tflops, 2),
-               "mfu": round(mfu, 4) if mfu is not None else None,
                "attention_path": PATH_TAKEN["last"]}
         if want_hlo:
             # collective accounting of the program that actually trained
@@ -281,7 +276,6 @@ def main():
         "metric": "attention_lm_tokens_per_sec_t%d" % t,
         "value": headline["tokens_per_sec"],
         "unit": "tok/s",
-        "mfu": headline["mfu"],
         "vs_baseline": (round(headline["tokens_per_sec"]
                               / base["tokens_per_sec"], 3)
                         if base else None),

@@ -22,15 +22,14 @@ program shape while at it:
   the ratio (this harness's wall clock is shared-machine noise; the
   deterministic halves above are what tier-1 asserts).
 
-Mirrors bench.py's contract: ONE json line on stdout —
+The benches' contract: ONE json line on stdout —
 ``{"metric": "moe_lm_tokens_per_sec_e<E>", "value", "unit",
 "vs_baseline", ...}`` — where ``vs_baseline`` (also spelled out as
 ``vs_dense_dispatch``) is the capacity path's speedup over the dense
-oracle on the same chips, plus the all-to-all count/byte accounting and
-the per-program ``mfu_table`` roofline rows (the expert-parallel step's
-row carries ``collective_bytes`` — the analysis/cost.py traffic
-accounting pricing the exchanges).  Per-config detail goes to stderr,
-one json per run.
+oracle on the same chips, plus the all-to-all count/byte accounting.
+Per-config detail goes to stderr, one json per run; its ``cost`` is the
+step's static price (``analysis.cost.program_cost``: the
+expert-parallel step's carries ``collective_bytes``, its exchanges).
 
 Env knobs: BENCH_T, BENCH_BATCH, BENCH_EMBED, BENCH_FFN, BENCH_HEADS,
 BENCH_VOCAB, BENCH_EXPERTS, BENCH_CF (capacity factor), BENCH_TOPK,
@@ -61,7 +60,7 @@ if os.environ.get("JAX_PLATFORMS", "") == "cpu" and \
 
 import numpy as np
 
-import bench as _bench
+from tools.mxlint import contract_line
 
 
 def main():
@@ -69,12 +68,13 @@ def main():
 
     import mxnet_tpu as mx
     from mxnet_tpu import ndarray as nd
-    from mxnet_tpu import obs
+    from mxnet_tpu.analysis.cost import program_cost
     from mxnet_tpu.io import DataBatch, DataDesc
     from mxnet_tpu.models import attention_lm
     from mxnet_tpu.ops.moe import MOE_PATH
     from mxnet_tpu.parallel import MeshConfig
     from mxnet_tpu.parallel.hlo_stats import collective_stats
+    from mxnet_tpu.programs.spec import probing
 
     platform = jax.devices()[0].platform
     n_dev = len(jax.devices())
@@ -109,9 +109,9 @@ def main():
 
     ctx_fn = mx.tpu if on_tpu else mx.cpu
     contexts = [ctx_fn(i) for i in range(n_dev)]
-    peak, kind = _bench._peak_for(jax.devices()[0])
+    kind = jax.devices()[0].device_kind
 
-    def measure(capacity_factor, telemetry_name):
+    def measure(capacity_factor):
         net = attention_lm.get_symbol(
             vocab_size=vocab, seq_len=t, num_layers=1, embed=e,
             heads=heads, ffn_hidden=ffn, moe_experts=experts,
@@ -143,12 +143,6 @@ def main():
             mod.forward_backward(batch)
             mod.update()
         sync()
-        if mod._fused_step is not None:
-            # the roofline row the MFU table publishes for this config
-            # (the per-program join in obs.mfu_table; re-register so the
-            # static prober lands under the bench's name)
-            mod._fused_step.telemetry_name = telemetry_name
-            mod._fused_step._static_registered = False
         tic = time.time()
         for _ in range(n_iters):
             mod.forward_backward(batch)
@@ -166,21 +160,25 @@ def main():
                 row["all_to_all_count"] = a2a["count"]
                 row["all_to_all_bytes"] = a2a["bytes"]
                 row["collective_bytes"] = st["total"]["bytes"]
-        # the module rides home so the weakly-bound static prober is
-        # still resolvable when the MFU table joins below
-        return row, mod
+            # the step's static price: the dense oracle's shows the E x
+            # FLOP bill the capacity path avoids
+            step, group = mod._fused_step, mod._exec_group
+            with probing(step):
+                cost = program_cost(step._entry_for(group),
+                                    step._abstract_args(group))
+            row["cost"] = {k: cost.get(k, 0) for k in
+                           ("flops", "bytes", "collective_bytes")}
+        return row
 
-    sparse, sparse_mod = measure(cf, "moe_train_step")
-    dense, dense_mod = measure(0.0, "moe_dense_train_step")
+    sparse = measure(cf)
+    dense = measure(0.0)
 
     # ---- dispatch algorithm accounting (MXNET_MOE_DISPATCH) ----------
     # price the capacity-slot assignment under BOTH algorithms at this
     # config's per-group token count: the sort path's argsort/scatter
-    # intermediates vs the one-hot cumsum pack, through the same
-    # program_cost machinery the mfu_table rows use (sort_scatter_bytes
-    # is the column the two modes differ in)
+    # intermediates vs the one-hot cumsum pack (sort_scatter_bytes is
+    # the column the two modes differ in)
     from mxnet_tpu import config as _config
-    from mxnet_tpu.analysis.cost import program_cost
     from mxnet_tpu.ops import moe as _moe
 
     def _price_dispatch(algo):
@@ -264,13 +262,7 @@ def main():
         assert dense["moe_path"] == "dense", dense
 
     ratio = sparse["tokens_per_sec"] / dense["tokens_per_sec"]
-    # only the bench's own renamed rows: the pre-rename warmup step also
-    # accrued a generic 'train_step' row (compile wall included), which
-    # would misread as a steady-state measurement
-    mfu_rows = [r for r in obs.mfu_table()
-                if r["program"].startswith("moe_")]
-    print(obs.render_mfu_table(mfu_rows), file=sys.stderr)
-    print(_bench.contract_line(
+    print(contract_line(
         "moe_lm_tokens_per_sec_e%d" % experts,
         sparse["tokens_per_sec"], "tok/s", round(ratio, 3),
         vs_dense_dispatch=round(ratio, 3),
@@ -284,8 +276,7 @@ def main():
                                "sort_scatter_bytes":
                                c["sort_scatter_bytes"]}
                         for algo, c in dispatch_cost.items()},
-        dispatch_identical=True,
-        mfu_table=mfu_rows))
+        dispatch_identical=True))
 
     if not SMOKE and ep > 1 and ratio < 2.0:
         # the acceptance line: at full dims the capacity path's E/(cf*k)

@@ -16,8 +16,8 @@ to every pool at allocation (``ops.attention.apply_kv_layout``).
 Backends that refuse a layout request (XLA:CPU) report it and keep the
 native row-major; the knob is then best left empty.
 
-Output contract: ONE bench.contract_line json per probed layout on
-stdout (winner flagged with ``"winner": true``); the human-readable
+Output contract: ONE ``tools.mxlint.contract_line`` json per probed
+layout on stdout (winner flagged with ``"winner": true``); the human-readable
 table goes to stderr.
 """
 import functools
@@ -32,7 +32,7 @@ from jax import lax
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-import bench as _bench
+from tools.mxlint import contract_line
 
 # (in_ch, out_ch, spatial, stride, n_blocks) rough resnet50 stage shapes
 STAGES = [
@@ -199,7 +199,7 @@ def bench_kv(iters=30):
         base_dt = results[0][0]
         best_dt, best, _ = min(results)
         for dt, name, gbps in results:
-            print(_bench.contract_line(
+            print(contract_line(
                 "kv_layout_%s_ms" % name.replace(",", ""),
                 round(dt * 1e3, 4), "ms", round(base_dt / dt, 3),
                 layout=name, pool_stream_gbps=round(gbps, 1),
@@ -222,7 +222,7 @@ if __name__ == "__main__":
         base_dt = timings[0][0]
         best = min(timings)[1]
         for dt, mode in timings:
-            print(_bench.contract_line(
+            print(contract_line(
                 "conv_layout_%s_ms" % mode, round(dt * 1e3, 2), "ms",
                 round(base_dt / dt, 3), layout=mode,
                 images_per_sec=round(BATCH / dt, 1),

@@ -5,5 +5,5 @@
 # has to fail soon and cleanly.
 #   chiprun --timeout 3400 -- sh benchmarks/runs/pr53_first.sh
 sh benchmarks/runs/pr53_probe.sh 5300000101 1 _first
-sh benchmarks/runs/pr53_cell.sh runs:here:solar2_serve_agent:1:5300000111
-sh benchmarks/runs/pr53_cell.sh runs:parent:solar2_serve_agent:0:5300000111
+sh benchmarks/runs/cell.sh pr53 runs:here:solar2_serve_agent:1:5300000111
+sh benchmarks/runs/cell.sh pr53 runs:parent:solar2_serve_agent:0:5300000111

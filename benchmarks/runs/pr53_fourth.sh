@@ -5,7 +5,7 @@
 # nothing there and say nothing), then the cell on the change: once traced,
 # three times plain.
 #   chiprun --timeout 3400 -- sh benchmarks/runs/pr53_fourth.sh
-sh benchmarks/runs/pr53_cell.sh runs:parent:solar2_serve_agent:0:5300000300 \
+sh benchmarks/runs/cell.sh pr53 runs:parent:solar2_serve_agent:0:5300000300 \
   runs:parent:falconh1_serve_chat:1:5300000305 \
   runs:change:solar2_serve_agent:1:5300000301 \
   runs:change:solar2_serve_agent:0:5300000302,5300000303,5300000304

@@ -4,6 +4,6 @@
 # traced and twice plain, then pr53_fifth.sh's two accepted cells, parent,
 # change, change, parent.
 #   chiprun --timeout 3550 -- sh benchmarks/runs/pr53_sixth.sh
-sh benchmarks/runs/pr53_cell.sh runs:change:solar2_serve_agent:1:5300000501 \
+sh benchmarks/runs/cell.sh pr53 runs:change:solar2_serve_agent:1:5300000501 \
   runs:change:solar2_serve_agent:0:5300000502,5300000503
 sh benchmarks/runs/pr53_fifth.sh

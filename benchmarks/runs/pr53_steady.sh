@@ -2,7 +2,7 @@
 # PR 53, benchmark round: is opt_serve_backlog steady on the change?  The
 # cell's window runs no line this PR changed but one `"kda_rows" in note` a
 # tick (DecodeServer._note_counts); its programs lower to the parent's text
-# (pr53_hashes.py).  As pr52_steady.sh: parent (scratch/parent, git archive
+# (`hashes.py` since PR 61).  As pr52_steady.sh: parent (scratch/parent, git archive
 # HEAD) and change (scratch/change, git archive $(git write-tree)) on the
 # same seeds, the order turned round each seed, one call, then each side's
 # quartile spread of every end-to-end metric (statistics.quantiles, n=4,

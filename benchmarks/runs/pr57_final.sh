@@ -8,8 +8,8 @@
 # shares ops.kda._step, ops.ssm._conv and the int8 pools with the new cell,
 # parent change change parent.
 #   chiprun --timeout 3500 -- sh benchmarks/runs/pr57_final.sh
-sh benchmarks/runs/pr57_cell.sh runs:change:olmoh_serve_rollouts:1:5700000301 \
+sh benchmarks/runs/cell.sh pr57 runs:change:olmoh_serve_rollouts:1:5700000301 \
   runs:change:olmoh_serve_rollouts:0:5700000211,5700000212,5700000213,5700000214,5700000215,5700000216
 python3 benchmarks/runs/pr57_spread.py chiprun_out/pr57_change_olmoh_serve_rollouts_570000021?_0.out
-LAST=2500 sh benchmarks/runs/pr57_cell.sh runs:parent_bench:opt_serve_backlog:1:5700000401
-sh benchmarks/runs/pr57_cell.sh pccp:solar2_serve_agent:5700000501:5700000502
+LAST=2500 sh benchmarks/runs/cell.sh pr57 runs:parent_bench:opt_serve_backlog:1:5700000401
+sh benchmarks/runs/cell.sh pr57 pccp:solar2_serve_agent:5700000501:5700000502

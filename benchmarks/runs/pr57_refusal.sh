@@ -5,7 +5,7 @@
 # ticks of 100-150 ms (the machine's stops, pr57_stops.py) beside its rate;
 # then what brings a stop, by phase.
 #   chiprun --timeout 1700 -- sh benchmarks/runs/pr57_refusal.sh
-sh benchmarks/runs/pr57_cell.sh \
+sh benchmarks/runs/cell.sh pr57 \
   runs:here:olmoh_serve_rollouts:0:5700000611,5700000612,5700000613,5700000614,5700000615,5700000616
 python3 benchmarks/runs/pr57_spread.py chiprun_out/pr57_here_olmoh_serve_rollouts_570000061?_0.out
 python3 - <<'P'

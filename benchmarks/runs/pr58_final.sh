@@ -3,7 +3,7 @@
 # two other cells whose graphs carry a recurrent state through the walk this
 # PR touched (decode.py's state branch), parent beside change.
 #   chiprun --timeout 3550 -- sh benchmarks/runs/pr58_final.sh
-sh benchmarks/runs/pr58_cell.sh \
+sh benchmarks/runs/cell.sh pr58 \
   pccp:olmoh_serve_rollouts:5800000205:5800000206 \
   pccp:solar2_serve_agent:5800000215:5800000216 \
   pccp:falconh1_serve_chat:5800000231:5800000232 \

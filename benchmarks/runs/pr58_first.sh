@@ -4,4 +4,4 @@
 # from the tree the script is started from.
 #   chiprun --timeout 1500 -- sh benchmarks/runs/pr58_first.sh
 sh benchmarks/runs/pr58_probe.sh
-sh benchmarks/runs/pr58_cell.sh runs:here:olmoh_serve_rollouts:1:5800000101 runs:here:solar2_serve_agent:1:5800000102
+sh benchmarks/runs/cell.sh pr58 runs:here:olmoh_serve_rollouts:1:5800000101 runs:here:solar2_serve_agent:1:5800000102

@@ -4,7 +4,7 @@
 # (scratch/parent = git archive HEAD, scratch/change = git archive $(git
 # write-tree)).
 #   chiprun --timeout 3550 -- sh benchmarks/runs/pr58_pairs.sh
-sh benchmarks/runs/pr58_cell.sh \
+sh benchmarks/runs/cell.sh pr58 \
   pccp:olmoh_serve_rollouts:5800000201:5800000202 \
   pccp:solar2_serve_agent:5800000211:5800000212 \
   pccp:olmoh_serve_rollouts:5800000203:5800000204 \

@@ -8,6 +8,6 @@
 mkdir -p chiprun_out
 python3 benchmarks/probe_moe_width.py 2> chiprun_out/pr59_width.err | tee chiprun_out/pr59_width.out
 grep -v "^WARNING\|^$" chiprun_out/pr59_width.err | tail -3 | cut -c1-300
-sh benchmarks/runs/pr59_cell.sh runs:here:nemotron3_serve_agent:1:5900000111
+sh benchmarks/runs/cell.sh pr59 runs:here:nemotron3_serve_agent:1:5900000111
 sh benchmarks/runs/pr59_probe.sh 5900000101 1 _first
-sh benchmarks/runs/pr59_cell.sh runs:parent_bench:nemotron3_serve_agent:0:5900000111
+sh benchmarks/runs/cell.sh pr59 runs:parent_bench:nemotron3_serve_agent:0:5900000111

@@ -8,4 +8,4 @@
 #   chiprun --timeout 3400 -- sh benchmarks/runs/pr59_sound.sh
 sh benchmarks/runs/pr59_probe.sh 5900000102,5900000103,5900000104,5900000105,5900000106,5900000107,5900000108,5900000109,5900000110,5900000112 0 _sound
 sh benchmarks/runs/pr59_probe.sh 5900000113,5900000114 2 _control fp8_weights
-sh benchmarks/runs/pr59_cell.sh runs:parent_bench:nemotron3_serve_agent:0:5900000111
+sh benchmarks/runs/cell.sh pr59 runs:parent_bench:nemotron3_serve_agent:0:5900000111

@@ -4,7 +4,7 @@
 # twice over (scratch/parent = git archive HEAD, scratch/change = git
 # archive $(git write-tree)).
 #   chiprun --timeout 3550 -- sh benchmarks/runs/pr60_pairs.sh
-sh benchmarks/runs/pr60_cell.sh \
+sh benchmarks/runs/cell.sh pr60 \
   runs:parent:solar2_serve_agent:1:6000000222 \
   runs:change:solar2_serve_agent:1:6000000222 \
   pccp:solar2_serve_agent:6000000211:6000000212 \

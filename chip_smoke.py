@@ -510,9 +510,6 @@ def main():
     print(start, flush=True)
 
     import mxnet_tpu as mx
-    from mxnet_tpu.obs.roofline import require_peak_flops
-
-    require_peak_flops(jax.devices()[0])
 
     # seconds jax spent in backend compiles (cache hits included, as the
     # time to fetch them) and how often the persistent cache answered

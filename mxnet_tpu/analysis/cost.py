@@ -1,8 +1,7 @@
 """Static roofline cost of one compiled program — FLOPs + traffic bytes.
 
-The join key of the telemetry subsystem's per-program MFU table
-(``mxnet_tpu.obs.roofline``): the dispatch wrappers measure wall time,
-this module prices the program —
+What the analyzer's passes, ``benchmarks/bench_moe.py`` and the tests
+price a program with —
 
 * **FLOPs** from :func:`~mxnet_tpu.analysis.hlo_parse.dot_flops` over
   the LOWERED StableHLO (what the program asked for, before backend
@@ -15,14 +14,14 @@ this module prices the program —
   (:func:`~mxnet_tpu.analysis.hlo_parse.stablehlo_collective_stats`
   over the same lowered text — the MoE all-to-all dispatch/combine,
   ring ppermutes and Megatron psums all land here, so an
-  expert-parallel step's roofline row prices its exchanges).  This is
+  expert-parallel step's cost prices its exchanges).  This is
   the program's memory-traffic FLOOR: every operand read once, every
   result written once, every collective payload moved once;
   intermediates that spill past on-chip memory add to it, so
   achieved-bytes/s against HBM peak is a lower bound.
 
 Everything here is trace+lower only — no compile, no execution, no
-device work — and runs at table time, never on a hot path.
+device work — and never runs on a hot path.
 """
 from __future__ import annotations
 
@@ -61,9 +60,9 @@ def program_cost(fn, args):
     step of it, and the caller scales by the steps it assumes).  The
     sort/scatter term does the same for the MoE dispatch algorithms
     (``MXNET_MOE_DISPATCH``): the sort path's key sort and slot scatter
-    are priced, so the mfu_table compares it honestly against the
-    one-hot cumsum pack it replaced.  All extras fold into ``bytes``
-    and break out separately so the roofline table can show them.
+    are priced, so the two compare honestly against the one-hot
+    cumsum pack it replaced.  All extras fold into ``bytes`` and break
+    out separately so a caller can show them.
     Callers holding trace-counting instrumentation must arm their
     probing flag around this (the trace here is a probe, same economics
     as ``artifact_from_jit``)."""

@@ -672,7 +672,7 @@ def stablehlo_sort_scatter_stats(stablehlo_text):
     the consumer; a multi-result sort — argsort's (keys, payload) pair —
     sums every result tensor).
 
-    This is what lets the roofline table compare the MoE dispatch
+    This is what lets ``analysis.cost`` compare the MoE dispatch
     algorithms honestly (``MXNET_MOE_DISPATCH``): the sort path's
     intermediates are O(k*N) key/payload vectors plus the slot scatter,
     where the one-hot cumsum pack materializes (k*N, E) int32 one-hot

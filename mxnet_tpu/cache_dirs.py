@@ -24,8 +24,7 @@ def arm_compile_cache():
     """Give jax's persistent compilation cache a directory and return it.
 
     Called first by the programs that run on the chip (``chip_smoke.py``,
-    ``bench.py``, ``benchmarks/*.py`` outside ``--smoke``) — never by
-    library import, and never by the tests (tests/conftest.py says why).
+    ``benchmarks/*.py`` outside ``--smoke``) — never by library import, and never by the tests (tests/conftest.py says why).
     ``JAX_COMPILATION_CACHE_DIR`` places the cache from outside: jax reads
     it itself, so when it is set no directory is configured here.
 

@@ -377,10 +377,10 @@ register("MXNET_ELASTIC_GRACE", float, 30.0,
          "a missing first stamp does not read as dead.")
 register("MXNET_TELEMETRY", bool, True,
          "Arm the unified telemetry subsystem (mxnet_tpu.obs): timed "
-         "dispatch wrappers on the compiled programs (the per-program "
-         "MFU/roofline table), always-on timeline spans and instant "
-         "events (bounded ring buffer), and the lazy static-cost "
-         "probers.  Purely host-side — compiled HLO is byte-identical "
+         "dispatch wrappers on the compiled programs (a span a "
+         "dispatch), always-on timeline spans and instant events "
+         "(bounded ring buffer), and the lazy readers of each "
+         "program's HLO.  Purely host-side — compiled HLO is byte-identical "
          "on or off (tests/test_obs.py pins it).  The step_stats loop "
          "counters predate the subsystem and stay on regardless.")
 register("MXNET_TRACE_BUFFER", int, 65536,
@@ -403,13 +403,6 @@ register("MXNET_METRICS_PORT", int, 0,
          "(obs.MetricsServer, 127.0.0.1): /metrics is the Prometheus "
          "text format, /metrics.json the snapshot, /trace the current "
          "timeline as Chrome-trace JSON.  0 (default) = no server.")
-register("MXNET_PEAK_FLOPS", float, 0.0,
-         "Peak accelerator FLOP/s used as the MFU denominator in the "
-         "per-program roofline table (obs.mfu_table / bench.py "
-         "mfu_table / tools/mxstat.py).  0 (default) = look the device "
-         "kind up in the TPU spec table; unknown devices (the CPU "
-         "harness) then report mfu=null while flops/bytes/wall stay "
-         "populated.")
 register("MXNET_FLEET_SWAP", bool, True,
          "Arm preemption/swap in the paged serving loop "
          "(decode.DecodeServer / serve.swap): when the page pool cannot "

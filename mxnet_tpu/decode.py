@@ -656,9 +656,9 @@ class DecodePredictor:
                                       donate_argnums=donate)
         self._verify_shapes = set()   # distinct (B, k, has_q) driven
         self._prefill_fns = {}   # (B, P) -> jitted prefill program
-        # roofline telemetry: program name -> (jitted fn, arg avals),
-        # snapped once on the first dispatch so obs.programs can price
-        # the program lazily (trace+lower at TABLE time, off hot paths)
+        # telemetry: program name -> (jitted fn, arg avals), snapped
+        # once on the first dispatch so obs.programs can read the
+        # program's HLO lazily (when a map is asked for, off hot paths)
         self._static_args = {}
         self._steady = None     # the state's avals from the second tick on
         # jnp dummies reused every call (sample_tokens at temperature 0
@@ -930,16 +930,15 @@ class DecodePredictor:
         return (pages, self._page_tokens, aval.shape[2])
 
     # ------------------------------------------------------------------
-    # roofline telemetry (mxnet_tpu.obs) — host-side only: the compiled
-    # programs are byte-identical with telemetry on or off
+    # telemetry (mxnet_tpu.obs) — host-side only: the compiled programs
+    # are byte-identical with telemetry on or off
     # ------------------------------------------------------------------
-    def _roofline_register(self, name, fn, args, static=True):
-        """Snap ``args``' avals once and register a lazy static-cost
-        prober (``static``: a row of the MFU table) and a lazy reader of
-        the optimized HLO (the scope map) for program ``name`` (first
+    def _register_hlo(self, name, fn, args):
+        """Snap ``args``' avals once and register a lazy reader of the
+        optimized HLO (the scope map) for program ``name`` (first
         dispatch only; later calls are one dict hit).  The first paged
         step also registers readers for the small programs the serving
-        loop runs beside it (:data:`_BESIDE`), which are not priced."""
+        loop runs beside it (:data:`_BESIDE`)."""
         if name in self._static_args or not _obs.enabled():
             return
         import weakref
@@ -950,12 +949,8 @@ class DecodePredictor:
 
         self._static_args[name] = (fn, jtu.tree_map(aval_of, args))
         # weakly bound: a collected predictor must not stay pinned (env
-        # params + snapped programs) by the process-global accounting
+        # params + snapped programs) by the process-global readers
         ref = weakref.ref(self)
-        if static:
-            _obs.programs.register_static(
-                name, lambda n=name, r=ref: (
-                    r()._roofline_static(n) if r() is not None else None))
         names = (name,) + (tuple(self._BESIDE) if self._paged and name in (
             "paged_decode_step", "paged_verify_step",
             "paged_decode_mtp_step") else ())
@@ -1076,7 +1071,7 @@ class DecodePredictor:
         the lowering that already holds a loaded executable is the one the
         dispatch calls, and its text is read with nothing compiled.
         Where neither does, a snapped program is compiled as snapped (the
-        accounting sees the compile and marks the map ``"relowered"``)
+        reader sees the compile and marks the map ``"relowered"``)
         and a program beside the steps is left without a map."""
         from .programs.spec import probing
 
@@ -1099,19 +1094,6 @@ class DecodePredictor:
                 if _loaded(lowered):
                     return lowered.compile().as_text()
             return None if snapped is None else lowered.compile().as_text()
-
-    def _roofline_static(self, name):
-        """Price one snapped program (trace+lower only; probe-flagged so
-        the trace counters stay honest).  A program dispatching an
-        AOT-loaded executable carries its source in the row."""
-        from .programs.spec import probe_cost
-
-        fn, args = self._static_args[name]
-        cost = probe_cost(self, fn, args)
-        src = getattr(fn, "source", None)
-        if cost is not None and src and src != "jit":
-            cost = dict(cost, aot=src)
-        return cost
 
     # ------------------------------------------------------------------
     # the shared graph walk (traced inside both programs)
@@ -2332,7 +2314,7 @@ class DecodePredictor:
         state, tables, act = self.paged_prepare(state, lens_h, 1, active)
         args = (self._env, state, tables, act,
                 key if key is not None else self._zero_key)
-        self._roofline_register("paged_decode_step", self._decode_fn, args)
+        self._register_hlo("paged_decode_step", self._decode_fn, args)
         with _obs.program_span("paged_decode_step"):
             return self._decode_fn(*args)
 
@@ -2349,7 +2331,7 @@ class DecodePredictor:
             state, lo, 2 + int(behind), active)
         args = (self._env, state, tables, act,
                 key if key is not None else self._zero_key)
-        self._roofline_register("paged_decode_mtp_step", self._mtp_fn, args)
+        self._register_hlo("paged_decode_mtp_step", self._mtp_fn, args)
         with _obs.program_span("paged_decode_mtp_step"):
             return self._mtp_fn(*args)
 
@@ -2364,8 +2346,7 @@ class DecodePredictor:
                 [prompt[end] if end < prompt.size else -1], np.int32), key)
         # its dispatch wall accrues to the "prefill" row; only the scope
         # map knows the chunk program by its own name
-        self._roofline_register("prefill_chunk_mtp", self._chunk_mtp_fn,
-                                args, static=False)
+        self._register_hlo("prefill_chunk_mtp", self._chunk_mtp_fn, args)
         with _obs.program_span("prefill"):
             return self._chunk_mtp_fn(*args)
 
@@ -2377,8 +2358,7 @@ class DecodePredictor:
         args = (state.lens, state.tok, state.draft, state.draft_probs,
                 np.int32(slot), jnp.asarray([plen], jnp.int32), tok, draft,
                 dprobs)
-        self._roofline_register("slot_commit_mtp", self._commit_mtp_fn, args,
-                                static=False)
+        self._register_hlo("slot_commit_mtp", self._commit_mtp_fn, args)
         lens, tok, draft, dprobs = self._commit_mtp_fn(*args)
         return DecodeState(state.caches, lens, tok, draft=draft,
                            draft_probs=dprobs)
@@ -2452,7 +2432,7 @@ class DecodePredictor:
                                  draft_probs is not None))
         args = (self._env, state, tables, act, draft_toks, draft_probs,
                 key if key is not None else self._zero_key)
-        self._roofline_register("paged_verify_step", self._verify_fn, args)
+        self._register_hlo("paged_verify_step", self._verify_fn, args)
         with _obs.program_span("paged_verify_step"):
             return self._verify_fn(*args)
 
@@ -2572,7 +2552,7 @@ class DecodePredictor:
             self._prefill_fns[(b, p)] = fn
         args = (self._env, tokens, lens,
                 key if key is not None else self._zero_key)
-        self._roofline_register("prefill", fn, args)
+        self._register_hlo("prefill", fn, args)
         with _obs.program_span("prefill"):
             return fn(*args)
 
@@ -2589,7 +2569,7 @@ class DecodePredictor:
             return out
         args = (self._env, state,
                 key if key is not None else self._zero_key)
-        self._roofline_register("decode_step", self._decode_fn, args)
+        self._register_hlo("decode_step", self._decode_fn, args)
         with _obs.program_span("decode_step"):
             return self._decode_fn(*args)
 
@@ -2623,7 +2603,7 @@ class DecodePredictor:
                                  draft_probs is not None))
         args = (self._env, state, draft_toks, draft_probs,
                 key if key is not None else self._zero_key)
-        self._roofline_register("verify_step", self._verify_fn, args)
+        self._register_hlo("verify_step", self._verify_fn, args)
         with _obs.program_span("verify_step"):
             return self._verify_fn(*args)
 
@@ -4388,10 +4368,10 @@ class DecodeServer:
                         p["slot"], p["prompt"][p["pos"]:p["pos"] + n],
                         p["pos"], self._chunk_w) + (
                             np.asarray([ends], np.int32), sub)
-                    # its dispatch wall accrues to the "prefill" row; only
-                    # the scope map knows the chunk program by its own name
-                    pred._roofline_register("prefill_chunk", pred._chunk_fn,
-                                            args, static=False)
+                    # its dispatch's span is named "prefill"; only the
+                    # scope map knows the chunk program by its own name
+                    pred._register_hlo("prefill_chunk", pred._chunk_fn,
+                                       args)
                     with _obs.program_span("prefill"):
                         caches, probs, tok, *moe = pred._chunk_fn(*args)
                 if moe:

@@ -551,8 +551,7 @@ class ImageIter(DataIter):
         c, h, w = self.data_shape
         # assemble NCHW directly: one strided store per image instead of an
         # NHWC store plus a whole-batch transposed copy (the assembly cost
-        # matters — on a 1-core host it was ~35% of pipeline time,
-        # benchmarks/bench_input_pipeline.py)
+        # matters — on a 1-core host it was ~35% of pipeline time)
         images = np.zeros((self.batch_size, c, h, w), self._dtype)
         label_shape = self.provide_label[0].shape
         labels = np.zeros(label_shape, np.float32)

@@ -8,11 +8,10 @@ Three layers (docs/observability.md), shared process-wide singletons:
 * :data:`timeline` — the always-on trace timeline
   (:mod:`~mxnet_tpu.obs.trace`): a bounded ring buffer of thread-aware
   spans and instant events, exported as Chrome-trace JSON (Perfetto);
-* :data:`programs` — per-program roofline accounting
-  (:mod:`~mxnet_tpu.obs.roofline`): measured dispatch wall per compiled
-  program joined against static FLOPs/bytes into the MFU table, and
-  each program's scope map (:mod:`~mxnet_tpu.obs.scopes`: which layer
-  every instruction of its optimized HLO belongs to).
+* :data:`programs` — the programs that ran
+  (:mod:`~mxnet_tpu.obs.program_maps`): a lazy reader of each one's
+  optimized HLO and its scope map (:mod:`~mxnet_tpu.obs.scopes`: which
+  layer every instruction belongs to).
 
 The process's own start and every compile are accounted for by
 :mod:`~mxnet_tpu.obs.startup` (``obs.phase`` / ``obs.top_span``, the one
@@ -33,16 +32,14 @@ import time
 from .metrics import (Counter, Gauge, Histogram, MetricsRegistry,
                       PeriodicExporter, percentile)
 from .prom import MetricsServer
-from .roofline import (PEAK_FLOPS, ProgramAccounting, auto_peak,
-                       peak_flops_for, render_mfu_table)
+from .program_maps import ProgramMaps
 from .trace import TraceTimeline, _annotation
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "MetricsServer",
-    "PEAK_FLOPS", "PeriodicExporter", "ProgramAccounting", "TraceTimeline",
-    "auto_peak", "enabled", "mfu_table", "mirror", "peak_flops_for",
-    "percentile", "phase", "phased",
-    "program_span", "programs", "registry", "render_mfu_table",
+    "PeriodicExporter", "ProgramMaps", "TraceTimeline",
+    "enabled", "mirror", "percentile", "phase", "phased",
+    "program_span", "programs", "registry",
     "serve_metrics", "span", "timeline", "top_span",
 ]
 
@@ -54,22 +51,15 @@ from .. import config as _config
 registry = MetricsRegistry()
 timeline = TraceTimeline(capacity=max(int(_config.get("MXNET_TRACE_BUFFER")),
                                       1))
-programs = ProgramAccounting()
+programs = ProgramMaps()
 
 
 def enabled():
     """Whether telemetry recording is armed (``MXNET_TELEMETRY``).
     Counters predating the subsystem (``profiler.step_stats``'s loop
     accounting) stay on regardless; this gates the timeline spans /
-    instant events and the per-program dispatch timing."""
+    instant events and the per-program dispatch spans."""
     return bool(_config.get("MXNET_TELEMETRY"))
-
-
-def mfu_table(peak_flops=None):
-    """The per-program MFU/roofline table (see
-    :meth:`~mxnet_tpu.obs.roofline.ProgramAccounting.table`); the peak
-    defaults to ``MXNET_PEAK_FLOPS`` or the device spec sheet."""
-    return programs.table(auto_peak() if peak_flops is None else peak_flops)
 
 
 # ---------------------------------------------------------------------------
@@ -92,10 +82,9 @@ from .startup import _tls, phase, phased, top_span  # noqa: E402
 
 
 class _ProgramSpan:
-    """Times one compiled-program dispatch: feeds the roofline
-    accounting AND drops a span on the timeline (cat="program"), off
-    one clock reading at each end.  While it is open the thread's compile
-    stages are booked under its name (:mod:`~mxnet_tpu.obs.startup`)."""
+    """Times one compiled-program dispatch: a span on the timeline
+    (cat="program").  While it is open the thread's compile stages are
+    booked under its name (:mod:`~mxnet_tpu.obs.startup`)."""
 
     __slots__ = ("_name", "_t0", "_ann", "_outer")
 
@@ -113,7 +102,6 @@ class _ProgramSpan:
     def __exit__(self, *exc):
         t1 = time.perf_counter_ns()
         _tls.program = self._outer
-        programs.note(self._name, (t1 - self._t0) * 1e-9)
         timeline.add_span_ns(self._name, self._t0, t1, cat="program")
         self._ann.__exit__(*exc)
         return False

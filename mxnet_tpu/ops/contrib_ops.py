@@ -319,8 +319,7 @@ def _multibox_detection(attrs, cls_prob, loc_pred, anchors):
             # only the top-k candidates by score enter NMS (ref
             # multibox_detection.cc:125-127) — and the suppression scan
             # runs over the k-row slice, not all anchors (k steps, k x k
-            # IoU: the detection-scale fast path, benchmarks/
-            # bench_detection.py)
+            # IoU: the detection-scale fast path)
             order_full = jnp.argsort(
                 -jnp.where(keep_score, score, -jnp.inf))
             top = order_full[:nms_topk]

@@ -177,15 +177,14 @@ def record_request(queue_wait_s, ttft_s, tokens, decode_s, rid=None):
 
 
 def reset_step_stats():
-    """Zero the loop counters, request histograms and the per-program
-    roofline timings — a bench's measurement window starts here.  Only
+    """Zero the loop counters and request histograms — a bench's
+    measurement window starts here.  Only
     the facade-owned series reset; other subsystems' registry metrics
     (serve-loop mirrors, liveness gauges, user counters) are untouched."""
     global _t0
     with _lock:
         for m in _OWNED_METRICS:
             m.reset()
-        _obs.programs.reset()
         _t0 = time.time()
     # the window's opening, on the timeline's clock: a reader of the ring
     # finds the spans recorded since

@@ -6,7 +6,7 @@ separate times (``CompiledTrainStep`` / ``CompiledEvalStep`` /
 
 * :mod:`~mxnet_tpu.programs.spec` — :class:`ProgramSpec` (name,
   abstract args, donation map, partition rules, trace counters ->
-  artifact / roofline cost / fingerprint) and the shared ``_probing``
+  artifact / fingerprint) and the shared ``_probing``
   guard helpers;
 * :mod:`~mxnet_tpu.programs.partition` — regex partition rules over
   named param trees (the fmengine ``match_partition_rules`` idiom);
@@ -22,11 +22,10 @@ from .aot import AOT_STATS, AotDispatch
 from .partition import build_shardings, match_partition_rules, \
     rules_from_plan
 from .registry import REGISTRY, ProgramRegistry
-from .spec import ProgramSpec, probe_artifact, probe_cost, \
-    probe_lowered_text, probing
+from .spec import ProgramSpec, probe_artifact, probe_lowered_text, \
+    probing
 
 __all__ = ["AOT_STATS", "AotDispatch", "ProgramRegistry", "ProgramSpec",
            "REGISTRY", "aot", "build_shardings", "match_partition_rules",
-           "partition", "probe_artifact", "probe_cost",
-           "probe_lowered_text", "probing", "registry",
-           "rules_from_plan"]
+           "partition", "probe_artifact", "probe_lowered_text", "probing",
+           "registry", "rules_from_plan"]

@@ -201,8 +201,8 @@ class AotDispatch:
     executable was not compiled for falls back to the JIT path
     (counted in ``AOT_STATS['fallbacks']``, warned once per dispatch) —
     slower, never wrong.  Probe surfaces (``.lower``/``.trace``/
-    ``.eval_shape``) always delegate to the jit fn so artifacts, FLOP
-    text and roofline costs keep working unchanged.
+    ``.eval_shape``) always delegate to the jit fn so artifacts and
+    FLOP text keep working unchanged.
     """
 
     _MAX_ARMED = 4
@@ -259,7 +259,7 @@ class AotDispatch:
                     "(slower, traced) for such calls", self.name)
         return self.fn(*args)
 
-    # probe delegation — artifacts/FLOP text/roofline never notice
+    # probe delegation — artifacts/FLOP text never notice
     def lower(self, *args, **kw):
         return self.fn.lower(*args, **kw)
 

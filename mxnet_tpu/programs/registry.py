@@ -5,8 +5,8 @@ Two namespaces, one registry object:
 * **Live programs** — every :class:`~mxnet_tpu.programs.spec.
   ProgramSpec` a call site registers (``registry.register(spec)``,
   latest wins per name, weakly owned).  ``registry.trace_report()``
-  folds their retrace counters into one accounting view; artifacts and
-  roofline costs come off the specs themselves.
+  folds their retrace counters into one accounting view; artifacts
+  come off the specs themselves.
 * **Canonical programs** — the programs ``tools/mxlint.py`` audits.
   ``analysis/programs.py`` REGISTERS builder groups here (a builder
   drives a real workload and returns ``[(name, artifact), ...]``);
@@ -39,8 +39,7 @@ class ProgramRegistry:
     # live programs
     # ------------------------------------------------------------------
     def register(self, spec):
-        """Register (or refresh) a live program spec; latest wins —
-        the same refresh rule as the roofline's static probers.  Held
+        """Register (or refresh) a live program spec; latest wins.  Held
         WEAKLY: the registering call site owns the spec (the spec in
         turn owns a jitted fn closing over real model state, which a
         process-global table must never pin); a collected owner's entry

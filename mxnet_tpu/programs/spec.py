@@ -5,17 +5,14 @@ eval step, the decode/verify/chunk serving programs, the page
 migration pair) used to hand-thread the same plumbing three separate
 times: an aval snapshot for probes, a ``_probing`` guard so probe
 traces don't count as retraces, a donated-leaf count for the donation
-pass, mesh/dtype metadata for the artifact, and a lazy static-cost
-prober for the roofline table.  A :class:`ProgramSpec` is that plumbing
-written ONCE: the call site registers (name, jitted fn, abstract args,
-donation map, partition rules, trace counters) and gets
+pass and mesh/dtype metadata for the artifact.  A :class:`ProgramSpec`
+is that plumbing written ONCE: the call site registers (name, jitted fn,
+abstract args, donation map, partition rules, trace counters) and gets
 
 * :meth:`artifact`  — the :class:`~mxnet_tpu.analysis.artifact.
   ProgramArtifact` probe (jaxpr + StableHLO + compiled HLO + metadata),
   donated leaves COMPUTED from ``donate_argnums`` over the actual args
   instead of hand-counted;
-* :meth:`cost`      — the roofline static cost
-  (``analysis.cost.program_cost``), probe-flagged;
 * :meth:`lowered` / :meth:`compiled` — the raw AOT pipeline stages;
 * :meth:`fingerprint` — the content address of the compiled program:
   a digest over (name, abstract args, donation map, jax version,
@@ -24,10 +21,10 @@ donation map, partition rules, trace counters) and gets
   byte-identical programs by comparing keys.
 
 The probing helpers at module level (:func:`probing`,
-:func:`probe_artifact`, :func:`probe_cost`, :func:`probe_lowered_text`)
-are the ONE copy of the ``owner._probing`` guard dance that
+:func:`probe_artifact`, :func:`probe_lowered_text`) are the ONE copy of
+the ``owner._probing`` guard dance that
 ``CompiledTrainStep``/``CompiledEvalStep``/``DecodePredictor`` each
-used to hand-roll around every artifact/cost/HLO probe.
+used to hand-roll around every artifact/HLO probe.
 """
 from __future__ import annotations
 
@@ -36,7 +33,7 @@ import hashlib
 import json
 import weakref
 
-__all__ = ["ProgramSpec", "probing", "probe_artifact", "probe_cost",
+__all__ = ["ProgramSpec", "probing", "probe_artifact",
            "probe_lowered_text"]
 
 
@@ -67,15 +64,6 @@ def probe_artifact(owner, fn, args, name, refine=None, **kw):
     with probing(owner):
         art = artifact_from_jit(fn, args, name=name, **kw)
     return refine(art) if refine is not None else art
-
-
-def probe_cost(owner, fn, args):
-    """Static FLOPs + traffic bytes (``analysis.cost.program_cost``)
-    under the probing guard — the roofline prober body."""
-    from ..analysis.cost import program_cost
-
-    with probing(owner):
-        return program_cost(fn, args)
 
 
 def probe_lowered_text(owner, fn, args):
@@ -168,7 +156,7 @@ class ProgramSpec:
                    for i in self.donate_argnums if i < len(args))
 
     # ------------------------------------------------------------------
-    # probes (the uniform exposure the passes/roofline consume)
+    # probes (the uniform exposure the passes consume)
     # ------------------------------------------------------------------
     def artifact(self, args=None, name=None, refine=None, **extra_meta):
         """:class:`~mxnet_tpu.analysis.artifact.ProgramArtifact` of the
@@ -186,25 +174,6 @@ class ProgramSpec:
             mesh_shape=_resolve(self._mesh_shape),
             trace_count=_resolve(self._trace_count),
             expected_traces=_resolve(self._expected_traces) or 1, **meta)
-
-    def cost(self, args=None):
-        """Roofline static cost at ``args`` (None before runnable)."""
-        args = self.avals(args)
-        if args is None:
-            return None
-        return probe_cost(self.owner(), self.fn, args)
-
-    def register_roofline(self, accounting=None, name=None):
-        """Attach this spec's :meth:`cost` as the program's lazy
-        static-cost prober (weakly bound through the spec's own weak
-        owner ref, so registration never pins the model)."""
-        from .. import obs as _obs
-
-        acc = accounting if accounting is not None else _obs.programs
-        ref = weakref.ref(self)
-        acc.register_static(
-            name or self.name,
-            lambda: (ref().cost() if ref() is not None else None))
 
     # ------------------------------------------------------------------
     # the AOT pipeline stages

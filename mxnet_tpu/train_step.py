@@ -35,22 +35,21 @@ from .obs.scopes import scope as _scope
 __all__ = ["CompiledTrainStep", "CompiledEvalStep"]
 
 
-def _weak_prober(step, method="roofline_static"):
-    """A lazy reader of ``step.<method>()`` (the roofline static cost; with
-    ``compiled_hlo``, the optimized HLO for ``obs.programs.scope_map``)
-    that does NOT pin the step object (and transitively its executor
-    group + master weights) in the process-global accounting: once the
-    step is collected, it resolves to None and the program's row simply
-    keeps no statics."""
+def _weak_hlo_reader(step):
+    """A lazy reader of ``step.compiled_hlo()`` (the optimized HLO for
+    ``obs.programs.scope_map``) that does NOT pin the step object (and
+    transitively its executor group + master weights) in the
+    process-global readers: once the step is collected, it resolves to
+    None."""
     import weakref
 
     ref = weakref.ref(step)
 
-    def prober():
+    def read():
         live = ref()
-        return getattr(live, method)() if live is not None else None
+        return live.compiled_hlo() if live is not None else None
 
-    return prober
+    return read
 
 
 def _register_step_spec(step):
@@ -167,7 +166,7 @@ class CompiledEvalStep:
         self._fn = jax.jit(step, donate_argnums=(2,))
         self._last_args = None   # aval snapshot for artifact probes
         self._snap_traces = -1   # trace_count the snapshot was taken at
-        self._static_registered = False  # roofline prober armed once
+        self._registered = False  # spec + HLO reader registered once
 
     def _place(self, arr, name):
         import jax
@@ -183,20 +182,18 @@ class CompiledEvalStep:
             return jax.device_put(v, group._input_sharding(name))
         return jax.device_put(v, group.contexts[0].jax_device)
 
-    # telemetry: the roofline row this program's dispatch wall accrues to
+    # telemetry: the name this program's dispatch spans carry
     telemetry_name = "eval_step"
 
     def run(self, data_batch):
         """Accumulate one batch on device.  No host transfer happens here;
         the metric's accumulator state is donated through the program.
-        Dispatch wall time feeds the per-program roofline table
-        (``obs.programs``) — host-side only, the program is untouched."""
+        Each dispatch leaves a ``cat="program"`` span on the timeline —
+        host-side only, the program is untouched."""
         if not _obs.enabled():
             return self._run_impl(data_batch)
-        if not self._static_registered:
-            self._static_registered = True
-            _obs.programs.register_static(self.telemetry_name,
-                                          _weak_prober(self))
+        if not self._registered:
+            self._registered = True
             self._program_spec = _register_step_spec(self)
         with _obs.program_span(self.telemetry_name):
             return self._run_impl(data_batch)
@@ -279,17 +276,6 @@ class CompiledEvalStep:
             donated_leaves=len(jtu.tree_leaves(mstate)),
             trace_count=self.trace_count, expected_traces=1,
             metric=type(self._acc.metric).__name__)
-
-    def roofline_static(self):
-        """Static FLOPs + traffic bytes of the eval program at the
-        last-run shapes (None before the first ``run``) — the lazy
-        roofline join, trace+lower only, probe-flagged so it never
-        counts as a retrace."""
-        from .programs.spec import probe_cost
-
-        if self._last_args is None:
-            return None
-        return probe_cost(self, self._fn, self._last_args)
 
 
 class CompiledTrainStep:
@@ -376,7 +362,7 @@ class CompiledTrainStep:
         self._fns[id(exec_group.exec_)] = (self._fn, exec_group.exec_)
         self.num_steps = 0
         self._hyper_cache = None
-        self._static_registered = False  # roofline prober armed once
+        self._registered = False  # spec + HLO reader registered once
         # lifecycle state is a property of the shared store, not of any one
         # module (several bucket modules may view this step)
         self.step_stale = False   # executor buffers newer than the store
@@ -533,8 +519,8 @@ class CompiledTrainStep:
         self.programs_built += 1
         return jax.jit(step, donate_argnums=(0, 1, 2, 3))
 
-    # telemetry: the roofline row this program's dispatch wall accrues to
-    # (one shared store = one row, however many bucket executors)
+    # telemetry: the name this program's dispatch spans carry (one
+    # shared store = one name, however many bucket executors)
     telemetry_name = "train_step"
 
     # ------------------------------------------------------------------
@@ -542,20 +528,17 @@ class CompiledTrainStep:
         """Execute one full training step; returns output jnp arrays.
 
         ``group`` selects the (bucket) executor whose graph to run; the
-        master weights/slots are this store's regardless.  Dispatch wall
-        time feeds the per-program roofline table (``obs.programs``) —
-        host-side timing only, the compiled program is byte-identical
-        with telemetry on or off (tests/test_obs.py pins it).
+        master weights/slots are this store's regardless.  Each dispatch
+        leaves a ``cat="program"`` span on the timeline — host-side
+        timing only, the compiled program is byte-identical with
+        telemetry on or off (tests/test_obs.py pins it).
         """
         if not _obs.enabled():
             return self._run_impl(data_batch, group)
-        if not self._static_registered:
-            self._static_registered = True
-            _obs.programs.register_static(self.telemetry_name,
-                                          _weak_prober(self))
+        if not self._registered:
+            self._registered = True
             _obs.programs.register_hlo(
-                self.telemetry_name, _weak_prober(self, "compiled_hlo"),
-                owner=self)
+                self.telemetry_name, _weak_hlo_reader(self), owner=self)
             self._program_spec = _register_step_spec(self)
         with _obs.program_span(self.telemetry_name):
             return self._run_impl(data_batch, group)
@@ -742,19 +725,6 @@ class CompiledTrainStep:
             expected_traces=self.programs_built,
             num_steps=self.num_steps,
             sharding_coverage=coverage)
-
-    def roofline_static(self, group=None):
-        """Static FLOPs + traffic bytes of the fused step program at the
-        live shapes (None before the first ``run``) — the lazy roofline
-        join for ``obs.programs``.  Trace+lower only (no compile, no
-        execution), probe-flagged so it never counts as a retrace."""
-        from .programs.spec import probe_cost
-
-        group = group if group is not None else self._group
-        args = self._abstract_args(group)
-        if args is None:
-            return None
-        return probe_cost(self, self._entry_for(group), args)
 
     def _place(self, arr, name, group=None):
         import jax

@@ -106,6 +106,11 @@ def test_metric_sync_period_bounds_host_transfers(loop_knobs):
     assert sync_rate >= 2.0  # label + pred materialize every step
     period = int(ASYNC_ENV["MXNET_METRIC_SYNC_PERIOD"])
     assert s_async["host_syncs_per_step"] <= sync_rate / period
+    # device-side accumulation: well under the two transfers a step
+    # (label + pred) of the synchronous host-metric loop
+    assert s_async["host_syncs_per_step"] < 1.0, s_async
+    for stats in (s_sync, s_async):
+        assert 0.0 <= stats["input_stall_fraction"] <= 1.0, stats
 
 
 def test_async_loop_with_metric_reading_callback(loop_knobs):

@@ -1,21 +1,10 @@
 """Tier-1 smoke runs of the benchmarks.
 
-`bench.py --smoke` drives a small MLP fit through the FULL async training
-loop (device-side metrics + device prefetch + bounded in-flight dispatch)
-and must emit the loop-accounting fields `input_stall_fraction` and
-`host_syncs_per_step` alongside the metric contract — plus the
-per-program `mfu_table` roofline rows (mxnet_tpu.obs): flops, bytes,
-wall_s and mfu for every canonical program the smoke drives.
-
-`tools/mxstat.py --smoke` self-checks the telemetry machinery (registry
-concurrency, numpy-exact histogram percentiles, exporters, the
-ring-bounded chrome-trace timeline, the MFU-table join) without jax.
-
 Tier-1 smoke run of the long-context benchmark.
 
 `benchmarks/bench_long_context.py --smoke` (tiny T, 8 virtual CPU
 devices) must stay importable and runnable on every PR: one JSON line on
-stdout under the bench.py contract, per-(mesh, schedule) detail JSONs on
+stdout under the benches' contract, per-(mesh, schedule) detail JSONs on
 stderr covering BOTH ring communication schedules (serial and
 double-buffered), with collective traffic accounted from compiled HLO.
 A broken bench would otherwise only surface on the TPU rig.
@@ -27,7 +16,7 @@ Tier-1 smoke run of the decode benchmark.
 mixed-length continuous-batching serve in BOTH configurations — the PR-4
 dense-cache baseline and speculation x int8-quantized caches — plus the
 shared-system-prompt trace drained dense-ring AND paged+prefix-cache) at
-tiny dims and must emit the bench.py metric contract plus the decode
+tiny dims and must emit the benches' metric contract plus the decode
 accounting fields — the HLO-level dot-FLOP counts behind the
 O(1)-in-prefix assertion (which the bench itself enforces, nonzero exit
 on regression), the speculative accept-rate/steps accounting, the
@@ -58,66 +47,6 @@ import sys
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
-def test_bench_smoke_async_loop_contract():
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    # scrub inherited bench/loop/telemetry knobs so the smoke measures
-    # the defaults
-    for key in [k for k in env if k.startswith("BENCH_")
-                or k.startswith("MXNET_METRICS_")
-                or k in ("MXNET_DEVICE_METRICS", "MXNET_DEVICE_PREFETCH",
-                         "MXNET_MAX_STEPS_IN_FLIGHT",
-                         "MXNET_METRIC_SYNC_PERIOD", "MXNET_TELEMETRY",
-                         "MXNET_TRACE_BUFFER", "MXNET_PEAK_FLOPS")]:
-        env.pop(key)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "bench.py"), "--smoke"],
-        capture_output=True, text=True, timeout=300, cwd=ROOT, env=env)
-    assert proc.returncode == 0, proc.stderr[-3000:]
-
-    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
-    assert len(lines) == 1, proc.stdout
-    head = json.loads(lines[0])
-    # the bench.py metric contract ...
-    assert head["metric"].startswith("async_fit_mlp_imgs_per_sec")
-    assert head["unit"] == "img/s"
-    assert head["value"] > 0 and head["vs_baseline"] > 0
-    # ... plus the async-loop accounting fields, present and sane
-    assert 0.0 <= head["input_stall_fraction"] <= 1.0
-    assert head["host_syncs_per_step"] >= 0.0
-    # device-side accumulation means well under the 2-transfers-per-step
-    # (label + pred) floor of the synchronous host-metric loop
-    assert head["host_syncs_per_step"] < 1.0, head
-    # ... plus the elastic-checkpoint accounting: the smoke fit runs under
-    # async fenced checkpointing, so the deterministic halves must hold —
-    # at least the initial fence committed, no recovery happened on a
-    # clean run, and the stall fraction is a sane fraction (its
-    # async-beats-sync comparison lives in tests/test_elastic.py where
-    # both configurations run on one trace)
-    assert head["ckpt_writes"] >= 1, head
-    assert head["recoveries"] == 0, head
-    assert 0.0 <= head["checkpoint_stall_fraction"] <= 1.0, head
-    assert head["last_ckpt_ms"] > 0.0, head
-    # ... plus the per-program MFU/roofline table (mxnet_tpu.obs): every
-    # canonical program the smoke drives — the fused train step, the
-    # device-metric eval step, the KV-cache prefill and the donated
-    # decode step — gets a row joining measured dispatch wall against
-    # static FLOPs and traffic bytes.  mfu itself is null on the CPU
-    # harness (no spec-sheet peak) but the field must be present; on a
-    # TPU it is a number in (0, 1].
-    rows = {r["program"]: r for r in head["mfu_table"]}
-    for prog in ("train_step", "eval_step", "prefill", "decode_step"):
-        assert prog in rows, sorted(rows)
-        row = rows[prog]
-        for key in ("flops", "bytes", "wall_s", "mfu"):
-            assert key in row, row
-        assert row["calls"] > 0 and row["wall_s"] > 0, row
-        assert row["flops"] > 0 and row["bytes"] > 0, row
-        assert row["mfu"] is None or 0 < row["mfu"] <= 1, row
-    # the fit dominates: train_step saw every step the loop dispatched
-    assert rows["train_step"]["calls"] >= 50, rows["train_step"]
-
-
 def test_bench_long_context_smoke_contract():
     env = dict(os.environ)
     # the bench pins the platform itself under --smoke; scrub any
@@ -134,14 +63,14 @@ def test_bench_long_context_smoke_contract():
         capture_output=True, text=True, timeout=420, cwd=ROOT, env=env)
     assert proc.returncode == 0, proc.stderr[-3000:]
 
-    # stdout: exactly one JSON line, the bench.py metric contract
+    # stdout: exactly one JSON line, the benches' metric contract
     lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
     assert len(lines) == 1, proc.stdout
     head = json.loads(lines[0])
     assert head["metric"].startswith("attention_lm_tokens_per_sec_t")
     assert head["unit"] == "tok/s"
     assert head["value"] > 0
-    for key in ("mfu", "vs_baseline", "vs_serial"):
+    for key in ("vs_baseline", "vs_serial"):
         assert key in head, head
     assert head["vs_baseline"] > 0 and head["vs_serial"] > 0
 
@@ -178,7 +107,7 @@ def test_bench_decode_smoke_contract():
         capture_output=True, text=True, timeout=300, cwd=ROOT, env=env)
     assert proc.returncode == 0, proc.stderr[-3000:]
 
-    # stdout: exactly one JSON line, the bench.py metric contract plus the
+    # stdout: exactly one JSON line, the benches' metric contract plus the
     # decode accounting fields
     lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
     assert len(lines) == 1, proc.stdout
@@ -339,11 +268,10 @@ def test_bench_fleet_smoke_contract():
     assert head["swapped_pages"] >= 1 and head["swap_outs"] >= 1, head
     # the TTFT SLO headline is present and sane
     assert head["p95_ttft_ms"] is not None and head["p95_ttft_ms"] > 0
-    # the serving programs feed the roofline table (page migration's
+    # the serving programs' dispatches leave spans (page migration's
     # extract/install wrappers included)
-    progs = {r["program"] for r in head["mfu_table"]}
     assert {"paged_decode_step", "prefill", "page_install",
-            "page_extract"} <= progs, sorted(progs)
+            "page_extract"} <= set(head["programs"]), head["programs"]
 
     # stderr: one JSON per policy phase, both present
     rows = [json.loads(ln) for ln in proc.stderr.splitlines()
@@ -423,10 +351,10 @@ def test_bench_moe_smoke_contract():
     """`benchmarks/bench_moe.py --smoke` drives the expert-parallel MoE
     LM fused step (explicit all-to-all dispatch over the 8-virtual-device
     'expert' mesh) AND the dense one-hot-dispatch oracle at tiny dims,
-    and must emit the bench.py metric contract plus the MoE accounting:
+    and must emit the benches' metric contract plus the MoE accounting:
     the traced dispatch path, the all-to-all count/bytes from compiled
     HLO (the same surface the mxlint collective-budget pass ceilings),
-    and the per-program mfu_table rows whose expert-parallel row carries
+    and each step's static price, whose expert-parallel one carries
     collective_bytes.  The >= 2x vs-dense acceptance line is asserted by
     the bench's own full-dims run; the smoke only pins the deterministic
     halves (this harness's wall clock is shared-machine noise)."""
@@ -462,17 +390,16 @@ def test_bench_moe_smoke_contract():
     assert rows["moe_a2a"]["moe_path"] == "sparse_a2a", rows
     assert rows["dense_dispatch"]["moe_path"] == "dense", rows
     assert rows["dense_dispatch"].get("all_to_all_count", 0) == 0, rows
-    # the roofline join: the expert-parallel step's row exists, carries
-    # statics, and breaks out its exchange traffic; the dense oracle's
-    # row shows the E× FLOP bill the capacity path avoids
-    mfu = {r["program"]: r for r in head["mfu_table"]}
-    for prog in ("moe_train_step", "moe_dense_train_step"):
-        assert prog in mfu, sorted(mfu)
-        assert mfu[prog]["calls"] > 0 and mfu[prog]["wall_s"] > 0
-        assert mfu[prog]["flops"] > 0 and mfu[prog]["bytes"] > 0
-    assert mfu["moe_train_step"]["collective_bytes"] > 0, mfu
-    assert mfu["moe_train_step"]["flops"] * 2 <= \
-        mfu["moe_dense_train_step"]["flops"], mfu
+    # the static price (analysis.cost.program_cost): the
+    # expert-parallel step's breaks out its exchange traffic; the dense
+    # oracle's shows the E× FLOP bill the capacity path avoids
+    cost = {name: rows[name]["cost"]
+            for name in ("moe_a2a", "dense_dispatch")}
+    for c in cost.values():
+        assert c["flops"] > 0 and c["bytes"] > 0, cost
+    assert cost["moe_a2a"]["collective_bytes"] > 0, cost
+    assert cost["moe_a2a"]["flops"] * 2 <= \
+        cost["dense_dispatch"]["flops"], cost
     # ... plus the dispatch-algorithm accounting (ISSUE-12): the default
     # is the sort-based pack, both algorithms' priced dispatch bytes are
     # published (only the sort path materializes sort/scatter
@@ -484,39 +411,6 @@ def test_bench_moe_smoke_contract():
     assert db["onehot"]["sort_scatter_bytes"] == 0, db
     assert db["sort"]["bytes"] != db["onehot"]["bytes"], db
     assert head["dispatch_identical"] is True, head
-
-
-def test_mxstat_smoke_contract():
-    """`tools/mxstat.py --smoke` must self-check the telemetry machinery
-    (concurrent counter sums, numpy-exact histogram percentiles, the
-    JSON-lines/Prometheus exporters, the ring-bounded timeline's
-    chrome-trace schema, and the MFU-table join) and emit one
-    bench-contract JSON line with zero failed checks.  The LIVE
-    pipeline — real compiled programs feeding the same table — is pinned
-    by test_bench_smoke_async_loop_contract's mfu_table assertions; this
-    keeps the CLI and exporters honest at near-zero cost (no jax)."""
-    env = dict(os.environ)
-    for key in [k for k in env if k.startswith("MXNET_METRICS_")
-                or k in ("MXNET_TELEMETRY", "MXNET_TRACE_BUFFER",
-                         "MXNET_PEAK_FLOPS")]:
-        env.pop(key)
-    proc = subprocess.run(
-        [sys.executable, os.path.join(ROOT, "tools", "mxstat.py"),
-         "--smoke"],
-        capture_output=True, text=True, timeout=120, cwd=ROOT, env=env)
-    assert proc.returncode == 0, (proc.stdout, proc.stderr[-3000:])
-
-    lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
-    assert len(lines) == 1, proc.stdout
-    head = json.loads(lines[0])
-    assert head["metric"] == "mxstat_smoke_checks"
-    assert head["unit"] == "checks"
-    assert head["value"] >= 5 and head["vs_baseline"] == 1.0, head
-    assert head["failed"] == [], head
-    assert head["programs"] == 2, head
-    # stderr carries the rendered table: both synthetic programs present
-    assert "train_step" in proc.stderr and "decode_step" in proc.stderr
-    assert "mfu" in proc.stderr
 
 
 def test_mxlint_smoke_contract():
@@ -555,7 +449,7 @@ def test_mxlint_smoke_contract():
         capture_output=True, text=True, timeout=300, cwd=ROOT, env=env)
     assert proc.returncode == 0, (proc.stdout, proc.stderr[-3000:])
 
-    # stdout: exactly one JSON line, the bench.py metric contract
+    # stdout: exactly one JSON line, the benches' metric contract
     lines = [ln for ln in proc.stdout.splitlines() if ln.strip()]
     assert len(lines) == 1, proc.stdout
     head = json.loads(lines[0])

@@ -69,12 +69,33 @@ def toy():
     return sym, toy_params(sym)
 
 
+@pytest.fixture(scope="module")
+def flat(toy):
+    """The toy under a flat head: at temperature 1 most drafts are taken."""
+    sym, _ = toy
+    return sym, toy_params(sym, head_std=0.02)
+
+
+_PREDICTORS = {}
+
+
 def make_server(toy, spec_k, eos=None, temperature=0.0, kv_dtype="",
-                slots=2, seed=0, chunk=CHUNK):
+                slots=2, seed=0, fresh=False):
+    """A server over the toy.  Servers of one toy, temperature and cache
+    type share a predictor and so what it compiled: a server opens its own
+    session (fresh pools, a fresh manager) over it.  ``fresh`` builds a
+    predictor of the test's own, for one that counts its traces or reads
+    the maps of what it dispatched first."""
     sym, params = toy
-    pred = DecodePredictor(sym, params, cache_len=CACHE, ctx=mx.cpu(),
-                           paged=True, page_tokens=PAGE, kv_dtype=kv_dtype,
-                           prefill_chunk=chunk, temperature=temperature)
+    key = (id(params), temperature, kv_dtype)
+    pred = None if fresh else _PREDICTORS.get(key, (None, None))[1]
+    if pred is None:
+        pred = DecodePredictor(sym, params, cache_len=CACHE, ctx=mx.cpu(),
+                               paged=True, page_tokens=PAGE,
+                               kv_dtype=kv_dtype, prefill_chunk=CHUNK,
+                               temperature=temperature)
+        if not fresh:
+            _PREDICTORS[key] = (params, pred)   # params held: the id stays
     return pred, DecodeServer(pred, max_prefill=32, slots=slots,
                               spec_k=spec_k, eos_id=eos, seed=seed)
 
@@ -84,11 +105,19 @@ PROMPTS = [np.random.default_rng(5).integers(0, VOCAB, size=n)
 CAPS = (9, 3, 12, 1, 6, 8, 2)
 
 
+_ORACLES = {}
+
+
 def oracle(toy, eos=None, kv_dtype=""):
-    _, server = make_server(toy, 0, eos, kv_dtype=kv_dtype)
-    for p, c in zip(PROMPTS, CAPS):
-        server.submit(p, max_new_tokens=c)
-    return server.run()
+    """What the toy served without drafts gives: once an EOS and a cache
+    type (greedy: the same tokens every time)."""
+    key = (id(toy), eos, kv_dtype)
+    if key not in _ORACLES:
+        _, server = make_server(toy, 0, eos, kv_dtype=kv_dtype)
+        for p, c in zip(PROMPTS, CAPS):
+            server.submit(p, max_new_tokens=c)
+        _ORACLES[key] = (toy, server.run())
+    return _ORACLES[key][1]
 
 
 def drive(server, want, schedule):
@@ -182,7 +211,7 @@ def test_the_self_drafting_tick_is_read_behind_and_traced_once(toy):
     ticks = obs.registry.get("mx_serve_ticks_total")
     before = {r: ticks.labels(read=r).get() for r in ("behind", "first")}
     proposed = obs.registry.get("mx_spec_proposed").get()
-    pred, server = make_server(toy, 1)
+    pred, server = make_server(toy, 1, fresh=True)
     want = oracle(toy)
     got = drive(server, want, SCHEDULES["accept"])
     assert got.keys() == want.keys()
@@ -210,11 +239,9 @@ def test_the_self_drafting_tick_is_read_behind_and_traced_once(toy):
 
 
 @pytest.mark.parametrize("seed", [0, 1, 2])
-def test_sampled_requests_get_exactly_their_caps(toy, seed):
+def test_sampled_requests_get_exactly_their_caps(flat, seed):
     """At temperature 1 with a flat head most drafts are accepted: pairs
     everywhere, and no request receives a token beyond its cap."""
-    sym, _ = toy
-    flat = (sym, toy_params(sym, head_std=0.02))
     _, server = make_server(flat, 1, temperature=1.0, seed=seed)
     for p, c in zip(PROMPTS, CAPS):
         server.submit(p, max_new_tokens=c)
@@ -388,21 +415,30 @@ def test_an_accepted_cells_program_is_the_text_it_was(which):
     assert got == ACCEPTED_PROGRAMS[which], json.dumps({which: got})
 
 
+@pytest.fixture(scope="module")
+def drafted(toy):
+    """A self-drafting predictor of its own that has served the prompts:
+    its first dispatches registered its programs' readers."""
+    obs.programs.reset(clear_static=True)
+    pred, server = make_server(toy, 1, kv_dtype="int8", fresh=True)
+    for p, c in zip(PROMPTS, CAPS):
+        server.submit(p, max_new_tokens=c)
+    server.run()
+    yield pred
+    obs.programs.reset(clear_static=True)
+
+
 @pytest.mark.parametrize("program,stem", [
     ("paged_decode_mtp_step", "jit__paged_decode_mtp_impl"),
     ("prefill_chunk_mtp", "jit__mtp_chunk_impl"),
     ("slot_commit_mtp", "jit__commit_mtp_impl")])
-def test_the_scope_maps_are_the_self_drafting_programs_that_ran(toy, program,
+def test_the_scope_maps_are_the_self_drafting_programs_that_ran(drafted,
+                                                                program,
                                                                 stem):
     """``obs.programs``' maps of the tick, the chunk and the commit are read
     off the executables the loop dispatches, with nothing compiled; the
     block's nodes are filed under ``mtp`` and the stack's shared expert under
     ``moe/shared``."""
-    obs.programs.reset(clear_static=True)
-    pred, server = make_server(toy, 1, kv_dtype="int8")
-    for p, c in zip(PROMPTS, CAPS):
-        server.submit(p, max_new_tokens=c)
-    server.run()
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda event, _secs, **_: compiles.append(event)
@@ -414,17 +450,14 @@ def test_the_scope_maps_are_the_self_drafting_programs_that_ran(toy, program,
         assert {"mtp", "mtp/experts", "mtp/shared", "mtp/kv_append",
                 "moe/shared", "moe/experts", "attn_window/kv_append"} \
             <= scopes, sorted(scopes)
-    obs.programs.reset(clear_static=True)
 
 
-def test_a_sampling_servers_key_split_is_a_program_with_a_map(toy):
+def test_a_sampling_servers_key_split_is_a_program_with_a_map(flat):
     """Every tick of a sampling server splits its key on the device: one
     named program, read off the executable that ran, so that a traced cell's
     busy time is joined to a map to the last tick."""
-    sym, _ = toy
     obs.programs.reset(clear_static=True)
-    _, server = make_server((sym, toy_params(sym, head_std=0.02)), 1,
-                            temperature=1.0)
+    _, server = make_server(flat, 1, temperature=1.0, fresh=True)
     for p, c in zip(PROMPTS, CAPS):
         server.submit(p, max_new_tokens=c)
     server.run()

@@ -66,6 +66,16 @@ def system_probs(sym, params, toks):
     return ex.outputs[0].data
 
 
+def padded_rows(seqs):
+    """Sequences of several lengths as the rows of one batch, zeros behind
+    their ends: a causal model's positions do not see their padding, so one
+    pass over the batch is one pass over each."""
+    batch = np.zeros((len(seqs), max(s.size for s in seqs)), seqs[0].dtype)
+    for row, seq in zip(batch, seqs):
+        row[:seq.size] = seq
+    return batch
+
+
 @pytest.fixture(scope="module")
 def toy():
     cfg = toy_config()
@@ -73,6 +83,22 @@ def toy():
     toks = np.random.default_rng(0).integers(0, cfg["vocab_size"],
                                              size=(1, T))
     return cfg, sym, params, toks, system_probs(sym, params, toks)
+
+
+_SHARED = {}
+
+
+def shared(sym, params, kv_dtype=""):
+    """The toy's paged predictor of one cache type, built once: what it
+    compiled serves every test that does not count its traces (a server,
+    like ``prefill``, opens fresh pools and a fresh manager over it)."""
+    key = (id(params), kv_dtype)
+    if key not in _SHARED:
+        nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
+        _SHARED[key] = (params, DecodePredictor(
+            sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
+            page_tokens=PAGE, kv_dtype=kv_dtype, prefill_chunk=CHUNK))
+    return _SHARED[key][1]
 
 
 def test_pattern_and_shapes(toy):
@@ -229,9 +255,7 @@ def test_server_matches_generate_and_counts_moe_rows(toy):
     """The serving loop over both groups gives each request the tokens of
     its own ``generate``; the MoE counters count (token, choice) pairs."""
     cfg, sym, params, _, _ = toy
-    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
-    pred = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
-                           page_tokens=PAGE, prefill_chunk=CHUNK)
+    pred = shared(sym, params)
     server = DecodeServer(pred, max_prefill=32, slots=3, spec_k=0)
     rng = np.random.default_rng(3)
     prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 26, 9, 30)]
@@ -249,10 +273,8 @@ def test_server_matches_generate_and_counts_moe_rows(toy):
     before, seen = held_rows(), len(noted())
     rids = [server.submit(p, max_new_tokens=12) for p in prompts]
     results = server.run()
-    alone = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
-                            page_tokens=PAGE, prefill_chunk=CHUNK)
     for rid, p in zip(rids, prompts):
-        want = alone.generate(p[None].astype(np.float32), p.size,
+        want = pred.generate(p[None].astype(np.float32), p.size,
                               max_new_tokens=12)[0]
         assert np.array_equal(results[rid], want), rid
     after = held_rows()
@@ -292,25 +314,19 @@ def test_reading_behind_gives_the_tokens_of_reading_first(toy, kv_dtype,
     from mxnet_tpu.test_utils import check_reading_behind
 
     cfg, sym, params, _, _ = toy
-    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 26, 9, 30, 12)]
 
     def make_server(eos_id):
-        pred = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(),
-                               paged=True, page_tokens=PAGE,
-                               kv_dtype=kv_dtype, prefill_chunk=CHUNK)
-        return DecodeServer(pred, max_prefill=32, slots=2, spec_k=0,
-                            eos_id=eos_id)
+        return DecodeServer(shared(sym, params, kv_dtype), max_prefill=32,
+                            slots=2, spec_k=0, eos_id=eos_id)
 
     check_reading_behind(make_server, prompts, (9, 3, 12, 1, 6, 8), eos)
 
 
 def test_what_a_ring_cannot_carry_is_refused_by_name(toy):
     cfg, sym, params, _, _ = toy
-    nd = {n: mx.nd.NDArray(v, mx.cpu()) for n, v in params.items()}
-    pred = DecodePredictor(sym, nd, cache_len=64, ctx=mx.cpu(), paged=True,
-                           page_tokens=PAGE, prefill_chunk=CHUNK)
+    pred = shared(sym, params)
     assert pred.has_window_group
     # a verify step of k + 1 rows needs that many ring positions beyond the
     # window (PR 46): this ring of 16 has 8, and k = 8 is refused by name
@@ -467,7 +483,7 @@ def _nodes(sym):
 def test_a_graph_with_defaults_has_the_parents_node_list():
     """The new arguments at their defaults change nothing: the nodes of a
     one-layer graph with a per-head q/k norm, in order and by name, as the
-    tree before PR 57 built them (``benchmarks/runs/pr57_hashes.py`` holds
+    tree before PR 57 built them (``benchmarks/runs/hashes.py`` holds
     the accepted cells' serving programs to the parent's text)."""
     from mxnet_tpu.base import NameManager
     from mxnet_tpu.models import decoder_lm

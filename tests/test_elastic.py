@@ -332,8 +332,10 @@ def test_async_checkpoint_overlaps_and_stalls_less_than_sync(tmp_path):
     assert stats_async["checkpoint_stall_fraction"] < \
         stats_sync["checkpoint_stall_fraction"], (stats_async, stats_sync)
     # both runs produced resumable state and the accounting fields exist
-    assert stats_sync["last_ckpt_ms"] > 0
-    assert stats_async["recoveries"] == 0
+    assert stats_sync["last_ckpt_ms"] > 0 and stats_async["last_ckpt_ms"] > 0
+    assert stats_async["recoveries"] == stats_sync["recoveries"] == 0
+    for stats in (stats_async, stats_sync):
+        assert 0.0 <= stats["checkpoint_stall_fraction"] <= 1.0, stats
 
 
 # ---------------------------------------------------------------------------

@@ -85,6 +85,19 @@ def predictor(sym, params, kv_dtype="", **kw):
         kv_dtype=kv_dtype, prefill_chunk=CHUNK, **kw)
 
 
+_SHARED = {}
+
+
+def shared(sym, params, kv_dtype=""):
+    """The toy's predictor of one cache type, built once: what it compiled
+    serves every test that neither counts its traces nor patches it (a
+    server, like ``prefill``, opens fresh pools and a fresh manager)."""
+    key = (id(params), kv_dtype)
+    if key not in _SHARED:
+        _SHARED[key] = (params, predictor(sym, params, kv_dtype))
+    return _SHARED[key][1]
+
+
 @pytest.fixture(scope="module")
 def toy():
     cfg = toy_config()
@@ -196,12 +209,16 @@ def test_server_logits_match_the_reference(toy, kv_dtype, atol):
     caps = (12, 7, 9, 12, 5)
     rids = [server.submit(p, max_new_tokens=c) for p, c in zip(prompts, caps)]
     results = server.run()
-    for rid, p, cap in zip(rids, prompts, caps):
-        toks = results[rid]
-        assert len(toks) == cap == len(seen[rid])
-        seq = np.concatenate([p, toks[:-1]])[None]
-        want = ref.forward(params, cfg, seq)[0, p.size - 1:]
-        out = correct.compare_logp(jnp.stack(seen[rid]), want, atol)
+    from test_decoder_lm import padded_rows
+
+    # the five sequences as the rows of one padded batch
+    seqs = [np.concatenate([p, results[rid][:-1]])
+            for rid, p in zip(rids, prompts)]
+    wants = ref.forward(params, cfg, padded_rows(seqs))
+    for rid, p, cap, seq, want in zip(rids, prompts, caps, seqs, wants):
+        assert len(results[rid]) == cap == len(seen[rid])
+        out = correct.compare_logp(jnp.stack(seen[rid]),
+                                   want[p.size - 1:seq.size], atol)
         assert out["ok"] and out["positions"] == cap, (rid, out)
     # one trace of each program served every chunk, every step, every slot
     assert pred.trace_counts["chunk"] == 1
@@ -230,7 +247,7 @@ def test_reading_behind_gives_the_tokens_of_reading_first(toy, kv_dtype,
     prompts = [rng.integers(0, 96, size=n) for n in (5, 19, 27, 9, 30, 12)]
 
     def make_server(eos_id):
-        return DecodeServer(predictor(sym, params, kv_dtype),
+        return DecodeServer(shared(sym, params, kv_dtype),
                             max_prefill=32, slots=2, spec_k=0, eos_id=eos_id)
 
     check_reading_behind(make_server, prompts, (9, 3, 12, 1, 6, 8), eos)
@@ -244,7 +261,7 @@ def test_the_benchmarks_comparison_at_a_toy_size(toy):
     traffic = {"slots": 4, "check_prompt": 21, "check_decode": 4}
     for kv_dtype, atol in (("", FLOAT_ATOL), ("int8", INT8_ATOL)):
         out = serve_ticks.check_against_reference(
-            predictor(sym, params, kv_dtype), cfg, traffic, params, 5, atol)
+            shared(sym, params, kv_dtype), cfg, traffic, params, 5, atol)
         assert out[0]["ok"] and out[0]["positions"] == 5, out
 
 
@@ -259,7 +276,7 @@ def test_a_masked_slots_state_is_bit_identical_across_a_decode_tick(toy):
     middle of its chunked prefill is), slot 2 is empty: one decode tick
     changes slot 0's rows and no bit of the others'."""
     cfg, sym, params, toks, _ = toy
-    pred = predictor(sym, params)
+    pred = shared(sym, params)
     both = np.zeros((3, 20), np.float32)
     both[0], both[1] = toks[0, :20], toks[0, 20:]
     state, _ = pred.prefill(both, np.array([20, 13, 1]))
@@ -275,7 +292,7 @@ def test_a_masked_slots_state_is_bit_identical_across_a_decode_tick(toy):
     # those of a predictor that never ticked in between
     state, probs = pred.paged_step(state, lens + [1, 0, 0],
                                    active=np.array([0, 1, 0]))
-    alone = predictor(sym, params)
+    alone = pred                    # a fresh state of one row
     state1, _ = alone.prefill(both[1:2], np.array([13]))
     _, want = alone.step(state1)
     # (another batch shape: equal up to the order of the products' sums)
@@ -309,7 +326,7 @@ def test_zeroing_the_carried_state_between_chunks_fails_the_limit(toy,
     in chunks of 8 with the state group's rows zeroed after the second chunk
     reads far outside the tolerance (and inside it when left alone)."""
     cfg, sym, params, toks, _ = toy
-    pred = predictor(sym, params)
+    pred = shared(sym, params)
     state = pred.paged_batch_state(1)
     mgr = pred._manager
     prompt = toks[0, :PROMPT].astype(np.int64)
@@ -332,7 +349,7 @@ def test_zeroing_the_carried_state_between_chunks_fails_the_limit(toy,
 
 def test_what_a_state_row_cannot_carry_is_refused_by_name(toy):
     cfg, sym, params, toks, _ = toy
-    pred = predictor(sym, params)
+    pred = shared(sym, params)
     assert not pred.has_window_group
     assert [g.kind for g in pred.unshared_groups] == ["state"]
     with pytest.raises(MXNetError, match="'state' cache group.*rejected "
@@ -394,19 +411,10 @@ def test_serving_avals_and_pool_bytes_know_the_state_group(toy):
     assert half.state_row_bytes() == 3 * (4 * 3 * 96 + 2 * 4 * 16 * 8)
 
 
-@pytest.mark.parametrize("program,stem", [
-    ("paged_decode_step", "jit__paged_decode_impl"),
-    ("prefill_chunk", "jit__chunk_impl")])
-def test_the_maps_are_the_programs_the_loop_runs_from_its_second_tick(
-        toy, program, stem):
-    """With bfloat16 weights a mixer's conv tail is allocated bfloat16 and
-    comes back float32 from every block after the first, so jax traces a
-    second decode and a second chunk program on a session's second tick,
-    and those run from then on.  ``obs.programs``' maps must be theirs, not
-    the first dispatch's (which ran once): read with nothing compiled, and
-    with the entry parameters of the state the loop really carries."""
-    import re
-
+@pytest.fixture(scope="module")
+def bfloat16_served(toy):
+    """A predictor over bfloat16 weights that has served four requests
+    through three slots, and the shapes of the caches its loop carries."""
     from mxnet_tpu import obs
     from mxnet_tpu.analysis.hlo_parse import shape_str
 
@@ -423,8 +431,27 @@ def test_the_maps_are_the_programs_the_loop_runs_from_its_second_tick(
     ps = server.serve_open()
     while server.has_work:
         server.serve_tick()
-    live = sorted(shape_str(a.shape, a.dtype)
-                  for a in jax.tree_util.tree_leaves(ps["state"].caches))
+    yield pred, sorted(shape_str(a.shape, a.dtype) for a in
+                       jax.tree_util.tree_leaves(ps["state"].caches))
+    obs.programs.reset(clear_static=True)
+
+
+@pytest.mark.parametrize("program,stem", [
+    ("paged_decode_step", "jit__paged_decode_impl"),
+    ("prefill_chunk", "jit__chunk_impl")])
+def test_the_maps_are_the_programs_the_loop_runs_from_its_second_tick(
+        bfloat16_served, program, stem):
+    """With bfloat16 weights a mixer's conv tail is allocated bfloat16 and
+    comes back float32 from every block after the first, so jax traces a
+    second decode and a second chunk program on a session's second tick,
+    and those run from then on.  ``obs.programs``' maps must be theirs, not
+    the first dispatch's (which ran once): read with nothing compiled, and
+    with the entry parameters of the state the loop really carries."""
+    import re
+
+    from mxnet_tpu import obs
+
+    _, live = bfloat16_served
     compiles = []
     jax.monitoring.register_event_duration_secs_listener(
         lambda event, _secs, **_: compiles.append(event)
@@ -437,4 +464,3 @@ def test_the_maps_are_the_programs_the_loop_runs_from_its_second_tick(
     assert carried == live
     assert obs.programs.scope_map(program) == {
         k: v["scope"] for k, v in entry["instructions"].items()}
-    obs.programs.reset(clear_static=True)

@@ -7,6 +7,7 @@ Tolerance 2e-5 on outputs of order 1: everything is float32 on the CPU, the
 forms differ in the order of their sums (the chunked form solves a triangular
 system a block where the recurrence corrects the state token by token).
 """
+import functools
 import sys
 
 import jax
@@ -46,6 +47,14 @@ def carried(b, seed=2):
     r = np.random.default_rng(seed)
     return (jnp.asarray(r.normal(size=(b, K - 1, 3 * W)), jnp.float32),
             jnp.asarray(r.normal(size=(b, H, D, D)), jnp.float32))
+
+
+# ``kda.mix`` as ONE program a signature, as a serving program holds it (an
+# eager call compiles each of its primitives apart at every new shape): for
+# the tests that compare values with the recurrence at this file's 8 x 8
+# heads.  Those that compare bits, patch the module or read what a call left
+# behind (``STEP_PATH``, the dispatch counter) call ``kda.mix`` itself.
+mix = jax.jit(functools.partial(kda.mix, ATTRS))
 
 
 def cut(xs, lo, hi):
@@ -96,9 +105,9 @@ def by_chunks(xs, w, sizes, width, state=None):
     for n in sizes:
         part = tuple(jnp.pad(x[:, pos:pos + n],
                              ((0, 0), (0, width - n), (0, 0))) for x in xs)
-        out, state, _ = kda.mix(ATTRS, *part, *w, state=state,
-                                pos0=jnp.full((b,), pos, jnp.int32),
-                                nvalid=jnp.full((b,), n, jnp.int32))
+        out, state, _ = mix(*part, *w, state=state,
+                            pos0=jnp.full((b,), pos, jnp.int32),
+                            nvalid=jnp.full((b,), n, jnp.int32))
         outs.append(out[:, :n])
         pos += n
     return jnp.concatenate(outs, 1), state
@@ -130,7 +139,7 @@ def close(a, b, atol=ATOL):
 def test_a_whole_sequence_is_the_recurrence(t):
     xs, w = streams(2, t), weights()
     want, s = plain(xs, w)
-    got, (tail, state), rows = kda.mix(ATTRS, *xs, *w)
+    got, (tail, state), rows = mix(*xs, *w)
     assert close(got, want) and close(state, s) and int(rows) == 2
     assert float(jnp.max(jnp.abs(want))) > 0.5
     # the tail is the last K - 1 rows of [query | key | value], zeros before
@@ -166,18 +175,18 @@ def test_chunks_carry_state_and_tail_across_their_edges(sizes, width):
     want, s = plain(xs, w)
     got, (tail, state) = by_chunks(xs, w, sizes, width)
     assert close(got, want) and close(state, s)
-    whole_tail = kda.mix(ATTRS, *xs, *w)[1][0]
+    whole_tail = mix(*xs, *w)[1][0]
     assert np.array_equal(np.asarray(tail), np.asarray(whole_tail))
     # a tail not carried shows at once: the second chunk from a zero tail
     if len(sizes) > 1:
         first = sizes[0]
-        _, st, _ = kda.mix(ATTRS, *cut(xs, 0, first), *w)
+        _, st, _ = mix(*cut(xs, 0, first), *w)
         nxt = cut(xs, first, first + sizes[1])
         args = dict(pos0=jnp.full((2,), first, jnp.int32),
                     nvalid=jnp.full((2,), sizes[1], jnp.int32))
-        kept, _, _ = kda.mix(ATTRS, *nxt, *w, state=st, **args)
-        lost, _, _ = kda.mix(ATTRS, *nxt, *w,
-                             state=(jnp.zeros_like(st[0]), st[1]), **args)
+        kept, _, _ = mix(*nxt, *w, state=st, **args)
+        lost, _, _ = mix(*nxt, *w,
+                         state=(jnp.zeros_like(st[0]), st[1]), **args)
         assert close(kept, want[:, first:first + sizes[1]])
         assert not close(lost[:, :1], kept[:, :1], 1e-3)
 
@@ -309,7 +318,7 @@ def test_log_decays_of_minus_twenty_a_step_stay_finite(form):
     w[1], w[2] = jnp.zeros((H,), jnp.float32), jnp.zeros((W,), jnp.float32)
     want, s = plain(xs, w)
     if form == "sequence":
-        got, (_, state), _ = kda.mix(ATTRS, *xs, *w)
+        got, (_, state), _ = mix(*xs, *w)
     elif form == "chunks":
         got, (_, state) = by_chunks(xs, w, (64, 6), 64)
     else:
@@ -347,7 +356,7 @@ def test_the_registered_op_infers_its_shapes_and_differentiates():
     assert outs == [shape]
     xs, w = streams(2, 12), weights()
     loss = lambda q: jnp.sum(kda.mix(ATTRS, q, *xs[1:], *w)[0] ** 2)
-    grad = jax.grad(loss)(xs[0])
+    grad = jax.jit(jax.grad(loss))(xs[0])
     assert grad.shape == shape and bool(jnp.all(jnp.isfinite(grad))) \
         and float(jnp.abs(grad).max()) > 0
     with pytest.raises(ValueError, match="not \\(B, T"):

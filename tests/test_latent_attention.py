@@ -312,14 +312,26 @@ def test_absorbed_is_expanded_to_float32_rounding(monkeypatch, path, rank,
 # ---------------------------------------------------------------------------
 # every planted fault fails the tolerance
 # ---------------------------------------------------------------------------
+def _patched():
+    """What ``probe.planted`` patches, as it stands."""
+    from mxnet_tpu.models import decoder_lm
+
+    return [getattr(attn, n) for n in (
+        "latent_spec", "latent_query_scale", "latent_attend",
+        "latent_rotate")] + [decoder_lm.sym.RMSNorm]
+
+
 @pytest.mark.parametrize("on_path", PATHS, indirect=True)
-@pytest.mark.parametrize("fault", probe.FAULTS)
+@pytest.mark.parametrize("fault", probe.FAULTS + ("sound",))
 def test_a_planted_fault_fails(on_path, fault):
     """Each fault the probe plants in the serving programs on the chip, here:
     chunks and decode rows under it are not the reference's (ten times the
     tolerance at the least), whether the decode rows take the walk or the
-    kernel."""
+    kernel; the module is as it was when the fault is lifted, and after the
+    last of them a graph built and traced anew (``"sound"``, which plants
+    nothing) serves the reference's rows."""
     (cfg, _, params, toks, want), row_form = on_path
+    before = _patched()
     with probe.planted(fault):
         sym = harness.build_symbol(cfg)
         served_params = probe.coarse(params) if fault == "fp8_weights" \
@@ -328,11 +340,13 @@ def test_a_planted_fault_fails(on_path, fault):
         got = served(pred, toks)
     assert pred._decode_paths[1] == {row_form}
     check = correct.compare_logp(got, want[PROMPT - 1:T - 1], ATOL)
-    assert check["max_abs_dlogp"] > 10 * ATOL, (fault, check)
+    if fault == "sound":
+        assert check["ok"], check
+    else:
+        assert check["max_abs_dlogp"] > 10 * ATOL, (fault, check)
     # and the module is as it was
     assert attn.latent_attend.__module__ == attn.__name__
-    sound = served(predictor(harness.build_symbol(cfg), params), toks[:, :T])
-    assert correct.compare_logp(sound, want[PROMPT - 1:T - 1], ATOL)["ok"]
+    assert all(a is b for a, b in zip(_patched(), before))
 
 
 # ---------------------------------------------------------------------------
@@ -400,11 +414,11 @@ def test_a_shared_prefix_forks_pages_of_a_latent_group(toy):
     stats = server.stats()
     assert stats["prefix_cache_hits"] >= 1 and stats["cow_forks"] >= 1
     for rid, prompt in zip(rids, prompts):
-        seq = list(prompt)
-        for tok in results[rid]:
-            logits = ref.forward(params, cfg, np.asarray(seq)[None, :])[0, -1]
-            assert int(jnp.argmax(logits)) == int(tok)
-            seq.append(int(tok))
+        # one pass over the finished sequence gives every position's argmax
+        seq = np.concatenate([prompt, results[rid]]).astype(np.int64)
+        logits = ref.forward(params, cfg, seq[None, :-1])[0]
+        assert [int(t) for t in jnp.argmax(logits[len(prompt) - 1:], -1)] \
+            == [int(t) for t in results[rid]]
 
 
 def test_publishing_a_long_prompt_costs_by_its_pages():

@@ -213,6 +213,6 @@ def test_the_registered_op_infers_its_shapes_and_differentiates():
     assert args == [shape] * 4 + [(D,), (D,), (H * D,)] and outs == [shape]
     xs, w = streams(2, 12), weights()
     loss = lambda q: jnp.sum(linattn.mix(ATTRS, q, *xs[1:], *w)[0] ** 2)
-    grad = jax.grad(loss)(xs[0])
+    grad = jax.jit(jax.grad(loss))(xs[0])
     assert grad.shape == shape and bool(jnp.all(jnp.isfinite(grad))) \
         and float(jnp.abs(grad).max()) > 0

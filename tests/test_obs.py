@@ -292,6 +292,14 @@ def _decode_artifact():
     return pred.decode_artifact(state)
 
 
+def _program_spans():
+    """How many ``cat="program"`` spans the timeline holds, by name."""
+    import collections
+
+    return collections.Counter(e["name"] for e in obs.timeline.events()
+                               if e["cat"] == "program")
+
+
 def _without_source_locations(hlo):
     """Compiled HLO embeds python source locations (four header tables
     plus a ``stack_frame_id`` per op).  The telemetry wrapper dispatches
@@ -332,10 +340,9 @@ def test_instrumentation_is_free_hlo_byte_identical(telemetry):
     assert report.ok(), report.format_text()
     assert all(f.severity == "info" for f in report.findings), \
         report.format_text()
-    # both programs really were instrumented: their dispatch wall landed
-    # in the roofline accounting while telemetry was on
-    rows = {r["program"] for r in obs.programs.table()}
-    assert {"train_step", "decode_step"} <= rows
+    # both programs really were instrumented: each dispatch left its
+    # span on the timeline while telemetry was on
+    assert {"train_step", "decode_step"} <= set(_program_spans())
 
 
 def test_telemetry_off_records_nothing(telemetry):
@@ -745,7 +752,8 @@ def test_two_predictors_keep_their_own_maps_and_count_the_conflict(
     alone = dict(obs.programs.scope_maps()[stem])
     assert obs.programs.instruction_maps()[stem]["conflicts"] == 0
     second = serve(3)
-    with caplog.at_level(logging.WARNING, logger="mxnet_tpu.obs.roofline"):
+    with caplog.at_level(logging.WARNING,
+                         logger="mxnet_tpu.obs.program_maps"):
         entry = obs.programs.instruction_maps()[stem]
         obs.programs.instruction_maps()
     # the newer predictor's program, whole: not the two merged
@@ -798,6 +806,83 @@ def test_programs_beside_the_steps_have_maps_after_a_serve(program, stem,
     assert "jit__extract_impl" not in maps and "jit__install_impl" not in maps
     assert obs.programs.scope_map("page_extract") is None
     assert not compiles
+    obs.programs.reset(clear_static=True)
+
+
+def _small_fit(num_epoch=2):
+    net = mx.sym.SoftmaxOutput(mx.sym.FullyConnected(
+        mx.sym.Variable("data"), num_hidden=4), name="softmax")
+    rng = np.random.RandomState(0)
+    it = mx.io.NDArrayIter(rng.uniform(-1, 1, (12, 8)).astype(np.float32),
+                           rng.randint(0, 4, (12,)).astype(np.float32),
+                           batch_size=4)
+    mod = mx.mod.Module(net, context=mx.cpu())
+    mod.fit(it, num_epoch=num_epoch, optimizer="sgd")
+    return mod
+
+
+def test_every_dispatch_leaves_a_program_span_and_a_reader(telemetry):
+    """After a fit and a serve every dispatched step left a
+    ``cat="program"`` span under its name, as many as were dispatched,
+    and ``obs.programs`` holds HLO readers and nothing else: no timing,
+    no static cost, no table."""
+    from mxnet_tpu.decode import DecodePredictor
+    from mxnet_tpu.obs.program_maps import _Registered
+
+    telemetry(True)
+    obs.programs.reset(clear_static=True)
+    obs.timeline.clear()
+    mod = _small_fit()
+    pred, server = _paged_server()
+    rng = np.random.RandomState(4)
+    for n in (5, 7, 3):
+        server.submit(rng.randint(0, 32, size=(n,)))
+    assert len(server.run()) == 3
+    spans = _program_spans()
+    # three batches an epoch, two epochs; a chunk a ``serve.prefill``
+    assert spans["train_step"] == mod._fused_step.num_steps == 6
+    assert spans["paged_decode_step"] == server.steps > 0
+    assert spans["prefill"] == sum(e["name"] == "serve.prefill"
+                                   for e in obs.timeline.events()) > 0
+    assert set(vars(obs.programs)) == {"_lock", "_hlo", "_registered",
+                                       "_conflicts_logged"}
+    assert all(isinstance(r, _Registered)
+               for r in obs.programs._hlo.values())
+    assert {name for name, _ in obs.programs._hlo} == {
+        "train_step", "paged_decode_step", "prefill_chunk"} \
+        | set(DecodePredictor._BESIDE)
+    assert not hasattr(obs.programs, "table")
+    del mod, pred, server
+    obs.programs.reset(clear_static=True)
+
+
+@pytest.mark.parametrize("kind", ["step", "predictor"])
+def test_a_collected_owner_is_not_pinned_by_the_readers(kind, telemetry):
+    """A fused step (with its module's master weights) or a predictor
+    (its parameters and snapped programs) that nothing else holds is
+    collected although the process-wide readers know its programs: a
+    reader's thunk names its owner weakly."""
+    import gc
+    import weakref
+
+    telemetry(True)
+    obs.programs.reset(clear_static=True)
+    if kind == "step":
+        holder = _small_fit(num_epoch=1)
+        owner, name = holder._fused_step, "train_step"
+    else:
+        owner, holder = _paged_server()
+        holder.submit(np.arange(5))
+        assert len(holder.run()) == 1
+        name = "paged_decode_step"
+    assert (name, id(owner)) in obs.programs._hlo
+    ref = weakref.ref(owner)
+    del owner, holder
+    gc.collect()
+    assert ref() is None
+    # its record goes with it at the next reading
+    assert obs.programs.scope_map(name) is None
+    assert not obs.programs._hlo and not obs.programs.scope_maps()
     obs.programs.reset(clear_static=True)
 
 

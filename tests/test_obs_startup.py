@@ -425,7 +425,7 @@ def test_one_listener_of_each_kind():
     import jax
     from jax._src import monitoring
 
-    from mxnet_tpu.obs import roofline
+    from mxnet_tpu.obs import program_maps
 
     mine = lambda fs: [f for f in fs
                        if f.__module__.startswith("mxnet_tpu")]
@@ -434,21 +434,21 @@ def test_one_listener_of_each_kind():
     assert mine(monitoring.get_event_duration_listeners()) \
         == [startup._on_duration]
     assert mine(monitoring.get_event_listeners()) == [startup._on_event]
-    assert not hasattr(roofline, "_on_compile")
+    assert not hasattr(program_maps, "_on_compile")
     # the map readers' count is the same listener's
-    before = roofline._backend_compiles()
+    before = program_maps._backend_compiles()
     jax.jit(lambda x: x * 3 + 1)(np.arange(5.0))
-    assert roofline._backend_compiles() == before + 1
+    assert program_maps._backend_compiles() == before + 1
 
 
 def test_telemetry_off_records_none_of_it(telemetry):
     import jax
 
-    from mxnet_tpu.obs import roofline
+    from mxnet_tpu.obs import program_maps
 
     telemetry(False)
     before = (obs.registry.snapshot(), len(obs.timeline),
-              roofline._backend_compiles())
+              program_maps._backend_compiles())
     with obs.phase("build.test_off"):
         with obs.top_span("serve.tick", cat="serve"):
             with obs.program_span("test_off"):
@@ -456,4 +456,4 @@ def test_telemetry_off_records_none_of_it(telemetry):
     assert obs.phased("build.test_off")(lambda: 3)() == 3
     assert (obs.registry.snapshot(), len(obs.timeline)) == before[:2]
     # the map readers still learn that something compiled
-    assert roofline._backend_compiles() == before[2] + 1
+    assert program_maps._backend_compiles() == before[2] + 1

@@ -919,6 +919,21 @@ def test_paged_int8_pools_under_a_mesh_replicate_the_scale_plane():
                                    rtol=1e-4, atol=1e-5)
 
 
+def _each_is_the_argmax_of_one_pass(system_probs, sym, params, prompts,
+                                    answers):
+    """Every answer is the arg max of ONE whole forward pass over its own
+    sequence, at every decoded position: the sequences as rows of one
+    padded batch."""
+    from test_decoder_lm import padded_rows
+
+    seqs = [np.concatenate([p, a[:-1]]) for p, a in zip(prompts, answers)]
+    batch = padded_rows(seqs)
+    probs = np.asarray(system_probs(sym, params, batch))
+    probs = probs.reshape(batch.shape + probs.shape[-1:])   # (B * T, V)
+    for row, p, seq, answer in zip(probs, prompts, seqs, answers):
+        assert np.array_equal(row[p.size - 1:seq.size].argmax(-1), answer)
+
+
 # ---------------------------------------------------------------------------
 # a state group of two-leaf rows, a full group of int8 pages and held experts
 # in one graph (``solar_open2``'s keys at a toy size)
@@ -970,11 +985,9 @@ def test_delta_rows_beside_pages_and_held_experts_serve_as_one_pass(
         want = alone.generate(p[None].astype(np.float32), p.size,
                               max_new_tokens=10)[0]
         assert np.array_equal(results[rid], want), rid
-        if kv_dtype:
-            continue    # int8 keys move a near-tie; float pools are exact
-        seq = np.concatenate([p, results[rid][:-1]])[None]
-        probs = np.asarray(system_probs(sym, params, seq))
-        assert np.array_equal(probs[p.size - 1:].argmax(-1), results[rid])
+    if not kv_dtype:    # int8 keys move a near-tie; float pools are exact
+        _each_is_the_argmax_of_one_pass(system_probs, sym, params, prompts,
+                                        [results[rid] for rid in rids])
     rows = [a["kda_rows"] for a in noted()[seen:] if "kda_rows" in a]
     assert rows and set(rows) <= {3, 6}
     assert sum(rows) == 3 * len(prompts) * (10 - 1)
@@ -1055,11 +1068,9 @@ def test_gated_deltanet_rows_beside_padded_int8_pages_serve_as_one_pass(
         want = alone.generate(p[None].astype(np.float32), p.size,
                               max_new_tokens=10)[0]
         assert np.array_equal(results[rid], want), rid
-        if kv_dtype:
-            continue    # int8 keys move a near-tie; float pools are exact
-        seq = np.concatenate([p, results[rid][:-1]])[None]
-        probs = np.asarray(system_probs(sym, params, seq))
-        assert np.array_equal(probs[p.size - 1:].argmax(-1), results[rid])
+    if not kv_dtype:    # int8 keys move a near-tie; float pools are exact
+        _each_is_the_argmax_of_one_pass(system_probs, sym, params, prompts,
+                                        [results[rid] for rid in rids])
     rows = [a["gdn_rows"] for a in noted()[seen:] if "gdn_rows" in a]
     assert rows and set(rows) <= {3, 6}
     assert sum(rows) == 3 * len(prompts) * (10 - 1)
@@ -1147,11 +1158,9 @@ def test_stateless_layers_between_stateful_ones_serve_as_one_pass(
         want = alone.generate(p[None].astype(np.float32), p.size,
                               max_new_tokens=10)[0]
         assert np.array_equal(results[rid], want), rid
-        if kv_dtype:
-            continue    # int8 keys move a near-tie; float pools are exact
-        seq = np.concatenate([p, results[rid][:-1]])[None]
-        probs = np.asarray(system_probs(sym, params, seq))
-        assert np.array_equal(probs[p.size - 1:].argmax(-1), results[rid])
+    if not kv_dtype:    # int8 keys move a near-tie; float pools are exact
+        _each_is_the_argmax_of_one_pass(system_probs, sym, params, prompts,
+                                        [results[rid] for rid in rids])
     notes = [a for a in noted()[seen:] if "ssm_rows" in a]
     assert notes and {a["ssm_rows"] for a in notes} <= {2, 4}
     assert sum(a["ssm_rows"] for a in notes) == 2 * len(prompts) * (10 - 1)

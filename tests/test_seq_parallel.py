@@ -581,6 +581,7 @@ def test_ring_tp_gradient_finite_difference():
         mesh=mesh, in_specs=(spec,) * 3, out_specs=spec, check_vma=False)
     w = rng.normal(size=(b, t, e))
 
+    @jax.jit                # one program for the six evaluations below
     def loss(q_, k_, v_):
         return jnp.sum(ring(q_, k_, v_) * w)
 

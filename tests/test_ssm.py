@@ -173,7 +173,7 @@ def test_the_registered_op_infers_its_shapes_and_differentiates():
     from mxnet_tpu.registry import OpContext
 
     f = lambda *xs: op.fcompute(attrs, list(xs), [], OpContext())[0][0].sum()
-    grads = jax.grad(f, argnums=tuple(range(7)))(data, *w)
+    grads = jax.jit(jax.grad(f, argnums=tuple(range(7))))(data, *w)
     assert all(np.isfinite(g).all() and float(jnp.abs(g).max()) > 0
                for g in grads)
     with pytest.raises(ValueError, match="input width"):

@@ -33,7 +33,8 @@ drift               priced quantities (FLOPs, collective/cache bytes,
                     donation map) vs a recorded snapshot (``--check``)
 ==================  =====================================================
 
-Output follows the bench.py contract: ONE json line on stdout —
+Output follows the benches' contract (:func:`contract_line`): ONE json
+line on stdout —
 ``{"metric": "mxlint_unsuppressed_findings", "value", "unit",
 "vs_baseline", ...}`` — with per-finding detail on stderr in the
 ``--format`` of choice (default ``jsonl``: one json object per line).
@@ -85,6 +86,16 @@ if os.environ.get("JAX_PLATFORMS", "") == "cpu" and \
     os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
                                + " --xla_force_host_platform_device_count=8"
                                ).strip()
+
+
+def contract_line(metric, value, unit, vs_baseline, **extra):
+    """The one-line stdout JSON contract the benches and this CLI emit,
+    so CI consumes one schema:
+    {"metric", "value", "unit", "vs_baseline", ...extras}."""
+    row = {"metric": metric, "value": value, "unit": unit,
+           "vs_baseline": vs_baseline}
+    row.update(extra)
+    return json.dumps(row)
 
 
 def _parse_args(argv):
@@ -173,7 +184,6 @@ def main(argv=None):
     import mxnet_tpu.analysis.programs  # noqa: F401 — registers the
     # canonical builder groups with the program registry; --list,
     # --programs and the audit below all enumerate the registry
-    import bench as _bench
 
     if args.list_only:
         for name in progreg.canonical_names():
@@ -246,9 +256,9 @@ def main(argv=None):
         for f in report.findings:
             print(json.dumps(f.to_dict()), file=sys.stderr)
 
-    # schedule/drift aggregates for the bench contract line — mxstat
-    # --diff flattens these, so overlap structure and drift state ride
-    # the same trend lines as the byte ceilings
+    # schedule/drift aggregates for the bench contract line, so overlap
+    # structure and drift state ride the same trend lines as the byte
+    # ceilings
     sched = {"pairs": 0, "unpaired": 0, "serialized": 0}
     for art in artifacts:
         if art.compiled_text is not None:
@@ -261,7 +271,7 @@ def main(argv=None):
 
     s = report.summary()
     unsup = len(report.unsuppressed)
-    print(_bench.contract_line(
+    print(contract_line(
         "mxlint_unsuppressed_findings", unsup, "findings",
         1.0 if unsup == 0 else 0.0,
         errors=s["errors"], warnings=s["warnings"],
