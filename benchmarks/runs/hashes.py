@@ -1,4 +1,4 @@
-"""Do two trees lower to the same programs?  Three parts, a hash a line.
+"""Do two trees lower to the same programs?  Four parts, a hash a line.
 
 The serving programs (decode and chunk; exaone's verify and two self-drafting
 ones) of every serving configuration at its test's toy size: caches of 1024
@@ -16,6 +16,12 @@ The decode row of each serving cell's first node that takes the kernel
 through ``paged_attend`` AT THE CELL'S OWN SHAPES, on a backend that is told
 it runs Pallas: a hash of the jaxpr (the ``pallas_call``'s body in it is what
 Mosaic lowers; no source locations), with the ``Tiles`` the rule gave.
+
+The latent node of ``mistral4_serve_longdoc`` AT THE CELL'S OWN SHAPES, the
+same way (PR 62): its decode row (20 slots, one row) and its prefill chunk
+(one slot, 2048 rows) through ``latent_attend``, with the form each took.
+The toy above cannot show them: its widths tile for neither kernel and its
+chunk is 64 rows, the absorbed walk.
 
 Run it over the parent's tree and over the change's, BOTH UNPACKED AT ONE
 PATH in turn (a Mosaic kernel's body carries its checkout's path), and
@@ -219,3 +225,32 @@ print("opt-1.3b train step", train_step(
 attn._kernel_backend = lambda: (True, False)
 for cell in probe.SERVING_CELLS:
     print(cell, *decode_row(cell))
+
+
+def latent_rows(cell):
+    """The latent node of ``cell`` at the cell's shapes: ``(rows a slot,
+    form, hash, length)`` of its decode row and of its prefill chunk."""
+    import probe_latent_decode
+    from mxnet_tpu.ops import pallas_decode as pd
+
+    spec, b, m, pt = probe_latent_decode.cell_shapes()
+    plane = jax.ShapeDtypeStruct(
+        pd.latent_plane_shape(b * m + 1, pt, spec.rank + spec.rope),
+        jnp.bfloat16)
+    w = jax.ShapeDtypeStruct(
+        (spec.heads * (spec.nope + spec.v), spec.rank), jnp.bfloat16)
+    chunk = manifest.load_cell(cell)["traffic"]["prefill_chunk"]
+    for slots, rows in ((b, 1), (1, chunk)):
+        q = lambda d: jax.ShapeDtypeStruct((slots, rows, spec.heads, d),
+                                           jnp.bfloat16)
+        text = str(jax.make_jaxpr(
+            lambda qn, qr, c, table, total, w: attn.latent_attend(
+                qn, qr, c, table, total, w, spec))(
+            q(spec.nope), q(spec.rope), plane,
+            jax.ShapeDtypeStruct((slots, m), jnp.int32),
+            jax.ShapeDtypeStruct((slots,), jnp.int32), w))
+        yield rows, attn.DECODE_PATH["last"], digest(text), len(text)
+
+
+for row in latent_rows("mistral4_serve_longdoc"):
+    print("mistral4_serve_longdoc latent node, rows a slot", *row)

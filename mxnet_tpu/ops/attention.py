@@ -1077,8 +1077,9 @@ def chunk_kernel_selected(q_shape, k_pool, v_pool, table_shape, num_heads,
     rows with every head's running state within fast memory).  ``chosen`` =
     ``(mask shape, width)`` of a selection laid over the walk
     (:func:`paged_attend_sparse`).  A call that brings its own ``gather``
-    (latent attention's expanded chunk) does not ask.  ``take`` is the
-    call's ``ChunkTiles`` where it is taken, None where it is not."""
+    (latent attention's expanded chunk) does not ask: it has a rule and a
+    kernel of its own (:func:`latent_chunk_kernel_selected`).  ``take`` is
+    the call's ``ChunkTiles`` where it is taken, None where it is not."""
     from . import pallas_decode as _pd
 
     runs, interpret = _kernel_backend()
@@ -2005,9 +2006,10 @@ def latent_expand(rows, w_kvb, spec):
 
 def _note_latent(form):
     """Count a node traced against a cache under the form it took
-    (``expanded``, ``absorbed``, or ``absorbed-kernel`` where the absorbed
-    row went to the Pallas kernel), and leave it in :data:`DECODE_PATH` for
-    the program's record, as :func:`paged_attend` leaves its path."""
+    (``expanded``, ``absorbed``, or ``absorbed-kernel`` / ``expanded-kernel``
+    where the absorbed row / the expanded chunk went to its Pallas kernel),
+    and leave it in :data:`DECODE_PATH` for the program's record, as
+    :func:`paged_attend` leaves its path."""
     from .. import obs as _obs
 
     DECODE_PATH["last"] = form
@@ -2061,6 +2063,107 @@ def latent_kernel_selected(q_shape, plane, table_shape, spec,
                             spec.rank, spec.rank + spec.rope), interpret
 
 
+def latent_chunk_kernel_selected(q_shape, plane, table_shape, spec,
+                                 mesh_active=False):
+    """``(take, interpret)``: whether :func:`latent_attend` hands a prefill
+    chunk in the expanded form to the chunk's Pallas kernel
+    (``pallas_decode.attend_latent_segment`` under
+    :func:`_attend_latent_segments`) in the walk's place, decided from what
+    the call shows, as :func:`latent_kernel_selected` decides for the decode
+    row.
+
+    All must hold: the expanded form (:func:`latent_form`) with ONE slot of
+    at least :data:`CHUNK_MIN_ROWS` query rows (``q_shape`` is (1, t, H *
+    (nope + rope))); a paged plane (``table_shape`` not None: a dense ring is
+    attended whole); a :func:`live_block_plan` (no mesh, a view of more than
+    a block); a backend that runs Pallas (:func:`_kernel_backend`); and
+    shapes the kernel tiles (``pallas_decode.latent_chunk_tiles``: keys and
+    values of whole lane tiles a head, a head's running row and a tile's
+    scores within fast memory).  ``take`` is the call's ``LatentChunkTiles``
+    where it is taken, None where it is not."""
+    from . import pallas_decode as _pd
+
+    runs, interpret = _kernel_backend()
+    pt = _page_positions(plane, spec.rank + spec.rope)
+    if q_shape[0] != 1 or q_shape[1] < CHUNK_MIN_ROWS \
+            or latent_form(q_shape[1]) != "expanded" or table_shape is None \
+            or not runs or live_block_plan(
+                q_shape, table_shape, pt, mesh_active=mesh_active) is None:
+        return None, False
+    return _pd.latent_chunk_tiles(
+        q_shape[1], spec.heads, spec.nope + spec.rope, spec.v, plane.dtype,
+        pt, table_shape[1] * pt), interpret
+
+
+def _attend_latent_segments(q_nope, q_rope, cache, table, total_len, w_kvb,
+                            spec, tiles, interpret):
+    """:func:`_attend_live_blocks`'s running row for an expanded chunk, a
+    SEGMENT of ``tiles.segment`` positions a step and the step a Pallas
+    kernel (:func:`latent_chunk_kernel_selected`).  A loop whose trip count
+    is data, the segments the slot's length has reached, gathers a segment's
+    pages, re-lays them out to positions and expands them through ``W_kvb``
+    heads first (the walk's step at a wider stride), and
+    ``pallas_decode.attend_latent_segment`` folds the segment into the
+    running ``(max, sum, acc)`` with the scores in fast memory.  The limit is
+    the walk's, every live position is attended, and the combine after the
+    loop is the walk's own."""
+    import jax
+    import jax.numpy as jnp
+
+    from . import pallas_decode as _pd
+
+    _, tq, h, _ = q_nope.shape
+    width = spec.rank + spec.rope
+    pt = _page_positions(cache, width)
+    cap = table.shape[1] * pt
+    pps = tiles.segment // pt
+    ns = -(-table.shape[1] // pps)
+    total = jnp.asarray(total_len, jnp.int32).reshape(-1)[0]
+    with _scope(spec.layer, "kv_gather"):
+        # a last segment that is not whole reads the scratch page past the
+        # table's end: those positions lie at or above the capacity
+        pages = jnp.pad(table[0].astype(jnp.int32),
+                        (0, ns * pps - table.shape[1])).reshape(ns, pps)
+        steps = jnp.where(total >= cap, ns,
+                          jnp.clip(-(-total // tiles.segment), 1, ns))
+    w_k, w_v = (w.astype(cache.dtype) for w in _latent_weights(w_kvb, spec))
+    # a head's key weights over a whole cached row: its own columns from the
+    # latent, the rotated part as it lies (an identity), (H, nope + rope,
+    # width), so that ONE product lays a head's keys side by side
+    w_k = jnp.concatenate(
+        [jnp.pad(w_k, ((0, 0), (0, 0), (0, spec.rope))),
+         jnp.broadcast_to(jnp.pad(jnp.eye(spec.rope, dtype=cache.dtype),
+                                  ((0, 0), (spec.rank, 0))),
+                          (h, spec.rope, width))], axis=1)
+    q = jnp.swapaxes(jnp.concatenate([q_nope, q_rope], axis=-1)[0], 0, 1)
+    state = (jnp.concatenate(
+        [jnp.full((h, 1, tq), jnp.finfo(jnp.float32).min, jnp.float32),
+         jnp.zeros((h, 7, tq), jnp.float32)], axis=1),
+        jnp.zeros((h, tq, spec.v), jnp.float32))
+
+    def step(carry):
+        i, state = carry
+        with _scope(spec.layer, "kv_gather"):
+            rows = latent_pages(cache, jax.lax.dynamic_slice_in_dim(
+                pages, i, 1), width)[0]                     # (segment, width)
+        with _scope(spec.layer, "expand"):
+            keys = jnp.einsum("cw,hdw->hcd", rows, w_k)
+            values = jnp.einsum("cr,hdr->hcd", rows[:, :spec.rank], w_v)
+        with _scope(spec.layer, "scores"):
+            state = _pd.attend_latent_segment(
+                q, keys, values, state, i * tiles.segment, total, cap, tiles,
+                spec.scale, interpret=interpret)
+        return i + 1, state
+
+    _, (stats, acc) = jax.lax.while_loop(
+        lambda carry: carry[0] < steps, step, (jnp.int32(0), state))
+    with _scope(spec.layer, "scores"):
+        m, den = (jnp.swapaxes(stats[:, r], 0, 1)[None, None]
+                  for r in (0, 1))                          # (1, 1, tq, H)
+        acc = jnp.swapaxes(acc, 0, 1)[None, None]
+    return _combine_blocks(m, den, acc, None, 1.0, cache.dtype, spec.layer)
+
+
 def latent_attend(q_nope, q_rope, cache, table, total_len, w_kvb, spec,
                   mesh_active=False):
     """(B, t, H, nope) and (B, t, H, rope) queries, already rotated, against
@@ -2078,17 +2181,31 @@ def latent_attend(q_nope, q_rope, cache, table, total_len, w_kvb, spec,
     same list that copies each live block's pages as they lie and multiplies
     them there (``absorbed-kernel``).  The arithmetic is the walk's (the
     serving type's operands, float32 sums, probabilities rounded to the
-    plane's type) in another order; the logits are not rounded on the way."""
+    plane's type) in another order; the logits are not rounded on the way.
+
+    So has the expanded form: the walk (``expanded``: a step gathers and
+    expands a block and leaves its float32 scores to HBM between the two
+    products), and, for ONE slot's prefill chunk over shapes the kernel tiles
+    (:func:`latent_chunk_kernel_selected`), the walk by segments of several
+    blocks with a Pallas kernel as its step (``expanded-kernel``,
+    :func:`_attend_latent_segments`): the scores and a head's running row
+    stay in fast memory.  The same arithmetic in another order again."""
     import jax.numpy as jnp
 
     b, t, h, _ = q_nope.shape
     form = latent_form(t)
     width = spec.rank + spec.rope
-    tiles, interpret = (None, False) if form == "expanded" else \
-        latent_kernel_selected(
-            (b, t, h * width), cache, None if table is None else table.shape,
-            spec, mesh_active=mesh_active)
-    _note_latent(form if tiles is None else "absorbed-kernel")
+    shown = (None if table is None else table.shape, spec)
+    tiles, interpret = latent_chunk_kernel_selected(
+        (b, t, h * (spec.nope + spec.rope)), cache, *shown,
+        mesh_active=mesh_active) if form == "expanded" else \
+        latent_kernel_selected((b, t, h * width), cache, *shown,
+                               mesh_active=mesh_active)
+    _note_latent(form if tiles is None else form + "-kernel")
+    if form == "expanded" and tiles is not None:
+        return _attend_latent_segments(q_nope, q_rope, cache, table,
+                                       total_len, w_kvb, spec, tiles,
+                                       interpret)
     w_k, w_v = _latent_weights(w_kvb, spec)
     if form == "expanded":
         q = jnp.concatenate([q_nope, q_rope], axis=-1)

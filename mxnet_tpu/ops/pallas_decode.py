@@ -74,6 +74,13 @@ rows laid block-diagonally over a row's positions.  Its shape rule is
 with the kernel above and none of its body (the section's header below says
 why the plane is stored so, and in which order a block's positions lie inside
 the kernel).
+
+Two more serve a prefill chunk, ONE slot's many query rows, each under a
+section header of its own below: :func:`attend_chunk_blocks` over pools whose
+pages ARE keys and values (it copies them), and :func:`attend_latent_segment`
+over a latent plane's rows once a segment of them has been multiplied into
+keys and values (ordinary blocks; the step of the walk by segments,
+``ops.attention._attend_latent_segments``).
 """
 from __future__ import annotations
 
@@ -1339,3 +1346,287 @@ def _attend_chunk_blocks(qh, mask, kd, vd, turned, pages, total, *, t, scale,
         )(pages.reshape(-1).astype(jnp.int32),
           jnp.reshape(total, (1,)).astype(jnp.int32), *args)
     return acc, stats
+
+
+# ---------------------------------------------------------------------------
+# The expanded latent chunk (``ops.attention.latent_attend`` with ONE slot and
+# many query rows over a paged plane).  The walk's step gathers a block of 512
+# positions, expands it through ``W_kvb`` and takes ``_sdpa_cache`` over it:
+# between the two products the float32 logits of ``heads x rows x block``
+# entries go to HBM and come back, and the running accumulator of ``rows x
+# heads x v`` floats is read and written a block.  Here the walk keeps its
+# shape and takes a wider step: the loop (``ops.attention.
+# _attend_latent_segments``) gathers and expands a SEGMENT of
+# :data:`LATENT_CHUNK_SEGMENT` positions in XLA, heads first, and ONE kernel
+# takes every row against the segment's dense keys and values.  A grid step is
+# (head, block of the segment); a head's running ``(max, sum, acc)`` comes in
+# from the segment before, stays in fast memory over the segment's blocks and
+# leaves once: the kernel IS the walk's fold, so the accumulator crosses HBM
+# once a segment and the scores never do.  A fourth kernel with a shape rule
+# of its own (:func:`latent_chunk_tiles`); it copies no page (a segment's keys
+# and values are ordinary blocks) and shares the walk's limit and the combine
+# after the loop.
+#
+# A head's keys are its own ``nope`` columns beside the ONE rotated part every
+# head shares (64 + 64 at Mistral-Small-4: one lane tile, one pass of the
+# matrix unit's depth, where the two parts apart would be two half-filled
+# ones), so the expansion lays them side by side, heads first.  The causal
+# limit is the walk's, by absolute position; a block no row can see is neither
+# multiplied nor fetched (its index is held at the last live block's), a tile
+# of rows skips the blocks above its last row, and only the blocks its limit
+# crosses build a mask.
+#
+# The other form, the expansion INSIDE the kernel from the pages as they lie
+# (block-diagonal weights over a row's two positions, a head-major grid), was
+# measured beside this one and is kept, with its numbers, in
+# benchmarks/probe_latent_chunk.py.
+# ---------------------------------------------------------------------------
+
+# Positions a step of the loop (a segment), positions a grid step (a block)
+# and query rows a tile (the largest first that divides the chunk and fits).
+# Measured alone on the chip (TPU v5 lite, jax 0.9.0, the one cell's shapes:
+# ONE slot's 2048 rows of 32 heads of 64 + 64 key and 128 value columns over a
+# bfloat16 plane of 83,201 pages, the chunk's last row at 16k / 32k / 64k
+# positions; benchmarks/probe_latent_chunk.py, its lines in benchmarks/runs/
+# pr62_probe.out, PR 62; ms a layer, gather and expansion inside on every
+# side; in brackets the share of the bfloat16 peak by the causal FLOPs the
+# benchmark's reader counts):
+#   the walk (a block of 512 a step)        6.04 / 11.90 / 23.65  (48-51 %)
+#   the expansion inside the kernel, tiles of 256 rows
+#                                           5.20 / 10.04 / 19.69  (55-61 %)
+#   segment 8192, block 1024, tile 256      4.06 /  7.88 / 15.49  (71-78 %)
+#     segment 4096                          4.21 /  8.17 / 16.06
+#     segment 16384                         4.01 /  7.72 / 15.17
+#     block 512                             4.10 /  7.93 / 15.63
+#     block 2048                            4.41 /  8.36 / 16.34
+#     tile 128                              4.68 /  9.13 / 17.93
+#     tile 512                              4.23 /  8.16 / 16.01
+# A segment of 16384 is 2 % ahead (the running row crosses HBM half as often)
+# and holds 0.27 GB of expanded keys and values where 8192 holds 0.13, and a
+# chunk's last segment is half dead on average, expanded all the same.  What
+# the kernel cannot hide, with both products taken out of it (the same probe,
+# a block of 512 and tiles of 512 without the straight run below: 8.86 ms
+# at 32k whole, 6.64 without the first product, 7.00 without the second, 5.10
+# without both, 8.59 without the exponentials): the softmax's passes over the
+# scores and XLA's gather and expansion are 5.1 ms where the two products are
+# 5.6 at the peak, so the two sides nearly tie and the rest is how well they
+# overlap: tiles of 256 in one straight run gave 8.86 -> 7.88.
+LATENT_CHUNK_SEGMENT = 8192
+LATENT_CHUNK_BLOCK = 1024
+LATENT_CHUNK_TILE_ROWS = (256, 128)
+
+
+class LatentChunkTiles(NamedTuple):
+    """The static sizes of one :func:`attend_latent_segment` call."""
+
+    heads: int       # H
+    hd: int          # a head's key columns: nope + rope
+    v: int           # a head's value columns
+    rows: int        # query rows of the chunk
+    tile: int        # query rows a tile
+    block: int       # positions a grid step
+    segment: int     # positions a step of the loop
+    exact: bool      # a float32 plane: products at Precision.HIGHEST
+    vmem: int        # bytes of fast memory a step may take
+
+
+def latent_chunk_tiles(rows, heads, hd, v, dtype, page_tokens, cap):
+    """The :class:`LatentChunkTiles` of one slot's ``rows`` query rows of
+    ``heads`` heads (keys of ``hd`` = nope + rope columns, values of ``v``)
+    over a plane of ``dtype`` read through a table of ``cap`` positions in
+    pages of ``page_tokens``, or None where the kernel does not tile the
+    shapes: the walk then serves them."""
+    import jax.numpy as jnp
+
+    item = jnp.dtype(dtype).itemsize
+    block = LATENT_CHUNK_BLOCK
+    # a head's keys and values are whole lane tiles, a block whole pages
+    if item not in (2, 4) or hd % LANES or v % LANES or heads <= 0 \
+            or block % page_tokens or block % LANES or rows % LANES:
+        return None
+    # no more of a segment than the view holds, in whole blocks
+    segment = min(LATENT_CHUNK_SEGMENT, -(-cap // block) * block)
+    if segment % block:
+        return None
+    for tile in LATENT_CHUNK_TILE_ROWS:
+        if rows % tile:
+            continue
+        # a head's queries and a block's keys and values (two buffers each),
+        # the head's accumulator in and out (two each) and its maxima and
+        # sums, a tile's scores five times over
+        vmem = 2 * rows * hd * item + 2 * block * (hd + v) * item \
+            + 4 * rows * v * 4 + 4 * 8 * rows * 4 + 2 * rows * LANES * 4 \
+            + 5 * tile * block * 4
+        if vmem <= _VMEM_BUDGET:
+            return LatentChunkTiles(heads, hd, v, rows, tile, block, segment,
+                                    item == 4, vmem)
+    return None
+
+
+def _segment_live_blocks(at_ref, t, cap):
+    """Blocks of the segment that hold a position some row sees (the first
+    always): ``at_ref`` = (the segment's first position, the slot's length
+    through the chunk's last row)."""
+    import jax.numpy as jnp
+
+    seen = jnp.minimum(at_ref[1], cap) - at_ref[0]
+    return jnp.clip(_div(seen + t.block - 1, t.block), 1,
+                    t.segment // t.block)
+
+
+def _latent_chunk_kernel(at_ref, q_ref, k_ref, v_ref, acc_in, stat_in,
+                         acc_ref, stat_ref, m_scr, l_scr, *, t, scale, cap):
+    """One invocation is a head's ``t.rows`` query rows against one block of
+    the segment.  ``q_ref`` (1, rows, hd) the head's queries; ``k_ref`` (1,
+    block, hd), ``v_ref`` (1, block, v) its keys and values.  ``acc_in`` (1,
+    rows, v) and ``stat_in`` (1, 8, rows: the maxima in row 0, the sums in
+    row 1) are the head's running row as the segment before left it;
+    ``acc_ref`` and ``stat_ref`` take it as this one leaves it."""
+    import jax
+    import jax.numpy as jnp
+    pl, _ = _pallas()
+
+    j = pl.program_id(1)
+    rows, tile, block = t.rows, t.tile, t.block
+    fill = jnp.finfo(jnp.float32).min
+    prec = jax.lax.Precision.HIGHEST if t.exact else None
+    # row r sees the positions under total - (rows - 1) + r, and none at or
+    # above the view's capacity: the walk's limit
+    low = at_ref[1] - (rows - 1)
+    pos0 = at_ref[0] + j * block
+
+    @pl.when(j == 0)
+    def _enter():
+        acc_ref[...] = acc_in[...]
+        stats = stat_in[0]                                  # (8, rows)
+        m_scr[...] = jnp.broadcast_to(stats[0:1], (LANES, rows)).T
+        l_scr[...] = jnp.broadcast_to(stats[1:2], (LANES, rows)).T
+
+    def update(r0, masked):
+        at = pl.ds(r0, tile)
+        s = jax.lax.dot_general(
+            q_ref[0, at], k_ref[0], (((1,), (1,)), ((), ())), precision=prec,
+            preferred_element_type=jnp.float32) * jnp.float32(scale)
+        if masked:                                          # (tile, block)
+            pos = pos0 + jax.lax.broadcasted_iota(jnp.int32, (tile, block), 1)
+            limit = jnp.minimum(low + r0 + jax.lax.broadcasted_iota(
+                jnp.int32, (tile, 1), 0), cap)
+            s = jnp.where(pos < limit, s, fill)
+        m0 = m_scr[at]                                      # (tile, 128)
+        m1 = jnp.maximum(m0, jnp.max(s, axis=1, keepdims=True))
+        shrink = jnp.exp(m0 - m1)
+        p = jnp.exp(s - jnp.concatenate([m1] * (block // LANES), axis=1))
+        l_scr[at] = shrink * l_scr[at] + jnp.sum(p, axis=1, keepdims=True)
+        m_scr[at] = m1
+        acc_ref[0, at] = acc_ref[0, at] * jnp.concatenate(
+            [shrink] * (t.v // LANES), axis=1) + jax.lax.dot_general(
+                p.astype(v_ref.dtype), v_ref[0], (((1,), (0,)), ((), ())),
+                precision=prec, preferred_element_type=jnp.float32)
+
+    def crossed(r, _):
+        r0 = pl.multiple_of(r * tile, tile)
+        first = jnp.minimum(low + r0, cap)
+        # (a row that sees nothing still takes the view's first block, so
+        # that its answer is finite: the walk's)
+        last = jnp.maximum(jnp.minimum(low + r0 + tile - 1, cap), 1)
+
+        @pl.when(pos0 + block <= first)
+        def _whole():
+            update(r0, False)
+
+        @pl.when((pos0 < last) & (pos0 + block > first))
+        def _some():
+            update(r0, True)
+
+    @pl.when(j < _segment_live_blocks(at_ref, t, cap))
+    def _block():
+        # a block under every row's limit (all but the chunk's own few) is
+        # ONE straight run of the tiles, no branch between them: the
+        # scheduler lays a tile's products beside its neighbour's softmax
+        every = pos0 + block <= jnp.minimum(low, cap)
+
+        @pl.when(every)
+        def _all():
+            for r in range(rows // tile):
+                update(r * tile, False)
+
+        @pl.when(jnp.logical_not(every))
+        def _edge():
+            jax.lax.fori_loop(0, rows // tile, crossed, None)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _leave():
+        top = jax.lax.broadcasted_iota(jnp.int32, (8, rows), 0) == 0
+        stat_ref[0] = jnp.where(top, m_scr[...].T[:8], l_scr[...].T[:8])
+
+
+def attend_latent_segment(q, keys, values, state, first, total, cap, t, scale,
+                          interpret=False):
+    """One slot's query rows over one expanded segment, folded into the
+    walk's running row: ``state`` = ``(stats (H, 8, rows), acc (H, rows, v))``
+    float32 as the segments before left it (the maxima in row 0 of ``stats``,
+    the sums in row 1; ``finfo.min``, 0 and 0 before the first) -> the same
+    after this one, not yet normalized.
+
+    ``q`` (H, rows, hd), row ``i`` at position ``total - rows + i``; ``keys``
+    (H, segment, hd) and ``values`` (H, segment, v) the positions ``first``
+    to ``first + segment`` of the view, in the plane's type, as the queries
+    are taken; ``cap`` the view's capacity: row ``i`` attends the positions
+    under ``min(total - (rows - 1) + i, cap)``.  ``t`` the call's
+    :class:`LatentChunkTiles`."""
+    import jax.numpy as jnp
+
+    at = jnp.stack([jnp.asarray(first, jnp.int32).reshape(()),
+                    jnp.asarray(total, jnp.int32).reshape(())])
+    return _jitted_latent_chunk()(
+        at, q.astype(keys.dtype), keys, values, *state, t=t,
+        scale=float(scale), cap=int(cap), interpret=bool(interpret))
+
+
+@functools.lru_cache(maxsize=None)
+def _jitted_latent_chunk():
+    import jax
+
+    return jax.jit(_attend_latent_segment,
+                   static_argnames=("t", "scale", "cap", "interpret"))
+
+
+def _attend_latent_segment(at, q, keys, values, stats, acc, *, t, scale, cap,
+                           interpret):
+    import jax
+    import jax.numpy as jnp
+    pl, pltpu = _pallas()
+
+    head = lambda h, j, at_ref: (h, 0, 0)
+    # a block no row sees is not fetched: the last live one stays
+    block = lambda h, j, at_ref: (
+        h, jnp.minimum(j, _segment_live_blocks(at_ref, t, cap) - 1), 0)
+    with jax.enable_x64(False):
+        acc, stats = pl.pallas_call(
+            functools.partial(_latent_chunk_kernel, t=t, scale=float(scale),
+                              cap=int(cap)),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1,
+                grid=(t.heads, t.segment // t.block),
+                in_specs=[pl.BlockSpec((1, t.rows, t.hd), head),
+                          pl.BlockSpec((1, t.block, t.hd), block),
+                          pl.BlockSpec((1, t.block, t.v), block),
+                          pl.BlockSpec((1, t.rows, t.v), head),
+                          pl.BlockSpec((1, 8, t.rows), head)],
+                out_specs=[pl.BlockSpec((1, t.rows, t.v), head),
+                           pl.BlockSpec((1, 8, t.rows), head)],
+                scratch_shapes=[pltpu.VMEM((t.rows, LANES), jnp.float32),
+                                pltpu.VMEM((t.rows, LANES), jnp.float32)]),
+            out_shape=[
+                jax.ShapeDtypeStruct((t.heads, t.rows, t.v), jnp.float32),
+                jax.ShapeDtypeStruct((t.heads, 8, t.rows), jnp.float32)],
+            # the running row is folded where it lies
+            input_output_aliases={4: 0, 5: 1},
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary"),
+                vmem_limit_bytes=int(min(100 << 20,
+                                         max(32 << 20, 2 * t.vmem)))),
+            name="latent_chunk_segment",
+            interpret=interpret,
+        )(at, q, keys, values, acc, stats)
+    return stats, acc

@@ -368,7 +368,9 @@ def test_heads_of_64_keep_the_walk(interpret):
 def test_an_expanded_latent_chunk_keeps_the_walk(interpret):
     """Latent attention's expanded chunk brings its own ``gather`` (a block's
     rows turned back into keys and values inside the walk): it does not ask
-    the rule, and its program holds no kernel."""
+    THIS rule, and a chunk of 96 rows, which its own rule
+    (``latent_chunk_kernel_selected``, tests/test_latent_chunk_kernel.py)
+    does not tile, holds no kernel."""
     spec = attn.latent_spec(dict(num_heads=2, qk_nope_head_dim=128,
                                  qk_rope_head_dim=64, v_head_dim=128,
                                  kv_lora_rank=256))

@@ -847,6 +847,47 @@ def test_latent_kernel_compiles_for_the_chip_at_the_cells_shapes(
     assert not re.search(r"bf16\[\d+,512,320\]", text)
 
 
+def test_latent_chunk_kernel_compiles_for_the_chip_at_the_cells_shapes(
+        one_chip, monkeypatch):
+    """The cell's prefill chunk (ONE slot's 2048 rows) through
+    ``latent_attend``, compiled by the chip's own compiler: Mosaic takes the
+    segments' kernel at the published widths, the loop is still there (a
+    segment a step), and no array holds a block's float32 scores (results:
+    tests/test_latent_chunk_kernel.py)."""
+    import re
+
+    import probe_latent_decode as latent_probe
+
+    monkeypatch.setattr(attn, "_kernel_backend", lambda: (True, False))
+    spec, b, m, pt = latent_probe.cell_shapes()
+    width = spec.rank + spec.rope
+    sds = lambda shape, dtype: jax.ShapeDtypeStruct(shape, dtype,
+                                                    sharding=one_chip)
+    args = (sds((1, 2048, spec.heads, spec.nope), jnp.bfloat16),
+            sds((1, 2048, spec.heads, spec.rope), jnp.bfloat16),
+            sds(pd.latent_plane_shape(b * m + 1, pt, width), jnp.bfloat16),
+            sds((1, m), jnp.int32), sds((1,), jnp.int32),
+            sds((spec.heads * (spec.nope + spec.v), spec.rank),
+                jnp.bfloat16))
+
+    def attend(*a):
+        return attn.latent_attend(*a, spec)
+
+    compiled = _compiled_for_the_chip(attend, args)
+    text = compiled.as_text()
+    assert attn.DECODE_PATH["last"] == "expanded-kernel"
+    t, _ = attn.latent_chunk_kernel_selected(
+        (1, 2048, spec.heads * (spec.nope + spec.rope)), args[2], (1, m),
+        spec)
+    assert (t.hd, t.v, t.tile, t.block, t.segment) == \
+        (128, 128, 256, 1024, 8192)
+    assert text.count("tpu_custom_call") == 1 and " while(" in text
+    assert not re.search(r"bf16\[83201,8,640\]\S* copy\(", text)
+    assert not re.search(r"f32\[1,32,2048,\d+\]", text)      # the scores
+    # a segment's keys and values, not a context's
+    assert compiled.memory_analysis().temp_size_in_bytes < 300 << 20
+
+
 # ---------------------------------------------------------------------------
 # the delta rule's decode step (ops/pallas_delta.py; its results against the
 # elementwise step are tests/test_delta_step_kernel.py's)
